@@ -11,30 +11,37 @@ BASELINE.md "North-star targets"); vs_baseline = achieved_MFU / 0.35.
 from __future__ import annotations
 
 import json
-import sys
+import os
 import time
 
+# one compile cache for every run from this checkout, placeable from outside
+os.environ.setdefault(
+    "JAX_COMPILATION_CACHE_DIR",
+    os.path.join(os.path.dirname(os.path.abspath(__file__)), ".jax_cache"),
+)
+# a Pallas kernel's module is serialised with its Python call stack (file paths
+# and line numbers, ten frames up) into an opaque string the cache key cannot
+# strip, so a moved checkout or a shifted line would recompile the step
+os.environ.setdefault("JAX_TRACEBACK_IN_LOCATIONS_LIMIT", "0")
+
+# per-chip peak dense bf16 FLOP/s, keyed by ``device_kind`` exactly as JAX
+# reports it on a machine this repo has run on (Google Cloud documentation,
+# "TPU v5e": 197 TFLOP/s). Add a device with its source when one is seen.
 PEAK_BF16_FLOPS = {
-    # per-chip peak dense bf16 FLOP/s (public spec sheets)
-    "v4": 275e12,
-    "v5litepod": 197e12,
-    "v5e": 197e12,
-    "v5p": 459e12,
-    "v6e": 918e12,
-    "cpu": 1e11,  # nominal, only so the script degrades gracefully
+    "TPU v5 lite": 197e12,
 }
 
 
-def _detect_peak(backend: str, device_kind: str) -> float:
-    kind = device_kind.lower()
-    if backend != "tpu":
-        return PEAK_BF16_FLOPS["cpu"]
-    for key, val in PEAK_BF16_FLOPS.items():
-        if key in kind.replace(" ", "").replace("lite", "litepod"):
-            return val
-    if "v5" in kind:
-        return PEAK_BF16_FLOPS["v5e"]
-    return PEAK_BF16_FLOPS["v5e"]
+def peak_bf16_flops(device_kind: str) -> float:
+    """Peak of one chip of ``device_kind``; a device that is not in the
+    table is an error, not a default."""
+    try:
+        return PEAK_BF16_FLOPS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no bf16 peak recorded for device_kind {device_kind!r}: add it to "
+            f"PEAK_BF16_FLOPS with its source (known: {sorted(PEAK_BF16_FLOPS)})"
+        ) from None
 
 
 def main():
@@ -48,6 +55,7 @@ def main():
     backend = jax.default_backend()
     n_dev = len(jax.devices())
     device_kind = jax.devices()[0].device_kind
+    peak = peak_bf16_flops(device_kind) * n_dev  # no chip it knows: an error
 
     # GPT-J-6B LAYER GEOMETRY (d_model 4096, 16 heads x head_dim 256,
     # d_ff 16384, seq 2048, parallel block, remat on): per-layer compute is
@@ -56,34 +64,20 @@ def main():
     # needs the v5e-64 FSDP mesh the driver cannot attach). MFU measured on
     # these layers transfers to full depth: remat makes every layer's
     # compute/memory profile identical.
-    if backend == "tpu":
-        cfg = TransformerConfig(
-            vocab_size=50432,
-            d_model=4096,
-            n_layers=4,
-            n_heads=16,
-            d_ff=16384,
-            max_seq_len=2048,
-            parallel_block=True,
-            use_swiglu=False,
-            # dots-saveable selective remat: backward re-runs only cheap
-            # elementwise work; matmul outputs stay in HBM (fits at batch 8)
-            remat_policy="dots",
-        )
-        batch, seq, steps = 8, 2048, 10
-    else:  # CPU fallback so the script always emits its line
-        cfg = TransformerConfig(
-            vocab_size=1024,
-            d_model=256,
-            n_layers=4,
-            n_heads=8,
-            d_ff=1024,
-            max_seq_len=256,
-            parallel_block=True,
-            use_swiglu=False,
-            remat=False,
-        )
-        batch, seq, steps = 4, 256, 3
+    cfg = TransformerConfig(
+        vocab_size=50432,
+        d_model=4096,
+        n_layers=4,
+        n_heads=16,
+        d_ff=16384,
+        max_seq_len=2048,
+        parallel_block=True,
+        use_swiglu=False,
+        # dots-saveable selective remat: backward re-runs only cheap
+        # elementwise work; matmul outputs stay in HBM (fits at batch 8)
+        remat_policy="dots",
+    )
+    batch, seq, steps = 8, 2048, 10
 
     mesh = create_mesh(MeshConfig(data=n_dev))
     bundle = build_lm_train_step(cfg, mesh, learning_rate=1e-4)
@@ -94,16 +88,16 @@ def main():
     targets = np.roll(tokens, -1, axis=1)
     tok, tgt = bundle.shard_batch(tokens, targets)
 
-    # warmup (compile); sync via device_get — block_until_ready can return
-    # early on relayed/experimental PJRT backends
+    # warmup (compile)
     state, metrics = bundle.step_fn(state, tok, tgt)
-    float(jax.device_get(metrics["loss"]))
+    jax.block_until_ready(metrics)
 
     t0 = time.perf_counter()
     for _ in range(steps):
         state, metrics = bundle.step_fn(state, tok, tgt)
-    final_loss = float(jax.device_get(metrics["loss"]))
+    jax.block_until_ready(metrics)
     dt = time.perf_counter() - t0
+    final_loss = float(metrics["loss"])
 
     n_params = cfg.num_params()
     tokens_per_step = batch * seq
@@ -114,7 +108,6 @@ def main():
     steps_per_sec = steps / dt
     tokens_per_sec = tokens_per_step * steps_per_sec
     achieved = model_flops_per_step * steps_per_sec
-    peak = _detect_peak(backend, device_kind) * n_dev
     mfu = achieved / peak
 
     result = {

@@ -5,15 +5,28 @@ tpu.py:71``): chip count via /dev/accel* or vfio, ``TPU_VISIBLE_CHIPS``
 visibility control, pod type from GCE metadata (``tpu.py:48``), worker id, and
 the ``TPU-{pod}-head`` gang-scheduling resource (``tpu.py:334``). Detection
 here never imports jax (the core runtime must not initialize the device).
+
+One process per chip: a worker that holds no ``TPU`` resource is pinned to
+JAX's CPU backend at start-up (``set_worker_platform``), and one that holds
+chips gets the visibility AND per-process bounds libtpu needs to open just
+those chips (``visible_chip_env``).
 """
 
 from __future__ import annotations
 
 import glob
 import os
-from typing import List, Optional
+import sys
+from typing import Dict, List, Optional, Sequence
 
 TPU_VISIBLE_CHIPS_ENV = "TPU_VISIBLE_CHIPS"
+TPU_CHIPS_PER_HOST_BOUNDS_ENV = "TPU_CHIPS_PER_HOST_BOUNDS"
+TPU_HOST_BOUNDS_ENV = "TPU_HOST_BOUNDS"
+# libtpu opens a SUBSET of a host's chips only when told the subset's shape
+# (parity: the reference's set_current_process_visible_accelerator_ids);
+# with the host's own bounds left in place a one-chip process claims every
+# chip of the host
+_SUBSET_BOUNDS = {1: "1,1,1", 2: "1,2,1"}
 GCE_TPU_ACCELERATOR_ENV = "TPU_ACCELERATOR_TYPE"  # e.g. "v5litepod-64"
 GCE_TPU_WORKER_ID_ENV = "TPU_WORKER_ID"
 GCE_TPU_TOPOLOGY_ENV = "TPU_TOPOLOGY"
@@ -29,17 +42,23 @@ def _visible_chips() -> Optional[List[str]]:
     return [c for c in raw.split(",") if c != ""]
 
 
+def host_chip_count() -> int:
+    """Chips the host's /dev shows, whatever this process may see of them:
+    /dev/accelN (older TPU VMs) or one numbered IOMMU group per chip under
+    /dev/vfio/ next to the "vfio" control node (the v5e machines this repo
+    runs on show /dev/vfio/3 + /dev/vfio/vfio for one chip)."""
+    paths = glob.glob("/dev/accel*") or [
+        p for p in glob.glob("/dev/vfio/*") if os.path.basename(p).isdigit()
+    ]
+    return len(paths)
+
+
 def detect_chip_count() -> int:
-    """Number of TPU chips attached to this host (0 if none)."""
+    """Number of TPU chips this process may use (0 if none)."""
     vis = _visible_chips()
     if vis is not None:
         return len(vis)
-    paths = glob.glob("/dev/accel*") or glob.glob("/dev/vfio/*")
-    if paths:
-        return len([p for p in paths if os.path.basename(p) != "vfio"])
-    if os.environ.get("RAY_TPU_FAKE_CHIPS"):
-        return int(os.environ["RAY_TPU_FAKE_CHIPS"])
-    return 0
+    return host_chip_count()
 
 
 def detect_pod_type() -> Optional[str]:
@@ -75,8 +94,43 @@ def pod_host_count(pod_type: str) -> int:
     return max(1, chips // per_host)
 
 
-def set_visible_chips(chips: List[str]) -> None:
-    os.environ[TPU_VISIBLE_CHIPS_ENV] = ",".join(chips)
+def visible_chip_env(chips: Sequence[int], host_chips: int) -> Dict[str, str]:
+    """Env that makes libtpu open exactly ``chips`` of a ``host_chips`` host.
+    The whole host needs nothing (libtpu's defaults are the host's bounds);
+    a subset needs its indices plus the bounds of the sub-mesh it forms."""
+    if host_chips and len(chips) >= host_chips:
+        return {}
+    env = {TPU_VISIBLE_CHIPS_ENV: ",".join(str(c) for c in chips)}
+    bounds = _SUBSET_BOUNDS.get(len(chips))
+    if bounds:
+        env[TPU_CHIPS_PER_HOST_BOUNDS_ENV] = bounds
+        env[TPU_HOST_BOUNDS_ENV] = "1,1,1"
+    return env
+
+
+_inherited_platforms: Optional[str] = None
+
+
+def set_worker_platform(holds_tpu: bool) -> None:
+    """Bind this worker process's JAX platform to the resources it holds.
+
+    Without chips the worker is held to the CPU backend, so nothing it
+    imports can open (and lock) a chip that a replica or trainer needs.
+    With chips it gets back the platform list it was started with — or the
+    strict ``tpu,cpu`` when there was none, under which JAX fails at
+    backend start-up instead of warning and carrying on on the CPU. A
+    backend that is already up keeps its platform;
+    ``train.jax_utils.ensure_platform`` refuses that mismatch."""
+    global _inherited_platforms
+    if _inherited_platforms is None:
+        _inherited_platforms = os.environ.get("JAX_PLATFORMS", "")
+    plat = (_inherited_platforms or "tpu,cpu") if holds_tpu else "cpu"
+    os.environ["JAX_PLATFORMS"] = plat
+    # a jax that is still mid-import on another thread has no config yet and
+    # reads the env when it gets there
+    config = getattr(sys.modules.get("jax"), "config", None)
+    if config is not None:
+        config.update("jax_platforms", plat)
 
 
 def get_current_pod_name() -> Optional[str]:

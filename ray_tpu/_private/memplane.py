@@ -247,13 +247,27 @@ def _get_device_gauges() -> Dict[str, object]:
     return _device_gauges
 
 
+_jax_backend_up = False
+
+
+def note_jax_backend_up() -> None:
+    """This process has started a JAX backend (it compiled, or a ray_tpu
+    entry point checked its platform): the device sweep may now run. The
+    sweep's own calls (``live_arrays``/``local_devices``) START a backend
+    where there is none, and a process that merely imported jax would then
+    open — and keep — the chip its neighbour was given."""
+    global _jax_backend_up
+    _jax_backend_up = True
+
+
 def maybe_record_device_metrics() -> bool:
-    """Record per-device JAX memory gauges when (and only when) user code
-    has imported jax in this process. Called from the telemetry flusher
-    cadence; self-rate-limited; never imports jax itself. Returns True
-    when a sweep was recorded."""
+    """Record per-device JAX memory gauges when (and only when) this
+    process is known to have a JAX backend up (``note_jax_backend_up``).
+    Called from the telemetry flusher cadence; self-rate-limited; never
+    imports jax or starts a backend itself. Returns True when a sweep was
+    recorded."""
     global _last_device_probe
-    if "jax" not in sys.modules or not enabled():
+    if not _jax_backend_up or not enabled():
         return False
     now = time.monotonic()
     if now - _last_device_probe < _DEVICE_PROBE_INTERVAL_S:
@@ -268,7 +282,7 @@ def maybe_record_device_metrics() -> bool:
 def collect_device_metrics() -> bool:
     """One sweep of jax device stats into the ``ray_tpu_device_*`` gauges.
     Separate from the rate-limited probe so tests/read paths can force it."""
-    import jax  # already imported by user code (see maybe_record_device_metrics)
+    import jax
 
     pid = str(os.getpid())
     gauges = _get_device_gauges()

@@ -128,7 +128,13 @@ def visible_env_for(allocs: Dict[str, List[Tuple[int, float]]]) -> Dict[str, str
     env: Dict[str, str] = {}
     tpu = allocs.get("TPU")
     if tpu:
-        env["TPU_VISIBLE_CHIPS"] = ",".join(str(i) for i, _ in tpu)
+        from ray_tpu._private.accelerators import tpu as tpu_accel
+
+        env.update(
+            tpu_accel.visible_chip_env(
+                [i for i, _ in tpu], tpu_accel.host_chip_count()
+            )
+        )
     gpu = allocs.get("GPU")
     if gpu:
         env["CUDA_VISIBLE_DEVICES"] = ",".join(str(i) for i, _ in gpu)

@@ -295,15 +295,15 @@ def maybe_install_jax_hooks() -> None:
     """Cheap periodic probe (called from the telemetry flusher cadence):
     once user code has imported jax, register the duration listener. Never
     imports jax itself."""
-    if _jax_hooked or "jax" not in sys.modules:
+    if _jax_hooked or "jax.monitoring" not in sys.modules:
         return
     install_jax_hooks()
 
 
 def install_jax_hooks() -> bool:
     """Record ``jax:<event>`` profile spans for jax's monitored durations
-    (compile/backend/execute events) when jax's monitoring listener API is
-    importable. Safe no-op otherwise; idempotent.
+    (compile/backend/execute events) through ``jax.monitoring``'s listener
+    registry. Idempotent.
 
     Each span is attributed to the (task, trace) the TRIGGERING thread is
     executing — the sampler's per-thread registry plus the thread's active
@@ -313,66 +313,72 @@ def install_jax_hooks() -> bool:
     global _jax_hooked
     if _jax_hooked:
         return True
-    try:
-        from jax._src import monitoring as _jm  # jax >= 0.4 internal API
+    # the module object, never an import statement: the flusher thread gets
+    # here while user code may be mid-``import jax`` on another thread, and
+    # waiting for jax's import lock from here raises _DeadlockError in the
+    # USER's import. ``import jax`` loads jax.monitoring itself.
+    monitoring = sys.modules.get("jax.monitoring")
+    register = getattr(monitoring, "register_event_duration_secs_listener", None)
+    if register is None:
+        return False  # jax absent, or its import has not got that far yet
 
-        register = getattr(_jm, "register_event_duration_secs_listener", None)
-        if register is None:
-            return False
-
-        def _listener(event: str, duration_s: float, **kwargs) -> None:
+    def _listener(event: str, duration_s: float, **kwargs) -> None:
+        try:
+            end = time.time()
+            task_id, trace_id = _thread_tasks.get(
+                threading.get_ident(), (None, None)
+            )
+            extra: Dict[str, str] = {}
             try:
-                end = time.time()
-                task_id, trace_id = _thread_tasks.get(
-                    threading.get_ident(), (None, None)
-                )
-                extra: Dict[str, str] = {}
-                try:
-                    from ray_tpu.util import tracing as _tracing
+                from ray_tpu.util import tracing as _tracing
 
-                    ctx = _tracing.get_current_context()
-                    if ctx is not None:
-                        # a child span of the executing task's span: the
-                        # compile appears as its own node in the trace tree
-                        extra = {
-                            "trace_id": ctx.trace_id,
-                            "span_id": _tracing._new_id(8),
-                            "parent_id": ctx.span_id,
-                        }
-                    elif trace_id:
-                        # registry knows the trace but no live context on
-                        # this thread (e.g. a pool thread between scopes)
-                        extra = {
-                            "trace_id": trace_id,
-                            "span_id": _tracing._new_id(8),
-                        }
-                except Exception:
-                    pass
-                span = {
-                    "event": f"jax:{event.strip('/').replace('/', '.')}",
-                    "start": end - duration_s,
-                    "end": end,
-                    "duration_ms": duration_s * 1e3,
-                    "pid": os.getpid(),
-                    "task_id": task_id,
-                    "extra": extra,
-                }
-                from ray_tpu._private import telemetry as _telemetry
-
-                _telemetry.record_span(span)
-                # training step plane: attribute compile time to the step
-                # that triggered it (and arm the recompile detector)
-                from ray_tpu._private import stepplane as _stepplane
-
-                _stepplane.note_compile(event, duration_s)
+                ctx = _tracing.get_current_context()
+                if ctx is not None:
+                    # a child span of the executing task's span: the
+                    # compile appears as its own node in the trace tree
+                    extra = {
+                        "trace_id": ctx.trace_id,
+                        "span_id": _tracing._new_id(8),
+                        "parent_id": ctx.span_id,
+                    }
+                elif trace_id:
+                    # registry knows the trace but no live context on
+                    # this thread (e.g. a pool thread between scopes)
+                    extra = {
+                        "trace_id": trace_id,
+                        "span_id": _tracing._new_id(8),
+                    }
             except Exception:
                 pass
+            span = {
+                "event": f"jax:{event.strip('/').replace('/', '.')}",
+                "start": end - duration_s,
+                "end": end,
+                "duration_ms": duration_s * 1e3,
+                "pid": os.getpid(),
+                "task_id": task_id,
+                "extra": extra,
+            }
+            from ray_tpu._private import telemetry as _telemetry
 
-        register(_listener)
-        _jax_hooked = True
-        return True
-    except Exception:
-        return False
+            _telemetry.record_span(span)
+            # training step plane: attribute compile time to the step
+            # that triggered it (and arm the recompile detector)
+            from ray_tpu._private import stepplane as _stepplane
+
+            _stepplane.note_compile(event, duration_s)
+            if "backend_compile" in event:
+                # only a process with a backend up compiles for it: the
+                # memory plane's device sweep may start (memplane)
+                from ray_tpu._private import memplane as _memplane
+
+                _memplane.note_jax_backend_up()
+        except Exception:
+            pass
+
+    register(_listener)
+    _jax_hooked = True
+    return True
 
 
 def format_sample_summary(rows, top: int = 20) -> str:

@@ -261,16 +261,21 @@ class WorkerRuntime:
                         # scope the process's accelerator visibility to the
                         # task (env applies before the exec dequeues — pipe
                         # order guarantees it precedes the task thread's
-                        # first device use). ALWAYS drop the previous
-                        # task's keys first: a TPU task followed by a
+                        # first device use). ALWAYS put the previous
+                        # task's keys back first: a TPU task followed by a
                         # GPU-only task must not keep TPU_VISIBLE_CHIPS
+                        from ray_tpu._private.accelerators import tpu as tpu_accel
                         from ray_tpu._private.resources import visible_env_for
 
-                        if prev:
-                            for k in visible_env_for(prev):
+                        for k, old in getattr(self, "_accel_env_saved", {}).items():
+                            if old is None:
                                 os.environ.pop(k, None)
-                        if accel:
-                            os.environ.update(visible_env_for(accel))
+                            else:
+                                os.environ[k] = old
+                        env = visible_env_for(accel) if accel else {}
+                        self._accel_env_saved = {k: os.environ.get(k) for k in env}
+                        os.environ.update(env)
+                        tpu_accel.set_worker_platform(bool(accel and accel.get("TPU")))
                         self._accel_alloc = accel
                     self.exec_queue.put(msg[1])
                 elif kind == "pubsub_msg":
@@ -1315,6 +1320,12 @@ def worker_main(conn, worker_id_bin: bytes, shm_dir: str, fallback_dir: str, con
         _signal.signal(_signal.SIGTERM, _on_sigterm)
     except (ValueError, OSError):
         pass  # non-main thread / unsupported platform: keep default
+
+    # no TPU resource yet: this process stays off the chip (one process per
+    # chip — see accelerators/tpu.py) until an exec hands it an assignment
+    from ray_tpu._private.accelerators import tpu as _tpu_accel
+
+    _tpu_accel.set_worker_platform(False)
 
     reader = threading.Thread(target=rt.reader_loop, name="reader", daemon=True)
     reader.start()

@@ -21,6 +21,7 @@ Config presets cover the benchmark models named in BASELINE.json: GPT-J-6B
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
@@ -166,7 +167,9 @@ def param_logical_axes(cfg: TransformerConfig) -> Dict[str, Tuple]:
     return axes
 
 
-def _block(cfg: TransformerConfig, x, layer, cos, sin, positions, context_axis, mesh):
+def _block(
+    cfg: TransformerConfig, x, layer, cos, sin, positions, context_axis, mesh, attn_spec=None
+):
     """One transformer block. x: (B, S, D)."""
     h = rms_norm(x, layer["attn_norm"])
     q = jnp.einsum("bsd,dhk->bshk", h, layer["wq"])
@@ -177,19 +180,26 @@ def _block(cfg: TransformerConfig, x, layer, cos, sin, positions, context_axis, 
     if context_axis is not None:
         # partial-manual shard_map: only the context axis goes manual (ring
         # ppermute over ICI); batch/model axes stay under GSPMD
-        import functools
-
         from jax.sharding import PartitionSpec as P
 
-        from ray_tpu.parallel._shard_map import shard_map as _shard_map
-
         spec = P(None, context_axis, None, None)
-        att = _shard_map(
+        att = jax.shard_map(
             functools.partial(ring_attention, axis_name=context_axis, causal=True),
             mesh=mesh,
             in_specs=(spec, spec, spec),
             out_specs=spec,
             axis_names={context_axis},
+        )(q, k, v)
+    elif attn_spec is not None:
+        # GSPMD cannot partition a Mosaic kernel ("wrap the call in a
+        # shard_map"): attention runs per shard — batch and heads are split,
+        # sequence and head_dim whole, so no term crosses a shard
+        att = jax.shard_map(
+            functools.partial(attention, causal=True),
+            mesh=mesh,
+            in_specs=(attn_spec, attn_spec, attn_spec),
+            out_specs=attn_spec,
+            check_vma=False,
         )(q, k, v)
     else:
         att = attention(q, k, v, causal=True)
@@ -222,10 +232,13 @@ def forward(
     positions: Optional[jax.Array] = None,
     context_axis: Optional[str] = None,
     mesh=None,
+    attn_spec=None,
 ) -> jax.Array:
     """tokens (B, S) -> logits (B, S, vocab). With ``context_axis`` (+``mesh``)
     attention runs as a ring over that axis; ``positions`` must then be the
-    absolute token positions of this shard's slice of the sequence."""
+    absolute token positions of this shard's slice of the sequence. With
+    ``attn_spec`` (+``mesh``), the (B, S, H, Hd) PartitionSpec of a
+    multi-device mesh, plain attention runs per shard under ``shard_map``."""
     x = params["embed"][tokens]
     cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq_len, cfg.rope_theta)
 
@@ -234,7 +247,7 @@ def forward(
     }
 
     def body(x, layer):
-        out = _block(cfg, x, layer, cos, sin, positions, context_axis, mesh)
+        out = _block(cfg, x, layer, cos, sin, positions, context_axis, mesh, attn_spec)
         return out, None
 
     if cfg.remat:
@@ -262,11 +275,18 @@ def loss_fn(
     positions=None,
     context_axis=None,
     mesh=None,
+    attn_spec=None,
     loss_mask: Optional[jax.Array] = None,
 ) -> jax.Array:
     """Mean next-token cross-entropy in fp32."""
     logits = forward(
-        params, tokens, cfg, positions=positions, context_axis=context_axis, mesh=mesh
+        params,
+        tokens,
+        cfg,
+        positions=positions,
+        context_axis=context_axis,
+        mesh=mesh,
+        attn_spec=attn_spec,
     ).astype(jnp.float32)
     logz = jax.nn.logsumexp(logits, axis=-1)
     gold = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]

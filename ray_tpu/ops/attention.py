@@ -44,7 +44,10 @@ def attention(
     use_flash: bool = True,
 ) -> jax.Array:
     """Multi-head attention. On TPU with supported shapes, dispatches to the
-    Pallas splash/flash kernel; otherwise a fused-by-XLA einsum softmax."""
+    Pallas flash kernel; otherwise a fused-by-XLA einsum softmax. The choice
+    is made from platform and shape alone (``_can_use_flash``): a kernel
+    that was chosen and fails raises, it never gives way to the other path
+    — a run must be able to tell which one it timed."""
     n_rep = q.shape[2] // k.shape[2]
     k = _repeat_kv(k, n_rep)
     v = _repeat_kv(v, n_rep)
@@ -57,9 +60,7 @@ def attention(
         and kv_positions is None
         and _can_use_flash(q, k)
     ):
-        out = _flash(q, k, v, causal=causal)
-        if out is not None:
-            return out
+        return _flash(q, k, v, causal=causal)
     return _einsum_attention(
         q, k, v, causal=causal, mask=mask, q_positions=q_positions, kv_positions=kv_positions
     )
@@ -122,25 +123,18 @@ def _tuned_block_sizes(head_dim: int, q_seq: int, kv_seq: int):
 
 
 def _flash(q, k, v, *, causal):
-    try:
-        from jax.experimental.pallas.ops.tpu.flash_attention import (
-            flash_attention,
-        )
-    except ImportError:
-        return None
+    from jax.experimental.pallas.ops.tpu.flash_attention import flash_attention
+
     # pallas kernel wants BHSD
     qt, kt, vt = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))
-    sm_scale = 1.0 / (q.shape[-1] ** 0.5)
-    block_sizes = _tuned_block_sizes(q.shape[-1], q.shape[1], k.shape[1])
-    try:
-        if block_sizes is not None:
-            out = flash_attention(
-                qt, kt, vt, causal=causal, sm_scale=sm_scale, block_sizes=block_sizes
-            )
-        else:
-            out = flash_attention(qt, kt, vt, causal=causal, sm_scale=sm_scale)
-    except Exception:
-        return None
+    out = flash_attention(
+        qt,
+        kt,
+        vt,
+        causal=causal,
+        sm_scale=1.0 / (q.shape[-1] ** 0.5),
+        block_sizes=_tuned_block_sizes(q.shape[-1], q.shape[1], k.shape[1]),
+    )
     return jnp.swapaxes(out, 1, 2)
 
 
@@ -223,8 +217,7 @@ def ring_attention(
     l0 = jnp.zeros((b, h, s), jnp.float32)
     # constants start axis-unvarying under shard_map's vma typing; the carry
     # becomes varying after step 1, so mark them varying up front
-    if hasattr(jax.lax, "pcast"):
-        o0, m0, l0 = (jax.lax.pcast(x, (axis_name,), to="varying") for x in (o0, m0, l0))
+    o0, m0, l0 = (jax.lax.pcast(x, (axis_name,), to="varying") for x in (o0, m0, l0))
     (o, m, l, _, _, _), _ = jax.lax.scan(
         step, (o0, m0, l0, k, v, my_idx), None, length=axis_size
     )
@@ -237,12 +230,10 @@ def make_context_parallel_attention(mesh, axis_name: str = "context", causal: bo
     """Wrap ``ring_attention`` in shard_map for direct use on global arrays."""
     from jax.sharding import PartitionSpec as P
 
-    from ray_tpu.parallel._shard_map import shard_map as _shard_map
-
     spec = P(None, axis_name, None, None)
 
     @functools.partial(
-        _shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,

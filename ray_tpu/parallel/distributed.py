@@ -60,29 +60,17 @@ def initialize(
     global _initialized
     import jax
 
-    platforms = os.environ.get("JAX_PLATFORMS", "")
-    if "cpu" in platforms.split(","):
-        try:
-            jax.config.update("jax_platforms", platforms)
-        except Exception:
-            pass
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:
-            pass
+    if os.environ.get("JAX_PLATFORMS", "").split(",")[0] == "cpu":
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
         # a reused pool worker may already have run a jax computation
         # (backend init is process-wide and first-use);
         # jax.distributed.initialize refuses once backends exist, so on
-        # the virtual-cpu path reset them — the cpu backend rebuilds
+        # the virtual-cpu path drop them — the cpu backend rebuilds
         # cheaply and no device buffers can span the reset (this process
         # has not joined a mesh yet)
-        try:
-            from jax._src import xla_bridge
+        import jax.extend.backend
 
-            if xla_bridge.backends_are_initialized():
-                xla_bridge._clear_backends()
-        except Exception:
-            pass
+        jax.extend.backend.clear_backends()
 
     kwargs = {}
     if local_device_ids is not None:

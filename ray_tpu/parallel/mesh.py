@@ -22,7 +22,6 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
-import numpy as np
 from jax.experimental import mesh_utils
 from jax.sharding import Mesh
 
@@ -111,12 +110,12 @@ def create_mesh(
     if math.prod(shape) != len(devices):
         # all axes trivial-dropped but devices remain
         names, shape = [AXIS_DATA], [len(devices)]
-    try:
-        dev_array = mesh_utils.create_device_mesh(
-            shape, devices=list(devices), allow_split_physical_axes=True
-        )
-    except (ValueError, AssertionError, NotImplementedError):
-        dev_array = np.array(list(devices)).reshape(shape)
+    # follows the physical ICI topology on a TPU slice (a plain reshape on
+    # other platforms); a shape the slice cannot take is an error, never a
+    # silent reshape of the flat device list
+    dev_array = mesh_utils.create_device_mesh(
+        shape, devices=list(devices), allow_split_physical_axes=True
+    )
     return Mesh(dev_array, tuple(names))
 
 

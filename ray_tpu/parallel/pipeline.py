@@ -18,8 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ray_tpu.parallel._shard_map import shard_map as _shard_map
-
 
 def pipeline_apply(
     stage_fn: Callable[[Any, jax.Array], jax.Array],
@@ -44,9 +42,8 @@ def pipeline_apply(
     out_shape = jax.eval_shape(lambda x: stage_fn(stage_params, x), microbatches[0])
     outputs0 = jnp.zeros((M,) + tuple(out_shape.shape), out_shape.dtype)
     state0 = jnp.zeros(out_shape.shape, out_shape.dtype)
-    if hasattr(jax.lax, "pcast"):
-        outputs0 = jax.lax.pcast(outputs0, (axis_name,), to="varying")
-        state0 = jax.lax.pcast(state0, (axis_name,), to="varying")
+    outputs0 = jax.lax.pcast(outputs0, (axis_name,), to="varying")
+    state0 = jax.lax.pcast(state0, (axis_name,), to="varying")
 
     def tick(carry, t):
         outputs, incoming = carry
@@ -97,7 +94,7 @@ def make_pipeline_fn(
     mspec = P()  # microbatches replicated; stage 0 consumes
 
     @functools.partial(
-        _shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(pspec, mspec),
         out_specs=P(axis_name),

@@ -107,8 +107,15 @@ def build_lm_train_step(
 
     def init(key):
         params = constrain(tfm.init_params(key, cfg))
-        # optimizer moments inherit the param shardings via XLA propagation
-        opt_state = optimizer.init(params)
+        # the moments are sharded like their parameters; left to XLA's
+        # propagation they come out of init replicated (zeros have no
+        # producer to inherit from) and the first step compiles twice
+        opt_state = optax.tree_utils.tree_map_params(
+            optimizer,
+            jax.lax.with_sharding_constraint,
+            optimizer.init(params),
+            p_shard,
+        )
         return {"params": params, "opt": opt_state, "step": jnp.zeros((), jnp.int32)}
 
     if ctx_axis is not None:
@@ -119,8 +126,18 @@ def build_lm_train_step(
                 params, tokens, targets, cfg, context_axis=ctx_axis, mesh=mesh
             )
     else:
+        # per-shard attention on a mesh of several devices: the flash kernel
+        # cannot be partitioned by GSPMD (batch and heads split, the rest whole)
+        attn_spec = (
+            logical_to_mesh_spec(["batch", None, "heads", "head_dim"], rules, mesh)
+            if mesh.size > 1
+            else None
+        )
+
         def loss(params, tokens, targets):
-            return tfm.loss_fn(params, tokens, targets, cfg)
+            return tfm.loss_fn(
+                params, tokens, targets, cfg, mesh=mesh, attn_spec=attn_spec
+            )
 
     def step(state, tokens, targets):
         lossval, grads = jax.value_and_grad(loss)(state["params"], tokens, targets)

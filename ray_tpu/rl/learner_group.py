@@ -38,12 +38,12 @@ class SPMDLearnerWorker:
         from ray_tpu.parallel import distributed as dist
         from ray_tpu.train.jax_utils import ensure_platform
 
-        ensure_platform()
         self.rank, self.world = rank, world
         if world > 1:
             rt = get_runtime()
             coord = dist.rendezvous_via_kv(rt, rdzv_key, rank, world)
             dist.initialize(coord, num_processes=world, process_id=rank)
+        ensure_platform()  # after the join: it starts the backend
         self._build(builder_config)
 
     def _build(self, bc: dict) -> None:

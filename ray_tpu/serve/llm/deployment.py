@@ -76,12 +76,16 @@ class LLMServer:
         import jax
 
         from ray_tpu.models.transformer import init_params
+        from ray_tpu.train.jax_utils import ensure_platform
 
+        ensure_platform()  # a replica that asked for a chip runs on it
         cfg = _resolve_model_cfg(model_cfg)
         if params_loader is not None:
             params = params_loader(cfg)
         else:
-            params = init_params(jax.random.PRNGKey(int(weight_seed)), cfg)
+            # jitted: the eager call holds a float32 copy of every stacked
+            # tensor before the cast (7.5 GB for one GPT-J-6B MLP tensor)
+            params = jax.jit(lambda: init_params(jax.random.PRNGKey(int(weight_seed)), cfg))()
         self._engine = InferenceEngine(
             params, cfg, _resolve_engine_cfg(engine_cfg), deployment=deployment
         )

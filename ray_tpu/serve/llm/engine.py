@@ -211,6 +211,7 @@ class InferenceEngine:
             model_cfg, block_size=ecfg.block_size
         )
         self._pool = G.init_paged_pool(model_cfg, ecfg.num_blocks, ecfg.block_size)
+        self._device = next(iter(self._pool["k"].devices()))
         self._alloc = BlockAllocator(ecfg.num_blocks, ecfg.block_size)
         self._slots: List[Optional[_Running]] = [None] * ecfg.max_batch
         self._waiting: "list[tuple[_Request, TokenStream]]" = []
@@ -332,6 +333,18 @@ class InferenceEngine:
     # -- stats ----------------------------------------------------------
 
     def kv_stats(self) -> Dict[str, Any]:
+        """KV/batching occupancy plus where the pool lives, as JAX reports
+        it — a caller can tell a replica on the chip from one that is not."""
+        dev = self._device
+        return {
+            **self._occupancy(),
+            "platform": dev.platform,
+            "device_kind": dev.device_kind,
+            "device_count": len(dev.client.devices()),
+            "device_peak_bytes": (dev.memory_stats() or {}).get("peak_bytes_in_use"),
+        }
+
+    def _occupancy(self) -> Dict[str, Any]:
         """Host-side KV/batching occupancy snapshot (also the memplane
         gauge source via the registered provider)."""
         usable = self._alloc.num_usable
@@ -365,13 +378,13 @@ class InferenceEngine:
         try:
             from ray_tpu._private import memplane
 
-            memplane.register_kv_provider(self.deployment, self.kv_stats)
+            memplane.register_kv_provider(self.deployment, self._occupancy)
         except Exception:
             pass
 
     def _update_gauges(self) -> None:
         try:
-            stats = self.kv_stats()
+            stats = self._occupancy()
             m = _engine_metrics()
             tags = {"deployment": self.deployment}
             m["running"].set(float(stats["running"]), tags=tags)

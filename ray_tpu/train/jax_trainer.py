@@ -53,14 +53,12 @@ def _setup_jax_distributed(rendezvous_key: str) -> bool:
     from ray_tpu._private.worker import get_runtime
     from ray_tpu.parallel import distributed as dist
     from ray_tpu.train._session import get_context
-    from ray_tpu.train.jax_utils import ensure_platform
     from ray_tpu.train.torch_trainer import _node_ip
 
     ctx = get_context()
     rank, world = ctx.get_world_rank(), ctx.get_world_size()
     if world <= 1:
         return False
-    ensure_platform()
     rt = get_runtime()
     coord = dist.rendezvous_via_kv(
         rt, rendezvous_key, rank, world, node_ip=_node_ip()
@@ -99,21 +97,25 @@ class JaxTrainer:
         datasets: Optional[Dict[str, Any]] = None,
         resume_from_checkpoint: Optional[Checkpoint] = None,
     ):
-        self.train_loop = train_loop_per_worker
         self.train_loop_config = train_loop_config
         self.scaling_config = scaling_config or ScalingConfig()
         self.run_config = run_config or RunConfig()
         self.datasets = datasets or {}
         self.resume_from_checkpoint = resume_from_checkpoint
-        if self.scaling_config.use_jax_distributed:
-            self.train_loop = self._wrap_distributed(train_loop_per_worker)
+        self.train_loop = self._wrap(
+            train_loop_per_worker, self.scaling_config.use_jax_distributed
+        )
 
     @staticmethod
-    def _wrap_distributed(user_fn: Callable) -> Callable:
+    def _wrap(user_fn: Callable, distributed: bool) -> Callable:
+        """The loop as a worker runs it: (join jax.distributed,) check that
+        JAX gives the worker the device its resources name, run, leave."""
         base_key = f"jaxdist_{uuid.uuid4().hex[:12]}"
 
         def wrapped(config=None):
             import inspect
+
+            from ray_tpu.train.jax_utils import ensure_platform
 
             # fit() injects a per-attempt suffix so a retry never rendezvous
             # against the dead coordinator a failed attempt left in the KV
@@ -121,8 +123,11 @@ class JaxTrainer:
                 key = f"{base_key}_{config.pop('__jaxdist_attempt__', 0)}"
             else:
                 key = base_key
-            joined = _setup_jax_distributed(key)
+            # join BEFORE the check: it starts the backend, and
+            # jax.distributed.initialize refuses once one exists
+            joined = distributed and _setup_jax_distributed(key)
             try:
+                ensure_platform()
                 if config is not None and len(inspect.signature(user_fn).parameters):
                     return user_fn(config)
                 return user_fn()
@@ -217,7 +222,7 @@ class JaxTrainer:
                         latest = resume_fn()
                     run_config = config
                     if self.scaling_config.use_jax_distributed:
-                        # per-attempt rendezvous key suffix (see _wrap_distributed)
+                        # per-attempt rendezvous key suffix (see _wrap)
                         run_config = dict(config or {})
                         run_config["__jaxdist_attempt__"] = attempt
                     executor.run(

@@ -1,0 +1,186 @@
+"""Compile the main paths' device programs with the TPU's own compiler, for
+a v5e chip that is described and not attached (on-chip-measurement guide,
+section 2). Nothing runs: these catch what the chip's compiler refuses —
+a kernel's tiling, its fast-memory budget, a program that cannot be
+partitioned — before a chip call is spent on it.
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process may load libtpu, and every xdist worker imports this file.
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceSharding
+
+GPTJ = dict(
+    vocab_size=50400, d_model=4096, n_heads=16, d_ff=16384, max_seq_len=2048,
+    parallel_block=True, use_swiglu=False,
+)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        t = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever keeps libtpu from describing it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # an entry written for a described chip cannot be read back without one
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _on(sharding, tree):
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding), tree
+    )
+
+
+@pytest.mark.parametrize(
+    "head_dim,heads,seq", [(256, 16, 2048), (128, 32, 2048), (64, 16, 1024)]
+)
+def test_flash_attention_forward_and_backward(one_chip, head_dim, heads, seq):
+    """The kernel ``ops.attention`` picks on a TPU, with its tuned blocks,
+    at GPT-J's head_dim 256 (seq 2048), Llama's 128 and the small 64."""
+    from ray_tpu.ops.attention import _flash
+
+    q = jax.ShapeDtypeStruct((1, seq, heads, head_dim), jnp.bfloat16, sharding=one_chip)
+    flash = functools.partial(_flash, causal=True)
+    fwd = jax.jit(flash).lower(q, q, q).compile()
+    assert "tpu_custom_call" in fwd.as_text()
+
+    def loss(q, k, v):
+        return flash(q, k, v).astype(jnp.float32).sum()
+
+    bwd = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, q, q).compile()
+    assert bwd.as_text().count("tpu_custom_call") >= 3  # fwd + dq + dkv
+
+
+def test_flash_module_is_the_same_wherever_it_is_called_from(one_chip):
+    """The kernel's module travels as an opaque string with its Python call
+    stack inside, which the compile cache's key cannot strip: by default the
+    lowered program differs with the caller's line. ``chip_smoke.py`` and
+    ``bench.py`` export ``JAX_TRACEBACK_IN_LOCATIONS_LIMIT=0`` so that a moved
+    checkout or an edited script still finds the step it compiled before."""
+    import re
+
+    from ray_tpu.ops.attention import _flash
+
+    q = jax.ShapeDtypeStruct((1, 2048, 16, 256), jnp.bfloat16, sharding=one_chip)
+
+    def lowered():
+        jax.clear_caches()  # or the kernel's own jit answers from its first trace
+        return jax.jit(functools.partial(_flash, causal=True)).lower(q, q, q).as_text()
+
+    here = lowered()
+    there = lowered()  # the same program, called from the next line
+    assert here != there
+    for launcher in ("chip_smoke.py", "bench.py"):
+        with open(os.path.join(os.path.dirname(__file__), "..", launcher)) as f:
+            (limit,) = re.findall(r'"JAX_TRACEBACK_IN_LOCATIONS_LIMIT", "(\d+)"', f.read())
+        jax.config.update("jax_traceback_in_locations_limit", int(limit))
+        try:
+            here = lowered()
+            there = lowered()
+        finally:
+            jax.config.update("jax_traceback_in_locations_limit", 10)  # JAX's default
+        assert here == there, launcher
+
+
+def test_paged_decode_and_prefill_at_gptj_widths(one_chip):
+    """serve.llm's two device programs (``make_paged_fns``) at GPT-J-6B's
+    published widths; 2 of the 28 layers (the scan body is the same)."""
+    from ray_tpu.models import generation as G
+    from ray_tpu.models.transformer import TransformerConfig, init_params
+
+    cfg = TransformerConfig(n_layers=2, **GPTJ)
+    block, blocks, batch, per_seq = 16, 384, 8, 64
+    prefill, _, decode_greedy = G.make_paged_fns(cfg, block_size=block)
+    params = _on(one_chip, jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    pool = _on(one_chip, jax.eval_shape(lambda: G.init_paged_pool(cfg, blocks, block)))
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    decode_greedy.lower(
+        params, arg((batch,), jnp.int32), arg((batch,), jnp.int32),
+        arg((batch, per_seq), jnp.int32), pool, arg((batch,), jnp.bool_),
+    ).compile()
+    prefill.lower(
+        params, arg((1, 512), jnp.int32), arg((1, per_seq), jnp.int32), pool,
+        arg((), jnp.int32),
+    ).compile()
+
+
+def _steered_to_tpu(monkeypatch):
+    """``attention`` asks ``jax.default_backend()``, which is the CPU here:
+    the test steers it to the branch it takes on the chip."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _bench_cfg(n_layers):
+    from ray_tpu.models.transformer import TransformerConfig
+
+    return TransformerConfig(
+        n_layers=n_layers, remat_policy="dots", **{**GPTJ, "vocab_size": 50432}
+    )
+
+
+def test_train_step_at_bench_geometry(topo, monkeypatch):
+    """``build_lm_train_step`` at ``bench.py``'s geometry (GPT-J widths,
+    4 layers, batch 8 x 2048, dots remat) on one described chip."""
+    from ray_tpu.ops.attention import _can_use_flash
+    from ray_tpu.parallel.spmd import build_lm_train_step
+
+    _steered_to_tpu(monkeypatch)
+    mesh = Mesh([topo.devices[0]], ("data",))
+    bundle = build_lm_train_step(_bench_cfg(4), mesh, learning_rate=1e-4)
+    state = jax.eval_shape(bundle.init_fn, jax.random.PRNGKey(0))
+    rep = NamedSharding(mesh, PartitionSpec())
+    tok = jax.ShapeDtypeStruct((8, 2048), jnp.int32, sharding=bundle.batch_shard)
+    compiled = bundle.step_fn.lower(_on(rep, state), tok, tok).compile()
+    qk = jax.ShapeDtypeStruct((8, 2048, 16, 256), jnp.bfloat16)
+    assert _can_use_flash(qk, qk)
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+def test_train_step_on_a_2x2_mesh(topo, monkeypatch):
+    """The same step over ``MeshConfig(fsdp=2, tensor=2)`` on the described
+    topology's four chips (what ``chip_smoke.py --chips 4`` runs). GSPMD
+    cannot partition the flash kernel: this is the compile that refused the
+    step until attention ran per shard. The state leaves ``init`` sharded the
+    way the step returns it (aliased whole), with collectives in between."""
+    from ray_tpu.parallel.mesh import MeshConfig, create_mesh
+    from ray_tpu.parallel.spmd import build_lm_train_step
+
+    _steered_to_tpu(monkeypatch)
+    mesh = create_mesh(MeshConfig(fsdp=2, tensor=2), devices=topo.devices)
+    bundle = build_lm_train_step(_bench_cfg(2), mesh, learning_rate=1e-4)
+    init = bundle.init_seed_fn.lower(0).compile()
+    state = jax.tree.map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+        jax.eval_shape(bundle.init_fn, jax.random.PRNGKey(0)),
+        init.output_shardings,
+    )
+    tok = jax.ShapeDtypeStruct((8, 2048), jnp.int32, sharding=bundle.batch_shard)
+    step = bundle.step_fn.lower(state, tok, tok).compile()
+    text = step.as_text()
+    assert text.count("tpu_custom_call") >= 3
+    assert "all-gather" in text and "all-reduce" in text
+    mem = step.memory_analysis()
+    assert mem.alias_size_in_bytes > 0.99 * mem.argument_size_in_bytes  # all but the batch
