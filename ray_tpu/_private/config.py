@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import json
 import os
+import tempfile
 from dataclasses import dataclass, field, fields
 from typing import Any
 
@@ -313,7 +314,11 @@ class Config:
     # alert) and/or "webhook:<url>" (POST from a daemon thread)
     alert_sinks: str = ""
     # --- misc ---
-    session_dir_root: str = "/tmp/ray_tpu_sessions"
+    # under the process's temporary directory (TMPDIR), so two checkouts run
+    # with temporary directories of their own keep their sessions apart
+    session_dir_root: str = field(
+        default_factory=lambda: os.path.join(tempfile.gettempdir(), "ray_tpu_sessions")
+    )
     log_to_driver: bool = True
 
     @classmethod

@@ -1,0 +1,122 @@
+"""Loop records on disk: what the two hot loops measured of themselves, kept
+where it outlives the cluster.
+
+The serving engine keeps one record per iteration of its loop and one per
+finished request; the trainer's step plane closes one record per step. They
+ride the telemetry batches (``TelemetryBuffer.record_loop`` for the engine,
+the step records' own two channels for the trainer), and the head appends
+them as they land to
+
+    <session_dir>/loops/llm-<deployment>-<pid>.jsonl
+    <session_dir>/loops/train-<run>-rank<r>.jsonl
+
+next to ``<session_dir>/logs/``: one JSON object a line, each naming its
+``kind`` and its fields, so a reader needs nothing from this package. Stamps
+are ``time.time_ns()``, the clock of a profiler trace's host and device
+events. ``telemetry_enabled`` off means no batches, so no files.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import re
+from typing import Dict, Iterable, Optional
+
+# one engine loop iteration (serve/llm/engine.py ``_loop``); 0 = the phase did
+# not happen in this iteration
+LLM_STEP_FIELDS = (
+    "step",  # decode steps dispatched so far, this one included
+    "t_loop",  # top of the iteration, after any idle wait
+    "t_admit_end",  # after the last prefill of the iteration
+    "t_result",  # the in-flight step's result is on the host
+    "t_retire_end",
+    "t_dispatch",
+    "t_dispatch_end",  # around the call of the decode program
+    "t_emit_end",  # tokens handed to the streams, series folded: end of the iteration
+    "live",  # sequences in the dispatched step
+    "prefills",  # prefills done in the iteration
+    "fused",  # 1 = the greedy (on-device argmax) program ran
+)
+# one finished, failed or shed request
+LLM_REQUEST_FIELDS = (
+    "request",
+    "t_submit",
+    "t_admit",  # popped from the waiting queue (0: shed, or failed before)
+    "t_first",  # first token on the host (0: none)
+    "t_finish",
+    "prompt_len",
+    "bucket",
+    "tokens",
+    "steps",
+    "reason",  # length | stop | error | shed_blocks | shed_waiting | shutdown
+    "trace_id",
+)
+_KINDS = {"s": ("llm_step", LLM_STEP_FIELDS), "r": ("llm_request", LLM_REQUEST_FIELDS)}
+
+MAX_FILE_BYTES = 32 << 20  # a file past this moves to <name>.1 (one kept)
+_MAX_OPEN = 64
+
+# where this process's head last wrote loop records; stays after shutdown so
+# that whoever ran the cluster can find what it left
+last_dir: Optional[str] = None
+
+
+def encode(rec) -> Optional[str]:
+    """An engine record tuple (tag, field values...) as a JSON line."""
+    try:
+        kind, fields = _KINDS[rec[0]]
+        return json.dumps({"kind": kind, **dict(zip(fields, rec[1:]))})
+    except (KeyError, IndexError, TypeError, ValueError):
+        return None  # telemetry batches are untrusted
+
+
+class LoopLog:
+    """The head's writer: bounded append files under one directory."""
+
+    def __init__(self, session_dir: str):
+        self._dir = os.path.join(session_dir, "loops")
+        self._files: Dict[str, object] = {}
+
+    def append(self, stem: str, lines: Iterable[str]) -> None:
+        """Append lines to ``<stem>.jsonl`` and flush: a batch lands whole."""
+        global last_dir
+        text = "".join(line + "\n" for line in lines if line)
+        if not text:
+            return
+        name = re.sub(r"[^A-Za-z0-9_.-]", "_", str(stem))[:160] + ".jsonl"
+        fh = self._files.get(name)
+        try:
+            if fh is None:
+                os.makedirs(self._dir, exist_ok=True)
+                last_dir = self._dir
+                if len(self._files) >= _MAX_OPEN:
+                    self._files.pop(next(iter(self._files))).close()
+                fh = self._files[name] = open(os.path.join(self._dir, name), "a")
+            if fh.tell() + len(text) > MAX_FILE_BYTES:
+                fh.close()
+                path = os.path.join(self._dir, name)
+                os.replace(path, path + ".1")
+                fh = self._files[name] = open(path, "a")
+            fh.write(text)
+            fh.flush()
+        except OSError:
+            pass  # a full disk must not take the head's loop down
+
+    def ingest(self, loops: Dict[str, list]) -> None:
+        """One telemetry batch's engine records: stem -> [record tuple]."""
+        for stem, recs in loops.items():
+            self.append(stem, (encode(r) for r in recs))
+
+    def append_train_step(self, rec: dict) -> None:
+        """One decoded step record (``stepplane.decode_record``)."""
+        self.append(f"train-{rec.get('run')}-rank{rec.get('rank')}",
+                    (json.dumps({"kind": "train_step", **rec}),))
+
+    def close(self) -> None:
+        for fh in self._files.values():
+            try:
+                fh.close()
+            except OSError:
+                pass
+        self._files.clear()

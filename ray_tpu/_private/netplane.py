@@ -324,6 +324,17 @@ def record_wire_span(
         pass
 
 
+def requester_ctx():
+    """The calling thread's (trace_id, span_id) for a transfer it is about
+    to ask for, or None (untraced, or the plane off)."""
+    if not enabled():
+        return None
+    from ray_tpu.util import tracing
+
+    ctx = tracing.get_current_context()
+    return (ctx.trace_id, ctx.span_id) if ctx is not None else None
+
+
 def finish_blocked_read(
     path: str,
     nbytes: int,

@@ -11,7 +11,25 @@ from __future__ import annotations
 
 import contextlib
 import os
+import sys
 import time
+
+_NO_ANNOTATION = contextlib.nullcontext()
+
+
+def annotate(name: str, **kwargs):
+    """A host span for whatever profiler trace is being taken of this
+    process (``jax.profiler.TraceAnnotation``: a flag test when none is).
+    It lands on the calling thread's line of the ``/host:CPU`` plane, on
+    the clock of the device's events, so a kept ``.xplane.pb`` shows which
+    phase of a loop each device gap belongs to. Probes for jax and never
+    imports it: a process that has not loaded jax has no profiler to
+    annotate for."""
+    jax = sys.modules.get("jax")
+    profiler = getattr(jax, "profiler", None)
+    if profiler is None:
+        return _NO_ANNOTATION
+    return profiler.TraceAnnotation(name, **kwargs)
 
 
 @contextlib.contextmanager

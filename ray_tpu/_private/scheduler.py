@@ -47,6 +47,7 @@ from ray_tpu._private.ids import (
     WorkerID,
 )
 from ray_tpu._private import netplane as _netplane
+from ray_tpu._private import stepplane as _stepplane
 from ray_tpu._private.object_store import StoreFullError
 from ray_tpu._private.task_spec import Arg, SchedulingStrategy, TaskSpec, TaskType
 from ray_tpu._private.resources import quantize
@@ -609,9 +610,7 @@ class Scheduler:
         self._job_latency: Dict[str, _LatencyWindow] = {}
         # ---- training step plane (per-run step records + downtime
         # ledger; see DESIGN_MAP "Training observability") ----
-        from ray_tpu._private.stepplane import StepIndex as _StepIndex
-
-        self._train_index = _StepIndex(config)
+        self._train_index = _stepplane.StepIndex(config)
         # ---- failure-forensics plane ----
         # structured cluster events (WORKER_DIED, NODE_DEAD, TASK_RETRY,
         # TASK_FAILED, LEASE_FAILED, OBJECT_LOST, OOM, STRAGGLER, ...);
@@ -640,6 +639,11 @@ class Scheduler:
         self._last_straggler_scan = time.monotonic()
         # persisted worker-log files: filename -> open handle (bounded)
         self._log_files: Dict[str, Any] = {}
+        # loop records of the engine and the trainer, kept on disk beside
+        # the logs (they outlive the cluster: _private/looplog.py)
+        from ray_tpu._private.looplog import LoopLog as _LoopLog
+
+        self._loop_log = _LoopLog(self._node.session_dir)
         # ---- telemetry plane (merged TelemetryBuffer batches) ----
         # metric aggregation across processes: name -> {kind, description,
         # per_proc: {pid: data}}; the merged view is written to the GCS KV
@@ -761,12 +765,10 @@ class Scheduler:
         # "seen_bytes", "seen_t"}: start stamp + relay hop + requester
         # trace ctx + the stall watchdog's progress watermark
         self._fetch_meta: Dict[Tuple[ObjectID, NodeID], dict] = {}
-        # oid -> (trace_id, span_id) of the most recent traced requester
-        # (rides the ensure_local rpc; bounded)
-        self._xfer_trace_req: Dict[ObjectID, Tuple[str, str]] = {}
-        # oid -> outstanding fetch count (O(1) requester-ctx GC on the
-        # completion path instead of scanning _fetching per transfer)
-        self._xfer_inflight_by_oid: Dict[ObjectID, int] = {}
+        # (oid, dest) -> (trace_id, span_id) of the traced consumer there,
+        # for a fetch that has not started yet (rides the pull / the
+        # ensure_local rpc; bounded)
+        self._xfer_trace_req: Dict[Tuple[ObjectID, NodeID], Tuple[str, str]] = {}
         # per-producing-task-name completed socket-plane bytes: the data
         # streaming executor's per-operator cross-node byte attribution
         # (block tasks are name-tagged `data:<stage>`); bounded
@@ -1230,8 +1232,8 @@ class Scheduler:
             spec: TaskSpec = msg[1]
             self.submit(spec)
         elif kind == "pull":
-            _, req_id, oids = msg
-            self._handle_pull(wid, req_id, oids)
+            _, req_id, oids, *ctx = msg  # the puller's trace ctx may ride along
+            self._handle_pull(wid, req_id, oids, ctx[0] if ctx else None)
         elif kind == "block_begin":
             if w.state == "busy" and w.actor_id is None:
                 w.state = "blocked"
@@ -1370,7 +1372,7 @@ class Scheduler:
         dirs = self._same_host_dirs_for(oid, node_id)
         return ("stored", dirs) if dirs else entry
 
-    def _handle_pull(self, wid: WorkerID, req_id: int, oids: List[ObjectID]):
+    def _handle_pull(self, wid: WorkerID, req_id: int, oids: List[ObjectID], ctx=None):
         w = self.workers[wid]
         reply: Dict[ObjectID, Tuple] = {}
         for oid in oids:
@@ -1390,6 +1392,10 @@ class Scheduler:
                 if entry[0] == "stored":
                     entry = self._stored_entry_for(oid, entry, w.node_id)
                     if len(entry) == 1:  # no zero-copy peer: start a transfer
+                        if ctx:
+                            # in the puller's name: a short transfer settles
+                            # before the consumer's own traced poll names it
+                            self._note_xfer_requester(oid, ctx, w.node_id)
                         self._ensure_local(oid, w.node_id)
                 reply[oid] = entry
             else:
@@ -1484,9 +1490,6 @@ class Scheduler:
             waiting.discard(dest)
         # value: (src, charged) — shm short-circuits don't hold a source slot
         self._fetching[key] = (src, same_host is None)
-        self._xfer_inflight_by_oid[oid] = (
-            self._xfer_inflight_by_oid.get(oid, 0) + 1
-        )
         # transfer plane: hop tagging (a source that is itself still
         # RECEIVING makes this a relay hop) + requester trace ctx + the
         # stall watchdog's start stamp
@@ -1495,7 +1498,8 @@ class Scheduler:
             "t0": time.time(),
             "t0_mono": time.monotonic(),
             "hop": (src_meta["hop"] + 1) if src_meta is not None else 0,
-            "trace": self._xfer_trace_req.get(oid),
+            # the consumer on THIS destination, if its traced rpc has landed
+            "trace": self._xfer_trace_req.pop(key, None),
             "seen_bytes": -1,
             "seen_t": time.monotonic(),
         }
@@ -1549,12 +1553,6 @@ class Scheduler:
         destinations (which can now source from it)."""
         entry = self._fetching.pop((oid, dest), None)
         meta = self._fetch_meta.pop((oid, dest), None)
-        if entry is not None:
-            left = self._xfer_inflight_by_oid.get(oid, 1) - 1
-            if left <= 0:
-                self._xfer_inflight_by_oid.pop(oid, None)
-            else:
-                self._xfer_inflight_by_oid[oid] = left
         if entry is not None and entry[1]:
             self._xfer_load[entry[0]] = max(0, self._xfer_load[entry[0]] - 1)
         if entry is not None:
@@ -1582,8 +1580,6 @@ class Scheduler:
                     )
             self._object_locations[oid].add(dest)
             self._shm_xfer_failed.discard((oid, dest))
-            if oid not in self._xfer_inflight_by_oid:
-                self._xfer_trace_req.pop(oid, None)
         elif entry is not None and not entry[1]:
             # an shm-only read missed (peer spilled it / arena unreadable):
             # remember, so the retry goes through socket admission, and
@@ -1782,26 +1778,30 @@ class Scheduler:
             )
         return gibps
 
-    def _note_xfer_requester(self, oid: ObjectID, ctx, dest=None) -> None:
-        """A traced consumer asked for this object (ensure_local rpc): keep
-        its (trace_id, span_id) so the transfer's wire span can join the
-        request's trace tree as a child of the task's arg_fetch. Fetches
-        usually start from the PULL path before the consumer's traced rpc
-        lands, so the ctx is also backfilled into the already-in-flight
-        fetch toward the requester's node."""
+    def _note_xfer_requester(self, oid: ObjectID, ctx, dest: NodeID) -> None:
+        """A traced consumer on ``dest`` asked for this object (its pull, or
+        the ensure_local rpc): its (trace_id, span_id) makes the transfer's wire span a child
+        of the task's arg_fetch in the request's trace tree. One object
+        fanned out to several nodes has a requester per destination, so the
+        ctx is kept per (object, destination). It lands before the fetch
+        starts (a worker's PULL carries it; kept for the fetch) or while it
+        is in flight (the consumer's traced poll: backfilled)."""
         try:
-            trace_id, span_id = ctx[0], ctx[1]
+            trace = (ctx[0], ctx[1])
         except (TypeError, IndexError):
             return
-        if not trace_id:
+        if not trace[0]:
             return
-        if oid not in self._xfer_trace_req and len(self._xfer_trace_req) >= 2048:
-            self._xfer_trace_req.pop(next(iter(self._xfer_trace_req)))
-        self._xfer_trace_req[oid] = (trace_id, span_id)
-        if dest is not None:
-            meta = self._fetch_meta.get((oid, self._loc_node(dest)))
-            if meta is not None and not meta.get("trace"):
-                meta["trace"] = (trace_id, span_id)
+        key = (oid, self._loc_node(dest))
+        meta = self._fetch_meta.get(key)
+        if meta is not None:
+            if not meta.get("trace"):
+                meta["trace"] = trace
+            return
+        if key[1] not in self._object_locations.get(oid, ()):
+            if len(self._xfer_trace_req) >= 2048:
+                self._xfer_trace_req.pop(next(iter(self._xfer_trace_req)))
+            self._xfer_trace_req[key] = trace
 
     def _note_transfer_done(
         self, oid: ObjectID, src: NodeID, dest: NodeID, ok: bool,
@@ -5344,11 +5344,6 @@ class Scheduler:
         for key in [k for k in self._fetching if k[1] == node_id]:
             src, charged = self._fetching.pop(key)
             self._fetch_meta.pop(key, None)
-            left = self._xfer_inflight_by_oid.get(key[0], 1) - 1
-            if left <= 0:
-                self._xfer_inflight_by_oid.pop(key[0], None)
-            else:
-                self._xfer_inflight_by_oid[key[0]] = left
             if charged:
                 self._xfer_load[src] = max(0, self._xfer_load[src] - 1)
         self._xfer_load.pop(node_id, None)
@@ -5954,10 +5949,7 @@ class Scheduler:
             # executor-pushed step records (drained off the report rpcs
             # they rode, batched on the publish cadence)
             for srec in args[0] if args else ():
-                try:
-                    self._train_index.ingest(srec)
-                except Exception:
-                    logger.exception("train step record ingest failed")
+                self._ingest_train_step(srec)
             return True
         if op == "train_run_meta":
             # executor-pushed run metadata (periodic goodput + downtime
@@ -6998,10 +6990,13 @@ class Scheduler:
             except Exception:
                 logger.exception("object provenance record ingest failed")
         for srec in batch.get("train_steps") or ():
+            self._ingest_train_step(srec)
+        loops = batch.get("loops")
+        if loops:
             try:
-                self._train_index.ingest(srec)
+                self._loop_log.ingest(loops)
             except Exception:
-                logger.exception("train step record ingest failed")
+                logger.exception("loop record ingest failed")
         for trec in batch.get("transfers") or ():
             try:
                 self._ingest_transfer_record(trec, holder=holder)
@@ -7013,6 +7008,20 @@ class Scheduler:
             except Exception:
                 logger.exception("metric merge failed for %r", name)
         self._telemetry_dropped += int(batch.get("dropped") or 0)
+
+    def _ingest_train_step(self, srec) -> None:
+        """One step record, by either of its channels (the executor's
+        batched push or a telemetry batch): into the StepIndex, and onto
+        disk as the run's loop record."""
+        try:
+            if isinstance(srec, (tuple, list)):
+                srec = _stepplane.decode_record(srec)
+            if not srec:
+                return
+            self._train_index.ingest(srec)
+            self._loop_log.append_train_step(srec)
+        except Exception:
+            logger.exception("train step record ingest failed")
 
     def _merge_metric(self, name, kind, description, data, proc) -> None:
         """Aggregate per-process snapshots into one series (parity: the
@@ -8314,6 +8323,7 @@ class Scheduler:
 
     def _shutdown_workers(self):
         self._close_log_files()
+        self._loop_log.close()
         for w in self.workers.values():
             if w.state != "dead":
                 try:

@@ -18,8 +18,10 @@ distributed JAX training steps. Every ``train.report`` boundary closes one
     -> checkpoint_stall (the blocking local-snapshot portion of
                          train.report(checkpoint=), joining the PR-5
                          checkpoint_save spans)
-    -> other (honest residue: report/collector overhead and anything the
-              seams above did not measure)
+    -> report (the collector round trip ``train.report`` blocks the loop
+               for: the per-step cost of reporting at all)
+    -> other (honest residue of the report half: anything the seams above
+              did not measure)
 
 Worker side: a :class:`StepTimer` per training session, activated
 process-wide so the data iterator and the jax monitoring listener can
@@ -113,7 +115,7 @@ def _get_metrics() -> Dict[str, Any]:
                     "ray_tpu_train_step_seconds",
                     "per-step stage decomposition of training steps "
                     "(seconds; stage=data_wait|host_to_device|compile|"
-                    "compute|collective_wait|checkpoint_stall|other)",
+                    "compute|collective_wait|checkpoint_stall|report|other)",
                     tag_keys=("stage",),
                 ),
                 "step_wall": Histogram(
@@ -231,6 +233,15 @@ def note_batch_signature(sig: str) -> None:
         t.note_batch_signature(sig)
 
 
+def last_step() -> Optional[dict]:
+    """The active session's last closed step record (``decode_record``'s
+    shape: wall_ms, stages, the step's wall-clock bounds), or None before
+    the first ``train.report`` returns or with the plane off. A loop logs
+    its own data wait with this."""
+    t = current()
+    return t.last_step() if t is not None else None
+
+
 def batch_signature(batch: Dict[str, Any]) -> str:
     """Abstract-shape signature of one batch dict — what jit retraces on.
     ``key:dtype[shape]`` per column, sorted for stability."""
@@ -259,10 +270,12 @@ class StepTimer:
     Lifecycle per step: the loop half (data_wait / host_to_device /
     compile / compute) runs from the previous report's return (``t0``) to
     the next report's entry (``t1``, :meth:`mark_pre_report`); the report
-    half (checkpoint_stall + collector overhead -> other) runs ``t1..t2``
-    (:meth:`finalize_step`). ``compute`` is the loop residual; ``other``
-    the report residual — both floored at zero so overlap (e.g. a compile
-    inside a data-wait window) can only oversum, never hide time.
+    half (checkpoint_stall, the collector round trip -> report, the rest
+    -> other) runs ``t1..t2`` (:meth:`finalize_step`). ``compute`` is the
+    loop residual; ``other`` the report residual — both floored at zero so
+    overlap (e.g. a compile inside a data-wait window) can only oversum,
+    never hide time. Wall-clock stamps are ``time.time_ns()``, the clock a
+    profiler trace's events are on.
     """
 
     def __init__(self, run: str, rank: int, world: int,
@@ -291,6 +304,8 @@ class StepTimer:
         self._last_metrics_flush = time.perf_counter()
         # the finalized-but-unshipped record awaiting the next report rpc
         self._pending_rec: Optional[tuple] = None
+        # the last closed step's record, for the loop to read (last_step)
+        self._last_rec: Optional[tuple] = None
         # sub-floor steps coalesce here (stage sums + count) and emerge as
         # ONE merged record per flush interval — per-step rows for sub-ms
         # loops cost record construction per step and flood the bounded
@@ -298,7 +313,7 @@ class StepTimer:
         self._floor_ms = float(_config_attr("train_obs_min_step_ms", 2.0))
         self._co: Optional[list] = None  # [t0w, t1w, t2w, step, count,
         #                                  wall, dw, h2d, comp, cu, ck, ot,
-        #                                  compile_events]
+        #                                  compile_events, report]
         # resolved once: per-step getattr/import walks (sampler probe,
         # telemetry buffer, enabled gate) priced out of finalize_step
         self._enabled = enabled()
@@ -319,7 +334,7 @@ class StepTimer:
             self._jax_probe_done = lambda: True
         self._hooks_done = False
         self._probe_jax_hooks()
-        self._reset(time.time(), time.perf_counter())
+        self._reset(time.time_ns(), time.perf_counter())
 
     def _probe_jax_hooks(self) -> None:
         """The compile stage needs the jax.monitoring listener installed
@@ -335,15 +350,16 @@ class StepTimer:
         except Exception:
             pass
 
-    def _reset(self, wall_now: float, perf_now: float) -> None:
-        self._t0_wall = wall_now
+    def _reset(self, wall_now_ns: int, perf_now: float) -> None:
+        self._t0_ns = wall_now_ns
         self._t0 = perf_now
-        self._t1_wall: Optional[float] = None
+        self._t1_ns: Optional[int] = None
         self._t1: Optional[float] = None
         self._data_wait = 0.0
         self._h2d = 0.0
         self._compile = 0.0
         self._ckpt_stall = 0.0
+        self._report = 0.0
         self._ops: Dict[str, float] = {}
         self._compile_events = 0
         self._recompiled = False
@@ -371,32 +387,41 @@ class StepTimer:
     def note_checkpoint_stall(self, seconds: float) -> None:
         self._ckpt_stall += max(0.0, float(seconds))
 
+    def note_report(self, seconds: float) -> None:
+        """The collector round trip ``train.report`` blocked the loop for."""
+        self._report += max(0.0, float(seconds))
+
     def note_batch_signature(self, sig: str) -> None:
         if sig != self._sig:
             self._sig_prev, self._sig = self._sig, sig
 
     def mark_pre_report(self) -> None:
         """Entry of train.report: the loop half of the step ends here."""
-        self._t1_wall = time.time()
+        self._t1_ns = time.time_ns()
         self._t1 = time.perf_counter()
+
+    def last_step(self) -> Optional[dict]:
+        rec = self._last_rec
+        return decode_record(rec) if rec is not None else None
 
     # -- finalize ----------------------------------------------------------
 
     def finalize_step(self, step: int, trace_id: Optional[str] = None) -> Optional[dict]:
         """Close the step at the report boundary; emit the record + metrics.
         Returns the record (None when the plane is disabled)."""
-        end_wall = time.time()
+        end_ns = time.time_ns()
         end = time.perf_counter()
         self._probe_jax_hooks()  # user code may import jax mid-run
         if self._t1 is None:  # report entry not marked (direct callers)
-            self._t1, self._t1_wall = end, end_wall
+            self._t1, self._t1_ns = end, end_ns
+        t0_wall, t1_wall, end_wall = self._t0_ns / 1e9, self._t1_ns / 1e9, end_ns / 1e9
         wall = max(0.0, end - self._t0)
         loop_wall = max(0.0, self._t1 - self._t0)
         report_wall = max(0.0, end - self._t1)
         compute = max(
             0.0, loop_wall - self._data_wait - self._h2d - self._compile
         )
-        other = max(0.0, report_wall - self._ckpt_stall)
+        other = max(0.0, report_wall - self._ckpt_stall - self._report)
         wall_ms = wall * 1e3
         if (
             wall_ms < self._floor_ms
@@ -410,10 +435,10 @@ class StepTimer:
             co = self._co
             if co is None:
                 co = self._co = [
-                    self._t0_wall, self._t1_wall, end_wall, int(step), 0,
-                    0.0, 0.0, 0.0, 0.0, 0.0, 0.0, 0.0, 0,
+                    t0_wall, t1_wall, end_wall, int(step), 0,
+                    0.0, 0.0, 0.0, 0.0, 0.0, 0.0, 0.0, 0, 0.0,
                 ]
-            co[1] = self._t1_wall
+            co[1] = t1_wall
             co[2] = end_wall
             co[3] = int(step)
             co[4] += 1
@@ -424,6 +449,7 @@ class StepTimer:
             co[9] += compute
             co[11] += other
             co[12] += self._compile_events
+            co[13] += self._report
             rec = None
         else:
             # compact positional tuple (decode_record is the schema): a
@@ -434,8 +460,8 @@ class StepTimer:
                 self.rank,
                 self.world,
                 int(step),
-                self._t0_wall,
-                self._t1_wall,
+                t0_wall,
+                t1_wall,
                 end_wall,
                 wall_ms,
                 (
@@ -445,6 +471,7 @@ class StepTimer:
                     compute * 1e3,
                     self._ckpt_stall * 1e3,
                     other * 1e3,
+                    self._report * 1e3,
                 ),
                 {k: v * 1e3 for k, v in self._ops.items()}
                 if self._ops
@@ -454,15 +481,18 @@ class StepTimer:
                 1 if self._recompiled else 0,
                 self._sig,
                 1,
+                self._t0_ns,
+                end_ns,
             )
         recompiled = self._recompiled
         sig, sig_prev = self._sig, self._sig_prev
         ops = dict(self._ops)
-        data_wait, h2d, compile_s, ckpt = (
+        data_wait, h2d, compile_s, ckpt, report = (
             self._data_wait, self._h2d, self._compile, self._ckpt_stall,
+            self._report,
         )
         self.steps_done += 1
-        self._reset(end_wall, end)
+        self._reset(end_ns, end)
         if not self._enabled:
             return None
         # the record RIDES THE NEXT REPORT's collector rpc (zero extra
@@ -471,6 +501,7 @@ class StepTimer:
         # session's LAST record drains through the telemetry ring when
         # the timer deactivates (flush_pending_record)
         if rec is not None:
+            self._last_rec = rec
             prev = self._pending_rec
             if prev is not None and self._buffer is not None:
                 # collector-less session (driver-local loops): nothing
@@ -484,6 +515,7 @@ class StepTimer:
             ("compile", compile_s),
             ("compute", compute),
             ("checkpoint_stall", ckpt),
+            ("report", report),
             ("other", other),
         ):
             if v > 0 or stage == "compute":
@@ -541,11 +573,13 @@ class StepTimer:
         co, self._co = self._co, None
         if co is None or not co[4]:
             return
-        t0w, t1w, t2w, step, count, wall, dw, h2d, comp, cu, ck, ot, cev = co
+        t0w, t1w, t2w, step, count, wall, dw, h2d, comp, cu, ck, ot, cev, rp = co
         rec = (
             self.run, self.rank, self.world, step, t0w, t1w, t2w, wall,
-            (dw * 1e3, h2d * 1e3, comp * 1e3, cu * 1e3, ck * 1e3, ot * 1e3),
+            (dw * 1e3, h2d * 1e3, comp * 1e3, cu * 1e3, ck * 1e3, ot * 1e3,
+             rp * 1e3),
             None, None, cev, 0, self._sig, count,
+            int(t0w * 1e9), int(t2w * 1e9),
         )
         if self._pending_rec is None:
             self._pending_rec = rec
@@ -622,6 +656,7 @@ _STAGE_KEYS = (
     "compute_ms",
     "collective_wait_ms",
     "checkpoint_stall_ms",
+    "report_ms",
     "other_ms",
 )
 
@@ -633,6 +668,7 @@ _REC_STAGE_KEYS = (
     "compute_ms",
     "checkpoint_stall_ms",
     "other_ms",
+    "report_ms",
 )
 
 
@@ -643,8 +679,12 @@ def decode_record(rec) -> Optional[dict]:
     coalesced block of sub-floor steps (stage values are sums over it)."""
     try:
         (run, rank, world, step, t0, t1, t2, wall_ms, stages, ops,
-         trace_id, compile_events, recompiled, sig, merged) = rec
+         trace_id, compile_events, recompiled, sig, merged) = rec[:15]
+        # the step's bounds in time.time_ns() (the profiler's clock)
+        t0_ns, t2_ns = rec[15:17] if len(rec) >= 17 else (None, None)
         return {
+            "t0_ns": t0_ns,
+            "t2_ns": t2_ns,
             "merged": int(merged),
             "run": run,
             "rank": int(rank),
@@ -970,6 +1010,7 @@ _BAR_CHARS = {
     "compute_ms": "#",
     "collective_wait_ms": "w",
     "checkpoint_stall_ms": "c",
+    "report_ms": "r",
     "other_ms": ".",
 }
 

@@ -13,8 +13,10 @@ records in one lock-light ring buffer:
   carrying the active trace context so spans form one tree across
   processes;
 * **metric snapshots** — ``ray_tpu.util.metrics`` Counter/Gauge/Histogram
-  updates, coalesced last-writer-wins per metric so one interval produces
-  at most one KV write per metric no matter how many records landed.
+  series. A record only updates the process's shadow and marks the metric
+  dirty; the flusher snapshots the dirty metrics once an interval, so one
+  interval produces at most one KV write per metric no matter how many
+  records landed.
 
 A background thread flushes the buffer every ``metrics_report_interval_ms``
 (the previously-unused knob) as a single ``telemetry_batch`` message to the
@@ -48,6 +50,12 @@ def _runtime():
     if rt is not None:
         return rt
     return worker_mod._driver
+
+
+def flush_interval_s() -> float:
+    """How often this process's buffer is flushed: nothing that only feeds
+    a gauge needs computing oftener."""
+    return _buffer._interval_s()
 
 
 def enabled() -> bool:
@@ -89,12 +97,17 @@ class TelemetryBuffer:
         # train.report boundary — see _private/stepplane.py); merged into
         # the scheduler's bounded per-run StepIndex on flush
         self._train_steps: collections.deque = collections.deque()
+        # loop records of the serving engine (one per loop iteration, one per
+        # finished request — see _private/looplog.py), by the file they go
+        # to; the head appends them under <session_dir>/loops/
+        self._loops: Dict[str, list] = {}
         # transfer-plane read records (peer-arena reads / spill restores —
         # paths with no completion message to ride; see
         # _private/netplane.py); merged into the scheduler's link ledger
         self._transfers: collections.deque = collections.deque()
-        # name -> (kind, description, data snapshot): last writer wins, so
-        # N records within one interval flush as ONE write per metric
+        # name -> (kind, description, data snapshot), taken from util.metrics
+        # at drain time: N records within one interval flush as ONE write
+        # per metric. Holds only what a failed send put back
         self._metrics: Dict[str, Tuple[str, str, dict]] = {}
         # continuous-profiler stack samples, pre-aggregated per process:
         # (task_id, trace_id, stack) -> count. Bounded by the same capacity;
@@ -172,6 +185,21 @@ class TelemetryBuffer:
                 return
             self._train_steps.append(rec)
 
+    def record_loop(self, stem: str, rec) -> None:
+        """One loop record (compact tuple; ``looplog`` is the schema) for
+        the file ``<session_dir>/loops/<stem>.jsonl``. The engine thread
+        calls this once an iteration, after its dispatch: a lock and an
+        append, no serialisation."""
+        with self._lock:
+            recs = self._loops.get(stem)
+            if recs is None:
+                recs = self._loops[stem] = []
+            if len(recs) >= self._capacity():
+                self._dropped_pending += 1
+                self._dropped_total += 1
+                return
+            recs.append(rec)
+
     def record_transfer(self, rec) -> None:
         """One (path, oid_bin, bytes, wire_s, t0, src_shm_dir, trace_id)
         read record (transfer plane; size-floored by the caller)."""
@@ -181,10 +209,6 @@ class TelemetryBuffer:
                 self._dropped_total += 1
                 return
             self._transfers.append(rec)
-
-    def record_metric(self, name: str, kind: str, description: str, data: dict) -> None:
-        with self._lock:
-            self._metrics[name] = (kind, description, data)
 
     def record_samples(self, counts: Dict[Tuple, int]) -> None:
         """Merge one sampler sweep's (task, trace, stack) -> count map."""
@@ -212,7 +236,10 @@ class TelemetryBuffer:
     # -- flushing ----------------------------------------------------------
 
     def _drain(self) -> Optional[dict]:
+        fresh = _dirty_metrics()
         with self._lock:
+            if fresh:
+                self._metrics.update(fresh)  # newer than a re-queued snapshot
             if not (
                 self._events
                 or self._spans
@@ -220,6 +247,7 @@ class TelemetryBuffer:
                 or self._cluster_events
                 or self._objects
                 or self._train_steps
+                or self._loops
                 or self._transfers
                 or self._metrics
                 or self._samples
@@ -238,6 +266,7 @@ class TelemetryBuffer:
                 list(self._train_steps),
                 collections.deque(),
             )
+            loops, self._loops = self._loops, {}
             transfers, self._transfers = (
                 list(self._transfers),
                 collections.deque(),
@@ -256,6 +285,7 @@ class TelemetryBuffer:
             "cluster_events": cluster_events,
             "objects": objects,
             "train_steps": train_steps,
+            "loops": loops,
             "transfers": transfers,
             "metrics": metrics,
             "samples": samples,
@@ -280,6 +310,7 @@ class TelemetryBuffer:
             + len(batch["cluster_events"])
             + len(batch.get("objects") or ())
             + len(batch.get("train_steps") or ())
+            + sum(len(r) for r in (batch.get("loops") or {}).values())
             + len(batch.get("transfers") or ())
             # per-SAMPLE, not per-stack-key (matches record_samples and the
             # scheduler-side accounting)
@@ -337,6 +368,19 @@ class TelemetryBuffer:
                 pass
 
 
+def _dirty_metrics() -> dict:
+    """Snapshots of the ``util.metrics`` series updated since the last flush
+    (name -> (kind, description, data)). The record path only marks a metric
+    dirty; the copy is made here, once an interval. Nothing is taken while
+    the pipeline is off: the marks wait for a runtime that can carry them."""
+    import sys
+
+    mod = sys.modules.get("ray_tpu.util.metrics")
+    if mod is None or not enabled():
+        return {}
+    return mod._collect_dirty()
+
+
 def _send_batch(batch: dict) -> bool:
     rt = _runtime()
     if rt is None or getattr(rt, "closed", False):
@@ -375,13 +419,6 @@ def record_span(span: dict) -> None:
     if not enabled():
         return
     _buffer.record_span(span)
-    _buffer.ensure_flusher()
-
-
-def record_metric(name: str, kind: str, description: str, data: dict) -> None:
-    if not enabled():
-        return
-    _buffer.record_metric(name, kind, description, data)
     _buffer.ensure_flusher()
 
 

@@ -680,6 +680,17 @@ class DriverRuntime:
         return _scope()
 
     def shutdown(self):
+        if getattr(self.config, "telemetry_enabled", True):
+            # the last pull: what the workers and this process still hold
+            # (a loop's last records, a session's last step) reaches the
+            # head, and its files, before there is no head to send to
+            from ray_tpu._private import telemetry
+
+            try:
+                telemetry.flush()
+                self.scheduler.request_telemetry_flush(timeout=2.0)
+            except Exception:
+                pass
         self.closed = True
         if self._direct is not None:
             self._direct.shutdown()

@@ -29,7 +29,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import cloudpickle
 
 from ray_tpu import exceptions as exc
-from ray_tpu._private import memplane, serialization
+from ray_tpu._private import memplane, netplane, serialization
 from ray_tpu._private.ids import ObjectID, TaskID, WorkerID, _Counter
 from ray_tpu._private.object_store import StoreFullError
 from ray_tpu._private.task_spec import Arg, TaskSpec, TaskType
@@ -424,7 +424,10 @@ class WorkerRuntime:
                     if self._direct is None or not self._direct.routes_local(o)
                 }
                 if pulled:
-                    self._send(("pull", req_id, list(pulled)))
+                    # the reader's trace context rides the pull: a transfer it
+                    # starts joins this task's trace even when it settles
+                    # before the traced poll below would have named it
+                    self._send(("pull", req_id, list(pulled), netplane.requester_ctx()))
                 # the scheduler always replies once immediately (inline values
                 # arrive only through that reply) — a user timeout shorter
                 # than the round-trip must not fail already-complete gets, so
@@ -505,7 +508,6 @@ class WorkerRuntime:
             # try a zero-copy read out of a colocated peer node's store
             # first, then poll the local store while periodically asking the
             # scheduler to transfer — or lineage-reconstruct — it
-            from ray_tpu._private import netplane
 
             deadline = time.monotonic() + (timeout if timeout is not None else 60.0)
             path = "shm"

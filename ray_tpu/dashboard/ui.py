@@ -279,11 +279,12 @@ const RENDER = {
     // step waterfall (stage-colored bars) + downtime ledger
     const STAGES = ["data_wait_ms","host_to_device_ms","compile_ms",
                     "compute_ms","collective_wait_ms","checkpoint_stall_ms",
-                    "other_ms"];
+                    "report_ms","other_ms"];
     const COLORS = {data_wait_ms:"#e3a04f", host_to_device_ms:"#b06fd8",
                     compile_ms:"#e3504f", compute_ms:"#38c172",
                     collective_wait_ms:"#4fa3ff",
-                    checkpoint_stall_ms:"#d8c94f", other_ms:"#6b7a8c"};
+                    checkpoint_stall_ms:"#d8c94f", report_ms:"#4fd8c9",
+                    other_ms:"#6b7a8c"};
     const sel = location.hash.split(":")[1];
     if (sel) {
       const d = await j("/api/train?run=" + sel);

@@ -48,6 +48,7 @@ class DataIterator:
 
     def iter_batches(self, *, batch_size: int = 256, drop_last: bool = False):
         from ray_tpu._private import stepplane
+        from ray_tpu._private.profiling import annotate
 
         it = iter(
             self._ds.iter_batches(batch_size=batch_size, drop_last=drop_last)
@@ -56,7 +57,8 @@ class DataIterator:
             timer = stepplane.current()  # re-read: a step may start mid-iter
             t0 = time.perf_counter()
             try:
-                batch = next(it)
+                with annotate("train.data_wait"):
+                    batch = next(it)
             except StopIteration:
                 return
             if timer is not None:
@@ -91,15 +93,17 @@ class DataIterator:
         import jax
 
         from ray_tpu._private import stepplane
+        from ray_tpu._private.profiling import annotate
 
         for batch in self.iter_batches(batch_size=batch_size, drop_last=drop_last):
             t0 = time.perf_counter()
             out = {}
-            for k, v in batch.items():
-                arr = np.asarray(v)
-                if dtypes and k in dtypes:
-                    arr = arr.astype(dtypes[k])
-                out[k] = jax.device_put(arr, sharding) if sharding is not None else jax.device_put(arr)
+            with annotate("train.host_to_device"):
+                for k, v in batch.items():
+                    arr = np.asarray(v)
+                    if dtypes and k in dtypes:
+                        arr = arr.astype(dtypes[k])
+                    out[k] = jax.device_put(arr, sharding) if sharding is not None else jax.device_put(arr)
             timer = stepplane.current()
             if timer is not None:
                 timer.note_host_to_device(time.perf_counter() - t0)

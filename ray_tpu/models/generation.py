@@ -249,6 +249,7 @@ def _forward_paged(
     # and copy every layer's full k/v through it, which defeats buffer
     # donation and turns each decode step into an O(pool-size) memcpy.
     # Carry-threaded updates alias in place under ``donate_argnums``.
+    @jax.named_scope("block")
     def body(carry, layer_inputs):
         x, pk, pv = carry
         layer, li = layer_inputs
@@ -265,36 +266,41 @@ def _forward_paged(
         bits = pk.dtype != jnp.dtype(cfg.dtype)
         kw = k.reshape(b * s, *k.shape[2:]).astype(cfg.dtype)
         vw = v.reshape(b * s, *v.shape[2:]).astype(cfg.dtype)
-        if bits:
-            kw = jax.lax.bitcast_convert_type(kw, pk.dtype)
-            vw = jax.lax.bitcast_convert_type(vw, pv.dtype)
-        pk = pk.at[li, write_slots].set(kw)
-        pv = pv.at[li, write_slots].set(vw)
-        gk, gv = pk[li][gather_idx], pv[li][gather_idx]
-        if bits:
-            gk = jax.lax.bitcast_convert_type(gk, cfg.dtype)
-            gv = jax.lax.bitcast_convert_type(gv, cfg.dtype)
-        att = _paged_attention(q, gk, gv, positions)
+        with jax.named_scope("paged_scatter"):
+            if bits:
+                kw = jax.lax.bitcast_convert_type(kw, pk.dtype)
+                vw = jax.lax.bitcast_convert_type(vw, pv.dtype)
+            pk = pk.at[li, write_slots].set(kw)
+            pv = pv.at[li, write_slots].set(vw)
+        with jax.named_scope("paged_gather"):
+            gk, gv = pk[li][gather_idx], pv[li][gather_idx]
+            if bits:
+                gk = jax.lax.bitcast_convert_type(gk, cfg.dtype)
+                gv = jax.lax.bitcast_convert_type(gv, cfg.dtype)
+        with jax.named_scope("paged_attn"):
+            att = _paged_attention(q, gk, gv, positions)
         att_out = jnp.einsum("bshk,hkd->bsd", att, layer["wo"])
+        with jax.named_scope("mlp"):
+            if cfg.parallel_block:
+                mlp_out = _mlp(cfg, layer, h)
+            else:
+                x = x + att_out
+                mlp_out = _mlp(cfg, layer, rms_norm(x, layer["mlp_norm"]))
         if cfg.parallel_block:
-            m = h
-            x_out = x + att_out + _mlp(cfg, layer, m)
-        else:
-            x1 = x + att_out
-            m = rms_norm(x1, layer["mlp_norm"])
-            x_out = x1 + _mlp(cfg, layer, m)
-        return (x_out, pk, pv), None
+            return (x + att_out + mlp_out, pk, pv), None
+        return (x + mlp_out, pk, pv), None
 
     (x, new_k, new_v), _ = jax.lax.scan(
         body,
         (x, pool["k"], pool["v"]),
         (_stacked(params), jnp.arange(cfg.n_layers)),
     )
-    x = rms_norm(x, params["final_norm"])
-    unembed = params.get("unembed")
-    if unembed is None:
-        unembed = params["embed"].T
-    logits = jnp.einsum("bsd,dv->bsv", x, unembed).astype(jnp.float32)
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"])
+        unembed = params.get("unembed")
+        if unembed is None:
+            unembed = params["embed"].T
+        logits = jnp.einsum("bsd,dv->bsv", x, unembed).astype(jnp.float32)
     return logits, {"k": new_k, "v": new_v}
 
 

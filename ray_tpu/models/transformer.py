@@ -167,11 +167,40 @@ def param_logical_axes(cfg: TransformerConfig) -> Dict[str, Tuple]:
     return axes
 
 
+@jax.named_scope("block")
 def _block(
     cfg: TransformerConfig, x, layer, cos, sin, positions, context_axis, mesh, attn_spec=None
 ):
-    """One transformer block. x: (B, S, D)."""
-    h = rms_norm(x, layer["attn_norm"])
+    """One transformer block. x: (B, S, D). The named scopes (``block``,
+    ``block/attn``, ``block/mlp``) label the block's device operations in a
+    profiler trace, forward and backward; they change no value."""
+    with jax.named_scope("attn"):
+        h = rms_norm(x, layer["attn_norm"])
+        att_out = _attend(h, layer, cos, sin, positions, context_axis, mesh, attn_spec)
+
+    if cfg.parallel_block:
+        # GPT-J: MLP reads the same normed input; both branches add to residual
+        m = h
+    else:
+        x = x + att_out
+        m = rms_norm(x, layer["mlp_norm"])
+    with jax.named_scope("mlp"):
+        if cfg.use_swiglu:
+            ff = swiglu(
+                jnp.einsum("bsd,df->bsf", m, layer["w_gate"]),
+                jnp.einsum("bsd,df->bsf", m, layer["w_up"]),
+            )
+        else:
+            ff = gelu(jnp.einsum("bsd,df->bsf", m, layer["w_up"]))
+        mlp_out = jnp.einsum("bsf,fd->bsd", ff, layer["w_down"])
+    if cfg.parallel_block:
+        return x + att_out + mlp_out
+    return x + mlp_out
+
+
+def _attend(h, layer, cos, sin, positions, context_axis, mesh, attn_spec):
+    """Projections, rotary embedding, attention and the output projection
+    over the normed input ``h``."""
     q = jnp.einsum("bsd,dhk->bshk", h, layer["wq"])
     k = jnp.einsum("bsd,dhk->bshk", h, layer["wk"])
     v = jnp.einsum("bsd,dhk->bshk", h, layer["wv"])
@@ -203,25 +232,7 @@ def _block(
         )(q, k, v)
     else:
         att = attention(q, k, v, causal=True)
-    att_out = jnp.einsum("bshk,hkd->bsd", att, layer["wo"])
-
-    if cfg.parallel_block:
-        # GPT-J: MLP reads the same normed input; both branches add to residual
-        m = h
-    else:
-        x = x + att_out
-        m = rms_norm(x, layer["mlp_norm"])
-    if cfg.use_swiglu:
-        ff = swiglu(
-            jnp.einsum("bsd,df->bsf", m, layer["w_gate"]),
-            jnp.einsum("bsd,df->bsf", m, layer["w_up"]),
-        )
-    else:
-        ff = gelu(jnp.einsum("bsd,df->bsf", m, layer["w_up"]))
-    mlp_out = jnp.einsum("bsf,fd->bsd", ff, layer["w_down"])
-    if cfg.parallel_block:
-        return x + att_out + mlp_out
-    return x + mlp_out
+    return jnp.einsum("bshk,hkd->bsd", att, layer["wo"])
 
 
 def forward(
@@ -259,11 +270,12 @@ def forward(
         else:
             body = jax.checkpoint(body)
     x, _ = jax.lax.scan(body, x, stacked)
-    x = rms_norm(x, params["final_norm"])
-    unembed = params.get("unembed")
-    if unembed is None:
-        unembed = params["embed"].T
-    return jnp.einsum("bsd,dv->bsv", x, unembed)
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"])
+        unembed = params.get("unembed")
+        if unembed is None:
+            unembed = params["embed"].T
+        return jnp.einsum("bsd,dv->bsv", x, unembed)
 
 
 def loss_fn(
@@ -288,9 +300,10 @@ def loss_fn(
         mesh=mesh,
         attn_spec=attn_spec,
     ).astype(jnp.float32)
-    logz = jax.nn.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-    nll = logz - gold
-    if loss_mask is not None:
-        return jnp.sum(nll * loss_mask) / jnp.maximum(jnp.sum(loss_mask), 1.0)
-    return jnp.mean(nll)
+    with jax.named_scope("loss"):
+        logz = jax.nn.logsumexp(logits, axis=-1)
+        gold = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
+        nll = logz - gold
+        if loss_mask is not None:
+            return jnp.sum(nll * loss_mask) / jnp.maximum(jnp.sum(loss_mask), 1.0)
+        return jnp.mean(nll)

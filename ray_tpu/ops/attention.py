@@ -127,14 +127,15 @@ def _flash(q, k, v, *, causal):
 
     # pallas kernel wants BHSD
     qt, kt, vt = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))
-    out = flash_attention(
-        qt,
-        kt,
-        vt,
-        causal=causal,
-        sm_scale=1.0 / (q.shape[-1] ** 0.5),
-        block_sizes=_tuned_block_sizes(q.shape[-1], q.shape[1], k.shape[1]),
-    )
+    with jax.named_scope("flash"):  # the kernels keep the names they give themselves
+        out = flash_attention(
+            qt,
+            kt,
+            vt,
+            causal=causal,
+            sm_scale=1.0 / (q.shape[-1] ** 0.5),
+            block_sizes=_tuned_block_sizes(q.shape[-1], q.shape[1], k.shape[1]),
+        )
     return jnp.swapaxes(out, 1, 2)
 
 

@@ -142,8 +142,9 @@ def build_lm_train_step(
     def step(state, tokens, targets):
         lossval, grads = jax.value_and_grad(loss)(state["params"], tokens, targets)
         grads = constrain(grads)
-        updates, new_opt = optimizer.update(grads, state["opt"], state["params"])
-        new_params = constrain(optax.apply_updates(state["params"], updates))
+        with jax.named_scope("optimizer"):
+            updates, new_opt = optimizer.update(grads, state["opt"], state["params"])
+            new_params = constrain(optax.apply_updates(state["params"], updates))
         gnorm = optax.global_norm(grads)
         return (
             {"params": new_params, "opt": new_opt, "step": state["step"] + 1},

@@ -135,6 +135,11 @@ class LLMServer:
     def kv_stats(self) -> Dict[str, Any]:
         return self._engine.kv_stats()
 
+    def loop_stats(self, records: int = 64) -> Dict[str, Any]:
+        """The engine loop's newest step and request records and its phase
+        sums (``InferenceEngine.loop_stats``): where a step's time went."""
+        return self._engine.loop_stats(records)
+
     def check_health(self) -> bool:
         if self._engine._thread is None or not self._engine._thread.is_alive():
             raise RuntimeError("inference engine loop is not running")

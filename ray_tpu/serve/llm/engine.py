@@ -25,19 +25,43 @@ The fixed decode shape also buys schedule-invariance: a sequence's
 tokens depend only on its own prompt and (seed, step) PRNG stream, never
 on which neighbours share the batch — continuous batching is tokenwise
 identical to isolated decode (tested).
+
+What the loop measures of itself (schema in ``_private/looplog.py``). Every
+stamp is ``time.time_ns()``, the clock a profiler trace's events are on. One
+fixed-size tuple per iteration: top of the iteration, end of its prefills,
+the in-flight step's result on the host, end of retire, around the dispatch,
+end of the iteration. Phases follow by subtraction: ``t_admit_end - t_loop``
+is how long prefills held every stream, ``t_result - t_admit_end`` how long
+the thread waited for the device, ``t_dispatch_end - t_result`` the host work
+the device waits for. A request that ends leaves a record too and, when its
+caller was traced, three spans under the caller's span (``llm.queue_wait``,
+``llm.prefill``, ``llm.decode``). The records ride the telemetry batches to
+``<session_dir>/loops/`` and the newest stay in a bounded ring in the process
+(``loop_stats``, which does its sums when read). Before the dispatch the loop
+only reads the clock; records, spans and the ``ray_tpu_llm_*`` series (bound
+handles) are made after it, while the device is busy. Each phase is also a
+``TraceAnnotation`` (``llm.admit``, ``llm.prefill``, ``llm.retire``,
+``llm.dispatch``, ``llm.emit``) on this thread's line of any profiler trace.
+With ``telemetry_enabled`` off no record or span is made and the ring is empty.
 """
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import itertools
+import os
 import queue
 import threading
 import time
 from typing import Any, Dict, List, Optional, Sequence
 
+from ray_tpu._private import memplane, telemetry
+from ray_tpu._private.looplog import LLM_REQUEST_FIELDS, LLM_STEP_FIELDS
+from ray_tpu._private.profiling import annotate
 from ray_tpu.serve.exceptions import DeploymentOverloadedError
 from ray_tpu.serve.llm.kv_cache import BlockAllocator, BlockTable
+from ray_tpu.util import tracing
 
 __all__ = ["EngineConfig", "InferenceEngine", "TokenStream"]
 
@@ -76,8 +100,11 @@ def _engine_metrics() -> dict:
         )
         _metrics["step"] = Histogram(
             "ray_tpu_llm_decode_step_ms",
-            "wall time of one continuous-batching decode step (all active "
-            "slots advance one token) per LLM deployment",
+            "host wall time from the top of one decode step's dispatch to "
+            "the end of its retire in the next loop iteration: the device "
+            "step plus everything the loop does in between (emit, the next "
+            "iteration's prefills), on the monotonic clock; the loop records "
+            "split it (loop_stats)",
             tag_keys=("deployment",),
         )
     return _metrics
@@ -105,6 +132,11 @@ class EngineConfig:
     stream_timeout_s: float = 120.0
 
 
+# loop and request records kept in the process for loop_stats(); at 20 steps
+# a second this is the last three minutes
+LOOP_RING = 4096
+
+
 class _Request:
     __slots__ = (
         "id",
@@ -116,7 +148,11 @@ class _Request:
         "eos_token",
         "need_blocks",
         "out",
-        "submitted_at",
+        "ctx",  # the submitting thread's trace context (the replica's span)
+        "t_submit",  # time_ns stamps; 0 = not reached
+        "t_admit",
+        "t_first",
+        "bucket",
     )
 
     def __init__(self, **kw):
@@ -142,18 +178,22 @@ class TokenStream:
     re-raise here (typed, never a silent hang — a stalled engine trips
     ``stream_timeout_s``)."""
 
-    def __init__(self, request_id: int, timeout_s: float):
+    def __init__(self, request_id: int, timeout_s: float, submitted_ns: Optional[int] = None):
         self.request_id = request_id
         self._timeout_s = timeout_s
         self._q: "queue.Queue" = queue.Queue()
-        self._submitted_at = time.perf_counter()
+        self._submitted_ns = time.time_ns() if submitted_ns is None else submitted_ns
         self.ttft_s: Optional[float] = None
         self.finish_reason: Optional[str] = None
 
     # engine side -------------------------------------------------------
-    def _emit(self, token: int) -> None:
+    def _emit(self, token: int, t_ns: Optional[int] = None) -> None:
+        """``t_ns``: when the token reached the host, where the engine has
+        stamped it (the first token: the request's ``llm.prefill`` span ends
+        on the same stamp)."""
         if self.ttft_s is None:
-            self.ttft_s = time.perf_counter() - self._submitted_at
+            # wall-clock stamps (the spans'): a clock set back must not go negative
+            self.ttft_s = max(0, (t_ns or time.time_ns()) - self._submitted_ns) / 1e9
         self._q.put(("tok", token))
 
     def _finish(self, reason: str) -> None:
@@ -224,7 +264,27 @@ class InferenceEngine:
         self.max_context = min(
             ecfg.max_blocks_per_seq * ecfg.block_size, model_cfg.max_seq_len
         )
-        self._register_kv_provider()
+        k = self._pool["k"]
+        self._bytes_per_block = int(
+            k.dtype.itemsize * 2 * k.shape[0] * ecfg.block_size * k.shape[2] * k.shape[3]
+        )
+        self.decode_steps = 0  # dispatched so far: a step's number
+        # -- what the loop measures of itself (module docstring) ----------
+        # resolved once: a replica builds its engine after it has connected
+        self._tel = telemetry.get_buffer() if telemetry.enabled() else None
+        self._ring: "collections.deque[tuple]" = collections.deque(maxlen=LOOP_RING)
+        self._stem = f"llm-{deployment}-{os.getpid()}"
+        self._gauge_period_ns = int(telemetry.flush_interval_s() * 1e9)
+        self._gauges_at = 0
+        m, tags = _engine_metrics(), {"deployment": deployment}
+        self._m_running = m["running"].bind(tags)
+        self._m_waiting = m["waiting"].bind(tags)
+        self._m_shed = m["shed"].bind(tags)
+        self._m_step = m["step"].bind(tags)
+        self._m_prefill_tokens = m["tokens"].bind({**tags, "phase": "prefill"})
+        self._m_decode_tokens = m["tokens"].bind({**tags, "phase": "decode"})
+        # periodic device sweeps refresh the ray_tpu_kv_* gauges of an idle engine
+        memplane.register_kv_provider(deployment, self._occupancy)
         if start:
             self.start()
 
@@ -245,10 +305,13 @@ class InferenceEngine:
         if self._thread is not None:
             self._thread.join(timeout=timeout_s)
         err = RuntimeError("inference engine shut down")
+        ended: List[tuple] = []
+        now = time.time_ns()
         with self._cv:
             for req, stream in self._waiting:
                 self._committed_blocks -= req.need_blocks
                 stream._fail(err)
+                ended.append((req, "shutdown", now, 1 if req.t_first else 0))
             self._waiting.clear()
             for i, run in enumerate(self._slots):
                 if run is not None:
@@ -256,7 +319,12 @@ class InferenceEngine:
                     self._committed_blocks -= run.req.need_blocks
                     run.req.out._fail(err)
                     self._slots[i] = None
-        self._update_gauges()
+                    ended.append((run.req, "shutdown", now, run.generated))
+        for item in ended:
+            self._close_request(*item)
+        self._m_running.set(0.0)
+        self._m_waiting.set(0.0)
+        self._refresh_kv_gauges()
 
     # -- admission ------------------------------------------------------
 
@@ -288,46 +356,54 @@ class InferenceEngine:
             )
         need = self._alloc.blocks_for_tokens(total)
         usable = self._alloc.num_usable
+        ctx = tracing.get_current_context()  # the replica's span, if traced
         with self._cv:
             if self._stop:
                 raise RuntimeError("inference engine is shut down")
             free_slots = sum(1 for s in self._slots if s is None)
-            overloaded = (
-                len(self._waiting) >= self.cfg.max_waiting + free_slots
-                or self._committed_blocks + need > usable
-            )
-            if overloaded:
-                try:
-                    _engine_metrics()["shed"].inc(
-                        tags={"deployment": self.deployment}
-                    )
-                except Exception:
-                    pass
-                raise DeploymentOverloadedError(
-                    deployment=self.deployment,
-                    retry_after_s=self.cfg.retry_after_s,
-                    load=self._committed_blocks + need,
-                    capacity=usable,
+            load = self._committed_blocks + need
+            cause = None
+            if len(self._waiting) >= self.cfg.max_waiting + free_slots:
+                cause = "waiting"
+            elif load > usable:
+                cause = "blocks"
+            if cause is None:
+                req = _Request(
+                    id=next(self._ids),
+                    prompt=prompt,
+                    max_new_tokens=int(max_new_tokens),
+                    temperature=float(temperature),
+                    top_k=int(top_k),
+                    seed=int(seed),
+                    eos_token=eos_token,
+                    need_blocks=need,
+                    out=None,
+                    ctx=ctx,
+                    t_submit=time.time_ns(),
+                    t_admit=0,
+                    t_first=0,
+                    bucket=0,
                 )
-            req = _Request(
-                id=next(self._ids),
-                prompt=prompt,
-                max_new_tokens=int(max_new_tokens),
-                temperature=float(temperature),
-                top_k=int(top_k),
-                seed=int(seed),
-                eos_token=eos_token,
-                need_blocks=need,
-                out=None,
-                submitted_at=time.perf_counter(),
+                stream = TokenStream(req.id, self.cfg.stream_timeout_s, req.t_submit)
+                req.out = stream
+                self._committed_blocks += need
+                self._waiting.append((req, stream))
+                self._streams[req.id] = stream
+                waiting = len(self._waiting)
+                self._cv.notify_all()
+        if cause is not None:
+            self._m_shed.inc()
+            if self._tel is not None:
+                now = time.time_ns()
+                shed = _Request(id=-1, prompt=prompt, ctx=ctx, t_submit=now, t_admit=0, t_first=0, bucket=0)
+                self._close_request(shed, "shed_" + cause, now, 0)
+            raise DeploymentOverloadedError(
+                deployment=self.deployment,
+                retry_after_s=self.cfg.retry_after_s,
+                load=load,
+                capacity=usable,
             )
-            stream = TokenStream(req.id, self.cfg.stream_timeout_s)
-            req.out = stream
-            self._committed_blocks += need
-            self._waiting.append((req, stream))
-            self._streams[req.id] = stream
-            self._cv.notify_all()
-        self._update_gauges()
+        self._m_waiting.set(float(waiting))
         return stream
 
     # -- stats ----------------------------------------------------------
@@ -353,15 +429,6 @@ class InferenceEngine:
             running = sum(1 for s in self._slots if s is not None)
             waiting = len(self._waiting)
             committed = self._committed_blocks
-        bytes_per_block = 0
-        try:
-            k = self._pool["k"]
-            bytes_per_block = int(
-                k.dtype.itemsize * 2 * k.shape[0] * self.cfg.block_size
-                * k.shape[2] * k.shape[3]
-            )
-        except Exception:
-            pass
         return {
             "deployment": self.deployment,
             "block_size": self.cfg.block_size,
@@ -371,29 +438,99 @@ class InferenceEngine:
             "occupancy": 0.0 if not usable else 1.0 - free / usable,
             "running": running,
             "waiting": waiting,
-            "bytes_per_block": bytes_per_block,
+            "bytes_per_block": self._bytes_per_block,
         }
 
-    def _register_kv_provider(self) -> None:
-        try:
-            from ray_tpu._private import memplane
+    def _refresh_kv_gauges(self) -> None:
+        """The ``ray_tpu_kv_*`` gauges from this engine's occupancy: the
+        loop calls it at most once a telemetry flush interval (a gauge is
+        read no oftener) and when it drains, shutdown once. The one metric
+        call of the engine that goes through another plane's code, which
+        keeps its own guard (``record_kv_occupancy`` never raises)."""
+        memplane.record_kv_occupancy(self._occupancy())
 
-            memplane.register_kv_provider(self.deployment, self._occupancy)
-        except Exception:
-            pass
+    def loop_stats(self, records: int = 64) -> Dict[str, Any]:
+        """The newest loop records (``records``) and request records
+        (``requests``) this process holds, as tuples in the order of
+        ``fields`` / ``request_fields`` with stamps in ``time.time_ns()``,
+        and ``phases``: count, sum and maximum in ns of each phase over all
+        the ring holds, computed here on the reader's thread. ``queue_wait``
+        and ``prefill`` are per request (submit -> popped -> first token),
+        ``prefill_stall`` per iteration that prefilled, ``device_wait`` and
+        ``dispatch_gap`` per retired step, ``emit`` per iteration. Empty with
+        ``telemetry_enabled`` off."""
+        ring = self._ring.copy()  # atomic against the loop's appends
+        steps = [r[1:] for r in ring if r[0] == "s"]
+        reqs = [r[1:] for r in ring if r[0] == "r"]
+        spans: Dict[str, List[int]] = {
+            k: [] for k in ("queue_wait", "prefill", "prefill_stall", "device_wait", "dispatch_gap", "emit")
+        }
+        for r in steps:
+            d = dict(zip(LLM_STEP_FIELDS, r))
+            if d["prefills"]:
+                spans["prefill_stall"].append(d["t_admit_end"] - d["t_loop"])
+            if d["t_result"]:
+                spans["device_wait"].append(d["t_result"] - d["t_admit_end"])
+                if d["t_dispatch_end"]:
+                    spans["dispatch_gap"].append(d["t_dispatch_end"] - d["t_result"])
+            spans["emit"].append(d["t_emit_end"] - max(d["t_dispatch_end"], d["t_retire_end"], d["t_admit_end"]))
+        for r in reqs:
+            d = dict(zip(LLM_REQUEST_FIELDS, r))
+            spans["queue_wait"].append((d["t_admit"] or d["t_finish"]) - d["t_submit"])
+            if d["t_first"]:
+                spans["prefill"].append(d["t_first"] - d["t_admit"])
+        n = max(int(records), 0)
+        return {
+            "deployment": self.deployment,
+            "fields": LLM_STEP_FIELDS,
+            "records": steps[-n:] if n else [],
+            "request_fields": LLM_REQUEST_FIELDS,
+            "requests": reqs[-n:] if n else [],
+            "phases": {k: {"count": len(v), "sum_ns": sum(v), "max_ns": max(v, default=0)}
+                       for k, v in spans.items()},
+        }
 
-    def _update_gauges(self) -> None:
-        try:
-            stats = self._occupancy()
-            m = _engine_metrics()
-            tags = {"deployment": self.deployment}
-            m["running"].set(float(stats["running"]), tags=tags)
-            m["waiting"].set(float(stats["waiting"]), tags=tags)
-            from ray_tpu._private import memplane
+    def _record(self, rec: tuple) -> None:
+        """One loop or request record (telemetry on): into the ring, and on
+        its way to ``<session_dir>/loops/``. An append and a locked append,
+        from the engine thread after its dispatch or a shedding caller."""
+        self._ring.append(rec)
+        self._tel.record_loop(self._stem, rec)
 
-            memplane.record_kv_occupancy(stats)
-        except Exception:
-            pass
+    def _close_request(self, req: _Request, reason: str, t_finish: int, tokens: int) -> None:
+        """A request has ended (finished, failed, shed, shut down): its
+        record, and under a traced caller the spans of the phases it
+        reached, as children of the caller's span. The engine thread calls
+        this after its next dispatch. Nothing with telemetry off."""
+        if self._tel is None:
+            return
+        t_admit = req.t_admit or t_finish
+        ctx = req.ctx
+        steps = max(0, tokens - 1)
+        self._record((
+            "r", req.id, req.t_submit, req.t_admit, req.t_first, t_finish,
+            len(req.prompt), req.bucket, tokens, steps, reason,
+            ctx.trace_id if ctx is not None else None,
+        ))
+        if ctx is None:
+            return
+        spans = [("llm.queue_wait", req.t_submit, t_admit, {})]
+        if req.t_admit:
+            spans.append(("llm.prefill", req.t_admit, req.t_first or t_finish,
+                          {"bucket": req.bucket, "prompt_len": len(req.prompt)}))
+        if req.t_first:
+            spans.append(("llm.decode", req.t_first, t_finish, {"steps": steps, "tokens": tokens}))
+        spans[-1][3]["finish_reason"] = reason
+        for name, start, end, extra in spans:
+            extra.update(
+                deployment=self.deployment, request=req.id, trace_id=ctx.trace_id,
+                span_id=tracing._new_id(8), parent_id=ctx.span_id,
+            )
+            telemetry.record_span({
+                "event": name, "start": start / 1e9, "end": end / 1e9,
+                "duration_ms": (end - start) / 1e6, "pid": os.getpid(),
+                "task_id": None, "extra": extra,
+            })
 
     # -- the loop -------------------------------------------------------
 
@@ -403,8 +540,10 @@ class InferenceEngine:
     def _loop(self) -> None:
         """One-step-pipelined scheduler: step k+1 is dispatched to the
         device BEFORE step k's tokens are emitted to consumers, so queue
-        wakeups, gauge updates, and next-iteration admissions overlap
-        device compute instead of extending the step critical path."""
+        wakeups, series, gauges, request spans and the loop's own record
+        overlap device compute instead of extending the step critical
+        path. Before the dispatch the loop only reads the clock."""
+        now, mono = time.time_ns, time.perf_counter_ns
         inflight = None
         while True:
             admits: List[tuple] = []
@@ -418,29 +557,79 @@ class InferenceEngine:
                     self._cv.wait(self.cfg.idle_poll_s)
                 if self._stop:
                     return
+                t_loop = now()
                 for i, slot in enumerate(self._slots):
                     if slot is None and self._waiting:
-                        admits.append((i, *self._waiting.pop(0)))
-            for slot_idx, req, stream in admits:
-                self._do_prefill(slot_idx, req, stream)
+                        req, stream = self._waiting.pop(0)
+                        req.t_admit = t_loop
+                        admits.append((i, req, stream))
+            # requests that end in this iteration: (request, reason, when,
+            # tokens), closed after the dispatch
+            ended: List[tuple] = []
+            t_admit_end = t_loop
+            if admits:
+                with annotate("llm.admit", requests=len(admits)):
+                    for slot_idx, req, stream in admits:
+                        self._do_prefill(slot_idx, req, stream, ended)
+                t_admit_end = now()
             emissions: List[tuple] = []
             finishes: List[tuple] = []
+            t_result = t_retire_end = step_ns = 0
             if inflight is not None:
-                emissions, finishes = self._retire_step(inflight)
+                with annotate("llm.retire", step=inflight[4]):
+                    emissions, finishes, t_result = self._retire_step(inflight, ended)
+                t_retire_end = now()
+                # the step's series value, on the monotonic clock as it always
+                # was: from the top of its dispatch to the end of its retire
+                step_ns = mono() - inflight[3]
                 inflight = None
             # finished slots detach (blocks freed) before the next
             # dispatch; their streams see the 'done' marker after their
             # final token below
-            for slot_idx, _run, _reason in finishes:
+            for slot_idx, run, reason in finishes:
                 self._detach_slot(slot_idx)
+                ended.append((run.req, reason, t_retire_end, run.generated))
+            t_dispatch = t_dispatch_end = live = fused = 0
             if self._has_active():
-                inflight = self._dispatch_step()
-            for stream, tok in emissions:
-                stream._emit(tok)
-            for _slot_idx, run, reason in finishes:
-                run.req.out._finish(reason)
-            if admits or emissions or finishes:
-                self._update_gauges()
+                t_dispatch = now()
+                with annotate("llm.dispatch", step=self.decode_steps + 1):
+                    inflight = self._dispatch_step(mono(), ended)
+                t_dispatch_end = now()
+                if inflight is not None:
+                    live, fused = len(inflight[0]), int(inflight[2])
+            # ---- the device is busy (or there is nothing for it to do) ----
+            with annotate("llm.emit"):
+                for stream, tok in emissions:
+                    stream._emit(tok)
+                for _slot_idx, run, reason in finishes:
+                    run.req.out._finish(reason)
+                if t_result:
+                    self._m_step.observe(step_ns / 1e6)
+                    self._m_decode_tokens.inc(len(emissions))
+                if admits:
+                    self._fold_prefills(admits)
+                if admits or finishes or ended:
+                    self._m_running.set(float(sum(1 for s in self._slots if s is not None)))
+                    self._m_waiting.set(float(len(self._waiting)))
+                for item in ended:
+                    self._close_request(*item)
+                drained = inflight is None and not self._waiting
+                if drained or t_loop - self._gauges_at >= self._gauge_period_ns:
+                    self._gauges_at = t_loop
+                    self._refresh_kv_gauges()
+            if self._tel is not None:
+                self._record((
+                    "s", self.decode_steps, t_loop, t_admit_end, t_result, t_retire_end,
+                    t_dispatch, t_dispatch_end, now(), live, len(admits), fused,
+                ))
+
+    def _fold_prefills(self, admits: List[tuple]) -> None:
+        """The token series of this iteration's prefills that reached a
+        first token (each counts under ``decode`` too, as it always has)."""
+        done = [req for _i, req, _s in admits if req.t_first]
+        if done:
+            self._m_prefill_tokens.inc(sum(len(r.prompt) for r in done))
+            self._m_decode_tokens.inc(len(done))
 
     # -- phases ---------------------------------------------------------
 
@@ -477,12 +666,7 @@ class InferenceEngine:
             self._streams.pop(run.req.id, None)
             self._cv.notify_all()
 
-    def _finish(self, slot_idx: int, reason: str) -> None:
-        run = self._slots[slot_idx]
-        self._detach_slot(slot_idx)
-        run.req.out._finish(reason)
-
-    def _fail_slot(self, slot_idx: int, error: BaseException) -> None:
+    def _fail_slot(self, slot_idx: int, error: BaseException, ended: List[tuple]) -> None:
         run = self._slots[slot_idx]
         run.table.release()
         with self._cv:
@@ -490,29 +674,31 @@ class InferenceEngine:
             self._slots[slot_idx] = None
             self._streams.pop(run.req.id, None)
         run.req.out._fail(error)
+        ended.append((run.req, "error", time.time_ns(), run.generated))
 
-    def _do_prefill(self, slot_idx: int, req: _Request, stream: TokenStream) -> None:
+    def _do_prefill(self, slot_idx: int, req: _Request, stream: TokenStream, ended: List[tuple]) -> None:
         import numpy as np
         import jax.numpy as jnp
 
+        req.bucket = bucket = self._bucket(len(req.prompt))
         try:
-            table = BlockTable(self._alloc)
-            table.reserve(len(req.prompt))  # reserved at admission: cannot fail
-            table.length = len(req.prompt)
-            bucket = self._bucket(len(req.prompt))
-            toks = np.zeros((1, bucket), np.int32)
-            toks[0, : len(req.prompt)] = req.prompt
-            bt = np.asarray(
-                [table.as_list(self.cfg.max_blocks_per_seq)], np.int32
-            )
-            logits, self._pool = self._prefill(
-                self.params,
-                jnp.asarray(toks),
-                jnp.asarray(bt),
-                self._pool,
-                jnp.int32(len(req.prompt)),
-            )
-            first = self._sample(logits[0], req, step=0)
+            with annotate("llm.prefill", bucket=bucket, prompt_len=len(req.prompt)):
+                table = BlockTable(self._alloc)
+                table.reserve(len(req.prompt))  # reserved at admission: cannot fail
+                table.length = len(req.prompt)
+                toks = np.zeros((1, bucket), np.int32)
+                toks[0, : len(req.prompt)] = req.prompt
+                bt = np.asarray(
+                    [table.as_list(self.cfg.max_blocks_per_seq)], np.int32
+                )
+                logits, self._pool = self._prefill(
+                    self.params,
+                    jnp.asarray(toks),
+                    jnp.asarray(bt),
+                    self._pool,
+                    jnp.int32(len(req.prompt)),
+                )
+                first = self._sample(logits[0], req, step=0)
         except BaseException as e:  # noqa: BLE001 — typed failure to the stream
             try:
                 table.release()
@@ -522,22 +708,16 @@ class InferenceEngine:
                 self._committed_blocks -= req.need_blocks
                 self._streams.pop(req.id, None)
             stream._fail(e)
+            ended.append((req, "error", time.time_ns(), 0))
             return
-        try:
-            _engine_metrics()["tokens"].inc(
-                len(req.prompt),
-                tags={"deployment": self.deployment, "phase": "prefill"},
-            )
-            _engine_metrics()["tokens"].inc(
-                tags={"deployment": self.deployment, "phase": "decode"}
-            )
-        except Exception:
-            pass
+        req.t_first = time.time_ns()  # the first token is on the host
         run = _Running(req, table, first)
         self._slots[slot_idx] = run
-        stream._emit(first)  # TTFT: admission -> first token
+        stream._emit(first, req.t_first)  # TTFT: submit -> first token
         if self._is_done(run, first):
-            self._finish(slot_idx, self._done_reason(run, first))
+            self._detach_slot(slot_idx)
+            stream._finish(self._done_reason(run, first))
+            ended.append((req, self._done_reason(run, first), req.t_first, 1))
 
     def _is_done(self, run: _Running, token: int) -> bool:
         return (
@@ -550,15 +730,15 @@ class InferenceEngine:
             return "stop"
         return "length"
 
-    def _dispatch_step(self):
+    def _dispatch_step(self, t0: int, ended: List[tuple]):
         """Enqueue one decode step on the device and return without
-        waiting for it. A batch where every sequence decodes greedily
-        uses the fused-argmax step (B ints cross back to the host, not
-        B x vocab logits)."""
+        waiting for it: ``(live slots, result, fused, t0, step number)``;
+        ``t0`` is the caller's ``perf_counter_ns`` at the top of the dispatch.
+        A batch where every sequence decodes greedily uses the fused-argmax
+        step (B ints cross back to the host, not B x vocab logits)."""
         import numpy as np
         import jax.numpy as jnp
 
-        t0 = time.perf_counter()
         b = self.cfg.max_batch
         mb = self.cfg.max_blocks_per_seq
         tokens = np.zeros((b,), np.int32)
@@ -594,24 +774,27 @@ class InferenceEngine:
             )
         except BaseException as e:  # noqa: BLE001
             for i in list(live):
-                self._fail_slot(i, e)
+                self._fail_slot(i, e, ended)
             return None
-        return (live, out, fused, t0)
+        self.decode_steps += 1
+        return (live, out, fused, t0, self.decode_steps)
 
-    def _retire_step(self, inflight) -> tuple:
+    def _retire_step(self, inflight, ended: List[tuple]) -> tuple:
         """Block on the in-flight step's result and fold it into the run
-        states. Returns ``(emissions, finishes)`` for the loop to deliver
-        AFTER it dispatches the next step."""
+        states. Returns ``(emissions, finishes, t_result)`` for the loop to
+        deliver AFTER it dispatches the next step; ``t_result`` is when the
+        result was on the host (0: the step failed)."""
         import numpy as np
 
-        live, out, fused, t0 = inflight
+        live, out, fused = inflight[:3]
         try:
             np_out = np.asarray(out)  # blocks until the device step lands
         except BaseException as e:  # noqa: BLE001
             for i in list(live):
                 if self._slots[i] is not None:
-                    self._fail_slot(i, e)
-            return [], []
+                    self._fail_slot(i, e, ended)
+            return [], [], 0
+        t_result = time.time_ns()
         emissions: List[tuple] = []
         finishes: List[tuple] = []
         for i in live:
@@ -625,11 +808,4 @@ class InferenceEngine:
             emissions.append((run.req.out, tok))
             if self._is_done(run, tok):
                 finishes.append((i, run, self._done_reason(run, tok)))
-        try:
-            tags = {"deployment": self.deployment}
-            m = _engine_metrics()
-            m["tokens"].inc(len(emissions), tags={**tags, "phase": "decode"})
-            m["step"].observe((time.perf_counter() - t0) * 1e3, tags=tags)
-        except Exception:
-            pass
-        return emissions, finishes
+        return emissions, finishes, t_result

@@ -145,6 +145,16 @@ class TrainContext:
     def get_trial_dir(self) -> str:
         return self.trial_dir
 
+    def get_last_step(self) -> Optional[Dict[str, Any]]:
+        """The step plane's record of the last step this loop closed (the
+        one its previous ``train.report`` ended): ``wall_ms``, ``stages``
+        (``data_wait_ms``, ``host_to_device_ms``, ``compute_ms``,
+        ``report_ms``, ...) and the step's bounds. None before the first
+        report returns, or with the plane off."""
+        from ray_tpu._private import stepplane
+
+        return stepplane.last_step()
+
 
 def _preempt_shield():
     """The active runtime's preemption-shield toggle, or a no-op when the
@@ -213,18 +223,21 @@ class _Session:
         self.latest_checkpoint = latest_checkpoint
 
     def report(self, metrics: Dict[str, Any], checkpoint: Optional[Checkpoint] = None):
-        if checkpoint is None:
-            return self._report(metrics, None)
-        # preemption shield: the window from snapshot start to the shard's
-        # arrival at the head barrier must not be a preemption/OOM-kill
-        # target — victim selection skips shielded workers, so an
-        # arbitration kill never tears a shard racing toward its commit
-        shield = _preempt_shield()
-        shield(+1)
-        try:
-            return self._report(metrics, checkpoint)
-        finally:
-            shield(-1)
+        from ray_tpu._private.profiling import annotate
+
+        with annotate("train.report", step=self.iteration + 1):
+            if checkpoint is None:
+                return self._report(metrics, None)
+            # preemption shield: the window from snapshot start to the shard's
+            # arrival at the head barrier must not be a preemption/OOM-kill
+            # target — victim selection skips shielded workers, so an
+            # arbitration kill never tears a shard racing toward its commit
+            shield = _preempt_shield()
+            shield(+1)
+            try:
+                return self._report(metrics, checkpoint)
+            finally:
+                shield(-1)
 
     def _report(self, metrics: Dict[str, Any], checkpoint: Optional[Checkpoint]):
         self.iteration += 1
@@ -301,6 +314,7 @@ class _Session:
             # (zero extra messages on the step hot path); the session's
             # last record drains via telemetry when the timer deactivates
             step_rec = timer.pop_pending_record() if timer is not None else None
+            t_rpc = time.perf_counter()
             resp = ray_tpu.get(
                 self.collector.report.remote(
                     self.context.world_rank,
@@ -310,6 +324,8 @@ class _Session:
                     step_rec,
                 )
             )
+            if timer is not None:
+                timer.note_report(time.perf_counter() - t_rpc)
             # the collector doubles as the executor's control plane: a
             # non-bool int response is an abort generation — a peer rank
             # died and the executor wants every survivor to unwind NOW

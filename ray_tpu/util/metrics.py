@@ -15,6 +15,7 @@ stage bandwidth, telemetry drop counters) in Prometheus text format.
 
 from __future__ import annotations
 
+import bisect
 import json
 import threading
 from typing import Dict, List, Optional, Tuple
@@ -23,61 +24,160 @@ from ray_tpu._private.worker import get_runtime
 
 _NS = "metrics"
 _lock = threading.Lock()
-# local shadow (shipped in batches by the telemetry flusher): name ->
-# {labels_json: value}
+# local shadow, the process's record of every series: name -> {labels_json:
+# value}. Updated in place under ``_lock``; a histogram's value is a dict
+# (count, sum, buckets, boundaries). The telemetry flusher snapshots the
+# metrics marked dirty once per interval (``_collect_dirty``)
 _local: Dict[str, Dict[str, object]] = {}
+# name -> (kind, description) of every metric constructed here, and the names
+# updated since the flusher's last snapshot
+_meta: Dict[str, Tuple[str, str]] = {}
+_dirty: set = set()
 
 
-def _enqueue(name: str, kind: str, description: str, data: Dict[str, object]):
-    """Queue this metric's latest snapshot for the next batched flush (one
-    KV write per interval per metric, not per record). Loss is accounted by
-    ``ray_tpu_telemetry_dropped_total``, not swallowed."""
+def _collect_dirty() -> Dict[str, Tuple[str, str, dict]]:
+    """The flusher's side: one snapshot of each metric updated since the
+    last call (one KV write per interval per metric, however many records
+    landed). Histogram entries are copied so the batch can be pickled while
+    the series move on."""
+    with _lock:
+        names = list(_dirty)
+        _dirty.clear()
+        out = {}
+        for name in names:
+            kind, description = _meta.get(name, ("untyped", ""))
+            out[name] = (kind, description, {
+                k: (dict(v, buckets=list(v["buckets"])) if isinstance(v, dict) else v)
+                for k, v in _local.get(name, {}).items()
+            })
+    return out
+
+
+def _ensure_flusher() -> None:
+    """Off the record path: where a metric or a series is made. The
+    flusher ships what the record path only marks."""
     from ray_tpu._private import telemetry
 
-    telemetry.record_metric(name, kind, description, data)
+    if telemetry.enabled():
+        telemetry.get_buffer().ensure_flusher()
+
+
+class _Bound:
+    """One series of a metric (a fixed label set): the label key is built
+    once, and an update is a dict store under the module lock plus a set
+    add. Nothing here serialises, copies or calls into the telemetry plane,
+    so a hot loop can afford one per step."""
+
+    __slots__ = ("_name", "_key", "_series")
+
+    def __init__(self, metric: "_Metric", key: str):
+        self._name = metric._name
+        self._key = key
+        self._series = _local[metric._name]
+
+
+class _BoundCounter(_Bound):
+    __slots__ = ()
+
+    def inc(self, value: float = 1.0) -> None:
+        series, key = self._series, self._key
+        with _lock:
+            series[key] = series.get(key, 0.0) + value
+            _dirty.add(self._name)
+
+
+class _BoundGauge(_Bound):
+    __slots__ = ()
+
+    def set(self, value: float) -> None:
+        with _lock:
+            self._series[self._key] = value
+            _dirty.add(self._name)
+
+
+class _BoundHistogram(_Bound):
+    __slots__ = ("_boundaries",)
+
+    def __init__(self, metric: "Histogram", key: str):
+        super().__init__(metric, key)
+        self._boundaries = metric._boundaries
+
+    def observe(self, value: float) -> None:
+        self.observe_many((value,))
+
+    def observe_many(self, values) -> None:
+        if not values:
+            return
+        bounds = self._boundaries
+        with _lock:
+            entry = self._series.get(self._key)
+            if entry is None:
+                entry = self._series[self._key] = {
+                    "count": 0,
+                    "sum": 0.0,
+                    "buckets": [0] * (len(bounds) + 1),
+                    "boundaries": bounds,
+                }
+            buckets = entry["buckets"]
+            for value in values:
+                entry["count"] += 1
+                entry["sum"] += value
+                # first bound >= value; past the last one, the overflow bucket
+                buckets[bisect.bisect_left(bounds, value)] += 1
+            _dirty.add(self._name)
 
 
 class _Metric:
     KIND = "untyped"
+    _BOUND = _Bound
 
     def __init__(self, name: str, description: str = "", tag_keys: Tuple[str, ...] = ()):
         self._name = name
         self._description = description
         self._tag_keys = tuple(tag_keys)
         self._default_tags: Dict[str, str] = {}
+        # label items -> bound series, so the un-bound API builds a label
+        # key once per label set and not once per record
+        self._bound: Dict[tuple, _Bound] = {}
         with _lock:
             _local.setdefault(name, {})
+            _meta[name] = (self.KIND, description)
+        _ensure_flusher()
 
     def set_default_tags(self, tags: Dict[str, str]):
         self._default_tags = dict(tags)
+        self._bound = {}
         return self
 
     def _key(self, tags: Optional[Dict[str, str]]) -> str:
         merged = {**self._default_tags, **(tags or {})}
         return json.dumps(merged, sort_keys=True)
 
-    def _store(self, key: str, value):
-        with _lock:
-            _local[self._name][key] = value
-            snapshot = dict(_local[self._name])
-        _enqueue(self._name, self.KIND, self._description, snapshot)
+    def bind(self, tags: Optional[Dict[str, str]] = None):
+        """The series for ``tags`` as a handle whose updates cost a dict
+        store: build it once outside the loop, update it inside."""
+        ident = tuple(sorted(tags.items())) if tags else ()
+        bound = self._bound.get(ident)
+        if bound is None:
+            bound = self._bound[ident] = self._BOUND(self, self._key(tags))
+            _ensure_flusher()
+        return bound
 
 
 class Counter(_Metric):
     KIND = "counter"
+    _BOUND = _BoundCounter
 
     def inc(self, value: float = 1.0, tags: Optional[Dict[str, str]] = None):
-        key = self._key(tags)
-        with _lock:
-            current = _local[self._name].get(key, 0.0)
-        self._store(key, current + value)
+        self.bind(tags).inc(value)
 
 
 class Gauge(_Metric):
     KIND = "gauge"
+    _BOUND = _BoundGauge
 
     def set(self, value: float, tags: Optional[Dict[str, str]] = None):
-        self._store(self._key(tags), value)
+        self.bind(tags).set(value)
 
 
 # default histogram grid: sub-millisecond buckets resolve dispatch-path
@@ -140,41 +240,19 @@ def resolve_boundaries(name: str, explicit: Optional[List[float]] = None) -> Lis
 
 class Histogram(_Metric):
     KIND = "histogram"
+    _BOUND = _BoundHistogram
 
     def __init__(self, name, description="", boundaries: Optional[List[float]] = None,
                  tag_keys: Tuple[str, ...] = ()):
-        super().__init__(name, description, tag_keys)
         self._boundaries = resolve_boundaries(name, boundaries)
+        super().__init__(name, description, tag_keys)
 
     def observe(self, value: float, tags: Optional[Dict[str, str]] = None):
-        self.observe_many((value,), tags)
+        self.bind(tags).observe_many((value,))
 
     def observe_many(self, values, tags: Optional[Dict[str, str]] = None):
-        """Fold a batch of observations in with ONE entry copy + snapshot
-        enqueue (observe() per value pays a json round-trip each — hot
-        per-step callers like the train step plane accumulate locally and
-        flush batches through here)."""
-        if not values:
-            return
-        key = self._key(tags)
-        with _lock:
-            entry = _local[self._name].get(key) or {
-                "count": 0,
-                "sum": 0.0,
-                "buckets": [0] * (len(self._boundaries) + 1),
-            }
-            entry = json.loads(json.dumps(entry))  # copy
-        for value in values:
-            entry["count"] += 1
-            entry["sum"] += value
-            for i, b in enumerate(self._boundaries):
-                if value <= b:
-                    entry["buckets"][i] += 1
-                    break
-            else:
-                entry["buckets"][-1] += 1
-        entry["boundaries"] = self._boundaries
-        self._store(key, entry)
+        """Fold a batch of observations in under one lock hold."""
+        self.bind(tags).observe_many(values)
 
 
 def _sync_cluster_telemetry(rt) -> None:

@@ -1,0 +1,424 @@
+"""The clock inside the two hot loops: the engine's loop records, request
+spans and counters, the trainer's report stage and step records, the files
+they leave under ``<session_dir>/loops/``, the bound metric handles they are
+folded through, and the named scopes of the device programs. Tiny sizes, CPU.
+"""
+
+import glob
+import json
+import os
+import sys
+import time
+
+import pytest
+
+jax = pytest.importorskip("jax")
+import jax.numpy as jnp  # noqa: E402
+
+import ray_tpu  # noqa: E402
+from ray_tpu import train  # noqa: E402
+from ray_tpu._private import looplog, telemetry  # noqa: E402
+from ray_tpu._private.profiling import traced_section  # noqa: E402
+from ray_tpu.models import generation as G  # noqa: E402
+from ray_tpu.models.transformer import TransformerConfig, init_params  # noqa: E402
+from ray_tpu.serve.exceptions import DeploymentOverloadedError  # noqa: E402
+from ray_tpu.serve.llm import engine as engine_mod  # noqa: E402
+from ray_tpu.serve.llm.engine import EngineConfig, InferenceEngine  # noqa: E402
+from ray_tpu.util import metrics  # noqa: E402
+
+CFG = TransformerConfig(
+    vocab_size=97, d_model=32, n_layers=2, n_heads=4, n_kv_heads=2, d_ff=64,
+    max_seq_len=128, dtype=jnp.float32,
+)
+ECFG = EngineConfig(
+    block_size=4, num_blocks=64, max_batch=3, max_blocks_per_seq=16, max_waiting=16,
+    stream_timeout_s=60.0,
+)
+
+
+@pytest.fixture(scope="module")
+def params():
+    return init_params(jax.random.PRNGKey(0), CFG)
+
+
+def _series(name, deployment):
+    """This process's series of one metric for one deployment label."""
+    with metrics._lock:
+        return {k: (dict(v) if isinstance(v, dict) else v)
+                for k, v in metrics._local.get(name, {}).items() if f'"{deployment}"' in k}
+
+
+def _read_loops(session_dir, prefix):
+    out = []
+    for path in sorted(glob.glob(os.path.join(session_dir, "loops", prefix + "*.jsonl"))):
+        with open(path) as f:
+            out.extend(json.loads(line) for line in f)
+    return out
+
+
+# -- the engine's loop records and counters ----------------------------------
+
+
+def test_loop_records_are_monotone_bounded_and_agree_with_the_series(params, monkeypatch, ray_start_regular):
+    monkeypatch.setattr(engine_mod, "LOOP_RING", 8)
+    eng = InferenceEngine(params, CFG, ECFG, deployment="loop-rec")
+    try:
+        streams = [eng.submit([3 + i, 5, 7, 11][: 2 + i % 3], max_new_tokens=6 + i) for i in range(5)]
+        outs = [s.tokens() for s in streams]
+        assert [len(o) for o in outs] == [6, 7, 8, 9, 10]
+        assert all(s.ttft_s is not None and s.ttft_s > 0 for s in streams)
+        deadline = time.time() + 10
+        while eng._has_active() and time.time() < deadline:
+            time.sleep(0.01)
+        time.sleep(0.05)  # the loop folds its last iteration after the last token is out
+        stats = eng.loop_stats(records=10_000)
+    finally:
+        eng.shutdown()
+    fields = stats["fields"]
+    recs = [dict(zip(fields, r)) for r in stats["records"]]
+    assert recs and len(recs) + len(stats["requests"]) == 8  # the ring is bounded
+    assert eng.decode_steps > 8  # and had more to hold than it keeps
+    assert eng.loop_stats(records=2)["records"] == stats["records"][-2:]
+    for r in recs:
+        stamps = [r[k] for k in ("t_loop", "t_admit_end", "t_result", "t_retire_end",
+                                 "t_dispatch", "t_dispatch_end", "t_emit_end") if r[k]]
+        assert stamps == sorted(stamps), r  # phases in order on one clock
+        assert abs(r["t_loop"] / 1e9 - time.time()) < 600  # which is the wall clock, in ns
+    assert [r["t_loop"] for r in recs] == sorted(r["t_loop"] for r in recs)
+    assert [r["step"] for r in recs] == sorted(r["step"] for r in recs)
+    # the series count what was done: a step a dispatch, the tokens that came out
+    step = next(iter(_series("ray_tpu_llm_decode_step_ms", "loop-rec").values()))
+    assert step["count"] == eng.decode_steps == recs[-1]["step"] and step["sum"] > 0
+    assert sum(step["buckets"]) == step["count"]
+    tokens = _series("ray_tpu_llm_tokens_total", "loop-rec")
+    assert sum(v for k, v in tokens.items() if '"decode"' in k) == sum(len(o) for o in outs)
+    assert sum(v for k, v in tokens.items() if '"prefill"' in k) == sum([2, 3, 4, 2, 3])
+    assert _series("ray_tpu_llm_shed_total", "loop-rec") == {}
+
+
+def test_live_sequences_over_records_are_the_decode_tokens_less_first_tokens(params, ray_start_regular):
+    eng = InferenceEngine(params, CFG, ECFG, deployment="loop-live")
+    try:
+        outs = [s.tokens() for s in [eng.submit([2, 3, 4 + i], max_new_tokens=4 + 2 * i) for i in range(4)]]
+        time.sleep(0.1)
+        stats = eng.loop_stats(records=10_000)
+    finally:
+        eng.shutdown()
+    live, prefills = (stats["fields"].index(k) for k in ("live", "prefills"))
+    first_tokens = sum(r[prefills] for r in stats["records"])
+    assert first_tokens == 4 == len(stats["requests"])
+    tokens = _series("ray_tpu_llm_tokens_total", "loop-live")
+    decode_tokens = sum(v for k, v in tokens.items() if '"decode"' in k)
+    assert sum(r[live] for r in stats["records"]) == decode_tokens - first_tokens
+    assert decode_tokens == sum(len(o) for o in outs)
+    assert eng.decode_steps == sum(1 for r in stats["records"] if r[live])
+    # the phases, summed from the records when read
+    ph = stats["phases"]
+    assert all(ph[k]["sum_ns"] >= ph[k]["max_ns"] > 0
+               for k in ("queue_wait", "prefill", "prefill_stall", "device_wait", "dispatch_gap", "emit"))
+    assert ph["queue_wait"]["count"] == ph["prefill"]["count"] == 4
+    assert ph["device_wait"]["count"] == eng.decode_steps and ph["emit"]["count"] == len(stats["records"])
+    reason = stats["request_fields"].index("reason")
+    assert [r[reason] for r in stats["requests"]] == ["length"] * 4
+
+
+# -- request spans, under the caller's span -----------------------------------
+
+
+def test_every_ended_request_leaves_spans_under_the_callers_span(params, ray_start_regular):
+    """Finished, failed and shed: each leaves the spans of the phases it
+    reached, with the caller's trace id and the caller's span as parent;
+    ``ray_tpu.trace`` shows them; the head wrote the records to disk."""
+    small = EngineConfig(block_size=4, num_blocks=8, max_batch=2, max_blocks_per_seq=8, max_waiting=4)
+    eng = InferenceEngine(params, CFG, small, deployment="loop-span")
+    real_prefill = eng._prefill
+
+    def failing_prefill(p, toks, *rest):
+        if int(toks[0, 0]) == 96:  # the request marked to fail
+            raise RuntimeError("prefill blew up")
+        return real_prefill(p, toks, *rest)
+
+    eng._prefill = failing_prefill
+    try:
+        with traced_section("serve:replica:stand-in") as _:
+            from ray_tpu.util import tracing
+
+            ctx = tracing.get_current_context()
+            ok = eng.submit([5, 6, 7], max_new_tokens=5)
+            assert len(ok.tokens()) == 5
+            bad = eng.submit([96, 6], max_new_tokens=3)
+            with pytest.raises(RuntimeError, match="prefill blew up"):
+                bad.tokens()
+            with pytest.raises(DeploymentOverloadedError):
+                eng.submit([1] * 20, max_new_tokens=12)  # 8 blocks of 7 usable
+        time.sleep(0.1)
+        stats = eng.loop_stats()
+        reason = stats["request_fields"].index("reason")
+        assert [r[reason] for r in stats["requests"]] == ["length", "error", "shed_blocks"]
+        assert sum(_series("ray_tpu_llm_shed_total", "loop-span").values()) == 1
+    finally:
+        eng.shutdown()
+    t = None
+    for _ in range(20):
+        t = ray_tpu.trace(ctx.trace_id)
+        if sum(1 for s in t.spans.values() if (s.name or "").startswith("llm.")) >= 6:
+            break
+        time.sleep(0.2)
+    llm = [s for s in t.spans.values() if (s.name or "").startswith("llm.")]
+    assert all(s.parent_id == ctx.span_id and s.trace_id == ctx.trace_id for s in llm)
+    by_request = {}
+    for s in llm:
+        by_request.setdefault(s.extra["request"], {})[s.name] = s
+    done, failed, shed = by_request[ok.request_id], by_request[bad.request_id], by_request[-1]
+    assert set(done) == {"llm.queue_wait", "llm.prefill", "llm.decode"}
+    assert done["llm.decode"].extra["finish_reason"] == "length"
+    assert done["llm.decode"].extra["tokens"] == 5 and done["llm.decode"].extra["steps"] == 4
+    assert done["llm.prefill"].extra["bucket"] == 8 and done["llm.prefill"].extra["prompt_len"] == 3
+    assert done["llm.queue_wait"].end <= done["llm.prefill"].start + 1e-6
+    assert done["llm.prefill"].end <= done["llm.decode"].start + 1e-6
+    # the stream's TTFT is taken from the same stamps as the spans
+    assert ok.ttft_s == pytest.approx(done["llm.prefill"].end - done["llm.queue_wait"].start, abs=1e-5)
+    assert set(failed) == {"llm.queue_wait", "llm.prefill"}
+    assert failed["llm.prefill"].extra["finish_reason"] == "error"
+    assert set(shed) == {"llm.queue_wait"} and shed["llm.queue_wait"].extra["finish_reason"] == "shed_blocks"
+    # the replica stand-in's span is their parent in the tree
+    parent = t.spans[ctx.span_id]
+    assert {c.span_id for c in parent.children} >= {s.span_id for s in llm}
+
+    session_dir = ray_start_regular.node.session_dir
+    recs = _read_loops(session_dir, f"llm-loop-span-{os.getpid()}")
+    reqs = {r["request"]: r for r in recs if r["kind"] == "llm_request"}
+    assert reqs[ok.request_id]["reason"] == "length" and reqs[ok.request_id]["trace_id"] == ctx.trace_id
+    assert reqs[bad.request_id]["reason"] == "error" and reqs[-1]["reason"] == "shed_blocks"
+    assert 0 < reqs[ok.request_id]["t_submit"] <= reqs[ok.request_id]["t_admit"] <= reqs[ok.request_id]["t_first"]
+    steps = [r for r in recs if r["kind"] == "llm_step"]
+    assert steps and set(steps[0]) == {"kind", *looplog.LLM_STEP_FIELDS}
+    assert looplog.last_dir == os.path.join(session_dir, "loops")
+
+
+def test_a_request_through_llm_deployment_shows_its_phases_under_the_replicas_span():
+    from ray_tpu import serve
+    from ray_tpu.serve.llm import TINY_MODEL, llm_deployment
+
+    ray_tpu.init(num_cpus=4, ignore_reinit_error=True)
+    try:
+        engine_cfg = dict(block_size=4, num_blocks=128, max_batch=3, max_blocks_per_seq=16, max_waiting=16)
+        serve.run(llm_deployment(TINY_MODEL, engine_cfg, deployment_name="llm"), name="loopapp", route_prefix=None)
+        h = serve.get_app_handle("loopapp")
+        assert len(list(h.options(stream=True).generate.remote([3, 1, 4, 1, 5], max_new_tokens=6))) == 6
+        # the replica's own view of its loop, beside kv_stats
+        stats = h.loop_stats.remote().result(timeout_s=60)
+        assert stats["deployment"] == "llm" and stats["phases"]["device_wait"]["count"] >= 5
+        assert [dict(zip(stats["request_fields"], r))["tokens"] for r in stats["requests"]] == [6]
+        found = None
+        deadline = time.time() + 30
+        while found is None and time.time() < deadline:
+            for digest in ray_tpu.recent_traces(limit=30):
+                t = ray_tpu.trace(digest["trace_id"])
+                names = {s.name for s in t.spans.values()}
+                if {"llm.queue_wait", "llm.prefill", "llm.decode"} <= names:
+                    found = t
+                    break
+            else:
+                time.sleep(0.3)
+        assert found is not None, "no trace of the request shows the engine's phases"
+        replica = next(s for s in found.spans.values() if (s.name or "").startswith("serve:replica:llm.generate"))
+        phases = [s for s in found.spans.values() if (s.name or "").startswith("llm.")]
+        assert len(phases) == 3 and all(s.parent_id == replica.span_id for s in phases)
+        assert "llm.decode" in found.summary()
+    finally:
+        serve.shutdown()
+        ray_tpu.shutdown()
+
+
+def test_with_telemetry_off_nothing_is_recorded_or_written(params):
+    rt = ray_tpu.init(num_cpus=1, _system_config={"telemetry_enabled": False}, ignore_reinit_error=True)
+    try:
+        from ray_tpu._private import stepplane
+
+        buf = telemetry.get_buffer()
+        before = (len(buf._spans), sum(len(v) for v in buf._loops.values()))
+        eng = InferenceEngine(params, CFG, ECFG, deployment="loop-off")
+        try:
+            with traced_section("serve:replica:stand-in"):
+                assert len(eng.submit([5, 6, 7], max_new_tokens=4).tokens()) == 4
+            time.sleep(0.1)
+            stats = eng.loop_stats()
+        finally:
+            eng.shutdown()
+        # the engine served, and recorded nothing of its loop ...
+        assert eng.decode_steps >= 3 and stats["records"] == [] and stats["requests"] == []
+        assert all(p["count"] == 0 for p in stats["phases"].values())
+        # ... and nothing left the engine: no span, no loop record, no step timer, no file
+        assert (len(buf._spans), sum(len(v) for v in buf._loops.values())) == before
+        assert stepplane.make_timer("off", 0, 1) is None
+        assert not os.path.exists(os.path.join(rt.node.session_dir, "loops"))
+    finally:
+        ray_tpu.shutdown()
+
+
+# -- bound metric handles ------------------------------------------------------
+
+
+def test_bound_handles_and_the_unbound_api_give_the_same_text(ray_start_regular):
+    def drive(prefix, bound: bool):
+        c = metrics.Counter(f"{prefix}_total", "c", tag_keys=("who",))
+        g = metrics.Gauge(f"{prefix}_level", "g", tag_keys=("who",))
+        h = metrics.Histogram(f"{prefix}_ms", "h", boundaries=[1, 10, 100], tag_keys=("who",))
+        tags = {"who": "x"}
+        if bound:
+            cb, gb, hb = c.bind(tags), g.bind(tags), h.bind(tags)
+            for v in (0.5, 1, 7, 250):
+                cb.inc()
+                cb.inc(2.5)
+                gb.set(v)
+                hb.observe(v)
+            hb.observe_many([3, 30])
+        else:
+            for v in (0.5, 1, 7, 250):
+                c.inc(tags=tags)
+                c.inc(2.5, tags=tags)
+                g.set(v, tags=tags)
+                h.observe(v, tags=tags)
+            h.observe_many([3, 30], tags=tags)
+
+    drive("lt_bound", True)
+    drive("lt_plain", False)
+    text = metrics.prometheus_text()
+    bound = sorted(line.replace("lt_bound", "X") for line in text.splitlines() if "lt_bound" in line)
+    plain = sorted(line.replace("lt_plain", "X") for line in text.splitlines() if "lt_plain" in line)
+    assert bound == plain and len(bound) == 3 * 2 + 1 + 1 + 6
+    assert 'X_total{who="x"} 14.0' in bound
+    assert 'X_ms_bucket{who="x",le="1"} 2' in bound and 'X_ms_bucket{who="x",le="+Inf"} 6' in bound
+    assert 'X_ms_sum{who="x"} 291.5' in bound
+
+
+def test_a_bound_update_calls_neither_json_nor_telemetry(ray_start_regular):
+    c = metrics.Counter("lt_hot_total", "c", tag_keys=("who",)).bind({"who": "x"})
+    g = metrics.Gauge("lt_hot_level", "g", tag_keys=("who",)).bind({"who": "x"})
+    h = metrics.Histogram("lt_hot_ms", "h", tag_keys=("who",)).bind({"who": "x"})
+    h.observe(1.0)  # the entry exists: the steady state is what a loop pays
+    called = []
+
+    def profiler(frame, event, arg):
+        if event == "call":
+            called.append(frame.f_code.co_filename)
+        elif event == "c_call":
+            called.append(getattr(arg, "__module__", None) or "")
+
+    sys.setprofile(profiler)
+    try:
+        for i in range(50):
+            c.inc()
+            g.set(float(i))
+            h.observe(float(i))
+    finally:
+        sys.setprofile(None)
+    assert called  # the updates were seen
+    offenders = [f for f in called if "json" in f or "telemetry" in f]
+    assert offenders == [], sorted(set(offenders))
+    # one snapshot of each dirty metric per flush, however many records landed
+    snap = telemetry._dirty_metrics()
+    assert {"lt_hot_total", "lt_hot_level", "lt_hot_ms"} <= set(snap)
+    assert snap["lt_hot_total"][2] == {'{"who": "x"}': 50.0}
+    assert not {"lt_hot_total", "lt_hot_level", "lt_hot_ms"} & set(telemetry._dirty_metrics())
+
+
+# -- the trainer's report stage and step records --------------------------------
+
+
+def test_step_records_time_the_report_and_reach_the_loop_and_the_disk(ray_start_regular, tmp_path):
+    from ray_tpu.train import JaxTrainer, RunConfig, ScalingConfig
+    from ray_tpu.util import state
+
+    def loop(config=None):
+        ctx = train.get_context()
+        seen = []
+        for i in range(5):
+            time.sleep(0.02)
+            last = ctx.get_last_step()
+            seen.append(None if last is None else (last["step"], last["stages"]["report_ms"], last["wall_ms"]))
+            train.report({"i": i, "seen": list(seen)})
+
+    res = JaxTrainer(
+        loop, scaling_config=ScalingConfig(num_workers=1),
+        run_config=RunConfig(storage_path=str(tmp_path), name="loop_steps"),
+    ).fit()
+    assert res.error is None
+    seen = res.metrics["seen"]
+    # the loop is handed each closed step as it goes: none before the first report returns
+    assert seen[0] is None and [s[0] for s in seen[1:]] == [1, 2, 3, 4]
+    assert all(s[1] > 0 and s[2] >= 20 for s in seen[1:])
+    d = state.train_run("loop_steps")
+    for srec in d["steps"]:
+        rec = srec["ranks"]["0"]
+        st = rec["stages"]
+        assert st["report_ms"] > 0
+        assert sum(st.values()) == pytest.approx(rec["wall_ms"], rel=0.1)
+        # the step's bounds on the profiler's clock
+        assert rec["t2_ns"] - rec["t0_ns"] == pytest.approx(rec["wall_ms"] * 1e6, rel=0.05)
+        assert abs(rec["t2_ns"] / 1e9 - rec["t2"]) < 1e-3
+    ray_tpu.timeline()  # a cluster-wide flush: the session's last record rides telemetry
+    lines = _read_loops(ray_start_regular.node.session_dir, "train-loop_steps-rank0")
+    assert sorted(r["step"] for r in lines) == [1, 2, 3, 4, 5]
+    assert all(r["kind"] == "train_step" and r["stages"]["report_ms"] > 0 for r in lines)
+
+
+# -- named scopes in the device programs -----------------------------------------
+
+
+def _scoped(text: str, scope: str) -> bool:
+    """Whether some operation's location in the lowered module lies under
+    ``scope`` (``block/mlp``; a transformed scope reads ``jvp(head)``)."""
+    import re
+
+    return re.search(r'loc\("(?:[^"]*[/(])?' + re.escape(scope) + r'[/)"]', text) is not None
+
+
+def test_the_lowered_train_step_and_decode_step_carry_the_scopes(params):
+    from ray_tpu.parallel.mesh import MeshConfig, create_mesh
+    from ray_tpu.parallel.spmd import build_lm_train_step
+
+    mesh = create_mesh(MeshConfig(data=1), devices=jax.devices()[:1])
+    bundle = build_lm_train_step(CFG, mesh)
+    state = jax.eval_shape(lambda: bundle.init_fn(jax.random.PRNGKey(0)))
+    tok = jax.ShapeDtypeStruct((2, 16), jnp.int32)
+    step = bundle.step_fn.lower(state, tok, tok).as_text(debug_info=True)
+    for scope in ("block/attn", "block/mlp", "head", "loss", "optimizer"):
+        assert _scoped(step, scope), scope
+    # the backward pass keeps them: its recomputed block is still the block
+    assert _scoped(step, "rematted_computation/block/mlp") or "transpose(jvp(block))" in step
+
+    _prefill, _decode, greedy = G.make_paged_fns(CFG, block_size=4)
+    pool = G.init_paged_pool(CFG, 16, 4)
+    b = 3
+    decode = greedy.lower(
+        params, jnp.zeros((b,), jnp.int32), jnp.zeros((b,), jnp.int32), jnp.zeros((b, 8), jnp.int32),
+        pool, jnp.ones((b,), bool),
+    ).as_text(debug_info=True)
+    for scope in ("block/paged_scatter", "block/paged_gather", "block/paged_attn", "block/mlp", "head"):
+        assert _scoped(decode, scope), scope
+
+
+# -- where the records land ---------------------------------------------------------
+
+
+def test_sessions_land_under_the_process_temporary_directory(monkeypatch, tmp_path):
+    """Two checkouts run with a ``TMPDIR`` each keep their sessions, and the
+    loop records in them, apart; the flag still overrides."""
+    import tempfile
+
+    from ray_tpu._private.config import Config
+
+    monkeypatch.setattr(tempfile, "tempdir", str(tmp_path))
+    assert Config().session_dir_root == os.path.join(str(tmp_path), "ray_tpu_sessions")
+    assert Config.from_env().session_dir_root == os.path.join(str(tmp_path), "ray_tpu_sessions")
+    monkeypatch.setenv("RAY_TPU_SESSION_DIR_ROOT", str(tmp_path / "elsewhere"))
+    assert Config.from_env().session_dir_root == str(tmp_path / "elsewhere")
+    log = looplog.LoopLog(os.path.join(Config.from_env().session_dir_root, "session_x"))
+    monkeypatch.setattr(looplog, "last_dir", None)
+    log.ingest({"llm-a-1": [("s", *range(len(looplog.LLM_STEP_FIELDS))), ("bogus",)]})
+    log.close()
+    assert looplog.last_dir == str(tmp_path / "elsewhere" / "session_x" / "loops")
+    (line,) = open(os.path.join(looplog.last_dir, "llm-a-1.jsonl")).read().splitlines()
+    assert json.loads(line) == {"kind": "llm_step", **dict(zip(looplog.LLM_STEP_FIELDS, range(11)))}

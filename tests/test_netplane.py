@@ -230,6 +230,10 @@ def test_socket_broadcast_ledger_and_trace_join():
             ctx = tracing.get_current_context()
             return int(x[0]) + x.nbytes, ctx.trace_id if ctx else None
 
+        # a live worker on every reader node first: the three reads below then
+        # start together, so the third finds both source slots taken and relays
+        # (readers that spawn one after another on a loaded machine do not)
+        ray_tpu.get([read.remote(np.zeros(1, np.int64)) for _ in range(3)], timeout=600)
         blob = ray_tpu.put(np.full(2 * 1024 * 1024, 7, dtype=np.int64))
         out = ray_tpu.get(
             [read.remote(blob) for _ in range(3)], timeout=600

@@ -27,6 +27,7 @@ _STAGES = (
     "compute_ms",
     "collective_wait_ms",
     "checkpoint_stall_ms",
+    "report_ms",
     "other_ms",
 )
 
