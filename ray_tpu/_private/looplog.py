@@ -37,6 +37,7 @@ LLM_STEP_FIELDS = (
     "live",  # sequences in the dispatched step
     "prefills",  # prefills done in the iteration
     "fused",  # 1 = the greedy (on-device argmax) program ran
+    "kv_blocks",  # live KV blocks of the dispatched sequences: what the step's attention reads
 )
 # one finished, failed or shed request
 LLM_REQUEST_FIELDS = (

@@ -31,6 +31,7 @@ import jax.numpy as jnp
 
 from ray_tpu.models.transformer import TransformerConfig
 from ray_tpu.ops.layers import apply_rope, gelu, rms_norm, rope_frequencies, swiglu
+from ray_tpu.ops.paged_attention import can_use_paged_kernel, paged_decode_attention
 
 _NEG_INF = -1e30
 
@@ -221,9 +222,19 @@ def _forward_paged(
 ):
     """Run the model over ``tokens`` (B,S) at per-sequence absolute
     ``positions`` (B,S), scattering k/v into the block pool and attending
-    over each sequence's gathered blocks. ``write_mask`` (B,S) diverts
-    padded rows to the null block; ``block_tables`` (B, max_blocks) maps
-    block index -> pool block (0-padded). Returns (logits (B,S,V), pool)."""
+    over each sequence's blocks. ``write_mask`` (B,S) diverts padded rows
+    to the null block; ``block_tables`` (B, max_blocks) maps block index ->
+    pool block (0-padded). Returns (logits (B,S,V), pool).
+
+    Attention takes one of two paths, by platform and static shape alone
+    (``ops.paged_attention.can_use_paged_kernel``): with S == 1 on a TPU
+    (the decode steps) the Pallas kernel reads each sequence's live blocks
+    in place, positions [0, position] of a row whose ``write_mask`` is set
+    and nothing of one whose mask is clear (its output is 0); otherwise
+    (every prefill, the CPU backend) the table's ``max_blocks x
+    block_size`` rows are gathered out of the pool and attended to as a
+    masked dense block (``_paged_attention``). A chosen kernel that fails
+    raises."""
     b, s = tokens.shape
     mb = block_tables.shape[1]
     x = params["embed"][tokens]
@@ -243,6 +254,8 @@ def _forward_paged(
         block_tables[:, :, None] * block_size
         + jnp.arange(block_size)[None, None, :]
     ).reshape(b, mb * block_size)
+    # positions [0, position] of a sequence count; an inactive slot has none
+    lengths = jnp.where(write_mask[:, 0], positions[:, 0] + 1, 0)
 
     # The pool rides in the scan CARRY (updated at a dynamic layer index),
     # not in the per-layer ys: stacked scan outputs allocate a fresh slab
@@ -272,13 +285,26 @@ def _forward_paged(
                 vw = jax.lax.bitcast_convert_type(vw, pv.dtype)
             pk = pk.at[li, write_slots].set(kw)
             pv = pv.at[li, write_slots].set(vw)
+        # One query position a sequence on a TPU (the decode steps): the
+        # kernel reads each sequence's live blocks where they lie in the
+        # pool. Anything else (every prefill, the CPU backend) gathers the
+        # table's rows. Chosen from platform and shape alone; a chosen
+        # kernel that fails raises.
+        use_kernel = can_use_paged_kernel(q, pk, block_size)
         with jax.named_scope("paged_gather"):
-            gk, gv = pk[li][gather_idx], pv[li][gather_idx]
-            if bits:
-                gk = jax.lax.bitcast_convert_type(gk, cfg.dtype)
-                gv = jax.lax.bitcast_convert_type(gv, cfg.dtype)
+            if not use_kernel:
+                gk, gv = pk[li][gather_idx], pv[li][gather_idx]
+                if bits:
+                    gk = jax.lax.bitcast_convert_type(gk, cfg.dtype)
+                    gv = jax.lax.bitcast_convert_type(gv, cfg.dtype)
         with jax.named_scope("paged_attn"):
-            att = _paged_attention(q, gk, gv, positions)
+            if use_kernel:
+                att = paged_decode_attention(
+                    q[:, 0], pk, pv, li, block_tables, lengths,
+                    block_size=block_size,
+                )[:, None]
+            else:
+                att = _paged_attention(q, gk, gv, positions)
         att_out = jnp.einsum("bshk,hkd->bsd", att, layer["wo"])
         with jax.named_scope("mlp"):
             if cfg.parallel_block:
@@ -319,6 +345,9 @@ def make_paged_fns(cfg: TransformerConfig, *, block_size: int):
 
     Shapes are static per (S, MB, B): the engine buckets prompt lengths
     and runs decode at a fixed max batch, so each compiles exactly once.
+    On a TPU the two decode steps attend through the paged-attention kernel
+    (``ops/paged_attention.py``: time follows the sequences' live blocks,
+    not MB); the prefill gathers (see ``_forward_paged``).
     """
 
     @functools.partial(jax.jit, donate_argnums=(3,))

@@ -457,16 +457,22 @@ class InferenceEngine:
         the ring holds, computed here on the reader's thread. ``queue_wait``
         and ``prefill`` are per request (submit -> popped -> first token),
         ``prefill_stall`` per iteration that prefilled, ``device_wait`` and
-        ``dispatch_gap`` per retired step, ``emit`` per iteration. Empty with
-        ``telemetry_enabled`` off."""
+        ``dispatch_gap`` per retired step, ``emit`` per iteration.
+        ``kv_blocks`` is what the dispatched steps' attention read: count of
+        steps, sum and maximum of their live KV blocks (over a step's ``live``
+        x ``max_blocks_per_seq`` it is the share of the tables that is live).
+        Empty with ``telemetry_enabled`` off."""
         ring = self._ring.copy()  # atomic against the loop's appends
         steps = [r[1:] for r in ring if r[0] == "s"]
         reqs = [r[1:] for r in ring if r[0] == "r"]
         spans: Dict[str, List[int]] = {
             k: [] for k in ("queue_wait", "prefill", "prefill_stall", "device_wait", "dispatch_gap", "emit")
         }
+        kv: List[int] = []
         for r in steps:
             d = dict(zip(LLM_STEP_FIELDS, r))
+            if d["live"]:
+                kv.append(d["kv_blocks"])
             if d["prefills"]:
                 spans["prefill_stall"].append(d["t_admit_end"] - d["t_loop"])
             if d["t_result"]:
@@ -488,6 +494,7 @@ class InferenceEngine:
             "requests": reqs[-n:] if n else [],
             "phases": {k: {"count": len(v), "sum_ns": sum(v), "max_ns": max(v, default=0)}
                        for k, v in spans.items()},
+            "kv_blocks": {"count": len(kv), "sum": sum(kv), "max": max(kv, default=0)},
         }
 
     def _record(self, rec: tuple) -> None:
@@ -589,14 +596,14 @@ class InferenceEngine:
             for slot_idx, run, reason in finishes:
                 self._detach_slot(slot_idx)
                 ended.append((run.req, reason, t_retire_end, run.generated))
-            t_dispatch = t_dispatch_end = live = fused = 0
+            t_dispatch = t_dispatch_end = live = fused = kv_blocks = 0
             if self._has_active():
                 t_dispatch = now()
                 with annotate("llm.dispatch", step=self.decode_steps + 1):
                     inflight = self._dispatch_step(mono(), ended)
                 t_dispatch_end = now()
                 if inflight is not None:
-                    live, fused = len(inflight[0]), int(inflight[2])
+                    live, fused, kv_blocks = len(inflight[0]), int(inflight[2]), inflight[5]
             # ---- the device is busy (or there is nothing for it to do) ----
             with annotate("llm.emit"):
                 for stream, tok in emissions:
@@ -620,7 +627,7 @@ class InferenceEngine:
             if self._tel is not None:
                 self._record((
                     "s", self.decode_steps, t_loop, t_admit_end, t_result, t_retire_end,
-                    t_dispatch, t_dispatch_end, now(), live, len(admits), fused,
+                    t_dispatch, t_dispatch_end, now(), live, len(admits), fused, kv_blocks,
                 ))
 
     def _fold_prefills(self, admits: List[tuple]) -> None:
@@ -732,8 +739,9 @@ class InferenceEngine:
 
     def _dispatch_step(self, t0: int, ended: List[tuple]):
         """Enqueue one decode step on the device and return without
-        waiting for it: ``(live slots, result, fused, t0, step number)``;
-        ``t0`` is the caller's ``perf_counter_ns`` at the top of the dispatch.
+        waiting for it: ``(live slots, result, fused, t0, step number, live
+        KV blocks)``; ``t0`` is the caller's ``perf_counter_ns`` at the top of
+        the dispatch, the last is what the step's attention reads.
         A batch where every sequence decodes greedily uses the fused-argmax
         step (B ints cross back to the host, not B x vocab logits)."""
         import numpy as np
@@ -746,6 +754,7 @@ class InferenceEngine:
         tables = np.zeros((b, mb), np.int32)
         active = np.zeros((b,), bool)
         live: List[int] = []
+        kv_blocks = 0
         fused = True
         for i, run in enumerate(self._slots):
             if run is None:
@@ -760,6 +769,7 @@ class InferenceEngine:
             tables[i] = run.table.as_list(mb)
             active[i] = True
             live.append(i)
+            kv_blocks += len(run.table.blocks)
             if run.req.temperature and run.req.temperature > 0:
                 fused = False
         fn = self._decode_greedy if fused else self._decode
@@ -777,7 +787,7 @@ class InferenceEngine:
                 self._fail_slot(i, e, ended)
             return None
         self.decode_steps += 1
-        return (live, out, fused, t0, self.decode_steps)
+        return (live, out, fused, t0, self.decode_steps, kv_blocks)
 
     def _retire_step(self, inflight, ended: List[tuple]) -> tuple:
         """Block on the in-flight step's result and fold it into the run

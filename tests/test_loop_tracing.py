@@ -118,6 +118,16 @@ def test_live_sequences_over_records_are_the_decode_tokens_less_first_tokens(par
                for k in ("queue_wait", "prefill", "prefill_stall", "device_wait", "dispatch_gap", "emit"))
     assert ph["queue_wait"]["count"] == ph["prefill"]["count"] == 4
     assert ph["device_wait"]["count"] == eng.decode_steps and ph["emit"]["count"] == len(stats["records"])
+    # what the steps' attention read: a sequence's blocks are those of its
+    # positions so far (prompt 3, block 4: one block until the step that writes
+    # position 4), summed over the dispatched slots
+    kv_blocks = stats["fields"].index("kv_blocks")
+    assert all((r[kv_blocks] > 0) == (r[live] > 0) for r in stats["records"])
+    assert all(r[live] <= r[kv_blocks] <= r[live] * ECFG.max_blocks_per_seq for r in stats["records"])
+    kv = stats["kv_blocks"]
+    assert kv["count"] == eng.decode_steps and kv["sum"] == sum(r[kv_blocks] for r in stats["records"])
+    want = sum(-(-(3 + 1 + t) // ECFG.block_size) for i in range(4) for t in range(4 + 2 * i - 1))
+    assert kv["sum"] == want and kv["max"] == max(r[kv_blocks] for r in stats["records"])
     reason = stats["request_fields"].index("reason")
     assert [r[reason] for r in stats["requests"]] == ["length"] * 4
 
@@ -421,4 +431,4 @@ def test_sessions_land_under_the_process_temporary_directory(monkeypatch, tmp_pa
     log.close()
     assert looplog.last_dir == str(tmp_path / "elsewhere" / "session_x" / "loops")
     (line,) = open(os.path.join(looplog.last_dir, "llm-a-1.jsonl")).read().splitlines()
-    assert json.loads(line) == {"kind": "llm_step", **dict(zip(looplog.LLM_STEP_FIELDS, range(11)))}
+    assert json.loads(line) == {"kind": "llm_step", **dict(zip(looplog.LLM_STEP_FIELDS, range(len(looplog.LLM_STEP_FIELDS))))}

@@ -1,0 +1,132 @@
+"""The decode step's paged-attention kernel (``ops/paged_attention.py``) in
+Pallas interpret mode on the CPU, against the path it replaces on a TPU: the
+table's rows gathered out of the pool and attended to as a masked dense block
+(``generation._paged_attention``). The kernel's compile for the chip is in
+``test_tpu_compile.py``; its speed is the benchmark's."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.models import generation as G
+from ray_tpu.ops.paged_attention import can_use_paged_kernel, paged_decode_attention
+
+# a chunk of the kernel is 16 of these blocks: a full table is two chunks and a half
+BLOCK, TABLE, LAYERS, POOL_BLOCKS, HEAD_DIM = 16, 40, 2, 176, 128
+FULL = BLOCK * TABLE
+# four sequences a case; 0 is an inactive slot
+LENGTHS = {
+    "one": [1, 2, 5, 9],
+    "a_block": [16, 32, 48, 16],
+    "a_block_and_one": [17, 33, 1, 49],
+    "a_full_table": [FULL, FULL - 1, FULL - BLOCK + 1, FULL],
+    "chunks": [16 * BLOCK, 16 * BLOCK + 1, 32 * BLOCK, 17 * BLOCK],  # whole, and one block into the next
+    "inactive_slots": [0, 40, 0, 7],
+}
+
+
+def _pools(dtype, kv_heads, seed):
+    """Two pools of random rows in ``dtype``, stored as the engine stores them."""
+    rng = np.random.default_rng(seed)
+    shape = (LAYERS, POOL_BLOCKS * BLOCK, kv_heads, HEAD_DIM)
+    storage = G._kv_storage_dtype(dtype)
+
+    def pool():
+        x = jnp.asarray(rng.standard_normal(shape), dtype)
+        return x if storage == dtype else jax.lax.bitcast_convert_type(x, storage)
+
+    return pool(), pool()
+
+
+def _tables(lengths, seed):
+    """Each sequence's blocks drawn without order from the pool (never the
+    null block 0), the rest of its table padded with 0."""
+    rng = np.random.default_rng(seed)
+    free = list(rng.permutation(np.arange(1, POOL_BLOCKS)))
+    tables = np.zeros((len(lengths), TABLE), np.int32)
+    for i, n in enumerate(lengths):
+        for j in range(-(-n // BLOCK)):
+            tables[i, j] = free.pop()
+    return tables
+
+
+@jax.jit
+def _kernel(q, pk, pv, tables, lengths):
+    return paged_decode_attention(q, pk, pv, 1, tables, lengths, block_size=BLOCK, interpret=True)
+
+
+@jax.jit
+def _gathered(q, pk, pv, tables, lengths):
+    idx = (tables[:, :, None] * BLOCK + jnp.arange(BLOCK)[None, None, :]).reshape(len(tables), -1)
+    gk, gv = pk[1][idx], pv[1][idx]
+    if gk.dtype != q.dtype:
+        gk, gv = (jax.lax.bitcast_convert_type(x, q.dtype) for x in (gk, gv))
+    return G._paged_attention(q[:, None], gk, gv, lengths[:, None] - 1)[:, 0]
+
+
+@pytest.mark.parametrize("case", list(LENGTHS))
+@pytest.mark.parametrize("n_rep", [1, 2])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["uint16_pool", "float32_pool"])
+def test_kernel_agrees_with_attention_over_the_gathered_rows(dtype, n_rep, case):
+    kv_heads = 32 // jnp.dtype(dtype).itemsize  # one sublane tile of the storage type
+    lengths = np.asarray(LENGTHS[case], np.int32)
+    pk, pv = _pools(dtype, kv_heads, seed=1)
+    tables = _tables(lengths, seed=2)
+    q = jnp.asarray(
+        np.random.default_rng(3).standard_normal((len(lengths), kv_heads * n_rep, HEAD_DIM)), dtype
+    )
+    got = np.asarray(_kernel(q, pk, pv, tables, lengths).astype(jnp.float32))
+    want = np.asarray(_gathered(q, pk, pv, tables, lengths).astype(jnp.float32))
+    active = lengths > 0
+    assert np.isfinite(got).all()
+    assert not got[~active].any()  # an inactive slot reads nothing and gives 0
+    # float32: the same sums in another order. bfloat16: the weights are
+    # rounded before the chunk's sum is divided by the whole, not after
+    tol = 2e-6 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(got[active], want[active], atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["uint16_pool", "float32_pool"])
+def test_a_sequence_reads_the_same_alone_and_among_neighbours(dtype):
+    """Bit for bit: in another slot, beside neighbours of other lengths whose
+    rows passed through the same buffers, with the pool's other blocks changed."""
+    kv_heads = 32 // jnp.dtype(dtype).itemsize
+    rng = np.random.default_rng(4)
+    pk, pv = _pools(dtype, kv_heads, seed=5)
+    for length in (1, 17, 300, FULL):
+        lengths = np.asarray([length, 0, 0, 0], np.int32)
+        tables = _tables(lengths, seed=6)
+        q = jnp.asarray(rng.standard_normal((4, kv_heads, HEAD_DIM)), dtype)
+        alone = np.asarray(_kernel(q, pk, pv, tables, lengths).astype(jnp.float32))[0]
+
+        crowd = np.asarray([FULL, 33, length, 100], np.int32)
+        crowd_tables = _tables(crowd, seed=7)
+        crowd_tables[2] = tables[0]
+        for row, n in zip((0, 1, 3), (FULL, 33, 100)):  # off the sequence's own blocks
+            spare = [b for b in range(1, POOL_BLOCKS) if b not in tables[0]]
+            crowd_tables[row, : -(-n // BLOCK)] = rng.permutation(spare)[: -(-n // BLOCK)]
+        among = _kernel(q[jnp.asarray([1, 2, 0, 3])], pk, pv, crowd_tables, crowd)
+        assert np.array_equal(alone, np.asarray(among.astype(jnp.float32))[2]), length
+
+
+GPTJ_Q, LLAMA7B_Q = (8, 1, 16, 256), (8, 1, 32, 128)
+
+
+@pytest.mark.parametrize(
+    "backend,q_shape,kv_heads,pool_dtype,want",
+    [
+        ("tpu", GPTJ_Q, 16, jnp.uint16, True),  # GPT-J-6B's decode step
+        ("tpu", LLAMA7B_Q, 32, jnp.uint16, True),
+        ("tpu", (8, 1, 8, 128), 8, jnp.float32, True),
+        ("cpu", GPTJ_Q, 16, jnp.uint16, False),  # tier-1, the rehearsals
+        ("tpu", (1, 512, 16, 256), 16, jnp.uint16, False),  # a prefill: S is the bucket
+        ("tpu", (8, 1, 64, 128), 8, jnp.uint16, False),  # Llama-2-70B: 8 kv heads are half a tile
+        ("tpu", (8, 1, 16, 64), 16, jnp.uint16, False),  # head_dim under a lane tile
+    ],
+)
+def test_the_path_is_chosen_by_platform_and_shape(monkeypatch, backend, q_shape, kv_heads, pool_dtype, want):
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    q = jax.ShapeDtypeStruct(q_shape, jnp.bfloat16)
+    pool = jax.ShapeDtypeStruct((2, 64 * 16, kv_heads, q_shape[-1]), pool_dtype)
+    assert can_use_paged_kernel(q, pool, 16) is want

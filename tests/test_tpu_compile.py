@@ -102,29 +102,43 @@ def test_flash_module_is_the_same_wherever_it_is_called_from(one_chip):
         assert here == there, launcher
 
 
-def test_paged_decode_and_prefill_at_gptj_widths(one_chip):
-    """serve.llm's two device programs (``make_paged_fns``) at GPT-J-6B's
-    published widths; 2 of the 28 layers (the scan body is the same)."""
+def test_paged_decode_and_prefill_at_gptj_widths(one_chip, monkeypatch):
+    """serve.llm's device programs (``make_paged_fns``) at GPT-J-6B's
+    published widths; 2 of the 28 layers (the scan body is the same). The
+    decode steps hold the paged-attention kernel and read the pool nowhere
+    else: no gathered copy of a table's rows, no layer cut out of the pool.
+    The prefill (S is the bucket) stays on the gather path."""
+    import re
+
     from ray_tpu.models import generation as G
     from ray_tpu.models.transformer import TransformerConfig, init_params
 
+    _steered_to_tpu(monkeypatch)
     cfg = TransformerConfig(n_layers=2, **GPTJ)
     block, blocks, batch, per_seq = 16, 384, 8, 64
-    prefill, _, decode_greedy = G.make_paged_fns(cfg, block_size=block)
+    prefill, decode, decode_greedy = G.make_paged_fns(cfg, block_size=block)
     params = _on(one_chip, jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)))
     pool = _on(one_chip, jax.eval_shape(lambda: G.init_paged_pool(cfg, blocks, block)))
 
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    decode_greedy.lower(
-        params, arg((batch,), jnp.int32), arg((batch,), jnp.int32),
-        arg((batch, per_seq), jnp.int32), pool, arg((batch,), jnp.bool_),
-    ).compile()
-    prefill.lower(
+    # results that are a table's rows (B, 1024, 16, 256) or one layer of the pool
+    slots = blocks * block
+    unwanted = {f"[{batch},{per_seq * block},16,256]"} | {f"[{lead}{slots},16,256]" for lead in ("", "1,", "2,")}
+    for step in (decode_greedy, decode):
+        text = step.lower(
+            params, arg((batch,), jnp.int32), arg((batch,), jnp.int32),
+            arg((batch, per_seq), jnp.int32), pool, arg((batch,), jnp.bool_),
+        ).compile().as_text()
+        assert "tpu_custom_call" in text and "paged_decode_attention" in text
+        made = re.findall(r"= \w+(\[[\d,]*\])\S* (?:gather|dynamic-slice|copy)\(", text)
+        assert made and not unwanted & set(made)
+    text = prefill.lower(
         params, arg((1, 512), jnp.int32), arg((1, per_seq), jnp.int32), pool,
         arg((), jnp.int32),
-    ).compile()
+    ).compile().as_text()
+    assert "tpu_custom_call" not in text
 
 
 def _steered_to_tpu(monkeypatch):
