@@ -70,18 +70,6 @@ def load_cell(workload: str) -> dict:
     }
 
 
-def model_kwargs(config: dict) -> dict:
-    """``TransformerConfig`` keyword arguments from a configuration file's
-    published keys (EleutherAI/gpt-j-6b ``config.json`` names)."""
-    out = dict(
-        vocab_size=config["vocab_size"], d_model=config["n_embd"], n_layers=config["n_layer"],
-        n_heads=config["n_head"], d_ff=config["n_inner"], max_seq_len=config["n_positions"],
-        parallel_block=True, use_swiglu=False, tie_embeddings=False, dtype=config["dtype"],
-    )
-    out.update(config.get("model_extra", {}))
-    return out
-
-
 def peaks_for(device_kind: str) -> dict:
     table = load_json(os.path.join(BENCH, "peaks.json"))["devices"]
     if device_kind not in table:

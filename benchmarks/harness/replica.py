@@ -52,24 +52,27 @@ def device_report(dev) -> dict:
 
 
 class BenchLLMServer(LLMServer):
-    def __init__(self, model_cfg, engine_cfg, *, weight_seed: int, deployment: str = "llm"):
+    def __init__(self, config: dict, *, weight_seed: int, deployment: str = "llm"):
+        """``config`` is the cell's configuration file: its family gives the
+        model arguments, the seeded weights and the plain reference."""
         import jax
 
-        from benchmarks.harness.weights import make_weights, seed_words
+        from benchmarks import families
+        from benchmarks.harness.common import Heartbeat
+        from benchmarks.harness.weights import seed_words
 
         t_begin = time.time()
-        from benchmarks.harness.common import Heartbeat
-
         self._watch, self._heart = CompileWatch(), Heartbeat()
-        self._model = dict(model_cfg)
+        self._family = families.of(config)
+        self._model = self._family.model_kwargs(config)
 
         def loader(cfg):
-            params = jax.jit(lambda w: make_weights(w, self._model, cfg.dtype))(seed_words(weight_seed))
+            params = jax.jit(lambda w: self._family.make_weights(w, self._model, cfg.dtype))(seed_words(weight_seed))
             jax.block_until_ready(params)
             print(f"backend and seeded weights ready {time.time() - t_begin:.2f} s after the replica's start", flush=True)
             return params
 
-        super().__init__(model_cfg, engine_cfg, deployment=deployment, params_loader=loader)
+        super().__init__(self._model, dict(config["engine"]), deployment=deployment, params_loader=loader)
         print(f"engine ready {time.time() - t_begin:.2f} s after the replica's start", flush=True)
         self._trace_dir = None
 
@@ -134,9 +137,9 @@ class BenchLLMServer(LLMServer):
         import jax.numpy as jnp
         import numpy as np
 
-        from benchmarks.reference import gptj
         from ray_tpu.serve.llm.kv_cache import BlockTable
 
+        reference = self._family.reference()
         eng = self._engine
         ecfg = eng.cfg
         if eng._has_active() or eng._waiting:
@@ -194,14 +197,14 @@ class BenchLLMServer(LLMServer):
             fed = list(prompt) + list(emitted[: steps[i]])
             seq[: len(fed)] = fed
             rows = np.arange(len(prompt) - 1, len(prompt) - 1 + n)
-            ref = np.asarray(gptj.logits_at(params, seq, rows, "f32"))
+            ref = np.asarray(reference.logits_at(params, seq, rows, "f32"))
             got = np.stack(engine_logits[i])
             served = np.asarray(emitted[:n])
             rms = np.sqrt(np.mean(ref**2, axis=-1))
             rel_err.extend((np.linalg.norm(got - ref, axis=-1) / np.linalg.norm(ref, axis=-1)).tolist())
             gap.extend(((ref.max(-1) - ref[np.arange(n), served]) / rms).tolist())
             for prec, c in ctl.items():
-                low = np.asarray(gptj.logits_at(params, seq, rows, prec))
+                low = np.asarray(reference.logits_at(params, seq, rows, prec))
                 c["err"].extend((np.linalg.norm(low - ref, axis=-1) / np.linalg.norm(ref, axis=-1)).tolist())
                 c["gap"].extend(((ref.max(-1) - ref[np.arange(n), low.argmax(-1)]) / rms).tolist())
         mismatches = sum(a != b for i, (_, emitted) in enumerate(samples)

@@ -9,6 +9,7 @@ import random
 import threading
 import time
 
+from benchmarks import families
 from benchmarks.harness import arith, common, traffic as tr
 from benchmarks.harness.common import say
 
@@ -83,7 +84,7 @@ def run(cell: dict, args) -> int:
     from benchmarks.harness.replica import BenchLLMServer
 
     mix, config = cell["traffic"], cell["config"]
-    model, engine = common.model_kwargs(config), dict(config["engine"])
+    model, engine = families.of(config).model_kwargs(config), dict(config["engine"])
     worst = mix["prompt_len"]["hi"] + mix["output_len"]["hi"]
     if worst > engine["max_blocks_per_seq"] * engine["block_size"]:
         raise SystemExit(f"traffic asks for {worst} tokens a request, the engine holds fewer")
@@ -95,7 +96,7 @@ def run(cell: dict, args) -> int:
             BenchLLMServer, name="llm",
             max_ongoing_requests=engine["max_batch"] + engine.get("max_waiting", 32),
             ray_actor_options={"num_tpus": num_tpus},
-        ).bind(model, engine, weight_seed=args.seed, deployment="llm"),
+        ).bind(config, weight_seed=args.seed, deployment="llm"),
         name="bench", route_prefix="/bench",
     )
     h = serve.get_deployment_handle("llm", app_name="bench")
@@ -223,8 +224,9 @@ def run(cell: dict, args) -> int:
     limits = config["limits"]
     compared = {k: (verdict[k], limits[k]) for k in limits}
     correct = bool(picked) and all(v <= lim for v, lim in compared.values())
-    say(f"correct={correct}: " + "; ".join(f"{k} {v:.6g} (limit {lim})" for k, (v, lim) in compared.items())
-        + f"; over {verdict['positions']} positions of {len(picked)} requests; all readings {verdict}")
+    args.verdict = (f"correct={correct}: " + "; ".join(f"{k} {v:.6g} (limit {lim})" for k, (v, lim) in compared.items())
+                    + f"; over {verdict['positions']} positions of {len(picked)} requests; all readings {verdict}")
+    say(args.verdict)
 
     trace = h.bench_reduce_trace.remote(not args.keep_trace).result(timeout_s=600) if args.trace else None
     stats = h.bench_counters.remote().result(timeout_s=60)

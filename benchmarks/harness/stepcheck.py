@@ -3,8 +3,8 @@
 batch, against the plain reference. What is compared is what that step left
 behind: its loss, and a seeded sample of its first moments (its gradients),
 its second moments, and the change it made to the parameters, each against a
-plain float64 AdamW step (``reference/adamw.py``) of the reference's float32
-gradients over the same whole batch (``reference/gptj.py``)."""
+plain float64 AdamW step (``reference/adamw.py``) of the float32 gradients
+that the family's plain reference gives over the same whole batch."""
 
 from __future__ import annotations
 
@@ -48,21 +48,21 @@ def rel(a, b) -> float:
     return float(np.linalg.norm(a - b) / np.linalg.norm(b))
 
 
-def compare(sampled: dict, step_loss: float, params1, tokens, targets, picks: dict, adamw: dict,
+def compare(reference, sampled: dict, step_loss: float, params1, tokens, targets, picks: dict, adamw: dict,
             learning_rate: float, controls=()) -> dict:
-    """The readings of `correct`. ``params1`` are the seeded weights on one
-    device, ``tokens``/``targets`` the whole first batch there. With
+    """The readings of `correct`. ``reference`` is the family's plain
+    reference (its ``mean_loss_and_grads``), ``params1`` are the seeded
+    weights on one device, ``tokens``/``targets`` the whole first batch there. With
     ``controls`` also what the control reads: the reference computed in each
     of those precisions, its moments kept in the parameters' type, put in the
     program's place."""
     from benchmarks.reference import adamw as plain
-    from benchmarks.reference import gptj
 
     leaves = tuple(picks)
     hyper = dict(adamw, learning_rate=learning_rate)
 
     def plain_step(precision, as_program):
-        loss, grads = gptj.mean_loss_and_grads(params1, tokens, targets, precision=precision, leaves=leaves)
+        loss, grads = reference.mean_loss_and_grads(params1, tokens, targets, precision=precision, leaves=leaves)
         out = {}
         for k in leaves:
             g = np.asarray(grads[k]).reshape(-1)[picks[k]]

@@ -12,29 +12,28 @@ import shutil
 import tempfile
 import time
 
+from benchmarks import families
 from benchmarks.harness import arith, common, stepcheck, traffic as tr
 from benchmarks.harness.common import say
 
 
 def train_loop(config: dict) -> None:
     import jax
-    import jax.numpy as jnp
     import numpy as np
 
     from benchmarks.harness.replica import CONTROLS, CompileWatch, device_report
-    from benchmarks.harness.weights import make_weights, seed_words
+    from benchmarks.harness.weights import seed_words
     from ray_tpu import train
-    from ray_tpu.models.transformer import TransformerConfig
     from ray_tpu.parallel.mesh import MeshConfig, create_mesh
     from ray_tpu.parallel.spmd import build_lm_train_step
 
     watch, heart = CompileWatch(), common.Heartbeat()
-    model, tc, mix = dict(config["model"]), config["train"], config["traffic"]
-    dtype = jnp.dtype(model.pop("dtype")).type
-    cfg = TransformerConfig(**model, dtype=dtype)
+    family = families.of(config["config"])
+    model, tc, mix = family.model_kwargs(config["config"]), config["config"]["train"], config["traffic"]
+    cfg = family.train_config(model)
     mesh = create_mesh(MeshConfig(**tc["mesh"]), devices=jax.devices())
     bundle = build_lm_train_step(cfg, mesh, learning_rate=tc["learning_rate"])
-    weights_of = jax.jit(lambda w: make_weights(w, config["model"], dtype),
+    weights_of = jax.jit(lambda w: family.make_weights(w, model, jax.numpy.dtype(model["dtype"])),
                          out_shardings=bundle.param_shardings)
 
     def fresh_weights():
@@ -56,7 +55,7 @@ def train_loop(config: dict) -> None:
 
         batches = ((b["tokens"], b["targets"]) for b in endless())
     else:
-        data = tr.token_batches(config["seed"], cfg.vocab_size, batch, seq)
+        data = tr.token_batches(config["seed"], model["vocab_size"], batch, seq)
         fixed = bundle.shard_batch(data["tokens"], data["targets"])
         batches = iter(lambda: fixed, None)
 
@@ -117,13 +116,13 @@ def train_loop(config: dict) -> None:
     one = jax.devices()[0]
     params1 = jax.device_put(fresh_weights(), one)
     tok1, tgt1 = (jax.device_put(first_batch[i], one) for i in (0, 1))
-    verdict = stepcheck.compare(sampled, losses[0], params1, tok1, tgt1, picks, tc["adamw"],
+    verdict = stepcheck.compare(family.reference(), sampled, losses[0], params1, tok1, tgt1, picks, tc["adamw"],
                                 tc["learning_rate"], CONTROLS if config["control"] else ())
     train.report({"step": len(losses), "loss": losses[-1], "summary": {
         "t0": t0, "n_warm": n_warm, "n_plain": n_plain, "starts": starts, "waits": waits, "parts": parts,
         "losses": losses, "compile_in_window": {k: compile1[k] - compile0[k] for k in compile1},
         "compile_total": dict(watch.counts), "host_stalls": host_stalls, "device": device, "trace": trace,
-        "verdict": verdict, "n_params": cfg.num_params(),
+        "verdict": verdict, "n_params": sum(x.size for x in jax.tree.leaves(params1)),
     }})
 
 
@@ -133,7 +132,7 @@ def run(cell: dict, args) -> int:
 
     mix, config = cell["traffic"], cell["config"]
     tc = config["train"]
-    model = common.model_kwargs(config)
+    model = families.of(config).model_kwargs(config)
     chips = common.start_cluster(cell["chips"], args.rehearse)
     datasets = None
     if mix["ingest"]:
@@ -153,7 +152,7 @@ def run(cell: dict, args) -> int:
         result = JaxTrainer(
             train_loop,
             train_loop_config=dict(
-                model=model, train=tc, traffic=mix, seed=args.seed, seconds=args.seconds,
+                config=config, traffic=mix, seed=args.seed, seconds=args.seconds,
                 trace=args.trace, control=args.control, keep_trace=args.keep_trace,
                 trace_dir=common.trace_dir(cell, args),
             ),
@@ -199,8 +198,9 @@ def run(cell: dict, args) -> int:
     v, limits = s["verdict"], config["limits"]
     compared = {k: (v[k], limits[k]) for k in limits}
     correct = bool(compared) and all(math.isfinite(x) and x <= lim for x, lim in compared.values()) and not bad
-    say(f"correct={correct}: " + "; ".join(f"{k} {x:.6g} (limit {lim})" for k, (x, lim) in compared.items())
-        + f"; all readings {v}")
+    args.verdict = (f"correct={correct}: " + "; ".join(f"{k} {x:.6g} (limit {lim})" for k, (x, lim) in compared.items())
+                    + f"; all readings {v}")
+    say(args.verdict)
 
     if args.trace:
         trace = s["trace"]

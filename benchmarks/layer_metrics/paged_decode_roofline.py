@@ -1,11 +1,13 @@
 """Kernels: the least time one decode step could take on this chip (the
 weights once plus the live KV rows read, over the memory bandwidth; its FLOPs
-over the peak; the larger) over the decode program's device time in the
-trace. The paged path has no kernel yet, so this is the whole
-``jit_decode_step_greedy`` program: gathers, attention, MLP, head and argmax.
-Live rows per step come from the engine's counters over the window: the mean
-number of sequences in a step times the mean context they hold."""
+over the peak; the larger; counted by the configuration's family) over the
+decode program's device time in the trace. This is the whole
+``jit_decode_step_greedy`` program: scatter, the paged-attention kernel, MLP,
+head and argmax; the kernel's own share is ``paged_attn_roofline``. Live rows
+per step come from the engine's counters over the window: the mean number of
+sequences in a step times the mean context they hold."""
 
+from benchmarks import families
 from benchmarks.harness import readers, rooflines
 
 
@@ -17,6 +19,6 @@ def read(ctx):
     n, seconds = runs
     batch = (c["decode_tokens"] - c["first_tokens"]) / c["decode_steps"]
     live_rows = batch * ctx["mean_context"]
-    need = rooflines.paged_decode_step(ctx["model"], batch, live_rows)
+    need = families.of(ctx["config"]).decode_step_need(ctx["model"], batch, live_rows)
     least = rooflines.least_time_s(need["flops"], need["bytes"], ctx["peaks"])
     return 100.0 * least["seconds"] / (seconds / n)

@@ -47,7 +47,7 @@ def main() -> int:
     ap.add_argument("--keep-trace", type=int, choices=(0, 1), default=0)
     args = ap.parse_args()
     args.t_start = T_START
-    args.result = None
+    args.result = args.verdict = None  # the driver's: the result line's parts, the numbers compared beside their limits
 
     from benchmarks.harness import common
 
@@ -68,6 +68,7 @@ def main() -> int:
         raise SystemExit("benchmark: the parent imported jax")
     if args.result is None:
         return 1
+    print(args.verdict, file=sys.stderr, flush=True)  # the last line of standard error, whatever the teardown wrote
     common.emit(*args.result)
     return 3 if args.rehearse else 0
 

@@ -29,23 +29,19 @@ def read(ctx):
 @pytest.fixture(scope="module")
 def tree(tmp_path_factory):
     dest = str(tmp_path_factory.mktemp("bench"))
-    tiny.CONFIGS["made-up"], tiny.TRAFFIC["made-up-mix"] = MADE_UP_CONFIG, MADE_UP_MIX
-    try:
-        tiny.build(
-            dest, extra_cells=[("made-up-cell", "made-up", "made-up-mix", 1)],
-            extra_per_layer=[{"name": "made_up.requests_done", "unit": "requests", "better": "higher",
-                              "source": "host_clock", "layer": "entry", "moves": "serve_tokens_per_s",
-                              "workloads": ["made-up-cell"]},
-                             {"name": "batch_occupancy.made_up", "unit": "%", "better": "higher",
-                              "source": "program_counter", "layer": "engine", "moves": "serve_tokens_per_s",
-                              "workloads": ["made-up-cell"]}],
-        )
-    finally:
-        del tiny.CONFIGS["made-up"], tiny.TRAFFIC["made-up-mix"]
-    with open(os.path.join(dest, "benchmarks", "layer_metrics", "made_up.requests_done.py"), "w") as f:
-        f.write(MADE_UP_READER)
-    with open(os.path.join(dest, "benchmarks", "layer_metrics", "batch_occupancy.made_up.py"), "w") as f:
-        f.write(open(os.path.join(dest, "benchmarks", "layer_metrics", "batch_occupancy.py")).read())
+    metric = os.path.join(tiny.ROOT, "benchmarks", "layer_metrics", "batch_occupancy.py")
+    tiny.build(
+        dest, extra_cells=[("made-up-cell", "made-up", "made-up-mix", 1)],
+        extra_configs={"made-up": MADE_UP_CONFIG}, extra_traffic={"made-up-mix": MADE_UP_MIX},
+        extra_files={"layer_metrics/made_up.requests_done.py": MADE_UP_READER,
+                     "layer_metrics/batch_occupancy.made_up.py": open(metric).read()},
+        extra_per_layer=[{"name": "made_up.requests_done", "unit": "requests", "better": "higher",
+                          "source": "host_clock", "layer": "entry", "moves": "serve_tokens_per_s",
+                          "workloads": ["made-up-cell"]},
+                         {"name": "batch_occupancy.made_up", "unit": "%", "better": "higher",
+                          "source": "program_counter", "layer": "engine", "moves": "serve_tokens_per_s",
+                          "workloads": ["made-up-cell"]}],
+    )
     # the made-up cell serves under serve_tokens_per_s: an entry appended, nothing edited
     bench = json.load(open(os.path.join(dest, "BENCHMARK.json")))
     for m in bench["end_to_end"]:

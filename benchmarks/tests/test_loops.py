@@ -3,7 +3,6 @@ recorded sample kept beside them (``harness/loops_sample/``: a stretch of a
 rehearsal of each cell kind, as the head wrote it), without a file, and in a
 rehearsal of each cell from end to end."""
 
-import importlib.util
 import json
 import os
 
@@ -21,12 +20,7 @@ NEW = {
 }
 
 
-def reader(name):
-    path = os.path.join(tiny.ROOT, "benchmarks", "layer_metrics", name + ".py")
-    spec = importlib.util.spec_from_file_location("layer_metric", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+reader = tiny.reader
 
 
 @pytest.mark.parametrize("name,ctx,value", [

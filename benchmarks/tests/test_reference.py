@@ -7,11 +7,12 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmarks.harness.weights import make_weights, seed_words
-from benchmarks.reference import gptj
+from benchmarks.families import gptj as family
+from benchmarks.harness.weights import seed_words
 from ray_tpu.models import generation as G
 from ray_tpu.models import transformer as tfm
 
+gptj, make_weights = family.reference(), family.make_weights
 MODEL = dict(vocab_size=1024, d_model=256, n_layers=4, n_heads=4, d_ff=1024, max_seq_len=256,
              parallel_block=True, use_swiglu=False, tie_embeddings=False)
 
@@ -127,7 +128,7 @@ def first_step_readings(dtype, seed, controls=(), spoil=None):
     if spoil:
         spoil(sampled)
     _, fresh = setup(dtype, seed)
-    return stepcheck.compare(sampled, float(metrics["loss"]), fresh, tokens, targets, picks, ADAMW, 1e-4, controls)
+    return stepcheck.compare(gptj, sampled, float(metrics["loss"]), fresh, tokens, targets, picks, ADAMW, 1e-4, controls)
 
 
 def test_the_first_step_agrees_with_the_plain_reference_in_float32():

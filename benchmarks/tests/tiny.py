@@ -68,18 +68,32 @@ TWIN = {"serve-gptj6b-batch": ["tiny-batch", "tiny-online"],
         "train-gptj4l-ingest": ["tiny-ingest", "tiny-mesh"]}
 
 
-def build(dest: str, extra_cells=(), extra_per_layer=()) -> str:
+def build(dest: str, extra_cells=(), extra_per_layer=(), extra_configs=None, extra_traffic=None,
+          extra_files=None, extra_twins=None) -> str:
     """Copy ``benchmarks/`` to ``dest`` and add the tiny cells as new files
-    and entries; nothing that is there is edited."""
+    and entries; nothing that is there is edited. A test of a later PR brings
+    its own: ``extra_configs`` and ``extra_traffic`` (name -> the file's
+    object), ``extra_files`` (path under ``benchmarks/`` -> text; a file that
+    is there already is an error), ``extra_cells`` ((name, config, traffic,
+    chips)), ``extra_per_layer`` (entries) and ``extra_twins`` (real cell ->
+    the extra cells that report its metrics, as ``TWIN`` has the tiny ones)."""
     real = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
     shutil.copytree(os.path.join(ROOT, "benchmarks"), os.path.join(dest, "benchmarks"),
                     ignore=shutil.ignore_patterns("__pycache__", "tests"))
-    for name, cfg in CONFIGS.items():
-        json.dump(cfg, open(os.path.join(dest, "benchmarks", "configs", name + ".json"), "w"))
-    for name, mix in TRAFFIC.items():
-        json.dump(mix, open(os.path.join(dest, "benchmarks", "traffic", name + ".json"), "w"))
+    files = {f"{kind}/{name}.json": json.dumps(obj)
+             for kind, group in (("configs", {**CONFIGS, **(extra_configs or {})}),
+                                 ("traffic", {**TRAFFIC, **(extra_traffic or {})}))
+             for name, obj in group.items()}
+    files.update(extra_files or {})
+    for rel, text in files.items():
+        path = os.path.join(dest, "benchmarks", rel)
+        os.makedirs(os.path.dirname(path), exist_ok=True)
+        with open(path, "x") as f:  # added, never written over
+            f.write(text)
     cells = list(CELLS) + list(extra_cells)
     names = [c[0] for c in cells]
+    extra_twins = extra_twins or {}
+    twins = {w: [*TWIN.get(w, ()), *extra_twins.get(w, ())] for w in {*TWIN, *extra_twins}}
     bench = dict(real)
     bench["configs"] = [
         {"name": n, "source": "made up", "file": f"benchmarks/configs/{n}.json", "reduced": [], "why": "test"}
@@ -91,13 +105,24 @@ def build(dest: str, extra_cells=(), extra_per_layer=()) -> str:
     # every metric the real file has, read in the tiny cell of the same kind
     for group in ("end_to_end", "per_layer"):
         bench[group] = [
-            {**m, **({"workloads": [t for w in m["workloads"] for t in TWIN.get(w, ()) if t in names]}
+            {**m, **({"workloads": [t for w in m["workloads"] for t in twins.get(w, ()) if t in names]}
                      if "workloads" in m else {})}
             for m in real[group]
         ]
     bench["per_layer"] += list(extra_per_layer)
     json.dump(bench, open(os.path.join(dest, "BENCHMARK.json"), "w"), indent=1)
     return dest
+
+
+def reader(name: str):
+    """The ``read`` of ``layer_metrics/<name>.py``, loaded as the harness loads it."""
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "layer_metric", os.path.join(ROOT, "benchmarks", "layer_metrics", name + ".py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.read
 
 
 def run_cell(tree: str, workload: str, trace: int, seconds: float = 2.0, seed: int = 7,
