@@ -53,7 +53,21 @@ LLM_REQUEST_FIELDS = (
     "reason",  # length | stop | error | shed_blocks | shed_waiting | shutdown
     "trace_id",
 )
-_KINDS = {"s": ("llm_step", LLM_STEP_FIELDS), "r": ("llm_request", LLM_REQUEST_FIELDS)}
+# one read of the expert layers' routing counts (a model that has such a layer
+# sums them on the device; the engine reads them once a flush interval). All
+# four are cumulative since the engine started, summed over layers and decode
+# steps: differences between two records are what the steps between them did
+LLM_MOE_FIELDS = (
+    "t",  # when the counts reached the host
+    "step",  # decode steps dispatched when the counts were copied: they cover at least these
+    "held",  # (token, choice) rows sent to experts this replica holds
+    "zero",  # ... to zero-compute (identity) experts
+    "absent",  # ... to experts of another chip's share
+    "touched",  # held experts with at least one row, a layer a step
+    "layers",  # expert layers a decode step runs
+)
+_KINDS = {"s": ("llm_step", LLM_STEP_FIELDS), "r": ("llm_request", LLM_REQUEST_FIELDS),
+          "m": ("llm_moe", LLM_MOE_FIELDS)}
 
 MAX_FILE_BYTES = 32 << 20  # a file past this moves to <name>.1 (one kept)
 _MAX_OPEN = 64

@@ -70,13 +70,16 @@ class MemoryStore:
 
     def __init__(self):
         self._lock = threading.Lock()
-        self._cv = threading.Condition(self._lock)
         # oid -> ("inline", bytes) | ("stored",) | ("error", bytes)
         self._table: Dict[ObjectID, Tuple] = {}
         # per-oid waiter index: put() hits exactly the waiters of that oid,
         # so a get() over N objects costs O(N) total instead of O(N) per
         # commit (rescanning every oid on notify_all was the driver-side
-        # hot spot in the deep-queue microbench)
+        # hot spot in the deep-queue microbench). Each waiter sleeps on a
+        # condition of its own over the store's one lock, and a put wakes the
+        # waiters it completes and no others: with one condition for all, 40
+        # consumers of 40 token streams all woke for every token of any
+        # stream, and a replica's streams together stopped at ~600 items/s
         self._waiters: Dict[ObjectID, List[dict]] = {}
 
     def _put_locked(self, oid: ObjectID, entry: Tuple) -> None:
@@ -89,20 +92,19 @@ class MemoryStore:
                 waiter["need"] is None and not waiter["remaining"]
             ) or (waiter["need"] is not None and waiter["hits"] >= waiter["need"]):
                 waiter["done"] = True
+                waiter["cv"].notify()
 
     def put(self, oid: ObjectID, entry: Tuple) -> None:
-        with self._cv:
+        with self._lock:
             self._put_locked(oid, entry)
-            self._cv.notify_all()
 
     def put_many(self, items) -> None:
         """Commit a batch of (oid, entry) pairs under ONE lock round and one
         notify — the per-task commit lock was the last per-task cost on the
         lease completion path (a (node, tick) frame commits dozens)."""
-        with self._cv:
+        with self._lock:
             for oid, entry in items:
                 self._put_locked(oid, entry)
-            self._cv.notify_all()
 
     def get_entry(self, oid: ObjectID) -> Optional[Tuple]:
         with self._lock:
@@ -114,7 +116,8 @@ class MemoryStore:
 
     def _register_waiter(self, missing: Set[ObjectID], need: Optional[int]) -> dict:
         # caller holds the lock
-        waiter = {"remaining": missing, "hits": 0, "need": need, "done": False}
+        waiter = {"remaining": missing, "hits": 0, "need": need, "done": False,
+                  "cv": threading.Condition(self._lock)}
         for o in missing:
             self._waiters.setdefault(o, []).append(waiter)
         return waiter
@@ -136,7 +139,7 @@ class MemoryStore:
         """Block until all oids present or timeout; returns the ready set."""
         oids = set(oids)
         deadline = None if timeout is None else time.monotonic() + timeout
-        with self._cv:
+        with self._lock:
             missing = {o for o in oids if o not in self._table}
             if not missing:
                 return oids
@@ -148,7 +151,7 @@ class MemoryStore:
                         remaining = deadline - time.monotonic()
                         if remaining <= 0:
                             break
-                    self._cv.wait(remaining if remaining is not None else 1.0)
+                    waiter["cv"].wait(remaining if remaining is not None else 1.0)
             finally:
                 self._drop_waiter(waiter)
             return oids - waiter["remaining"]
@@ -157,7 +160,7 @@ class MemoryStore:
         """Block until >= num_returns of oids are present or timeout."""
         oids = list(dict.fromkeys(oids))
         deadline = None if timeout is None else time.monotonic() + timeout
-        with self._cv:
+        with self._lock:
             missing = {o for o in oids if o not in self._table}
             have = len(oids) - len(missing)
             if have >= num_returns or not missing:
@@ -170,7 +173,7 @@ class MemoryStore:
                         remaining = deadline - time.monotonic()
                         if remaining <= 0:
                             break
-                    self._cv.wait(remaining if remaining is not None else 1.0)
+                    waiter["cv"].wait(remaining if remaining is not None else 1.0)
             finally:
                 self._drop_waiter(waiter)
             return [o for o in oids if o in self._table]

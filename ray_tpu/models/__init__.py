@@ -24,3 +24,15 @@ __all__ = [
 from ray_tpu.models import vit  # noqa: E402  (ViT family: models/vit.py)
 
 __all__.append("vit")
+
+
+def paged_model(cfg):
+    """The module that runs ``cfg`` over a paged pool for ``serve.llm``:
+    ``make_paged_fns``, ``init_paged_pool``, ``paged_block_bytes`` and
+    ``init_params``. The pool is the model's to shape; the engine asks here."""
+    from ray_tpu.models import generation, longcat
+
+    return longcat if isinstance(cfg, longcat.LongcatConfig) else generation
+
+
+__all__.append("paged_model")

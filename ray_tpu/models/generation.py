@@ -29,7 +29,7 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.transformer import TransformerConfig
+from ray_tpu.models.transformer import TransformerConfig, init_params  # noqa: F401 - a paged model's module has it
 from ray_tpu.ops.layers import apply_rope, gelu, rms_norm, rope_frequencies, swiglu
 from ray_tpu.ops.paged_attention import can_use_paged_kernel, paged_decode_attention
 
@@ -183,6 +183,12 @@ def init_paged_pool(
     shape = (cfg.n_layers, n_slots, cfg.kv_heads, cfg.head_dim)
     st = _kv_storage_dtype(cfg.dtype)
     return {"k": jnp.zeros(shape, st), "v": jnp.zeros(shape, st)}
+
+
+def paged_block_bytes(cfg: TransformerConfig, block_size: int) -> int:
+    """Bytes one block of the pool holds: K and V rows over all layers."""
+    row = cfg.kv_heads * cfg.head_dim * jnp.dtype(_kv_storage_dtype(cfg.dtype)).itemsize
+    return 2 * cfg.n_layers * block_size * row
 
 
 def _paged_attention(q, gk, gv, q_positions):

@@ -1,98 +1,127 @@
-"""Mixture-of-Experts MLP with expert parallelism.
+"""The expert layer, told which experts it holds.
 
-Absent from the reference (SURVEY.md §2.3: expert parallel row — "absent");
-first-class here: GShard-style top-k gating with capacity, dispatch/combine
-einsums whose expert dimension shards over the ``expert`` mesh axis — GSPMD
-lowers the dispatch to all-to-alls over ICI.
+A router over ``n_routed + n_zero`` outputs chooses ``top_k`` of them for
+every token; the first ``n_routed`` are SwiGLU experts, the rest are
+zero-compute (identity) experts that add ``w * u`` with no matmul. A chip
+holds experts ``[expert_offset, expert_offset + held)`` of a layer that is
+shared over several chips: it routes over all the outputs, computes its own
+experts' part of the result for the tokens that chose them and every identity
+expert's part (those have no weights, so each chip adds them for its own
+tokens), and adds nothing for a choice of an expert that lives elsewhere. On
+one chip the layer runs without its exchange.
+
+No capacity and no ``(tokens, experts, capacity)`` tensor: the chosen rows are
+sorted by expert and go through a grouped matmul over the experts held
+(``jax.lax.ragged_dot``), so no token is ever dropped, whatever the routing.
 """
 
 from __future__ import annotations
 
-import dataclasses
-from typing import Dict, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.ops.layers import swiglu
 
-@dataclasses.dataclass(frozen=True)
-class MoEConfig:
-    d_model: int = 128
-    d_ff: int = 512
-    num_experts: int = 8
-    top_k: int = 2
-    capacity_factor: float = 1.25
-    dtype: Tuple = jnp.float32
+# what ``expert_layer`` counts of its routing, in this order
+COUNTS = ("held", "zero", "absent", "touched")
 
 
-def init_moe_params(key, cfg: MoEConfig) -> Dict[str, jax.Array]:
-    k1, k2, k3 = jax.random.split(key, 3)
-    scale_in = 1.0 / jnp.sqrt(cfg.d_model)
-    scale_out = 1.0 / jnp.sqrt(cfg.d_ff)
+# The seeded router: logits of deviation ``ROUTER_SCALE``, so that a token's
+# chosen weights span two orders of magnitude with the last choice the
+# smallest, as a trained router's do (a flat softmax gives every chosen expert
+# ``scale / n_outputs``, and an expert's part then vanishes beside the dense
+# paths); the choice bias ``BIAS_SCALE`` x normal, small beside a chosen ``p``.
+ROUTER_SCALE = 4.0
+BIAS_SCALE = 1e-5
+
+
+def init_expert_params(key, d_model: int, d_ff: int, held: int, n_outputs: int, dtype=jnp.float32) -> Dict[str, jax.Array]:
+    """Seeded weights of one layer: 1/sqrt(fan-in) scales, the router's
+    columns times ``ROUTER_SCALE``, the choice bias ``BIAS_SCALE`` x normal.
+    ``e_down`` is not scaled down with depth as a dense path's output
+    projection is: what an expert writes is weighted by ``scale * p`` first."""
+    kr, kb, kg, ku, kd = jax.random.split(key, 5)
+    s_in, s_ff = d_model ** -0.5, d_ff ** -0.5
     return {
-        "router": (jax.random.normal(k1, (cfg.d_model, cfg.num_experts)) * scale_in).astype(cfg.dtype),
-        "w_in": (jax.random.normal(k2, (cfg.num_experts, cfg.d_model, cfg.d_ff)) * scale_in).astype(cfg.dtype),
-        "w_out": (jax.random.normal(k3, (cfg.num_experts, cfg.d_ff, cfg.d_model)) * scale_out).astype(cfg.dtype),
+        "router": (jax.random.normal(kr, (d_model, n_outputs)) * s_in * ROUTER_SCALE).astype(dtype),
+        "router_bias": jax.random.normal(kb, (n_outputs,)) * BIAS_SCALE,
+        "e_gate": (jax.random.normal(kg, (held, d_model, d_ff)) * s_in).astype(dtype),
+        "e_up": (jax.random.normal(ku, (held, d_model, d_ff)) * s_in).astype(dtype),
+        "e_down": (jax.random.normal(kd, (held, d_ff, d_model)) * s_ff).astype(dtype),
     }
 
 
-def moe_param_logical_axes() -> Dict[str, Tuple]:
+def expert_param_logical_axes() -> Dict[str, Tuple]:
     return {
         "router": ("embed", None),
-        "w_in": ("expert", "embed", "mlp"),
-        "w_out": ("expert", "mlp", "embed"),
+        "router_bias": (None,),
+        "e_gate": ("expert", "embed", "mlp"),
+        "e_up": ("expert", "embed", "mlp"),
+        "e_down": ("expert", "mlp", "embed"),
     }
 
 
-def moe_mlp(params: Dict[str, jax.Array], x: jax.Array, cfg: MoEConfig):
-    """x: (B, S, D) -> (y (B, S, D), aux_loss).
+def route(u: jax.Array, router: jax.Array, bias: jax.Array, *, top_k: int, scale: float):
+    """``u`` (T, D) -> (weights (T, K) float32, experts (T, K) int32).
+    Softmax in float32 over every output; the ``top_k`` of ``p + bias`` are
+    chosen (the bias moves the choice only); a chosen output's weight is
+    ``scale * p``, not renormalised over the chosen."""
+    z = jnp.dot(u.astype(jnp.float32), router.astype(jnp.float32), precision=jax.lax.Precision.HIGHEST)
+    p = jax.nn.softmax(z, axis=-1)
+    _, experts = jax.lax.top_k(p + bias.astype(jnp.float32), top_k)
+    return scale * jnp.take_along_axis(p, experts, axis=-1), experts
 
-    GShard dispatch: tokens are routed to their top-k experts with a per-
-    expert capacity; overflow tokens are dropped (their residual passes
-    through). aux_loss is the standard load-balancing loss.
-    """
-    B, S, D = x.shape
-    T = B * S
-    E, K = cfg.num_experts, cfg.top_k
-    xt = x.reshape(T, D)
 
-    logits = (xt.astype(jnp.float32) @ params["router"].astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)  # (T, E)
+def expert_layer(params: Dict[str, jax.Array], u: jax.Array, *, n_routed: int, top_k: int, scale: float,
+                 expert_offset: int = 0, live: Optional[jax.Array] = None, layer=None):
+    """``u`` (T, D) -> (y (T, D), counts (4,) uint32 in the order ``COUNTS``).
 
-    # top-k selection
-    gate_vals, expert_idx = jax.lax.top_k(probs, K)  # (T, K)
-    gate_vals = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True), 1e-9)
+    ``params``: ``router`` (D, n_routed + n_zero), ``router_bias``, and the
+    held experts' ``e_gate``/``e_up`` (held, D, F) and ``e_down`` (held, F,
+    D): experts ``expert_offset ..`` of the ``n_routed``. ``live`` (T,) bool
+    marks the rows that are tokens (padding and empty decode slots route
+    nowhere and are not counted). ``counts``: rows sent to held, identity and
+    absent experts, and held experts with at least one row.
 
-    capacity = max(1, int(cfg.capacity_factor * T * K / E))
-
-    # position of each (token, k) within its expert's capacity
-    onehot = jax.nn.one_hot(expert_idx, E, dtype=jnp.int32)  # (T, K, E)
-    flat = onehot.reshape(T * K, E)
-    pos_in_expert = jnp.cumsum(flat, axis=0) * flat - 1  # (T*K, E)
-    pos = pos_in_expert.reshape(T, K, E).max(-1)  # (T, K) position, -1 if none
-    within = (pos >= 0) & (pos < capacity)
-
-    # dispatch tensor (T, E, C) and combine weights
-    dispatch = jnp.zeros((T, E, capacity), jnp.float32)
-    combine = jnp.zeros((T, E, capacity), jnp.float32)
-    t_idx = jnp.arange(T)[:, None].repeat(K, 1)
-    safe_pos = jnp.clip(pos, 0, capacity - 1)
-    dispatch = dispatch.at[t_idx, expert_idx, safe_pos].add(within.astype(jnp.float32))
-    combine = combine.at[t_idx, expert_idx, safe_pos].add(
-        (gate_vals * within).astype(jnp.float32)
-    )
-
-    # expert compute: (E, C, D) — expert dim shards over the 'expert' axis
-    expert_in = jnp.einsum("tec,td->ecd", dispatch, xt.astype(jnp.float32))
-    h = jax.nn.gelu(jnp.einsum("ecd,edf->ecf", expert_in, params["w_in"].astype(jnp.float32)))
-    expert_out = jnp.einsum("ecf,efd->ecd", h, params["w_out"].astype(jnp.float32))
-    yt = jnp.einsum("tec,ecd->td", combine, expert_out)
-
-    # load-balancing loss (Shazeer et al.): E * sum_e f_e * p_e
-    token_frac = jnp.mean(
-        jax.nn.one_hot(expert_idx[:, 0], E, dtype=jnp.float32), axis=0
-    )
-    prob_frac = jnp.mean(probs, axis=0)
-    aux_loss = E * jnp.sum(token_frac * prob_frac)
-
-    return yt.reshape(B, S, D).astype(x.dtype), aux_loss
+    ``layer``: the expert tensors are all layers' stacked, (layers, held, ..),
+    and this is the layer to use (it may be traced). The grouped matmul then
+    runs over ``layers x held`` groups of which only this layer's have rows,
+    and reads those experts where they lie: cutting a layer out of the stack
+    would copy every held expert, touched or not, once a call."""
+    t, d = u.shape
+    e_gate, e_up, e_down = params["e_gate"], params["e_up"], params["e_down"]
+    held_n = e_gate.shape[0] if layer is None else e_gate.shape[1]
+    with jax.named_scope("router"):
+        weights, experts = route(u, params["router"], params["router_bias"], top_k=top_k, scale=scale)
+        alive = jnp.ones((t, 1), bool) if live is None else live[:, None]
+        is_zero = (experts >= n_routed) & alive
+        local = experts - expert_offset
+        is_held = (local >= 0) & (local < held_n) & (experts < n_routed) & alive
+    with jax.named_scope("zero"):
+        # identity experts: the sum of their weights times the token, no matmul
+        y = (jnp.sum(jnp.where(is_zero, weights, 0.0), axis=-1, keepdims=True) * u.astype(jnp.float32))
+    with jax.named_scope("experts"):
+        # every (token, choice) pair is a row; the held ones sort to the front,
+        # by expert, and the grouped matmul visits those alone
+        group = jnp.where(is_held, local, held_n).reshape(t * top_k)
+        order = jnp.argsort(group, stable=True)
+        sizes = jnp.bincount(group, length=held_n + 1)[:held_n].astype(jnp.int32)
+        rows = u[order // top_k]
+        groups = sizes
+        if layer is not None:
+            groups = jax.lax.dynamic_update_slice(
+                jnp.zeros((e_gate.shape[0] * held_n,), jnp.int32), sizes, (layer * held_n,))
+            e_gate, e_up, e_down = (w.reshape(-1, *w.shape[2:]) for w in (e_gate, e_up, e_down))
+        hidden = swiglu(jax.lax.ragged_dot(rows, e_gate, groups), jax.lax.ragged_dot(rows, e_up, groups))
+        out = jax.lax.ragged_dot(hidden, e_down, groups, preferred_element_type=jnp.float32)
+        # back to (token, choice) order; a token's parts add in the order of its
+        # choices, whoever else is in the batch
+        out = out[jnp.argsort(order)].reshape(t, top_k, d)
+        # (rows past the last group are whatever the grouped matmul left there)
+        y = y + jnp.sum(jnp.where(is_held[..., None], out * weights[..., None], 0.0), axis=1)
+    counts = jnp.stack([
+        jnp.sum(is_held), jnp.sum(is_zero), jnp.sum(alive & ~is_held & ~is_zero), jnp.sum(sizes > 0),
+    ]).astype(jnp.uint32)
+    return y.astype(u.dtype), counts

@@ -35,18 +35,25 @@ TINY_MODEL: Dict[str, Any] = {
 
 
 def _resolve_model_cfg(model_cfg):
+    """A config dataclass as it is; a dict becomes the model its ``kind``
+    names (``"longcat"``), and a ``TransformerConfig`` where it names none."""
+    from ray_tpu.models import longcat
     from ray_tpu.models.transformer import TransformerConfig
 
     if model_cfg is None:
         model_cfg = TINY_MODEL
-    if isinstance(model_cfg, TransformerConfig):
+    if isinstance(model_cfg, (TransformerConfig, longcat.LongcatConfig)):
         return model_cfg
     import jax.numpy as jnp
 
     cfg = dict(model_cfg)
+    kinds = {None: TransformerConfig, longcat.KIND: longcat.LongcatConfig}
+    kind = cfg.pop("kind", None)
+    if kind not in kinds:
+        raise ValueError(f"unknown model kind {kind!r} (known: {sorted(k for k in kinds if k)})")
     if isinstance(cfg.get("dtype"), str):
         cfg["dtype"] = jnp.dtype(cfg["dtype"]).type
-    return TransformerConfig(**cfg)
+    return kinds[kind](**cfg)
 
 
 def _resolve_engine_cfg(engine_cfg):
@@ -75,7 +82,7 @@ class LLMServer:
     ):
         import jax
 
-        from ray_tpu.models.transformer import init_params
+        from ray_tpu.models import paged_model
         from ray_tpu.train.jax_utils import ensure_platform
 
         ensure_platform()  # a replica that asked for a chip runs on it
@@ -85,6 +92,7 @@ class LLMServer:
         else:
             # jitted: the eager call holds a float32 copy of every stacked
             # tensor before the cast (7.5 GB for one GPT-J-6B MLP tensor)
+            init_params = paged_model(cfg).init_params
             params = jax.jit(lambda: init_params(jax.random.PRNGKey(int(weight_seed)), cfg))()
         self._engine = InferenceEngine(
             params, cfg, _resolve_engine_cfg(engine_cfg), deployment=deployment

@@ -43,6 +43,14 @@ handles) are made after it, while the device is busy. Each phase is also a
 ``TraceAnnotation`` (``llm.admit``, ``llm.prefill``, ``llm.retire``,
 ``llm.dispatch``, ``llm.emit``) on this thread's line of any profiler trace.
 With ``telemetry_enabled`` off no record or span is made and the ring is empty.
+
+A model with an expert layer (``models/longcat.py``) sums what its decode
+steps routed in a leaf of the pool, on the device. Once a flush interval the
+loop, after its dispatch, enqueues a copy of that leaf behind the step in
+flight and reads it an iteration later, when that step has been retired: no
+step waits for it. Each read feeds ``ray_tpu_llm_moe_rows_total`` /
+``ray_tpu_llm_moe_experts_touched_total`` and, with telemetry on, one loop
+record of kind ``llm_moe`` (cumulative counts; ``looplog.LLM_MOE_FIELDS``).
 """
 
 from __future__ import annotations
@@ -57,7 +65,7 @@ import time
 from typing import Any, Dict, List, Optional, Sequence
 
 from ray_tpu._private import memplane, telemetry
-from ray_tpu._private.looplog import LLM_REQUEST_FIELDS, LLM_STEP_FIELDS
+from ray_tpu._private.looplog import LLM_MOE_FIELDS, LLM_REQUEST_FIELDS, LLM_STEP_FIELDS
 from ray_tpu._private.profiling import annotate
 from ray_tpu.serve.exceptions import DeploymentOverloadedError
 from ray_tpu.serve.llm.kv_cache import BlockAllocator, BlockTable
@@ -105,6 +113,20 @@ def _engine_metrics() -> dict:
             "step plus everything the loop does in between (emit, the next "
             "iteration's prefills), on the monotonic clock; the loop records "
             "split it (loop_stats)",
+            tag_keys=("deployment",),
+        )
+        _metrics["moe_rows"] = Counter(
+            "ray_tpu_llm_moe_rows_total",
+            "(token, choice) rows the decode steps' expert layers routed, by "
+            "destination: held = an expert this replica holds, zero = a "
+            "zero-compute (identity) expert, absent = an expert of another "
+            "chip's share (adds nothing here); summed over layers",
+            tag_keys=("deployment", "dest"),
+        )
+        _metrics["moe_touched"] = Counter(
+            "ray_tpu_llm_moe_experts_touched_total",
+            "held experts that got at least one row, summed over layers and "
+            "decode steps: what a step reads of its expert weights",
             tag_keys=("deployment",),
         )
     return _metrics
@@ -237,7 +259,9 @@ class InferenceEngine:
         deployment: str = "llm",
         start: bool = True,
     ):
-        from ray_tpu.models import generation as G
+        import jax
+
+        from ray_tpu.models import generation as G, paged_model
 
         ecfg = engine_cfg or EngineConfig()
         if ecfg.max_batch < 1:
@@ -247,11 +271,14 @@ class InferenceEngine:
         self.cfg = ecfg
         self.deployment = deployment
         self._G = G
-        self._prefill, self._decode, self._decode_greedy = G.make_paged_fns(
+        # the pool is the model's to shape: its module makes it, says what a
+        # block of it holds, and gives the three programs that run over it
+        model = paged_model(model_cfg)
+        self._prefill, self._decode, self._decode_greedy = model.make_paged_fns(
             model_cfg, block_size=ecfg.block_size
         )
-        self._pool = G.init_paged_pool(model_cfg, ecfg.num_blocks, ecfg.block_size)
-        self._device = next(iter(self._pool["k"].devices()))
+        self._pool = model.init_paged_pool(model_cfg, ecfg.num_blocks, ecfg.block_size)
+        self._device = next(iter(jax.tree.leaves(self._pool)[0].devices()))
         self._alloc = BlockAllocator(ecfg.num_blocks, ecfg.block_size)
         self._slots: List[Optional[_Running]] = [None] * ecfg.max_batch
         self._waiting: "list[tuple[_Request, TokenStream]]" = []
@@ -264,11 +291,15 @@ class InferenceEngine:
         self.max_context = min(
             ecfg.max_blocks_per_seq * ecfg.block_size, model_cfg.max_seq_len
         )
-        k = self._pool["k"]
-        self._bytes_per_block = int(
-            k.dtype.itemsize * 2 * k.shape[0] * ecfg.block_size * k.shape[2] * k.shape[3]
-        )
+        self._bytes_per_block = int(model.paged_block_bytes(model_cfg, ecfg.block_size))
         self.decode_steps = 0  # dispatched so far: a step's number
+        # a model with an expert layer sums its routing counts on the device,
+        # in a leaf of the pool; the loop copies them out once a flush interval
+        self._routing_counts = getattr(model, "routing_counts", None)
+        self._moe_layers = getattr(model_cfg, "num_layers", 0)
+        self._moe_copy = None  # (the copy, still on the device; the step it was taken behind)
+        self._moe_seen = [0, 0, 0, 0]  # the counts last read, modulo 2**32
+        self._moe_total = [0, 0, 0, 0]  # held, zero, absent rows; experts touched
         # -- what the loop measures of itself (module docstring) ----------
         # resolved once: a replica builds its engine after it has connected
         self._tel = telemetry.get_buffer() if telemetry.enabled() else None
@@ -283,6 +314,9 @@ class InferenceEngine:
         self._m_step = m["step"].bind(tags)
         self._m_prefill_tokens = m["tokens"].bind({**tags, "phase": "prefill"})
         self._m_decode_tokens = m["tokens"].bind({**tags, "phase": "decode"})
+        if self._routing_counts is not None:  # a model without an expert layer has no such series
+            self._m_moe = [m["moe_rows"].bind({**tags, "dest": d}) for d in ("held", "zero", "absent")]
+            self._m_moe.append(m["moe_touched"].bind(tags))
         # periodic device sweeps refresh the ray_tpu_kv_* gauges of an idle engine
         memplane.register_kv_provider(deployment, self._occupancy)
         if start:
@@ -465,6 +499,7 @@ class InferenceEngine:
         ring = self._ring.copy()  # atomic against the loop's appends
         steps = [r[1:] for r in ring if r[0] == "s"]
         reqs = [r[1:] for r in ring if r[0] == "r"]
+        routed = [r[1:] for r in ring if r[0] == "m"]
         spans: Dict[str, List[int]] = {
             k: [] for k in ("queue_wait", "prefill", "prefill_stall", "device_wait", "dispatch_gap", "emit")
         }
@@ -495,6 +530,8 @@ class InferenceEngine:
             "phases": {k: {"count": len(v), "sum_ns": sum(v), "max_ns": max(v, default=0)}
                        for k, v in spans.items()},
             "kv_blocks": {"count": len(kv), "sum": sum(kv), "max": max(kv, default=0)},
+            # the newest read of the expert layers' routing counts (cumulative)
+            "moe": dict(zip(LLM_MOE_FIELDS, routed[-1])) if routed else None,
         }
 
     def _record(self, rec: tuple) -> None:
@@ -621,14 +658,38 @@ class InferenceEngine:
                 for item in ended:
                     self._close_request(*item)
                 drained = inflight is None and not self._waiting
+                if self._moe_copy is not None and (drained or self._moe_copy[1] < self.decode_steps):
+                    self._fold_routing_counts()
                 if drained or t_loop - self._gauges_at >= self._gauge_period_ns:
                     self._gauges_at = t_loop
                     self._refresh_kv_gauges()
+                    if self._routing_counts is not None and self._moe_copy is None:
+                        self._moe_copy = (self._routing_counts(self._pool), self.decode_steps)
+                        if drained:
+                            self._fold_routing_counts()
             if self._tel is not None:
                 self._record((
                     "s", self.decode_steps, t_loop, t_admit_end, t_result, t_retire_end,
                     t_dispatch, t_dispatch_end, now(), live, len(admits), fused, kv_blocks,
                 ))
+
+    def _fold_routing_counts(self) -> None:
+        """Read the copy of the pool's routing counts taken at a gauge tick:
+        by now the step it was enqueued behind has been retired (or nothing is
+        in flight), so the read waits for no device work. The device sums
+        modulo 2**32; the differences between reads add up here."""
+        import numpy as np
+
+        (copy, step), self._moe_copy = self._moe_copy, None
+        now = [int(v) for v in np.asarray(copy)]
+        seen, self._moe_seen = self._moe_seen, now
+        for i, (a, b) in enumerate(zip(seen, now)):
+            delta = (b - a) % (1 << 32)
+            self._moe_total[i] += delta
+            if delta:
+                self._m_moe[i].inc(delta)
+        if self._tel is not None:
+            self._record(("m", time.time_ns(), step, *self._moe_total, self._moe_layers))
 
     def _fold_prefills(self, admits: List[tuple]) -> None:
         """The token series of this iteration's prefills that reached a

@@ -34,36 +34,34 @@ def test_pipeline_matches_sequential(cpu_mesh_devices):
 
 
 def test_moe_expert_parallel_matches_single(cpu_mesh_devices):
-    from ray_tpu.models.moe import (
-        MoEConfig,
-        init_moe_params,
-        moe_mlp,
-        moe_param_logical_axes,
-    )
+    from ray_tpu.models.moe import expert_layer, expert_param_logical_axes, init_expert_params
     from ray_tpu.parallel.sharding import DEFAULT_LM_RULES, infer_param_sharding
 
-    cfg = MoEConfig(d_model=32, d_ff=64, num_experts=8, top_k=2, capacity_factor=2.0)
-    params = init_moe_params(jax.random.PRNGKey(0), cfg)
-    x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, 32))
-    y_ref, aux_ref = moe_mlp(params, x, cfg)
+    # 8 routed experts, all held, and 4 identity experts; top-3 of 12 outputs
+    params = init_expert_params(jax.random.PRNGKey(0), 32, 64, held=8, n_outputs=12)
+    params["router_bias"] = 0.01 * jax.random.normal(jax.random.PRNGKey(2), (12,))
+    x = jax.random.normal(jax.random.PRNGKey(1), (32, 32))
+    kw = dict(n_routed=8, top_k=3, scale=6.0)
+    y_ref, counts_ref = expert_layer(params, x, **kw)
 
     mesh = create_mesh(MeshConfig(expert=8))
-    shardings = infer_param_sharding(moe_param_logical_axes(), DEFAULT_LM_RULES, mesh)
+    shardings = infer_param_sharding(expert_param_logical_axes(), DEFAULT_LM_RULES, mesh)
     params_sh = jax.tree.map(lambda p, s: jax.device_put(p, s), params, shardings)
-    y_ep, aux_ep = jax.jit(lambda p, xx: moe_mlp(p, xx, cfg))(params_sh, x)
+    y_ep, counts_ep = jax.jit(lambda p, xx: expert_layer(p, xx, **kw))(params_sh, x)
     np.testing.assert_allclose(np.asarray(y_ep), np.asarray(y_ref), atol=1e-5)
-    assert abs(float(aux_ep) - float(aux_ref)) < 1e-5
+    np.testing.assert_array_equal(np.asarray(counts_ep), np.asarray(counts_ref))
 
 
-def test_moe_capacity_drops_overflow():
-    from ray_tpu.models.moe import MoEConfig, init_moe_params, moe_mlp
+def test_moe_routes_every_row_whatever_the_load():
+    from ray_tpu.models.moe import expert_layer, init_expert_params
 
-    # capacity far below demand: outputs are partially zero but finite
-    cfg = MoEConfig(d_model=16, d_ff=32, num_experts=2, top_k=1, capacity_factor=0.25)
-    params = init_moe_params(jax.random.PRNGKey(0), cfg)
-    x = jax.random.normal(jax.random.PRNGKey(1), (1, 32, 16))
-    y, aux = moe_mlp(params, x, cfg)
-    assert np.all(np.isfinite(np.asarray(y)))
+    # what was the capacity overflow case: 32 tokens, 2 experts, top-1. There
+    # is no capacity any more: every row reaches its expert and none is zero
+    params = init_expert_params(jax.random.PRNGKey(0), 16, 32, held=2, n_outputs=2)
+    x = jax.random.normal(jax.random.PRNGKey(1), (32, 16))
+    y, counts = expert_layer(params, x, n_routed=2, top_k=1, scale=1.0)
+    assert np.asarray(counts).tolist()[:3] == [32, 0, 0]
+    assert np.all(np.isfinite(np.asarray(y))) and np.all(np.abs(np.asarray(y)).sum(-1) > 0)
 
 
 def test_mnist_mlp_learns_synthetic(cpu_mesh_devices):

@@ -141,6 +141,40 @@ def test_paged_decode_and_prefill_at_gptj_widths(one_chip, monkeypatch):
     assert "tpu_custom_call" not in text
 
 
+def test_latent_decode_and_prefill_at_longcat_widths(one_chip):
+    """serve.llm's programs for LongCat-Flash's language model at the published
+    widths, one chip's share of the experts (16 of 512), 2 layers. The decode
+    step holds the grouped matmul over the held experts, gathers a table's
+    blocks and never re-lays the pool out: its rows are stored 640 wide (576
+    values: the TPU gives such a pool another device layout than the one the
+    program computes in, and copies it whole, in and out, every step)."""
+    import re
+
+    from ray_tpu.models import longcat as M
+
+    cfg = M.LongcatConfig(vocab_size=16384, num_layers=2, experts_held=16)
+    block, blocks, batch, per_seq = 16, 4097, 32, 128
+    prefill, _, decode_greedy = M.make_paged_fns(cfg, block_size=block)
+    params = _on(one_chip, jax.eval_shape(lambda: M.init_params(jax.random.PRNGKey(0), cfg)))
+    pool = _on(one_chip, jax.eval_shape(lambda: M.init_paged_pool(cfg, blocks, block)))
+    assert pool["latent"].shape == (4, blocks, block, 640) and M.paged_block_bytes(cfg, block) == 4 * 16 * 1280
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = decode_greedy.lower(
+        params, arg((batch,), jnp.int32), arg((batch,), jnp.int32), arg((batch, per_seq), jnp.int32), pool,
+        arg((batch,), jnp.bool_),
+    ).compile().as_text()
+    assert text.count("ragged-dot") >= 3  # gate, up and down of the held experts
+    made = re.findall(r"= \w+(\[[\d,]*\])\S* (?:gather|copy|transpose)\(", text)
+    assert f"[{batch},{per_seq},{block},640]" in made  # a table's blocks, gathered
+    assert f"[4,{blocks},{block},640]" not in made  # the pool itself: scattered into in place
+    compiled = prefill.lower(
+        params, arg((1, 1024), jnp.int32), arg((1, per_seq), jnp.int32), pool, arg((), jnp.int32)).compile()
+    assert "gather(" not in "".join(line for line in compiled.as_text().splitlines() if f",{block},640]" in line)
+
+
 def _steered_to_tpu(monkeypatch):
     """``attention`` asks ``jax.default_backend()``, which is the CPU here:
     the test steers it to the branch it takes on the chip."""
