@@ -29,7 +29,7 @@ LLM_STEP_FIELDS = (
     "step",  # decode steps dispatched so far, this one included
     "t_loop",  # top of the iteration, after any idle wait
     "t_admit_end",  # after the last prefill of the iteration
-    "t_result",  # the in-flight step's result is on the host
+    "t_result",  # the oldest in-flight step's result is on the host
     "t_retire_end",
     "t_dispatch",
     "t_dispatch_end",  # around the call of the decode program
@@ -38,6 +38,8 @@ LLM_STEP_FIELDS = (
     "prefills",  # prefills done in the iteration
     "fused",  # 1 = the greedy (on-device argmax) program ran
     "kv_blocks",  # live KV blocks of the dispatched sequences: what the step's attention reads
+    "ahead",  # decode steps still in flight when this iteration dispatched its own: 1 = the loop ran ahead
+    "overrun",  # rows of the step(s) retired in this iteration whose sequence had already ended (an EOS seen a step late)
 )
 # one finished, failed or shed request
 LLM_REQUEST_FIELDS = (

@@ -12,8 +12,14 @@ sequence one token per step:
   the slots.
 * **in-flight batching** — new requests join the running batch at step
   boundaries; nobody waits for a "batch" to form or drain.
-* **immediate reclamation** — a finished sequence frees its KV blocks at
-  the step boundary it finishes on, not when its batch cohort ends.
+* **immediate reclamation** — a sequence frees its slot and its KV blocks
+  when its last step is dispatched (the host counts the rows in flight), or
+  when an EOS is read; not when its batch cohort ends.
+* **one step ahead** — while every live request is greedy the newest token
+  of each slot stays on the device: step k+1 is enqueued before step k is
+  read, a newcomer's prefill is enqueued and its first token read later, in
+  device order. A sampled request puts the loop back to one step in flight
+  (``_loop`` says what is in flight when).
 * **KV-aware admission** — ``submit`` reserves a request's worst-case
   block need (prompt + max_new_tokens) up front; when the reservation
   cannot fit, it sheds with the serve plane's typed
@@ -29,11 +35,15 @@ identical to isolated decode (tested).
 What the loop measures of itself (schema in ``_private/looplog.py``). Every
 stamp is ``time.time_ns()``, the clock a profiler trace's events are on. One
 fixed-size tuple per iteration: top of the iteration, end of its prefills,
-the in-flight step's result on the host, end of retire, around the dispatch,
-end of the iteration. Phases follow by subtraction: ``t_admit_end - t_loop``
-is how long prefills held every stream, ``t_result - t_admit_end`` how long
-the thread waited for the device, ``t_dispatch_end - t_result`` the host work
-the device waits for. A request that ends leaves a record too and, when its
+the oldest in-flight step's result on the host, end of retire, around the
+dispatch, end of the iteration; then ``ahead`` (steps still in flight at the
+dispatch) and ``overrun`` (rows read for sequences that had ended). Phases
+follow by subtraction: ``t_admit_end - t_loop`` is the host's time to enqueue
+the prefills (run ahead, nobody waits for them there; a sampled request's is
+waited for), ``t_result - t_admit_end`` how long the thread waited for the
+device, ``t_dispatch_end - t_result`` the host's turn, which the device waits
+for only with one step in flight. What a stream waits for a token is the
+series ``ray_tpu_llm_decode_step_ms``, retire to retire. A request that ends leaves a record too and, when its
 caller was traced, three spans under the caller's span (``llm.queue_wait``,
 ``llm.prefill``, ``llm.decode``). The records ride the telemetry batches to
 ``<session_dir>/loops/`` and the newest stay in a bounded ring in the process
@@ -57,6 +67,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import itertools
 import os
 import queue
@@ -108,11 +119,11 @@ def _engine_metrics() -> dict:
         )
         _metrics["step"] = Histogram(
             "ray_tpu_llm_decode_step_ms",
-            "host wall time from the top of one decode step's dispatch to "
-            "the end of its retire in the next loop iteration: the device "
-            "step plus everything the loop does in between (emit, the next "
-            "iteration's prefills), on the monotonic clock; the loop records "
-            "split it (loop_stats)",
+            "host wall time from the end of the previous decode step's retire "
+            "(or the top of this step's dispatch, where that came later: one "
+            "step in flight) to the end of this step's retire, on the "
+            "monotonic clock: what a stream waits for its next token; the "
+            "loop records split it (loop_stats)",
             tag_keys=("deployment",),
         )
         _metrics["moe_rows"] = Counter(
@@ -154,6 +165,23 @@ class EngineConfig:
     stream_timeout_s: float = 120.0
 
 
+@functools.lru_cache(maxsize=None)
+def _take_first_token_program():
+    """The one device program of the engine's own (``jit_take_first_token``):
+    a prefill's first token, taken where the logits are. ``newest (B,) int32``
+    with entry ``slot`` replaced by ``argmax(logits[0])``, the first maximum
+    as numpy's argmax on the host picks; nothing donated, since ``newest`` is
+    also a step's result that the host has still to read."""
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def take_first_token(newest, logits, slot):
+        return newest.at[slot].set(jnp.argmax(logits[0], axis=-1).astype(newest.dtype))
+
+    return take_first_token
+
+
 # loop and request records kept in the process for loop_stats(); at 20 steps
 # a second this is the last three minutes
 LOOP_RING = 4096
@@ -183,15 +211,41 @@ class _Request:
 
 
 class _Running:
-    """One occupied decode slot: request + block table + decode state."""
+    """One admitted sequence: request + block table + decode state. It holds
+    a decode slot (``slot``) until its last step has been dispatched or it has
+    ended; the steps in flight keep it until their rows are read."""
 
-    __slots__ = ("req", "table", "last_token", "generated")
+    __slots__ = ("req", "table", "slot", "last_token", "generated", "dispatched", "reason")
 
-    def __init__(self, req: _Request, table: BlockTable, first_token: int):
+    def __init__(self, req: _Request, table: BlockTable, slot: int):
         self.req = req
         self.table = table
-        self.last_token = first_token
-        self.generated = 1
+        self.slot: Optional[int] = slot  # None once detached: blocks released, the slot free
+        self.last_token = 0  # the newest token the host has read
+        self.generated = 0  # tokens the host has read
+        self.dispatched = 1  # tokens read or in flight: the prefill's, then a row a step
+        self.reason: Optional[str] = None  # why it ended; None while it runs
+
+
+class _Step:
+    """One decode step in flight: its rows ``(slot, sequence)``, its result
+    still on the device, whether the greedy program ran, ``perf_counter_ns``
+    at the top of its dispatch, and the live KV blocks its attention reads."""
+
+    __slots__ = ("rows", "out", "fused", "t0", "kv_blocks")
+
+    def __init__(self, rows, out, fused, t0, kv_blocks):
+        self.rows, self.out, self.fused, self.t0, self.kv_blocks = rows, out, fused, t0, kv_blocks
+
+
+class _First:
+    """A newcomer's first token in flight: entry ``slot`` of ``vec``, the
+    device's token vector as its prefill left it."""
+
+    __slots__ = ("run", "slot", "vec")
+
+    def __init__(self, run, slot, vec):
+        self.run, self.slot, self.vec = run, slot, vec
 
 
 class TokenStream:
@@ -293,6 +347,16 @@ class InferenceEngine:
         )
         self._bytes_per_block = int(model.paged_block_bytes(model_cfg, ecfg.block_size))
         self.decode_steps = 0  # dispatched so far: a step's number
+        # what the loop has enqueued on the device and not yet read, in device
+        # order: decode steps and newcomers' first tokens (the loop's alone)
+        self._flight: "collections.deque" = collections.deque()
+        # the newest token of every slot, on the device: the greedy step's
+        # output with newcomers' first tokens written over their slots; the
+        # next step's ``tokens`` while anything is in flight
+        self._newest = None
+        self._take_first_token = _take_first_token_program()
+        self._steps_retired = 0
+        self._retired_at = 0  # perf_counter_ns at the end of the newest retire
         # a model with an expert layer sums its routing counts on the device,
         # in a leaf of the pool; the loop copies them out once a flush interval
         self._routing_counts = getattr(model, "routing_counts", None)
@@ -347,12 +411,16 @@ class InferenceEngine:
                 stream._fail(err)
                 ended.append((req, "shutdown", now, 1 if req.t_first else 0))
             self._waiting.clear()
-            for i, run in enumerate(self._slots):
-                if run is not None:
-                    run.table.release()
-                    self._committed_blocks -= run.req.need_blocks
+            # the loop's last iteration read what was in flight; a loop that
+            # did not get there in time leaves it here
+            unread = [f.run for f in self._flight if type(f) is _First]
+            unread += [run for f in self._flight if type(f) is _Step for _i, run in f.rows]
+            self._flight.clear()
+            for run in [*self._slots, *unread]:
+                if run is not None and run.reason is None:
+                    run.reason = "shutdown"
+                    self._detach(run)
                     run.req.out._fail(err)
-                    self._slots[i] = None
                     ended.append((run.req, "shutdown", now, run.generated))
         for item in ended:
             self._close_request(*item)
@@ -495,6 +563,13 @@ class InferenceEngine:
         ``kv_blocks`` is what the dispatched steps' attention read: count of
         steps, sum and maximum of their live KV blocks (over a step's ``live``
         x ``max_blocks_per_seq`` it is the share of the tables that is live).
+        ``ahead``: count of dispatched steps and how many of them went out
+        with a step still in flight (sum over count is how often the loop ran
+        ahead); ``overrun``: count of retired steps and the rows of them that
+        were dropped because their sequence had already ended.
+        ``prefill`` now ends when the first token is read, which is after the
+        steps that were in flight before the prefill, and ``prefill_stall`` is
+        the host's time to enqueue the iteration's prefills.
         Empty with ``telemetry_enabled`` off."""
         ring = self._ring.copy()  # atomic against the loop's appends
         steps = [r[1:] for r in ring if r[0] == "s"]
@@ -504,10 +579,15 @@ class InferenceEngine:
             k: [] for k in ("queue_wait", "prefill", "prefill_stall", "device_wait", "dispatch_gap", "emit")
         }
         kv: List[int] = []
+        ahead: List[int] = []
+        overrun: List[int] = []
         for r in steps:
             d = dict(zip(LLM_STEP_FIELDS, r))
             if d["live"]:
                 kv.append(d["kv_blocks"])
+                ahead.append(d["ahead"])
+            if d["t_result"]:
+                overrun.append(d["overrun"])
             if d["prefills"]:
                 spans["prefill_stall"].append(d["t_admit_end"] - d["t_loop"])
             if d["t_result"]:
@@ -530,6 +610,8 @@ class InferenceEngine:
             "phases": {k: {"count": len(v), "sum_ns": sum(v), "max_ns": max(v, default=0)}
                        for k, v in spans.items()},
             "kv_blocks": {"count": len(kv), "sum": sum(kv), "max": max(kv, default=0)},
+            "ahead": {"count": len(ahead), "sum": sum(ahead)},
+            "overrun": {"count": len(overrun), "sum": sum(overrun)},
             # the newest read of the expert layers' routing counts (cumulative)
             "moe": dict(zip(LLM_MOE_FIELDS, routed[-1])) if routed else None,
         }
@@ -579,86 +661,127 @@ class InferenceEngine:
     # -- the loop -------------------------------------------------------
 
     def _has_active(self) -> bool:
+        """A sequence holds a slot, or work the loop enqueued is still unread."""
+        return bool(self._flight) or self._any_slot()
+
+    def _any_slot(self) -> bool:
         return any(s is not None for s in self._slots)
 
+    def _may_run_ahead(self, admits: List[tuple]) -> bool:
+        """Every live request decodes greedily, this iteration's newcomers
+        included, and so did the steps in flight: the next token of every slot
+        is, or will be, on the device without the host's doing."""
+        return not (
+            any(req.temperature > 0 for _i, req in admits)
+            or any(run is not None and run.req.temperature > 0 for run in self._slots)
+            or any(type(f) is _Step and not f.fused for f in self._flight)
+        )
+
     def _loop(self) -> None:
-        """One-step-pipelined scheduler: step k+1 is dispatched to the
-        device BEFORE step k's tokens are emitted to consumers, so queue
-        wakeups, series, gauges, request spans and the loop's own record
-        overlap device compute instead of extending the step critical
-        path. Before the dispatch the loop only reads the clock."""
-        now, mono = time.time_ns, time.perf_counter_ns
-        inflight = None
+        """The scheduler. An iteration admits, retires, dispatches, emits.
+
+        **What is in flight when.** Everything the loop enqueues on the device
+        goes into ``_flight`` in device order and is read in that order: a
+        decode step (its rows' next tokens) or a newcomer's first token. While
+        every live request is greedy the loop runs one step ahead: with steps
+        k-1 and k in flight it reads k-1, then dispatches k+1, whose ``tokens``
+        argument is the on-device vector of each slot's newest token (step
+        k's output, with a newcomer's first token written over its slot by
+        ``take_first_token``); positions, tables and ``active`` come from the
+        host, which knows them without the token values. So the device always
+        has a step queued behind the one it runs, and the host's turn costs it
+        nothing. A prefill is enqueued and not waited for: its first token is
+        read, and handed to its stream at once, just before the result of the
+        first step dispatched after it. Tokens of steps, queue wake-ups,
+        series, gauges, request spans and the loop's own record come after the
+        dispatch; before it the loop only reads the clock.
+
+        **What the host knows one step late.** A sequence's last step is known
+        when it is dispatched (the host counts rows in flight), so it gives
+        up its slot and its blocks there, and a newcomer's prefill, enqueued
+        behind that step, may take both. An EOS is seen when its step is read:
+        the sequence has one more row in flight, which is discarded
+        (``overrun``), inside its admission reservation.
+
+        **Why a sampled batch is not run ahead.** ``temperature > 0`` samples
+        on the host, from a row of logits: the next step's tokens exist only
+        once the last step has been read. From the iteration such a request is
+        admitted until it has ended the loop reads all that is in flight,
+        then dispatches from the host's tokens: one step in flight, as ever.
+        The loop chooses by what it sees in its slots, not by a setting."""
+        now, flight = time.time_ns, self._flight
         while True:
             admits: List[tuple] = []
             with self._cv:
-                while (
-                    not self._stop
-                    and not self._waiting
-                    and not self._has_active()
-                    and inflight is None
-                ):
+                while not (self._stop or self._waiting or flight or self._any_slot()):
                     self._cv.wait(self.cfg.idle_poll_s)
-                if self._stop:
+                stopping = self._stop  # one last iteration reads what is in flight
+                if stopping and not flight:
                     return
                 t_loop = now()
                 for i, slot in enumerate(self._slots):
-                    if slot is None and self._waiting:
-                        req, stream = self._waiting.pop(0)
+                    if slot is None and self._waiting and not stopping:
+                        req, _stream = self._waiting.pop(0)
                         req.t_admit = t_loop
-                        admits.append((i, req, stream))
+                        admits.append((i, req))
             # requests that end in this iteration: (request, reason, when,
-            # tokens), closed after the dispatch
+            # tokens, the error of one that failed), told and closed after the
+            # dispatch and after the tokens read before they ended are out;
+            # and those whose first token reached the host in it
             ended: List[tuple] = []
+            firsts: List[_Request] = []
+            ahead = not stopping and self._may_run_ahead(admits)
             t_admit_end = t_loop
             if admits:
                 with annotate("llm.admit", requests=len(admits)):
-                    for slot_idx, req, stream in admits:
-                        self._do_prefill(slot_idx, req, stream, ended)
+                    for slot_idx, req in admits:
+                        self._do_prefill(slot_idx, req, ahead, ended, firsts)
                 t_admit_end = now()
+            flying = sum(type(f) is _Step for f in flight)
+            if ahead:
+                # the older of two; or the last one, when no slot is left to dispatch
+                steps = 1 if flying > (1 if self._any_slot() else 0) else 0
+            else:
+                steps = flying
             emissions: List[tuple] = []
-            finishes: List[tuple] = []
-            t_result = t_retire_end = step_ns = 0
-            if inflight is not None:
-                with annotate("llm.retire", step=inflight[4]):
-                    emissions, finishes, t_result = self._retire_step(inflight, ended)
+            step_ms: List[float] = []
+            t_result = t_retire_end = overrun = 0
+            if flight and (steps or not flying):
+                with annotate("llm.retire", step=self._steps_retired + 1):
+                    t_result, overrun = self._retire(steps, ended, firsts, emissions, step_ms)
                 t_retire_end = now()
-                # the step's series value, on the monotonic clock as it always
-                # was: from the top of its dispatch to the end of its retire
-                step_ns = mono() - inflight[3]
-                inflight = None
-            # finished slots detach (blocks freed) before the next
-            # dispatch; their streams see the 'done' marker after their
-            # final token below
-            for slot_idx, run, reason in finishes:
-                self._detach_slot(slot_idx)
-                ended.append((run.req, reason, t_retire_end, run.generated))
-            t_dispatch = t_dispatch_end = live = fused = kv_blocks = 0
-            if self._has_active():
+            t_dispatch = t_dispatch_end = live = fused = kv_blocks = n_ahead = 0
+            if self._any_slot() and not stopping:
                 t_dispatch = now()
+                n_ahead = flying - steps
                 with annotate("llm.dispatch", step=self.decode_steps + 1):
-                    inflight = self._dispatch_step(mono(), ended)
+                    step = self._dispatch_step(ended)
                 t_dispatch_end = now()
-                if inflight is not None:
-                    live, fused, kv_blocks = len(inflight[0]), int(inflight[2]), inflight[5]
+                if step is not None:
+                    live, fused, kv_blocks = len(step.rows), int(step.fused), step.kv_blocks
             # ---- the device is busy (or there is nothing for it to do) ----
             with annotate("llm.emit"):
                 for stream, tok in emissions:
                     stream._emit(tok)
-                for _slot_idx, run, reason in finishes:
-                    run.req.out._finish(reason)
-                if t_result:
-                    self._m_step.observe(step_ns / 1e6)
-                    self._m_decode_tokens.inc(len(emissions))
-                if admits:
-                    self._fold_prefills(admits)
-                if admits or finishes or ended:
+                for req, reason, _when, _tokens, error in ended:
+                    if error is None:
+                        req.out._finish(reason)  # after its final token
+                    else:
+                        req.out._fail(error)
+                for ms in step_ms:
+                    self._m_step.observe(ms)
+                if emissions or firsts:
+                    # each first token counts under ``decode`` too, as it always has
+                    self._m_decode_tokens.inc(len(emissions) + len(firsts))
+                if firsts:
+                    self._m_prefill_tokens.inc(sum(len(r.prompt) for r in firsts))
+                if admits or ended:
                     self._m_running.set(float(sum(1 for s in self._slots if s is not None)))
                     self._m_waiting.set(float(len(self._waiting)))
                 for item in ended:
-                    self._close_request(*item)
-                drained = inflight is None and not self._waiting
-                if self._moe_copy is not None and (drained or self._moe_copy[1] < self.decode_steps):
+                    self._close_request(*item[:4])
+                drained = not flight and not self._waiting
+                if self._moe_copy is not None and (drained or self._moe_copy[1] <= self._steps_retired):
                     self._fold_routing_counts()
                 if drained or t_loop - self._gauges_at >= self._gauge_period_ns:
                     self._gauges_at = t_loop
@@ -671,7 +794,10 @@ class InferenceEngine:
                 self._record((
                     "s", self.decode_steps, t_loop, t_admit_end, t_result, t_retire_end,
                     t_dispatch, t_dispatch_end, now(), live, len(admits), fused, kv_blocks,
+                    n_ahead, overrun,
                 ))
+            if stopping:
+                return
 
     def _fold_routing_counts(self) -> None:
         """Read the copy of the pool's routing counts taken at a gauge tick:
@@ -691,14 +817,6 @@ class InferenceEngine:
         if self._tel is not None:
             self._record(("m", time.time_ns(), step, *self._moe_total, self._moe_layers))
 
-    def _fold_prefills(self, admits: List[tuple]) -> None:
-        """The token series of this iteration's prefills that reached a
-        first token (each counts under ``decode`` too, as it always has)."""
-        done = [req for _i, req, _s in admits if req.t_first]
-        if done:
-            self._m_prefill_tokens.inc(sum(len(r.prompt) for r in done))
-            self._m_decode_tokens.inc(len(done))
-
     # -- phases ---------------------------------------------------------
 
     def _bucket(self, n: int) -> int:
@@ -712,7 +830,7 @@ class InferenceEngine:
         by (seed, step) only, so sampling is batch-composition invariant."""
         import numpy as np
 
-        if req.temperature and req.temperature > 0:
+        if req.temperature > 0:
             tok = self._G.sample_token(
                 logits_row,
                 temperature=req.temperature,
@@ -722,33 +840,75 @@ class InferenceEngine:
             return int(np.asarray(tok))
         return int(np.asarray(logits_row).argmax())
 
-    def _detach_slot(self, slot_idx: int) -> None:
-        """Free a finished slot's KV blocks + admission reservation (the
-        stream's 'done' marker is the caller's job, ordered after the
-        final token emission)."""
-        run = self._slots[slot_idx]
+    def _detach(self, run: _Running) -> None:
+        """Give up a sequence's slot, KV blocks and admission reservation,
+        once: when its last step has been dispatched or it has ended, whichever
+        comes first. What the device has still to do with the blocks was
+        enqueued before anything their next owner will enqueue."""
+        if run.slot is None:
+            return
         run.table.release()  # blocks return to the pool immediately
         with self._cv:
             self._committed_blocks -= run.req.need_blocks
-            self._slots[slot_idx] = None
-            self._streams.pop(run.req.id, None)
+            self._slots[run.slot] = None
             self._cv.notify_all()
+        run.slot = None
 
-    def _fail_slot(self, slot_idx: int, error: BaseException, ended: List[tuple]) -> None:
-        run = self._slots[slot_idx]
-        run.table.release()
-        with self._cv:
-            self._committed_blocks -= run.req.need_blocks
-            self._slots[slot_idx] = None
-            self._streams.pop(run.req.id, None)
-        run.req.out._fail(error)
-        ended.append((run.req, "error", time.time_ns(), run.generated))
+    def _fail_run(self, run: _Running, error: BaseException, ended: List[tuple]) -> None:
+        """A step or a prefill of this sequence failed: its stream fails, once,
+        however many of its rows are in flight (the loop tells it after the
+        tokens that were read before)."""
+        if run.reason is not None:
+            return
+        run.reason = "error"
+        self._detach(run)
+        self._streams.pop(run.req.id, None)
+        ended.append((run.req, "error", time.time_ns(), run.generated, error))
 
-    def _do_prefill(self, slot_idx: int, req: _Request, stream: TokenStream, ended: List[tuple]) -> None:
+    def _take(self, run: _Running, tok: int, ended: List[tuple]) -> None:
+        """One more token of a sequence is on the host. Where it was the last
+        the sequence ends: the slot is free if it was still held."""
+        run.generated += 1
+        run.last_token = tok
+        if run.req.eos_token is not None and tok == run.req.eos_token:
+            run.reason = "stop"
+        elif run.generated >= run.req.max_new_tokens:
+            run.reason = "length"
+        else:
+            return
+        self._detach(run)
+        self._streams.pop(run.req.id, None)
+        ended.append((run.req, run.reason, time.time_ns(), run.generated, None))
+
+    def _first_token(self, run: _Running, tok: int, ended: List[tuple], firsts: List[_Request]) -> None:
+        """A request's first token is on the host: to its stream at once."""
+        req = run.req
+        req.t_first = time.time_ns()
+        firsts.append(req)
+        req.out._emit(tok, req.t_first)  # TTFT: submit -> first token
+        self._take(run, tok, ended)
+
+    def _host_tokens(self):
+        """The newest token of every slot as the host has read it: the whole
+        truth whenever nothing is in flight."""
+        import jax.numpy as jnp
+        import numpy as np
+
+        return jnp.asarray(np.asarray([0 if run is None else run.last_token for run in self._slots], np.int32))
+
+    def _do_prefill(
+        self, slot_idx: int, req: _Request, ahead: bool, ended: List[tuple], firsts: List[_Request]
+    ) -> None:
+        """Enqueue a newcomer's prefill and give it the slot. Running ahead,
+        its first token is taken on the device (argmax of the logits' row,
+        the first maximum as numpy's) into the slot's entry of the token
+        vector, and read later, in device order; else here, as the request
+        samples."""
         import numpy as np
         import jax.numpy as jnp
 
         req.bucket = bucket = self._bucket(len(req.prompt))
+        table = None
         try:
             with annotate("llm.prefill", bucket=bucket, prompt_len=len(req.prompt)):
                 table = BlockTable(self._alloc)
@@ -766,55 +926,45 @@ class InferenceEngine:
                     self._pool,
                     jnp.int32(len(req.prompt)),
                 )
-                first = self._sample(logits[0], req, step=0)
+                if ahead:
+                    base = self._newest if self._flight else self._host_tokens()
+                    self._newest = self._take_first_token(base, logits, np.int32(slot_idx))
+                else:
+                    first = self._sample(logits[0], req, step=0)
         except BaseException as e:  # noqa: BLE001 — typed failure to the stream
-            try:
+            if table is not None:
                 table.release()
-            except Exception:
-                pass
             with self._cv:
                 self._committed_blocks -= req.need_blocks
                 self._streams.pop(req.id, None)
-            stream._fail(e)
-            ended.append((req, "error", time.time_ns(), 0))
+            ended.append((req, "error", time.time_ns(), 0, e))
             return
-        req.t_first = time.time_ns()  # the first token is on the host
-        run = _Running(req, table, first)
+        run = _Running(req, table, slot_idx)
         self._slots[slot_idx] = run
-        stream._emit(first, req.t_first)  # TTFT: submit -> first token
-        if self._is_done(run, first):
-            self._detach_slot(slot_idx)
-            stream._finish(self._done_reason(run, first))
-            ended.append((req, self._done_reason(run, first), req.t_first, 1))
+        if not ahead:
+            self._first_token(run, first, ended, firsts)
+            return
+        self._flight.append(_First(run, slot_idx, self._newest))
+        if req.max_new_tokens <= 1:
+            self._detach(run)  # no step to come: the slot is the next newcomer's
 
-    def _is_done(self, run: _Running, token: int) -> bool:
-        return (
-            run.generated >= run.req.max_new_tokens
-            or (run.req.eos_token is not None and token == run.req.eos_token)
-        )
-
-    def _done_reason(self, run: _Running, token: int) -> str:
-        if run.req.eos_token is not None and token == run.req.eos_token:
-            return "stop"
-        return "length"
-
-    def _dispatch_step(self, t0: int, ended: List[tuple]):
+    def _dispatch_step(self, ended: List[tuple]) -> Optional["_Step"]:
         """Enqueue one decode step on the device and return without
-        waiting for it: ``(live slots, result, fused, t0, step number, live
-        KV blocks)``; ``t0`` is the caller's ``perf_counter_ns`` at the top of
-        the dispatch, the last is what the step's attention reads.
-        A batch where every sequence decodes greedily uses the fused-argmax
-        step (B ints cross back to the host, not B x vocab logits)."""
+        waiting for it. A batch where every sequence decodes greedily uses
+        the fused-argmax step (B ints cross back to the host, not B x vocab
+        logits); its tokens come from the device's vector while anything is
+        in flight, from the host's copy otherwise. A sequence whose last row
+        this is gives up its slot here."""
         import numpy as np
         import jax.numpy as jnp
 
+        t0 = time.perf_counter_ns()
         b = self.cfg.max_batch
         mb = self.cfg.max_blocks_per_seq
-        tokens = np.zeros((b,), np.int32)
         positions = np.zeros((b,), np.int32)
         tables = np.zeros((b, mb), np.int32)
         active = np.zeros((b,), bool)
-        live: List[int] = []
+        rows: List[tuple] = []
         kv_blocks = 0
         fused = True
         for i, run in enumerate(self._slots):
@@ -825,58 +975,90 @@ class InferenceEngine:
             # reservation to succeed
             pos = run.table.length
             run.table.append_token()
-            tokens[i] = run.last_token
             positions[i] = pos
             tables[i] = run.table.as_list(mb)
             active[i] = True
-            live.append(i)
+            rows.append((i, run))
             kv_blocks += len(run.table.blocks)
-            if run.req.temperature and run.req.temperature > 0:
+            if run.req.temperature > 0:
                 fused = False
         fn = self._decode_greedy if fused else self._decode
         try:
             out, self._pool = fn(
                 self.params,
-                jnp.asarray(tokens),
+                self._newest if self._flight else self._host_tokens(),
                 jnp.asarray(positions),
                 jnp.asarray(tables),
                 self._pool,
                 jnp.asarray(active),
             )
         except BaseException as e:  # noqa: BLE001
-            for i in list(live):
-                self._fail_slot(i, e, ended)
+            for _i, run in rows:
+                self._fail_run(run, e, ended)
             return None
+        self._newest = out if fused else None
         self.decode_steps += 1
-        return (live, out, fused, t0, self.decode_steps, kv_blocks)
+        step = _Step(rows, out, fused, t0, kv_blocks)
+        self._flight.append(step)
+        for _i, run in rows:
+            run.dispatched += 1
+            if run.dispatched >= run.req.max_new_tokens:
+                self._detach(run)  # its last row is in flight
+        return step
 
-    def _retire_step(self, inflight, ended: List[tuple]) -> tuple:
-        """Block on the in-flight step's result and fold it into the run
-        states. Returns ``(emissions, finishes, t_result)`` for the loop to
-        deliver AFTER it dispatches the next step; ``t_result`` is when the
-        result was on the host (0: the step failed)."""
+    def _retire(
+        self, steps: int, ended: List[tuple], firsts: List[_Request], emissions: List[tuple],
+        step_ms: List[float],
+    ) -> tuple:
+        """Read, in device order, the oldest ``steps`` decode steps in flight
+        with the first tokens enqueued before them, and the first tokens left
+        when no step is; each read blocks until the device has got there.
+        Step tokens go to ``emissions`` and sequences that ended to
+        ``ended``, for the loop to deliver AFTER its dispatch. Returns
+        ``(t_result, overrun)``: when the last step's result was on the host
+        (0: none was read, or it failed) and how many rows belonged to
+        sequences that had already ended."""
         import numpy as np
 
-        live, out, fused = inflight[:3]
-        try:
-            np_out = np.asarray(out)  # blocks until the device step lands
-        except BaseException as e:  # noqa: BLE001
-            for i in list(live):
-                if self._slots[i] is not None:
-                    self._fail_slot(i, e, ended)
-            return [], [], 0
-        t_result = time.time_ns()
-        emissions: List[tuple] = []
-        finishes: List[tuple] = []
-        for i in live:
-            run = self._slots[i]
-            if fused:
-                tok = int(np_out[i])
-            else:
-                tok = self._sample(np_out[i], run.req, step=run.generated)
-            run.generated += 1
-            run.last_token = tok
-            emissions.append((run.req.out, tok))
-            if self._is_done(run, tok):
-                finishes.append((i, run, self._done_reason(run, tok)))
-        return emissions, finishes, t_result
+        flight = self._flight
+        t_result = overrun = 0
+        while flight:
+            head = flight[0]
+            if type(head) is _Step:
+                if not steps:
+                    break
+                steps -= 1
+            elif not steps and any(type(f) is _Step for f in flight):
+                break  # read with the step behind it
+            flight.popleft()
+            if type(head) is _First:
+                if head.run.reason is None:
+                    try:
+                        tok = int(np.asarray(head.vec)[head.slot])
+                    except BaseException as e:  # noqa: BLE001
+                        self._fail_run(head.run, e, ended)
+                    else:
+                        self._first_token(head.run, tok, ended, firsts)
+                continue
+            self._steps_retired += 1
+            try:
+                np_out = np.asarray(head.out)  # blocks until the device step lands
+            except BaseException as e:  # noqa: BLE001
+                for _i, run in head.rows:
+                    self._fail_run(run, e, ended)
+                continue
+            t_result = time.time_ns()
+            for i, run in head.rows:
+                if run.reason is not None:
+                    overrun += 1  # it ended at an earlier read: the row is dropped
+                    continue
+                tok = int(np_out[i]) if head.fused else self._sample(np_out[i], run.req, step=run.generated)
+                emissions.append((run.req.out, tok))
+                self._take(run, tok, ended)
+            # the step's series value, on the monotonic clock: what a stream
+            # waited for this token, from the last retire's end (or the top of
+            # this step's dispatch, where that came later) to this one's
+            end = time.perf_counter_ns()
+            step_ms.append((end - max(self._retired_at, head.t0)) / 1e6)
+            self._retired_at = end
+        return t_result, overrun

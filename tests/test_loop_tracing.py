@@ -132,6 +132,39 @@ def test_live_sequences_over_records_are_the_decode_tokens_less_first_tokens(par
     assert [r[reason] for r in stats["requests"]] == ["length"] * 4
 
 
+def test_ahead_and_overrun_are_recorded_and_the_series_sums_to_the_runs_retire_to_retire_time(params, ray_start_regular):
+    eng = InferenceEngine(params, CFG, ECFG, deployment="loop-ahead")
+    try:
+        streams = [eng.submit([2, 3, 4 + i], max_new_tokens=10 + 3 * i) for i in range(3)]
+        assert [len(s.tokens()) for s in streams] == [10, 13, 16]
+        deadline = time.time() + 10
+        while eng._has_active() and time.time() < deadline:
+            time.sleep(0.01)
+        time.sleep(0.05)
+        stats = eng.loop_stats(records=10_000)
+    finally:
+        eng.shutdown()
+    assert stats["fields"] == looplog.LLM_STEP_FIELDS and stats["fields"][-2:] == ("ahead", "overrun")
+    recs = [dict(zip(stats["fields"], r)) for r in stats["records"]]
+    dispatching = [r for r in recs if r["live"]]
+    retiring = [r for r in recs if r["t_result"]]
+    assert len(dispatching) == len(retiring) == eng.decode_steps
+    # from idle the first step goes out alone; every later one behind a step in flight
+    assert [r["ahead"] for r in dispatching] == [0] + [1] * (len(dispatching) - 1)
+    assert stats["ahead"] == {"count": len(dispatching), "sum": len(dispatching) - 1}
+    assert stats["overrun"] == {"count": len(retiring), "sum": 0}  # every request ended by length
+    # a prefill holds the loop for its enqueue only; the first token is read behind it, in device order
+    reqs = [dict(zip(stats["request_fields"], r)) for r in stats["requests"]]
+    assert all(r["t_admit"] < r["t_first"] <= r["t_finish"] for r in reqs)
+    # the series: each retired step observes the time since the retire before
+    # it (the first: since the top of its own dispatch), so one unbroken run
+    # sums to the time from its first dispatch to its last retire
+    step = next(iter(_series("ray_tpu_llm_decode_step_ms", "loop-ahead").values()))
+    assert step["count"] == eng.decode_steps
+    run_ms = (retiring[-1]["t_retire_end"] - dispatching[0]["t_dispatch"]) / 1e6
+    assert step["sum"] == pytest.approx(run_ms, rel=0.02, abs=0.5)
+
+
 # -- request spans, under the caller's span -----------------------------------
 
 
@@ -432,3 +465,34 @@ def test_sessions_land_under_the_process_temporary_directory(monkeypatch, tmp_pa
     assert looplog.last_dir == str(tmp_path / "elsewhere" / "session_x" / "loops")
     (line,) = open(os.path.join(looplog.last_dir, "llm-a-1.jsonl")).read().splitlines()
     assert json.loads(line) == {"kind": "llm_step", **dict(zip(looplog.LLM_STEP_FIELDS, range(len(looplog.LLM_STEP_FIELDS))))}
+
+
+def test_loop_summary_tool_reads_the_share_ahead_and_a_newcomers_wait(tmp_path):
+    """``tools/loop_summary.py`` over records as the head writes them: the
+    time the batch was full, how often the loop ran ahead, what a newcomer
+    waited for its first token. A record older than ``ahead`` reads 0."""
+    import subprocess
+
+    ms = 1_000_000
+    fields = looplog.LLM_STEP_FIELDS
+
+    def step(i, live, ahead, **kw):
+        rec = dict.fromkeys(fields, 0)
+        rec.update(step=i, t_loop=i * 20 * ms, t_result=i * 20 * ms + 15 * ms, live=live, ahead=ahead, **kw)
+        return ("s", *(rec[k] for k in fields))
+
+    steps = [step(1, 1, 0), *(step(i, 2, 1) for i in range(2, 12)), step(12, 1, 1)]
+    old = steps[5][: 1 + fields.index("ahead")]  # as a program before the two fields wrote it
+    req = ("r", 7, 30 * ms, 60 * ms, 95 * ms, 200 * ms, 5, 8, 4, 3, "length", None)
+    log = looplog.LoopLog(str(tmp_path))
+    log.ingest({"llm-x-1": [*steps[:5], old, *steps[6:], req]})
+    log.close()
+    tool = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "tools", "loop_summary.py")
+    out = subprocess.run([sys.executable, tool, str(tmp_path / "loops"), "--skip-s", "0"],
+                         capture_output=True, text=True, check=True).stdout
+    got = json.loads(out)
+    assert got["slots"] == 2 and got["steps"] == 10 and got["overrun"] == 0
+    assert got["ahead_share"] == pytest.approx(0.9)  # nine of the ten say so, the old record nothing
+    assert got["result_to_result_ms"] == pytest.approx(20.0)
+    assert got["first_token_ms"] == {"count": 1, "mean_ms": 35.0, "median_ms": 35.0, "p90_ms": 35.0, "max_ms": 35.0}
+    assert got["queue_wait_ms"]["mean_ms"] == 30.0

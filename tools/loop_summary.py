@@ -1,0 +1,77 @@
+#!/usr/bin/env python3
+"""What the engine's loop records of one run say of the time the batch was full:
+
+    python tools/loop_summary.py <session_dir>/loops [--skip-s 4]
+
+One JSON object: over the iterations from the first to the last with every
+decode slot dispatched (less ``--skip-s`` seconds of ramp at the start), the
+share of dispatched steps that went out with a step still in flight
+(``ahead``), the rows dropped for an EOS seen a step late (``overrun``), the
+mean time between two results; and over the requests admitted in that time,
+``t_first - t_admit`` (a newcomer's wait for its first token, behind whatever
+was in flight) and ``t_admit - t_submit``. Records older than a field read 0
+there. Reads with the standard library alone; newest session under the
+temporary directory where no directory is given.
+"""
+
+from __future__ import annotations
+
+import argparse
+import glob
+import json
+import os
+import statistics
+import sys
+import tempfile
+
+from loop_trace_check import load_records  # the script's own directory: tools/
+
+
+def newest_loops_dir() -> str:
+    found = glob.glob(os.path.join(tempfile.gettempdir(), "ray_tpu_sessions", "*", "loops"))
+    if not found:
+        raise SystemExit("no <session_dir>/loops under the temporary directory")
+    return max(found, key=os.path.getmtime)
+
+
+def spread_ms(values_ns: list) -> dict:
+    if not values_ns:
+        return {"count": 0}
+    ms = sorted(v / 1e6 for v in values_ns)
+    return {"count": len(ms), "mean_ms": statistics.fmean(ms), "median_ms": statistics.median(ms),
+            "p90_ms": ms[min(len(ms) - 1, int(0.9 * len(ms)))], "max_ms": ms[-1]}
+
+
+def summarise(recs: dict, skip_s: float) -> dict:
+    steps = [r for r in recs["llm_step"] if r["live"]]
+    if not steps:
+        return {"steps": 0}
+    full = max(r["live"] for r in steps)
+    at_full = [r["t_loop"] for r in steps if r["live"] == full]
+    t0, t1 = min(at_full) + int(skip_s * 1e9), max(at_full)
+    window = [r for r in steps if t0 <= r["t_loop"] <= t1]
+    results = sorted(r["t_result"] for r in recs["llm_step"] if r["t_result"] and t0 <= r["t_loop"] <= t1)
+    reqs = [r for r in recs["llm_request"] if r["t_first"] and t0 <= r["t_admit"] <= t1]
+    return {
+        "slots": full, "seconds": (t1 - t0) / 1e9, "steps": len(window),
+        "ahead_share": sum(r.get("ahead", 0) for r in window) / max(len(window), 1),
+        "overrun": sum(r.get("overrun", 0) for r in recs["llm_step"] if t0 <= r["t_loop"] <= t1),
+        "mean_live": statistics.fmean(r["live"] for r in window) if window else 0.0,
+        "result_to_result_ms": (results[-1] - results[0]) / 1e6 / (len(results) - 1) if len(results) > 1 else None,
+        "first_token_ms": spread_ms([r["t_first"] - r["t_admit"] for r in reqs]),
+        "queue_wait_ms": spread_ms([r["t_admit"] - r["t_submit"] for r in reqs]),
+    }
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("loops", nargs="?", help="<session_dir>/loops (default: the newest session's)")
+    ap.add_argument("--skip-s", type=float, default=4.0)
+    args = ap.parse_args()
+    where = args.loops or newest_loops_dir()
+    print(json.dumps({"loops": where, **summarise(load_records(where), args.skip_s)}))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -92,8 +92,9 @@ def main() -> int:
                         offsets[base].append(max(abs(start - steps[step]["t_dispatch"]),
                                                  abs(end - steps[step]["t_dispatch_end"])))
                     elif base == "llm.retire" and step is not None:
-                        # step k is retired in the iteration that dispatches k+1 (or none)
-                        recs = [steps[s] for s in (step + 1, step) if s in steps and steps[s].get("t_retire_end")]
+                        # step k is retired in the iteration that dispatches k+2 (the loop
+                        # one step ahead), k+1 (one step in flight) or none
+                        recs = [steps[s] for s in (step + 2, step + 1, step) if s in steps and steps[s].get("t_retire_end")]
                         if recs:
                             offsets[base].append(min(abs(end - r["t_retire_end"]) for r in recs))
                     elif base == "train.report" and step in train and train[step].get("t2_ns"):
