@@ -14,7 +14,7 @@ PY ?= python
 # reproduce a failing chaos run kill-for-kill
 CHAOS_SEED ?= 1729
 
-.PHONY: all native cpp sanitize test test-fast chaos chaos-serve bench bench-isolation bench-trace trace-demo train-obs-demo bench-train-obs bench-net bench-launch bench-incidents bench-lm-decode bench-gate ci clean
+.PHONY: all native cpp sanitize test test-fast chaos chaos-serve bench bench-isolation bench-trace trace-demo train-obs-demo bench-train-obs bench-net bench-launch bench-incidents bench-gate ci clean
 
 all: native cpp
 
@@ -104,13 +104,6 @@ bench-launch:
 # BENCH_CORE.jsonl.
 bench-incidents:
 	JAX_PLATFORMS=cpu $(PY) bench_incidents.py --append
-
-# LM decode: static vs continuous batching tokens/s, serve-deployed TTFT
-# p50/p99 (tracing-plane stream spans via the controller fold, registers
-# the deployment_ttft_p99 SLO), and the >=100-stream KV saturation run.
-# Appends rows to BENCH_LM_DECODE.jsonl.
-bench-lm-decode:
-	$(PY) bench_lm_decode.py --mode all
 
 # bench regression gate: re-reads the BENCH_*.jsonl ledgers and fails
 # non-zero if the newest row of any *_overhead_ratio metric exceeds its

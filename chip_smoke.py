@@ -318,8 +318,8 @@ def serve_phase(size: dict, seed: int, tiny: bool) -> dict:
           f"kv_stats() shows the pool drained ({stats['blocks_free']}/{stats['blocks_total']} blocks free)")
     say(f"  replica device: {stats['platform']} / {stats['device_kind']} x {stats['device_count']}; "
         f"peak device bytes {stats['device_peak_bytes']}")
-    say("  attention path: generation._paged_attention (XLA einsum over gathered blocks; "
-        "the paged path has no kernel)")
+    say("  attention path: generation._attend_pool (decode steps: the Pallas kernel of ops/paged_attention.py; "
+        "a prefill: XLA einsum over its table's gathered blocks)")
     say(f"  compile cache {os.environ['JAX_COMPILATION_CACHE_DIR']}: "
         f"{entries_before} entries before, {cache_entries()} after")
 

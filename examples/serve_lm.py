@@ -36,7 +36,7 @@ class LMServer:
 
     def generate(self, tokens, max_new_tokens: int = 16):
         """Autoregressive completion on the KV-cache decode path
-        (``models/generation.py``; bench: ``bench_lm_decode.py``)."""
+        (``models/generation.py``)."""
         from ray_tpu.models.generation import generate, make_decode_fns
 
         # cache the jitted (prefill, decode_step) pair per shape — without

@@ -5,6 +5,8 @@ These play the role of the reference's example/benchmark workloads
 every model declares logical sharding axes so it runs under any mesh.
 """
 
+import importlib
+
 from ray_tpu.models.transformer import (
     TransformerConfig,
     forward,
@@ -26,13 +28,30 @@ from ray_tpu.models import vit  # noqa: E402  (ViT family: models/vit.py)
 __all__.append("vit")
 
 
+# The model kinds ``serve.llm`` runs: the ``kind`` a config dict names (None:
+# it names none) -> (its module, the name of its config class there). The module
+# gives ``models/paged.py`` a kind's four things; it is imported when asked for.
+PAGED_KINDS = {
+    None: ("ray_tpu.models.generation", "TransformerConfig"),
+    "longcat": ("ray_tpu.models.longcat", "LongcatConfig"),
+}
+
+
+def paged_config(kind):
+    """The config class of the model kind a config dict names."""
+    if kind not in PAGED_KINDS:
+        raise ValueError(f"unknown model kind {kind!r} (known: {sorted(k for k in PAGED_KINDS if k)})")
+    module, config = PAGED_KINDS[kind]
+    return getattr(importlib.import_module(module), config)
+
+
 def paged_model(cfg):
-    """The module that runs ``cfg`` over a paged pool for ``serve.llm``:
-    ``make_paged_fns``, ``init_paged_pool``, ``paged_block_bytes`` and
-    ``init_params``. The pool is the model's to shape; the engine asks here."""
-    from ray_tpu.models import generation, longcat
+    """The module of ``cfg``'s model kind. The pool is the kind's to shape;
+    the engine asks here."""
+    for module, config in PAGED_KINDS.values():
+        if type(cfg).__name__ == config:
+            return importlib.import_module(module)
+    raise TypeError(f"{type(cfg).__name__} is the config of no model kind in PAGED_KINDS")
 
-    return longcat if isinstance(cfg, longcat.LongcatConfig) else generation
 
-
-__all__.append("paged_model")
+__all__ += ["PAGED_KINDS", "paged_config", "paged_model"]

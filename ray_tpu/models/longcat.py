@@ -14,31 +14,25 @@ The expert layer is ``models/moe.py`` (a router over routed and zero-compute
 experts; this chip's share of the routed ones), the attention
 ``ops/latent_attention.py``. Key names follow the published ``config.json``.
 
+This module gives ``models/paged.py`` the four things it asks of a model kind.
 The paged cache is this model's own shape: one row a position a attention of
 ``kv_lora_rank + qk_rope_head_dim`` values (the latent after its norm and
-scale, and the rotated shared key), ``2 x num_layers`` attentions. The engine
-asks this module for the pool and for a block's bytes
-(``init_paged_pool``, ``paged_block_bytes``), and runs the same three programs
-as ``generation.make_paged_fns`` gives a ``TransformerConfig``. The pool also
-carries ``moe_counts``: what the decode steps' expert layers counted of their
-routing, summed on the device (``routing_counts`` copies them out).
+scale, and the rotated shared key), ``2 x num_layers`` attentions. The pool
+also carries ``moe_counts``: what the decode steps' expert layers counted of
+their routing, summed on the device (``routing_counts`` copies them out).
 """
 
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import moe
-from ray_tpu.models.generation import _kv_storage_dtype
 from ray_tpu.ops.latent_attention import latent_decode_attention, latent_prefill_attention, rope_interleaved
 from ray_tpu.ops.layers import rms_norm, swiglu
-
-KIND = "longcat"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -79,9 +73,9 @@ class LongcatConfig:
             raise ValueError(f"experts {self.expert_offset}..{self.expert_offset + held} are not among "
                              f"{self.n_routed_experts}")
 
-    @property
-    def max_seq_len(self) -> int:
-        return self.max_position_embeddings
+    # the names ``models/paged.py`` and the engine read
+    n_layers = property(lambda self: self.num_layers)
+    max_seq_len = property(lambda self: self.max_position_embeddings)
 
     @property
     def cache_row(self) -> int:
@@ -148,30 +142,18 @@ def init_params(key, cfg: LongcatConfig) -> Dict[str, Any]:
     }
 
 
-_UNSTACKED = ("embed", "unembed", "final_norm")
-_PER_LAYER = ("router", "router_bias")  # (layers, ...): a layer's slice is small
-_EXPERTS = ("e_gate", "e_up", "e_down")  # (layers, held, ...): read in place by the grouped matmul
-
-
-# -- the paged pool ------------------------------------------------------------
-
-
 def init_paged_pool(cfg: LongcatConfig, num_blocks: int, block_size: int) -> Dict:
-    """``latent``: (attentions, num_blocks, block_size, cache_row_stored),
-    block 0 the null block, 16-bit values held as raw bits as in
-    ``generation.init_paged_pool``. A block is the unit the decode step
+    """``latent``: (attentions, num_blocks, block_size, cache_row_stored) in
+    ``cfg.dtype``, block 0 the null block. A block is the unit the decode step
     gathers. ``moe_counts``: ``moe.COUNTS`` summed over the layers and decode
     steps so far, modulo 2**32."""
     shape = (2 * cfg.num_layers, num_blocks, block_size, cfg.cache_row_stored)
-    return {
-        "latent": jnp.zeros(shape, _kv_storage_dtype(cfg.dtype)),
-        "moe_counts": jnp.zeros((len(moe.COUNTS),), jnp.uint32),
-    }
+    return {"latent": jnp.zeros(shape, cfg.dtype), "moe_counts": jnp.zeros((len(moe.COUNTS),), jnp.uint32)}
 
 
 def paged_block_bytes(cfg: LongcatConfig, block_size: int) -> int:
     """Bytes one block of the pool holds over all attentions."""
-    return 2 * cfg.num_layers * block_size * cfg.cache_row_stored * jnp.dtype(_kv_storage_dtype(cfg.dtype)).itemsize
+    return 2 * cfg.num_layers * block_size * cfg.cache_row_stored * jnp.dtype(cfg.dtype).itemsize
 
 
 _copy = jax.jit(lambda x: x + 0)
@@ -184,143 +166,84 @@ def routing_counts(pool: Dict):
     return _copy(pool["moe_counts"])
 
 
-# -- the forward pass ----------------------------------------------------------
-
-
-def _forward_paged(params, tokens, positions, write_mask, block_tables, pool, cfg: LongcatConfig,
-                   block_size: int, last=None):
-    """``tokens`` (B, S) at ``positions`` (B, S); cache rows scattered into
-    the pool (``write_mask`` clear: to the null block). S > 1 is a prefill of
-    one prompt from position 0, which attends to its own rows per head; S == 1
-    is a decode step, which gathers each sequence's table (``max_blocks x
-    block_size`` latent rows) and attends in the absorbed form. ``last``: the
-    one position whose logits are wanted (a prefill), else all. Returns
-    (logits (B, S or 1, V), pool)."""
-    b, s = tokens.shape
-    t = b * s
-    decode = s == 1
-    if not decode and b != 1:
+def mla(cfg: LongcatConfig, w, att_index, h, rows_pool, step):
+    """One latent attention over ``h`` (T, D), its weights read by ``w(name)``:
+    the cache rows scattered into attention ``att_index`` of the pool; then a
+    prefill (S > 1: one prompt from position 0) attends to its own rows per head,
+    and a decode step (S == 1) gathers each sequence's table (``max_blocks x
+    block_size`` latent rows) and attends in the absorbed form."""
+    b, s = step.positions.shape
+    t, bs = b * s, step.block_size
+    if s > 1 and b != 1:
         raise ValueError("a prefill takes one prompt")
-    mb = block_tables.shape[1]
-    heads, eps, dn, dr, rkv = (cfg.num_attention_heads, cfg.rms_norm_eps, cfg.qk_nope_head_dim,
-                               cfg.qk_rope_head_dim, cfg.kv_lora_rank)
-    att_scale = (dn + dr) ** -0.5
-    x = params["embed"][tokens].reshape(t, -1)
+    heads, eps, dn, rkv = cfg.num_attention_heads, cfg.rms_norm_eps, cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    att_scale = (dn + cfg.qk_rope_head_dim) ** -0.5
+    cq = rms_norm(h @ w("wqa"), w("qa_norm") * cfg.scale_q, eps)
+    q = jnp.einsum("tr,kr->tk", cq, w("wqb")).reshape(b, s, heads, -1)
+    q_n, q_r = q[..., :dn], rope_interleaved(q[..., dn:], step.positions, cfg.rope_theta)
+    kva = jnp.einsum("td,rd->tr", h, w("wkva")).reshape(b, s, -1)
+    ckv = rms_norm(kva[..., :rkv], w("kva_norm") * cfg.scale_kv, eps)
+    k_r = rope_interleaved(kva[..., rkv:], step.positions, cfg.rope_theta)
+    new_rows = jnp.concatenate([ckv, k_r], axis=-1).astype(cfg.dtype)
+    with jax.named_scope("latent_scatter"):
+        flat = jnp.pad(new_rows.reshape(t, -1), ((0, 0), (0, cfg.cache_row_stored - cfg.cache_row)))
+        rows_pool = rows_pool.at[att_index, step.write_slots // bs, step.write_slots % bs].set(flat)
+    wkvb = w("wkvb")
+    if s == 1:
+        with jax.named_scope("latent_gather"):
+            rows = rows_pool[att_index, step.block_tables].reshape(b, -1, cfg.cache_row_stored)[..., :cfg.cache_row]
+        with jax.named_scope("latent_attn"):
+            q_l = jnp.einsum("bhn,hrn->bhr", q_n[:, 0], wkvb[..., :dn])
+            o_l = latent_decode_attention(q_l, q_r[:, 0], rows, step.lengths, scale=att_scale)
+            att = jnp.einsum("bhr,hrv->bhv", o_l, wkvb[..., dn:])
+    else:
+        with jax.named_scope("latent_attn"):
+            kv = jnp.einsum("sr,hrk->shk", new_rows[0, :, :rkv], wkvb)
+            att = latent_prefill_attention(q_n[0], q_r[0], kv[..., :dn], new_rows[0, :, rkv:], kv[..., dn:],
+                                           scale=att_scale)
+    return att.reshape(t, -1) @ w("wo"), rows_pool
 
-    pidx = jnp.clip(positions // block_size, 0, mb - 1)
-    slot = jnp.take_along_axis(block_tables, pidx, axis=1) * block_size + positions % block_size
-    null_slot = jnp.arange(t, dtype=slot.dtype) % block_size
-    write_slots = jnp.where(write_mask.reshape(-1), slot.reshape(-1), null_slot)
-    live = write_mask.reshape(-1)
-    write_blocks, write_rows = write_slots // block_size, write_slots % block_size
-    if decode:
-        lengths = jnp.where(write_mask[:, 0], positions[:, 0] + 1, 0)
 
-    # The layers' tensors stay whole outside the loop, their two leading axes
-    # (layer, which of the layer's two) merged, and a matmul reads its matrix
-    # through one dynamic index. Handed to the scan as per-layer inputs, a
-    # layer's (2, D, F) pair and its (experts, D, F) stack are copied out of
-    # the stacked tensor before use: every weight read and written once more
-    # a step (30 of 47 ms, PERF.md section 6, PR 29).
-    merged = {k: v.reshape(-1, *v.shape[2:]) for k, v in params.items()
-              if k not in _UNSTACKED and k not in _PER_LAYER and k != "hyper"}
-    per_layer = {k: params[k] for k in _PER_LAYER}
+def ffn(w, h):
+    return swiglu(h @ w("w_gate"), h @ w("w_up")) @ w("w_down")
 
-    def mla(w, att_index, h, rows_pool):
-        cq = rms_norm(h @ w("wqa"), w("qa_norm") * cfg.scale_q, eps)
-        q = jnp.einsum("tr,kr->tk", cq, w("wqb")).reshape(b, s, heads, dn + dr)
-        q_n, q_r = q[..., :dn], rope_interleaved(q[..., dn:], positions, cfg.rope_theta)
-        kva = jnp.einsum("td,rd->tr", h, w("wkva")).reshape(b, s, -1)
-        ckv = rms_norm(kva[..., :rkv], w("kva_norm") * cfg.scale_kv, eps)
-        k_r = rope_interleaved(kva[..., rkv:], positions, cfg.rope_theta)
-        new_rows = jnp.concatenate([ckv, k_r], axis=-1).astype(cfg.dtype)
-        pad = cfg.cache_row_stored - cfg.cache_row
-        bits = rows_pool.dtype != jnp.dtype(cfg.dtype)
-        with jax.named_scope("latent_scatter"):
-            flat = jnp.pad(new_rows.reshape(t, -1), ((0, 0), (0, pad)))
-            if bits:
-                flat = jax.lax.bitcast_convert_type(flat, rows_pool.dtype)
-            rows_pool = rows_pool.at[att_index, write_blocks, write_rows].set(flat)
-        wkvb = w("wkvb")
-        if decode:
-            with jax.named_scope("latent_gather"):
-                rows = rows_pool[att_index, block_tables].reshape(b, mb * block_size, -1)[..., :cfg.cache_row]
-                if bits:
-                    rows = jax.lax.bitcast_convert_type(rows, cfg.dtype)
-            with jax.named_scope("latent_attn"):
-                q_l = jnp.einsum("bhn,hrn->bhr", q_n[:, 0], wkvb[..., :dn])
-                o_l = latent_decode_attention(q_l, q_r[:, 0], rows, lengths, scale=att_scale)
-                att = jnp.einsum("bhr,hrv->bhv", o_l, wkvb[..., dn:])
-        else:
-            with jax.named_scope("latent_attn"):
-                kv = jnp.einsum("sr,hrk->shk", new_rows[0, :, :rkv], wkvb)
-                att = latent_prefill_attention(q_n[0], q_r[0], kv[..., :dn], new_rows[0, :, rkv:], kv[..., dn:],
-                                               scale=att_scale)
-        return att.reshape(t, -1) @ w("wo"), rows_pool
 
-    def ffn(w, h):
-        return swiglu(h @ w("w_gate"), h @ w("w_up")) @ w("w_down")
+def paged_layer(cfg: LongcatConfig, params, step):
+    """The layer (the formula at the top) over ``x`` (B, S, D), for one call of
+    a paged program. A layer's two attentions and two MLPs are stacked (layers,
+    2, ...): a matmul reads its matrix through one dynamic index over the two
+    leading axes merged (``models/paged.py`` says why); the experts' tensors stay
+    whole, read in place by the grouped matmul. A decode step adds its routing
+    counts to the pool's."""
+    eps = cfg.rms_norm_eps
 
     @jax.named_scope("block")
-    def body(carry, layer_inputs):
-        x, rows_pool, counts = carry
-        lw, li = layer_inputs
+    def layer(x, pool, li):
+        shape = x.shape
+        x = x.reshape(-1, shape[-1])
 
         def of(which):  # the layer's first (0) or second (1) attention and MLP
-            return lambda name: jax.lax.dynamic_index_in_dim(merged[name], 2 * li + which, keepdims=False)
+            return lambda name: jax.lax.dynamic_index_in_dim(
+                params[name].reshape(-1, *params[name].shape[2:]), 2 * li + which, keepdims=False)
 
         first, second = of(0), of(1)
+        rows_pool, counts = pool["latent"], pool["moe_counts"]
         with jax.named_scope("mla0"):
-            att, rows_pool = mla(first, 2 * li, rms_norm(x, first("in_norm"), eps), rows_pool)
+            att, rows_pool = mla(cfg, first, 2 * li, rms_norm(x, first("in_norm"), eps), rows_pool, step)
         a = x + att
         u = rms_norm(a, first("post_norm"), eps)
         with jax.named_scope("moe"):
+            router = {k: jax.lax.dynamic_index_in_dim(params[k], li, keepdims=False) for k in ("router", "router_bias")}
             m, routed = moe.expert_layer(
-                {**lw, **{k: params[k] for k in _EXPERTS}}, u, layer=li, n_routed=cfg.n_routed_experts,
-                top_k=cfg.moe_topk, scale=cfg.routed_scaling_factor, expert_offset=cfg.expert_offset, live=live)
+                {**params, **router}, u, layer=li, n_routed=cfg.n_routed_experts, top_k=cfg.moe_topk,
+                scale=cfg.routed_scaling_factor, expert_offset=cfg.expert_offset, live=step.live)
         with jax.named_scope("ffn0"):
             x = a + ffn(first, u)
         with jax.named_scope("mla1"):
-            att, rows_pool = mla(second, 2 * li + 1, rms_norm(x, second("in_norm"), eps), rows_pool)
+            att, rows_pool = mla(cfg, second, 2 * li + 1, rms_norm(x, second("in_norm"), eps), rows_pool, step)
         x = x + att
         with jax.named_scope("ffn1"):
             x = x + ffn(second, rms_norm(x, second("post_norm"), eps)) + m
-        return (x, rows_pool, counts + routed if decode else counts), None
+        return x.reshape(shape), {"latent": rows_pool, "moe_counts": counts + routed if shape[1] == 1 else counts}
 
-    (x, rows_pool, counts), _ = jax.lax.scan(
-        body, (x, pool["latent"], pool["moe_counts"]), (per_layer, jnp.arange(cfg.num_layers)))
-    with jax.named_scope("head"):
-        x = x.reshape(b, s, -1)
-        if last is not None:
-            x = jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1)
-        x = rms_norm(x, params["final_norm"], eps)
-        logits = jnp.einsum("bsd,dv->bsv", x, params["unembed"]).astype(jnp.float32)
-    return logits, {"latent": rows_pool, "moe_counts": counts}
-
-
-def make_paged_fns(cfg: LongcatConfig, *, block_size: int):
-    """(prefill, decode_step, decode_step_greedy) with the signatures of
-    ``generation.make_paged_fns``, the pool donated."""
-
-    @functools.partial(jax.jit, donate_argnums=(3,))
-    def prefill(params, tokens, block_table, pool, length):
-        positions = jnp.broadcast_to(jnp.arange(tokens.shape[1])[None, :], tokens.shape)
-        logits, pool = _forward_paged(params, tokens, positions, positions < length, block_table, pool, cfg,
-                                      block_size, last=length - 1)
-        return logits[:, 0, :], pool
-
-    def step(params, tokens, positions, block_tables, pool, active):
-        logits, pool = _forward_paged(params, tokens[:, None], positions[:, None], active[:, None], block_tables,
-                                      pool, cfg, block_size)
-        return logits[:, 0, :], pool
-
-    @functools.partial(jax.jit, donate_argnums=(4,))
-    def decode_step(params, tokens, positions, block_tables, pool, active):
-        return step(params, tokens, positions, block_tables, pool, active)
-
-    @functools.partial(jax.jit, donate_argnums=(4,))
-    def decode_step_greedy(params, tokens, positions, block_tables, pool, active):
-        logits, pool = step(params, tokens, positions, block_tables, pool, active)
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32), pool
-
-    return prefill, decode_step, decode_step_greedy
+    return layer

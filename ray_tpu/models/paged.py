@@ -1,0 +1,139 @@
+"""The paged program: what every model kind ``serve.llm`` runs has in common.
+
+The engine keeps one device-wide pool of fixed-size blocks and, for each
+sequence, a block table mapping absolute positions to pool blocks. Shapes stay
+static (tables are dense int32 arrays padded with the reserved null block 0),
+so one compiled decode step serves whichever sequences occupy the batch slots.
+This module owns what no kind restates: the embedding lookup, where a call's
+cache rows go (``Step``), the scan over layers with the pool in its carry, the
+head, and the three jitted programs (``make_paged_fns``). To it the pool is an
+opaque pytree that a kind's layer maps to a new one.
+
+A model kind is one entry of ``models.PAGED_KINDS`` and one module that gives
+four things:
+
+    paged_layer(cfg, params, step) -> layer(x (B, S, D), pool, li) -> (x, pool)
+    init_paged_pool(cfg, num_blocks, block_size) -> pool
+    paged_block_bytes(cfg, block_size) -> bytes one block holds over all layers
+    init_params(key, cfg) -> params with ``embed``, ``final_norm``, ``unembed``
+        (or none: the embedding is tied)
+
+over a config with ``n_layers`` and ``max_seq_len`` (and ``rms_norm_eps``,
+where the final norm's is not ``rms_norm``'s own). ``paged_layer`` is called
+once a program, outside the scan over layers, and what it computes there is
+computed once a call: XLA does not lift it out of the loop by itself (a rotary
+table built inside the layer was rebuilt 28 times a step: PERF.md section 6, PR
+31). **How a layer gets its weights is decided here:** ``params`` reaches the
+kind whole and the scan carries only the layer's index, so a matmul reads its
+matrix out of the stacked tensor through one dynamic index. Handed to the scan
+as per-layer inputs, a layer's tensors are copied out of the stack before use:
+every weight read and written once more a step (30 of 47 ms, PERF.md section 6,
+PR 29).
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import NamedTuple
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.ops.layers import rms_norm
+
+UNSTACKED = ("embed", "unembed", "final_norm")  # the program's own: the lookup and the head
+
+
+class Step(NamedTuple):
+    """Where one call's tokens are and where their cache rows go: the same for
+    every layer. A slot is a row of the pool seen flat, block ``b`` covering
+    slots ``[b * block_size, (b + 1) * block_size)``. Block 0 is the null
+    block: padded table entries and masked rows' writes land there, and what it
+    holds is always behind the mask, so attention never reads it."""
+
+    positions: jax.Array  # (B, S) absolute
+    block_tables: jax.Array  # (B, MB): a sequence's block index -> pool block
+    block_size: int
+    write_slots: jax.Array  # (B * S,): each token's slot; a masked row's lies in the null block
+    live: jax.Array  # (B * S,) bool: the rows that are tokens
+    lengths: jax.Array  # (B,): a decode step's sequences count positions [0, position]; an inactive slot none
+
+
+def head(cfg, params, x, last=None):
+    """``x`` (B, S, D) -> float32 logits (B, S, V), or (B, 1, V) of position
+    ``last`` alone (a prefill wants one position's, and S x V is not small)."""
+    with jax.named_scope("head"):
+        if last is not None:
+            x = jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1)
+        x = rms_norm(x, params["final_norm"], getattr(cfg, "rms_norm_eps", 1e-6))
+        unembed = params.get("unembed")
+        if unembed is None:
+            unembed = params["embed"].T
+        return jnp.einsum("bsd,dv->bsv", x, unembed).astype(jnp.float32)
+
+
+def forward_paged(paged_layer, cfg, params, tokens, positions, write_mask, block_tables, pool, block_size: int, last=None):
+    """``tokens`` (B, S) at per-sequence absolute ``positions`` (B, S) through
+    ``cfg.n_layers`` calls of a kind's layer, each writing its cache rows into the
+    pool and attending over the sequences' blocks. ``write_mask`` (B, S)
+    diverts padded rows and inactive slots to the null block. Returns
+    (``head``'s logits, pool)."""
+    b, s = tokens.shape
+    pidx = jnp.clip(positions // block_size, 0, block_tables.shape[1] - 1)
+    slot = jnp.take_along_axis(block_tables, pidx, axis=1) * block_size + positions % block_size
+    null_slot = jnp.arange(b * s, dtype=slot.dtype) % block_size
+    live = write_mask.reshape(-1)
+    layer = paged_layer(cfg, params, Step(
+        positions, block_tables, block_size, jnp.where(live, slot.reshape(-1), null_slot), live,
+        jnp.where(write_mask[:, 0], positions[:, 0] + 1, 0)))
+
+    # The pool rides in the scan CARRY, not in per-layer outputs: stacked scan
+    # outputs allocate a fresh slab and copy every layer's rows through it,
+    # which defeats buffer donation and turns each decode step into an
+    # O(pool-size) memcpy. Carry-threaded updates alias in place.
+    def body(carry, li):
+        return layer(*carry, li), None
+
+    (x, pool), _ = jax.lax.scan(body, (params["embed"][tokens], pool), jnp.arange(cfg.n_layers))
+    return head(cfg, params, x, last), pool
+
+
+def make_paged_fns(paged_layer, cfg, *, block_size: int):
+    """(prefill, decode_step, decode_step_greedy) over a kind's ``paged_layer``,
+    jitted with the pool donated (in place on the device between steps).
+
+    prefill(params, tokens (1,S), block_table (1,MB), pool, length ())
+        -> (logits at position length-1 (1,V), pool)
+    decode_step(params, tokens (B,), positions (B,), block_tables (B,MB),
+        pool, active (B,) bool) -> (logits (B,V), pool)
+    decode_step_greedy(same args) -> (next tokens (B,) int32, pool)
+        — argmax fused on device so a greedy batch ships B ints to the
+        host per step instead of B x vocab logits (the hot serving path;
+        identical tokens to argmax over ``decode_step``'s logits).
+
+    Shapes are static per (S, MB, B): the engine buckets prompt lengths
+    and runs decode at a fixed max batch, so each compiles exactly once.
+    """
+
+    @functools.partial(jax.jit, donate_argnums=(3,))
+    def prefill(params, tokens, block_table, pool, length):
+        positions = jnp.broadcast_to(jnp.arange(tokens.shape[1])[None, :], tokens.shape)
+        logits, pool = forward_paged(paged_layer, cfg, params, tokens, positions, positions < length, block_table, pool,
+                                     block_size, last=length - 1)
+        return logits[:, 0, :], pool
+
+    def step(params, tokens, positions, block_tables, pool, active):
+        logits, pool = forward_paged(paged_layer, cfg, params, tokens[:, None], positions[:, None], active[:, None],
+                                     block_tables, pool, block_size)
+        return logits[:, 0, :], pool
+
+    @functools.partial(jax.jit, donate_argnums=(4,))
+    def decode_step(params, tokens, positions, block_tables, pool, active):
+        return step(params, tokens, positions, block_tables, pool, active)
+
+    @functools.partial(jax.jit, donate_argnums=(4,))
+    def decode_step_greedy(params, tokens, positions, block_tables, pool, active):
+        logits, pool = step(params, tokens, positions, block_tables, pool, active)
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32), pool
+
+    return prefill, decode_step, decode_step_greedy

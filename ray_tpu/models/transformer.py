@@ -168,15 +168,25 @@ def param_logical_axes(cfg: TransformerConfig) -> Dict[str, Tuple]:
 
 
 @jax.named_scope("block")
-def _block(
-    cfg: TransformerConfig, x, layer, cos, sin, positions, context_axis, mesh, attn_spec=None
-):
-    """One transformer block. x: (B, S, D). The named scopes (``block``,
-    ``block/attn``, ``block/mlp``) label the block's device operations in a
-    profiler trace, forward and backward; they change no value."""
+def _block(cfg: TransformerConfig, x, layer, cos, sin, positions, attend, cache=None):
+    """One transformer block, the only statement of the GPT-J/Llama layer.
+    x: (B, S, D). What differs between training, the dense cache and the paged
+    pool is ``attend(q, k, v, cache) -> (attention (B, S, H, Hd), cache)``
+    over the rotated q and k: how this step's keys and values meet the ones
+    before them, and where they are kept. Returns (x, cache). The named
+    scopes (``block``, ``block/attn``, ``block/mlp``; ``attend`` names its
+    own) label the block's device operations in a profiler trace, forward and
+    backward; they change no value."""
     with jax.named_scope("attn"):
         h = rms_norm(x, layer["attn_norm"])
-        att_out = _attend(h, layer, cos, sin, positions, context_axis, mesh, attn_spec)
+        q = jnp.einsum("bsd,dhk->bshk", h, layer["wq"])
+        k = jnp.einsum("bsd,dhk->bshk", h, layer["wk"])
+        v = jnp.einsum("bsd,dhk->bshk", h, layer["wv"])
+        q = apply_rope(q, cos, sin, positions)
+        k = apply_rope(k, cos, sin, positions)
+    att, cache = attend(q, k, v, cache)
+    with jax.named_scope("attn"):
+        att_out = jnp.einsum("bshk,hkd->bsd", att, layer["wo"])
 
     if cfg.parallel_block:
         # GPT-J: MLP reads the same normed input; both branches add to residual
@@ -194,18 +204,14 @@ def _block(
             ff = gelu(jnp.einsum("bsd,df->bsf", m, layer["w_up"]))
         mlp_out = jnp.einsum("bsf,fd->bsd", ff, layer["w_down"])
     if cfg.parallel_block:
-        return x + att_out + mlp_out
-    return x + mlp_out
+        return x + att_out + mlp_out, cache
+    return x + mlp_out, cache
 
 
-def _attend(h, layer, cos, sin, positions, context_axis, mesh, attn_spec):
-    """Projections, rotary embedding, attention and the output projection
-    over the normed input ``h``."""
-    q = jnp.einsum("bsd,dhk->bshk", h, layer["wq"])
-    k = jnp.einsum("bsd,dhk->bshk", h, layer["wk"])
-    v = jnp.einsum("bsd,dhk->bshk", h, layer["wv"])
-    q = apply_rope(q, cos, sin, positions)
-    k = apply_rope(k, cos, sin, positions)
+@jax.named_scope("attn")
+def _attend_sequence(q, k, v, cache, *, context_axis, mesh, attn_spec):
+    """Training's ``attend``: causal attention of the sequence over itself;
+    nothing is kept."""
     if context_axis is not None:
         # partial-manual shard_map: only the context axis goes manual (ring
         # ppermute over ICI); batch/model axes stay under GSPMD
@@ -232,7 +238,7 @@ def _attend(h, layer, cos, sin, positions, context_axis, mesh, attn_spec):
         )(q, k, v)
     else:
         att = attention(q, k, v, causal=True)
-    return jnp.einsum("bshk,hkd->bsd", att, layer["wo"])
+    return att, cache
 
 
 def forward(
@@ -257,9 +263,10 @@ def forward(
         k: v for k, v in params.items() if k not in ("embed", "unembed", "final_norm")
     }
 
+    attend = functools.partial(_attend_sequence, context_axis=context_axis, mesh=mesh, attn_spec=attn_spec)
+
     def body(x, layer):
-        out = _block(cfg, x, layer, cos, sin, positions, context_axis, mesh, attn_spec)
-        return out, None
+        return _block(cfg, x, layer, cos, sin, positions, attend)
 
     if cfg.remat:
         if cfg.remat_policy == "dots":

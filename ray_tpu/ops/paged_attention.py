@@ -4,8 +4,8 @@ One query position a sequence. The pool (L, slots, kv_heads, head_dim)
 stays in HBM; for each sequence the kernel copies its *live* blocks, and
 only those, into VMEM where they lie (a block is ``block_size`` consecutive
 slots: one contiguous piece of a layer), several blocks a chunk and
-double-buffered, re-types the raw bits there, and keeps a running maximum,
-sum and output in float32 (online softmax). It never reads ``pool[layer]``
+double-buffered, and keeps a running maximum, sum and output in float32
+(online softmax). It never reads ``pool[layer]``
 as a value, so no layer slab and no gathered copy is made.
 
 Heads stay interleaved as the pool stores them: a chunk is read as
@@ -36,7 +36,7 @@ _CHUNK_BYTES = 1 << 20  # of K (and of V) in one VMEM buffer; two buffers each
 def can_use_paged_kernel(q, pool_k, block_size: int) -> bool:
     """Platform and static shape alone, as ``ops.attention._can_use_flash``:
     a TPU, one query position, a head_dim of whole lanes, and kv heads that
-    fill whole sublane tiles of the pool's storage type (so that a chunk
+    fill whole sublane tiles of the pool's type (so that a chunk
     flattens to (rows * kv_heads, head_dim) without a relayout)."""
     if jax.default_backend() != "tpu":
         return False
@@ -57,7 +57,7 @@ def _kernel(
     q_ref, pk_ref, pv_ref,  # q (1, H, Hd) in VMEM; the pools in HBM
     o_ref,
     kbuf, vbuf, sem,  # (2, rows, KV, Hd) each; DMA semaphores (2, 2)
-    *, block_size, chunk_blocks, dtype,
+    *, block_size, chunk_blocks,
 ):
     b = pl.program_id(0)
     li = li_ref[0]
@@ -98,10 +98,6 @@ def _kernel(
     own_head = jax.lax.rem(col, kv_heads) == jax.lax.div(head, n_rep)
     row = jax.lax.div(col, kv_heads)
 
-    def typed(bits):
-        x = bits.reshape(cols, head_dim)
-        return x if x.dtype == dtype else jax.lax.bitcast_convert_type(x, dtype)
-
     def chunk_step(c, carry):
         m, l, acc = carry
         slot = jax.lax.rem(c, 2)
@@ -112,7 +108,7 @@ def _kernel(
 
         for_live_blocks(c, slot, lambda d: d.wait())
         s = jax.lax.dot_general(
-            q, typed(kbuf[slot]), (((1,), (1,)), ((), ())),
+            q, kbuf[slot].reshape(cols, head_dim), (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale
         s = jnp.where(own_head & (c * rows + row < length), s, _NEG_INF)
@@ -121,7 +117,7 @@ def _kernel(
         p = jnp.exp(s - m_new)
         l = alpha * l + p.sum(axis=-1, keepdims=True)
         acc = alpha * acc + jnp.dot(
-            p.astype(dtype), typed(vbuf[slot]), preferred_element_type=jnp.float32
+            p.astype(vbuf.dtype), vbuf[slot].reshape(cols, head_dim), preferred_element_type=jnp.float32
         )
         return m_new, l, acc
 
@@ -140,8 +136,8 @@ def _kernel(
 def paged_decode_attention(
     q, pool_k, pool_v, layer, block_tables, lengths, *, block_size: int, interpret=False
 ):
-    """q (B, H, Hd) in the model's dtype against the pools (L, slots, KV, Hd)
-    in their storage dtype (raw bits for 16-bit floats), at layer ``layer``.
+    """q (B, H, Hd) against the pools (L, slots, KV, Hd), both in the model's
+    dtype, at layer ``layer``.
     ``block_tables`` (B, MB) maps a sequence's block index to a pool block;
     ``lengths`` (B,) is how many positions of each sequence count (0: an
     inactive slot, whose output is 0), at most MB x ``block_size``; both
@@ -154,9 +150,7 @@ def paged_decode_attention(
     block_bytes = block_size * kv_heads * head_dim * jnp.dtype(pool_k.dtype).itemsize
     chunk_blocks = max(1, min(block_tables.shape[1], _CHUNK_BYTES // block_bytes))
     rows = chunk_blocks * block_size
-    kernel = functools.partial(
-        _kernel, block_size=block_size, chunk_blocks=chunk_blocks, dtype=q.dtype
-    )
+    kernel = functools.partial(_kernel, block_size=block_size, chunk_blocks=chunk_blocks)
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),

@@ -14,6 +14,7 @@ has no checkpoint loader); pass ``params_loader`` for real weights.
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Any, Callable, Dict, Iterator, Optional, Sequence, Union
 
 from ray_tpu.serve.llm.engine import EngineConfig, InferenceEngine
@@ -35,25 +36,20 @@ TINY_MODEL: Dict[str, Any] = {
 
 
 def _resolve_model_cfg(model_cfg):
-    """A config dataclass as it is; a dict becomes the model its ``kind``
-    names (``"longcat"``), and a ``TransformerConfig`` where it names none."""
-    from ray_tpu.models import longcat
-    from ray_tpu.models.transformer import TransformerConfig
+    """A config dataclass as it is; a dict becomes the config of the model
+    kind its ``kind`` names (``models.PAGED_KINDS``), and a
+    ``TransformerConfig`` where it names none."""
+    from ray_tpu.models import paged_config
 
-    if model_cfg is None:
-        model_cfg = TINY_MODEL
-    if isinstance(model_cfg, (TransformerConfig, longcat.LongcatConfig)):
+    if dataclasses.is_dataclass(model_cfg):
         return model_cfg
     import jax.numpy as jnp
 
-    cfg = dict(model_cfg)
-    kinds = {None: TransformerConfig, longcat.KIND: longcat.LongcatConfig}
-    kind = cfg.pop("kind", None)
-    if kind not in kinds:
-        raise ValueError(f"unknown model kind {kind!r} (known: {sorted(k for k in kinds if k)})")
+    cfg = dict(TINY_MODEL if model_cfg is None else model_cfg)
+    config = paged_config(cfg.pop("kind", None))
     if isinstance(cfg.get("dtype"), str):
         cfg["dtype"] = jnp.dtype(cfg["dtype"]).type
-    return kinds[kind](**cfg)
+    return config(**cfg)
 
 
 def _resolve_engine_cfg(engine_cfg):

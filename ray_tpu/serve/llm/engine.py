@@ -315,7 +315,7 @@ class InferenceEngine:
     ):
         import jax
 
-        from ray_tpu.models import generation as G, paged_model
+        from ray_tpu.models import generation as G, paged, paged_model
 
         ecfg = engine_cfg or EngineConfig()
         if ecfg.max_batch < 1:
@@ -326,10 +326,10 @@ class InferenceEngine:
         self.deployment = deployment
         self._G = G
         # the pool is the model's to shape: its module makes it, says what a
-        # block of it holds, and gives the three programs that run over it
+        # block of it holds, and gives the layer the three programs run over it
         model = paged_model(model_cfg)
-        self._prefill, self._decode, self._decode_greedy = model.make_paged_fns(
-            model_cfg, block_size=ecfg.block_size
+        self._prefill, self._decode, self._decode_greedy = paged.make_paged_fns(
+            model.paged_layer, model_cfg, block_size=ecfg.block_size
         )
         self._pool = model.init_paged_pool(model_cfg, ecfg.num_blocks, ecfg.block_size)
         self._device = next(iter(jax.tree.leaves(self._pool)[0].devices()))

@@ -2,9 +2,9 @@
 device pool, handed out by a free-list allocator and mapped per sequence
 by a block table.
 
-The device arrays live in ``ray_tpu.models.generation`` (``init_paged_pool``
-/ ``make_paged_fns``); this module is the host-side half: which pool block
-belongs to which sequence. Fixed-size blocks make fragmentation structural
+The device arrays are a model kind's (``init_paged_pool``), run over by
+``ray_tpu.models.paged``'s programs; this module is the host-side half: which
+pool block belongs to which sequence. Fixed-size blocks make fragmentation structural
 zero — any request for ``n <= num_free`` blocks always succeeds, there is
 no external fragmentation to compact and no defrag pause on the decode
 path. Block 0 is reserved as the null block (padding target for block

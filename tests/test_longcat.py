@@ -16,7 +16,7 @@ import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
 from benchmarks.reference import longcat as R  # noqa: E402
-from ray_tpu.models import longcat as M, moe, paged_model  # noqa: E402
+from ray_tpu.models import longcat as M, moe, paged, paged_model  # noqa: E402
 from ray_tpu.ops.latent_attention import (  # noqa: E402
     latent_decode_attention,
     latent_prefill_attention,
@@ -61,7 +61,7 @@ def run_paged(cfg, params, prompt, steps=STEPS, batch=3, slot=1, neighbours=()):
     batch of ``batch``; ``neighbours`` are (slot, prompt) pairs that decode
     beside it. Returns (logits of every position fed (steps + 1, V), tokens
     fed, the pool)."""
-    prefill, decode, _ = M.make_paged_fns(cfg, block_size=BLOCK)
+    prefill, decode, _ = paged.make_paged_fns(M.paged_layer, cfg, block_size=BLOCK)
     pool = M.init_paged_pool(cfg, BLOCKS, BLOCK)
     alloc = BlockAllocator(BLOCKS, BLOCK)
     state = {}

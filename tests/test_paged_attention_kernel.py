@@ -1,7 +1,7 @@
 """The decode step's paged-attention kernel (``ops/paged_attention.py``) in
 Pallas interpret mode on the CPU, against the path it replaces on a TPU: the
 table's rows gathered out of the pool and attended to as a masked dense block
-(``generation._paged_attention``). The kernel's compile for the chip is in
+(``ops.attention.attention`` under the table's mask). The kernel's compile for the chip is in
 ``test_tpu_compile.py``; its speed is the benchmark's."""
 
 import jax
@@ -9,7 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import generation as G
+from ray_tpu.ops.attention import attention
 from ray_tpu.ops.paged_attention import can_use_paged_kernel, paged_decode_attention
 
 # a chunk of the kernel is 16 of these blocks: a full table is two chunks and a half
@@ -27,16 +27,10 @@ LENGTHS = {
 
 
 def _pools(dtype, kv_heads, seed):
-    """Two pools of random rows in ``dtype``, stored as the engine stores them."""
+    """Two pools of random rows in ``dtype``, as the engine holds them."""
     rng = np.random.default_rng(seed)
     shape = (LAYERS, POOL_BLOCKS * BLOCK, kv_heads, HEAD_DIM)
-    storage = G._kv_storage_dtype(dtype)
-
-    def pool():
-        x = jnp.asarray(rng.standard_normal(shape), dtype)
-        return x if storage == dtype else jax.lax.bitcast_convert_type(x, storage)
-
-    return pool(), pool()
+    return jnp.asarray(rng.standard_normal(shape), dtype), jnp.asarray(rng.standard_normal(shape), dtype)
 
 
 def _tables(lengths, seed):
@@ -59,17 +53,15 @@ def _kernel(q, pk, pv, tables, lengths):
 @jax.jit
 def _gathered(q, pk, pv, tables, lengths):
     idx = (tables[:, :, None] * BLOCK + jnp.arange(BLOCK)[None, None, :]).reshape(len(tables), -1)
-    gk, gv = pk[1][idx], pv[1][idx]
-    if gk.dtype != q.dtype:
-        gk, gv = (jax.lax.bitcast_convert_type(x, q.dtype) for x in (gk, gv))
-    return G._paged_attention(q[:, None], gk, gv, lengths[:, None] - 1)[:, 0]
+    mask = jnp.arange(idx.shape[1]) < lengths[:, None, None, None]  # (B, 1, 1, M)
+    return attention(q[:, None], pk[1][idx], pv[1][idx], causal=False, mask=mask)[:, 0]
 
 
 @pytest.mark.parametrize("case", list(LENGTHS))
 @pytest.mark.parametrize("n_rep", [1, 2])
-@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["uint16_pool", "float32_pool"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bfloat16_pool", "float32_pool"])
 def test_kernel_agrees_with_attention_over_the_gathered_rows(dtype, n_rep, case):
-    kv_heads = 32 // jnp.dtype(dtype).itemsize  # one sublane tile of the storage type
+    kv_heads = 32 // jnp.dtype(dtype).itemsize  # one sublane tile of the pool's type
     lengths = np.asarray(LENGTHS[case], np.int32)
     pk, pv = _pools(dtype, kv_heads, seed=1)
     tables = _tables(lengths, seed=2)
@@ -87,7 +79,7 @@ def test_kernel_agrees_with_attention_over_the_gathered_rows(dtype, n_rep, case)
     np.testing.assert_allclose(got[active], want[active], atol=tol, rtol=tol)
 
 
-@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["uint16_pool", "float32_pool"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bfloat16_pool", "float32_pool"])
 def test_a_sequence_reads_the_same_alone_and_among_neighbours(dtype):
     """Bit for bit: in another slot, beside neighbours of other lengths whose
     rows passed through the same buffers, with the pool's other blocks changed."""
@@ -116,13 +108,13 @@ GPTJ_Q, LLAMA7B_Q = (8, 1, 16, 256), (8, 1, 32, 128)
 @pytest.mark.parametrize(
     "backend,q_shape,kv_heads,pool_dtype,want",
     [
-        ("tpu", GPTJ_Q, 16, jnp.uint16, True),  # GPT-J-6B's decode step
-        ("tpu", LLAMA7B_Q, 32, jnp.uint16, True),
+        ("tpu", GPTJ_Q, 16, jnp.bfloat16, True),  # GPT-J-6B's decode step
+        ("tpu", LLAMA7B_Q, 32, jnp.bfloat16, True),
         ("tpu", (8, 1, 8, 128), 8, jnp.float32, True),
-        ("cpu", GPTJ_Q, 16, jnp.uint16, False),  # tier-1, the rehearsals
-        ("tpu", (1, 512, 16, 256), 16, jnp.uint16, False),  # a prefill: S is the bucket
-        ("tpu", (8, 1, 64, 128), 8, jnp.uint16, False),  # Llama-2-70B: 8 kv heads are half a tile
-        ("tpu", (8, 1, 16, 64), 16, jnp.uint16, False),  # head_dim under a lane tile
+        ("cpu", GPTJ_Q, 16, jnp.bfloat16, False),  # tier-1, the rehearsals
+        ("tpu", (1, 512, 16, 256), 16, jnp.bfloat16, False),  # a prefill: S is the bucket
+        ("tpu", (8, 1, 64, 128), 8, jnp.bfloat16, False),  # Llama-2-70B: 8 kv heads are half a tile
+        ("tpu", (8, 1, 16, 64), 16, jnp.bfloat16, False),  # head_dim under a lane tile
     ],
 )
 def test_the_path_is_chosen_by_platform_and_shape(monkeypatch, backend, q_shape, kv_heads, pool_dtype, want):

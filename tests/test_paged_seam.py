@@ -1,0 +1,168 @@
+"""The seams of ``ray_tpu/models``: one block under its three ``attend``s
+(training's, the dense cache's, the paged pool's) gives the same logits, and a
+model kind costs ``models/paged.py`` four things and one table entry (a
+made-up kind, defined here, served by the engine). CPU, float32."""
+
+import dataclasses
+import os
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
+import pytest  # noqa: E402
+
+from ray_tpu import models  # noqa: E402
+from ray_tpu.models import generation as G, transformer as T  # noqa: E402
+from ray_tpu.ops.layers import gelu, rms_norm  # noqa: E402
+from ray_tpu.serve.llm.deployment import LLMServer  # noqa: E402
+
+BLOCK, BLOCKS, MAX_BLOCKS, BUCKET = 4, 32, 8, 16
+
+# -- (a) the two block forms the zoo has, in the three views ---------------------------
+
+SHAPE = dict(vocab_size=96, d_model=32, n_layers=2, n_heads=4, d_ff=64, max_seq_len=32, dtype=jnp.float32, remat=False)
+FORMS = {
+    "gptj": T.TransformerConfig(**SHAPE, parallel_block=True, use_swiglu=False, tie_embeddings=False),
+    "llama": T.TransformerConfig(**SHAPE, parallel_block=False, use_swiglu=True, n_kv_heads=2),
+}
+TOKENS = np.random.default_rng(11).integers(1, 95, 14).astype(np.int32)
+
+
+def dense_logits(cfg, params, prompt_len):
+    """The prompt prefilled, the rest decoded a token a step, through the
+    dense cache: the logits of positions ``prompt_len - 1 ..``."""
+    prefill, decode = G.make_decode_fns(cfg, len(TOKENS))
+    logits, cache = prefill(params, jnp.asarray(TOKENS[None, :prompt_len]), G.init_kv_cache(cfg, 1, len(TOKENS)))
+    out = [logits[0]]
+    for t in TOKENS[prompt_len:]:
+        logits, cache = decode(params, jnp.asarray([[t]]), cache)
+        out.append(logits[0])
+    return np.stack(out)
+
+
+def paged_logits(cfg, params, prompt_len):
+    """The same through the paged programs, in slot 1 of 3 with blocks out of
+    order; the padded prefill and the two empty slots must not show."""
+    prefill, decode, greedy = G.make_paged_fns(cfg, block_size=BLOCK)
+    pool = G.init_paged_pool(cfg, BLOCKS, BLOCK)
+    table = np.zeros((3, MAX_BLOCKS), np.int32)
+    table[1, :4] = [7, 3, 21, 12]
+    toks = np.zeros((1, BUCKET), np.int32)
+    toks[0, :prompt_len] = TOKENS[:prompt_len]
+    logits, pool = prefill(params, jnp.asarray(toks), jnp.asarray(table[1:2]), pool, jnp.int32(prompt_len))
+    out = [logits[0]]
+    active = jnp.asarray([False, True, False])
+    for i, t in enumerate(TOKENS[prompt_len:]):
+        args = (jnp.asarray([0, t, 0], jnp.int32), jnp.asarray([0, prompt_len + i, 0], jnp.int32), jnp.asarray(table))
+        logits, pool = decode(params, *args, pool, active)
+        token, pool = greedy(params, *args, pool, active)  # writes the same rows again
+        assert int(token[1]) == int(jnp.argmax(logits[1]))
+        out.append(logits[1])
+    return np.stack(out)
+
+
+@pytest.mark.parametrize("view", [dense_logits, paged_logits])
+@pytest.mark.parametrize("form", list(FORMS))
+def test_one_block_gives_the_same_logits_in_training_and_over_both_caches(form, view):
+    cfg = FORMS[form]
+    params = T.init_params(jax.random.PRNGKey(3), cfg)
+    want = np.asarray(T.forward(params, jnp.asarray(TOKENS[None]), cfg)[0], np.float32)
+    for prompt_len in (1, 6):  # from the first position: every position's logits; then a prefill of several
+        got = view(cfg, params, prompt_len)
+        assert got.shape == want[prompt_len - 1:].shape
+        np.testing.assert_allclose(got, want[prompt_len - 1:], atol=2e-5, rtol=2e-4)
+
+
+# -- (b) a made-up third kind: what a ``model_config`` PR costs the program ------------
+#
+# Its mixer is no attention: position t reads the mean of the rows that
+# positions 0..t wrote. Its pool is its own shape, with a counter leaf of its own.
+
+
+@dataclasses.dataclass(frozen=True)
+class ToyConfig:
+    vocab_size: int = 64
+    width: int = 16
+    n_layers: int = 2
+    max_seq_len: int = 64
+    dtype: type = jnp.float32
+
+
+def init_params(key, cfg):
+    ke, km, ku, kd, kh = jax.random.split(key, 5)
+    shape = (cfg.n_layers, cfg.width, cfg.width)
+    return {
+        "embed": jax.random.normal(ke, (cfg.vocab_size, cfg.width), cfg.dtype),
+        "w_mix": jax.random.normal(km, shape, cfg.dtype) * cfg.width ** -0.5,
+        "w_up": jax.random.normal(ku, shape, cfg.dtype) * cfg.width ** -0.5,
+        "w_down": jax.random.normal(kd, shape, cfg.dtype) * cfg.width ** -0.5,
+        "final_norm": jnp.ones((cfg.width,), jnp.float32),
+        "unembed": jax.random.normal(kh, (cfg.width, cfg.vocab_size), cfg.dtype) * cfg.width ** -0.5,
+    }
+
+
+def init_paged_pool(cfg, num_blocks, block_size):
+    return {"rows": jnp.zeros((cfg.n_layers, num_blocks, block_size, cfg.width), cfg.dtype),
+            "rows_written": jnp.zeros((), jnp.int32)}
+
+
+def paged_block_bytes(cfg, block_size):
+    return cfg.n_layers * block_size * cfg.width * jnp.dtype(cfg.dtype).itemsize
+
+
+def paged_layer(cfg, params, step):
+    b, s = step.positions.shape
+    bs = step.block_size
+    upto = (jnp.arange(step.block_tables.shape[1] * bs) <= step.positions[..., None]).astype(cfg.dtype)  # (B, S, M)
+
+    def layer(x, pool, li):
+        row = x @ params["w_mix"][li]
+        rows = pool["rows"].at[li, step.write_slots // bs, step.write_slots % bs].set(row.reshape(b * s, -1))
+        seen = rows[li, step.block_tables].reshape(b, -1, cfg.width)  # row index == position
+        x = x + jnp.einsum("bsm,bmd->bsd", upto, seen) / (step.positions[..., None] + 1)
+        x = x + gelu(x @ params["w_up"][li]) @ params["w_down"][li]
+        return x, {"rows": rows, "rows_written": pool["rows_written"] + jnp.sum(step.live)}
+
+    return layer
+
+
+def toy_next_token(cfg, params, tokens):
+    """The plain model over the whole sequence, no cache: the next token."""
+    x = params["embed"][jnp.asarray(tokens)]
+    for li in range(cfg.n_layers):
+        mixed = jnp.cumsum(x @ params["w_mix"][li], axis=0) / jnp.arange(1, len(tokens) + 1)[:, None]
+        x = x + mixed
+        x = x + gelu(x @ params["w_up"][li]) @ params["w_down"][li]
+    return int(jnp.argmax(rms_norm(x[-1], params["final_norm"]) @ params["unembed"]))
+
+
+def test_a_made_up_kind_is_served_through_its_table_entry_alone():
+    models.PAGED_KINDS["toy"] = (__name__, "ToyConfig")
+    try:
+        server = LLMServer({"kind": "toy", "vocab_size": 48},
+                           dict(block_size=BLOCK, num_blocks=BLOCKS, max_batch=2, max_blocks_per_seq=MAX_BLOCKS),
+                           weight_seed=9)
+        try:
+            eng = server._engine
+            cfg = eng.model_cfg
+            assert type(cfg) is ToyConfig and cfg.vocab_size == 48
+            prompts = [[5, 9, 2], [7] * 9, [1, 2, 3, 4, 5, 6]]  # three requests on two slots
+            served = [list(s) for s in [server.generate(p, max_new_tokens=7) for p in prompts]]
+            for prompt, got in zip(prompts, served):
+                seq = list(prompt)
+                for token in got:
+                    assert token == toy_next_token(cfg, eng.params, seq)
+                    seq.append(token)
+            stats = server.kv_stats()
+            assert stats["bytes_per_block"] == cfg.n_layers * BLOCK * cfg.width * 4
+            assert stats["blocks_free"] == stats["blocks_total"] == BLOCKS - 1
+            # a prompt's rows and six tokens fed back, a layer each: the pool's own leaf rode along
+            assert int(eng._pool["rows_written"]) == cfg.n_layers * sum(len(p) + 6 for p in prompts)
+        finally:
+            server._engine.shutdown()
+    finally:
+        del models.PAGED_KINDS["toy"]
+    with pytest.raises(ValueError, match="unknown model kind 'toy'"):
+        LLMServer({"kind": "toy"})

@@ -150,11 +150,11 @@ def test_latent_decode_and_prefill_at_longcat_widths(one_chip):
     program computes in, and copies it whole, in and out, every step)."""
     import re
 
-    from ray_tpu.models import longcat as M
+    from ray_tpu.models import longcat as M, paged
 
     cfg = M.LongcatConfig(vocab_size=16384, num_layers=2, experts_held=16)
     block, blocks, batch, per_seq = 16, 4097, 32, 128
-    prefill, _, decode_greedy = M.make_paged_fns(cfg, block_size=block)
+    prefill, _, decode_greedy = paged.make_paged_fns(M.paged_layer, cfg, block_size=block)
     params = _on(one_chip, jax.eval_shape(lambda: M.init_params(jax.random.PRNGKey(0), cfg)))
     pool = _on(one_chip, jax.eval_shape(lambda: M.init_paged_pool(cfg, blocks, block)))
     assert pool["latent"].shape == (4, blocks, block, 640) and M.paged_block_bytes(cfg, block) == 4 * 16 * 1280
