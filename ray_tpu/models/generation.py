@@ -9,10 +9,11 @@ fp32 logits.
   cache (L, B, max_len, kv_heads, head_dim) a batch, all sequences advancing in
   lockstep, jitted with the cache donated. The static-batch path: the tests'
   reference for the engine, and the examples'.
-* paged (``paged_layer``, ``init_paged_pool``, ``paged_block_bytes``: what
-  ``models/paged.py`` asks of a model kind): the serve plane's pool, (L,
-  num_blocks * block_size, kv_heads, head_dim) in ``cfg.dtype``, block ``b``
-  covering slots ``[b * block_size, (b + 1) * block_size)``.
+* paged (``paged_layer``, ``init_paged_pool``, ``paged_block_bytes``,
+  ``paged_layouts``: what ``models/paged.py`` asks of a model kind): the serve
+  plane's pool, (L, num_blocks * block_size, kv_heads, head_dim) in
+  ``cfg.dtype``, block ``b`` covering slots ``[b * block_size, (b + 1) *
+  block_size)``.
 """
 
 from __future__ import annotations
@@ -97,6 +98,19 @@ def init_paged_pool(cfg: TransformerConfig, num_blocks: int, block_size: int) ->
 def paged_block_bytes(cfg: TransformerConfig, block_size: int) -> int:
     """Bytes one block of the pool holds: K and V rows over all layers."""
     return 2 * cfg.n_layers * block_size * cfg.kv_heads * cfg.head_dim * jnp.dtype(cfg.dtype).itemsize
+
+
+def paged_layouts(cfg: TransformerConfig) -> Dict[str, Tuple[int, ...]]:
+    """``wq``, ``wk``, ``wv`` heads-major on the device. They are (L, D, heads,
+    head_dim) and ``_block`` contracts them over D, which the default layout
+    leaves outside the tiles (the device tiles the two minor dimensions: the
+    heads lie in the sublanes, and the dot wants its operand staged in fast
+    memory). As (L, heads, D, head_dim) in memory a head is a D x head_dim
+    matrix, read in place like ``wo`` (L, heads, head_dim, D) and the MLP's
+    tensors, whose contraction already lies in the tiles: nothing for those.
+    The rule is about where a projection's contraction lies, whatever the
+    number of heads, kv heads or their size."""
+    return {name: (0, 2, 1, 3) for name in ("wq", "wk", "wv")}
 
 
 def _attend_pool(q, k, v, pool, *, li, step: paged.Step, rows):

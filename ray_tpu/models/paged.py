@@ -10,13 +10,15 @@ head, and the three jitted programs (``make_paged_fns``). To it the pool is an
 opaque pytree that a kind's layer maps to a new one.
 
 A model kind is one entry of ``models.PAGED_KINDS`` and one module that gives
-four things:
+four things, and may give a fifth:
 
     paged_layer(cfg, params, step) -> layer(x (B, S, D), pool, li) -> (x, pool)
     init_paged_pool(cfg, num_blocks, block_size) -> pool
     paged_block_bytes(cfg, block_size) -> bytes one block holds over all layers
     init_params(key, cfg) -> params with ``embed``, ``final_norm``, ``unembed``
         (or none: the embedding is tied)
+    paged_layouts(cfg) -> {name of a stacked tensor: its ``major_to_minor`` on
+        the device}, for the tensors whose default layout the layer reads badly
 
 over a config with ``n_layers`` and ``max_seq_len`` (and ``rms_norm_eps``,
 where the final norm's is not ``rms_norm``'s own). ``paged_layer`` is called
@@ -28,16 +30,28 @@ kind whole and the scan carries only the layer's index, so a matmul reads its
 matrix out of the stacked tensor through one dynamic index. Handed to the scan
 as per-layer inputs, a layer's tensors are copied out of the stack before use:
 every weight read and written once more a step (30 of 47 ms, PERF.md section 6,
-PR 29).
+PR 29). **How a weight lies on the device is decided here too**
+(``place_params``): shape, key and values are the kind's and training's, the
+order of the dimensions in memory is the paged programs'. The device tiles an
+array's two minor dimensions, and a matmul reads its matrix in place only where
+the contraction lies in those tiles. A tensor that has it further out (GPT-J's
+``wq``: (L, D, H, Hd), contracted over D) is staged whole in fast memory before
+its dot, every layer of every step: a q/k/v projection cost 2.9 ms a step where
+``wo``, the same bytes, costs 1.3 (PERF.md section 6, PR 32). The kind names such
+tensors and the engine places them once, before the pool exists; the programs
+are plain ``jax.jit``s, which take a committed argument's layout as it comes.
 """
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.compilation_cache import compilation_cache
+from jax.experimental.layout import Format, Layout
 
 from ray_tpu.ops.layers import rms_norm
 
@@ -57,6 +71,57 @@ class Step(NamedTuple):
     write_slots: jax.Array  # (B * S,): each token's slot; a masked row's lies in the null block
     live: jax.Array  # (B * S,) bool: the rows that are tokens
     lengths: jax.Array  # (B,): a decode step's sequences count positions [0, position]; an inactive slot none
+
+
+@contextlib.contextmanager
+def _no_compile_cache():
+    """Inside, a program is compiled and never read from JAX's persistent
+    compile cache. **A program whose result has a layout of its own must not
+    come from that cache** (JAX 0.9.0, the CPU backend and the chip alike): the
+    executable read back has forgotten its result's layout, so the array comes
+    out labelled with the default one over bytes that lie heads-major, and
+    every later reader gets other weights, silently (PERF.md section 6, PR 32).
+    Layouts of a program's *arguments* survive the cache, so the three paged
+    programs are cached as ever."""
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()  # the cache's verdict on itself is remembered
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+def place_params(model, cfg, params):
+    """``params`` with each tensor that the kind's module names
+    (``model.paged_layouts(cfg)``) re-laid on the device in its
+    ``major_to_minor``, on the sharding it has; and what was placed, ``{name:
+    major_to_minor}``. Chosen from the platform alone, as
+    ``can_use_paged_kernel`` is: on a TPU it places; elsewhere (the CPU backend
+    tiles nothing) and for a kind that names nothing it returns ``params``
+    itself and ``{}``.
+
+    One tensor at a time, and **the original is deleted** before the next is
+    placed: the caller hands its parameters over. GPT-J-6B's 12.1 GB leave room
+    for one 0.94 GB tensor in flight and, after this, for the pool; they do not
+    leave room for both copies of all three. The copying program is compiled
+    here every time (a second of a replica's start), and a tensor that does
+    not come out in the layout asked for raises."""
+    layouts = model.paged_layouts(cfg) if hasattr(model, "paged_layouts") else {}
+    if not layouts or jax.default_backend() != "tpu":
+        return params, {}
+    layouts = {name: tuple(order) for name, order in layouts.items()}
+    params = dict(params)
+    with _no_compile_cache():
+        for name, order in layouts.items():
+            old = params[name]
+            new = jax.block_until_ready(jax.device_put(old, Format(Layout(major_to_minor=order), old.sharding)))
+            if new.format.layout.major_to_minor != order:
+                raise RuntimeError(f"{name} asked for in major_to_minor {order} came out as {new.format.layout}")
+            params[name] = new
+            old.delete()
+    return params, layouts
 
 
 def head(cfg, params, x, last=None):

@@ -69,6 +69,7 @@ import collections
 import dataclasses
 import functools
 import itertools
+import logging
 import os
 import queue
 import threading
@@ -81,6 +82,8 @@ from ray_tpu._private.profiling import annotate
 from ray_tpu.serve.exceptions import DeploymentOverloadedError
 from ray_tpu.serve.llm.kv_cache import BlockAllocator, BlockTable
 from ray_tpu.util import tracing
+
+logger = logging.getLogger(__name__)
 
 __all__ = ["EngineConfig", "InferenceEngine", "TokenStream"]
 
@@ -320,7 +323,6 @@ class InferenceEngine:
         ecfg = engine_cfg or EngineConfig()
         if ecfg.max_batch < 1:
             raise ValueError("max_batch must be >= 1")
-        self.params = params
         self.model_cfg = model_cfg
         self.cfg = ecfg
         self.deployment = deployment
@@ -331,7 +333,20 @@ class InferenceEngine:
         self._prefill, self._decode, self._decode_greedy = paged.make_paged_fns(
             model.paged_layer, model_cfg, block_size=ecfg.block_size
         )
+        # the weights as the kind wants them to lie on the device, before the
+        # pool exists: ``params`` is the engine's from here (placed originals
+        # are deleted), and every reader of ``self.params`` gets the placed tree
+        t0 = time.perf_counter()
+        self.params, self.placed = paged.place_params(model, model_cfg, params)
+        if self.placed:
+            logger.info("%s: placed %s on the device in %.2f s", deployment, self.placed, time.perf_counter() - t0)
         self._pool = model.init_paged_pool(model_cfg, ecfg.num_blocks, ecfg.block_size)
+        if any(x.committed for x in jax.tree.leaves(self.params)):
+            # one committed argument (a placed or a sharded weight) commits a
+            # program's results, the pool among them: it starts as it will
+            # come back, or the first program called is lowered again when it
+            # next meets the pool, inside some request's time to first token
+            self._pool = jax.tree.map(lambda x: jax.device_put(x, x.sharding), self._pool)
         self._device = next(iter(jax.tree.leaves(self._pool)[0].devices()))
         self._alloc = BlockAllocator(ecfg.num_blocks, ecfg.block_size)
         self._slots: List[Optional[_Running]] = [None] * ecfg.max_batch
@@ -570,7 +585,9 @@ class InferenceEngine:
         ``prefill`` now ends when the first token is read, which is after the
         steps that were in flight before the prefill, and ``prefill_stall`` is
         the host's time to enqueue the iteration's prefills.
-        Empty with ``telemetry_enabled`` off."""
+        Empty with ``telemetry_enabled`` off, but for ``placed``: the stacked
+        tensors the engine re-laid on the device at start, name ->
+        ``major_to_minor`` (``paged.place_params``; empty where it placed none)."""
         ring = self._ring.copy()  # atomic against the loop's appends
         steps = [r[1:] for r in ring if r[0] == "s"]
         reqs = [r[1:] for r in ring if r[0] == "r"]
@@ -614,6 +631,8 @@ class InferenceEngine:
             "overrun": {"count": len(overrun), "sum": sum(overrun)},
             # the newest read of the expert layers' routing counts (cumulative)
             "moe": dict(zip(LLM_MOE_FIELDS, routed[-1])) if routed else None,
+            # the stacked tensors re-laid on the device at start, name -> major_to_minor
+            "placed": dict(self.placed),
         }
 
     def _record(self, rec: tuple) -> None:

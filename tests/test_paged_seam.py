@@ -1,10 +1,14 @@
 """The seams of ``ray_tpu/models``: one block under its three ``attend``s
-(training's, the dense cache's, the paged pool's) gives the same logits, and a
+(training's, the dense cache's, the paged pool's) gives the same logits, a
 model kind costs ``models/paged.py`` four things and one table entry (a
-made-up kind, defined here, served by the engine). CPU, float32."""
+made-up kind, defined here, served by the engine), and the fifth thing a kind
+may give, how its stacked weights lie on the device, changes no token. CPU,
+float32."""
 
 import dataclasses
+import logging
 import os
+import sys
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
@@ -14,9 +18,10 @@ import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
 from ray_tpu import models  # noqa: E402
-from ray_tpu.models import generation as G, transformer as T  # noqa: E402
+from ray_tpu.models import generation as G, paged, transformer as T  # noqa: E402
 from ray_tpu.ops.layers import gelu, rms_norm  # noqa: E402
 from ray_tpu.serve.llm.deployment import LLMServer  # noqa: E402
+from ray_tpu.serve.llm.engine import EngineConfig, InferenceEngine  # noqa: E402
 
 BLOCK, BLOCKS, MAX_BLOCKS, BUCKET = 4, 32, 8, 16
 
@@ -155,6 +160,8 @@ def test_a_made_up_kind_is_served_through_its_table_entry_alone():
                 for token in got:
                     assert token == toy_next_token(cfg, eng.params, seq)
                     seq.append(token)
+            # it names no layout: its parameters are the arrays it was given
+            assert eng.placed == {} and server.loop_stats()["placed"] == {}
             stats = server.kv_stats()
             assert stats["bytes_per_block"] == cfg.n_layers * BLOCK * cfg.width * 4
             assert stats["blocks_free"] == stats["blocks_total"] == BLOCKS - 1
@@ -166,3 +173,143 @@ def test_a_made_up_kind_is_served_through_its_table_entry_alone():
         del models.PAGED_KINDS["toy"]
     with pytest.raises(ValueError, match="unknown model kind 'toy'"):
         LLMServer({"kind": "toy"})
+
+
+# -- (c) how a kind's stacked weights lie on the device --------------------------------
+#
+# ``place_params`` asks the platform, which is the CPU here: a test that wants the
+# placing steers ``jax.default_backend`` around that one call (the CPU backend takes a
+# ``major_to_minor`` too) and lets the programs trace after it, on their CPU paths.
+
+HEADS_MAJOR = {name: (0, 2, 1, 3) for name in ("wq", "wk", "wv")}
+
+
+@pytest.mark.parametrize("case", ["the_cpu_backend", "a_kind_that_names_no_layout"])
+def test_placing_leaves_the_parameters_alone_on(case, monkeypatch):
+    if case == "the_cpu_backend":
+        model, cfg = G, FORMS["gptj"]
+    else:
+        model, cfg = sys.modules[__name__], ToyConfig()
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    params = model.init_params(jax.random.PRNGKey(1), cfg)
+    placed, what = paged.place_params(model, cfg, params)
+    assert placed is params and what == {}
+    assert not any(x.is_deleted() for x in params.values())
+
+
+@pytest.mark.parametrize("form", list(FORMS))
+def test_placed_and_unplaced_parameters_give_the_same_greedy_tokens(form, monkeypatch):
+    """24 greedy steps of two sequences through the paged programs: the
+    parameters as ``init_params`` leaves them, and the same with ``wq``,
+    ``wk``, ``wv`` heads-major in memory. Shape, key and value are the same;
+    the originals are gone (the engine's memory has no room for both)."""
+    cfg = FORMS[form]
+    assert G.paged_layouts(cfg) == HEADS_MAJOR
+    plain = T.init_params(jax.random.PRNGKey(5), cfg)
+    given = T.init_params(jax.random.PRNGKey(5), cfg)
+    with monkeypatch.context() as m:
+        m.setattr(jax, "default_backend", lambda: "tpu")
+        placed, what = paged.place_params(G, cfg, given)
+    assert what == HEADS_MAJOR and placed.keys() == plain.keys()
+    for name, x in placed.items():
+        if name in HEADS_MAJOR:
+            assert x.format.layout.major_to_minor == (0, 2, 1, 3) and given[name].is_deleted()
+            assert x.shape == plain[name].shape and x.sharding == plain[name].sharding
+            np.testing.assert_array_equal(x, plain[name])
+        else:
+            assert x is given[name]
+
+    def greedy_tokens(params):
+        prefill, _, greedy = G.make_paged_fns(cfg, block_size=BLOCK)
+        pool = G.init_paged_pool(cfg, BLOCKS, BLOCK)
+        table = np.zeros((2, MAX_BLOCKS), np.int32)
+        table[0], table[1] = np.arange(1, 9), np.arange(9, 17)
+        prompts = [TOKENS[:5], TOKENS[5:8]]
+        tokens = []
+        for i, prompt in enumerate(prompts):
+            toks = np.zeros((1, BUCKET), np.int32)
+            toks[0, :len(prompt)] = prompt
+            logits, pool = prefill(params, jnp.asarray(toks), jnp.asarray(table[i:i + 1]), pool, jnp.int32(len(prompt)))
+            tokens.append(int(jnp.argmax(logits[0])))
+        out = [tokens]
+        for t in range(24):
+            positions = jnp.asarray([len(p) + t for p in prompts], jnp.int32)
+            token, pool = greedy(params, jnp.asarray(out[-1], jnp.int32), positions, jnp.asarray(table), pool,
+                                 jnp.asarray([True, True]))
+            out.append([int(x) for x in token])
+        return out
+
+    assert greedy_tokens(placed) == greedy_tokens(plain)
+
+
+def test_placing_keeps_the_sharding_a_tensor_has(monkeypatch):
+    """A deployment over several chips: a tensor split over a mesh is re-laid
+    shard by shard, on the sharding it came with."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    cfg = FORMS["gptj"]
+    params = T.init_params(jax.random.PRNGKey(2), cfg)
+    want = np.asarray(params["wq"])
+    split = NamedSharding(Mesh(np.array(jax.devices()[:2]), ("tensor",)), PartitionSpec(None, None, "tensor", None))
+    params["wq"] = jax.device_put(params["wq"], split)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    placed, _ = paged.place_params(G, cfg, params)
+    assert placed["wq"].sharding == split and placed["wq"].format.layout.major_to_minor == (0, 2, 1, 3)
+    np.testing.assert_array_equal(placed["wq"], want)
+
+
+def test_the_engine_places_once_and_says_what_it_placed(monkeypatch, caplog):
+    """The engine's start: the placed tree is ``eng.params``, ``loop_stats()``
+    names it, one log line says it; the tokens it serves are the dense path's."""
+    cfg = FORMS["llama"]
+    given = T.init_params(jax.random.PRNGKey(7), cfg)
+    ecfg = EngineConfig(block_size=BLOCK, num_blocks=BLOCKS, max_batch=2, max_blocks_per_seq=MAX_BLOCKS)
+    with monkeypatch.context() as m, caplog.at_level(logging.INFO, logger="ray_tpu.serve.llm.engine"):
+        m.setattr(jax, "default_backend", lambda: "tpu")
+        eng = InferenceEngine(given, cfg, ecfg, deployment="placed")
+    try:
+        assert eng.placed == HEADS_MAJOR and eng.loop_stats()["placed"] == HEADS_MAJOR
+        assert eng.params["wq"].format.layout.major_to_minor == (0, 2, 1, 3) and eng.params["wo"] is given["wo"]
+        said = [r.getMessage() for r in caplog.records if "placed" in r.getMessage()]
+        assert len(said) == 1 and "'wq': (0, 2, 1, 3)" in said[0]
+        prompt = [int(t) for t in TOKENS[:6]]
+        want = np.asarray(G.generate(T.init_params(jax.random.PRNGKey(7), cfg), prompt, cfg, max_new_tokens=12))[0]
+        assert eng.submit(prompt, max_new_tokens=12).tokens() == want.tolist()
+        # placed tensors are committed to their device, and so is the pool from the start:
+        # the bucket's prefill met one signature in its two calls, and was lowered once
+        assert eng.submit(prompt, max_new_tokens=3).tokens() == want[:3].tolist()
+        assert eng._prefill._cache_size() == 1
+    finally:
+        eng.shutdown()
+
+
+def test_the_placing_program_never_comes_from_the_persistent_compile_cache(tmp_path, monkeypatch):
+    """An executable read back from JAX's persistent cache has forgotten its
+    result's layout: a tensor placed by it comes out labelled with the default
+    layout over heads-major bytes, other weights to every reader (found on the
+    chip, PR 32; the CPU backend does the same). So ``place_params`` compiles
+    its copy every time, with a cache configured and warm as a replica's is."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    cfg = FORMS["gptj"]
+    settings = {"jax_compilation_cache_dir": str(tmp_path), "jax_persistent_cache_min_compile_time_secs": 0.0,
+                "jax_persistent_cache_min_entry_size_bytes": 0}
+    before = {name: getattr(jax.config, name) for name in settings}
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    try:
+        for name, value in settings.items():
+            jax.config.update(name, value)
+        compilation_cache.reset_cache()
+        for start in range(2):  # a cold cache, then what the first start left in it
+            jax.clear_caches()  # a new process: nothing compiled is in memory
+            plain = T.init_params(jax.random.PRNGKey(5), cfg)
+            placed, what = paged.place_params(G, cfg, T.init_params(jax.random.PRNGKey(5), cfg))
+            assert what == HEADS_MAJOR
+            for name in HEADS_MAJOR:
+                assert placed[name].format.layout.major_to_minor == (0, 2, 1, 3)
+                np.testing.assert_array_equal(placed[name], plain[name])
+        assert jax.config.jax_enable_compilation_cache  # and the cache is on again for the programs behind it
+    finally:
+        for name, value in before.items():
+            jax.config.update(name, value)
+        compilation_cache.reset_cache()

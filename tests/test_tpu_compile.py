@@ -102,43 +102,105 @@ def test_flash_module_is_the_same_wherever_it_is_called_from(one_chip):
         assert here == there, launcher
 
 
-def test_paged_decode_and_prefill_at_gptj_widths(one_chip, monkeypatch):
-    """serve.llm's device programs (``make_paged_fns``) at GPT-J-6B's
-    published widths; 2 of the 28 layers (the scan body is the same). The
-    decode steps hold the paged-attention kernel and read the pool nowhere
-    else: no gathered copy of a table's rows, no layer cut out of the pool.
-    The prefill (S is the bucket) stays on the gather path."""
+# serve.llm's two published geometries of the GPT-J/Llama kind
+WIDTHS = {
+    "gptj": GPTJ,  # 16 heads of 256
+    "llama": dict(vocab_size=32000, d_model=4096, n_heads=32, d_ff=11008, max_seq_len=2048),  # Llama-2-7B: 32 of 128
+}
+
+
+def _alone(text):
+    """(dims, layout, op) of every instruction that runs by itself. What stands
+    inside a fused computation is the fusion's own arithmetic: a
+    ``dynamic-slice`` there is the fusion reading its slice in place."""
     import re
+
+    fused = set(re.findall(r" fusion\(.*?calls=%([\w.\-]+)", text))
+    out, inside = [], False
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\{$", line)
+        if head:
+            inside = head.group(1) in fused
+        made = re.match(r"\s+(?:ROOT )?%[\w.\-]+ = \w+\[([\d,]*)\](\S*) ([\w\-]+)\(", line)
+        if made and not inside:
+            out.append(made.groups())
+    return out
+
+
+def _staged(text, params):
+    """The instructions that make a copy of a layer's whole q, k or v
+    projection ((D, heads, head_dim), or that with a leading 1), or that hold
+    a layer of any stacked matrix in fast memory (``S(1)``)."""
+    layer = {name: ",".join(map(str, x.shape[1:])) for name, x in params.items() if x.ndim > 2}
+    whole = {lead + layer[name] for name in ("wq", "wk", "wv") for lead in ("", "1,")}
+    weights = {lead + dims for dims in layer.values() for lead in ("", "1,")}
+    return [
+        (dims, layout, op) for dims, layout, op in _alone(text)
+        if dims in whole or (dims in weights and "S(1)" in layout)
+    ]
+
+
+@pytest.mark.parametrize("widths", list(WIDTHS))
+def test_paged_decode_and_prefill_at_published_widths(one_chip, monkeypatch, widths):
+    """serve.llm's device programs (``make_paged_fns``) at GPT-J-6B's and
+    Llama-2-7B's published widths; 4 of the layers (the scan body is the
+    same; a stack of 2 fits fast memory whole and the compiler then keeps
+    ``wo`` there, which no deployment's depth allows), the parameters lying as
+    the engine places them (``paged_layouts``). The decode steps hold the paged-attention kernel and
+    read the pool nowhere else: no gathered copy of a table's rows, no layer
+    cut out of the pool. No program stages a layer's q, k or v projection in
+    fast memory before its dot: each reads its slice of the stacked tensor in
+    place. The prefill (S is the bucket) stays on the gather path."""
+    import re
+
+    from jax.experimental.layout import Format, Layout
 
     from ray_tpu.models import generation as G
     from ray_tpu.models.transformer import TransformerConfig, init_params
 
     _steered_to_tpu(monkeypatch)
-    cfg = TransformerConfig(n_layers=2, **GPTJ)
+    cfg = TransformerConfig(n_layers=4, **WIDTHS[widths])
+    heads, head_dim = cfg.kv_heads, cfg.head_dim
     block, blocks, batch, per_seq = 16, 384, 8, 64
     prefill, decode, decode_greedy = G.make_paged_fns(cfg, block_size=block)
-    params = _on(one_chip, jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    plain = _on(one_chip, jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    layouts = G.paged_layouts(cfg)
+    assert sorted(layouts) == ["wk", "wq", "wv"]
+    params = {
+        name: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=Format(Layout(major_to_minor=layouts[name]), one_chip))
+        if name in layouts else x for name, x in plain.items()
+    }
     pool = _on(one_chip, jax.eval_shape(lambda: G.init_paged_pool(cfg, blocks, block)))
 
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    # results that are a table's rows (B, 1024, 16, 256) or one layer of the pool
-    slots = blocks * block
-    unwanted = {f"[{batch},{per_seq * block},16,256]"} | {f"[{lead}{slots},16,256]" for lead in ("", "1,", "2,")}
-    for step in (decode_greedy, decode):
-        text = step.lower(
+    def step_text(step, params):
+        return step.lower(
             params, arg((batch,), jnp.int32), arg((batch,), jnp.int32),
             arg((batch, per_seq), jnp.int32), pool, arg((batch,), jnp.bool_),
         ).compile().as_text()
+
+    # results that are a table's rows (B, 1024, heads, head_dim) or one layer of the pool
+    slots = blocks * block
+    unwanted = {f"[{batch},{per_seq * block},{heads},{head_dim}]"} | {
+        f"[{lead}{slots},{heads},{head_dim}]" for lead in ("", "1,", "2,")}
+    for step in (decode_greedy, decode):
+        text = step_text(step, params)
         assert "tpu_custom_call" in text and "paged_decode_attention" in text
         made = re.findall(r"= \w+(\[[\d,]*\])\S* (?:gather|dynamic-slice|copy)\(", text)
         assert made and not unwanted & set(made)
-    text = prefill.lower(
-        params, arg((1, 512), jnp.int32), arg((1, per_seq), jnp.int32), pool,
-        arg((), jnp.int32),
-    ).compile().as_text()
-    assert "tpu_custom_call" not in text
+        assert not _staged(text, plain)
+    # the check sees what the placing removes: in the default layout each of
+    # the three projections is staged whole, a fusion a projection
+    assert [op for _, _, op in _staged(step_text(decode_greedy, plain), plain)] == ["fusion"] * 3
+    for bucket in (256, 512):
+        text = prefill.lower(
+            params, arg((1, bucket), jnp.int32), arg((1, per_seq), jnp.int32), pool,
+            arg((), jnp.int32),
+        ).compile().as_text()
+        assert "tpu_custom_call" not in text
+        assert not _staged(text, plain)
 
 
 def test_latent_decode_and_prefill_at_longcat_widths(one_chip):
