@@ -56,9 +56,10 @@ LLM_REQUEST_FIELDS = (
     "trace_id",
 )
 # one read of the expert layers' routing counts (a model that has such a layer
-# sums them on the device; the engine reads them once a flush interval). All
-# four are cumulative since the engine started, summed over layers and decode
-# steps: differences between two records are what the steps between them did
+# sums them on the device; the engine reads them once a flush interval). The
+# counts (``models.moe.COUNTS``, in that order) are cumulative since the engine
+# started, summed over layers and decode steps: differences between two records
+# are what the steps between them did
 LLM_MOE_FIELDS = (
     "t",  # when the counts reached the host
     "step",  # decode steps dispatched when the counts were copied: they cover at least these
@@ -66,6 +67,7 @@ LLM_MOE_FIELDS = (
     "zero",  # ... to zero-compute (identity) experts
     "absent",  # ... to experts of another chip's share
     "touched",  # held experts with at least one row, a layer a step
+    "peak",  # rows of the held expert that got the most, a layer a step
     "layers",  # expert layers a decode step runs
 )
 _KINDS = {"s": ("llm_step", LLM_STEP_FIELDS), "r": ("llm_request", LLM_REQUEST_FIELDS),

@@ -34,6 +34,7 @@ __all__.append("vit")
 PAGED_KINDS = {
     None: ("ray_tpu.models.generation", "TransformerConfig"),
     "longcat": ("ray_tpu.models.longcat", "LongcatConfig"),
+    "kimi_k2": ("ray_tpu.models.kimi", "KimiConfig"),
 }
 
 

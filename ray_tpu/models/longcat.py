@@ -25,13 +25,15 @@ their routing, summed on the device (``routing_counts`` copies them out).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import moe
-from ray_tpu.ops.latent_attention import latent_decode_attention, latent_prefill_attention, rope_interleaved
+from ray_tpu.models.moe import routing_counts  # noqa: F401 - the engine asks the kind's module for it
+from ray_tpu.ops.latent_attention import mla, rope_interleaved
 from ray_tpu.ops.layers import rms_norm, swiglu
 
 
@@ -75,6 +77,7 @@ class LongcatConfig:
 
     # the names ``models/paged.py`` and the engine read
     n_layers = property(lambda self: self.num_layers)
+    n_expert_layers = property(lambda self: self.num_layers)  # every layer has one: what the routing counts sum over
     max_seq_len = property(lambda self: self.max_position_embeddings)
 
     @property
@@ -156,54 +159,6 @@ def paged_block_bytes(cfg: LongcatConfig, block_size: int) -> int:
     return 2 * cfg.num_layers * block_size * cfg.cache_row_stored * jnp.dtype(cfg.dtype).itemsize
 
 
-_copy = jax.jit(lambda x: x + 0)
-
-
-def routing_counts(pool: Dict):
-    """A copy of the pool's routing counts that outlives the pool's donation
-    to the next step: enqueued behind whatever writes the pool now, so reading
-    it later waits for nothing that step would not have finished anyway."""
-    return _copy(pool["moe_counts"])
-
-
-def mla(cfg: LongcatConfig, w, att_index, h, rows_pool, step):
-    """One latent attention over ``h`` (T, D), its weights read by ``w(name)``:
-    the cache rows scattered into attention ``att_index`` of the pool; then a
-    prefill (S > 1: one prompt from position 0) attends to its own rows per head,
-    and a decode step (S == 1) gathers each sequence's table (``max_blocks x
-    block_size`` latent rows) and attends in the absorbed form."""
-    b, s = step.positions.shape
-    t, bs = b * s, step.block_size
-    if s > 1 and b != 1:
-        raise ValueError("a prefill takes one prompt")
-    heads, eps, dn, rkv = cfg.num_attention_heads, cfg.rms_norm_eps, cfg.qk_nope_head_dim, cfg.kv_lora_rank
-    att_scale = (dn + cfg.qk_rope_head_dim) ** -0.5
-    cq = rms_norm(h @ w("wqa"), w("qa_norm") * cfg.scale_q, eps)
-    q = jnp.einsum("tr,kr->tk", cq, w("wqb")).reshape(b, s, heads, -1)
-    q_n, q_r = q[..., :dn], rope_interleaved(q[..., dn:], step.positions, cfg.rope_theta)
-    kva = jnp.einsum("td,rd->tr", h, w("wkva")).reshape(b, s, -1)
-    ckv = rms_norm(kva[..., :rkv], w("kva_norm") * cfg.scale_kv, eps)
-    k_r = rope_interleaved(kva[..., rkv:], step.positions, cfg.rope_theta)
-    new_rows = jnp.concatenate([ckv, k_r], axis=-1).astype(cfg.dtype)
-    with jax.named_scope("latent_scatter"):
-        flat = jnp.pad(new_rows.reshape(t, -1), ((0, 0), (0, cfg.cache_row_stored - cfg.cache_row)))
-        rows_pool = rows_pool.at[att_index, step.write_slots // bs, step.write_slots % bs].set(flat)
-    wkvb = w("wkvb")
-    if s == 1:
-        with jax.named_scope("latent_gather"):
-            rows = rows_pool[att_index, step.block_tables].reshape(b, -1, cfg.cache_row_stored)[..., :cfg.cache_row]
-        with jax.named_scope("latent_attn"):
-            q_l = jnp.einsum("bhn,hrn->bhr", q_n[:, 0], wkvb[..., :dn])
-            o_l = latent_decode_attention(q_l, q_r[:, 0], rows, step.lengths, scale=att_scale)
-            att = jnp.einsum("bhr,hrv->bhv", o_l, wkvb[..., dn:])
-    else:
-        with jax.named_scope("latent_attn"):
-            kv = jnp.einsum("sr,hrk->shk", new_rows[0, :, :rkv], wkvb)
-            att = latent_prefill_attention(q_n[0], q_r[0], kv[..., :dn], new_rows[0, :, rkv:], kv[..., dn:],
-                                           scale=att_scale)
-    return att.reshape(t, -1) @ w("wo"), rows_pool
-
-
 def ffn(w, h):
     return swiglu(h @ w("w_gate"), h @ w("w_up")) @ w("w_down")
 
@@ -216,6 +171,9 @@ def paged_layer(cfg: LongcatConfig, params, step):
     whole, read in place by the grouped matmul. A decode step adds its routing
     counts to the pool's."""
     eps = cfg.rms_norm_eps
+    attend = functools.partial(
+        mla, cfg, rotate=functools.partial(rope_interleaved, theta=cfg.rope_theta),
+        att_scale=(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5, scale_q=cfg.scale_q, scale_kv=cfg.scale_kv)
 
     @jax.named_scope("block")
     def layer(x, pool, li):
@@ -229,7 +187,7 @@ def paged_layer(cfg: LongcatConfig, params, step):
         first, second = of(0), of(1)
         rows_pool, counts = pool["latent"], pool["moe_counts"]
         with jax.named_scope("mla0"):
-            att, rows_pool = mla(cfg, first, 2 * li, rms_norm(x, first("in_norm"), eps), rows_pool, step)
+            att, rows_pool = attend(first, 2 * li, rms_norm(x, first("in_norm"), eps), rows_pool, step)
         a = x + att
         u = rms_norm(a, first("post_norm"), eps)
         with jax.named_scope("moe"):
@@ -240,7 +198,7 @@ def paged_layer(cfg: LongcatConfig, params, step):
         with jax.named_scope("ffn0"):
             x = a + ffn(first, u)
         with jax.named_scope("mla1"):
-            att, rows_pool = mla(cfg, second, 2 * li + 1, rms_norm(x, second("in_norm"), eps), rows_pool, step)
+            att, rows_pool = attend(second, 2 * li + 1, rms_norm(x, second("in_norm"), eps), rows_pool, step)
         x = x + att
         with jax.named_scope("ffn1"):
             x = x + ffn(second, rms_norm(x, second("post_norm"), eps)) + m

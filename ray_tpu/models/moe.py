@@ -2,7 +2,14 @@
 
 A router over ``n_routed + n_zero`` outputs chooses ``top_k`` of them for
 every token; the first ``n_routed`` are SwiGLU experts, the rest are
-zero-compute (identity) experts that add ``w * u`` with no matmul. A chip
+zero-compute (identity) experts that add ``w * u`` with no matmul (a model
+may have none: ``n_zero`` is what the router has beyond ``n_routed``). Two
+routing rules stand side by side and the layer's caller names one: ``route``
+(LongCat-Flash: a softmax over every output, weights not renormalised) and
+``route_sigmoid`` (DeepSeek-V3's and Kimi-K2's ``noaux_tc``: sigmoid scores,
+weights renormalised over the chosen); in both a bias moves the choice and
+never the weights. A shared expert is no part of this layer: it is a dense
+SwiGLU that the kind's own layer adds for every token. A chip
 holds experts ``[expert_offset, expert_offset + held)`` of a layer that is
 shared over several chips: it routes over all the outputs, computes its own
 experts' part of the result for the tokens that chose them and every identity
@@ -24,8 +31,11 @@ import jax.numpy as jnp
 
 from ray_tpu.ops.layers import swiglu
 
-# what ``expert_layer`` counts of its routing, in this order
-COUNTS = ("held", "zero", "absent", "touched")
+# what ``expert_layer`` counts of its routing, in this order: (token, choice)
+# rows sent to held, identity and absent experts; held experts with at least one
+# row; the rows of the held expert that got the most (over ``held / touched``
+# it says how uneven the choice leaves the load: 1 = even)
+COUNTS = ("held", "zero", "absent", "touched", "peak")
 
 
 # The seeded router: logits of deviation ``ROUTER_SCALE``, so that a token's
@@ -53,6 +63,17 @@ def init_expert_params(key, d_model: int, d_ff: int, held: int, n_outputs: int, 
     }
 
 
+_copy = jax.jit(lambda x: x + 0)
+
+
+def routing_counts(pool: Dict):
+    """A copy of a pool's ``moe_counts`` (``COUNTS`` summed on the device by
+    the decode steps' expert layers) that outlives the pool's donation to the
+    next step: enqueued behind whatever writes the pool now, so reading it
+    later waits for nothing that step would not have finished anyway."""
+    return _copy(pool["moe_counts"])
+
+
 def expert_param_logical_axes() -> Dict[str, Tuple]:
     return {
         "router": ("embed", None),
@@ -74,16 +95,30 @@ def route(u: jax.Array, router: jax.Array, bias: jax.Array, *, top_k: int, scale
     return scale * jnp.take_along_axis(p, experts, axis=-1), experts
 
 
+def route_sigmoid(u: jax.Array, router: jax.Array, bias: jax.Array, *, top_k: int, scale: float):
+    """The second rule, same signature. Sigmoid scores in float32 over every
+    output; the ``top_k`` of ``s + bias`` are chosen (one group of experts:
+    the published rule first keeps the ``topk_group`` best of ``n_group``
+    groups, which at 1 of 1 keeps all; the kind refuses other values); a
+    chosen output's weight is ``scale * s / (sum of the chosen s + 1e-20)``."""
+    z = jnp.dot(u.astype(jnp.float32), router.astype(jnp.float32), precision=jax.lax.Precision.HIGHEST)
+    s = jax.nn.sigmoid(z)
+    _, experts = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
+    chosen = jnp.take_along_axis(s, experts, axis=-1)
+    return scale * chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20), experts
+
+
 def expert_layer(params: Dict[str, jax.Array], u: jax.Array, *, n_routed: int, top_k: int, scale: float,
-                 expert_offset: int = 0, live: Optional[jax.Array] = None, layer=None):
-    """``u`` (T, D) -> (y (T, D), counts (4,) uint32 in the order ``COUNTS``).
+                 expert_offset: int = 0, live: Optional[jax.Array] = None, layer=None, rule=route):
+    """``u`` (T, D) -> (y (T, D), counts uint32 in the order ``COUNTS``).
 
     ``params``: ``router`` (D, n_routed + n_zero), ``router_bias``, and the
     held experts' ``e_gate``/``e_up`` (held, D, F) and ``e_down`` (held, F,
     D): experts ``expert_offset ..`` of the ``n_routed``. ``live`` (T,) bool
     marks the rows that are tokens (padding and empty decode slots route
     nowhere and are not counted). ``counts``: rows sent to held, identity and
-    absent experts, and held experts with at least one row.
+    absent experts, held experts with at least one row, and the most rows any
+    held expert got. ``rule``: ``route`` or ``route_sigmoid``.
 
     ``layer``: the expert tensors are all layers' stacked, (layers, held, ..),
     and this is the layer to use (it may be traced). The grouped matmul then
@@ -94,7 +129,7 @@ def expert_layer(params: Dict[str, jax.Array], u: jax.Array, *, n_routed: int, t
     e_gate, e_up, e_down = params["e_gate"], params["e_up"], params["e_down"]
     held_n = e_gate.shape[0] if layer is None else e_gate.shape[1]
     with jax.named_scope("router"):
-        weights, experts = route(u, params["router"], params["router_bias"], top_k=top_k, scale=scale)
+        weights, experts = rule(u, params["router"], params["router_bias"], top_k=top_k, scale=scale)
         alive = jnp.ones((t, 1), bool) if live is None else live[:, None]
         is_zero = (experts >= n_routed) & alive
         local = experts - expert_offset
@@ -123,5 +158,6 @@ def expert_layer(params: Dict[str, jax.Array], u: jax.Array, *, n_routed: int, t
         y = y + jnp.sum(jnp.where(is_held[..., None], out * weights[..., None], 0.0), axis=1)
     counts = jnp.stack([
         jnp.sum(is_held), jnp.sum(is_zero), jnp.sum(alive & ~is_held & ~is_zero), jnp.sum(sizes > 0),
+        jnp.max(sizes),
     ]).astype(jnp.uint32)
     return y.astype(u.dtype), counts

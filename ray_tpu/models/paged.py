@@ -13,6 +13,8 @@ A model kind is one entry of ``models.PAGED_KINDS`` and one module that gives
 four things, and may give a fifth:
 
     paged_layer(cfg, params, step) -> layer(x (B, S, D), pool, li) -> (x, pool)
+        or, for a model of unlike layers, its sections in order:
+        [(layer, how many layers it covers), ...]
     init_paged_pool(cfg, num_blocks, block_size) -> pool
     paged_block_bytes(cfg, block_size) -> bytes one block holds over all layers
     init_params(key, cfg) -> params with ``embed``, ``final_norm``, ``unembed``
@@ -21,7 +23,16 @@ four things, and may give a fifth:
         the device}, for the tensors whose default layout the layer reads badly
 
 over a config with ``n_layers`` and ``max_seq_len`` (and ``rms_norm_eps``,
-where the final norm's is not ``rms_norm``'s own). ``paged_layer`` is called
+where the final norm's is not ``rms_norm``'s own). **A model of unlike
+layers** (Kimi-K2: one dense layer, then expert layers) hands back a section
+a kind of layer, and ``forward_paged`` runs one scan a section with ``(x,
+pool)`` carried from each into the next and the layer index running on
+(section two of a 1 + 6 model sees ``li`` 1..6: the pool's rows of layer
+``li`` are every section's, a section's own stacked tensors it indexes from
+its own first layer). How the sections divide the layers is the config's
+(``first_k_dense_replace``); the sum must be ``cfg.n_layers``. A kind that
+hands back one function is one section of ``cfg.n_layers``: the program it
+always traced. ``paged_layer`` is called
 once a program, outside the scan over layers, and what it computes there is
 computed once a call: XLA does not lift it out of the loop by itself (a rotary
 table built inside the layer was rebuilt 28 times a step: PERF.md section 6, PR
@@ -139,7 +150,8 @@ def head(cfg, params, x, last=None):
 
 def forward_paged(paged_layer, cfg, params, tokens, positions, write_mask, block_tables, pool, block_size: int, last=None):
     """``tokens`` (B, S) at per-sequence absolute ``positions`` (B, S) through
-    ``cfg.n_layers`` calls of a kind's layer, each writing its cache rows into the
+    ``cfg.n_layers`` calls of a kind's layer (or of its sections' layers, a
+    scan a section), each writing its cache rows into the
     pool and attending over the sequences' blocks. ``write_mask`` (B, S)
     diverts padded rows and inactive slots to the null block. Returns
     (``head``'s logits, pool)."""
@@ -148,18 +160,23 @@ def forward_paged(paged_layer, cfg, params, tokens, positions, write_mask, block
     slot = jnp.take_along_axis(block_tables, pidx, axis=1) * block_size + positions % block_size
     null_slot = jnp.arange(b * s, dtype=slot.dtype) % block_size
     live = write_mask.reshape(-1)
-    layer = paged_layer(cfg, params, Step(
+    sections = paged_layer(cfg, params, Step(
         positions, block_tables, block_size, jnp.where(live, slot.reshape(-1), null_slot), live,
         jnp.where(write_mask[:, 0], positions[:, 0] + 1, 0)))
+    if callable(sections):
+        sections = [(sections, cfg.n_layers)]
+    if sum(n for _, n in sections) != cfg.n_layers:
+        raise ValueError(f"the sections cover {[n for _, n in sections]} layers of {cfg.n_layers}")
 
     # The pool rides in the scan CARRY, not in per-layer outputs: stacked scan
     # outputs allocate a fresh slab and copy every layer's rows through it,
     # which defeats buffer donation and turns each decode step into an
     # O(pool-size) memcpy. Carry-threaded updates alias in place.
-    def body(carry, li):
-        return layer(*carry, li), None
-
-    (x, pool), _ = jax.lax.scan(body, (params["embed"][tokens], pool), jnp.arange(cfg.n_layers))
+    carry, first = (params["embed"][tokens], pool), 0
+    for layer, n in sections:
+        carry, _ = jax.lax.scan(lambda c, li, layer=layer: (layer(*c, li), None), carry, jnp.arange(first, first + n))
+        first += n
+    x, pool = carry
     return head(cfg, params, x, last), pool
 
 

@@ -14,29 +14,73 @@ the rotated key part all heads share (``d_r`` values).
   by the caller. The cache is never up-projected to per-head keys and values.
 
 Both take the rows as gathered arrays (plain ``jnp``; XLA on every platform).
+``mla`` is the whole attention over a paged pool of such rows, for every model
+kind that has one (``models/longcat.py``, ``models/kimi.py``): the caller
+gives the rotary and the score scale, which are what the kinds differ in.
 """
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
+import numpy as np
+
+from ray_tpu.ops.layers import rms_norm
 
 _NEG_INF = -1e30
 
 
 def rope_interleaved(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary embedding over pairs ``(2j, 2j+1)`` of the last axis. ``x``
-    (..., S, d) or (..., S, H, d); ``positions`` (..., S) absolute."""
+    """Rotary embedding over pairs ``(2j, 2j+1)`` of the last axis, at the
+    plain frequencies ``theta ** (-2j / d)``. ``x`` (..., S, d) or
+    (..., S, H, d); ``positions`` (..., S) absolute."""
     d = x.shape[-1]
-    inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    return rotate_pairs(x, positions, 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)))
+
+
+def rotate_pairs(x: jax.Array, positions: jax.Array, inv_freq, scale: float = 1.0) -> jax.Array:
+    """Pairs ``(2j, 2j+1)`` of the last axis rotated by ``positions *
+    inv_freq[j]``, cosine and sine times ``scale`` (YaRN's ratio of
+    mscales; 1: left out of the program)."""
+    d = x.shape[-1]
     ang = positions.astype(jnp.float32)[..., None] * inv_freq
     if x.ndim == positions.ndim + 2:  # a heads axis between S and d
         ang = ang[..., None, :]
     c, s = jnp.cos(ang), jnp.sin(ang)
+    if scale != 1.0:
+        c, s = c * scale, s * scale
     pairs = x.astype(jnp.float32).reshape(*x.shape[:-1], d // 2, 2)
     even, odd = pairs[..., 0], pairs[..., 1]
     out = jnp.stack([even * c - odd * s, odd * c + even * s], axis=-1)
     return out.reshape(x.shape).astype(x.dtype)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(d: int, theta: float, *, factor: float, original_max_position_embeddings: int,
+                  beta_fast: float = 32, beta_slow: float = 1, **_) -> np.ndarray:
+    """YaRN's frequencies over ``d // 2`` pairs (the public DeepSeek-V3
+    modelling code's): the plain ``theta ** (-2j / d)`` where a pair turns more
+    than ``beta_fast`` times over the original context, those over ``factor``
+    where it turns fewer than ``beta_slow`` times, a linear ramp between.
+    Numbers of the config alone: a constant of the program, built on the host
+    once a trace, outside the scan over layers."""
+    j = np.arange(d // 2, dtype=np.float64)
+    plain = theta ** (-2.0 * j / d)
+
+    def pair_that_turns(n):  # the (fractional) pair that turns n times over the original context
+        return d * math.log(original_max_position_embeddings / (2 * math.pi * n)) / (2 * math.log(theta))
+
+    low = max(math.floor(pair_that_turns(beta_fast)), 0)
+    high = min(math.ceil(pair_that_turns(beta_slow)), d - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((j - low) / (high - low), 0.0, 1.0)
+    return (plain * (1.0 - ramp) + plain / factor * ramp).astype(np.float32)
 
 
 def latent_prefill_attention(q_n, q_r, k_n, k_r, v, *, scale: float, block_q: int = 256):
@@ -72,3 +116,54 @@ def latent_decode_attention(q_l, q_r, rows, lengths, *, scale: float):
     scores = jnp.where(mask[:, None, :], scores * scale, _NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1).astype(rows.dtype)
     return jnp.einsum("bhm,bmr->bhr", probs, latent)
+
+
+def mla(cfg, w, att_index, h, rows_pool, step, *, rotate, att_scale: float, scale_q: float = 1.0,
+        scale_kv: float = 1.0):
+    """One latent attention over ``h`` (T, D), its weights read by ``w(name)``
+    (``wqa``, ``qa_norm``, ``wqb``, ``wkva``, ``kva_norm``, ``wkvb``, ``wo``,
+    the three middle matrices as the decode step reads them: ``wqb`` (heads x
+    (d_n + d_r), r_q) and ``wkva`` (r_kv + d_r, D) with the contraction last,
+    ``wkvb`` (heads, r_kv, d_n + d_v)): the cache rows scattered into
+    attention ``att_index`` of the pool ``rows_pool`` (attentions, blocks,
+    block_size, ``cfg.cache_row_stored``); then a prefill (S > 1: one prompt
+    from position 0) attends to its own rows per head, and a decode step (S
+    == 1) gathers each sequence's table (``max_blocks x block_size`` latent
+    rows) and attends in the absorbed form.
+
+    What the model kinds differ in is the caller's: ``rotate(x, positions)``
+    (the rotary over ``q_r`` and the shared ``k_r``), ``att_scale`` (what the
+    scores are multiplied by) and the factors on the two latent norms. ``cfg``
+    gives the sizes under their published names (``num_attention_heads``,
+    ``qk_nope_head_dim``, ``kv_lora_rank``, ``rms_norm_eps``) and the row
+    (``cache_row``, ``cache_row_stored``, ``dtype``); ``step`` is
+    ``models.paged.Step``."""
+    b, s = step.positions.shape
+    t, bs = b * s, step.block_size
+    if s > 1 and b != 1:
+        raise ValueError("a prefill takes one prompt")
+    heads, eps, dn, rkv = cfg.num_attention_heads, cfg.rms_norm_eps, cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    cq = rms_norm(h @ w("wqa"), w("qa_norm") * scale_q, eps)
+    q = jnp.einsum("tr,kr->tk", cq, w("wqb")).reshape(b, s, heads, -1)
+    q_n, q_r = q[..., :dn], rotate(q[..., dn:], step.positions)
+    kva = jnp.einsum("td,rd->tr", h, w("wkva")).reshape(b, s, -1)
+    ckv = rms_norm(kva[..., :rkv], w("kva_norm") * scale_kv, eps)
+    k_r = rotate(kva[..., rkv:], step.positions)
+    new_rows = jnp.concatenate([ckv, k_r], axis=-1).astype(cfg.dtype)
+    with jax.named_scope("latent_scatter"):
+        flat = jnp.pad(new_rows.reshape(t, -1), ((0, 0), (0, cfg.cache_row_stored - cfg.cache_row)))
+        rows_pool = rows_pool.at[att_index, step.write_slots // bs, step.write_slots % bs].set(flat)
+    wkvb = w("wkvb")
+    if s == 1:
+        with jax.named_scope("latent_gather"):
+            rows = rows_pool[att_index, step.block_tables].reshape(b, -1, cfg.cache_row_stored)[..., :cfg.cache_row]
+        with jax.named_scope("latent_attn"):
+            q_l = jnp.einsum("bhn,hrn->bhr", q_n[:, 0], wkvb[..., :dn])
+            o_l = latent_decode_attention(q_l, q_r[:, 0], rows, step.lengths, scale=att_scale)
+            att = jnp.einsum("bhr,hrv->bhv", o_l, wkvb[..., dn:])
+    else:
+        with jax.named_scope("latent_attn"):
+            kv = jnp.einsum("sr,hrk->shk", new_rows[0, :, :rkv], wkvb)
+            att = latent_prefill_attention(q_n[0], q_r[0], kv[..., :dn], new_rows[0, :, rkv:], kv[..., dn:],
+                                           scale=att_scale)
+    return att.reshape(t, -1) @ w("wo"), rows_pool

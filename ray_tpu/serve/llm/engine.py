@@ -54,12 +54,13 @@ handles) are made after it, while the device is busy. Each phase is also a
 ``llm.dispatch``, ``llm.emit``) on this thread's line of any profiler trace.
 With ``telemetry_enabled`` off no record or span is made and the ring is empty.
 
-A model with an expert layer (``models/longcat.py``) sums what its decode
+A model with an expert layer (``models/longcat.py``, ``models/kimi.py``) sums what its decode
 steps routed in a leaf of the pool, on the device. Once a flush interval the
 loop, after its dispatch, enqueues a copy of that leaf behind the step in
 flight and reads it an iteration later, when that step has been retired: no
 step waits for it. Each read feeds ``ray_tpu_llm_moe_rows_total`` /
-``ray_tpu_llm_moe_experts_touched_total`` and, with telemetry on, one loop
+``ray_tpu_llm_moe_experts_touched_total`` / ``ray_tpu_llm_moe_peak_rows_total``
+and, with telemetry on, one loop
 record of kind ``llm_moe`` (cumulative counts; ``looplog.LLM_MOE_FIELDS``).
 """
 
@@ -141,6 +142,13 @@ def _engine_metrics() -> dict:
             "ray_tpu_llm_moe_experts_touched_total",
             "held experts that got at least one row, summed over layers and "
             "decode steps: what a step reads of its expert weights",
+            tag_keys=("deployment",),
+        )
+        _metrics["moe_peak"] = Counter(
+            "ray_tpu_llm_moe_peak_rows_total",
+            "rows of the held expert that got the most, summed over layers "
+            "and decode steps: over held rows / experts touched it says how "
+            "uneven the router's choice leaves the load (1 = even)",
             tag_keys=("deployment",),
         )
     return _metrics
@@ -318,7 +326,7 @@ class InferenceEngine:
     ):
         import jax
 
-        from ray_tpu.models import generation as G, paged, paged_model
+        from ray_tpu.models import generation as G, moe, paged, paged_model
 
         ecfg = engine_cfg or EngineConfig()
         if ecfg.max_batch < 1:
@@ -375,10 +383,10 @@ class InferenceEngine:
         # a model with an expert layer sums its routing counts on the device,
         # in a leaf of the pool; the loop copies them out once a flush interval
         self._routing_counts = getattr(model, "routing_counts", None)
-        self._moe_layers = getattr(model_cfg, "num_layers", 0)
+        self._moe_layers = getattr(model_cfg, "n_expert_layers", 0)  # the kind's: what a step's counts sum over
         self._moe_copy = None  # (the copy, still on the device; the step it was taken behind)
-        self._moe_seen = [0, 0, 0, 0]  # the counts last read, modulo 2**32
-        self._moe_total = [0, 0, 0, 0]  # held, zero, absent rows; experts touched
+        self._moe_seen = [0] * len(moe.COUNTS)  # the counts last read, modulo 2**32
+        self._moe_total = [0] * len(moe.COUNTS)  # ``moe.COUNTS`` since the engine started
         # -- what the loop measures of itself (module docstring) ----------
         # resolved once: a replica builds its engine after it has connected
         self._tel = telemetry.get_buffer() if telemetry.enabled() else None
@@ -395,7 +403,7 @@ class InferenceEngine:
         self._m_decode_tokens = m["tokens"].bind({**tags, "phase": "decode"})
         if self._routing_counts is not None:  # a model without an expert layer has no such series
             self._m_moe = [m["moe_rows"].bind({**tags, "dest": d}) for d in ("held", "zero", "absent")]
-            self._m_moe.append(m["moe_touched"].bind(tags))
+            self._m_moe += [m["moe_touched"].bind(tags), m["moe_peak"].bind(tags)]
         # periodic device sweeps refresh the ray_tpu_kv_* gauges of an idle engine
         memplane.register_kv_provider(deployment, self._occupancy)
         if start:

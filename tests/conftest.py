@@ -82,3 +82,78 @@ def pytest_sessionfinish(session, exitstatus):
         os._exit(status)
 
     threading.Thread(target=_watchdog, name="exit-watchdog", daemon=True).start()
+
+
+# A test's own time limit. On an idle machine the longest test of tier 1 takes
+# about 340 s (the learner group's restart test sits out a 300 s update
+# timeout by design); every wait under it is bounded, and yet a run has been
+# seen to stop for good in that test's last seconds, and once just after it,
+# with the five other workers long done: the whole run then sat until its
+# caller's limit cut it, and counted nothing past the last full line of dots.
+# So a test that is still going after TEST_LIMIT_S fails where it stands, with
+# every thread's stack on the real stderr, and the run goes on to its end.
+# Where the main thread cannot be interrupted (blocked inside native code) the
+# stacks are all there is: the process is left alone, because under
+# ``--dist loadfile`` xdist hands a crashed worker's test to the next worker,
+# and the next, each for the whole of the limit.
+TEST_LIMIT_S = 480
+# what is left of a test that was cut (its ``finally``, its fixtures' teardown)
+# may be stuck on the same thing: it is cut again, this often
+TEST_CUT_AGAIN_S = 60
+_real_stderr_fd = 2
+
+
+def pytest_configure(config):
+    # global capture is suspended here, so this is the terminal's stderr and
+    # not the file a test's output is captured in
+    global _real_stderr_fd
+    import os
+
+    _real_stderr_fd = os.dup(2)
+
+
+@pytest.hookimpl(hookwrapper=True)
+def pytest_runtest_protocol(item, nextitem):
+    """Setup, call and teardown of one test, under one limit."""
+    import faulthandler
+    import os
+    import signal
+    import time
+
+    started = time.monotonic()
+
+    def _out_of_time(signum, frame):
+        faulthandler.cancel_dump_traceback_later()
+        signal.alarm(TEST_CUT_AGAIN_S)
+        os.write(
+            _real_stderr_fd,
+            f"\n[conftest] {item.nodeid} is still running after "
+            f"{time.monotonic() - started:.0f}s; every thread's stack:\n".encode(),
+        )
+        faulthandler.dump_traceback(file=_real_stderr_fd, all_threads=True)
+        pytest.fail(
+            f"{item.nodeid} ran into the {TEST_LIMIT_S}s limit that "
+            "tests/conftest.py gives every test",
+            pytrace=True,
+        )
+
+    # pytest's own faulthandler_timeout uses the same one timer
+    dump_later = not item.config.getini("faulthandler_timeout")
+    try:
+        previous = signal.signal(signal.SIGALRM, _out_of_time)
+    except ValueError:  # not the main thread: the stacks alone
+        previous = None
+    else:
+        signal.alarm(TEST_LIMIT_S)
+    if dump_later:
+        faulthandler.dump_traceback_later(
+            TEST_LIMIT_S + 30, file=_real_stderr_fd
+        )
+    try:
+        yield
+    finally:
+        if dump_later:
+            faulthandler.cancel_dump_traceback_later()
+        if previous is not None:
+            signal.alarm(0)
+            signal.signal(signal.SIGALRM, previous)

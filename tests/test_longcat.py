@@ -172,14 +172,14 @@ def test_the_shares_of_the_expert_layer_add_up_to_the_uncut_layer():
     want = np.asarray(R.moe(u, ref, 0, hy, "f32"))
     weights_, chosen = R.route(u, ref["router"][0], ref["router_bias"][0], hy, "f32")
     identity = np.asarray(R.identity_part(u, weights_, chosen, hy))
-    total, rows = np.zeros_like(want), np.zeros(4, np.int64)
+    total, rows = np.zeros_like(want), np.zeros(len(moe.COUNTS), np.int64)
     for offset in range(0, cfg.n_routed_experts, 2):
         share = {**layer, **{k: layer[k][offset:offset + 2] for k in ("e_gate", "e_up", "e_down")}}
         y, counts = moe.expert_layer(share, u, expert_offset=offset, **kw)
         total += np.asarray(y) - identity  # every chip adds the identity part for its own tokens: counted once
         rows += np.asarray(counts)
     np.testing.assert_allclose(total + identity, want, atol=2e-5)
-    held, zero, absent, _ = rows
+    held, zero, absent = rows[:3]
     # a routed row is held by one share and absent from the three others; an identity row is every share's
     assert zero % 4 == 0 and held + zero // 4 == 24 * 3 and absent == 3 * held
 
@@ -263,7 +263,7 @@ def test_no_row_is_dropped_when_every_token_chooses_one_expert():
     u = jax.random.normal(jax.random.PRNGKey(1), (t, d))
     y, counts = jax.jit(lambda rows: moe.expert_layer(params, rows, n_routed=2, top_k=1, scale=6.0))(u)
     w, chosen = moe.route(u, params["router"], params["router_bias"], top_k=1, scale=6.0)
-    assert np.all(np.asarray(chosen) == 0) and np.asarray(counts).tolist() == [t, 0, 0, 1]
+    assert np.all(np.asarray(chosen) == 0) and np.asarray(counts).tolist() == [t, 0, 0, 1, t]
     dense = (jax.nn.silu(u @ params["e_gate"][0]) * (u @ params["e_up"][0])) @ params["e_down"][0]
     np.testing.assert_allclose(np.asarray(y), np.asarray(w * dense), atol=1e-5)
     assert np.all(np.abs(np.asarray(y)).sum(-1) > 0)  # every one of the 40 rows came through
@@ -316,7 +316,7 @@ def test_the_engine_serves_the_replayed_tokens_and_reports_the_latent_pool():
         assert stats["blocks_total"] == BLOCKS - 1 and stats["blocks_free"] == BLOCKS - 1
         eng._moe_copy = (M.routing_counts(eng._pool), eng.decode_steps)
         eng._fold_routing_counts()
-        held, zero, absent, touched = eng._moe_total
+        held, zero, absent, touched, peak = eng._moe_total
         assert held + zero == sum(5 + i for i in range(6)) * cfg.num_layers * cfg.moe_topk and absent == 0
     finally:
         server._engine.shutdown()

@@ -175,6 +175,147 @@ def test_a_made_up_kind_is_served_through_its_table_entry_alone():
         LLMServer({"kind": "toy"})
 
 
+# -- (b') sections: a model of unlike layers in one paged program ------------------------
+#
+# The made-up kind again, with a leading layer of another shape: layer 0 has no MLP and
+# mixes twice as hard; its tensors are its own ("lead_mix", unstacked), the alike layers'
+# are stacked over those layers alone and read from the section's first layer on.
+
+
+@dataclasses.dataclass(frozen=True)
+class LeadToyConfig(ToyConfig):
+    n_layers: int = 3  # the leading layer, then two alike
+
+
+def lead_params(key, cfg):
+    params = init_params(key, dataclasses.replace(cfg, n_layers=cfg.n_layers - 1))
+    return {**params, "lead_mix": jax.random.normal(jax.random.fold_in(key, 7), (cfg.width, cfg.width), cfg.dtype)
+            * cfg.width ** -0.5}
+
+
+def lead_paged_layer(cfg, params, step):
+    b, s = step.positions.shape
+    bs = step.block_size
+    upto = (jnp.arange(step.block_tables.shape[1] * bs) <= step.positions[..., None]).astype(cfg.dtype)
+
+    def mix(x, pool, li, w):  # the layer's rows go to the pool's layer li, whichever section runs it
+        rows = pool["rows"].at[li, step.write_slots // bs, step.write_slots % bs].set((x @ w).reshape(b * s, -1))
+        seen = rows[li, step.block_tables].reshape(b, -1, cfg.width)
+        mixed = jnp.einsum("bsm,bmd->bsd", upto, seen) / (step.positions[..., None] + 1)
+        return mixed, {"rows": rows, "rows_written": pool["rows_written"] + jnp.sum(step.live)}
+
+    def lead(x, pool, li):
+        mixed, pool = mix(x, pool, li, params["lead_mix"])
+        return x + 2 * mixed, pool
+
+    def alike(x, pool, li):
+        own = li - 1  # this section's tensors are stacked from its own first layer
+        mixed, pool = mix(x, pool, li, params["w_mix"][own])
+        x = x + mixed
+        return x + gelu(x @ params["w_up"][own]) @ params["w_down"][own], pool
+
+    return [(lead, 1), (alike, cfg.n_layers - 1)]
+
+
+def lead_next_token(cfg, params, tokens):
+    x = params["embed"][jnp.asarray(tokens)]
+    count = jnp.arange(1, len(tokens) + 1)[:, None]
+    x = x + 2 * jnp.cumsum(x @ params["lead_mix"], axis=0) / count
+    for li in range(cfg.n_layers - 1):
+        x = x + jnp.cumsum(x @ params["w_mix"][li], axis=0) / count
+        x = x + gelu(x @ params["w_up"][li]) @ params["w_down"][li]
+    return int(jnp.argmax(rms_norm(x[-1], params["final_norm"]) @ params["unembed"]))
+
+
+def test_a_kind_of_two_sections_is_served_with_the_pool_carried_through_both():
+    lead = type(sys)("lead_toy")  # the kind's module: the toy's, with the two things that differ
+    lead.__dict__.update(LeadToyConfig=LeadToyConfig, init_params=lead_params, paged_layer=lead_paged_layer,
+                         init_paged_pool=init_paged_pool, paged_block_bytes=paged_block_bytes)
+    sys.modules["lead_toy"] = lead
+    models.PAGED_KINDS["lead_toy"] = ("lead_toy", "LeadToyConfig")
+    try:
+        server = LLMServer({"kind": "lead_toy", "vocab_size": 48},
+                           dict(block_size=BLOCK, num_blocks=BLOCKS, max_batch=2, max_blocks_per_seq=MAX_BLOCKS),
+                           weight_seed=4)
+        try:
+            eng = server._engine
+            cfg = eng.model_cfg
+            assert eng._pool["rows"].shape[0] == cfg.n_layers == 3
+            prompts = [[5, 9, 2], [7] * 9, [1, 2, 3, 4, 5, 6]]
+            for prompt in prompts:
+                seq = list(prompt)
+                for token in server.generate(prompt, max_new_tokens=7):
+                    assert token == lead_next_token(cfg, eng.params, seq)
+                    seq.append(token)
+            # every layer of both sections wrote its rows: the pool's own leaf rode through both scans
+            assert int(eng._pool["rows_written"]) == cfg.n_layers * sum(len(p) + 6 for p in prompts)
+        finally:
+            server._engine.shutdown()
+    finally:
+        del models.PAGED_KINDS["lead_toy"], sys.modules["lead_toy"]
+    # sections that do not cover the model's layers are refused when the program is traced
+    short = lambda cfg, params, step: lead_paged_layer(cfg, params, step)[:1]  # noqa: E731
+    cfg = LeadToyConfig()
+    prefill, _, _ = paged.make_paged_fns(short, cfg, block_size=BLOCK)
+    with pytest.raises(ValueError, match=r"cover \[1\] layers of 3"):
+        prefill(lead_params(jax.random.PRNGKey(0), cfg), jnp.zeros((1, BUCKET), jnp.int32),
+                jnp.zeros((1, MAX_BLOCKS), jnp.int32), init_paged_pool(cfg, BLOCKS, BLOCK), jnp.int32(3))
+
+
+def _forward_paged_before_sections(paged_layer, cfg, params, tokens, positions, write_mask, block_tables, pool,
+                                   block_size, last=None):
+    """``forward_paged`` as it stood before a kind could hand back sections (PR 32), kept here as the
+    witness: one layer function, one scan over ``cfg.n_layers``."""
+    b, s = tokens.shape
+    pidx = jnp.clip(positions // block_size, 0, block_tables.shape[1] - 1)
+    slot = jnp.take_along_axis(block_tables, pidx, axis=1) * block_size + positions % block_size
+    null_slot = jnp.arange(b * s, dtype=slot.dtype) % block_size
+    live = write_mask.reshape(-1)
+    layer = paged_layer(cfg, params, paged.Step(
+        positions, block_tables, block_size, jnp.where(live, slot.reshape(-1), null_slot), live,
+        jnp.where(write_mask[:, 0], positions[:, 0] + 1, 0)))
+
+    def body(carry, li):
+        return layer(*carry, li), None
+
+    (x, pool), _ = jax.lax.scan(body, (params["embed"][tokens], pool), jnp.arange(cfg.n_layers))
+    return paged.head(cfg, params, x, last), pool
+
+
+@pytest.mark.parametrize("kind", ["gptj", "longcat", "toy"])
+def test_a_kind_of_one_function_traces_the_program_it_traced_before_sections(kind, monkeypatch):
+    """The three programs of a kind that hands back one layer function, traced
+    through today's ``forward_paged`` and through the one it replaced: the same
+    jaxpr, letter for letter."""
+    if kind == "gptj":
+        cfg, layer = FORMS["gptj"], G.paged_layer
+        params, pool = T.init_params(jax.random.PRNGKey(0), cfg), G.init_paged_pool(cfg, BLOCKS, BLOCK)
+    elif kind == "longcat":
+        from ray_tpu.models import longcat
+
+        cfg = longcat.LongcatConfig(
+            vocab_size=64, hidden_size=32, ffn_hidden_size=48, expert_ffn_hidden_size=16, num_layers=2,
+            num_attention_heads=2, kv_lora_rank=8, q_lora_rank=16, qk_rope_head_dim=4, qk_nope_head_dim=4, v_head_dim=4,
+            n_routed_experts=4, zero_expert_num=2, moe_topk=2, max_position_embeddings=64, dtype=jnp.float32)
+        layer = longcat.paged_layer
+        params, pool = longcat.init_params(jax.random.PRNGKey(0), cfg), longcat.init_paged_pool(cfg, BLOCKS, BLOCK)
+    else:
+        cfg, layer = ToyConfig(), paged_layer
+        params, pool = init_params(jax.random.PRNGKey(0), cfg), init_paged_pool(cfg, BLOCKS, BLOCK)
+
+    def traced():
+        prefill, decode, greedy = paged.make_paged_fns(layer, cfg, block_size=BLOCK)
+        step = (params, jnp.zeros((3,), jnp.int32), jnp.zeros((3,), jnp.int32), jnp.zeros((3, MAX_BLOCKS), jnp.int32),
+                pool, jnp.ones((3,), bool))
+        return [str(jax.make_jaxpr(prefill)(params, jnp.zeros((1, BUCKET), jnp.int32),
+                                            jnp.zeros((1, MAX_BLOCKS), jnp.int32), pool, jnp.int32(5))),
+                str(jax.make_jaxpr(decode)(*step)), str(jax.make_jaxpr(greedy)(*step))]
+
+    now = traced()
+    monkeypatch.setattr(paged, "forward_paged", _forward_paged_before_sections)
+    assert traced() == now
+
+
 # -- (c) how a kind's stacked weights lie on the device --------------------------------
 #
 # ``place_params`` asks the platform, which is the CPU here: a test that wants the

@@ -237,6 +237,68 @@ def test_latent_decode_and_prefill_at_longcat_widths(one_chip):
     assert "gather(" not in "".join(line for line in compiled.as_text().splitlines() if f",{block},640]" in line)
 
 
+def test_latent_decode_and_prefill_at_kimi_k2_widths(one_chip):
+    """serve.llm's programs for Kimi-K2's language model as the benchmark's
+    configuration cuts it (``benchmarks/configs/kimi-k2-7l.json``): the
+    published widths, the dense layer and six expert layers as two scans in one
+    program, 12 of 384 experts, an eighth of the vocabulary, the engine's 48
+    slots over 7,681 blocks. 9.70 GB of weights and a 1.10 GB pool are the
+    program's arguments. The decode step holds the grouped matmuls, gathers a
+    table's blocks and never copies the pool. **No weight is copied out of
+    its stack before its matmul but the two that longcat's programs copy
+    too**, in the one ``mla`` both kinds run (PERF.md, section 7): ``wqb``'s
+    layer (a ``constant_dynamic-slice_fusion`` of 37.7 MB a layer, 0.69 ms a
+    step on the chip; at 64 slots and in the prefill into fast memory,
+    ``S(1)``; whichever way the matrix lies: a ``major_to_minor`` of (0, 2, 1)
+    adds a copy to it, so the kind names no ``paged_layouts``) and ``wkvb``'s
+    two halves (a head's key and value parts, cut along the minor axis, held
+    in ``S(1)``). The dense layer's 18432-wide tensors, the shared expert's,
+    ``wqa``, ``wkva``, ``wo``, the router and the experts are read where they
+    lie."""
+    import re
+
+    from ray_tpu.models import kimi as M, paged
+
+    cfg = M.KimiConfig(vocab_size=20480, num_hidden_layers=7, experts_held=12)
+    assert (cfg.first_k_dense_replace, cfg.n_expert_layers) == (1, 6) and not hasattr(M, "paged_layouts")
+    block, blocks, batch, per_seq = 16, 7681, 48, 96
+    prefill, _, decode_greedy = paged.make_paged_fns(M.paged_layer, cfg, block_size=block)
+    params = _on(one_chip, jax.eval_shape(lambda: M.init_params(jax.random.PRNGKey(0), cfg)))
+    pool = _on(one_chip, jax.eval_shape(lambda: M.init_paged_pool(cfg, blocks, block)))
+    assert pool["latent"].shape == (7, blocks, block, 640) and M.paged_block_bytes(cfg, block) == 7 * 16 * 1280
+    nbytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
+    assert 9.69e9 < nbytes < 9.71e9 and 1.10e9 < blocks * M.paged_block_bytes(cfg, block) < 1.11e9
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    stacked = {lead + ",".join(map(str, x.shape[1:])): name for name, x in params.items()
+               if x.ndim > 2 for lead in ("", "1,")}
+
+    def staged(text):
+        """Names of the stacked tensors a layer of which some instruction of its
+        own makes: a copy out of the stack, into fast memory or not."""
+        return {stacked[dims] for dims, layout, op in _alone(text)
+                if dims in stacked and op not in ("parameter", "bitcast", "get-tuple-element")}
+
+    compiled = decode_greedy.lower(
+        params, arg((batch,), jnp.int32), arg((batch,), jnp.int32), arg((batch, per_seq), jnp.int32), pool,
+        arg((batch,), jnp.bool_),
+    ).compile()
+    text = compiled.as_text()
+    assert text.count("ragged-dot") >= 3  # gate, up and down of the held experts
+    made = re.findall(r"= \w+(\[[\d,]*\])\S* (?:gather|copy|transpose)\(", text)
+    assert f"[{batch},{per_seq},{block},640]" in made  # a table's blocks, gathered
+    assert f"[7,{blocks},{block},640]" not in made  # the pool itself: scattered into in place
+    assert staged(text) == {"wqb", "wkvb"}
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9  # beside 10.8 GB of arguments
+    compiled = prefill.lower(
+        params, arg((1, 512), jnp.int32), arg((1, per_seq), jnp.int32), pool, arg((), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "gather(" not in "".join(line for line in text.splitlines() if f",{block},640]" in line)
+    assert staged(text) <= {"wqb", "wkvb"}
+
+
 def _steered_to_tpu(monkeypatch):
     """``attention`` asks ``jax.default_backend()``, which is the CPU here:
     the test steers it to the branch it takes on the chip."""
