@@ -16,7 +16,11 @@ the rotated key part all heads share (``d_r`` values).
 Both take the rows as gathered arrays (plain ``jnp``; XLA on every platform).
 ``mla`` is the whole attention over a paged pool of such rows, for every model
 kind that has one (``models/longcat.py``, ``models/kimi.py``): the caller
-gives the rotary and the score scale, which are what the kinds differ in.
+gives the rotary and the score scale, which are what the kinds differ in. On
+a TPU its decode step reads the pool where it lies, through the Pallas kernel
+``ops.paged_attention.paged_latent_attention`` (a sequence's live blocks and
+no others; chosen by platform and static shape alone); prefills, and the CPU,
+keep the two paths above.
 """
 
 from __future__ import annotations
@@ -28,6 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.ops.layers import rms_norm
+from ray_tpu.ops.paged_attention import can_use_latent_kernel, paged_latent_attention
 
 _NEG_INF = -1e30
 
@@ -128,8 +133,9 @@ def mla(cfg, w, att_index, h, rows_pool, step, *, rotate, att_scale: float, scal
     attention ``att_index`` of the pool ``rows_pool`` (attentions, blocks,
     block_size, ``cfg.cache_row_stored``); then a prefill (S > 1: one prompt
     from position 0) attends to its own rows per head, and a decode step (S
-    == 1) gathers each sequence's table (``max_blocks x block_size`` latent
-    rows) and attends in the absorbed form.
+    == 1) attends in the absorbed form: over each sequence's live blocks
+    where they lie (``can_use_latent_kernel``: a TPU), or over its whole
+    table, gathered (``max_blocks x block_size`` latent rows) and masked.
 
     What the model kinds differ in is the caller's: ``rotate(x, positions)``
     (the rotary over ``q_r`` and the shared ``k_r``), ``att_scale`` (what the
@@ -155,11 +161,15 @@ def mla(cfg, w, att_index, h, rows_pool, step, *, rotate, att_scale: float, scal
         rows_pool = rows_pool.at[att_index, step.write_slots // bs, step.write_slots % bs].set(flat)
     wkvb = w("wkvb")
     if s == 1:
-        with jax.named_scope("latent_gather"):
-            rows = rows_pool[att_index, step.block_tables].reshape(b, -1, cfg.cache_row_stored)[..., :cfg.cache_row]
         with jax.named_scope("latent_attn"):
             q_l = jnp.einsum("bhn,hrn->bhr", q_n[:, 0], wkvb[..., :dn])
-            o_l = latent_decode_attention(q_l, q_r[:, 0], rows, step.lengths, scale=att_scale)
+            if can_use_latent_kernel(s, rkv, rows_pool):
+                o_l = paged_latent_attention(q_l, q_r[:, 0], rows_pool, att_index, step.block_tables, step.lengths,
+                                             scale=att_scale)
+            else:
+                with jax.named_scope("latent_gather"):
+                    rows = rows_pool[att_index, step.block_tables].reshape(b, -1, cfg.cache_row_stored)
+                o_l = latent_decode_attention(q_l, q_r[:, 0], rows[..., :cfg.cache_row], step.lengths, scale=att_scale)
             att = jnp.einsum("bhr,hrv->bhv", o_l, wkvb[..., dn:])
     else:
         with jax.named_scope("latent_attn"):

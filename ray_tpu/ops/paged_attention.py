@@ -1,4 +1,9 @@
-"""Decode-time attention over the paged KV pool: a Pallas TPU kernel.
+"""Decode-time attention over the paged pools: two Pallas TPU kernels that
+share one walk (a sequence's live blocks in table order, in chunks of a fixed
+size, under an online softmax): ``paged_decode_attention`` over a pool of keys
+and one of values (GPT-J, Llama), described here, and
+``paged_latent_attention`` over the latent cache's one pool of rows (LongCat,
+Kimi-K2), at the end of the file.
 
 One query position a sequence. The pool (L, slots, kv_heads, head_dim)
 stays in HBM; for each sequence the kernel copies its *live* blocks, and
@@ -52,6 +57,48 @@ def can_use_paged_kernel(q, pool_k, block_size: int) -> bool:
     )
 
 
+def chunk_blocks_for(max_blocks: int, block_bytes: int, chunk_bytes: int = _CHUNK_BYTES) -> int:
+    """How many of a table's blocks one VMEM buffer takes: ``chunk_bytes`` of
+    them, a whole table at the most, one at the least. Fixed by shapes, so a
+    sequence walks its blocks in the same chunks whoever shares the step."""
+    return max(1, min(max_blocks, chunk_bytes // block_bytes))
+
+
+def for_live_blocks(tbl_ref, seq, n_blocks, chunk, chunk_blocks: int, copies, act):
+    """``act`` on every copy of chunk ``chunk`` of sequence ``seq``'s table:
+    for the chunk's ``j``-th block, where it is live, ``copies(block, j)``
+    names the descriptors that take pool block ``block`` to the buffer's
+    ``j``-th place (one a pool). Started and waited for through the same walk."""
+    for j in range(chunk_blocks):
+        i = chunk * chunk_blocks + j
+
+        @pl.when(i < n_blocks)
+        def _():
+            for copy in copies(tbl_ref[seq, i], j):
+                act(copy)
+
+
+def online_softmax_weights(m, l, s):
+    """One chunk of the online softmax: ``s`` (heads, cols) float32 scores,
+    the dead columns already at ``_NEG_INF``, against the running maximum
+    ``m`` and sum ``l`` (float32). -> the new maximum, the factor on what was
+    summed before, the chunk's weights (float32: the caller casts them to the
+    pool's type into its weighted sum) and the new sum."""
+    m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+    alpha = jnp.exp(m - m_new)
+    p = jnp.exp(s - m_new)
+    return m_new, alpha, p, alpha * l + p.sum(axis=-1, keepdims=True)
+
+
+def softmax_start(heads: int, width: int):
+    """The online softmax before its first chunk: maximum, sum, output."""
+    return (
+        jnp.full((heads, 1), _NEG_INF, jnp.float32),
+        jnp.zeros((heads, 1), jnp.float32),
+        jnp.zeros((heads, width), jnp.float32),
+    )
+
+
 def _kernel(
     li_ref, len_ref, tbl_ref,  # scalar prefetch
     q_ref, pk_ref, pv_ref,  # q (1, H, Hd) in VMEM; the pools in HBM
@@ -76,20 +123,20 @@ def _kernel(
     def _():
         vbuf[...] = jnp.zeros_like(vbuf)
 
-    def for_live_blocks(chunk, slot, act):
-        for j in range(chunk_blocks):
-            i = chunk * chunk_blocks + j
+    def chunk_copies(chunk, slot, act):
+        def copies(block, j):
+            src = pl.ds(block * block_size, block_size)
+            dst = pl.ds(j * block_size, block_size)
+            return (
+                pltpu.make_async_copy(pk_ref.at[li, src], kbuf.at[slot, dst], sem.at[0, slot]),
+                pltpu.make_async_copy(pv_ref.at[li, src], vbuf.at[slot, dst], sem.at[1, slot]),
+            )
 
-            @pl.when(i < n_blocks)
-            def _():
-                src = pl.ds(tbl_ref[b, i] * block_size, block_size)
-                dst = pl.ds(j * block_size, block_size)
-                act(pltpu.make_async_copy(pk_ref.at[li, src], kbuf.at[slot, dst], sem.at[0, slot]))
-                act(pltpu.make_async_copy(pv_ref.at[li, src], vbuf.at[slot, dst], sem.at[1, slot]))
+        for_live_blocks(tbl_ref, b, n_blocks, chunk, chunk_blocks, copies, act)
 
     @pl.when(n_chunks > 0)
     def _():
-        for_live_blocks(0, 0, lambda c: c.start())
+        chunk_copies(0, 0, lambda c: c.start())
 
     q = q_ref[0]
     scale = 1.0 / (head_dim**0.5)
@@ -104,31 +151,21 @@ def _kernel(
 
         @pl.when(c + 1 < n_chunks)
         def _():
-            for_live_blocks(c + 1, 1 - slot, lambda d: d.start())
+            chunk_copies(c + 1, 1 - slot, lambda d: d.start())
 
-        for_live_blocks(c, slot, lambda d: d.wait())
+        chunk_copies(c, slot, lambda d: d.wait())
         s = jax.lax.dot_general(
             q, kbuf[slot].reshape(cols, head_dim), (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale
         s = jnp.where(own_head & (c * rows + row < length), s, _NEG_INF)
-        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
-        alpha = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new)
-        l = alpha * l + p.sum(axis=-1, keepdims=True)
+        m, alpha, p, l = online_softmax_weights(m, l, s)
         acc = alpha * acc + jnp.dot(
             p.astype(vbuf.dtype), vbuf[slot].reshape(cols, head_dim), preferred_element_type=jnp.float32
         )
-        return m_new, l, acc
+        return m, l, acc
 
-    m, l, acc = jax.lax.fori_loop(
-        0, n_chunks, chunk_step,
-        (
-            jnp.full((heads, 1), _NEG_INF, jnp.float32),
-            jnp.zeros((heads, 1), jnp.float32),
-            jnp.zeros((heads, head_dim), jnp.float32),
-        ),
-    )
+    m, l, acc = jax.lax.fori_loop(0, n_chunks, chunk_step, softmax_start(heads, head_dim))
     # an inactive slot (length 0) read nothing: its output is 0, not 0/0
     o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
@@ -148,7 +185,7 @@ def paged_decode_attention(
     b, heads, head_dim = q.shape
     _, _, kv_heads, _ = pool_k.shape
     block_bytes = block_size * kv_heads * head_dim * jnp.dtype(pool_k.dtype).itemsize
-    chunk_blocks = max(1, min(block_tables.shape[1], _CHUNK_BYTES // block_bytes))
+    chunk_blocks = chunk_blocks_for(block_tables.shape[1], block_bytes)
     rows = chunk_blocks * block_size
     kernel = functools.partial(_kernel, block_size=block_size, chunk_blocks=chunk_blocks)
     return pl.pallas_call(
@@ -179,4 +216,154 @@ def paged_decode_attention(
         lengths.astype(jnp.int32),
         block_tables.astype(jnp.int32),
         q, pool_k, pool_v,
+    )
+
+
+# -- the latent cache (ops/latent_attention.py:mla) ----------------------------
+
+_LATENT_CHUNK_BYTES = 640 << 10  # of cache rows in one VMEM buffer (512 rows in bfloat16); two buffers
+
+
+def can_use_latent_kernel(s: int, r_kv: int, rows_pool) -> bool:
+    """Platform and static shape alone, as ``can_use_paged_kernel``: a TPU,
+    one query position, a latent and a stored row of whole lane tiles (the
+    kernel cuts the row between them), and blocks that fill whole sublane
+    tiles of the pool's type (a block is copied as it lies)."""
+    if jax.default_backend() != "tpu":
+        return False
+    _, _, block_size, stored = rows_pool.shape
+    sublanes = 32 // jnp.dtype(rows_pool.dtype).itemsize
+    return s == 1 and r_kv % 128 == 0 and stored % 128 == 0 and stored > r_kv and block_size % sublanes == 0
+
+
+def _latent_kernel(
+    ai_ref, len_ref, tbl_ref,  # scalar prefetch
+    ql_ref, qr_ref, pool_ref,  # (1, H, r_kv) and (1, H, stored - r_kv) in VMEM; the pool in HBM
+    o_ref,
+    buf, sem, ahead,  # (2, rows, stored); DMA semaphores (2,); SMEM (2,): see below
+    *, block_size, chunk_blocks, scale,
+):
+    """One sequence a grid step, as ``_kernel``, with two differences the
+    latent cache asks for. A row is key and value at once (scores over all of
+    it, the weighted sum over its first ``r_kv`` values) and all heads share
+    it: one buffer, no other head's columns. And a whole sequence is a chunk
+    or two, so the copies run ahead *across* sequences: before a sequence's
+    last chunk is computed, the next sequence's first is started into the
+    other buffer. ``ahead`` carries that from one grid step to the next: the
+    buffer this sequence's first chunk is in, and whether it is under way."""
+    b, last = pl.program_id(0), pl.num_programs(0) - 1
+    ai = ai_ref[0]
+    _, heads, r_kv = ql_ref.shape
+    _, rows, _ = buf.shape
+
+    def blocks_and_chunks(seq):
+        n_blocks = (len_ref[seq] + block_size - 1) // block_size
+        return n_blocks, (n_blocks + chunk_blocks - 1) // chunk_blocks
+
+    # dead rows of a chunk keep what the buffer held before, and a weight of
+    # exactly 0 times that must be 0: nothing but zeros and pool rows is ever
+    # in the buffer
+    @pl.when(b == 0)
+    def _():
+        buf[...] = jnp.zeros_like(buf)
+        ahead[0] = 0
+        ahead[1] = 0
+
+    def chunk_copies(seq, seq_blocks, chunk, slot, act):
+        def copies(block, j):
+            dst = pl.ds(j * block_size, block_size)
+            return (pltpu.make_async_copy(pool_ref.at[ai, block], buf.at[slot, dst], sem.at[slot]),)
+
+        for_live_blocks(tbl_ref, seq, seq_blocks, chunk, chunk_blocks, copies, act)
+
+    length = len_ref[b]
+    n_blocks, n_chunks = blocks_and_chunks(b)
+    first = ahead[0]
+
+    @pl.when((n_chunks > 0) & (ahead[1] == 0))
+    def _():
+        chunk_copies(b, n_blocks, 0, first, lambda d: d.start())
+
+    # an empty slot starts nothing for its neighbour, which then starts its own
+    nxt = jnp.minimum(b + 1, last)
+    nxt_blocks, nxt_chunks = blocks_and_chunks(nxt)
+    run_ahead = (b < last) & (n_chunks > 0) & (nxt_chunks > 0)
+
+    q = jnp.concatenate([ql_ref[0], qr_ref[0]], axis=-1)
+    col = jax.lax.broadcasted_iota(jnp.int32, (heads, rows), 1)
+
+    def chunk_step(c, carry):
+        m, l, acc = carry
+        slot = jax.lax.rem(first + c, 2)
+
+        @pl.when(c + 1 < n_chunks)
+        def _():
+            chunk_copies(b, n_blocks, c + 1, 1 - slot, lambda d: d.start())
+
+        @pl.when((c + 1 == n_chunks) & run_ahead)
+        def _():
+            chunk_copies(nxt, nxt_blocks, 0, 1 - slot, lambda d: d.start())
+
+        chunk_copies(b, n_blocks, c, slot, lambda d: d.wait())
+        kv = buf[slot]
+        s = jax.lax.dot_general(q, kv, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32) * scale
+        s = jnp.where(c * rows + col < length, s, _NEG_INF)
+        m, alpha, p, l = online_softmax_weights(m, l, s)
+        acc = alpha * acc + jnp.dot(p.astype(kv.dtype), kv[:, :r_kv], preferred_element_type=jnp.float32)
+        return m, l, acc
+
+    m, l, acc = jax.lax.fori_loop(0, n_chunks, chunk_step, softmax_start(heads, r_kv))
+    # an empty slot (length 0) read nothing: its output is 0, not 0/0
+    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+    ahead[0] = jax.lax.rem(first + n_chunks, 2)
+    ahead[1] = run_ahead.astype(jnp.int32)
+
+
+def paged_latent_attention(
+    q_l, q_r, rows_pool, att_index, block_tables, lengths, *, scale: float, interpret=False
+):
+    """The absorbed decode step over the pool where it lies. ``q_l`` (B, H,
+    r_kv) the query in the latent space, ``q_r`` (B, H, d_r) rotated, against
+    attention ``att_index`` (traced) of ``rows_pool`` (attentions, blocks,
+    block_size, stored): a row is ``[ckv | k_r | zeros]``. ``block_tables``
+    (B, MB) and ``lengths`` (B,) as ``paged_decode_attention`` takes them.
+    Returns (B, H, r_kv) in ``q_l``'s dtype, what
+    ``latent_attention.latent_decode_attention`` gives over the gathered
+    rows: softmax(([q_l | q_r] . row) x ``scale``) over positions [0, length)
+    in float32, the weights in the pool's dtype into the sum of the rows'
+    first ``r_kv`` values; 0 for a length of 0."""
+    b, heads, r_kv = q_l.shape
+    _, _, block_size, stored = rows_pool.shape
+    # the rotated part as wide as the rest of the stored row: the row's pad is zeros, so is the query's
+    q_r = jnp.pad(q_r, ((0, 0), (0, 0), (0, stored - r_kv - q_r.shape[-1])))
+    block_bytes = block_size * stored * jnp.dtype(rows_pool.dtype).itemsize
+    chunk_blocks = chunk_blocks_for(block_tables.shape[1], block_bytes, _LATENT_CHUNK_BYTES)
+    kernel = functools.partial(_latent_kernel, block_size=block_size, chunk_blocks=chunk_blocks, scale=scale)
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct(q_l.shape, q_l.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b,),
+            in_specs=[
+                pl.BlockSpec((1, heads, r_kv), lambda i, *_: (i, 0, 0)),
+                pl.BlockSpec((1, heads, stored - r_kv), lambda i, *_: (i, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((1, heads, r_kv), lambda i, *_: (i, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, chunk_blocks * block_size, stored), rows_pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((2,), jnp.int32),
+            ],
+        ),
+        # buffers, copies under way and ``ahead`` pass from one sequence to the next: in order, on one core
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        name="paged_latent_attention",
+        interpret=interpret,
+    )(
+        jnp.asarray(att_index, jnp.int32).reshape(1),
+        lengths.astype(jnp.int32),
+        block_tables.astype(jnp.int32),
+        q_l, q_r, rows_pool,
     )

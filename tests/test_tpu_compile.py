@@ -203,17 +203,31 @@ def test_paged_decode_and_prefill_at_published_widths(one_chip, monkeypatch, wid
         assert not _staged(text, plain)
 
 
-def test_latent_decode_and_prefill_at_longcat_widths(one_chip):
-    """serve.llm's programs for LongCat-Flash's language model at the published
-    widths, one chip's share of the experts (16 of 512), 2 layers. The decode
-    step holds the grouped matmul over the held experts, gathers a table's
-    blocks and never re-lays the pool out: its rows are stored 640 wide (576
-    values: the TPU gives such a pool another device layout than the one the
-    program computes in, and copies it whole, in and out, every step)."""
+def _latent_kernel_reads_the_pool_in_place(text, pool_dims, batch, per_seq, block):
+    """The decode step holds the latent kernel (``paged_latent_attention``, a
+    call a section's attention) and reads the pool nowhere else: no table's
+    blocks gathered or copied (``[batch, per_seq, block, 640]``), and the pool
+    itself scattered into in place, never copied or re-laid out."""
     import re
 
+    assert "tpu_custom_call" in text and "paged_latent_attention" in text
+    made = re.findall(r"= \w+(\[[\d,]*\])\S* (?:gather|dynamic-slice|copy|transpose)\(", text)
+    assert made and f"[{batch},{per_seq},{block},640]" not in made and f"[{batch},{per_seq * block},640]" not in made
+    assert pool_dims not in made
+
+
+def test_latent_decode_and_prefill_at_longcat_widths(one_chip, monkeypatch):
+    """serve.llm's programs for LongCat-Flash's language model at the published
+    widths, one chip's share of the experts (16 of 512), 2 layers. The decode
+    step holds the grouped matmul over the held experts and the latent kernel,
+    which reads a table's live blocks where they lie (no gathered copy), and
+    never re-lays the pool out: its rows are stored 640 wide (576
+    values: the TPU gives such a pool another device layout than the one the
+    program computes in, and copies it whole, in and out, every step). The
+    prefill attends to its own rows and holds no kernel."""
     from ray_tpu.models import longcat as M, paged
 
+    _steered_to_tpu(monkeypatch)
     cfg = M.LongcatConfig(vocab_size=16384, num_layers=2, experts_held=16)
     block, blocks, batch, per_seq = 16, 4097, 32, 128
     prefill, _, decode_greedy = paged.make_paged_fns(M.paged_layer, cfg, block_size=block)
@@ -229,22 +243,23 @@ def test_latent_decode_and_prefill_at_longcat_widths(one_chip):
         arg((batch,), jnp.bool_),
     ).compile().as_text()
     assert text.count("ragged-dot") >= 3  # gate, up and down of the held experts
-    made = re.findall(r"= \w+(\[[\d,]*\])\S* (?:gather|copy|transpose)\(", text)
-    assert f"[{batch},{per_seq},{block},640]" in made  # a table's blocks, gathered
-    assert f"[4,{blocks},{block},640]" not in made  # the pool itself: scattered into in place
-    compiled = prefill.lower(
-        params, arg((1, 1024), jnp.int32), arg((1, per_seq), jnp.int32), pool, arg((), jnp.int32)).compile()
-    assert "gather(" not in "".join(line for line in compiled.as_text().splitlines() if f",{block},640]" in line)
+    _latent_kernel_reads_the_pool_in_place(text, f"[4,{blocks},{block},640]", batch, per_seq, block)
+    text = prefill.lower(
+        params, arg((1, 1024), jnp.int32), arg((1, per_seq), jnp.int32), pool, arg((), jnp.int32)).compile().as_text()
+    assert "paged_latent_attention" not in text
+    assert "gather(" not in "".join(line for line in text.splitlines() if f",{block},640]" in line)
 
 
-def test_latent_decode_and_prefill_at_kimi_k2_widths(one_chip):
+def test_latent_decode_and_prefill_at_kimi_k2_widths(one_chip, monkeypatch):
     """serve.llm's programs for Kimi-K2's language model as the benchmark's
     configuration cuts it (``benchmarks/configs/kimi-k2-7l.json``): the
     published widths, the dense layer and six expert layers as two scans in one
     program, 12 of 384 experts, an eighth of the vocabulary, the engine's 48
     slots over 7,681 blocks. 9.70 GB of weights and a 1.10 GB pool are the
-    program's arguments. The decode step holds the grouped matmuls, gathers a
-    table's blocks and never copies the pool. **No weight is copied out of
+    program's arguments. The decode step holds the grouped matmuls and the
+    latent kernel (a table's live blocks read where they lie: no gathered
+    copy, and 5 MB of the program's own where the gathers had 40) and never
+    copies the pool. **No weight is copied out of
     its stack before its matmul but the two that longcat's programs copy
     too**, in the one ``mla`` both kinds run (PERF.md, section 7): ``wqb``'s
     layer (a ``constant_dynamic-slice_fusion`` of 37.7 MB a layer, 0.69 ms a
@@ -255,10 +270,9 @@ def test_latent_decode_and_prefill_at_kimi_k2_widths(one_chip):
     in ``S(1)``). The dense layer's 18432-wide tensors, the shared expert's,
     ``wqa``, ``wkva``, ``wo``, the router and the experts are read where they
     lie."""
-    import re
-
     from ray_tpu.models import kimi as M, paged
 
+    _steered_to_tpu(monkeypatch)
     cfg = M.KimiConfig(vocab_size=20480, num_hidden_layers=7, experts_held=12)
     assert (cfg.first_k_dense_replace, cfg.n_expert_layers) == (1, 6) and not hasattr(M, "paged_layouts")
     block, blocks, batch, per_seq = 16, 7681, 48, 96
@@ -287,14 +301,13 @@ def test_latent_decode_and_prefill_at_kimi_k2_widths(one_chip):
     ).compile()
     text = compiled.as_text()
     assert text.count("ragged-dot") >= 3  # gate, up and down of the held experts
-    made = re.findall(r"= \w+(\[[\d,]*\])\S* (?:gather|copy|transpose)\(", text)
-    assert f"[{batch},{per_seq},{block},640]" in made  # a table's blocks, gathered
-    assert f"[7,{blocks},{block},640]" not in made  # the pool itself: scattered into in place
+    _latent_kernel_reads_the_pool_in_place(text, f"[7,{blocks},{block},640]", batch, per_seq, block)
     assert staged(text) == {"wqb", "wkvb"}
-    assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9  # beside 10.8 GB of arguments
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.02e9  # beside 10.8 GB of arguments
     compiled = prefill.lower(
         params, arg((1, 512), jnp.int32), arg((1, per_seq), jnp.int32), pool, arg((), jnp.int32)).compile()
     text = compiled.as_text()
+    assert "paged_latent_attention" not in text
     assert "gather(" not in "".join(line for line in text.splitlines() if f",{block},640]" in line)
     assert staged(text) <= {"wqb", "wkvb"}
 
