@@ -387,6 +387,13 @@ def _get_kv_gauges() -> Dict[str, object]:
                 "deployment (blocks x bytes-per-block, both k and v)",
                 tag_keys=("deployment",),
             )
+            _kv_gauges["state_rows_used"] = Gauge(
+                "ray_tpu_kv_state_rows_used",
+                "state rows held by live sequences per LLM deployment: a model "
+                "kind whose layers keep a recurrent state has one a decode slot "
+                "(0 for every other kind)",
+                tag_keys=("deployment",),
+            )
     return _kv_gauges
 
 
@@ -408,5 +415,6 @@ def record_kv_occupancy(stats: Dict[str, object]) -> None:
         if bpb:
             # pool bytes include the reserved null block
             gauges["bytes_total"].set(float((total + 1) * bpb), tags=tags)
+        gauges["state_rows_used"].set(float(stats.get("state_rows_used", 0)), tags=tags)
     except Exception:
         pass

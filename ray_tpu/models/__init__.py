@@ -35,6 +35,7 @@ PAGED_KINDS = {
     None: ("ray_tpu.models.generation", "TransformerConfig"),
     "longcat": ("ray_tpu.models.longcat", "LongcatConfig"),
     "kimi_k2": ("ray_tpu.models.kimi", "KimiConfig"),
+    "olmo_hybrid": ("ray_tpu.models.olmo_hybrid", "OlmoHybridConfig"),
 }
 
 

@@ -113,7 +113,7 @@ def paged_layouts(cfg: TransformerConfig) -> Dict[str, Tuple[int, ...]]:
     return {name: (0, 2, 1, 3) for name in ("wq", "wk", "wv")}
 
 
-def _attend_pool(q, k, v, pool, *, li, step: paged.Step, rows):
+def attend_pool(q, k, v, pool, *, li, step: paged.Step, rows):
     """The paged pool's ``attend``: k and v scattered to the step's slots of
     layer ``li``, then attention by one of two paths, chosen from platform and
     static shape alone (``can_use_paged_kernel``; a chosen kernel that fails
@@ -149,7 +149,7 @@ def paged_layer(cfg: TransformerConfig, params, step: paged.Step):
 
     def layer(x, pool, li):
         weights = {k: jax.lax.dynamic_index_in_dim(v, li, keepdims=False) for k, v in stacked.items()}
-        attend = functools.partial(_attend_pool, li=li, step=step, rows=rows)
+        attend = functools.partial(attend_pool, li=li, step=step, rows=rows)
         return _block(cfg, x, weights, cos, sin, step.positions, attend, pool)
 
     return layer

@@ -14,7 +14,8 @@ four things, and may give a fifth:
 
     paged_layer(cfg, params, step) -> layer(x (B, S, D), pool, li) -> (x, pool)
         or, for a model of unlike layers, its sections in order:
-        [(layer, how many layers it covers), ...]
+        [(layer, how many layers it covers), ...]; a section whose body is
+        several layers a call: (layer, how many it covers, how many a call)
     init_paged_pool(cfg, num_blocks, block_size) -> pool
     paged_block_bytes(cfg, block_size) -> bytes one block holds over all layers
     init_params(key, cfg) -> params with ``embed``, ``final_norm``, ``unembed``
@@ -32,7 +33,17 @@ pool)`` carried from each into the next and the layer index running on
 its own first layer). How the sections divide the layers is the config's
 (``first_k_dense_replace``); the sum must be ``cfg.n_layers``. A kind that
 hands back one function is one section of ``cfg.n_layers``: the program it
-always traced. ``paged_layer`` is called
+always traced. **A model whose layers alternate in a period** (Olmo-Hybrid:
+three linear-attention layers and one of full attention, eight times) hands
+back one section whose body is a whole period, ``(layer, n_layers, layers a
+call)``: one scan over the periods, ``li`` each period's first layer; sixteen
+sections of a layer each would be sixteen scans. **A kind that keeps a state a
+sequence** beside its rows a position (``paged_state_bytes(cfg)``: the
+recurrent layers' state) finds each sequence's state row in the block table's
+last column: the programs made with ``state_rows=True`` cut it off the table
+and hand it to the kind as ``Step.state_rows`` (row 0 is the null row, as
+block 0 is the null block: an inactive slot's all-zero table names both).
+``paged_layer`` is called
 once a program, outside the scan over layers, and what it computes there is
 computed once a call: XLA does not lift it out of the loop by itself (a rotary
 table built inside the layer was rebuilt 28 times a step: PERF.md section 6, PR
@@ -57,7 +68,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -82,6 +93,7 @@ class Step(NamedTuple):
     write_slots: jax.Array  # (B * S,): each token's slot; a masked row's lies in the null block
     live: jax.Array  # (B * S,) bool: the rows that are tokens
     lengths: jax.Array  # (B,): a decode step's sequences count positions [0, position]; an inactive slot none
+    state_rows: Optional[jax.Array] = None  # (B,): each sequence's state row, for a kind that keeps one; 0 the null row
 
 
 @contextlib.contextmanager
@@ -148,41 +160,52 @@ def head(cfg, params, x, last=None):
         return jnp.einsum("bsd,dv->bsv", x, unembed).astype(jnp.float32)
 
 
-def forward_paged(paged_layer, cfg, params, tokens, positions, write_mask, block_tables, pool, block_size: int, last=None):
+def forward_paged(paged_layer, cfg, params, tokens, positions, write_mask, block_tables, pool, block_size: int, last=None,
+                  state_rows: bool = False):
     """``tokens`` (B, S) at per-sequence absolute ``positions`` (B, S) through
     ``cfg.n_layers`` calls of a kind's layer (or of its sections' layers, a
     scan a section), each writing its cache rows into the
     pool and attending over the sequences' blocks. ``write_mask`` (B, S)
-    diverts padded rows and inactive slots to the null block. Returns
-    (``head``'s logits, pool)."""
+    diverts padded rows and inactive slots to the null block. With
+    ``state_rows`` the tables' last column is each sequence's state row
+    (``Step.state_rows``), not a block. Returns (``head``'s logits, pool)."""
     b, s = tokens.shape
+    rows = None
+    if state_rows:
+        block_tables, rows = block_tables[:, :-1], block_tables[:, -1]
     pidx = jnp.clip(positions // block_size, 0, block_tables.shape[1] - 1)
     slot = jnp.take_along_axis(block_tables, pidx, axis=1) * block_size + positions % block_size
     null_slot = jnp.arange(b * s, dtype=slot.dtype) % block_size
     live = write_mask.reshape(-1)
     sections = paged_layer(cfg, params, Step(
         positions, block_tables, block_size, jnp.where(live, slot.reshape(-1), null_slot), live,
-        jnp.where(write_mask[:, 0], positions[:, 0] + 1, 0)))
+        jnp.where(write_mask[:, 0], positions[:, 0] + 1, 0), rows))
     if callable(sections):
         sections = [(sections, cfg.n_layers)]
-    if sum(n for _, n in sections) != cfg.n_layers:
-        raise ValueError(f"the sections cover {[n for _, n in sections]} layers of {cfg.n_layers}")
+    sections = [(*section, 1)[:3] for section in sections]  # (layer, layers covered, layers a call)
+    if sum(n for _, n, _ in sections) != cfg.n_layers or any(n % each for _, n, each in sections):
+        raise ValueError(f"the sections cover {[n for _, n, _ in sections]} layers of {cfg.n_layers}, "
+                         f"{[each for _, _, each in sections]} a call")
 
     # The pool rides in the scan CARRY, not in per-layer outputs: stacked scan
     # outputs allocate a fresh slab and copy every layer's rows through it,
     # which defeats buffer donation and turns each decode step into an
     # O(pool-size) memcpy. Carry-threaded updates alias in place.
     carry, first = (params["embed"][tokens], pool), 0
-    for layer, n in sections:
-        carry, _ = jax.lax.scan(lambda c, li, layer=layer: (layer(*c, li), None), carry, jnp.arange(first, first + n))
+    for layer, n, each in sections:
+        # each call's first layer (a layer a call: the arange every kind has always traced)
+        firsts = jnp.arange(first, first + n) if each == 1 else jnp.arange(first, first + n, each)
+        carry, _ = jax.lax.scan(lambda c, li, layer=layer: (layer(*c, li), None), carry, firsts)
         first += n
     x, pool = carry
     return head(cfg, params, x, last), pool
 
 
-def make_paged_fns(paged_layer, cfg, *, block_size: int):
+def make_paged_fns(paged_layer, cfg, *, block_size: int, state_rows: bool = False):
     """(prefill, decode_step, decode_step_greedy) over a kind's ``paged_layer``,
     jitted with the pool donated (in place on the device between steps).
+    ``state_rows``: the kind keeps a state a sequence, and MB counts the
+    tables' last column, which names each sequence's state row.
 
     prefill(params, tokens (1,S), block_table (1,MB), pool, length ())
         -> (logits at position length-1 (1,V), pool)
@@ -201,12 +224,12 @@ def make_paged_fns(paged_layer, cfg, *, block_size: int):
     def prefill(params, tokens, block_table, pool, length):
         positions = jnp.broadcast_to(jnp.arange(tokens.shape[1])[None, :], tokens.shape)
         logits, pool = forward_paged(paged_layer, cfg, params, tokens, positions, positions < length, block_table, pool,
-                                     block_size, last=length - 1)
+                                     block_size, last=length - 1, state_rows=state_rows)
         return logits[:, 0, :], pool
 
     def step(params, tokens, positions, block_tables, pool, active):
         logits, pool = forward_paged(paged_layer, cfg, params, tokens[:, None], positions[:, None], active[:, None],
-                                     block_tables, pool, block_size)
+                                     block_tables, pool, block_size, state_rows=state_rows)
         return logits[:, 0, :], pool
 
     @functools.partial(jax.jit, donate_argnums=(4,))

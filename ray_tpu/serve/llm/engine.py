@@ -338,8 +338,14 @@ class InferenceEngine:
         # the pool is the model's to shape: its module makes it, says what a
         # block of it holds, and gives the layer the three programs run over it
         model = paged_model(model_cfg)
+        # a kind whose layers keep a state a sequence (a recurrent layer's) says
+        # what a row of it holds; it gets a row a decode slot, handed out with
+        # the blocks and found by the programs in the table's last column
+        self._state_bytes = int(model.paged_state_bytes(model_cfg)) if hasattr(model, "paged_state_bytes") else 0
+        state_rows = ecfg.max_batch if self._state_bytes else 0
+        pool_rows = state_rows + 1 if state_rows else 0  # with the null row, as the pool holds them
         self._prefill, self._decode, self._decode_greedy = paged.make_paged_fns(
-            model.paged_layer, model_cfg, block_size=ecfg.block_size
+            model.paged_layer, model_cfg, block_size=ecfg.block_size, state_rows=bool(state_rows)
         )
         # the weights as the kind wants them to lie on the device, before the
         # pool exists: ``params`` is the engine's from here (placed originals
@@ -348,7 +354,9 @@ class InferenceEngine:
         self.params, self.placed = paged.place_params(model, model_cfg, params)
         if self.placed:
             logger.info("%s: placed %s on the device in %.2f s", deployment, self.placed, time.perf_counter() - t0)
-        self._pool = model.init_paged_pool(model_cfg, ecfg.num_blocks, ecfg.block_size)
+        self._pool = model.init_paged_pool(
+            model_cfg, ecfg.num_blocks, ecfg.block_size, **({"state_rows": pool_rows} if pool_rows else {})
+        )
         if any(x.committed for x in jax.tree.leaves(self.params)):
             # one committed argument (a placed or a sharded weight) commits a
             # program's results, the pool among them: it starts as it will
@@ -356,7 +364,7 @@ class InferenceEngine:
             # next meets the pool, inside some request's time to first token
             self._pool = jax.tree.map(lambda x: jax.device_put(x, x.sharding), self._pool)
         self._device = next(iter(jax.tree.leaves(self._pool)[0].devices()))
-        self._alloc = BlockAllocator(ecfg.num_blocks, ecfg.block_size)
+        self._alloc = BlockAllocator(ecfg.num_blocks, ecfg.block_size, state_rows=state_rows)
         self._slots: List[Optional[_Running]] = [None] * ecfg.max_batch
         self._waiting: "list[tuple[_Request, TokenStream]]" = []
         self._streams: Dict[int, TokenStream] = {}
@@ -366,9 +374,13 @@ class InferenceEngine:
         self._stop = False
         self._thread: Optional[threading.Thread] = None
         self.max_context = min(
-            ecfg.max_blocks_per_seq * ecfg.block_size, model_cfg.max_seq_len
+            (ecfg.max_blocks_per_seq - bool(state_rows)) * ecfg.block_size, model_cfg.max_seq_len
         )
         self._bytes_per_block = int(model.paged_block_bytes(model_cfg, ecfg.block_size))
+        blocks, rows = ecfg.num_blocks * self._bytes_per_block, pool_rows * self._state_bytes
+        logger.info("%s: the pool holds %.3f GB of blocks (%d x %d B), %.3f GB of state rows (%d x %d B), %.3f GB in all",
+                    deployment, blocks / 1e9, ecfg.num_blocks, self._bytes_per_block, rows / 1e9,
+                    pool_rows, self._state_bytes, (blocks + rows) / 1e9)
         self.decode_steps = 0  # dispatched so far: a step's number
         # what the loop has enqueued on the device and not yet read, in device
         # order: decode steps and newcomers' first tokens (the loop's alone)
@@ -564,7 +576,15 @@ class InferenceEngine:
             "running": running,
             "waiting": waiting,
             "bytes_per_block": self._bytes_per_block,
+            **self._state_rows(),
         }
+
+    def _state_rows(self) -> Dict[str, int]:
+        """The state rows of a kind that keeps a state a sequence (0 of 0 for
+        every other kind), and what one row holds over all layers."""
+        rows = self._alloc.state_rows
+        return {"state_rows_total": rows, "state_rows_used": rows - self._alloc.state_rows_free,
+                "state_bytes": self._state_bytes}
 
     def _refresh_kv_gauges(self) -> None:
         """The ``ray_tpu_kv_*`` gauges from this engine's occupancy: the
@@ -595,7 +615,9 @@ class InferenceEngine:
         the host's time to enqueue the iteration's prefills.
         Empty with ``telemetry_enabled`` off, but for ``placed``: the stacked
         tensors the engine re-laid on the device at start, name ->
-        ``major_to_minor`` (``paged.place_params``; empty where it placed none)."""
+        ``major_to_minor`` (``paged.place_params``; empty where it placed none),
+        and ``state_rows_total|used`` and ``state_bytes`` (a kind that keeps a
+        state a sequence; zeros for every other)."""
         ring = self._ring.copy()  # atomic against the loop's appends
         steps = [r[1:] for r in ring if r[0] == "s"]
         reqs = [r[1:] for r in ring if r[0] == "r"]
@@ -641,6 +663,7 @@ class InferenceEngine:
             "moe": dict(zip(LLM_MOE_FIELDS, routed[-1])) if routed else None,
             # the stacked tensors re-laid on the device at start, name -> major_to_minor
             "placed": dict(self.placed),
+            **self._state_rows(),  # as ``kv_stats()`` has them now
         }
 
     def _record(self, rec: tuple) -> None:

@@ -262,8 +262,113 @@ def test_a_kind_of_two_sections_is_served_with_the_pool_carried_through_both():
                 jnp.zeros((1, MAX_BLOCKS), jnp.int32), init_paged_pool(cfg, BLOCKS, BLOCK), jnp.int32(3))
 
 
+# -- (b'') a section whose body is several layers a call, and a state row a sequence ------
+#
+# The made-up kind with four layers in a period of two: an even layer is the toy's, an odd one
+# has no MLP and adds, beside its mix, the running mean of its inputs over the sequence so far:
+# a state that is not rows a position (one row a sequence and an odd layer, a count with it),
+# found through the table's last column.
+
+
+@dataclasses.dataclass(frozen=True)
+class PairToyConfig(ToyConfig):
+    n_layers: int = 4
+
+
+def pair_init_pool(cfg, num_blocks, block_size, state_rows):
+    return {**init_paged_pool(cfg, num_blocks, block_size),
+            "sums": jnp.zeros((cfg.n_layers // 2, state_rows, cfg.width), cfg.dtype),
+            "seen": jnp.zeros((cfg.n_layers // 2, state_rows), jnp.int32)}
+
+
+def pair_state_bytes(cfg):
+    return (cfg.n_layers // 2) * (cfg.width * jnp.dtype(cfg.dtype).itemsize + 4)
+
+
+def pair_paged_layer(cfg, params, step):
+    b, s = step.positions.shape
+    bs = step.block_size
+    upto = (jnp.arange(step.block_tables.shape[1] * bs) <= step.positions[..., None]).astype(cfg.dtype)
+    live = step.live.reshape(b, s)
+
+    def mix(x, pool, li):
+        rows = pool["rows"].at[li, step.write_slots // bs, step.write_slots % bs].set((x @ params["w_mix"][li]).reshape(b * s, -1))
+        seen = rows[li, step.block_tables].reshape(b, -1, cfg.width)
+        mixed = jnp.einsum("bsm,bmd->bsd", upto, seen) / (step.positions[..., None] + 1)
+        return mixed, {**pool, "rows": rows, "rows_written": pool["rows_written"] + jnp.sum(step.live)}
+
+    def pair(x, pool, li):  # layers li and li + 1; li the pair's first
+        mixed, pool = mix(x, pool, li)
+        x = x + mixed
+        x = x + gelu(x @ params["w_up"][li]) @ params["w_down"][li]
+        mixed, pool = mix(x, pool, li + 1)
+        own, row = li // 2, step.state_rows
+        # a prefill starts the row's sum over; a decode step adds to what the row holds
+        before = jnp.where(s > 1, 0.0, pool["sums"][own, row])
+        count = jnp.where(s > 1, 0, pool["seen"][own, row])
+        running = before[:, None] + jnp.cumsum(jnp.where(live[..., None], x, 0.0), axis=1)
+        mean = running / (count[:, None, None] + jnp.cumsum(live, axis=1)[..., None]).clip(1)
+        keep = live.any(axis=1)  # an inactive slot names the null row: it stays zeros
+        pool = {**pool,
+                "sums": pool["sums"].at[own, row].set(jnp.where(keep[:, None], running[:, -1], pool["sums"][own, row])),
+                "seen": pool["seen"].at[own, row].set(jnp.where(keep, count + jnp.sum(live, axis=1), pool["seen"][own, row]))}
+        return x + mixed + mean, pool
+
+    return [(pair, cfg.n_layers, 2)]
+
+
+def pair_next_token(cfg, params, tokens):
+    x = params["embed"][jnp.asarray(tokens)]
+    count = jnp.arange(1, len(tokens) + 1)[:, None]
+    for li in range(0, cfg.n_layers, 2):
+        x = x + jnp.cumsum(x @ params["w_mix"][li], axis=0) / count
+        x = x + gelu(x @ params["w_up"][li]) @ params["w_down"][li]
+        x = x + jnp.cumsum(x @ params["w_mix"][li + 1], axis=0) / count + jnp.cumsum(x, axis=0) / count
+    return int(jnp.argmax(rms_norm(x[-1], params["final_norm"]) @ params["unembed"]))
+
+
+def test_a_kind_whose_section_covers_two_layers_a_call_is_served_with_a_state_row_a_sequence():
+    pair = type(sys)("pair_toy")
+    pair.__dict__.update(PairToyConfig=PairToyConfig, init_params=init_params, paged_layer=pair_paged_layer,
+                         init_paged_pool=pair_init_pool, paged_block_bytes=paged_block_bytes,
+                         paged_state_bytes=pair_state_bytes)
+    sys.modules["pair_toy"] = pair
+    models.PAGED_KINDS["pair_toy"] = ("pair_toy", "PairToyConfig")
+    try:
+        server = LLMServer({"kind": "pair_toy", "vocab_size": 48},
+                           dict(block_size=BLOCK, num_blocks=BLOCKS, max_batch=2, max_blocks_per_seq=MAX_BLOCKS + 1),
+                           weight_seed=6)
+        try:
+            eng = server._engine
+            cfg = eng.model_cfg
+            # a row a slot and the null row; the table's last column is the row's, so a context of MAX_BLOCKS blocks
+            assert eng._pool["sums"].shape == (2, 3, cfg.width) and eng.max_context == MAX_BLOCKS * BLOCK
+            assert server.kv_stats()["state_rows_total"] == 2 and server.kv_stats()["state_bytes"] == 2 * (cfg.width * 4 + 4)
+            prompts = [[5, 9, 2], [7] * 9, [1, 2, 3, 4, 5, 6], [3, 1]]  # four requests on two slots: rows change hands
+            streams = [server.generate(p, max_new_tokens=7) for p in prompts]
+            for prompt, got in zip(prompts, [list(s) for s in streams]):
+                seq = list(prompt)
+                for token in got:
+                    assert token == pair_next_token(cfg, eng.params, seq)
+                    seq.append(token)
+            assert int(eng._pool["rows_written"]) == cfg.n_layers * sum(len(p) + 6 for p in prompts)
+            assert not np.asarray(eng._pool["sums"][:, 0]).any() and not np.asarray(eng._pool["seen"][:, 0]).any()
+            assert server.kv_stats()["state_rows_used"] == 0
+        finally:
+            server._engine.shutdown()
+    finally:
+        del models.PAGED_KINDS["pair_toy"], sys.modules["pair_toy"]
+    # a section whose layers are not whole calls is refused when the program is traced
+    cfg = PairToyConfig()
+    odd = lambda cfg, params, step: [(pair_paged_layer(cfg, params, step)[0][0], 4, 3)]  # noqa: E731
+    prefill, _, _ = paged.make_paged_fns(odd, cfg, block_size=BLOCK, state_rows=True)
+    with pytest.raises(ValueError, match=r"cover \[4\] layers of 4, \[3\] a call"):
+        prefill(init_params(jax.random.PRNGKey(0), cfg), jnp.zeros((1, BUCKET), jnp.int32),
+                jnp.zeros((1, MAX_BLOCKS + 1), jnp.int32), pair_init_pool(cfg, BLOCKS, BLOCK, 3), jnp.int32(3))
+
+
 def _forward_paged_before_sections(paged_layer, cfg, params, tokens, positions, write_mask, block_tables, pool,
-                                   block_size, last=None):
+                                   block_size, last=None, state_rows=False):
     """``forward_paged`` as it stood before a kind could hand back sections (PR 32), kept here as the
     witness: one layer function, one scan over ``cfg.n_layers``."""
     b, s = tokens.shape
@@ -411,7 +516,7 @@ def test_the_engine_places_once_and_says_what_it_placed(monkeypatch, caplog):
     try:
         assert eng.placed == HEADS_MAJOR and eng.loop_stats()["placed"] == HEADS_MAJOR
         assert eng.params["wq"].format.layout.major_to_minor == (0, 2, 1, 3) and eng.params["wo"] is given["wo"]
-        said = [r.getMessage() for r in caplog.records if "placed" in r.getMessage()]
+        said = [r.getMessage() for r in caplog.records if ": placed {" in r.getMessage()]  # the deployment's name is "placed" too
         assert len(said) == 1 and "'wq': (0, 2, 1, 3)" in said[0]
         prompt = [int(t) for t in TOKENS[:6]]
         want = np.asarray(G.generate(T.init_params(jax.random.PRNGKey(7), cfg), prompt, cfg, max_new_tokens=12))[0]

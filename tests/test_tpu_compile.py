@@ -312,6 +312,76 @@ def test_latent_decode_and_prefill_at_kimi_k2_widths(one_chip, monkeypatch):
     assert staged(text) <= {"wqb", "wkvb"}
 
 
+def test_hybrid_decode_and_prefill_at_olmo_hybrid_widths(one_chip, monkeypatch):
+    """serve.llm's programs for Olmo-Hybrid-7B as the benchmark's configuration
+    cuts it (``benchmarks/configs/olmo-hybrid-7b-16l.json``): the published
+    widths, 16 of 32 layers as four periods in one scan, the whole vocabulary,
+    the engine's 48 slots over 4,609 blocks and 49 state rows. The file's
+    arithmetic against the compiler: 8.20 GB of weights, a 4.83 GB K/V pool of
+    32 stored heads and 1.35 GB of state rows are the programs' arguments, and
+    each program's own memory is megabytes (the decode step 34 MB, the prefill
+    of 512 44 MB: with a layer of the K/V pool gathered for the prefill's
+    attention it was 1.2 GB, and with the output gate cut into heads of 192
+    lanes the whole ``gdn_gate`` stack was re-laid, 0.5 GB). The decode step
+    holds both kernels, ``gated_delta_update`` a linear layer and
+    ``paged_decode_attention`` a full one, and makes no copy of the state
+    pool, of the window pool or of a K/V layer. The prefill holds neither
+    kernel and gathers nothing out of the K/V pool."""
+    import json
+    import re
+
+    from benchmarks.families import olmo_hybrid as family
+    from ray_tpu.models import olmo_hybrid as M, paged
+    from ray_tpu.serve.llm.deployment import _resolve_model_cfg
+
+    _steered_to_tpu(monkeypatch)
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks", "configs", "olmo-hybrid-7b-16l.json")) as f:
+        config = json.load(f)
+    cfg = _resolve_model_cfg(family.model_kwargs(config))
+    e = config["engine"]
+    block, blocks, batch, per_seq = e["block_size"], e["num_blocks"], e["max_batch"], e["max_blocks_per_seq"]
+    assert (cfg.n_linear, cfg.n_full, cfg.period, cfg.kv_heads_stored) == (12, 4, M.PERIOD, 32)
+    prefill, _, decode_greedy = paged.make_paged_fns(M.paged_layer, cfg, block_size=block, state_rows=True)
+    params = _on(one_chip, jax.eval_shape(lambda: M.init_params(jax.random.PRNGKey(0), cfg)))
+    pool = _on(one_chip, jax.eval_shape(lambda: M.init_paged_pool(cfg, blocks, block, batch + 1)))
+
+    def nbytes(tree):
+        return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
+
+    assert 8.19e9 < nbytes(params) < 8.21e9
+    assert 4.83e9 < nbytes(pool["k"]) + nbytes(pool["v"]) == blocks * M.paged_block_bytes(cfg, block) < 4.84e9
+    rows = {name: nbytes(pool[name]) for name in ("state", "conv", "state_pos")}
+    assert 1.35e9 < sum(rows.values()) == (batch + 1) * M.paged_state_bytes(cfg) < 1.36e9
+    assert pool["state"].shape == (12, 49, 96, 5760) and pool["conv"].shape == (12, 49, 4 * 11520)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def pools_copied(text):
+        """Instructions of their own that make a pool, or a layer of one, anew."""
+        pools = {f"{lead}{dims}" for dims in ("49,96,5760", "49,46080", f"{blocks * block},32,128")
+                 for lead in ("", "1,", "12,", "4,")}
+        return [(dims, op) for dims, _, op in _alone(text)
+                if dims in pools and op in ("copy", "transpose", "gather", "dynamic-slice")]
+
+    compiled = decode_greedy.lower(
+        params, arg((batch,), jnp.int32), arg((batch,), jnp.int32), arg((batch, per_seq), jnp.int32), pool,
+        arg((batch,), jnp.bool_),
+    ).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert "gated_delta_update" in text and "paged_decode_attention" in text
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"", text)) == 4  # three linear layers and a full one, in the scan's body
+    assert not pools_copied(text)
+    assert 14.39e9 < mem.argument_size_in_bytes < 14.41e9 and mem.temp_size_in_bytes < 0.05e9
+    assert mem.alias_size_in_bytes > 0.999 * nbytes(pool)  # the pool comes back in place
+    compiled = prefill.lower(
+        params, arg((1, 512), jnp.int32), arg((1, per_seq), jnp.int32), pool, arg((), jnp.int32)).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert "gated_delta_update" not in text and "paged_decode_attention" not in text
+    assert not pools_copied(text)
+    assert mem.temp_size_in_bytes < 0.08e9  # beside 14.4 GB of arguments in a chip of 15.75 usable
+
+
 def _steered_to_tpu(monkeypatch):
     """``attention`` asks ``jax.default_backend()``, which is the CPU here:
     the test steers it to the branch it takes on the chip."""
