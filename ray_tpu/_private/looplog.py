@@ -68,6 +68,7 @@ LLM_MOE_FIELDS = (
     "absent",  # ... to experts of another chip's share
     "touched",  # held experts with at least one row, a layer a step
     "peak",  # rows of the held expert that got the most, a layer a step
+    "windows",  # windows of held rows the grouped matmuls walked, a layer a step (1 a layer-step: none spilled)
     "layers",  # expert layers a decode step runs
 )
 _KINDS = {"s": ("llm_step", LLM_STEP_FIELDS), "r": ("llm_request", LLM_REQUEST_FIELDS),

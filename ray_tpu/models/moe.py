@@ -17,9 +17,17 @@ expert's part (those have no weights, so each chip adds them for its own
 tokens), and adds nothing for a choice of an expert that lives elsewhere. On
 one chip the layer runs without its exchange.
 
-No capacity and no ``(tokens, experts, capacity)`` tensor: the chosen rows are
-sorted by expert and go through a grouped matmul over the experts held
-(``jax.lax.ragged_dot``), so no token is ever dropped, whatever the routing.
+No capacity and no ``(tokens, experts, capacity)`` tensor: the (token, choice)
+rows a held expert owns are sorted by expert and go through a grouped matmul
+over the experts held (``grouped_matmul``: ``jax.lax.ragged_dot``, on a TPU
+JAX's Pallas ``gmm`` at a stated tiling) a window at a time, so no token is
+ever dropped, whatever the routing. A window is ``window_rows`` compacted
+rows, a size that follows the rows the held experts expect and not the batch
+(a chip that holds 12 of 384 experts owns a thirty-second of a step's rows,
+and the row tile an expert's weights are streamed under is the rows handed in);
+windows are walked on the device until the held rows run out: one for an even
+router, none where no held expert was chosen, and a layer that holds every
+expert has one window of every row.
 """
 
 from __future__ import annotations
@@ -28,14 +36,23 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm as _megablox_gmm
 
 from ray_tpu.ops.layers import swiglu
 
 # what ``expert_layer`` counts of its routing, in this order: (token, choice)
 # rows sent to held, identity and absent experts; held experts with at least one
 # row; the rows of the held expert that got the most (over ``held / touched``
-# it says how uneven the choice leaves the load: 1 = even)
-COUNTS = ("held", "zero", "absent", "touched", "peak")
+# it says how uneven the choice leaves the load: 1 = even); windows of held
+# rows walked (over the calls: 1 = no call spilled past its first window)
+COUNTS = ("held", "zero", "absent", "touched", "peak", "windows")
+
+# A window holds this many times the (token, choice) rows a call's held experts
+# expect of an even router, rounded up to a power of two, and no fewer than
+# ``WINDOW_MIN`` (a row tile the grouped matmul streams an expert's weights
+# under; PERF.md section 6, PR 38)
+WINDOW_MULTIPLE = 2
+WINDOW_MIN = 32
 
 
 # The seeded router: logits of deviation ``ROUTER_SCALE``, so that a token's
@@ -108,6 +125,62 @@ def route_sigmoid(u: jax.Array, router: jax.Array, bias: jax.Array, *, top_k: in
     return scale * chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20), experts
 
 
+def window_rows(n_rows: int, held: int, n_outputs: int) -> int:
+    """Rows of one window of the grouped matmuls, from static shapes alone:
+    the smallest power of two at or above ``WINDOW_MULTIPLE`` times the held
+    rows an even router sends (``n_rows x held / n_outputs``), at least
+    ``WINDOW_MIN`` and at most every row."""
+    window = WINDOW_MIN
+    while window * n_outputs < WINDOW_MULTIPLE * n_rows * held:
+        window *= 2
+    return min(window, n_rows)
+
+
+# The grouped kernel's tiles: a window is one row tile, whatever its rows (so
+# every touched expert's weights are streamed once), and an expert's matrix
+# goes by in tiles of ``_WEIGHT_TILE`` elements (2 MB of bfloat16), the
+# contraction whole where it is no longer than ``_WHOLE_K``.
+_WEIGHT_TILE = 1 << 20
+_WHOLE_K = 2048
+_KERNEL_ROWS = 512  # the largest window the kernel takes as one row tile
+
+
+def _weight_tile(k: int, n: int) -> Tuple[int, int]:
+    tk = k if k <= _WHOLE_K else 512
+    return tk, min(n, _WEIGHT_TILE // tk)
+
+
+def can_use_grouped_kernel(rows, experts) -> bool:
+    """Platform and static shape alone, as ``ops.paged_attention``'s kernels
+    are chosen: a TPU, rows and experts of one 16-bit type, a window of whole
+    sublane tiles that fits one row tile, and matrices of whole weight tiles
+    of whole lanes."""
+    if jax.default_backend() != "tpu":
+        return False
+    (r, k), n = rows.shape, experts.shape[-1]
+    tk, tn = _weight_tile(k, n)
+    return (
+        rows.dtype == experts.dtype and jnp.dtype(rows.dtype).itemsize == 2
+        and r % 16 == 0 and r <= _KERNEL_ROWS
+        and k % tk == 0 and n % tn == 0 and tk % 128 == 0 and tn % 128 == 0
+    )
+
+
+def grouped_matmul(rows, experts, groups, out_type=None):
+    """``rows`` (R, K), sorted by group, times ``experts`` (G, K, N): the first
+    ``groups[0]`` rows by expert 0 and so on; rows past the last group come
+    out as whatever was there. On a TPU JAX's own Pallas grouped matmul at a
+    stated tiling (``jax.lax.ragged_dot``'s lowering streams an expert under
+    512 x 512 tiles, a quarter of the size, and took 126% of the weights'
+    time where this takes 111%: PERF.md section 6, PR 38); elsewhere, and for
+    shapes the kernel does not take, ``jax.lax.ragged_dot``."""
+    out_type = rows.dtype if out_type is None else out_type
+    if can_use_grouped_kernel(rows, experts):
+        tiling = (rows.shape[0], *_weight_tile(rows.shape[1], experts.shape[-1]))
+        return _megablox_gmm(rows, experts, groups, preferred_element_type=out_type, tiling=tiling)
+    return jax.lax.ragged_dot(rows, experts, groups, preferred_element_type=out_type)
+
+
 def expert_layer(params: Dict[str, jax.Array], u: jax.Array, *, n_routed: int, top_k: int, scale: float,
                  expert_offset: int = 0, live: Optional[jax.Array] = None, layer=None, rule=route):
     """``u`` (T, D) -> (y (T, D), counts uint32 in the order ``COUNTS``).
@@ -117,8 +190,14 @@ def expert_layer(params: Dict[str, jax.Array], u: jax.Array, *, n_routed: int, t
     D): experts ``expert_offset ..`` of the ``n_routed``. ``live`` (T,) bool
     marks the rows that are tokens (padding and empty decode slots route
     nowhere and are not counted). ``counts``: rows sent to held, identity and
-    absent experts, held experts with at least one row, and the most rows any
-    held expert got. ``rule``: ``route`` or ``route_sigmoid``.
+    absent experts, held experts with at least one row, the most rows any
+    held expert got, and the windows walked. ``rule``: ``route`` or
+    ``route_sigmoid``.
+
+    The held rows go through the three grouped matmuls ``window_rows`` at a
+    time, in sorted order, and each float32 result row is written to its own
+    (token, choice) place: a token's result depends neither on who shares its
+    batch nor on the window or the place in it that its rows fell to.
 
     ``layer``: the expert tensors are all layers' stacked, (layers, held, ..),
     and this is the layer to use (it may be traced). The grouped matmul then
@@ -139,25 +218,43 @@ def expert_layer(params: Dict[str, jax.Array], u: jax.Array, *, n_routed: int, t
         y = (jnp.sum(jnp.where(is_zero, weights, 0.0), axis=-1, keepdims=True) * u.astype(jnp.float32))
     with jax.named_scope("experts"):
         # every (token, choice) pair is a row; the held ones sort to the front,
-        # by expert, and the grouped matmul visits those alone
-        group = jnp.where(is_held, local, held_n).reshape(t * top_k)
-        order = jnp.argsort(group, stable=True)
+        # by expert, and the grouped matmuls see them a window at a time
+        n_rows = t * top_k
+        window = window_rows(n_rows, held_n, params["router"].shape[-1])
+        group = jnp.where(is_held, local, held_n).reshape(n_rows)
         sizes = jnp.bincount(group, length=held_n + 1)[:held_n].astype(jnp.int32)
-        rows = u[order // top_k]
-        groups = sizes
+        ends = jnp.cumsum(sizes)
+        starts, n_held = ends - sizes, ends[-1]
+        n_windows = (n_held + window - 1) // window
+        # padded to whole windows with places past the result's end
+        order = jnp.pad(jnp.argsort(group, stable=True).astype(jnp.int32), (0, -n_rows % window),
+                        constant_values=n_rows)
         if layer is not None:
-            groups = jax.lax.dynamic_update_slice(
-                jnp.zeros((e_gate.shape[0] * held_n,), jnp.int32), sizes, (layer * held_n,))
             e_gate, e_up, e_down = (w.reshape(-1, *w.shape[2:]) for w in (e_gate, e_up, e_down))
-        hidden = swiglu(jax.lax.ragged_dot(rows, e_gate, groups), jax.lax.ragged_dot(rows, e_up, groups))
-        out = jax.lax.ragged_dot(hidden, e_down, groups, preferred_element_type=jnp.float32)
-        # back to (token, choice) order; a token's parts add in the order of its
-        # choices, whoever else is in the batch
-        out = out[jnp.argsort(order)].reshape(t, top_k, d)
-        # (rows past the last group are whatever the grouped matmul left there)
-        y = y + jnp.sum(jnp.where(is_held[..., None], out * weights[..., None], 0.0), axis=1)
+
+        def walk(w, out):
+            """Window ``w``'s rows through the three grouped matmuls, each
+            result row written to its own (token, choice) place."""
+            at = w * window + jnp.arange(window, dtype=jnp.int32)
+            # rows past the last held one go nowhere: each a place of its own past the end
+            place = jnp.where(at < n_held, jax.lax.dynamic_slice(order, (w * window,), (window,)), n_rows + at)
+            rows = u[jnp.minimum(place, n_rows - 1) // top_k]
+            groups = jnp.clip(ends - w * window, 0, window) - jnp.clip(starts - w * window, 0, window)
+            if layer is not None:
+                groups = jax.lax.dynamic_update_slice(
+                    jnp.zeros((e_gate.shape[0],), jnp.int32), groups, (layer * held_n,))
+            hidden = swiglu(grouped_matmul(rows, e_gate, groups), grouped_matmul(rows, e_up, groups))
+            res = grouped_matmul(hidden, e_down, groups, jnp.float32)
+            return out.at[place].set(res, mode="drop", unique_indices=True)
+
+        out = jnp.zeros((n_rows, d), jnp.float32)
+        # a window that takes every row is the whole walk, and is traced without a loop
+        out = walk(0, out) if window == n_rows else jax.lax.fori_loop(0, n_windows, walk, out)
+        # a token's parts add in the order of its choices, whoever else is in
+        # the batch (a row no held expert owns was never written and adds zero)
+        y = y + jnp.sum(out.reshape(t, top_k, d) * weights[..., None], axis=1)
     counts = jnp.stack([
         jnp.sum(is_held), jnp.sum(is_zero), jnp.sum(alive & ~is_held & ~is_zero), jnp.sum(sizes > 0),
-        jnp.max(sizes),
+        jnp.max(sizes), n_windows,
     ]).astype(jnp.uint32)
     return y.astype(u.dtype), counts

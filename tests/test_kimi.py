@@ -329,6 +329,7 @@ def test_the_engine_serves_the_replayed_tokens_and_reports_its_expert_layers():
         rows = sum(5 + i for i in range(6)) * cfg.n_expert_layers * cfg.num_experts_per_tok
         assert total["held"] == rows and total["zero"] == total["absent"] == 0
         assert 0 < total["peak"] <= total["touched"] <= total["held"]
+        assert 0 < total["windows"] <= eng.decode_steps * cfg.n_expert_layers  # every expert held: one window a call
         newest = server.loop_stats()["moe"]
         if newest is not None:  # telemetry on: the newest record names every count, and the kind's expert layers
             assert {k: newest[k] for k in moe.COUNTS} == total and newest["layers"] == 2
@@ -360,6 +361,7 @@ def test_loop_stats_names_the_peak_count_in_the_newest_moe_record():
         assert list(newest) == list(looplog.LLM_MOE_FIELDS)
         assert looplog.LLM_MOE_FIELDS[2:-1] == moe.COUNTS and newest["layers"] == 2
         assert newest["held"] == 7 * 2 * 3 and newest["peak"] == 7 * 2 and newest["touched"] == newest["held"]
+        assert newest["windows"] == 7 * 2  # a window a layer a step
         assert '"peak": 14' in looplog.encode(("m", *newest.values()))
     finally:
         eng.shutdown()

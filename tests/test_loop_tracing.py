@@ -470,7 +470,8 @@ def test_sessions_land_under_the_process_temporary_directory(monkeypatch, tmp_pa
 def test_loop_summary_tool_reads_the_share_ahead_and_a_newcomers_wait(tmp_path):
     """``tools/loop_summary.py`` over records as the head writes them: the
     time the batch was full, how often the loop ran ahead, what a newcomer
-    waited for its first token. A record older than ``ahead`` reads 0."""
+    waited for its first token, the windows the expert layers walked a layer
+    a step. A record older than ``ahead`` reads 0."""
     import subprocess
 
     ms = 1_000_000
@@ -485,7 +486,10 @@ def test_loop_summary_tool_reads_the_share_ahead_and_a_newcomers_wait(tmp_path):
     old = steps[5][: 1 + fields.index("ahead")]  # as a program before the two fields wrote it
     req = ("r", 7, 30 * ms, 60 * ms, 95 * ms, 200 * ms, 5, 8, 4, 3, "length", None)
     log = looplog.LoopLog(str(tmp_path))
-    log.ingest({"llm-x-1": [*steps[:5], old, *steps[6:], req]})
+    # cumulative counts at steps 3 and 9 of a model of 2 expert layers: 13 windows over 12 layer-steps
+    moe = [("m", t * 20 * ms, t, *(w if k == "windows" else 2 if k == "layers" else 0
+                                   for k in looplog.LLM_MOE_FIELDS[2:])) for t, w in ((3, 6), (9, 19))]
+    log.ingest({"llm-x-1": [*steps[:5], old, *steps[6:], req, *moe]})
     log.close()
     tool = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "tools", "loop_summary.py")
     out = subprocess.run([sys.executable, tool, str(tmp_path / "loops"), "--skip-s", "0"],
@@ -496,3 +500,4 @@ def test_loop_summary_tool_reads_the_share_ahead_and_a_newcomers_wait(tmp_path):
     assert got["result_to_result_ms"] == pytest.approx(20.0)
     assert got["first_token_ms"] == {"count": 1, "mean_ms": 35.0, "median_ms": 35.0, "p90_ms": 35.0, "max_ms": 35.0}
     assert got["queue_wait_ms"]["mean_ms"] == 30.0
+    assert got["windows_per_layer_step"] == pytest.approx(13 / 12)

@@ -1,0 +1,164 @@
+"""The expert layer's window (``models/moe.py``): the grouped matmuls see the
+held (token, choice) rows ``window_rows`` at a time and walk windows until
+they run out. Against a plain loop over tokens and choices written here, for
+both routing rules, whatever the routing leaves the walk to do."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.models import moe
+
+D, F = 16, 32
+RULES = {"softmax": moe.route, "sigmoid": moe.route_sigmoid}
+
+
+def plain(params, u, *, n_routed, top_k, scale, rule, expert_offset=0, live=None, layer=None):
+    """(y, counts without ``windows``): a token at a time, a choice at a time,
+    the parts added in the order of the choices."""
+    take = (lambda w: w) if layer is None else (lambda w: w[layer])
+    gate, up, down = (np.asarray(take(params[k]), np.float32) for k in ("e_gate", "e_up", "e_down"))
+    routed = rule(u, params["router"], params["router_bias"], top_k=top_k, scale=scale)
+    weights, chosen = (np.asarray(a) for a in routed)
+    rows = np.asarray(u, np.float32)
+    y, sizes, zero, absent = np.zeros_like(rows), np.zeros(len(gate), np.int64), 0, 0
+    for t in range(len(rows)):
+        if live is not None and not live[t]:
+            continue
+        for w, e in zip(weights[t], chosen[t]):
+            if e >= n_routed:
+                zero, part = zero + 1, rows[t]
+            elif 0 <= e - expert_offset < len(gate):
+                e -= expert_offset
+                sizes[e] += 1
+                part = (np.asarray(jax.nn.silu(rows[t] @ gate[e])) * (rows[t] @ up[e])) @ down[e]
+            else:
+                absent += 1
+                continue
+            y[t] += w * part
+    return y, [int(sizes.sum()), zero, absent, int((sizes > 0).sum()), int(sizes.max())]
+
+
+def layer_params(held, n_outputs, *, favoured=(), shunned=(), seed=0):
+    """Seeded float32 weights; the choice bias sends every token to the
+    ``favoured`` outputs and none to the ``shunned``."""
+    params = moe.init_expert_params(jax.random.PRNGKey(seed), D, F, held=held, n_outputs=n_outputs)
+    bias = np.zeros(n_outputs, np.float32)
+    bias[list(favoured)], bias[list(shunned)] = 10.0, -10.0
+    return {**params, "router_bias": params["router_bias"] + bias}
+
+
+# name: (tokens, held, routed, outputs, top_k, favoured, shunned, extra arguments, windows walked)
+CASES = {
+    # 128 rows, 8 expected of an even router: a window of 32 and room to spare
+    "one_window": (64, 4, 56, 64, 2, (), (), {}, 1),
+    # every token's first choice is held expert 0: 40 held rows and a few more, past 32
+    "two_windows": (40, 4, 56, 64, 2, (0,), (), {}, 2),
+    # every token chooses held experts 0 and 1: all 144 rows held, in windows of 32
+    "five_windows": (72, 4, 56, 64, 2, (0, 1), (), {}, 5),
+    # every expert held: the window is every row and nothing is walked twice
+    "every_expert_held": (24, 6, 6, 8, 3, (), (), {}, 1),
+    # no token chooses a held expert: no window, and the identity experts' part alone
+    "no_held_row": (48, 4, 56, 64, 2, (), (0, 1, 2, 3), {}, 0),
+    # the chip's experts are 8..11 of the 56; two windows of them
+    "offset_share": (40, 4, 56, 64, 2, (9,), (), {"expert_offset": 8}, 2),
+    # rows that are no tokens route nowhere: 25 live rows of 50 fit one window
+    "masked_rows": (50, 4, 56, 64, 2, (0,), (), {"live": np.arange(50) % 2 == 0}, 1),
+    "masked_rows_spill": (90, 4, 56, 64, 2, (0,), (), {"live": np.arange(90) % 2 == 0}, 2),
+    # the experts of three layers stacked, the second one's used
+    "stacked_layer": (40, 4, 56, 64, 2, (2,), (), {"layer": 1}, 2),
+}
+
+
+@pytest.mark.parametrize("rule", list(RULES))
+@pytest.mark.parametrize("case", list(CASES))
+def test_the_walk_agrees_with_a_plain_loop(case, rule):
+    t, held, n_routed, n_outputs, top_k, favoured, shunned, extra, windows = CASES[case]
+    params = layer_params(held, n_outputs, favoured=favoured, shunned=shunned)
+    if "layer" in extra:
+        others = [layer_params(held, n_outputs, seed=s) for s in (1, 2)]
+        for k in ("e_gate", "e_up", "e_down"):
+            params[k] = jnp.stack([others[0][k], params[k], others[1][k]])
+    u = jax.random.normal(jax.random.PRNGKey(7), (t, D))
+    kw = dict(n_routed=n_routed, top_k=top_k, scale=2.5, rule=RULES[rule], **extra)
+    y, counts = jax.jit(lambda rows: moe.expert_layer(params, rows, **kw))(u)
+    want, want_counts = plain(params, u, **kw)
+    np.testing.assert_allclose(np.asarray(y), want, atol=2e-5)
+    assert dict(zip(moe.COUNTS, np.asarray(counts).tolist())) == dict(zip(moe.COUNTS, want_counts + [windows]))
+    n_held, window = want_counts[0], moe.window_rows(t * top_k, held, n_outputs)
+    assert windows == -(-n_held // window)  # the case is what its name says
+    if case == "no_held_row":
+        w, chosen = RULES[rule](u, params["router"], params["router_bias"], top_k=top_k, scale=2.5)
+        share = np.sum(np.where(np.asarray(chosen) >= n_routed, np.asarray(w), 0.0), -1, keepdims=True)
+        identity = share * np.asarray(u)
+        assert want_counts[1] > 0
+        np.testing.assert_allclose(np.asarray(y), identity, atol=1e-6)
+
+
+@pytest.mark.parametrize("rule", list(RULES))
+def test_a_tokens_result_is_the_same_bit_for_bit_wherever_its_rows_fall(rule):
+    """Alone (its ``top_k`` rows are the window), among 19 others (one window
+    of 32), and first or last of 40 tokens that all choose held expert 0, so
+    that its row for that expert is the first window's first or the second
+    window's eighth."""
+    params = layer_params(4, 64, favoured=(0,))
+    kw = dict(n_routed=56, top_k=2, scale=2.5, rule=RULES[rule])
+    layer = jax.jit(lambda rows: moe.expert_layer(params, rows, **kw))
+    token = jax.random.normal(jax.random.PRNGKey(11), (1, D))
+    others = jax.random.normal(jax.random.PRNGKey(12), (39, D))
+    alone, counts = layer(token)
+    assert np.asarray(counts).tolist()[0] >= 1 and np.any(np.asarray(alone) != 0)
+    among, counts = layer(jnp.concatenate([others[:7], token, others[7:19]]))
+    assert np.asarray(counts).tolist()[-1] == 1
+    first, counts_first = layer(jnp.concatenate([token, others]))
+    last, counts_last = layer(jnp.concatenate([others, token]))
+    assert np.asarray(counts_first).tolist() == np.asarray(counts_last).tolist() and np.asarray(counts_last)[-1] == 2
+    for got in (among[7], first[0], last[-1]):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(alone[0]))
+
+
+@pytest.mark.parametrize("n_rows,held,n_outputs,window", [
+    (384, 12, 384, 32), (512, 12, 384, 32),  # Kimi-K2's decode step at 48 and at 64 slots
+    (1024, 12, 384, 64), (2048, 12, 384, 128), (4096, 12, 384, 256),  # its prefill buckets
+    (384, 16, 768, 32), (3072, 16, 768, 128), (6144, 16, 768, 256), (12288, 16, 768, 512),  # LongCat's
+    (96, 8, 12, 96), (32, 2, 2, 32), (8, 4, 64, 8), (96, 6, 12, 96),  # most experts held, few rows: one window of every row
+])
+def test_the_window_follows_the_held_rows_and_not_the_batch(n_rows, held, n_outputs, window):
+    assert moe.window_rows(n_rows, held, n_outputs) == window
+
+
+@pytest.mark.parametrize("rows,k,n,dtype,taken", [
+    (32, 7168, 2048, jnp.bfloat16, True), (32, 2048, 7168, jnp.bfloat16, True),  # Kimi-K2's decode window: gate, down
+    (512, 6144, 2048, jnp.bfloat16, True), (512, 2048, 6144, jnp.bfloat16, True),  # LongCat's 1,024 bucket
+    (1024, 7168, 2048, jnp.bfloat16, False),  # more rows than one row tile takes: every expert held, a large batch
+    (24, 7168, 2048, jnp.bfloat16, False),  # no whole sublane tiles
+    (32, 7168, 2048, jnp.float32, False),  # the tests' float32 twins
+    (32, 7000, 2048, jnp.bfloat16, False), (32, 2048, 1000, jnp.bfloat16, False),  # no whole weight tiles
+])
+def test_the_grouped_kernel_is_chosen_by_platform_and_static_shape(monkeypatch, rows, k, n, dtype, taken):
+    x, w = jax.ShapeDtypeStruct((rows, k), dtype), jax.ShapeDtypeStruct((6, k, n), dtype)
+    assert not moe.can_use_grouped_kernel(x, w)  # the CPU keeps ragged_dot
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert moe.can_use_grouped_kernel(x, w) == taken
+    tk, tn = moe._weight_tile(k, n)
+    assert tk * tn <= 1 << 20 and (not taken or (k % tk == 0 and n % tn == 0))
+
+
+def test_the_grouped_kernel_at_its_tiling_agrees_with_ragged_dot():
+    """JAX's Pallas grouped matmul in interpret mode, at the tiling
+    ``grouped_matmul`` states, against ``jax.lax.ragged_dot``: groups of a
+    stacked tensor of which one layer's have rows, an empty group among them,
+    and dead rows past the last group (left as whatever was there)."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+    rows, k, n, held = 32, 4096, 256, 4  # k past ``_WHOLE_K``: tiles of 512 x 256, eight steps of the contraction
+    x = jax.random.normal(jax.random.PRNGKey(0), (rows, k)).astype(jnp.bfloat16)
+    w = (jax.random.normal(jax.random.PRNGKey(1), (3 * held, k, n)) * k ** -0.5).astype(jnp.bfloat16)
+    groups = jnp.zeros((3 * held,), jnp.int32).at[held:2 * held].set(jnp.asarray([5, 0, 9, 7]))
+    tiling = (rows, *moe._weight_tile(k, n))
+    assert tiling == (32, 512, 256)
+    got = gmm(x, w, groups, preferred_element_type=jnp.float32, tiling=tiling, interpret=True)
+    want = jax.lax.ragged_dot(x, w, groups, preferred_element_type=jnp.float32)
+    np.testing.assert_allclose(np.asarray(got)[:21], np.asarray(want)[:21], rtol=2e-2, atol=2e-2)
+    np.testing.assert_array_equal(np.asarray(want)[:21] != 0, True)

@@ -216,10 +216,31 @@ def _latent_kernel_reads_the_pool_in_place(text, pool_dims, batch, per_seq, bloc
     assert pool_dims not in made
 
 
+def _grouped_matmuls_take(text, rows, width, all_rows):
+    """The program's three grouped matmuls (``moe.grouped_matmul``: on a TPU
+    at these widths JAX's Pallas ``gmm``, a Mosaic call each) multiply ``rows``
+    compacted rows, gate and up from ``[rows, width]`` operands and down back
+    to that width in float32, reading the experts out of their stack
+    (``[layers x held, ...]`` operands: no layer cut out); and no gather makes
+    a row a (token, choice) pair of the batch (``[all_rows, width]``): the
+    window's rows alone are gathered."""
+    import re
+
+    calls = [line for line in text.splitlines() if re.search(r"%gmm[.\d]* = ", line)]
+    made = [re.search(r"= (\w+)\[(\d+),(\d+)\]", line).groups() for line in calls]
+    assert len(calls) == 3 and all('custom_call_target="tpu_custom_call"' in line for line in calls)
+    assert "ragged-dot" not in text
+    assert sorted(int(n) for _, n, _ in made) == [rows] * 3 and ("f32", str(rows), str(width)) in made
+    assert sum(f"bf16[{rows},{width}]" in line for line in calls) == 2
+    assert str(all_rows) not in re.findall(rf"= \w+\[(\d+),{width}\]\S* gather\(", text)
+
+
 def test_latent_decode_and_prefill_at_longcat_widths(one_chip, monkeypatch):
     """serve.llm's programs for LongCat-Flash's language model at the published
     widths, one chip's share of the experts (16 of 512), 2 layers. The decode
-    step holds the grouped matmul over the held experts and the latent kernel,
+    step holds the grouped matmuls over a window of 32 of its 384 (token,
+    choice) rows (``moe.window_rows``; a 1,024 bucket's over 512 of 12,288) and
+    the latent kernel,
     which reads a table's live blocks where they lie (no gathered copy), and
     never re-lays the pool out: its rows are stored 640 wide (576
     values: the TPU gives such a pool another device layout than the one the
@@ -242,10 +263,11 @@ def test_latent_decode_and_prefill_at_longcat_widths(one_chip, monkeypatch):
         params, arg((batch,), jnp.int32), arg((batch,), jnp.int32), arg((batch, per_seq), jnp.int32), pool,
         arg((batch,), jnp.bool_),
     ).compile().as_text()
-    assert text.count("ragged-dot") >= 3  # gate, up and down of the held experts
+    _grouped_matmuls_take(text, 32, 6144, batch * cfg.moe_topk)  # gate, up and down of the held experts
     _latent_kernel_reads_the_pool_in_place(text, f"[4,{blocks},{block},640]", batch, per_seq, block)
     text = prefill.lower(
         params, arg((1, 1024), jnp.int32), arg((1, per_seq), jnp.int32), pool, arg((), jnp.int32)).compile().as_text()
+    _grouped_matmuls_take(text, 512, 6144, 1024 * cfg.moe_topk)
     assert "paged_latent_attention" not in text
     assert "gather(" not in "".join(line for line in text.splitlines() if f",{block},640]" in line)
 
@@ -256,7 +278,11 @@ def test_latent_decode_and_prefill_at_kimi_k2_widths(one_chip, monkeypatch):
     published widths, the dense layer and six expert layers as two scans in one
     program, 12 of 384 experts, an eighth of the vocabulary, the engine's 48
     slots over 7,681 blocks. 9.70 GB of weights and a 1.10 GB pool are the
-    program's arguments. The decode step holds the grouped matmuls and the
+    program's arguments. The decode step holds the grouped matmuls over a
+    window of 32 of its 384 (token, choice) rows, and of its 512 at 64 slots
+    (where the row tile had followed the batch to 512 and the step cost 25.5 ms
+    for 15.5: PERF.md, section 6, PR 34 and PR 38), a 512 bucket's over 256
+    of 4,096, and the
     latent kernel (a table's live blocks read where they lie: no gathered
     copy, and 5 MB of the program's own where the gathers had 40) and never
     copies the pool. **No weight is copied out of
@@ -300,13 +326,20 @@ def test_latent_decode_and_prefill_at_kimi_k2_widths(one_chip, monkeypatch):
         arg((batch,), jnp.bool_),
     ).compile()
     text = compiled.as_text()
-    assert text.count("ragged-dot") >= 3  # gate, up and down of the held experts
+    _grouped_matmuls_take(text, 32, 7168, batch * cfg.num_experts_per_tok)  # gate, up and down of the held experts
     _latent_kernel_reads_the_pool_in_place(text, f"[7,{blocks},{block},640]", batch, per_seq, block)
     assert staged(text) == {"wqb", "wkvb"}
     assert compiled.memory_analysis().temp_size_in_bytes < 0.02e9  # beside 10.8 GB of arguments
+    text = decode_greedy.lower(
+        params, arg((64,), jnp.int32), arg((64,), jnp.int32), arg((64, per_seq), jnp.int32), pool,
+        arg((64,), jnp.bool_),
+    ).compile().as_text()
+    _grouped_matmuls_take(text, 32, 7168, 64 * cfg.num_experts_per_tok)
+    assert staged(text) == {"wqb", "wkvb"}
     compiled = prefill.lower(
         params, arg((1, 512), jnp.int32), arg((1, per_seq), jnp.int32), pool, arg((), jnp.int32)).compile()
     text = compiled.as_text()
+    _grouped_matmuls_take(text, 256, 7168, 512 * cfg.num_experts_per_tok)
     assert "paged_latent_attention" not in text
     assert "gather(" not in "".join(line for line in text.splitlines() if f",{block},640]" in line)
     assert staged(text) <= {"wqb", "wkvb"}

@@ -9,7 +9,11 @@ share of dispatched steps that went out with a step still in flight
 (``ahead``), the rows dropped for an EOS seen a step late (``overrun``), the
 mean time between two results; and over the requests admitted in that time,
 ``t_first - t_admit`` (a newcomer's wait for its first token, behind whatever
-was in flight) and ``t_admit - t_submit``. Records older than a field read 0
+was in flight) and ``t_admit - t_submit``; and, of a model with expert
+layers, the windows of held rows its grouped matmuls walked a layer a decode
+step between that time's first and last ``llm_moe`` record
+(``windows_per_layer_step``: 1 = no call spilled past its first window; None
+without two records that carry the count). Records older than a field read 0
 there. Reads with the standard library alone; newest session under the
 temporary directory where no directory is given.
 """
@@ -42,6 +46,15 @@ def spread_ms(values_ns: list) -> dict:
             "p90_ms": ms[min(len(ms) - 1, int(0.9 * len(ms)))], "max_ms": ms[-1]}
 
 
+def windows_per_layer_step(moe_recs: list, t0: int, t1: int):
+    recs = [r for r in moe_recs if t0 <= r["t"] <= t1 and "windows" in r]
+    if len(recs) < 2:
+        return None
+    first, last = recs[0], recs[-1]
+    layer_steps = (last["step"] - first["step"]) * last["layers"]
+    return (last["windows"] - first["windows"]) / layer_steps if layer_steps > 0 else None
+
+
 def summarise(recs: dict, skip_s: float) -> dict:
     steps = [r for r in recs["llm_step"] if r["live"]]
     if not steps:
@@ -60,6 +73,7 @@ def summarise(recs: dict, skip_s: float) -> dict:
         "result_to_result_ms": (results[-1] - results[0]) / 1e6 / (len(results) - 1) if len(results) > 1 else None,
         "first_token_ms": spread_ms([r["t_first"] - r["t_admit"] for r in reqs]),
         "queue_wait_ms": spread_ms([r["t_admit"] - r["t_submit"] for r in reqs]),
+        "windows_per_layer_step": windows_per_layer_step(recs["llm_moe"], t0, t1),
     }
 
 
