@@ -2,10 +2,16 @@
 where it outlives the cluster.
 
 The serving engine keeps one record per iteration of its loop and one per
-finished request; the trainer's step plane closes one record per step. They
-ride the telemetry batches (``TelemetryBuffer.record_loop`` for the engine,
-the step records' own two channels for the trainer), and the head appends
-them as they land to
+finished request; the trainer's step plane closes one record per step. Two
+kinds say how a loop came to run: ``llm_start``, one an engine, holds the
+stamps of its start from the constructor's first line to the loop's thread;
+``compile``, one a ``jax.monitoring`` duration event of a program's tracing,
+lowering, backend compilation or load from the compile cache, names the
+program and the step it landed in, in the engine's file or the trainer's
+(``sampler.install_jax_hooks`` is the one listener that makes them). They
+ride the telemetry batches (``TelemetryBuffer.record_loop`` for the engine
+and for both loops' ``compile`` records, the step records' own two channels
+for the trainer), and the head appends them as they land to
 
     <session_dir>/loops/llm-<deployment>-<pid>.jsonl
     <session_dir>/loops/train-<run>-rank<r>.jsonl
@@ -71,8 +77,39 @@ LLM_MOE_FIELDS = (
     "windows",  # windows of held rows the grouped matmuls walked, a layer a step (1 a layer-step: none spilled)
     "layers",  # expert layers a decode step runs
 )
+# one engine's start, made when its loop's thread starts: where the time from
+# the constructor's first line to a replica that serves went
+LLM_START_FIELDS = (
+    "t_init",  # top of LLMServer.__init__ (of InferenceEngine.__init__ where there is no server)
+    "t_backend",  # the platform is chosen and the first device is in hand
+    "t_params",  # the weights are on the device (waited for, not just dispatched)
+    "t_placed",  # paged.place_params done: the stacked tensors the kind names lie as it wants them
+    "t_pool",  # the pool is made and committed
+    "t_ready",  # the loop's thread runs
+    "placed",  # tensors re-laid
+    "pool_bytes",  # blocks and state rows
+)
+# one jax.monitoring duration event of a program on its way to the device, in
+# the file of the loop its process holds. A trace nested in another program's
+# (a jitted function called while an outer one is traced) is part of the outer
+# one's seconds and leaves no record of its own
+COMPILE_FIELDS = (
+    "t",  # the event's end
+    "seconds",
+    "stage",  # trace | lower | compile | cache_load; ``compile`` holds its ``cache_load``
+    "program",  # jax's fun_name: ``prefill`` traced, ``jit(prefill)`` lowered and compiled
+    "step",  # decode steps dispatched, or the trainer's steps done, when it landed
+    "where",  # init: the constructor's thread before the loop runs | loop: the loop's thread | other
+)
+COMPILE_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load",
+}
 _KINDS = {"s": ("llm_step", LLM_STEP_FIELDS), "r": ("llm_request", LLM_REQUEST_FIELDS),
-          "m": ("llm_moe", LLM_MOE_FIELDS)}
+          "m": ("llm_moe", LLM_MOE_FIELDS), "b": ("llm_start", LLM_START_FIELDS),
+          "c": ("compile", COMPILE_FIELDS)}
 
 MAX_FILE_BYTES = 32 << 20  # a file past this moves to <name>.1 (one kept)
 _MAX_OPEN = 64

@@ -18,11 +18,15 @@ speedscope JSON via :func:`write_collapsed` / :func:`write_speedscope`
 
 JAX compile/execute boundaries: :func:`install_jax_hooks` registers a
 ``jax.monitoring`` duration listener (when the installed jax exposes one) so
-``jax:<event>`` spans land in the timeline/trace alongside stack samples.
+``jax:<event>`` spans land in the timeline/trace alongside stack samples, and
+the hot loop this process holds (a training step, a serving engine) gets a
+``compile`` loop record of every program traced, lowered, compiled or loaded
+from the compile cache (``looplog.COMPILE_FIELDS``).
 """
 
 from __future__ import annotations
 
+import collections
 import json
 import os
 import sys
@@ -290,6 +294,41 @@ def write_speedscope(rows, path: str, name: str = "ray_tpu profile") -> int:
 
 _jax_hooked = False
 
+# the serving engine this process holds (its newest), which takes the compile
+# events that no training step is live for, and the events that landed while
+# there was none: a replica's weights are jitted before its engine exists
+_compile_sink = None
+_compile_early: "collections.deque[tuple]" = collections.deque(maxlen=1024)
+
+
+def set_compile_sink(sink, since_ns: int) -> None:
+    """From now on ``sink.note_compile(t_ns, seconds, stage, program,
+    thread_ident)`` gets this process's compile events
+    (``looplog.COMPILE_STAGES``; the listener's thread calls it), and first
+    the ones kept since ``since_ns``. Older ones are some other caller's and
+    are dropped."""
+    global _compile_sink
+    _compile_sink = sink
+    while _compile_early:
+        ev = _compile_early.popleft()
+        if ev[0] >= since_ns:
+            sink.note_compile(*ev)
+
+
+def clear_compile_sink(sink) -> None:
+    global _compile_sink
+    if _compile_sink is sink:
+        _compile_sink = None
+
+
+def _outermost_trace() -> bool:
+    """Whether the trace that just ended was a program's own: jax times every
+    jitted function it traces, the ``jnp`` ones called inside a program among
+    them, and those seconds are already in the program's."""
+    core = sys.modules.get("jax._src.core")
+    clean = getattr(core, "trace_state_clean", None)
+    return clean is None or bool(clean())
+
 
 def maybe_install_jax_hooks() -> None:
     """Cheap periodic probe (called from the telemetry flusher cadence):
@@ -308,8 +347,13 @@ def install_jax_hooks() -> bool:
     Each span is attributed to the (task, trace) the TRIGGERING thread is
     executing — the sampler's per-thread registry plus the thread's active
     trace context — so compile time lands inside the request's span tree
-    (``ray_tpu.trace``) instead of as a global orphan, and feeds the active
-    training step's ``compile`` stage (``stepplane.note_compile``)."""
+    (``ray_tpu.trace``) instead of as a global orphan, with the program's
+    name (jax's ``fun_name``) as ``extra["program"]``, and feeds the active
+    training step's ``compile`` stage (``stepplane.note_compile``). An event
+    of a program's own tracing, lowering, compilation or cache load
+    (``looplog.COMPILE_STAGES``) also leaves a ``compile`` loop record with
+    the live training step, else with the serving engine this process holds
+    (``set_compile_sink``), else it is kept for the engine to come."""
     global _jax_hooked
     if _jax_hooked:
         return True
@@ -322,12 +366,15 @@ def install_jax_hooks() -> bool:
     if register is None:
         return False  # jax absent, or its import has not got that far yet
 
+    from ray_tpu._private.looplog import COMPILE_STAGES
+
     def _listener(event: str, duration_s: float, **kwargs) -> None:
         try:
-            end = time.time()
-            task_id, trace_id = _thread_tasks.get(
-                threading.get_ident(), (None, None)
-            )
+            end_ns = time.time_ns()
+            end = end_ns / 1e9
+            ident = threading.get_ident()
+            program = kwargs.get("fun_name")
+            task_id, trace_id = _thread_tasks.get(ident, (None, None))
             extra: Dict[str, str] = {}
             try:
                 from ray_tpu.util import tracing as _tracing
@@ -350,6 +397,8 @@ def install_jax_hooks() -> bool:
                     }
             except Exception:
                 pass
+            if program:
+                extra["program"] = str(program)
             span = {
                 "event": f"jax:{event.strip('/').replace('/', '.')}",
                 "start": end - duration_s,
@@ -366,7 +415,16 @@ def install_jax_hooks() -> bool:
             # that triggered it (and arm the recompile detector)
             from ray_tpu._private import stepplane as _stepplane
 
-            _stepplane.note_compile(event, duration_s)
+            stage = COMPILE_STAGES.get(event)
+            if stage == "trace" and not _outermost_trace():
+                stage = None
+            rec = None if stage is None else (end_ns, duration_s, stage, program, ident)
+            if not _stepplane.note_compile(event, duration_s, rec) and rec is not None:
+                sink = _compile_sink
+                if sink is not None:
+                    sink.note_compile(*rec)
+                else:
+                    _compile_early.append(rec)
             if "backend_compile" in event:
                 # only a process with a backend up compiles for it: the
                 # memory plane's device sweep may start (memplane)

@@ -213,12 +213,17 @@ def note_host_to_device(seconds: float) -> None:
         t.note_host_to_device(seconds)
 
 
-def note_compile(event: str, seconds: float) -> None:
+def note_compile(event: str, seconds: float, record: Optional[tuple] = None) -> bool:
     """One jax.monitoring duration event landed on this process (sampler
-    listener seam); attributed to the active step if a timer is live."""
+    listener seam); attributed to the active step if a timer is live, which
+    the return value says. ``record``: ``(t_ns, seconds, stage, program,
+    thread ident)`` where the event is one that leaves a ``compile`` loop
+    record (``looplog.COMPILE_STAGES``)."""
     t = current()
-    if t is not None:
-        t.note_compile(event, seconds)
+    if t is None:
+        return False
+    t.note_compile(event, seconds, record)
+    return True
 
 
 def note_checkpoint_stall(seconds: float) -> None:
@@ -289,6 +294,7 @@ class StepTimer:
             else _config_attr("train_recompile_warmup_steps", 2)
         )
         self.steps_done = 0  # session-local (fresh process = cold jit cache)
+        self._thread = threading.get_ident()  # the training loop's: a session is made where it runs
         self._sig: Optional[str] = None
         self._sig_prev: Optional[str] = None
         self._last_flagged_sig: Optional[str] = None
@@ -376,13 +382,22 @@ class StepTimer:
     def note_host_to_device(self, seconds: float) -> None:
         self._h2d += max(0.0, float(seconds))
 
-    def note_compile(self, event: str, seconds: float) -> None:
+    def note_compile(self, event: str, seconds: float, record: Optional[tuple] = None) -> None:
         self._compile += max(0.0, float(seconds))
         tail = event.rstrip("/").rsplit("/", 1)[-1]
         if any(tail.startswith(e) for e in _RECOMPILE_EVENTS):
             self._compile_events += 1
             if self.steps_done >= self.warmup:
                 self._recompiled = True
+        if record is not None and self._buffer is not None:
+            # the split the ``compile`` stage lacks, by program, in the file
+            # the step records go to (``looplog.COMPILE_FIELDS``)
+            t_ns, secs, stage, program, ident = record
+            self._buffer.record_loop(
+                f"train-{self.run}-rank{self.rank}",
+                ("c", t_ns, secs, stage, program, self.steps_done,
+                 "loop" if ident == self._thread else "other"),
+            )
 
     def note_checkpoint_stall(self, seconds: float) -> None:
         self._ckpt_stall += max(0.0, float(seconds))

@@ -15,6 +15,7 @@ has no checkpoint loader); pass ``params_loader`` for real weights.
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Any, Callable, Dict, Iterator, Optional, Sequence, Union
 
 from ray_tpu.serve.llm.engine import EngineConfig, InferenceEngine
@@ -76,12 +77,18 @@ class LLMServer:
         deployment: str = "llm",
         params_loader: Optional[Callable[[Any], Any]] = None,
     ):
+        t_init = time.time_ns()  # a start's first stamp (``looplog.LLM_START_FIELDS``)
         import jax
 
+        from ray_tpu._private import sampler
         from ray_tpu.models import paged_model
         from ray_tpu.train.jax_utils import ensure_platform
 
+        # before the first program is traced: the weights' jit is a start's first
+        sampler.install_jax_hooks()
         ensure_platform()  # a replica that asked for a chip runs on it
+        jax.local_devices()  # the backend is up, the first device in hand
+        t_backend = time.time_ns()
         cfg = _resolve_model_cfg(model_cfg)
         if params_loader is not None:
             params = params_loader(cfg)
@@ -91,7 +98,7 @@ class LLMServer:
             init_params = paged_model(cfg).init_params
             params = jax.jit(lambda: init_params(jax.random.PRNGKey(int(weight_seed)), cfg))()
         self._engine = InferenceEngine(
-            params, cfg, _resolve_engine_cfg(engine_cfg), deployment=deployment
+            params, cfg, _resolve_engine_cfg(engine_cfg), deployment=deployment, started=(t_init, t_backend)
         )
 
     def generate(
@@ -141,7 +148,8 @@ class LLMServer:
 
     def loop_stats(self, records: int = 64) -> Dict[str, Any]:
         """The engine loop's newest step and request records and its phase
-        sums (``InferenceEngine.loop_stats``): where a step's time went."""
+        sums (``InferenceEngine.loop_stats``): where a step's time went; and
+        ``start``: where the replica's start went, by phase and by program."""
         return self._engine.loop_stats(records)
 
     def check_health(self) -> bool:

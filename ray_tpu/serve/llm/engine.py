@@ -54,6 +54,20 @@ handles) are made after it, while the device is busy. Each phase is also a
 ``llm.dispatch``, ``llm.emit``) on this thread's line of any profiler trace.
 With ``telemetry_enabled`` off no record or span is made and the ring is empty.
 
+How the loop came to run leaves two more kinds, neither made by the loop. The
+constructors stamp a start's phases (the server's first line, the backend up,
+the weights on the device, the tensors placed, the pool committed, the loop's
+thread running) and the loop's thread, before its first iteration, makes one
+``llm_start`` record of them and the start's one log line. Every program that
+is traced, lowered, compiled or loaded from the compile cache in this process
+leaves a ``compile`` record with its name, its seconds, the decode steps
+dispatched so far and whether it came from the constructor (``init``), the
+loop's thread (``loop``: a prefill bucket's or a decode program's first call)
+or elsewhere; it comes from ``sampler``'s ``jax.monitoring`` listener, on the
+thread that compiled, and feeds ``ray_tpu_llm_compile_seconds_total``. A step
+that compiles nothing runs no line of this. ``loop_stats()["start"]`` has the
+stamps and the records' seconds by stage and program.
+
 A model with an expert layer (``models/longcat.py``, ``models/kimi.py``) sums what its decode
 steps routed in a leaf of the pool, on the device. Once a flush interval the
 loop, after its dispatch, enqueues a copy of that leaf behind the step in
@@ -77,8 +91,10 @@ import threading
 import time
 from typing import Any, Dict, List, Optional, Sequence
 
-from ray_tpu._private import memplane, telemetry
-from ray_tpu._private.looplog import LLM_MOE_FIELDS, LLM_REQUEST_FIELDS, LLM_STEP_FIELDS
+from ray_tpu._private import memplane, sampler, telemetry
+from ray_tpu._private.looplog import (
+    COMPILE_FIELDS, COMPILE_STAGES, LLM_MOE_FIELDS, LLM_REQUEST_FIELDS, LLM_START_FIELDS, LLM_STEP_FIELDS,
+)
 from ray_tpu._private.profiling import annotate
 from ray_tpu.serve.exceptions import DeploymentOverloadedError
 from ray_tpu.serve.llm.kv_cache import BlockAllocator, BlockTable
@@ -157,6 +173,15 @@ def _engine_metrics() -> dict:
             "summed over layers and decode steps: over decode steps x expert "
             "layers, 1 = every call's held rows fit its first window",
             tag_keys=("deployment",),
+        )
+        _metrics["compile"] = Counter(
+            "ray_tpu_llm_compile_seconds_total",
+            "seconds a replica's process spent bringing programs to the "
+            "device, by stage: trace, lower, compile (the backend's, which "
+            "holds cache_load: an executable read from the compile cache); "
+            "a start's are expected, a rise in a replica that serves is a "
+            "program compiled in some request's way",
+            tag_keys=("deployment", "stage"),
         )
     return _metrics
 
@@ -330,7 +355,12 @@ class InferenceEngine:
         *,
         deployment: str = "llm",
         start: bool = True,
+        started: Optional[tuple] = None,
     ):
+        """``started``: ``(t_init, t_backend)`` of the server that built this
+        engine, in ``time.time_ns()``; without one the start is the engine's own."""
+        now = time.time_ns
+        t_init = now()
         import jax
 
         from ray_tpu.models import generation as G, moe, paged, paged_model
@@ -342,6 +372,29 @@ class InferenceEngine:
         self.cfg = ecfg
         self.deployment = deployment
         self._G = G
+        # -- what the start leaves behind (module docstring): the listener is
+        # in before the first program is traced, and every compile event of
+        # this process from ``t_init`` on is this engine's, the kept ones of
+        # the weights' jit among them
+        sampler.install_jax_hooks()
+        # resolved once: a replica builds its engine after it has connected
+        self._tel = telemetry.get_buffer() if telemetry.enabled() else None
+        self._stem = f"llm-{deployment}-{os.getpid()}"
+        self._thread: Optional[threading.Thread] = None
+        self._init_thread = threading.get_ident()
+        self.decode_steps = 0  # dispatched so far: a step's number
+        self._compiles: "collections.deque[tuple]" = collections.deque(maxlen=LOOP_RING)
+        self._m_compile = {
+            stage: _engine_metrics()["compile"].bind({"deployment": deployment, "stage": stage})
+            for stage in COMPILE_STAGES.values()
+        }
+        t_init, t_backend = started or (t_init, now())
+        self._start = dict.fromkeys(LLM_START_FIELDS, 0)
+        self._start.update(t_init=t_init, t_backend=t_backend)
+        sampler.set_compile_sink(self, since_ns=t_init)
+        # the weights are on the device, not just dispatched there: the stamp is theirs
+        jax.block_until_ready(params)
+        self._start["t_params"] = now()
         # the pool is the model's to shape: its module makes it, says what a
         # block of it holds, and gives the layer the three programs run over it
         model = paged_model(model_cfg)
@@ -357,10 +410,8 @@ class InferenceEngine:
         # the weights as the kind wants them to lie on the device, before the
         # pool exists: ``params`` is the engine's from here (placed originals
         # are deleted), and every reader of ``self.params`` gets the placed tree
-        t0 = time.perf_counter()
         self.params, self.placed = paged.place_params(model, model_cfg, params)
-        if self.placed:
-            logger.info("%s: placed %s on the device in %.2f s", deployment, self.placed, time.perf_counter() - t0)
+        self._start.update(t_placed=now(), placed=len(self.placed))
         self._pool = model.init_paged_pool(
             model_cfg, ecfg.num_blocks, ecfg.block_size, **({"state_rows": pool_rows} if pool_rows else {})
         )
@@ -370,6 +421,8 @@ class InferenceEngine:
             # come back, or the first program called is lowered again when it
             # next meets the pool, inside some request's time to first token
             self._pool = jax.tree.map(lambda x: jax.device_put(x, x.sharding), self._pool)
+        jax.block_until_ready(self._pool)
+        self._start["t_pool"] = now()
         self._device = next(iter(jax.tree.leaves(self._pool)[0].devices()))
         self._alloc = BlockAllocator(ecfg.num_blocks, ecfg.block_size, state_rows=state_rows)
         self._slots: List[Optional[_Running]] = [None] * ecfg.max_batch
@@ -379,16 +432,12 @@ class InferenceEngine:
         self._ids = itertools.count()
         self._cv = threading.Condition()
         self._stop = False
-        self._thread: Optional[threading.Thread] = None
         self.max_context = min(
             (ecfg.max_blocks_per_seq - bool(state_rows)) * ecfg.block_size, model_cfg.max_seq_len
         )
         self._bytes_per_block = int(model.paged_block_bytes(model_cfg, ecfg.block_size))
-        blocks, rows = ecfg.num_blocks * self._bytes_per_block, pool_rows * self._state_bytes
-        logger.info("%s: the pool holds %.3f GB of blocks (%d x %d B), %.3f GB of state rows (%d x %d B), %.3f GB in all",
-                    deployment, blocks / 1e9, ecfg.num_blocks, self._bytes_per_block, rows / 1e9,
-                    pool_rows, self._state_bytes, (blocks + rows) / 1e9)
-        self.decode_steps = 0  # dispatched so far: a step's number
+        self._pool_rows = pool_rows
+        self._start["pool_bytes"] = ecfg.num_blocks * self._bytes_per_block + pool_rows * self._state_bytes
         # what the loop has enqueued on the device and not yet read, in device
         # order: decode steps and newcomers' first tokens (the loop's alone)
         self._flight: "collections.deque" = collections.deque()
@@ -407,10 +456,7 @@ class InferenceEngine:
         self._moe_seen = [0] * len(moe.COUNTS)  # the counts last read, modulo 2**32
         self._moe_total = [0] * len(moe.COUNTS)  # ``moe.COUNTS`` since the engine started
         # -- what the loop measures of itself (module docstring) ----------
-        # resolved once: a replica builds its engine after it has connected
-        self._tel = telemetry.get_buffer() if telemetry.enabled() else None
         self._ring: "collections.deque[tuple]" = collections.deque(maxlen=LOOP_RING)
-        self._stem = f"llm-{deployment}-{os.getpid()}"
         self._gauge_period_ns = int(telemetry.flush_interval_s() * 1e9)
         self._gauges_at = 0
         m, tags = _engine_metrics(), {"deployment": deployment}
@@ -433,12 +479,62 @@ class InferenceEngine:
     def start(self) -> None:
         if self._thread is None:
             self._thread = threading.Thread(
-                target=self._loop, name="llm-engine", daemon=True
+                target=self._run, name="llm-engine", daemon=True
             )
             self._thread.start()
 
+    def _run(self) -> None:
+        """The loop's thread: the start's last stamp, its record (telemetry
+        on) and its one log line, then the loop."""
+        st = self._start
+        st["t_ready"] = time.time_ns()
+        if self._tel is not None:
+            self._tel.record_loop(self._stem, ("b", *(st[k] for k in LLM_START_FIELDS)))
+        spent = {stage: sum(by.values()) for stage, by in self._compile_seconds().items()}
+
+        def s(first: str, last: str) -> float:
+            return (st[last] - st[first]) / 1e9
+
+        logger.info(
+            "%s: ready %.2f s after its start: backend %.2f s, weights %.2f, placed %s in %.2f, the pool's %.3f GB "
+            "(%d blocks of %d B, %d state rows of %d B) in %.2f; of it %.2f s tracing and lowering and %.2f s "
+            "compiling (%.2f loading from the compile cache)",
+            self.deployment, s("t_init", "t_ready"), s("t_init", "t_backend"), s("t_backend", "t_params"),
+            self.placed, s("t_params", "t_placed"), st["pool_bytes"] / 1e9, self.cfg.num_blocks,
+            self._bytes_per_block, self._pool_rows, self._state_bytes, s("t_placed", "t_pool"),
+            spent["trace"] + spent["lower"], spent["compile"], spent["cache_load"],
+        )
+        self._loop()
+
+    def note_compile(self, t_ns: int, seconds: float, stage: str, program: Optional[str], ident: int) -> None:
+        """One compile event of this process (``sampler.set_compile_sink``),
+        on the thread that compiled: the operator's series and, telemetry on,
+        a ``compile`` record (``looplog.COMPILE_FIELDS``). A lock and an
+        append each; never from the loop unless the loop compiled."""
+        self._m_compile[stage].inc(seconds)
+        if self._tel is None:
+            return
+        thread = self._thread
+        if thread is None:
+            where = "init" if ident == self._init_thread else "other"
+        else:
+            where = "loop" if ident == thread.ident else "other"
+        rec = ("c", t_ns, seconds, stage, program, self.decode_steps, where)
+        self._compiles.append(rec)
+        self._tel.record_loop(self._stem, rec)
+
+    def _compile_seconds(self) -> Dict[str, Dict[str, float]]:
+        """The held ``compile`` records' seconds, stage -> program -> sum."""
+        out: Dict[str, Dict[str, float]] = {stage: {} for stage in COMPILE_STAGES.values()}
+        for rec in self._compiles.copy():
+            d = dict(zip(COMPILE_FIELDS, rec[1:]))
+            by = out[d["stage"]]
+            by[d["program"]] = by.get(d["program"], 0.0) + d["seconds"]
+        return out
+
     def shutdown(self, timeout_s: float = 10.0) -> None:
         """Stop the loop and fail any unfinished streams (typed)."""
+        sampler.clear_compile_sink(self)
         with self._cv:
             self._stop = True
             self._cv.notify_all()
@@ -620,7 +716,11 @@ class InferenceEngine:
         ``prefill`` now ends when the first token is read, which is after the
         steps that were in flight before the prefill, and ``prefill_stall`` is
         the host's time to enqueue the iteration's prefills.
-        Empty with ``telemetry_enabled`` off, but for ``placed``: the stacked
+        ``start``: the stamps of the engine's start (``looplog.LLM_START_FIELDS``;
+        ``t_ready`` 0 until the loop's thread runs) and ``compile_s``, the
+        seconds of the ``compile`` records held, stage -> program -> sum.
+        Empty with ``telemetry_enabled`` off, but for ``start``'s stamps and
+        ``placed``: the stacked
         tensors the engine re-laid on the device at start, name ->
         ``major_to_minor`` (``paged.place_params``; empty where it placed none),
         and ``state_rows_total|used`` and ``state_bytes`` (a kind that keeps a
@@ -670,6 +770,7 @@ class InferenceEngine:
             "moe": dict(zip(LLM_MOE_FIELDS, routed[-1])) if routed else None,
             # the stacked tensors re-laid on the device at start, name -> major_to_minor
             "placed": dict(self.placed),
+            "start": {**self._start, "compile_s": self._compile_seconds()},
             **self._state_rows(),  # as ``kv_stats()`` has them now
         }
 

@@ -501,3 +501,210 @@ def test_loop_summary_tool_reads_the_share_ahead_and_a_newcomers_wait(tmp_path):
     assert got["first_token_ms"] == {"count": 1, "mean_ms": 35.0, "median_ms": 35.0, "p90_ms": 35.0, "max_ms": 35.0}
     assert got["queue_wait_ms"]["mean_ms"] == 30.0
     assert got["windows_per_layer_step"] == pytest.approx(13 / 12)
+
+
+# -- how a loop came to run: the start's stamps and the compile records -----------
+
+
+SERVER_ENGINE = dict(block_size=4, num_blocks=64, max_batch=3, max_blocks_per_seq=16, max_waiting=16)
+STAGES = {"trace", "lower", "compile", "cache_load"}
+
+
+def _server(name):
+    from ray_tpu.serve.llm.deployment import TINY_MODEL, LLMServer
+
+    return LLMServer(TINY_MODEL, SERVER_ENGINE, deployment=name)
+
+
+def _compile_records(srv):
+    return [dict(zip(looplog.COMPILE_FIELDS, r[1:])) for r in srv._engine._compiles.copy()]
+
+
+def test_a_server_leaves_one_start_record_with_monotone_stamps(ray_start_regular):
+    before = time.time_ns()
+    srv = _server("start-rec")
+    try:
+        assert len(srv([3, 1, 4], 3)) == 3  # the loop's thread has run by now
+        start = srv.loop_stats()["start"]
+    finally:
+        srv._engine.shutdown()
+    stamps = [start[k] for k in looplog.LLM_START_FIELDS[:6]]
+    assert looplog.LLM_START_FIELDS[:6] == ("t_init", "t_backend", "t_params", "t_placed", "t_pool", "t_ready")
+    assert before <= stamps[0] and stamps == sorted(stamps) and stamps[-1] <= time.time_ns()
+    assert start["placed"] == 0  # on the CPU nothing is re-laid
+    assert start["pool_bytes"] == SERVER_ENGINE["num_blocks"] * srv._engine._bytes_per_block > 0
+    ray_tpu.timeline()  # a cluster-wide flush
+    recs = _read_loops(ray_start_regular.node.session_dir, f"llm-start-rec-{os.getpid()}")
+    (on_disk,) = [r for r in recs if r["kind"] == "llm_start"]
+    assert on_disk == {"kind": "llm_start", **{k: start[k] for k in looplog.LLM_START_FIELDS}}
+    # the weights' jit ran before the engine existed, on the constructor's thread: kept, and written under its stem
+    init = [r for r in recs if r["kind"] == "compile" and r["where"] == "init"]
+    assert init and all(start["t_init"] <= r["t"] <= start["t_ready"] and r["step"] == 0 for r in init)
+    assert any(r["stage"] == "compile" and r["t"] <= start["t_params"] for r in init)
+
+
+def test_the_first_request_leaves_compile_records_that_name_its_programs(ray_start_regular):
+    srv = _server("start-compile")
+    try:
+        assert len(srv([3, 1, 4], 3)) == 3
+        recs = _compile_records(srv)
+        start = srv.loop_stats()["start"]
+    finally:
+        srv._engine.shutdown()
+    assert recs and all(set(r) == set(looplog.COMPILE_FIELDS) and r["stage"] in STAGES and r["seconds"] >= 0 for r in recs)
+    loop = [r for r in recs if r["where"] == "loop"]
+    for program in ("prefill", "decode_step_greedy"):
+        # traced under its own name, lowered and compiled as jax's ``jit(name)``
+        assert {r["stage"] for r in loop if r["program"] in (program, f"jit({program})")} == {"trace", "lower", "compile"}
+    assert all(r["step"] == 0 for r in loop)  # before the first decode step went out
+    assert {r["where"] for r in recs} == {"init", "loop"}
+    # a jnp function traced inside a program is part of the program's seconds, not a record
+    assert not any(r["program"] in ("_where", "_einsum", "multiply") for r in recs)
+    by_stage = {stage: sum(r["seconds"] for r in recs if r["stage"] == stage) for stage in STAGES}
+    assert {s: sum(by.values()) for s, by in start["compile_s"].items()} == pytest.approx(by_stage)
+    # the operator's series counts the same seconds, by stage
+    series = _series("ray_tpu_llm_compile_seconds_total", "start-compile")
+    assert sum(series.values()) == pytest.approx(sum(by_stage.values()))
+    assert any('"compile"' in k for k in series)
+    assert "ray_tpu_llm_compile_seconds_total{" in metrics.prometheus_text()
+    ray_tpu.timeline()
+    on_disk = [r for r in _read_loops(ray_start_regular.node.session_dir, f"llm-start-compile-{os.getpid()}")
+               if r["kind"] == "compile"]
+    assert [{k: r[k] for k in looplog.COMPILE_FIELDS} for r in on_disk] == recs
+
+
+def test_a_warmed_bucket_compiles_nothing_and_a_new_one_says_at_which_step(ray_start_regular):
+    srv = _server("start-bucket")
+    try:
+        assert len(srv([3, 1, 4], 4)) == 4
+        warmed = len(_compile_records(srv))
+        assert len(srv([2, 7, 1], 4)) == 4  # the same bucket, the same decode program
+        assert len(_compile_records(srv)) == warmed
+        steps = srv._engine.decode_steps
+        assert len(srv(list(range(1, 20)), 4)) == 4  # 19 tokens: the bucket of 32, not yet met
+        late = _compile_records(srv)[warmed:]
+    finally:
+        srv._engine.shutdown()
+    # what ``compiles_in_window`` counts: a program compiled while the replica serves
+    assert {r["stage"] for r in late} >= {"trace", "lower", "compile"}
+    assert all(r["step"] == steps > 0 and r["where"] == "loop" and "prefill" in r["program"] for r in late)
+
+
+def test_a_trainers_compile_records_lie_in_the_step_that_counted_them(ray_start_regular, tmp_path):
+    from ray_tpu.train import JaxTrainer, RunConfig, ScalingConfig
+
+    def loop(config=None):
+        import jax
+        import jax.numpy as jnp
+
+        for i in range(5):
+            # a program a step: each is traced, lowered and compiled inside its step
+            jax.jit(lambda x, i=i: (x * (i + 2.0)).sum())(jnp.ones(8)).block_until_ready()
+            train.report({"i": i})
+
+    res = JaxTrainer(
+        loop, scaling_config=ScalingConfig(num_workers=1),
+        run_config=RunConfig(storage_path=str(tmp_path), name="loop_compiles"),
+    ).fit()
+    assert res.error is None
+    ray_tpu.timeline()
+    lines = _read_loops(ray_start_regular.node.session_dir, "train-loop_compiles-rank0")
+    steps = sorted((r for r in lines if r["kind"] == "train_step"), key=lambda r: r["step"])
+    compiles = [r for r in lines if r["kind"] == "compile"]
+    assert len(steps) == 5 and compiles and all(set(r) == {"kind", *looplog.COMPILE_FIELDS} for r in compiles)
+    assert all(r["stage"] in STAGES and r["where"] == "loop" for r in compiles)
+    # the listener is in from the second step at the latest (the timer probes at every report)
+    assert {r["step"] for r in compiles} >= {1, 2, 3, 4}
+    for rec in compiles:
+        (step,) = [s for s in steps if s["t0_ns"] <= rec["t"] < s["t2_ns"]]
+        assert step["step"] == rec["step"] + 1  # steps done when it landed: the step it landed in is the next to close
+    for s in steps:
+        mine = [r for r in compiles if s["t0_ns"] <= r["t"] < s["t2_ns"]]
+        assert sum(r["seconds"] for r in mine) <= s["stages"]["compile_ms"] / 1e3 + 1e-6
+        if s["step"] >= 2:
+            assert {r["stage"] for r in mine if "<lambda>" in r["program"]} == {"trace", "lower", "compile"}
+
+
+def test_with_telemetry_off_a_start_leaves_stamps_and_no_record():
+    rt = ray_tpu.init(num_cpus=1, _system_config={"telemetry_enabled": False}, ignore_reinit_error=True)
+    try:
+        buf = telemetry.get_buffer()
+        before = sum(len(v) for v in buf._loops.values())
+        srv = _server("start-off")
+        try:
+            assert len(srv([5, 6, 7], 4)) == 4
+            start = srv.loop_stats()["start"]
+        finally:
+            srv._engine.shutdown()
+        assert srv._engine.decode_steps >= 3 and _compile_records(srv) == []
+        assert all(by == {} for by in start["compile_s"].values())
+        stamps = [start[k] for k in looplog.LLM_START_FIELDS[:6]]
+        assert stamps[0] > 0 and stamps == sorted(stamps)  # plain stores either way
+        assert sum(len(v) for v in buf._loops.values()) == before
+        assert not os.path.exists(os.path.join(rt.node.session_dir, "loops"))
+    finally:
+        ray_tpu.shutdown()
+
+
+def test_loop_summary_tool_prints_where_a_start_went(tmp_path):
+    import subprocess
+
+    s = 1_000_000_000
+    log = looplog.LoopLog(str(tmp_path))
+    start = ("b", 10 * s, 12 * s, 17 * s, 18 * s, 18 * s + s // 2, 18 * s + s // 2 + 1000, 3, 4096)
+    compiles = [
+        ("c", 13 * s, 0.5, "trace", "<lambda>", 0, "init"), ("c", 14 * s, 1.0, "lower", "jit(<lambda>)", 0, "init"),
+        ("c", 16 * s, 0.25, "cache_load", None, 0, "init"), ("c", 16 * s, 2.0, "compile", "jit(<lambda>)", 0, "init"),
+        ("c", 19 * s, 0.75, "trace", "prefill", 0, "loop"), ("c", 20 * s, 0.5, "lower", "jit(prefill)", 0, "loop"),
+        ("c", 21 * s, 1.5, "compile", "jit(prefill)", 0, "loop"),
+        ("c", 40 * s, 0.125, "compile", "jit(prefill)", 9, "loop"),  # after the batch was full: a bucket not warmed
+    ]
+    fields = looplog.LLM_STEP_FIELDS
+    steps = []
+    for i, live in ((1, 1), (2, 2), (3, 2)):
+        rec = dict.fromkeys(fields, 0)
+        rec.update(step=i, t_loop=(21 + i) * s, t_result=(21 + i) * s + 1000, live=live)
+        steps.append(("s", *(rec[k] for k in fields)))
+    log.ingest({"llm-x-1": [*compiles[:4], start, *compiles[4:7], *steps, compiles[7]]})
+    log.close()
+    tool = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "tools", "loop_summary.py")
+    out = subprocess.run([sys.executable, tool, str(tmp_path / "loops"), "--skip-s", "0"],
+                         capture_output=True, text=True, check=True).stdout
+    got = json.loads(out)["start"]
+    assert got["phases_s"] == {"init_to_backend": 2.0, "backend_to_params": 5.0, "params_to_placed": 1.0,
+                               "placed_to_pool": 0.5, "pool_to_ready": 1e-6}
+    assert got["placed"] == 3 and got["pool_bytes"] == 4096 and got["events"] == 8
+    assert got["seconds_by_stage"] == {"trace": 1.25, "lower": 1.5, "cache_load": 0.25, "compile": 3.625}
+    assert got["lowering_s"] == {"<lambda>": 1.5, "prefill": 1.25} and list(got["lowering_s"]) == ["<lambda>", "prefill"]
+    assert got["compile_s"] == {"<lambda>": 2.0, "prefill": 1.625}
+    assert got["compiles_after_full"] == ["jit(prefill)"]
+    # a run of a program older than the two kinds: no such object
+    old = looplog.LoopLog(str(tmp_path / "old"))
+    old.ingest({"llm-x-1": steps})
+    old.close()
+    out = subprocess.run([sys.executable, tool, str(tmp_path / "old" / "loops"), "--skip-s", "0"],
+                         capture_output=True, text=True, check=True).stdout
+    assert json.loads(out)["start"] is None
+
+
+def test_a_requests_compile_span_names_its_program(ray_start_regular):
+    """``ray_tpu.trace`` shows *which* program a traced caller's compile was:
+    the ``jax:*`` span under the caller's span carries jax's ``fun_name``."""
+    from ray_tpu._private import sampler
+    from ray_tpu.util import tracing
+
+    assert sampler.install_jax_hooks()
+    with traced_section("serve:replica:stand-in"):
+        ctx = tracing.get_current_context()
+        jax.jit(lambda x: x * 3.0 + 1, inline=False)(jnp.ones(5)).block_until_ready()
+    named = []
+    for _ in range(20):
+        t = ray_tpu.trace(ctx.trace_id)
+        named = [s for s in t.spans.values() if (s.name or "").startswith("jax:") and "program" in (s.extra or {})]
+        if {s.name.rsplit(".", 1)[-1] for s in named} >= {"jaxpr_trace_duration", "backend_compile_duration"}:
+            break
+        time.sleep(0.2)
+    by_event = {s.name.rsplit(".", 1)[-1]: s for s in named}
+    assert by_event["jaxpr_trace_duration"].extra["program"] == "<lambda>"
+    assert by_event["backend_compile_duration"].extra["program"] == "jit(<lambda>)"
+    assert all(s.parent_id == ctx.span_id for s in named)

@@ -510,14 +510,17 @@ def test_the_engine_places_once_and_says_what_it_placed(monkeypatch, caplog):
     cfg = FORMS["llama"]
     given = T.init_params(jax.random.PRNGKey(7), cfg)
     ecfg = EngineConfig(block_size=BLOCK, num_blocks=BLOCKS, max_batch=2, max_blocks_per_seq=MAX_BLOCKS)
-    with monkeypatch.context() as m, caplog.at_level(logging.INFO, logger="ray_tpu.serve.llm.engine"):
+    caplog.set_level(logging.INFO, logger="ray_tpu.serve.llm.engine")
+    with monkeypatch.context() as m:
         m.setattr(jax, "default_backend", lambda: "tpu")
         eng = InferenceEngine(given, cfg, ecfg, deployment="placed")
     try:
         assert eng.placed == HEADS_MAJOR and eng.loop_stats()["placed"] == HEADS_MAJOR
+        assert eng.loop_stats()["start"]["placed"] == 3
         assert eng.params["wq"].format.layout.major_to_minor == (0, 2, 1, 3) and eng.params["wo"] is given["wo"]
-        said = [r.getMessage() for r in caplog.records if ": placed {" in r.getMessage()]  # the deployment's name is "placed" too
-        assert len(said) == 1 and "'wq': (0, 2, 1, 3)" in said[0]
+        assert eng.submit([1], max_new_tokens=1).tokens()  # the loop's thread has run: it writes the start's one line
+        said = [r.getMessage() for r in caplog.records if ", placed {" in r.getMessage()]  # the deployment's name is "placed" too
+        assert len(said) == 1 and said[0].startswith("placed: ready ") and "'wq': (0, 2, 1, 3)" in said[0]
         prompt = [int(t) for t in TOKENS[:6]]
         want = np.asarray(G.generate(T.init_params(jax.random.PRNGKey(7), cfg), prompt, cfg, max_new_tokens=12))[0]
         assert eng.submit(prompt, max_new_tokens=12).tokens() == want.tolist()

@@ -14,8 +14,16 @@ layers, the windows of held rows its grouped matmuls walked a layer a decode
 step between that time's first and last ``llm_moe`` record
 (``windows_per_layer_step``: 1 = no call spilled past its first window; None
 without two records that carry the count). Records older than a field read 0
-there. Reads with the standard library alone; newest session under the
-temporary directory where no directory is given.
+there. And ``start``, how the loop came to run (None of a program that leaves
+no such record): the phases of the engine's ``llm_start`` record in seconds
+(``init_to_backend`` .. ``pool_to_ready``), what it placed and its pool's
+bytes; from the ``compile`` records (the engine's or the trainer's) the
+seconds by stage, ``lowering_s`` (tracing and lowering) and ``compile_s`` (the
+backend's, loads from the compile cache inside it) by program, heaviest first;
+and ``compiles_after_full``, the programs compiled after the first iteration
+with every slot dispatched: in a replica whose shapes were warmed, none.
+Reads with the standard library alone; newest session under the temporary
+directory where no directory is given.
 """
 
 from __future__ import annotations
@@ -55,6 +63,37 @@ def windows_per_layer_step(moe_recs: list, t0: int, t1: int):
     return (last["windows"] - first["windows"]) / layer_steps if layer_steps > 0 else None
 
 
+START_STAMPS = ("t_init", "t_backend", "t_params", "t_placed", "t_pool", "t_ready")
+
+
+def start_summary(recs: dict):
+    starts, compiles = recs["llm_start"], recs["compile"]
+    if not starts and not compiles:
+        return None
+    out = {}
+    if starts:
+        st = starts[0]
+        out["phases_s"] = {f"{a[2:]}_to_{b[2:]}": (st[b] - st[a]) / 1e9 for a, b in zip(START_STAMPS, START_STAMPS[1:])}
+        out.update(placed=st["placed"], pool_bytes=st["pool_bytes"])
+    by_stage, lowering, compiling = {}, {}, {}
+    for r in compiles:
+        by_stage[r["stage"]] = by_stage.get(r["stage"], 0.0) + r["seconds"]
+        # jax names a program ``f`` while it traces it and ``jit(f)`` from then on
+        name = (r["program"] or "").removeprefix("jit(").removesuffix(")")
+        into = lowering if r["stage"] in ("trace", "lower") else compiling if r["stage"] == "compile" else None
+        if into is not None:
+            into[name] = into.get(name, 0.0) + r["seconds"]
+    out.update(events=len(compiles), seconds_by_stage=by_stage,
+               lowering_s=dict(sorted(lowering.items(), key=lambda kv: -kv[1])),
+               compile_s=dict(sorted(compiling.items(), key=lambda kv: -kv[1])))
+    live = [r for r in recs["llm_step"] if r["live"]]
+    if live:
+        full = max(r["live"] for r in live)
+        t_full = min(r["t_loop"] for r in live if r["live"] == full)
+        out["compiles_after_full"] = [r["program"] for r in compiles if r["stage"] == "compile" and r["t"] > t_full]
+    return out
+
+
 def summarise(recs: dict, skip_s: float) -> dict:
     steps = [r for r in recs["llm_step"] if r["live"]]
     if not steps:
@@ -83,7 +122,8 @@ def main() -> int:
     ap.add_argument("--skip-s", type=float, default=4.0)
     args = ap.parse_args()
     where = args.loops or newest_loops_dir()
-    print(json.dumps({"loops": where, **summarise(load_records(where), args.skip_s)}))
+    recs = load_records(where)
+    print(json.dumps({"loops": where, **summarise(recs, args.skip_s), "start": start_summary(recs)}))
     return 0
 
 
