@@ -81,62 +81,113 @@ def _can_use_flash(q, k) -> bool:
 
 
 def _tuned_block_sizes(head_dim: int, q_seq: int, kv_seq: int):
-    """Measured on v5e: the library defaults underfill the MXU at both ends
-    of the head_dim range. head_dim 64: 512 blocks throughout beat defaults
-    and the einsum path (~1.4x at seq 1k). head_dim 256 (GPT-J geometry):
-    block_q 512 / block_k 1024 in all passes cuts the 6B-shaped train step
-    ~19% vs defaults (957 -> 773 ms, seq 2048, with dots-saveable remat).
-    None = library defaults."""
+    """The library's forward kernel's blocks where no gradient is taken: 512
+    x 512 for head_dim 256 (GPT-J) and 64; None = the library's defaults (128
+    throughout), which head_dim 128 keeps (the hybrid's prefill of 128-512
+    positions, not measured alone). Measured on a v5e, kernels alone at (8, 16,
+    2048, 256) causal, tree of 2026-09-30 (PR 40), ms a call with the
+    statistics kept, (block_q, block_k_major, block_k): (512, 512, 512) 4.26,
+    (1024, 1024, 512) 4.24, (1024, 1024, 1024) 4.28, (512, 1024, 1024) 4.46
+    (what ran until then: 6 of 8 block pairs where 512 x 512 runs 10 of 16),
+    (512, 1024, 512) 4.58, (256, 512, 512) 4.99, (512, 256, 256) 4.91, (256,
+    256, 256) 5.79; 2048 on either side does not fit VMEM; (512, 1024, 1024)
+    without the statistics 3.69. At head_dim 128 (8, 16, 2048, 128): defaults
+    13.17, (512, 512, 512) 3.25, (1024, 1024, 512) 3.15. head_dim 64 at 512
+    beat the defaults and the einsum path ~1.4x at 1,024 positions (measured
+    before the benchmark existed)."""
     from jax.experimental.pallas.ops.tpu.flash_attention import BlockSizes
 
-    def pick(seq: int, *prefs: int):
-        # largest preferred block that tiles the sequence (the kernel
-        # requires block | seq); a short sequence is its own block
-        for p in prefs:
+    def pick(seq: int):
+        # the larger block that tiles the sequence (the kernels require
+        # block | seq); a short sequence is its own block
+        for p in (512, 256):
             if seq % p == 0:
                 return p
-        return seq if seq <= prefs[0] else None
+        return seq if seq <= 512 else None
 
-    if head_dim == 256:
-        bq = pick(q_seq, 512, 256)
-        bk = pick(kv_seq, 1024, 512, 256)
-    elif head_dim == 64:
-        bq = pick(q_seq, 512, 256)
-        bk = pick(kv_seq, 512, 256)
-    else:
-        return None
-    if bq is None or bk is None:
+    bq, bk = pick(q_seq), pick(kv_seq)
+    if head_dim not in (64, 256) or bq is None or bk is None:
         return None  # library defaults
-    return BlockSizes(
-        block_q=bq,
-        block_k_major=bk,
-        block_k=bk,
-        block_b=1,
-        block_q_major_dkv=bq,
-        block_k_major_dkv=bk,
-        block_k_dkv=bk,
-        block_q_dkv=bq,
-        block_k_major_dq=bk,
-        block_k_dq=bk,
-        block_q_dq=bq,
-    )
+    return BlockSizes(block_q=bq, block_k_major=bk, block_k=bk, block_b=1)
+
+
+# the one name the forward rule gives what it keeps for the backward pass: a
+# ``jax.checkpoint`` policy that saves it (``transformer.forward``'s "dots")
+# does not run the forward kernel again when it recomputes a block
+FLASH_RESIDUALS = "flash_residuals"
 
 
 def _flash(q, k, v, *, causal):
+    with jax.named_scope("flash"):  # the kernels keep the names they give themselves
+        return _flash_bshd(q, k, v, causal)
+
+
+def _heads_major(*xs):
+    """(batch, seq, heads, head_dim) <-> (batch, heads, seq, head_dim), the
+    layout the Pallas kernels take."""
+    return tuple(jnp.swapaxes(x, 1, 2) for x in xs)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _flash_bshd(q, k, v, causal):
+    """Flash attention over (batch, seq, heads, head_dim). Without a gradient
+    it is the library's forward kernel and keeps nothing."""
     from jax.experimental.pallas.ops.tpu.flash_attention import flash_attention
 
-    # pallas kernel wants BHSD
-    qt, kt, vt = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))
-    with jax.named_scope("flash"):  # the kernels keep the names they give themselves
-        out = flash_attention(
-            qt,
-            kt,
-            vt,
-            causal=causal,
-            sm_scale=1.0 / (q.shape[-1] ** 0.5),
-            block_sizes=_tuned_block_sizes(q.shape[-1], q.shape[1], k.shape[1]),
-        )
-    return jnp.swapaxes(out, 1, 2)
+    q, k, v = _heads_major(q, k, v)
+    (out,) = _heads_major(flash_attention(
+        q, k, v, causal=causal, sm_scale=q.shape[-1] ** -0.5,
+        block_sizes=_tuned_block_sizes(q.shape[3], q.shape[2], k.shape[2]),
+    ))
+    return out
+
+
+def _training_blocks(q, k):
+    """(block_q, block_k) of the repo's own kernels: 512 where it tiles the
+    sequence, else 256 or 128. Measured as ``_tuned_block_sizes``' (same
+    shapes, day and chip), ms a call. head_dim 256, forward: (512, 512) 3.42,
+    (256, 512) 3.49, (512, 256) 3.50, (256, 256) 3.70, (1024, 512) 3.81, (512,
+    1024) 3.82, (1024, 1024) 3.92 (the library's best 4.24); backward with
+    ``di``'s sum: (256, 256) 6.37, (512, 512) 6.41, (512, 256) 6.51, (256, 512)
+    6.52, (1024, 1024) 7.15, (512, 1024) 7.21, (1024, 512) 7.27, (128, 512)
+    8.07, (2048, 512) 8.85 (the library's two passes 12.99 at (512, 1024),
+    12.59 at (512, 512)). head_dim 128, forward: (512, 512) 2.47, (256, 512)
+    2.50, (1024, 512) 2.56, (512, 256) 2.95, (256, 256) 3.51; backward: (512,
+    512) 3.81, (1024, 512) 4.26, (512, 256) 4.46, (256, 512) 4.62, (256, 256)
+    5.62, (128, 128) 9.08 (the library's 8.83 at 512, 29.11 at its defaults)."""
+    return tuple(next(b for b in (512, 256, 128) if x.shape[2] % b == 0) for x in (q, k))
+
+
+def _flash_fwd(q, k, v, causal):
+    """Under a gradient: the forward kernel that keeps the softmax's
+    log-sum-exp, (B, H, S) float32, beside its output. Both are named for a
+    ``jax.checkpoint`` policy to keep."""
+    from jax.ad_checkpoint import checkpoint_name
+
+    from ray_tpu.ops import flash_kernels
+
+    qt, kt, vt = _heads_major(q, k, v)
+    block_q, block_k = _training_blocks(qt, kt)
+    o, lse = flash_kernels.flash_attention_fwd(
+        qt, kt, vt, causal=causal, sm_scale=q.shape[-1] ** -0.5, block_q=block_q, block_k=block_k
+    )
+    out, lse = checkpoint_name((*_heads_major(o), lse), FLASH_RESIDUALS)
+    return out, (q, k, v, out, lse)
+
+
+def _flash_bwd(causal, residuals, dout):
+    from ray_tpu.ops import flash_kernels
+
+    q, k, v, out, lse = residuals
+    di = jnp.swapaxes(jnp.sum(out.astype(jnp.float32) * dout.astype(jnp.float32), axis=-1), 1, 2)  # (B, H, S)
+    q, k, v, do = _heads_major(q, k, v, dout)
+    block_q, block_k = _training_blocks(q, k)
+    return _heads_major(*flash_kernels.flash_attention_bwd(
+        q, k, v, do, lse, di, causal=causal, sm_scale=q.shape[-1] ** -0.5, block_q=block_q, block_k=block_k
+    ))
+
+
+_flash_bshd.defvjp(_flash_fwd, _flash_bwd)
 
 
 def _einsum_attention(
