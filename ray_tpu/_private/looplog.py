@@ -47,6 +47,11 @@ LLM_STEP_FIELDS = (
     "ahead",  # decode steps still in flight when this iteration dispatched its own: 1 = the loop ran ahead
     "overrun",  # rows of the step(s) retired in this iteration whose sequence had already ended (an EOS seen a step late)
 )
+# ... of a model kind that keeps a ring of window rows a sequence (``paged_ring``): one field more, which the
+# records of every other kind do not carry
+LLM_STEP_RING_FIELDS = LLM_STEP_FIELDS + (
+    "ring_rows",  # live rows of the dispatched sequences' rings, the sum of min(length, window): what a window layer reads
+)
 # one finished, failed or shed request
 LLM_REQUEST_FIELDS = (
     "request",
@@ -107,7 +112,7 @@ COMPILE_STAGES = {
     "/jax/core/compile/backend_compile_duration": "compile",
     "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load",
 }
-_KINDS = {"s": ("llm_step", LLM_STEP_FIELDS), "r": ("llm_request", LLM_REQUEST_FIELDS),
+_KINDS = {"s": ("llm_step", LLM_STEP_RING_FIELDS), "r": ("llm_request", LLM_REQUEST_FIELDS),
           "m": ("llm_moe", LLM_MOE_FIELDS), "b": ("llm_start", LLM_START_FIELDS),
           "c": ("compile", COMPILE_FIELDS)}
 
@@ -123,7 +128,7 @@ def encode(rec) -> Optional[str]:
     """An engine record tuple (tag, field values...) as a JSON line."""
     try:
         kind, fields = _KINDS[rec[0]]
-        return json.dumps({"kind": kind, **dict(zip(fields, rec[1:]))})
+        return json.dumps({"kind": kind, **dict(zip(fields, rec[1:]))})  # a step of a kind without rings: a field fewer
     except (KeyError, IndexError, TypeError, ValueError):
         return None  # telemetry batches are untrusted
 

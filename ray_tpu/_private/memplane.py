@@ -394,6 +394,13 @@ def _get_kv_gauges() -> Dict[str, object]:
                 "(0 for every other kind)",
                 tag_keys=("deployment",),
             )
+            _kv_gauges["ring_bytes"] = Gauge(
+                "ray_tpu_kv_ring_bytes",
+                "device bytes the state rows' rings of window-attention rows "
+                "hold per LLM deployment, null row included (0 for a model "
+                "kind without window layers)",
+                tag_keys=("deployment",),
+            )
     return _kv_gauges
 
 
@@ -416,5 +423,7 @@ def record_kv_occupancy(stats: Dict[str, object]) -> None:
             # pool bytes include the reserved null block
             gauges["bytes_total"].set(float((total + 1) * bpb), tags=tags)
         gauges["state_rows_used"].set(float(stats.get("state_rows_used", 0)), tags=tags)
+        rows = int(stats.get("state_rows_total", 0))
+        gauges["ring_bytes"].set(float((rows + 1 if rows else 0) * int(stats.get("ring_bytes", 0))), tags=tags)
     except Exception:
         pass

@@ -36,6 +36,7 @@ PAGED_KINDS = {
     "longcat": ("ray_tpu.models.longcat", "LongcatConfig"),
     "kimi_k2": ("ray_tpu.models.kimi", "KimiConfig"),
     "olmo_hybrid": ("ray_tpu.models.olmo_hybrid", "OlmoHybridConfig"),
+    "phi4flash": ("ray_tpu.models.phi4flash", "Phi4FlashConfig"),
 }
 
 

@@ -15,7 +15,10 @@ four things, and may give a fifth:
     paged_layer(cfg, params, step) -> layer(x (B, S, D), pool, li) -> (x, pool)
         or, for a model of unlike layers, its sections in order:
         [(layer, how many layers it covers), ...]; a section whose body is
-        several layers a call: (layer, how many it covers, how many a call)
+        several layers a call: (layer, how many it covers, how many a call);
+        one that a prefill runs on its last position alone: (layer, how many
+        it covers, how many a call, True); and, for a kind whose layers hand
+        on a value that is not the residual, ``Carried(sections, leaf)``
     init_paged_pool(cfg, num_blocks, block_size) -> pool
     paged_block_bytes(cfg, block_size) -> bytes one block holds over all layers
     init_params(key, cfg) -> params with ``embed``, ``final_norm``, ``unembed``
@@ -24,7 +27,8 @@ four things, and may give a fifth:
         the device}, for the tensors whose default layout the layer reads badly
 
 over a config with ``n_layers`` and ``max_seq_len`` (and ``rms_norm_eps``,
-where the final norm's is not ``rms_norm``'s own). **A model of unlike
+where the final norm's is not ``rms_norm``'s own; or ``final_norm(params, x)``,
+where the final norm is not an RMSNorm at all). **A model of unlike
 layers** (Kimi-K2: one dense layer, then expert layers) hands back a section
 a kind of layer, and ``forward_paged`` runs one scan a section with ``(x,
 pool)`` carried from each into the next and the layer index running on
@@ -43,8 +47,21 @@ recurrent layers' state) finds each sequence's state row in the block table's
 last column: the programs made with ``state_rows=True`` cut it off the table
 and hand it to the kind as ``Step.state_rows`` (row 0 is the null row, as
 block 0 is the null block: an inactive slot's all-zero table names both).
+**A kind whose layers hand on a value beside the residual**
+(a state-space layer's scan output that later layers gate: ``models/phi4flash.py``)
+hands back ``Carried(sections, leaf)``: the leaf (B, S, ...) rides in the
+scans' carry after ``x`` and the pool, and the kind's layers take and return
+it, ``layer(x, pool, leaf, li) -> (x, pool, leaf)``. Every other kind's carry
+is ``(x, pool)`` as it always was. **A section that a prefill runs on its
+last position alone** (layers whose every input a decode step could give them:
+attention over a cache that an earlier section wrote, a gate on the carried
+leaf) says so with a fourth entry: ahead of its scan a prefill cuts ``x`` and
+the leaf down to position ``last`` and asks the kind for its sections once more
+over a ``Step`` of that one position (``length`` cached positions, the
+position's own slot), which is a decode step's; the head then norms the one
+position it is left with. A decode step is untouched.
 ``paged_layer`` is called
-once a program, outside the scan over layers, and what it computes there is
+once a program (twice in a prefill with such a section), outside the scan over layers, and what it computes there is
 computed once a call: XLA does not lift it out of the loop by itself (a rotary
 table built inside the layer was rebuilt 28 times a step: PERF.md section 6, PR
 31). **How a layer gets its weights is decided here:** ``params`` reaches the
@@ -68,7 +85,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
-from typing import NamedTuple, Optional
+from typing import Any, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -94,6 +111,14 @@ class Step(NamedTuple):
     live: jax.Array  # (B * S,) bool: the rows that are tokens
     lengths: jax.Array  # (B,): a decode step's sequences count positions [0, position]; an inactive slot none
     state_rows: Optional[jax.Array] = None  # (B,): each sequence's state row, for a kind that keeps one; 0 the null row
+
+
+class Carried(NamedTuple):
+    """A kind's sections with a leaf of its own in the scans' carry (module
+    docstring): ``leaf`` (B, S, ...) as the first section finds it."""
+
+    sections: list
+    leaf: Any
 
 
 @contextlib.contextmanager
@@ -153,7 +178,8 @@ def head(cfg, params, x, last=None):
     with jax.named_scope("head"):
         if last is not None:
             x = jax.lax.dynamic_slice_in_dim(x, last, 1, axis=1)
-        x = rms_norm(x, params["final_norm"], getattr(cfg, "rms_norm_eps", 1e-6))
+        norm = getattr(cfg, "final_norm", None)  # the kind's, where it is not an RMSNorm
+        x = norm(params, x) if norm else rms_norm(x, params["final_norm"], getattr(cfg, "rms_norm_eps", 1e-6))
         unembed = params.get("unembed")
         if unembed is None:
             unembed = params["embed"].T
@@ -173,32 +199,53 @@ def forward_paged(paged_layer, cfg, params, tokens, positions, write_mask, block
     rows = None
     if state_rows:
         block_tables, rows = block_tables[:, :-1], block_tables[:, -1]
-    pidx = jnp.clip(positions // block_size, 0, block_tables.shape[1] - 1)
-    slot = jnp.take_along_axis(block_tables, pidx, axis=1) * block_size + positions % block_size
-    null_slot = jnp.arange(b * s, dtype=slot.dtype) % block_size
-    live = write_mask.reshape(-1)
-    sections = paged_layer(cfg, params, Step(
-        positions, block_tables, block_size, jnp.where(live, slot.reshape(-1), null_slot), live,
-        jnp.where(write_mask[:, 0], positions[:, 0] + 1, 0), rows))
-    if callable(sections):
-        sections = [(sections, cfg.n_layers)]
-    sections = [(*section, 1)[:3] for section in sections]  # (layer, layers covered, layers a call)
-    if sum(n for _, n, _ in sections) != cfg.n_layers or any(n % each for _, n, each in sections):
-        raise ValueError(f"the sections cover {[n for _, n, _ in sections]} layers of {cfg.n_layers}, "
-                         f"{[each for _, _, each in sections]} a call")
+
+    def sections_over(positions, write_mask):
+        """The kind's sections, each (layer, layers covered, layers a call,
+        last position only), and its carried leaf (or none), over a ``Step``
+        of these positions."""
+        n = positions.shape[1]
+        pidx = jnp.clip(positions // block_size, 0, block_tables.shape[1] - 1)
+        slot = jnp.take_along_axis(block_tables, pidx, axis=1) * block_size + positions % block_size
+        null_slot = jnp.arange(b * n, dtype=slot.dtype) % block_size
+        live = write_mask.reshape(-1)
+        sections = paged_layer(cfg, params, Step(
+            positions, block_tables, block_size, jnp.where(live, slot.reshape(-1), null_slot), live,
+            jnp.where(write_mask[:, 0], positions[:, 0] + 1, 0), rows))
+        leaf = ()
+        if isinstance(sections, Carried):
+            sections, leaf = sections.sections, (sections.leaf,)
+        if callable(sections):
+            sections = [(sections, cfg.n_layers)]
+        return [(*section, *(1, False)[len(section) - 2:]) for section in sections], leaf
+
+    sections, leaf = sections_over(positions, write_mask)
+    if sum(n for _, n, _, _ in sections) != cfg.n_layers or any(n % each for _, n, each, _ in sections):
+        raise ValueError(f"the sections cover {[n for _, n, _, _ in sections]} layers of {cfg.n_layers}, "
+                         f"{[each for _, _, each, _ in sections]} a call")
+    cut = last is not None and s > 1 and any(only for *_, only in sections)
+    if cut:
+        if not all(only for *_, only in sections[next(i for i, sec in enumerate(sections) if sec[3]):]):
+            raise ValueError("a section on the last position alone is followed by one over every position")
+        at = lambda t: jax.lax.dynamic_slice_in_dim(t, last, 1, axis=1)  # noqa: E731
+        tail, _ = sections_over(at(positions), at(write_mask))
 
     # The pool rides in the scan CARRY, not in per-layer outputs: stacked scan
     # outputs allocate a fresh slab and copy every layer's rows through it,
     # which defeats buffer donation and turns each decode step into an
     # O(pool-size) memcpy. Carry-threaded updates alias in place.
-    carry, first = (params["embed"][tokens], pool), 0
-    for layer, n, each in sections:
+    carry, first, narrowed = (params["embed"][tokens], pool, *leaf), 0, False
+    for i, (layer, n, each, only) in enumerate(sections):
+        if cut and only:
+            if not narrowed:  # x and the kind's leaf down to the one position; the pool whole
+                carry, narrowed = (at(carry[0]), carry[1], *(at(t) for t in carry[2:])), True
+            layer = tail[i][0]
         # each call's first layer (a layer a call: the arange every kind has always traced)
         firsts = jnp.arange(first, first + n) if each == 1 else jnp.arange(first, first + n, each)
         carry, _ = jax.lax.scan(lambda c, li, layer=layer: (layer(*c, li), None), carry, firsts)
         first += n
-    x, pool = carry
-    return head(cfg, params, x, last), pool
+    x, pool = carry[:2]
+    return head(cfg, params, x, None if narrowed else last), pool
 
 
 def make_paged_fns(paged_layer, cfg, *, block_size: int, state_rows: bool = False):

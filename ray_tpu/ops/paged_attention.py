@@ -28,6 +28,7 @@ neighbours hold.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -38,30 +39,32 @@ _NEG_INF = -1e30
 _CHUNK_BYTES = 1 << 20  # of K (and of V) in one VMEM buffer; two buffers each
 
 
-def can_use_paged_kernel(q, pool_k, block_size: int) -> bool:
+def can_use_paged_kernel(q, pool_k, block_size: int, kv_heads: int = 0) -> bool:
     """Platform and static shape alone, as ``ops.attention._can_use_flash``:
     a TPU, one query position, a head_dim of whole lanes, and kv heads that
     fill whole sublane tiles of the pool's type (so that a chunk
-    flattens to (rows * kv_heads, head_dim) without a relayout)."""
+    flattens to (rows * kv_heads, head_dim) without a relayout). A **flat**
+    pool (layers, slots x ``kv_heads``, head_dim) lies flattened already, so
+    any head count will do whose block is whole sublane tiles (10 heads of a
+    block of 16: ``paged_decode_attention``)."""
     if jax.default_backend() != "tpu":
         return False
     _, s, heads, head_dim = q.shape
-    kv_heads = pool_k.shape[2]
     sublanes = 32 // jnp.dtype(pool_k.dtype).itemsize
-    return (
-        s == 1
-        and head_dim % 128 == 0
-        and heads % kv_heads == 0
-        and kv_heads % sublanes == 0
-        and (block_size * kv_heads) % 128 == 0
-    )
+    if pool_k.ndim == 3:
+        tiles = (block_size * kv_heads) % sublanes == 0
+    else:
+        kv_heads = pool_k.shape[2]
+        tiles = kv_heads % sublanes == 0 and (block_size * kv_heads) % 128 == 0
+    return s == 1 and head_dim % 128 == 0 and heads % kv_heads == 0 and tiles
 
 
-def chunk_blocks_for(max_blocks: int, block_bytes: int, chunk_bytes: int = _CHUNK_BYTES) -> int:
+def chunk_blocks_for(max_blocks: int, block_bytes: int, chunk_bytes: int = _CHUNK_BYTES, whole: int = 1) -> int:
     """How many of a table's blocks one VMEM buffer takes: ``chunk_bytes`` of
-    them, a whole table at the most, one at the least. Fixed by shapes, so a
-    sequence walks its blocks in the same chunks whoever shares the step."""
-    return max(1, min(max_blocks, chunk_bytes // block_bytes))
+    them, a whole table at the most, one at the least (``whole``: and a
+    multiple of that many). Fixed by shapes, so a sequence walks its blocks in
+    the same chunks whoever shares the step."""
+    return max(whole, min(max_blocks, chunk_bytes // block_bytes) // whole * whole)
 
 
 def for_live_blocks(tbl_ref, seq, n_blocks, chunk, chunk_blocks: int, copies, act):
@@ -103,8 +106,8 @@ def _kernel(
     li_ref, len_ref, tbl_ref,  # scalar prefetch
     q_ref, pk_ref, pv_ref,  # q (1, H, Hd) in VMEM; the pools in HBM
     o_ref,
-    kbuf, vbuf, sem,  # (2, rows, KV, Hd) each; DMA semaphores (2, 2)
-    *, block_size, chunk_blocks,
+    kbuf, vbuf, sem,  # (2, rows, KV, Hd) each, (2, rows x KV, Hd) over flat pools; DMA semaphores (2, 2)
+    *, block_size, chunk_blocks, kv_heads, scale,
 ):
     b = pl.program_id(0)
     li = li_ref[0]
@@ -112,9 +115,11 @@ def _kernel(
     n_blocks = (length + block_size - 1) // block_size
     n_chunks = (n_blocks + chunk_blocks - 1) // chunk_blocks
     _, heads, head_dim = q_ref.shape
-    _, rows, kv_heads, _ = kbuf.shape
+    flat = len(kbuf.shape) == 3  # the pools hold a slot's heads as rows: a block is copied as block_size x KV of them
+    rows = chunk_blocks * block_size
     n_rep = heads // kv_heads
     cols = rows * kv_heads
+    each = block_size * kv_heads if flat else block_size  # rows of the pool (and of a buffer) a block is
 
     # A chunk's dead rows keep what the buffer held before, and a weight of
     # exactly 0 times that must be 0: nothing but zeros and pool rows is ever
@@ -125,8 +130,8 @@ def _kernel(
 
     def chunk_copies(chunk, slot, act):
         def copies(block, j):
-            src = pl.ds(block * block_size, block_size)
-            dst = pl.ds(j * block_size, block_size)
+            src = pl.ds(block * each, each)
+            dst = pl.ds(j * each, each)
             return (
                 pltpu.make_async_copy(pk_ref.at[li, src], kbuf.at[slot, dst], sem.at[0, slot]),
                 pltpu.make_async_copy(pv_ref.at[li, src], vbuf.at[slot, dst], sem.at[1, slot]),
@@ -139,9 +144,10 @@ def _kernel(
         chunk_copies(0, 0, lambda c: c.start())
 
     q = q_ref[0]
-    scale = 1.0 / (head_dim**0.5)
-    col = jax.lax.broadcasted_iota(jnp.int32, (heads, cols), 1)
-    head = jax.lax.broadcasted_iota(jnp.int32, (heads, cols), 0)
+    # over flat pools a head count need not be a power of two: the column's head and row are worked out for
+    # one row of columns, and broadcast in the comparisons
+    col = jax.lax.broadcasted_iota(jnp.int32, (1 if flat else heads, cols), 1)
+    head = jax.lax.broadcasted_iota(jnp.int32, (heads, 1 if flat else cols), 0)
     own_head = jax.lax.rem(col, kv_heads) == jax.lax.div(head, n_rep)
     row = jax.lax.div(col, kv_heads)
 
@@ -157,7 +163,7 @@ def _kernel(
         s = jax.lax.dot_general(
             q, kbuf[slot].reshape(cols, head_dim), (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        ) * scale
+        ) * scale  # (a flat buffer is (cols, head_dim) as it lies)
         s = jnp.where(own_head & (c * rows + row < length), s, _NEG_INF)
         m, alpha, p, l = online_softmax_weights(m, l, s)
         acc = alpha * acc + jnp.dot(
@@ -171,10 +177,15 @@ def _kernel(
 
 
 def paged_decode_attention(
-    q, pool_k, pool_v, layer, block_tables, lengths, *, block_size: int, interpret=False
+    q, pool_k, pool_v, layer, block_tables, lengths, *, block_size: int, kv_heads: int = 0, scale=None,
+    interpret=False,
 ):
     """q (B, H, Hd) against the pools (L, slots, KV, Hd), both in the model's
-    dtype, at layer ``layer``.
+    dtype, at layer ``layer``; or against **flat** pools (L, slots x
+    ``kv_heads``, Hd), a slot's heads as consecutive rows, which is how a head
+    count off the sublane tile lies without padding (10 heads: the device pads
+    a (slots, 10, Hd) array's heads to 16). ``scale`` where it is not
+    Hd^-1/2 (packed pairs of half-heads: ``ops/window_attention.py``).
     ``block_tables`` (B, MB) maps a sequence's block index to a pool block;
     ``lengths`` (B,) is how many positions of each sequence count (0: an
     inactive slot, whose output is 0), at most MB x ``block_size``; both
@@ -183,11 +194,17 @@ def paged_decode_attention(
     [0, length), scores and softmax in float32, the weights in q's dtype
     into the weighted sum."""
     b, heads, head_dim = q.shape
-    _, _, kv_heads, _ = pool_k.shape
+    flat = pool_k.ndim == 3
+    if not flat:
+        _, _, kv_heads, _ = pool_k.shape
     block_bytes = block_size * kv_heads * head_dim * jnp.dtype(pool_k.dtype).itemsize
-    chunk_blocks = chunk_blocks_for(block_tables.shape[1], block_bytes)
+    # over flat pools a chunk's columns (rows x heads) are whole lane tiles by the count of blocks
+    whole = 128 // math.gcd(block_size * kv_heads, 128) if flat else 1
+    chunk_blocks = chunk_blocks_for(block_tables.shape[1], block_bytes, whole=whole)
     rows = chunk_blocks * block_size
-    kernel = functools.partial(_kernel, block_size=block_size, chunk_blocks=chunk_blocks)
+    buffer = (2, rows * kv_heads, head_dim) if flat else (2, rows, kv_heads, head_dim)
+    kernel = functools.partial(_kernel, block_size=block_size, chunk_blocks=chunk_blocks, kv_heads=kv_heads,
+                               scale=scale or 1.0 / (head_dim**0.5))
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
@@ -201,8 +218,8 @@ def paged_decode_attention(
             ],
             out_specs=pl.BlockSpec((1, heads, head_dim), lambda i, *_: (i, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((2, rows, kv_heads, head_dim), pool_k.dtype),
-                pltpu.VMEM((2, rows, kv_heads, head_dim), pool_v.dtype),
+                pltpu.VMEM(buffer, pool_k.dtype),
+                pltpu.VMEM(buffer, pool_v.dtype),
                 pltpu.SemaphoreType.DMA((2, 2)),
             ],
         ),

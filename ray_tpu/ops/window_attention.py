@@ -1,0 +1,173 @@
+"""Differential attention (Ye et al., arXiv:2410.05258) in the three forms a
+model of window and full layers asks for, none of which ``ops/attention.py``
+has: its flash path takes plain causal masks and one softmax.
+
+Heads come in **pairs**. A query pair is 128 values ``[q1; q2]``, a key pair
+``[k1; k2]`` and a value head ``[v1; v2]``, 64 + 64 each, two query pairs a
+K/V pair (``(..., pairs, 128)``). With the layer's number ``lam``:
+
+    o = (softmax(q1 K1^T x scale) - lam softmax(q2 K2^T x scale)) V
+
+over the positions the mask lets through; ``scale`` is 64^-1/2 however the
+pair is packed. What follows (a norm over ``o``'s 128 values, a constant
+factor, ``W_o``) is the model's.
+
+**One trick serves both kernels.** A key pair is stored as one head of 128
+and read whole; the queries ``[q1; 0]`` and ``[0; q2]`` score it against k1
+and against k2 alone (``split_queries``). So the paged kernel the tree has
+(``paged_decode_attention``) runs the full and the cross layers over the
+shared cache with twice the query heads and the subtraction outside; and the
+ring kernel here does the same inside.
+
+* ``diff_attention_prefill``: a whole prompt from position 0, in query blocks
+  against the keys a block can see: the block itself and the one before it
+  under a window, every earlier block without one. No (S, S) score tensor.
+* ``diff_attention_rows``: one query position a sequence over rows handed
+  over with a mask: the decode step's gather path (the CPU backend) and the
+  tests' statement of what the two kernels compute.
+* ``ring_window_attention``: a decode step over a **ring** of ``window`` rows
+  a sequence, contiguous on the device ((layers, state rows, window x K/V
+  pairs, 128), in the pool's state row): a Pallas TPU kernel that takes a
+  sequence's whole ring as one block (the block's index is the layer and the
+  state row, prefetched scalars; the pipeline copies the next sequence's ring
+  while this one is scored), does both softmaxes over the ``live`` rows and
+  the subtraction, and writes ``o``. There is no rotary, so a row's place in
+  the ring says nothing: position ``p`` lies at ``p % window`` and the mask is
+  ``row < min(p + 1, window)``.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+_NEG_INF = -1e30
+_VMEM_LIMIT = 48 << 20  # two buffers each of a ring's K and V (1.3 MB at the published widths) and a (48, 5120) score block
+
+
+def split_queries(qp):
+    """``qp`` (..., pairs, 2 x d) -> (``[q1; 0]``, ``[0; q2]``), each as
+    wide as a stored key pair."""
+    first = jnp.arange(qp.shape[-1]) < qp.shape[-1] // 2
+    return jnp.where(first, qp, 0), jnp.where(first, 0, qp)
+
+
+def _two_softmaxes(qp, k, mask, lam, scale):
+    """``qp`` (B, Q, G, R, 2d), ``k`` (B, M, G, 2d), ``mask`` broadcast to (B,
+    G, R, Q, M) -> softmax1 - lam softmax2, float32."""
+    d = k.shape[-1] // 2
+    dot = functools.partial(jnp.einsum, "bqgrd,bkgd->bgrqk", preferred_element_type=jnp.float32)
+    s1 = jnp.where(mask, dot(qp[..., :d], k[..., :d]) * scale, _NEG_INF)
+    s2 = jnp.where(mask, dot(qp[..., d:], k[..., d:]) * scale, _NEG_INF)
+    return jax.nn.softmax(s1, axis=-1) - lam * jax.nn.softmax(s2, axis=-1)
+
+
+def diff_attention_rows(qp, k, v, live, lam, *, scale):
+    """One query position a sequence: ``qp`` (B, pairs, 2d), ``k``, ``v`` (B,
+    M, K/V pairs, 2d), ``live`` (B, M) bool the rows that count (a sequence
+    with none gets zeros) -> ``o`` (B, pairs, 2d) float32."""
+    b, pairs, wide = qp.shape
+    groups = k.shape[2]
+    q = qp.reshape(b, 1, groups, pairs // groups, wide)
+    p = _two_softmaxes(q, k, live[:, None, None, None, :], lam, scale)
+    o = jnp.einsum("bgrqk,bkgd->bqgrd", p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+    return jnp.where(jnp.any(live, axis=1)[:, None, None], o.reshape(b, pairs, wide), 0.0)
+
+
+def diff_attention_prefill(qp, k, v, lam, *, scale, window=None, block: int = 512):
+    """A prompt from position 0: ``qp`` (B, S, pairs, 2d), ``k``, ``v`` (B, S,
+    K/V pairs, 2d) -> ``o`` (B, S, pairs, 2d) float32. Position t sees
+    positions ``t - window + 1 .. t`` (from 0 without a window). Query blocks
+    of ``window`` rows (``block`` without one) against, under a window, their
+    own rows and the block's before them, else every row: the masks are exact,
+    the blocks only bound what is scored."""
+    b, s, pairs, wide = qp.shape
+    groups = k.shape[2]
+    rows = min(window or block, s)
+    if s % rows:
+        raise ValueError(f"a prompt of {s} positions is not whole blocks of {rows}")
+    q = qp.reshape(b, s, groups, pairs // groups, wide)
+    if window is not None:  # the block before the first is padding behind the mask
+        k, v = (jnp.pad(x, ((0, 0), (rows, 0), (0, 0), (0, 0))) for x in (k, v))
+    span = 2 * rows if window is not None else s
+
+    def one(i):
+        q_pos = i * rows + jnp.arange(rows)
+        first = (i - 1) * rows if window is not None else 0  # the position of the first key scored
+        k_pos = first + jnp.arange(span)
+        sees = (k_pos[None, :] <= q_pos[:, None]) & (k_pos[None, :] >= 0)
+        if window is not None:
+            sees &= k_pos[None, :] > q_pos[:, None] - window
+        at = i * rows if window is not None else 0  # in the padded rows, where that key lies
+        ks, vs = (jax.lax.dynamic_slice_in_dim(x, at, span, axis=1) for x in (k, v))
+        p = _two_softmaxes(jax.lax.dynamic_slice_in_dim(q, i * rows, rows, axis=1), ks, sees, lam, scale)
+        return jnp.einsum("bgrqk,bkgd->bqgrd", p.astype(vs.dtype), vs, preferred_element_type=jnp.float32)
+
+    o = jax.lax.map(one, jnp.arange(s // rows))  # (blocks, B, rows, G, R, 2d)
+    return jnp.moveaxis(o, 0, 1).reshape(b, s, pairs, wide)
+
+
+# -- the ring, a decode step ----------------------------------------------------------------
+
+
+def can_use_ring_kernel(window: int, kv_pairs: int, wide: int, dtype) -> bool:
+    """Platform and static shape alone, as ``can_use_paged_kernel``: a TPU, a
+    stored pair of whole lane tiles and a ring of whole sublane tiles."""
+    sublanes = 32 // jnp.dtype(dtype).itemsize
+    return jax.default_backend() == "tpu" and wide % 128 == 0 and (window * kv_pairs) % sublanes == 0
+
+
+def _ring_kernel(li_ref, rows_ref, live_ref, q_ref, lam_ref, own_ref, row_ref, k_ref, v_ref, o_ref, *, half, scale):
+    """One sequence's ring a grid step. ``q_ref`` (1, 2 x half, 2d): the
+    ``[q1; 0]`` queries, padded to ``half`` rows, then the ``[0; q2]``.
+    ``own_ref`` (2 x half, columns): 0 where a column (a row of the ring and a
+    K/V pair, as stored) is the query's own pair's, else ``_NEG_INF``."""
+    del li_ref, rows_ref
+    k, v = k_ref[0, 0], v_ref[0, 0]  # (window x K/V pairs, 2d)
+    s = jax.lax.dot_general(q_ref[0], k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32) * scale
+    s = jnp.where(row_ref[...] < live_ref[pl.program_id(0)], s + own_ref[...], _NEG_INF)
+    m = s.max(axis=-1, keepdims=True)
+    p = jnp.where(s > 0.5 * _NEG_INF, jnp.exp(s - m), 0.0)
+    # a query with no live column of its own (an inactive slot, a padding row) has summed nothing: 0, not 0/0
+    acc = jnp.dot(p.astype(v.dtype), v, preferred_element_type=jnp.float32) / jnp.maximum(p.sum(axis=-1, keepdims=True), 1e-30)
+    o_ref[0] = acc[:half] - lam_ref[...] * acc[half:]
+
+
+def ring_window_attention(qp, ring_k, ring_v, layer, rows, live, lam, *, kv_pairs: int, scale, interpret=False):
+    """``qp`` (B, pairs, 2d) against layer ``layer`` (traced) of the rings
+    (layers, state rows, window x ``kv_pairs``, 2d), sequence ``b``'s at state
+    row ``rows[b]``; ``live`` (B,): how many of its ring's rows count (0: an
+    inactive slot, whose output is 0), the first ``live`` of them; ``lam`` a
+    float32 scalar. -> ``o`` (B, pairs, 2d) float32, what
+    ``diff_attention_rows`` gives over the ring's rows: scores and softmaxes in
+    float32, the weights in the rings' type into the weighted sums."""
+    b, pairs, wide = qp.shape
+    cols = ring_k.shape[2]
+    half = -(-pairs // 8) * 8  # whole float32 sublane tiles: the output's two halves part on a tile
+    q = jnp.concatenate([jnp.pad(x, ((0, 0), (0, half - pairs), (0, 0))) for x in split_queries(qp)], axis=1)
+    head, col = jnp.arange(2 * half) % half, jnp.arange(cols)
+    own = (col[None, :] % kv_pairs == (head // (pairs // kv_pairs))[:, None]) & (head < pairs)[:, None]
+    whole = lambda shape: pl.BlockSpec(shape, lambda i, *_: (0,) * len(shape))  # noqa: E731 - the same block every step
+    ring = pl.BlockSpec((1, 1, cols, wide), lambda i, li, rows, live: (li[0], rows[i], 0, 0))
+    o = pl.pallas_call(
+        functools.partial(_ring_kernel, half=half, scale=scale),
+        out_shape=jax.ShapeDtypeStruct((b, half, wide), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(b,),
+            in_specs=[pl.BlockSpec((1, 2 * half, wide), lambda i, *_: (i, 0, 0)), whole((1, wide)),
+                      whole((2 * half, cols)), whole((1, cols)), ring, ring],
+            out_specs=pl.BlockSpec((1, half, wide), lambda i, *_: (i, 0, 0)),
+        ),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",), vmem_limit_bytes=_VMEM_LIMIT),
+        name="ring_window_attention",
+        interpret=interpret,
+    )(
+        jnp.asarray(layer, jnp.int32).reshape(1), rows.astype(jnp.int32), live.astype(jnp.int32),
+        q, jnp.full((1, wide), lam, jnp.float32), jnp.where(own, 0.0, _NEG_INF).astype(jnp.float32),
+        (col // kv_pairs).astype(jnp.int32)[None, :], ring_k, ring_v,
+    )
+    return o[:, :pairs]

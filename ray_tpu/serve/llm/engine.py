@@ -93,7 +93,7 @@ from typing import Any, Dict, List, Optional, Sequence
 
 from ray_tpu._private import memplane, sampler, telemetry
 from ray_tpu._private.looplog import (
-    COMPILE_FIELDS, COMPILE_STAGES, LLM_MOE_FIELDS, LLM_REQUEST_FIELDS, LLM_START_FIELDS, LLM_STEP_FIELDS,
+    COMPILE_FIELDS, COMPILE_STAGES, LLM_MOE_FIELDS, LLM_REQUEST_FIELDS, LLM_START_FIELDS, LLM_STEP_FIELDS, LLM_STEP_RING_FIELDS,
 )
 from ray_tpu._private.profiling import annotate
 from ray_tpu.serve.exceptions import DeploymentOverloadedError
@@ -273,12 +273,13 @@ class _Running:
 class _Step:
     """One decode step in flight: its rows ``(slot, sequence)``, its result
     still on the device, whether the greedy program ran, ``perf_counter_ns``
-    at the top of its dispatch, and the live KV blocks its attention reads."""
+    at the top of its dispatch, the live KV blocks its attention reads and, of
+    a kind with rings, their live rows."""
 
-    __slots__ = ("rows", "out", "fused", "t0", "kv_blocks")
+    __slots__ = ("rows", "out", "fused", "t0", "kv_blocks", "ring_rows")
 
-    def __init__(self, rows, out, fused, t0, kv_blocks):
-        self.rows, self.out, self.fused, self.t0, self.kv_blocks = rows, out, fused, t0, kv_blocks
+    def __init__(self, rows, out, fused, t0, kv_blocks, ring_rows):
+        self.rows, self.out, self.fused, self.t0, self.kv_blocks, self.ring_rows = rows, out, fused, t0, kv_blocks, ring_rows
 
 
 class _First:
@@ -402,6 +403,9 @@ class InferenceEngine:
         # what a row of it holds; it gets a row a decode slot, handed out with
         # the blocks and found by the programs in the table's last column
         self._state_bytes = int(model.paged_state_bytes(model_cfg)) if hasattr(model, "paged_state_bytes") else 0
+        # ... and a kind whose window layers keep a ring a sequence in that row says how many rows a ring has
+        # and what a row's rings hold: the loop counts the live rows a step's window attention reads
+        self._rings = dict(model.paged_ring(model_cfg)) if hasattr(model, "paged_ring") else {"rows": 0, "bytes": 0}
         state_rows = ecfg.max_batch if self._state_bytes else 0
         pool_rows = state_rows + 1 if state_rows else 0  # with the null row, as the pool holds them
         self._prefill, self._decode, self._decode_greedy = paged.make_paged_fns(
@@ -497,11 +501,11 @@ class InferenceEngine:
 
         logger.info(
             "%s: ready %.2f s after its start: backend %.2f s, weights %.2f, placed %s in %.2f, the pool's %.3f GB "
-            "(%d blocks of %d B, %d state rows of %d B) in %.2f; of it %.2f s tracing and lowering and %.2f s "
+            "(%d blocks of %d B, %d state rows of %d B, %d B of it rings) in %.2f; of it %.2f s tracing and lowering and %.2f s "
             "compiling (%.2f loading from the compile cache)",
             self.deployment, s("t_init", "t_ready"), s("t_init", "t_backend"), s("t_backend", "t_params"),
             self.placed, s("t_params", "t_placed"), st["pool_bytes"] / 1e9, self.cfg.num_blocks,
-            self._bytes_per_block, self._pool_rows, self._state_bytes, s("t_placed", "t_pool"),
+            self._bytes_per_block, self._pool_rows, self._state_bytes, self._rings["bytes"], s("t_placed", "t_pool"),
             spent["trace"] + spent["lower"], spent["compile"], spent["cache_load"],
         )
         self._loop()
@@ -684,10 +688,11 @@ class InferenceEngine:
 
     def _state_rows(self) -> Dict[str, int]:
         """The state rows of a kind that keeps a state a sequence (0 of 0 for
-        every other kind), and what one row holds over all layers."""
+        every other kind), what one row holds over all layers, and how much of
+        that is rings of window rows (0 for a kind without them)."""
         rows = self._alloc.state_rows
         return {"state_rows_total": rows, "state_rows_used": rows - self._alloc.state_rows_free,
-                "state_bytes": self._state_bytes}
+                "state_bytes": self._state_bytes, "ring_bytes": self._rings["bytes"]}
 
     def _refresh_kv_gauges(self) -> None:
         """The ``ray_tpu_kv_*`` gauges from this engine's occupancy: the
@@ -723,8 +728,10 @@ class InferenceEngine:
         ``placed``: the stacked
         tensors the engine re-laid on the device at start, name ->
         ``major_to_minor`` (``paged.place_params``; empty where it placed none),
-        and ``state_rows_total|used`` and ``state_bytes`` (a kind that keeps a
-        state a sequence; zeros for every other)."""
+        and ``state_rows_total|used``, ``state_bytes`` and ``ring_bytes`` (a
+        kind that keeps a state a sequence; zeros for every other). A kind with
+        rings has ``looplog.LLM_STEP_RING_FIELDS`` as its ``fields``: its step
+        records end in ``ring_rows``."""
         ring = self._ring.copy()  # atomic against the loop's appends
         steps = [r[1:] for r in ring if r[0] == "s"]
         reqs = [r[1:] for r in ring if r[0] == "r"]
@@ -736,7 +743,7 @@ class InferenceEngine:
         ahead: List[int] = []
         overrun: List[int] = []
         for r in steps:
-            d = dict(zip(LLM_STEP_FIELDS, r))
+            d = dict(zip(LLM_STEP_RING_FIELDS, r))  # a kind without rings: a field fewer
             if d["live"]:
                 kv.append(d["kv_blocks"])
                 ahead.append(d["ahead"])
@@ -757,7 +764,7 @@ class InferenceEngine:
         n = max(int(records), 0)
         return {
             "deployment": self.deployment,
-            "fields": LLM_STEP_FIELDS,
+            "fields": LLM_STEP_RING_FIELDS if self._rings["rows"] else LLM_STEP_FIELDS,
             "records": steps[-n:] if n else [],
             "request_fields": LLM_REQUEST_FIELDS,
             "requests": reqs[-n:] if n else [],
@@ -908,7 +915,7 @@ class InferenceEngine:
                 with annotate("llm.retire", step=self._steps_retired + 1):
                     t_result, overrun = self._retire(steps, ended, firsts, emissions, step_ms)
                 t_retire_end = now()
-            t_dispatch = t_dispatch_end = live = fused = kv_blocks = n_ahead = 0
+            t_dispatch = t_dispatch_end = live = fused = kv_blocks = ring_rows = n_ahead = 0
             if self._any_slot() and not stopping:
                 t_dispatch = now()
                 n_ahead = flying - steps
@@ -916,7 +923,7 @@ class InferenceEngine:
                     step = self._dispatch_step(ended)
                 t_dispatch_end = now()
                 if step is not None:
-                    live, fused, kv_blocks = len(step.rows), int(step.fused), step.kv_blocks
+                    live, fused, kv_blocks, ring_rows = len(step.rows), int(step.fused), step.kv_blocks, step.ring_rows
             # ---- the device is busy (or there is nothing for it to do) ----
             with annotate("llm.emit"):
                 for stream, tok in emissions:
@@ -952,7 +959,7 @@ class InferenceEngine:
                 self._record((
                     "s", self.decode_steps, t_loop, t_admit_end, t_result, t_retire_end,
                     t_dispatch, t_dispatch_end, now(), live, len(admits), fused, kv_blocks,
-                    n_ahead, overrun,
+                    n_ahead, overrun, *((ring_rows,) if self._rings["rows"] else ()),
                 ))
             if stopping:
                 return
@@ -1123,7 +1130,8 @@ class InferenceEngine:
         tables = np.zeros((b, mb), np.int32)
         active = np.zeros((b,), bool)
         rows: List[tuple] = []
-        kv_blocks = 0
+        kv_blocks = ring_rows = 0
+        window = self._rings["rows"]
         fused = True
         for i, run in enumerate(self._slots):
             if run is None:
@@ -1138,6 +1146,7 @@ class InferenceEngine:
             active[i] = True
             rows.append((i, run))
             kv_blocks += len(run.table.blocks)
+            ring_rows += min(pos + 1, window)
             if run.req.temperature > 0:
                 fused = False
         fn = self._decode_greedy if fused else self._decode
@@ -1156,7 +1165,7 @@ class InferenceEngine:
             return None
         self._newest = out if fused else None
         self.decode_steps += 1
-        step = _Step(rows, out, fused, t0, kv_blocks)
+        step = _Step(rows, out, fused, t0, kv_blocks, ring_rows)
         self._flight.append(step)
         for _i, run in rows:
             run.dispatched += 1

@@ -367,6 +367,104 @@ def test_a_kind_whose_section_covers_two_layers_a_call_is_served_with_a_state_ro
                 jnp.zeros((1, MAX_BLOCKS + 1), jnp.int32), pair_init_pool(cfg, BLOCKS, BLOCK, 3), jnp.int32(3))
 
 
+# -- (b''') a value carried beside the residual, and a section that a prefill runs on one position ------
+#
+# The made-up kind with three layers: two of the toy's, the second of which hands its output on as a
+# memo; then one that writes no rows: it adds tanh(memo) times the running mean of the rows layer 1
+# wrote, and an MLP. Everything it reads a decode step could give it, so a prefill runs it on the
+# prompt's last position alone (``paged.Carried``; the section's fourth entry).
+
+
+@dataclasses.dataclass(frozen=True)
+class MemoToyConfig(ToyConfig):
+    n_layers: int = 3
+
+
+def memo_init_pool(cfg, num_blocks, block_size):
+    return {**init_paged_pool(cfg, num_blocks, block_size), "tail_positions": jnp.zeros((), jnp.int32)}
+
+
+def memo_paged_layer(cfg, params, step):
+    b, s = step.positions.shape
+    bs = step.block_size
+    upto = (jnp.arange(step.block_tables.shape[1] * bs) <= step.positions[..., None]).astype(cfg.dtype)
+    toy = paged_layer(cfg, params, step)
+
+    def first(x, pool, memo, li):
+        x, rest = toy(x, pool, li)
+        return x, {**pool, **rest}, jnp.where(li == 1, x, memo)
+
+    def last(x, pool, memo, li):
+        seen = pool["rows"][1, step.block_tables].reshape(b, -1, cfg.width)  # layer 1's rows: nothing written here
+        x = x + jnp.tanh(memo) * jnp.einsum("bsm,bmd->bsd", upto, seen) / (step.positions[..., None] + 1)
+        x = x + gelu(x @ params["w_up"][li]) @ params["w_down"][li]
+        return x, {**pool, "tail_positions": pool["tail_positions"] + jnp.sum(step.live)}, memo
+
+    return paged.Carried([(first, 2), (last, 1, 1, True)], jnp.zeros((b, s, cfg.width), cfg.dtype))
+
+
+def memo_next_token(cfg, params, tokens):
+    x = params["embed"][jnp.asarray(tokens)]
+    count = jnp.arange(1, len(tokens) + 1)[:, None]
+    for li in range(2):
+        rows = x @ params["w_mix"][li]
+        x = x + jnp.cumsum(rows, axis=0) / count
+        x = x + gelu(x @ params["w_up"][li]) @ params["w_down"][li]
+    x = x + jnp.tanh(x) * jnp.cumsum(rows, axis=0) / count
+    x = x + gelu(x @ params["w_up"][2]) @ params["w_down"][2]
+    return int(jnp.argmax(rms_norm(x[-1], params["final_norm"]) @ params["unembed"]))
+
+
+def test_a_kind_with_a_carried_leaf_and_a_last_position_section_is_served():
+    memo = type(sys)("memo_toy")
+    memo.__dict__.update(MemoToyConfig=MemoToyConfig, init_params=init_params, paged_layer=memo_paged_layer,
+                         init_paged_pool=memo_init_pool, paged_block_bytes=paged_block_bytes)
+    sys.modules["memo_toy"] = memo
+    models.PAGED_KINDS["memo_toy"] = ("memo_toy", "MemoToyConfig")
+    try:
+        server = LLMServer({"kind": "memo_toy", "vocab_size": 48},
+                           dict(block_size=BLOCK, num_blocks=BLOCKS, max_batch=2, max_blocks_per_seq=MAX_BLOCKS),
+                           weight_seed=2)
+        try:
+            eng = server._engine
+            cfg = eng.model_cfg
+            prompts = [[5, 9, 2], [7] * 9, [1, 2, 3, 4, 5, 6]]  # three requests on two slots
+            streams = [server.generate(p, max_new_tokens=7) for p in prompts]
+            for prompt, got in zip(prompts, [list(s) for s in streams]):
+                seq = list(prompt)
+                for token in got:
+                    assert token == memo_next_token(cfg, eng.params, seq)
+                    seq.append(token)
+            # the first section wrote every position's rows, two layers; the last ran one position a
+            # prefill and one a sequence a decode step
+            assert int(eng._pool["rows_written"]) == 2 * sum(len(p) + 6 for p in prompts)
+            assert int(eng._pool["tail_positions"]) == sum(1 + 6 for p in prompts)
+            assert "ring_rows" not in server.loop_stats()["fields"] and server.kv_stats()["ring_bytes"] == 0
+        finally:
+            server._engine.shutdown()
+    finally:
+        del models.PAGED_KINDS["memo_toy"], sys.modules["memo_toy"]
+    # over every position (the fourth entry dropped) the same first token; after a last-position section none
+    cfg = MemoToyConfig()
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    tokens = jnp.asarray([[3, 1, 4, 1, 5, 9, 2, 6] + [0] * (BUCKET - 8)], jnp.int32)
+    table = jnp.asarray([[1, 2] + [0] * (MAX_BLOCKS - 2)], jnp.int32)
+    args = (params, tokens, table)
+    every = lambda cfg, params, step: paged.Carried(  # noqa: E731
+        [sec[:3] for sec in memo_paged_layer(cfg, params, step).sections], jnp.zeros((1, BUCKET, cfg.width), cfg.dtype))
+    short, pool_short = paged.make_paged_fns(memo_paged_layer, cfg, block_size=BLOCK)[0](
+        *args, memo_init_pool(cfg, BLOCKS, BLOCK), jnp.int32(8))
+    whole, pool_whole = paged.make_paged_fns(every, cfg, block_size=BLOCK)[0](*args, memo_init_pool(cfg, BLOCKS, BLOCK), jnp.int32(8))
+    np.testing.assert_allclose(np.asarray(short), np.asarray(whole), atol=1e-5, rtol=1e-5)
+    assert (int(pool_short["tail_positions"]), int(pool_whole["tail_positions"])) == (1, 8)
+    np.testing.assert_array_equal(np.asarray(pool_short["rows"]), np.asarray(pool_whole["rows"]))
+    wrong = lambda cfg, params, step: paged.Carried(  # noqa: E731
+        [(memo_paged_layer(cfg, params, step).sections[1][0], 1, 1, True), (memo_paged_layer(cfg, params, step).sections[0][0], 2)],
+        jnp.zeros((1, step.positions.shape[1], cfg.width), cfg.dtype))
+    with pytest.raises(ValueError, match="followed by one over every position"):
+        paged.make_paged_fns(wrong, cfg, block_size=BLOCK)[0](*args, memo_init_pool(cfg, BLOCKS, BLOCK), jnp.int32(8))
+
+
 def _forward_paged_before_sections(paged_layer, cfg, params, tokens, positions, write_mask, block_tables, pool,
                                    block_size, last=None, state_rows=False):
     """``forward_paged`` as it stood before a kind could hand back sections (PR 32), kept here as the

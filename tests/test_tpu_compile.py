@@ -424,6 +424,77 @@ def test_hybrid_decode_and_prefill_at_olmo_hybrid_widths(one_chip, monkeypatch):
     assert mem.temp_size_in_bytes < 0.08e9  # beside 14.4 GB of arguments in a chip of 15.75 usable
 
 
+def test_phi4flash_decode_and_prefill_at_published_widths(one_chip, monkeypatch):
+    """serve.llm's programs for Phi-4-mini-flash-reasoning whole, as the
+    benchmark's configuration has it (``benchmarks/configs/phi4-mini-flash.json``):
+    the published widths, all 32 layers as three sections, the whole vocabulary,
+    the engine's 48 slots over 11,521 blocks of one stored layer and 49 state
+    rows. The file's arithmetic against the compiler: 7.70 GB of weights, a 0.94
+    GB shared K/V pool of ten flat pairs, 1.03 GB of rings and 0.16 GB of states
+    are the programs' arguments. The decode step holds the three kernels
+    (``selective_scan_update`` a state-space layer, ``ring_window_attention`` a
+    window layer, ``paged_decode_attention`` the full and every cross layer) and
+    copies no pool, ring or state. A prefill of 2,048 holds the prompt's scan
+    (``selective_scan_prefill``) and, for its last section on one position, the
+    paged kernel; its own memory stays under 1 GB beside 9.9 GB of arguments."""
+    import json
+    import re
+
+    from benchmarks.families import phi4flash as family
+    from ray_tpu.models import paged, phi4flash as M
+    from ray_tpu.serve.llm.deployment import _resolve_model_cfg
+
+    _steered_to_tpu(monkeypatch)
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks", "configs", "phi4-mini-flash.json")) as f:
+        config = json.load(f)
+    cfg = _resolve_model_cfg(family.model_kwargs(config))
+    e = config["engine"]
+    block, blocks, batch, per_seq = e["block_size"], e["num_blocks"], e["max_batch"], e["max_blocks_per_seq"]
+    prefill, _, decode_greedy = paged.make_paged_fns(M.paged_layer, cfg, block_size=block, state_rows=True)
+    params = _on(one_chip, jax.eval_shape(lambda: M.init_params(jax.random.PRNGKey(0), cfg)))
+    pool = _on(one_chip, jax.eval_shape(lambda: M.init_paged_pool(cfg, blocks, block, batch + 1)))
+
+    def nbytes(tree):
+        return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
+
+    assert 7.69e9 < nbytes(params) < 7.72e9
+    assert 0.94e9 < nbytes(pool["k"]) + nbytes(pool["v"]) == blocks * M.paged_block_bytes(cfg, block) < 0.95e9
+    assert 1.02e9 < nbytes(pool["ring_k"]) + nbytes(pool["ring_v"]) == (batch + 1) * M.paged_ring(cfg)["bytes"] < 1.03e9
+    rows = sum(nbytes(pool[name]) for name in ("state", "conv", "state_pos", "ring_k", "ring_v"))
+    assert rows == (batch + 1) * M.paged_state_bytes(cfg) and 1.18e9 < rows < 1.21e9
+    assert pool["state"].shape == (9, 49, 16, 5120) and pool["ring_k"].shape == (8, 49, 5120, 128)
+    assert pool["k"].shape == (1, blocks * block * 10, 128)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def pools_copied(text):
+        """Instructions of their own that make a pool, or a layer of one, anew."""
+        pools = {f"{lead}{dims}" for dims in ("49,16,5120", "49,20480", "49,5120,128", f"{blocks * block * 10},128")
+                 for lead in ("", "1,", "9,", "8,")}
+        return [(dims, op) for dims, _, op in _alone(text)
+                if dims in pools and op in ("copy", "transpose", "gather", "dynamic-slice")]
+
+    compiled = decode_greedy.lower(
+        params, arg((batch,), jnp.int32), arg((batch,), jnp.int32), arg((batch, per_seq), jnp.int32), pool,
+        arg((batch,), jnp.bool_),
+    ).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert {"selective_scan_update", "ring_window_attention", "paged_decode_attention"} <= set(_kernels(text))
+    # a scan's body holds its layers' kernels once: (ssm, ring), (ssm, paged), (paged)
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"", text)) == 5
+    assert not pools_copied(text)
+    assert 9.8e9 < mem.argument_size_in_bytes < 9.95e9 and mem.temp_size_in_bytes < 0.1e9
+    assert mem.alias_size_in_bytes > 0.999 * nbytes(pool)  # the pool comes back in place
+    compiled = prefill.lower(
+        params, arg((1, 2048), jnp.int32), arg((1, per_seq), jnp.int32), pool, arg((), jnp.int32)).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert {"selective_scan_prefill", "paged_decode_attention"} <= set(_kernels(text))
+    assert "ring_window_attention" not in text and "selective_scan_update" not in text
+    assert not pools_copied(text)
+    assert mem.temp_size_in_bytes < 1.0e9
+
+
 def _steered_to_tpu(monkeypatch):
     """``attention`` asks ``jax.default_backend()``, which is the CPU here:
     the test steers it to the branch it takes on the chip."""
