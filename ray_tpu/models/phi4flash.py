@@ -38,10 +38,14 @@ entry of the last section).
 * ``ring_k``, ``ring_v`` (window layers, state rows, W x K/V pairs, 128): a
   **ring** of the last W positions' rows a sequence a window layer, in the
   sequence's state row. Position p lies at ``p % W``; a decode step attends
-  over ``min(p + 1, W)`` rows (``ring_window_attention``); a prefill attends
-  over its own prompt in a band and leaves its last W rows. Its size does not
-  grow, nothing is allocated or released while a sequence decodes, and a write
-  at one position twice is the same row.
+  over ``min(p + 1, W)`` rows, its own among them: on a TPU
+  ``ring_window_attention`` takes the position's K and V, puts them at
+  ``p % W`` of the ring it has brought in, scores it and writes that row back,
+  the rings its outputs in place; elsewhere the row is scattered into the ring
+  (``_write_spans``) and the ring gathered. A prefill attends over its own
+  prompt in a band and leaves its last W rows, one scattered window a ring. A
+  ring's size does not grow, nothing is allocated or released while a sequence
+  decodes, and a write at one position twice is the same row.
 * ``state`` (state-space layers, state rows, N, d_in) float32, ``conv``
   (.., K x d_in), ``state_pos``: the recurrent state, the short convolution's
   window and the count of positions consumed, as ``models/olmo_hybrid.py`` keeps
@@ -343,19 +347,20 @@ def paged_layer(cfg: Phi4FlashConfig, params, step):
         if window:
             ring = {"ring_k": pool["ring_k"], "ring_v": pool["ring_v"]}
             if decode:
-                with jax.named_scope("ring_scatter"):
-                    starts = step.positions[:, 0] % W * G
-                    ring = {name: _write_spans(ring[name], (ai, rows), starts, t[:, 0])
-                            for name, t in (("ring_k", k), ("ring_v", v))}
-                with jax.named_scope("ring_attn"):
-                    held = jnp.minimum(step.lengths, W)
-                    if ring_kernel:
-                        o = ring_window_attention(qp[:, 0], ring["ring_k"], ring["ring_v"], ai, rows, held, lam,
-                                                  kv_pairs=G, scale=scale)
-                    else:
+                at_row, held = step.positions[:, 0] % W, jnp.minimum(step.lengths, W)
+                if ring_kernel:  # the kernel puts the row in its ring and scores the ring with it there
+                    with jax.named_scope("ring_attn"):
+                        o, ring["ring_k"], ring["ring_v"] = ring_window_attention(
+                            qp[:, 0], k[:, 0], v[:, 0], ring["ring_k"], ring["ring_v"], ai, rows, held, at_row, lam,
+                            kv_pairs=G, scale=scale)
+                else:
+                    with jax.named_scope("ring_scatter"):
+                        ring = {name: _write_spans(ring[name], (ai, rows), at_row * G, t[:, 0])
+                                for name, t in (("ring_k", k), ("ring_v", v))}
+                    with jax.named_scope("ring_attn"):
                         mine = [ring[name][ai, rows].reshape(b, W, G, wide) for name in ("ring_k", "ring_v")]
                         o = diff_attention_rows(qp[:, 0], *mine, jnp.arange(W)[None, :] < held[:, None], lam, scale=scale)
-                    o = o[:, None]
+                o = o[:, None]
             else:
                 with jax.named_scope("ring_attn"):
                     o = diff_attention_prefill(qp, k, v, lam, scale=scale, window=W)

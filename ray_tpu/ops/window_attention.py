@@ -33,7 +33,15 @@ ring kernel here does the same inside.
   while this one is scored), does both softmaxes over the ``live`` rows and
   the subtraction, and writes ``o``. There is no rotary, so a row's place in
   the ring says nothing: position ``p`` lies at ``p % window`` and the mask is
-  ``row < min(p + 1, window)``.
+  ``row < min(p + 1, window)``. **The kernel writes the step's own row too**:
+  the ring comes in with the row at ``p % window`` stale, the kernel puts the
+  new K and V there in the block it holds, scores that block, and copies the
+  sublane tiles that cover the row back into the ring in HBM, which is the
+  kernel's output in place of its input. A scatter ahead of the kernel did the
+  same for ~1.4 us an index on the chip, 48 sequences x K and V a layer: more
+  than the attention took. An inactive slot's ring is the null row, which
+  every inactive slot shares: it is read behind a mask of nothing and never
+  written.
 """
 
 from __future__ import annotations
@@ -114,60 +122,117 @@ def diff_attention_prefill(qp, k, v, lam, *, scale, window=None, block: int = 51
 # -- the ring, a decode step ----------------------------------------------------------------
 
 
+def _sublanes(dtype) -> int:
+    """Rows of a tile of the chip's memory in ``dtype``: 8 of 32 bits, 16 of 16."""
+    return 32 // jnp.dtype(dtype).itemsize
+
+
 def can_use_ring_kernel(window: int, kv_pairs: int, wide: int, dtype) -> bool:
     """Platform and static shape alone, as ``can_use_paged_kernel``: a TPU, a
     stored pair of whole lane tiles and a ring of whole sublane tiles."""
-    sublanes = 32 // jnp.dtype(dtype).itemsize
-    return jax.default_backend() == "tpu" and wide % 128 == 0 and (window * kv_pairs) % sublanes == 0
+    return jax.default_backend() == "tpu" and wide % 128 == 0 and (window * kv_pairs) % _sublanes(dtype) == 0
 
 
-def _ring_kernel(li_ref, rows_ref, live_ref, q_ref, lam_ref, own_ref, row_ref, k_ref, v_ref, o_ref, *, half, scale):
+def _ring_kernel(li_ref, rows_ref, live_ref, at_ref, q_ref, lam_ref, own_ref, row_ref, nk_ref, nv_ref, k_ref, v_ref,
+                 o_ref, k_out, v_out, sem, *, half, scale, kv_pairs, span):
     """One sequence's ring a grid step. ``q_ref`` (1, 2 x half, 2d): the
     ``[q1; 0]`` queries, padded to ``half`` rows, then the ``[0; q2]``.
     ``own_ref`` (2 x half, columns): 0 where a column (a row of the ring and a
-    K/V pair, as stored) is the query's own pair's, else ``_NEG_INF``."""
-    del li_ref, rows_ref
+    K/V pair, as stored) is the query's own pair's, else ``_NEG_INF``.
+    ``nk_ref``, ``nv_ref`` (B, K/V pairs, 2d): every sequence's new row.
+    ``k_ref``, ``v_ref``: the ring's block in VMEM, the row at ``at_ref[i]``
+    stale; ``k_out``, ``v_out``: the rings in HBM, the same buffers as the
+    inputs; ``sem``: a DMA semaphore each.
+
+    The new row is put in its place in the VMEM block (the ``span`` rows of
+    whole sublane tiles that cover it: read, patched row by row, stored), those
+    tiles start on their way back to the ring in HBM, the block is scored as it
+    now lies, and the copies are waited for before the step ends: the next step
+    but one's ring lands in this buffer. An inactive slot patches and writes
+    nothing: the null row is every inactive slot's."""
+    i = pl.program_id(0)
+    live, (cols, wide), sublanes = live_ref[i], k_ref.shape[2:], _sublanes(k_ref.dtype)
+    first = at_ref[i] * kv_pairs  # the new row's pairs are rows first .. first + kv_pairs - 1
+    start = pl.multiple_of(jnp.minimum(first // sublanes * sublanes, cols - span), sublanes)
+    tiles = pl.ds(start, span)
+    back = [pltpu.make_async_copy(ring.at[0, 0, tiles], out.at[li_ref[0], rows_ref[i], tiles], sem.at[j])
+            for j, (ring, out) in enumerate(((k_ref, k_out), (v_ref, v_out)))]
+
+    @pl.when(live > 0)
+    def _():
+        place = jax.lax.broadcasted_iota(jnp.int32, (span, wide), 0) - (first - start)
+        for new_ref, ring in ((nk_ref, k_ref), (nv_ref, v_ref)):
+            # through float32, which holds every value of the ring's type: a select of packed rows is not every chip's
+            new, rows = new_ref[i].astype(jnp.float32), ring[0, 0, tiles, :].astype(jnp.float32)
+            for j in range(kv_pairs):
+                rows = jnp.where(place == j, new[j:j + 1], rows)
+            ring[0, 0, tiles, :] = rows.astype(ring.dtype)
+        for copy in back:
+            copy.start()
+
     k, v = k_ref[0, 0], v_ref[0, 0]  # (window x K/V pairs, 2d)
     s = jax.lax.dot_general(q_ref[0], k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32) * scale
-    s = jnp.where(row_ref[...] < live_ref[pl.program_id(0)], s + own_ref[...], _NEG_INF)
+    s = jnp.where(row_ref[...] < live, s + own_ref[...], _NEG_INF)
     m = s.max(axis=-1, keepdims=True)
     p = jnp.where(s > 0.5 * _NEG_INF, jnp.exp(s - m), 0.0)
     # a query with no live column of its own (an inactive slot, a padding row) has summed nothing: 0, not 0/0
     acc = jnp.dot(p.astype(v.dtype), v, preferred_element_type=jnp.float32) / jnp.maximum(p.sum(axis=-1, keepdims=True), 1e-30)
     o_ref[0] = acc[:half] - lam_ref[...] * acc[half:]
 
+    @pl.when(live > 0)
+    def _():
+        for copy in back:
+            copy.wait()
 
-def ring_window_attention(qp, ring_k, ring_v, layer, rows, live, lam, *, kv_pairs: int, scale, interpret=False):
-    """``qp`` (B, pairs, 2d) against layer ``layer`` (traced) of the rings
+
+def ring_window_attention(qp, new_k, new_v, ring_k, ring_v, layer, rows, live, at, lam, *, kv_pairs: int, scale,
+                          interpret=False):
+    """A decode step's write and read of layer ``layer`` (traced) of the rings
     (layers, state rows, window x ``kv_pairs``, 2d), sequence ``b``'s at state
-    row ``rows[b]``; ``live`` (B,): how many of its ring's rows count (0: an
-    inactive slot, whose output is 0), the first ``live`` of them; ``lam`` a
-    float32 scalar. -> ``o`` (B, pairs, 2d) float32, what
-    ``diff_attention_rows`` gives over the ring's rows: scores and softmaxes in
-    float32, the weights in the rings' type into the weighted sums."""
+    row ``rows[b]``. ``qp`` (B, pairs, 2d) the queries; ``new_k``, ``new_v``
+    (B, ``kv_pairs``, 2d) the position's own K and V, cast to the rings' type;
+    ``at`` (B,) the ring row they go to (``position % window``), stale as the
+    rings come in; ``live`` (B,) how many of the ring's rows count with the new
+    one among them, the first ``live`` (``at < live``; 0: an inactive slot,
+    whose output is 0 and whose state row, the null row, is not written);
+    ``lam`` a float32 scalar. Live sequences hold distinct state rows.
+
+    -> (``o`` (B, pairs, 2d) float32, ``ring_k``, ``ring_v``). The rings come
+    back in place (``input_output_aliases``), bit for bit what a scatter of the
+    new rows leaves: only the whole sublane tiles that cover a new row are
+    written, from the block the kernel scored. ``o`` is what
+    ``diff_attention_rows`` gives over the rings that come back: scores and
+    softmaxes in float32, the weights in the rings' type into the weighted
+    sums. A call again at the same position writes the same row."""
     b, pairs, wide = qp.shape
     cols = ring_k.shape[2]
     half = -(-pairs // 8) * 8  # whole float32 sublane tiles: the output's two halves part on a tile
+    sublanes = _sublanes(ring_k.dtype)
+    span = min(cols, (-(-(kv_pairs - 1) // sublanes) + 1) * sublanes)  # the whole tiles a row's pairs can lie across
     q = jnp.concatenate([jnp.pad(x, ((0, 0), (0, half - pairs), (0, 0))) for x in split_queries(qp)], axis=1)
     head, col = jnp.arange(2 * half) % half, jnp.arange(cols)
     own = (col[None, :] % kv_pairs == (head // (pairs // kv_pairs))[:, None]) & (head < pairs)[:, None]
     whole = lambda shape: pl.BlockSpec(shape, lambda i, *_: (0,) * len(shape))  # noqa: E731 - the same block every step
-    ring = pl.BlockSpec((1, 1, cols, wide), lambda i, li, rows, live: (li[0], rows[i], 0, 0))
-    o = pl.pallas_call(
-        functools.partial(_ring_kernel, half=half, scale=scale),
-        out_shape=jax.ShapeDtypeStruct((b, half, wide), jnp.float32),
+    ring = pl.BlockSpec((1, 1, cols, wide), lambda i, li, rows, live, at: (li[0], rows[i], 0, 0))
+    in_place = pl.BlockSpec(memory_space=pl.ANY)
+    o, ring_k, ring_v = pl.pallas_call(
+        functools.partial(_ring_kernel, half=half, scale=scale, kv_pairs=kv_pairs, span=span),
+        out_shape=(jax.ShapeDtypeStruct((b, half, wide), jnp.float32),
+                   jax.ShapeDtypeStruct(ring_k.shape, ring_k.dtype), jax.ShapeDtypeStruct(ring_v.shape, ring_v.dtype)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3, grid=(b,),
+            num_scalar_prefetch=4, grid=(b,),
             in_specs=[pl.BlockSpec((1, 2 * half, wide), lambda i, *_: (i, 0, 0)), whole((1, wide)),
-                      whole((2 * half, cols)), whole((1, cols)), ring, ring],
-            out_specs=pl.BlockSpec((1, half, wide), lambda i, *_: (i, 0, 0)),
+                      whole((2 * half, cols)), whole((1, cols)), whole(new_k.shape), whole(new_v.shape), ring, ring],
+            out_specs=(pl.BlockSpec((1, half, wide), lambda i, *_: (i, 0, 0)), in_place, in_place),
+            scratch_shapes=[pltpu.SemaphoreType.DMA((2,))],
         ),
+        input_output_aliases={10: 1, 11: 2},  # the rings, counted with the four prefetched scalars
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",), vmem_limit_bytes=_VMEM_LIMIT),
         name="ring_window_attention",
         interpret=interpret,
     )(
-        jnp.asarray(layer, jnp.int32).reshape(1), rows.astype(jnp.int32), live.astype(jnp.int32),
+        jnp.asarray(layer, jnp.int32).reshape(1), rows.astype(jnp.int32), live.astype(jnp.int32), at.astype(jnp.int32),
         q, jnp.full((1, wide), lam, jnp.float32), jnp.where(own, 0.0, _NEG_INF).astype(jnp.float32),
-        (col // kv_pairs).astype(jnp.int32)[None, :], ring_k, ring_v,
+        (col // kv_pairs).astype(jnp.int32)[None, :], new_k.astype(ring_k.dtype), new_v.astype(ring_v.dtype), ring_k, ring_v,
     )
-    return o[:, :pairs]
+    return o[:, :pairs], ring_k, ring_v

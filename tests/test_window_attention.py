@@ -1,19 +1,26 @@
 """Differential attention's three forms (``ops/window_attention.py``) and the
 packed-pair call of the paged kernel over a flat pool, against differential
 attention written out with masks: the banded prefill, the ring kernel
-(interpret mode) with rings part full, full and wrapped, and
+(interpret mode) with rings part full, full and wrapped, the row it writes into
+its ring against a scatter of that row (``models/phi4flash.py:_write_spans``)
+wherever the row lies in the sublane tiles, a decode step of the model with the
+kernel in it against the step that scatters and gathers, and
 ``paged_decode_attention`` on ``[q1; 0]`` and ``[0; q2]`` at a head count off
-the sublane tile. CPU, float32."""
+the sublane tile. CPU, float32 (and the rings' bfloat16 where a row's place in
+a tile of 16 is the point)."""
 
 import os
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
+import functools  # noqa: E402
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
+from ray_tpu.models import paged, phi4flash as M  # noqa: E402
 from ray_tpu.ops import window_attention as W  # noqa: E402
 from ray_tpu.ops.paged_attention import can_use_paged_kernel, chunk_blocks_for, paged_decode_attention  # noqa: E402
 
@@ -58,13 +65,27 @@ def test_a_prompt_that_is_not_whole_blocks_is_refused():
                                  scale=SCALE, window=8)
 
 
+def attend(qp, ring_k, ring_v, layer, rows, live, kv_pairs=G):
+    """The kernel over rings that already hold the step's row (a step
+    dispatched again): each sequence's newest row handed over as the new one.
+    The rings come back as they went in. -> ``o``."""
+    at = jnp.maximum(live - 1, 0)
+    mine = (at[:, None] * kv_pairs + jnp.arange(kv_pairs))[:, :, None]
+    new = [jnp.take_along_axis(r[layer, rows], mine, axis=1) for r in (ring_k, ring_v)]
+    o, *rings = W.ring_window_attention(qp, *new, ring_k, ring_v, layer, rows, live, at, LAM, kv_pairs=kv_pairs,
+                                        scale=SCALE, interpret=True)
+    for got, want in zip(rings, (ring_k, ring_v)):
+        np.testing.assert_array_equal(got, want)
+    return o
+
+
 @pytest.mark.parametrize("live", [(8, 3, 0), (1, 8, 5)])
 def test_the_ring_kernel_is_masked_differential_attention_over_the_live_rows(live):
     window = 8
     qp = normal(4, 3, P, 2 * HALF)
     ring_k, ring_v = normal(5, 2, 4, window * G, 2 * HALF), normal(6, 2, 4, window * G, 2 * HALF)
     rows, live = jnp.asarray([2, 3, 0]), jnp.asarray(live)
-    got = W.ring_window_attention(qp, ring_k, ring_v, 1, rows, live, LAM, kv_pairs=G, scale=SCALE, interpret=True)
+    got = attend(qp, ring_k, ring_v, 1, rows, live)
     for b in range(3):
         k, v = (np.asarray(r[1, rows[b]]).reshape(window, G, 2 * HALF) for r in (ring_k, ring_v))
         if int(live[b]):
@@ -86,10 +107,90 @@ def test_a_ring_that_wrapped_is_the_window_whatever_order_its_rows_lie_in():
     qp = normal(9, 1, P, 2 * HALF)
     ring = [jnp.zeros((1, 2, window * G, 2 * HALF), jnp.float32).at[0, 1].set(
         jnp.concatenate([x[16:20], x[12:16]]).reshape(window * G, 2 * HALF)) for x in (k, v)]
-    got = W.ring_window_attention(qp, *ring, 0, jnp.asarray([1]), jnp.asarray([window]), LAM, kv_pairs=G, scale=SCALE,
-                                  interpret=True)
+    got = attend(qp, *ring, 0, jnp.asarray([1]), jnp.asarray([window]))
     want = masked(qp, k, v, ((np.arange(n) > 19 - window) & (np.arange(n) <= 19))[None])
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+
+
+# A row of ``kv_pairs`` pairs lies at rows ``at x kv_pairs`` on: never a whole sublane tile where the pairs are five
+# (float32: tiles of 8) or the published ten (bfloat16: tiles of 16), across two at ``at`` 1, and at the ring's last
+# row in the tiles whose start is clamped. Sequences (state rows, ``at``, ``live``) of a batch of three; row 0 is null.
+WRITES = {
+    "at_the_rings_start": ((2, 3, 1), (0, 0, 0), (1, "W", 1)),
+    "pairs_across_two_tiles": ((2, 3, 1), (1, 1, 1), (2, "W", 2)),
+    "the_rings_last_row": ((1, 2, 3), ("W-1", "W-1", "W-1"), ("W", "W", "W")),
+    "a_ring_that_wrapped": ((3, 1, 2), (3, 5, 2), ("W", "W", "W")),
+    "a_ring_shorter_than_the_window": ((2, 3, 1), (2, 4, 0), (3, 5, 1)),
+    "an_inactive_slot_among_live_ones": ((2, 0, 3), (3, 6, 1), ("W", 0, 2)),
+    "two_calls_at_one_position": ((2, 3, 1), (1, "W-1", 4), (2, "W", "W")),
+}
+
+
+@pytest.mark.parametrize("case", list(WRITES))
+@pytest.mark.parametrize("dtype, window, kv_pairs, wide", [("float32", 8, 5, 2 * HALF), ("bfloat16", 16, 10, 128)])
+def test_the_ring_kernel_writes_its_row_as_a_scatter_would_and_attends_over_it(case, dtype, window, kv_pairs, wide):
+    """The rings that come back are bit for bit ``_write_spans``' (the live
+    sequences' rows written, the null row and every other row as they came),
+    and ``o`` is ``diff_attention_rows`` over them: the new row is scored where
+    the stale one lay."""
+    pairs, layer, scale = 2 * kv_pairs, 1, (wide // 2) ** -0.5
+    rows, at, live = (jnp.asarray([{"W": window, "W-1": window - 1}.get(x, x) for x in xs]) for xs in WRITES[case])
+    qp, new_k, new_v = normal(13, 3, pairs, wide), normal(14, 3, kv_pairs, wide), normal(15, 3, kv_pairs, wide)
+    rings = [normal(seed, 2, 4, window * kv_pairs, wide).astype(dtype) for seed in (16, 17)]
+    kernel = functools.partial(W.ring_window_attention, kv_pairs=kv_pairs, scale=scale, interpret=True)
+    o, *got = kernel(qp, new_k, new_v, *rings, layer, rows, live, at, LAM)
+    if case == "two_calls_at_one_position":  # the replay: the same row again, the same output
+        once, (o, *got) = o, kernel(qp, new_k, new_v, *got, layer, rows, live, at, LAM)
+        np.testing.assert_array_equal(o, once)
+    held = np.flatnonzero(np.asarray(live))
+    want = [M._write_spans(ring, (layer, rows[held]), at[held] * kv_pairs, new[held]) for ring, new in zip(rings, (new_k, new_v))]
+    for g, w, ring in zip(got, want, rings):
+        assert g.dtype == ring.dtype
+        np.testing.assert_array_equal(np.asarray(g, np.float32), np.asarray(w, np.float32))
+        np.testing.assert_array_equal(np.asarray(g[:, 0], np.float32), np.asarray(ring[:, 0], np.float32))  # the null row
+    k, v = (w[layer, rows].reshape(3, window, kv_pairs, wide) for w in want)
+    same = W.diff_attention_rows(qp, k, v, jnp.arange(window)[None, :] < live[:, None], LAM, scale=scale)
+    tol = 2e-5 if dtype == "float32" else 2e-2  # bfloat16 weights into the sums: a rounding apart, not a row apart
+    np.testing.assert_allclose(o, same, atol=tol, rtol=tol)
+    assert not np.asarray(o)[np.asarray(live) == 0].any()
+
+
+def test_a_decode_step_with_the_kernel_in_it_is_the_step_that_scatters_and_gathers(monkeypatch):
+    """``models/phi4flash.py``'s decode step on the path it takes on a TPU (the
+    kernel writes the ring's row; here in interpret mode) against the path it
+    takes elsewhere (``_write_spans`` and the gathered ring): a prompt shorter
+    than the window, then steps through the ring's wrap, an inactive slot
+    beside it."""
+    cfg = M.Phi4FlashConfig(
+        vocab_size=64, hidden_size=64, intermediate_size=96, num_hidden_layers=8, num_attention_heads=4,
+        num_key_value_heads=2, max_position_embeddings=64, sliding_window=8, ssm_dt_rank=4, dtype=jnp.float32)
+    params = M.init_params(jax.random.PRNGKey(3), cfg)
+    block, per_seq, prompt = 4, 9, [5, 9, 2, 44, 17]
+    table = jnp.asarray([[0] * per_seq, [1, 2, 3, 4, 5, 6, 7, 8, 1]], jnp.int32)  # slot 0 inactive; blocks 1-8, state row 1
+    tokens = jnp.zeros((1, 8), jnp.int32).at[0, :len(prompt)].set(jnp.asarray(prompt))
+
+    def run():
+        prefill, decode, _ = paged.make_paged_fns(M.paged_layer, cfg, block_size=block, state_rows=True)
+        logits, pool = prefill(params, tokens, table[1:], M.init_paged_pool(cfg, 9, block, 2), jnp.int32(len(prompt)))
+        out = []
+        for position in range(len(prompt), len(prompt) + 6):  # positions 5..10 of a window of 8
+            fed = jnp.asarray([0, int(jnp.argmax(logits[-1]))], jnp.int32)
+            logits, pool = decode(params, fed, jnp.asarray([0, position], jnp.int32), table, pool, jnp.asarray([False, True]))
+            out.append(np.asarray(logits[1]))
+        return np.stack(out), jax.tree.map(np.asarray, pool)
+
+    gathered, pool = run()
+    monkeypatch.setattr(M, "can_use_ring_kernel", lambda *_: True)
+    traced = []
+    monkeypatch.setattr(M, "ring_window_attention", lambda *a, **kw: traced.append(a[0].shape) or W.ring_window_attention(
+        *a, **kw, interpret=True))
+    kernel, kernel_pool = run()
+    assert traced == [(2, cfg.q_pairs, cfg.pair_dim)]  # the window section's one trace, in the decode step alone
+    np.testing.assert_allclose(kernel, gathered, atol=2e-4, rtol=2e-4)
+    for name in ("ring_k", "ring_v"):
+        np.testing.assert_allclose(kernel_pool[name][:, 1], pool[name][:, 1], atol=2e-5, rtol=2e-5)
+        assert np.abs(kernel_pool[name][:, 1]).min(axis=-1).all()  # every row of the ring written by now
+        assert not kernel_pool[name][:, 0].any()  # the null row: the inactive slot wrote nothing
 
 
 @pytest.mark.parametrize("kv_pairs, pairs", [(2, 4), (5, 10)])
