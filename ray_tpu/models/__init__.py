@@ -37,6 +37,7 @@ PAGED_KINDS = {
     "kimi_k2": ("ray_tpu.models.kimi", "KimiConfig"),
     "olmo_hybrid": ("ray_tpu.models.olmo_hybrid", "OlmoHybridConfig"),
     "phi4flash": ("ray_tpu.models.phi4flash", "Phi4FlashConfig"),
+    "exaone_moe": ("ray_tpu.models.exaone_moe", "ExaoneMoeConfig"),
 }
 
 

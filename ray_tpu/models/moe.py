@@ -129,11 +129,15 @@ def window_rows(n_rows: int, held: int, n_outputs: int) -> int:
     """Rows of one window of the grouped matmuls, from static shapes alone:
     the smallest power of two at or above ``WINDOW_MULTIPLE`` times the held
     rows an even router sends (``n_rows x held / n_outputs``), at least
-    ``WINDOW_MIN`` and at most every row."""
+    ``WINDOW_MIN`` and at most every row; and, where it is not every row, at
+    most the row tile the grouped kernel takes (``_KERNEL_ROWS``): a chip that
+    holds an eighth of the experts owns an eighth of a long prefill's rows, and
+    a window past the tile would send all of them through ``ragged_dot``
+    (PERF.md section 6, PR 43)."""
     window = WINDOW_MIN
     while window * n_outputs < WINDOW_MULTIPLE * n_rows * held:
         window *= 2
-    return min(window, n_rows)
+    return n_rows if window >= n_rows else min(window, _KERNEL_ROWS)
 
 
 # The grouped kernel's tiles: a window is one row tile, whatever its rows (so

@@ -69,7 +69,7 @@ from ray_tpu.ops.gated_delta import short_conv_step
 from ray_tpu.ops.layers import layer_norm, rms_norm
 from ray_tpu.ops.paged_attention import can_use_paged_kernel, paged_decode_attention
 from ray_tpu.ops.window_attention import (can_use_ring_kernel, diff_attention_prefill, diff_attention_rows,
-                                          ring_window_attention, split_queries)
+                                          ring_window_attention, split_queries, write_spans as _write_spans)
 
 @dataclasses.dataclass(frozen=True)
 class Phi4FlashConfig:
@@ -205,19 +205,6 @@ def paged_state_bytes(cfg: Phi4FlashConfig) -> int:
     state = cfg.ssm_state_size * cfg.d_inner * 4
     window = cfg.ssm_conv_kernel * cfg.d_inner * jnp.dtype(cfg.dtype).itemsize
     return paged_ring(cfg)["bytes"] + cfg.n_ssm * (state + window + 4)
-
-
-def _write_spans(arr, lead, starts, updates):
-    """``arr[*lead_b, starts[b] : starts[b] + n] = updates[b]`` for every
-    ``b``: ``arr`` (*leading, rows, wide), ``lead`` the leading indices (each
-    a scalar or (B,)), ``updates`` (B, n, wide). One scatter of B windows, not
-    of B x n rows."""
-    b = updates.shape[0]
-    index = jnp.stack([jnp.broadcast_to(jnp.asarray(i, jnp.int32), (b,)) for i in (*lead, starts)], axis=-1)
-    dims = jax.lax.ScatterDimensionNumbers(
-        update_window_dims=(1, 2), inserted_window_dims=tuple(range(len(lead))),
-        scatter_dims_to_operand_dims=tuple(range(len(lead) + 1)))
-    return jax.lax.scatter(arr, index, updates.astype(arr.dtype), dims)
 
 
 def paged_layer(cfg: Phi4FlashConfig, params, step):

@@ -162,3 +162,23 @@ def test_the_grouped_kernel_at_its_tiling_agrees_with_ragged_dot():
     want = jax.lax.ragged_dot(x, w, groups, preferred_element_type=jnp.float32)
     np.testing.assert_allclose(np.asarray(got)[:21], np.asarray(want)[:21], rtol=2e-2, atol=2e-2)
     np.testing.assert_array_equal(np.asarray(want)[:21] != 0, True)
+
+
+def test_a_window_never_exceeds_the_kernels_row_tile_and_the_older_kinds_windows_are_what_they_were():
+    """``window_rows`` at the shapes the benchmark's kinds reach. Kimi-K2 (12 of
+    384, top-8) and LongCat (16 of 768 outputs, top-12) never met the cap: their
+    largest windows are 512 exactly, and their programs lower as before.
+    K-EXAONE holds an eighth of its experts: its 512 and 1,024 buckets would
+    have had windows of 1,024 and 2,048 rows, past the grouped kernel's row
+    tile, and walk windows of 512 instead. A layer that holds every expert
+    keeps one window of every row."""
+    kimi = {48: 32, 128: 64, 256: 128, 512: 256, 1024: 512}
+    for tokens, window in kimi.items():
+        assert moe.window_rows(tokens * 8, 12, 384) == window
+    longcat = {32: 32, 256: 128, 512: 256, 1024: 512}
+    for tokens, window in longcat.items():
+        assert moe.window_rows(tokens * 12, 16, 768) == window
+    exaone = {48: 128, 256: 512, 512: 512, 1024: 512}
+    for tokens, window in exaone.items():
+        assert moe.window_rows(tokens * 8, 16, 128) == window <= moe._KERNEL_ROWS
+    assert moe.window_rows(4096, 8, 8) == 4096 and moe.window_rows(48 * 3, 4, 8) == 144

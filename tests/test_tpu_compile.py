@@ -505,6 +505,93 @@ def test_phi4flash_decode_and_prefill_at_published_widths(one_chip, monkeypatch)
     assert mem.temp_size_in_bytes < 1.0e9
 
 
+def test_exaone_moe_decode_and_prefill_at_published_widths(one_chip, monkeypatch):
+    """serve.llm's programs for K-EXAONE-236B-A23B as the benchmark's
+    configuration cuts it (``benchmarks/configs/k-exaone-236b-8l.json``): the
+    published widths, layers 0-7 as three sections of whole periods (dense W;
+    expert W W F; expert W W W F), 16 of 128 experts, an eighth of the
+    vocabulary, the engine's 48 slots over 7,681 blocks of the two full layers
+    and 49 state rows of six rings. The file's arithmetic against the compiler:
+    11.96 GB of weights, a 1.01 GB K/V pool and 0.15 GB of rings are the
+    programs' arguments, 13.1 GB, and the pool comes back in place. The decode
+    step holds the ring's kernel a window layer (its plain form: it writes the
+    step's row, so no scatter into a ring is left), the paged kernel a full
+    layer over the flat pool of eight heads, and three grouped matmuls an expert
+    layer over windows of 128 rows; no conditional (a ``lax.cond`` on the layer's
+    kind copied both rings whole in its full branch), no pool or ring copied. A
+    prefill of 1,024 walks windows of 512 rows through the grouped kernel (no
+    ``ragged-dot``), holds the flash kernel a full layer and writes each ring
+    once; its own memory stays under 0.5 GB."""
+    import json
+    import re
+
+    from benchmarks.families import exaone_moe as family
+    from ray_tpu.models import exaone_moe as M, paged
+    from ray_tpu.serve.llm.deployment import _resolve_model_cfg
+
+    _steered_to_tpu(monkeypatch)
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks", "configs", "k-exaone-236b-8l.json")) as f:
+        config = json.load(f)
+    cfg = _resolve_model_cfg(family.model_kwargs(config))
+    e = config["engine"]
+    block, blocks, batch, per_seq = e["block_size"], e["num_blocks"], e["max_batch"], e["max_blocks_per_seq"]
+    prefill, _, decode_greedy = paged.make_paged_fns(M.paged_layer, cfg, block_size=block, state_rows=True)
+    params = _on(one_chip, jax.eval_shape(lambda: M.init_params(jax.random.PRNGKey(0), cfg)))
+    pool = _on(one_chip, jax.eval_shape(lambda: M.init_paged_pool(cfg, blocks, block, batch + 1)))
+
+    def nbytes(tree):
+        return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
+
+    assert 11.95e9 < nbytes(params) < 11.97e9
+    assert 1.00e9 < nbytes(pool["k"]) + nbytes(pool["v"]) == blocks * M.paged_block_bytes(cfg, block) < 1.01e9
+    rings = nbytes(pool["ring_k"]) + nbytes(pool["ring_v"])
+    assert 0.15e9 < rings == (batch + 1) * M.paged_state_bytes(cfg) == (batch + 1) * M.paged_ring(cfg)["bytes"] < 0.16e9
+    assert pool["k"].shape == (2, blocks * block * 8, 128) and pool["ring_k"].shape == (6, 49, 1024, 128)
+    assert params["e_gate"].shape == (7, 16, 6144, 2048) and params["e_down"].shape == (7, 16, 2048, 6144)
+    assert not hasattr(M, "paged_layouts")  # every projection's contraction lies in the tiles as it is stacked
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    flat, ring = f"{blocks * block * 8},128", "49,1024,128"
+
+    def pools_copied(text):
+        """Instructions of their own that make a pool, or a layer of one, anew."""
+        pools = {f"{lead}{dims}" for dims in (flat, ring) for lead in ("", "1,", "2,", "6,")}
+        return [(dims, op) for dims, _, op in _alone(text)
+                if dims in pools and op in ("copy", "transpose", "gather", "dynamic-slice")]
+
+    def ring_writes(text):
+        return re.findall(rf"= bf16\[6,{ring}\]\S* (?:dynamic-update-slice|scatter)\(", text)
+
+    def gmm_rows(text):
+        calls = [line for line in text.splitlines() if re.search(r"%gmm[.\d]* = ", line)]
+        return sorted(int(re.search(r"= \w+\[(\d+),\d+\]", line).group(1)) for line in calls)
+
+    compiled = decode_greedy.lower(
+        params, arg((batch,), jnp.int32), arg((batch,), jnp.int32), arg((batch, per_seq), jnp.int32), pool,
+        arg((batch,), jnp.bool_),
+    ).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    kernels = _kernels(text)
+    # a section's body holds its layers' kernels once: (W), (W W F), (W W W F); three grouped matmuls an expert layer
+    assert (kernels.count("ring_window_attention"), kernels.count("paged_decode_attention"), kernels.count("gmm")) == (6, 2, 21)
+    assert gmm_rows(text) == [128] * 21 and "ragged-dot" not in text and " conditional(" not in text
+    assert not pools_copied(text)
+    assert not ring_writes(text) and "ring_scatter" not in text  # the ring's kernel writes a step's row itself
+    assert 13.0e9 < mem.argument_size_in_bytes < 13.2e9 and mem.temp_size_in_bytes < 0.1e9
+    assert mem.alias_size_in_bytes > 0.999 * nbytes(pool)  # the pool comes back in place
+    compiled = prefill.lower(
+        params, arg((1, 1024), jnp.int32), arg((1, per_seq), jnp.int32), pool, arg((), jnp.int32)).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    kernels = _kernels(text)
+    assert (kernels.count("flash_attention"), kernels.count("gmm")) == (2, 21) and gmm_rows(text) == [512] * 21
+    assert "ragged-dot" not in text and not {"ring_window_attention", "paged_decode_attention"} & set(kernels)
+    assert len(ring_writes(text)) == 12 and "ring_scatter" in text  # a prompt's whole ring, K and V, a window layer
+    assert not pools_copied(text)
+    assert mem.temp_size_in_bytes < 0.5e9
+
+
 def _steered_to_tpu(monkeypatch):
     """``attention`` asks ``jax.default_backend()``, which is the CPU here:
     the test steers it to the branch it takes on the chip."""

@@ -223,3 +223,85 @@ def test_the_kernels_are_chosen_from_platform_and_shape_alone(monkeypatch):
     assert W.can_use_ring_kernel(512, 10, 128, jnp.bfloat16) and not W.can_use_ring_kernel(512, 10, 64, jnp.bfloat16)
     # a flat chunk's columns are whole lane tiles: 24 blocks of 160 rows, not the 25 a megabyte takes
     assert chunk_blocks_for(192, 16 * 10 * 128 * 2, whole=4) == 24 and chunk_blocks_for(192, 16 * 16 * 128 * 2) == 16
+
+
+# -- the plain form: one softmax of grouped queries (K-EXAONE's window layers) ---------------------
+
+
+def plain(q, k, v, sees, scale):
+    """One softmax a head: q (Q, H, d), k, v (M, G, d), sees (Q, M) bool."""
+    q, k, v = (np.asarray(x, np.float64) for x in (q, k, v))
+    out = np.zeros(q.shape)
+    for h in range(q.shape[1]):
+        g = h // (q.shape[1] // k.shape[1])
+        s = np.where(sees, q[:, h] @ k[:, g].T * scale, -np.inf)
+        e = np.exp(s - s.max(-1, keepdims=True))
+        out[:, h] = (e / e.sum(-1, keepdims=True)) @ v[:, g]
+    return out
+
+
+@pytest.mark.parametrize("window", [None, 8, 16])
+@pytest.mark.parametrize("s", [32, 8])
+def test_a_prompts_banded_blocks_are_masked_plain_attention(window, s):
+    d = 2 * HALF
+    q, k, v = normal(1, 1, s, P, d), normal(2, 1, s, G, d), normal(3, 1, s, G, d)
+    got = W.window_attention_prefill(q, k, v, scale=d ** -0.5, window=window, block=8)
+    pos = np.arange(s)
+    sees = pos[None, :] <= pos[:, None]
+    if window:
+        sees &= pos[None, :] > pos[:, None] - window
+    np.testing.assert_allclose(got[0], plain(q[0], k[0], v[0], sees, d ** -0.5), atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("case", list(WRITES))
+@pytest.mark.parametrize("dtype, window, heads, kv_heads, wide", [("float32", 8, 6, 3, 2 * HALF), ("bfloat16", 16, 64, 8, 128)])
+def test_the_ring_kernels_plain_form_writes_its_row_and_is_one_softmax_over_the_live_rows(case, dtype, window, heads,
+                                                                                       kv_heads, wide):
+    """``lam`` None: the queries as they are (64 heads of 128 over 8 K/V heads,
+    the published widths, and six over three in tiles of 8), one softmax, no
+    second half. The rings come back bit for bit a scatter's, the null row
+    untouched; ``o`` is ``window_attention_rows`` over them."""
+    layer, scale = 1, wide ** -0.5
+    rows, at, live = (jnp.asarray([{"W": window, "W-1": window - 1}.get(x, x) for x in xs]) for xs in WRITES[case])
+    q, new_k, new_v = normal(13, 3, heads, wide), normal(14, 3, kv_heads, wide), normal(15, 3, kv_heads, wide)
+    rings = [normal(seed, 2, 4, window * kv_heads, wide).astype(dtype) for seed in (16, 17)]
+    kernel = functools.partial(W.ring_window_attention, kv_pairs=kv_heads, scale=scale, interpret=True)
+    o, *got = kernel(q, new_k, new_v, *rings, layer, rows, live, at, None)
+    if case == "two_calls_at_one_position":
+        once, (o, *got) = o, kernel(q, new_k, new_v, *got, layer, rows, live, at, None)
+        np.testing.assert_array_equal(o, once)
+    held = np.flatnonzero(np.asarray(live))
+    want = [W.write_spans(ring, (layer, rows[held]), at[held] * kv_heads, new[held]) for ring, new in zip(rings, (new_k, new_v))]
+    for g, w, ring in zip(got, want, rings):
+        assert g.dtype == ring.dtype
+        np.testing.assert_array_equal(np.asarray(g, np.float32), np.asarray(w, np.float32))
+        np.testing.assert_array_equal(np.asarray(g[:, 0], np.float32), np.asarray(ring[:, 0], np.float32))  # the null row
+    k, v = (w[layer, rows].reshape(3, window, kv_heads, wide) for w in want)
+    same = W.window_attention_rows(q, k, v, jnp.arange(window)[None, :] < live[:, None], scale=scale)
+    tol = 2e-5 if dtype == "float32" else 2e-2
+    assert o.shape == (3, heads, wide)
+    np.testing.assert_allclose(o, same, atol=tol, rtol=tol)
+    assert not np.asarray(o)[np.asarray(live) == 0].any()
+    if dtype == "float32":  # and the rows form is the statement: one softmax a head over the live rows
+        for b in held:
+            sees = (np.arange(window) < int(live[b]))[None]
+            np.testing.assert_allclose(same[b], plain(q[b][None], k[b], v[b], sees, scale)[0], atol=2e-5, rtol=2e-5)
+
+
+def test_the_two_forms_are_told_apart_by_lam_alone_and_the_differential_calls_trace_as_before():
+    """The plain form's call has no ``lam`` operand and half the query rows
+    (no split); the differential call's operands are what they were: queries,
+    ``lam``, the mask, the rows' index, the new rows, the rings."""
+    q, new = normal(1, 2, 4, 16), normal(2, 2, 2, 16)
+    rings = [normal(s, 1, 3, 8 * 2, 16) for s in (3, 4)]
+    rows, live, at = jnp.asarray([1, 2]), jnp.asarray([3, 8]), jnp.asarray([2, 5])
+
+    def operands(lam):
+        jaxpr = jax.make_jaxpr(lambda *a: W.ring_window_attention(*a, 0, rows, live, at, lam, kv_pairs=2, scale=0.25,
+                                                                  interpret=True))(q, new, new, *rings)
+        (call,) = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "pallas_call"]
+        return [tuple(v.aval.shape) for v in call.invars]
+
+    both, one = operands(0.3), operands(None)
+    assert both[4:6] == [(2, 16, 16), (1, 16)] and one[4] == (2, 8, 16)  # [q1; 0] and [0; q2] padded to 8 each, lam
+    assert len(both) == len(one) + 1 and both[6:][1:] == one[5:][1:]  # beyond the mask's rows, the same operands
