@@ -48,7 +48,10 @@ counts:
   whole sublane tile of bfloat16, and ``ops/paged_attention.py`` takes a flat
   pool of any head count whose block is whole tiles). Full layer ``i`` is the
   pool's layer ``i // 4``. A block holds the full layers' rows alone
-  (``paged_block_bytes``): a quarter of what every layer's would cost.
+  (``paged_block_bytes``): a quarter of what every layer's would cost. On a
+  TPU a decode step's own row is written by ``paged_decode_attention``, into
+  the blocks it scores; elsewhere, and in every prefill, rows are scattered
+  (``write_spans``) and a decode step gathers its table's.
 * ``ring_k``, ``ring_v`` (window layers, state rows, W x G, d): a **ring** of
   the last W positions' rows a sequence a window layer, in the sequence's state
   row (``models/phi4flash.py`` says how a state row is handed out). Window
@@ -345,19 +348,23 @@ def paged_layer(cfg: ExaoneMoeConfig, params, step):
         with jax.named_scope("proj"):
             q, k, v = _qkv(cfg, at(li), u, None)
         kv = {"k": pool["k"], "v": pool["v"]}
-        with jax.named_scope("paged_scatter"):
-            if decode or s % bs:
-                starts, spans = step.write_slots * G, (k.reshape(b * s, G, d), v.reshape(b * s, G, d))
-            else:  # a block a window: a prompt's rows past its length lie behind the mask where they land
-                starts = (step.block_tables[:, :s // bs] * (bs * G)).reshape(-1)
-                spans = (k.reshape(-1, bs * G, d), v.reshape(-1, bs * G, d))
-            kv = {name: write_spans(kv[name], (fi,), starts, t) for name, t in zip(("k", "v"), spans)}
+        kernel = decode and can_use_paged_kernel(q, kv["k"], bs, G)
+        if not kernel:
+            with jax.named_scope("paged_scatter"):
+                if decode or s % bs:
+                    starts, spans = step.write_slots * G, (k.reshape(b * s, G, d), v.reshape(b * s, G, d))
+                else:  # a block a window: a prompt's rows past its length lie behind the mask where they land
+                    starts = (step.block_tables[:, :s // bs] * (bs * G)).reshape(-1)
+                    spans = (k.reshape(-1, bs * G, d), v.reshape(-1, bs * G, d))
+                kv = {name: write_spans(kv[name], (fi,), starts, t) for name, t in zip(("k", "v"), spans)}
         with jax.named_scope("paged_attn"):
             if not decode:
                 o = causal_attention(q, k, v, causal=True)
-            elif can_use_paged_kernel(q, kv["k"], bs, G):
-                o = paged_decode_attention(q[:, 0], kv["k"], kv["v"], fi, step.block_tables, step.lengths,
-                                           block_size=bs, kv_heads=G)[:, None]
+            elif kernel:  # the kernel puts the row in its block and scores the blocks with it there
+                o, kv["k"], kv["v"] = paged_decode_attention(
+                    q[:, 0], kv["k"], kv["v"], fi, step.block_tables, step.lengths, block_size=bs, kv_heads=G,
+                    new_k=k[:, 0], new_v=v[:, 0])
+                o = o[:, None]
             else:
                 with jax.named_scope("paged_gather"):
                     slots = (step.block_tables[:, :, None] * bs + jnp.arange(bs)).reshape(b, -1)

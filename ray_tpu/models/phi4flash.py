@@ -34,7 +34,10 @@ entry of the last section).
   one head of 128; the pool is *flat* (a slot's ten pairs are ten consecutive
   rows) because ten heads are no whole sublane tile and a (slots, 10, 128) array
   is padded to 16 on the device. ``paged_decode_attention`` reads it with the
-  queries ``[q1; 0]`` and ``[0; q2]``; the subtraction is here.
+  queries ``[q1; 0]`` and ``[0; q2]``; the subtraction is here. On a TPU the
+  full layer's call writes the decode step's own row too, into the cache it
+  scores; elsewhere, and in every prefill, rows are scattered
+  (``_write_spans``).
 * ``ring_k``, ``ring_v`` (window layers, state rows, W x K/V pairs, 128): a
   **ring** of the last W positions' rows a sequence a window layer, in the
   sequence's state row. Position p lies at ``p % W``; a decode step attends
@@ -307,21 +310,29 @@ def paged_layer(cfg: Phi4FlashConfig, params, step):
         y = rms_norm(o, w("subln"), eps) * (1.0 - lam0)
         return y.reshape(b, s, pairs * wide).astype(dtype) @ w("wo") + w("bo").astype(dtype)
 
-    def over_shared_cache(qp, pool, lam):
+    def over_shared_cache(qp, pool, lam, **new):
         """One query position a sequence (``qp`` (B, pairs, 128)) over the
         shared cache's positions [0, length): the paged kernel on the packed
-        pairs where it can run, the table's rows gathered elsewhere."""
+        pairs where it can run, the table's rows gathered elsewhere. -> (o, the
+        pool). ``new_k``, ``new_v`` (B, G, 128): the position's own row, which
+        the kernel writes into the cache before it scores it; where no kernel
+        runs the caller has scattered it."""
         k, v = pool["k"], pool["v"]
         if can_use_paged_kernel(qp[:, None], k, bs, G):
             packed = jnp.stack(split_queries(qp), axis=2).reshape(b, 2 * pairs, wide)
             o = paged_decode_attention(packed, k, v, 0, step.block_tables, step.lengths, block_size=bs, kv_heads=G,
-                                       scale=scale).reshape(b, pairs, 2, wide).astype(jnp.float32)
-            return o[:, :, 0] - lam * o[:, :, 1]
+                                       scale=scale, **new)
+            if new:
+                o, k, v = o
+                pool = {**pool, "k": k, "v": v}
+            o = o.reshape(b, pairs, 2, wide).astype(jnp.float32)
+            return o[:, :, 0] - lam * o[:, :, 1], pool
         with jax.named_scope("paged_gather"):
             slots = (step.block_tables[:, :, None] * bs + jnp.arange(bs)).reshape(b, -1)
             mine = (slots[:, :, None] * G + jnp.arange(G))  # (B, M, G): where each position's pairs lie
             k, v = k[0][mine], v[0][mine]
-        return diff_attention_rows(qp, k, v, jnp.arange(slots.shape[1])[None, :] < step.lengths[:, None], lam, scale=scale)
+        live = jnp.arange(slots.shape[1])[None, :] < step.lengths[:, None]
+        return diff_attention_rows(qp, k, v, live, lam, scale=scale), pool
 
     def own_attention(u, pool, ai, li, window: bool):
         """Attention ``ai`` (a window layer, or the full layer) of layer ``li``,
@@ -360,18 +371,18 @@ def paged_layer(cfg: Phi4FlashConfig, params, step):
                             for name, t in (("ring_k", k), ("ring_v", v))}
             pool = {**pool, **ring}
         else:
-            kv = {"k": pool["k"], "v": pool["v"]}
-            with jax.named_scope("paged_scatter"):
-                if decode or s % bs:
-                    starts, spans = step.write_slots * G, (k.reshape(b * s, G, wide), v.reshape(b * s, G, wide))
-                else:  # a block a window: a prompt's rows past its length lie behind the mask where they land
-                    starts = (step.block_tables[:, :s // bs] * (bs * G)).reshape(-1)
-                    spans = (k.reshape(-1, bs * G, wide), v.reshape(-1, bs * G, wide))
-                kv = {name: _write_spans(kv[name], (0,), starts, t) for name, t in zip(("k", "v"), spans)}
-            pool = {**pool, **kv}
+            if not (decode and can_use_paged_kernel(qp, pool["k"], bs, G)):  # else the kernel puts the row in the cache
+                with jax.named_scope("paged_scatter"):
+                    if decode or s % bs:
+                        starts, spans = step.write_slots * G, (k.reshape(b * s, G, wide), v.reshape(b * s, G, wide))
+                    else:  # a block a window: a prompt's rows past its length lie behind the mask where they land
+                        starts = (step.block_tables[:, :s // bs] * (bs * G)).reshape(-1)
+                        spans = (k.reshape(-1, bs * G, wide), v.reshape(-1, bs * G, wide))
+                    pool = {**pool, **{name: _write_spans(pool[name], (0,), starts, t) for name, t in zip(("k", "v"), spans)}}
             with jax.named_scope("paged_attn"):
                 if decode:
-                    o = over_shared_cache(qp[:, 0], pool, lam)[:, None]
+                    o, pool = over_shared_cache(qp[:, 0], pool, lam, new_k=k[:, 0], new_v=v[:, 0])
+                    o = o[:, None]
                 else:
                     o = diff_attention_prefill(qp, k, v, lam, scale=scale)
         with jax.named_scope("out"):
@@ -388,7 +399,7 @@ def paged_layer(cfg: Phi4FlashConfig, params, step):
             qp = (u @ w("wq") + w("bq").astype(dtype)).reshape(b, s, pairs, wide)
         with jax.named_scope("paged_attn"):
             if decode:
-                o = over_shared_cache(qp[:, 0], pool, lam)[:, None]
+                o = over_shared_cache(qp[:, 0], pool, lam)[0][:, None]
             else:  # every position of a prompt (a prefill cuts to its last: only a test asks for this)
                 mine = step.write_slots[:, None] * G + jnp.arange(G)
                 k, v = (pool[name][0][mine].reshape(b, s, G, wide) for name in ("k", "v"))

@@ -23,6 +23,12 @@ MXU has it to spare, and the copies set the kernel's time.
 A sequence's output depends on its own length, table and rows alone: it
 walks its own blocks in table order in chunks of a fixed size, whatever its
 neighbours hold.
+
+Handed the decode step's own K and V row (``new_k``, ``new_v``; flat pools),
+``paged_decode_attention`` writes it too: into the last chunk's buffer before
+that chunk is scored, and from there back to the pool, which is then the
+call's output in place of its input (``_kernel``). The models' layers then
+scatter nothing in a decode step.
 """
 
 from __future__ import annotations
@@ -50,13 +56,26 @@ def can_use_paged_kernel(q, pool_k, block_size: int, kv_heads: int = 0) -> bool:
     if jax.default_backend() != "tpu":
         return False
     _, s, heads, head_dim = q.shape
-    sublanes = 32 // jnp.dtype(pool_k.dtype).itemsize
+    sublanes = tile_rows(pool_k.dtype)
     if pool_k.ndim == 3:
         tiles = (block_size * kv_heads) % sublanes == 0
     else:
         kv_heads = pool_k.shape[2]
         tiles = kv_heads % sublanes == 0 and (block_size * kv_heads) % 128 == 0
     return s == 1 and head_dim % 128 == 0 and heads % kv_heads == 0 and tiles
+
+
+def tile_rows(dtype) -> int:
+    """Rows of a tile of the chip's memory in ``dtype``: 8 of 32 bits, 16 of 16."""
+    return 32 // jnp.dtype(dtype).itemsize
+
+
+def covering_span(n_rows: int, sublanes: int) -> int:
+    """Rows of the whole sublane tiles that cover ``n_rows`` consecutive rows
+    wherever they start, the start a multiple of ``n_rows``: one tile of 16
+    for 8 rows, two for 10 (which can start at row 14 of a tile)."""
+    furthest = sublanes - math.gcd(n_rows, sublanes)  # into a tile that a multiple of ``n_rows`` can start
+    return -(-(furthest + n_rows) // sublanes) * sublanes
 
 
 def chunk_blocks_for(max_blocks: int, block_bytes: int, chunk_bytes: int = _CHUNK_BYTES, whole: int = 1) -> int:
@@ -104,11 +123,29 @@ def softmax_start(heads: int, width: int):
 
 def _kernel(
     li_ref, len_ref, tbl_ref,  # scalar prefetch
-    q_ref, pk_ref, pv_ref,  # q (1, H, Hd) in VMEM; the pools in HBM
-    o_ref,
-    kbuf, vbuf, sem,  # (2, rows, KV, Hd) each, (2, rows x KV, Hd) over flat pools; DMA semaphores (2, 2)
-    *, block_size, chunk_blocks, kv_heads, scale,
+    q_ref,  # q (1, H, Hd) in VMEM
+    *refs,
+    block_size, chunk_blocks, kv_heads, scale,
 ):
+    """``refs``: ``pk_ref, pv_ref`` (the pools in HBM), ``o_ref``, ``kbuf,
+    vbuf`` ((2, rows, KV, Hd) each, (2, rows x KV, Hd) over flat pools), ``sem``
+    (DMA semaphores (2, 2)). Where the call **writes the step's own row**
+    (flat pools): ``nk_ref, nv_ref`` ((B, KV, Hd): every sequence's new row)
+    ahead of the pools, ``pk_out, pv_out`` (the pools again, the same buffers
+    as the inputs) behind ``o_ref`` and ``back_sem`` (a DMA semaphore a pool)
+    last. The row of position ``length - 1`` lies in the sequence's last live
+    block, so in its last chunk's buffer: once that chunk has come in, the
+    whole sublane tiles that cover the row (``span`` rows, inside the block: a
+    block is whole tiles and came in whole) are read, patched row by row and
+    stored, those tiles start on their way back to the pool, the chunk is
+    scored as it now lies, and the copies are waited for before the grid step
+    ends: the next sequence's first chunk lands in a buffer. An inactive slot
+    patches and writes nothing: the null block is no sequence's."""
+    writes = len(refs) > 6
+    if writes:
+        nk_ref, nv_ref, pk_ref, pv_ref, o_ref, pk_out, pv_out, kbuf, vbuf, sem, back_sem = refs
+    else:
+        pk_ref, pv_ref, o_ref, kbuf, vbuf, sem = refs
     b = pl.program_id(0)
     li = li_ref[0]
     length = len_ref[b]
@@ -143,6 +180,30 @@ def _kernel(
     def _():
         chunk_copies(0, 0, lambda c: c.start())
 
+    if writes:
+        sublanes = tile_rows(kbuf.dtype)
+        span = covering_span(kv_heads, sublanes)
+        at = jnp.maximum(length - 1, 0)  # the step's own position (an inactive slot: nothing below is started)
+        in_chunk = jax.lax.rem(at, rows)
+        first = in_chunk * kv_heads  # the new row's heads are rows first .. first + kv_heads - 1 of the last chunk's buffer
+        block_start = in_chunk // block_size * each
+        start = pl.multiple_of(jnp.minimum(first // sublanes * sublanes, block_start + each - span), sublanes)
+        tiles, last_slot = pl.ds(start, span), jax.lax.rem(jnp.maximum(n_chunks - 1, 0), 2)
+        home = pl.ds(pl.multiple_of(tbl_ref[b, at // block_size] * each + (start - block_start), sublanes), span)
+        back = [pltpu.make_async_copy(buf.at[last_slot, tiles], out.at[li, home], back_sem.at[j])
+                for j, (buf, out) in enumerate(((kbuf, pk_out), (vbuf, pv_out)))]
+
+        def write_row():
+            place = jax.lax.broadcasted_iota(jnp.int32, (span, head_dim), 0) - (first - start)
+            for new_ref, buf in ((nk_ref, kbuf), (nv_ref, vbuf)):
+                # through float32, which holds every value of the pool's type: a select of packed rows is not every chip's
+                new, held = new_ref[b].astype(jnp.float32), buf[last_slot, tiles, :].astype(jnp.float32)
+                for j in range(kv_heads):
+                    held = jnp.where(place == j, new[j:j + 1], held)
+                buf[last_slot, tiles, :] = held.astype(buf.dtype)
+            for copy in back:
+                copy.start()
+
     q = q_ref[0]
     # over flat pools a head count need not be a power of two: the column's head and row are worked out for
     # one row of columns, and broadcast in the comparisons
@@ -160,6 +221,8 @@ def _kernel(
             chunk_copies(c + 1, 1 - slot, lambda d: d.start())
 
         chunk_copies(c, slot, lambda d: d.wait())
+        if writes:
+            pl.when(c == n_chunks - 1)(write_row)
         s = jax.lax.dot_general(
             q, kbuf[slot].reshape(cols, head_dim), (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -174,11 +237,16 @@ def _kernel(
     m, l, acc = jax.lax.fori_loop(0, n_chunks, chunk_step, softmax_start(heads, head_dim))
     # an inactive slot (length 0) read nothing: its output is 0, not 0/0
     o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+    if writes:
+        @pl.when(length > 0)
+        def _():
+            for copy in back:
+                copy.wait()
 
 
 def paged_decode_attention(
     q, pool_k, pool_v, layer, block_tables, lengths, *, block_size: int, kv_heads: int = 0, scale=None,
-    interpret=False,
+    new_k=None, new_v=None, interpret=False,
 ):
     """q (B, H, Hd) against the pools (L, slots, KV, Hd), both in the model's
     dtype, at layer ``layer``; or against **flat** pools (L, slots x
@@ -192,7 +260,19 @@ def paged_decode_attention(
     are the caller's to keep in range (``BlockTable`` does). Returns
     (B, H, Hd) in q's dtype: softmax(q k^T / sqrt(Hd)) v over positions
     [0, length), scores and softmax in float32, the weights in q's dtype
-    into the weighted sum."""
+    into the weighted sum.
+
+    **With ``new_k``, ``new_v``** (B, ``kv_heads``, Hd), flat pools only: the
+    call writes them first, cast to the pools' type, as position ``length -
+    1``'s row of each sequence (stale as the pools come in), and returns
+    ``(o, pool_k, pool_v)``: the pools in place (``input_output_aliases``), bit
+    for bit what a scatter of the rows of the live sequences leaves (only the
+    whole sublane tiles that cover a row are written, from the chunk the kernel
+    scored; an inactive slot writes nothing), ``o`` bit for bit this call's
+    without rows over those pools. Live sequences hold distinct last blocks. A
+    call again at the same position writes the same row. A scatter ahead of the
+    kernel did the same for ~1.1 us an index on the chip behind a bounds check
+    and a select, 48 sequences x K and V a layer: as much as the attention took."""
     b, heads, head_dim = q.shape
     flat = pool_k.ndim == 3
     if not flat:
@@ -205,22 +285,37 @@ def paged_decode_attention(
     buffer = (2, rows * kv_heads, head_dim) if flat else (2, rows, kv_heads, head_dim)
     kernel = functools.partial(_kernel, block_size=block_size, chunk_blocks=chunk_blocks, kv_heads=kv_heads,
                                scale=scale or 1.0 / (head_dim**0.5))
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    o_shape, o_spec = jax.ShapeDtypeStruct(q.shape, q.dtype), pl.BlockSpec((1, heads, head_dim), lambda i, *_: (i, 0, 0))
+    news, writing = [], {}
+    if new_k is not None:
+        sublanes = tile_rows(pool_k.dtype)
+        if not flat or (block_size * kv_heads) % sublanes or covering_span(kv_heads, sublanes) > block_size * kv_heads:
+            raise ValueError(f"a step's row is written into flat pools whose blocks are whole tiles: {pool_k.shape}, "
+                             f"blocks of {block_size} x {kv_heads} rows")
+        news = [(new_k.astype(pool_k.dtype), pl.BlockSpec(new_k.shape, lambda i, *_: (0, 0, 0))),  # whole, every step
+                (new_v.astype(pool_v.dtype), pl.BlockSpec(new_v.shape, lambda i, *_: (0, 0, 0)))]
+        o_shape = (o_shape, jax.ShapeDtypeStruct(pool_k.shape, pool_k.dtype), jax.ShapeDtypeStruct(pool_v.shape, pool_v.dtype))
+        o_spec = (o_spec, in_hbm, in_hbm)
+        writing = {"input_output_aliases": {6: 1, 7: 2}}  # the pools, the last two operands, counted with the three scalars
     return pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=o_shape,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(b,),
             in_specs=[
                 pl.BlockSpec((1, heads, head_dim), lambda i, *_: (i, 0, 0)),
-                pl.BlockSpec(memory_space=pl.ANY),
-                pl.BlockSpec(memory_space=pl.ANY),
+                *(spec for _, spec in news),
+                in_hbm,
+                in_hbm,
             ],
-            out_specs=pl.BlockSpec((1, heads, head_dim), lambda i, *_: (i, 0, 0)),
+            out_specs=o_spec,
             scratch_shapes=[
                 pltpu.VMEM(buffer, pool_k.dtype),
                 pltpu.VMEM(buffer, pool_v.dtype),
                 pltpu.SemaphoreType.DMA((2, 2)),
+                *([pltpu.SemaphoreType.DMA((2,))] if news else []),
             ],
         ),
         # the V buffer is zeroed at the first sequence and the buffers pass
@@ -228,11 +323,12 @@ def paged_decode_attention(
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         name="paged_decode_attention",
         interpret=interpret,
+        **writing,
     )(
         jnp.asarray(layer, jnp.int32).reshape(1),
         lengths.astype(jnp.int32),
         block_tables.astype(jnp.int32),
-        q, pool_k, pool_v,
+        q, *(x for x, _ in news), pool_k, pool_v,
     )
 
 
@@ -249,7 +345,7 @@ def can_use_latent_kernel(s: int, r_kv: int, rows_pool) -> bool:
     if jax.default_backend() != "tpu":
         return False
     _, _, block_size, stored = rows_pool.shape
-    sublanes = 32 // jnp.dtype(rows_pool.dtype).itemsize
+    sublanes = tile_rows(rows_pool.dtype)
     return s == 1 and r_kv % 128 == 0 and stored % 128 == 0 and stored > r_kv and block_size % sublanes == 0
 
 

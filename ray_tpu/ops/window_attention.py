@@ -63,6 +63,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ray_tpu.ops.paged_attention import tile_rows
+
 _NEG_INF = -1e30
 _VMEM_LIMIT = 48 << 20  # two buffers each of a ring's K and V (1.3 MB at the published widths) and a (48, 5120) score block
 
@@ -146,15 +148,10 @@ diff_attention_rows, diff_attention_prefill = window_attention_rows, window_atte
 # -- the ring, a decode step ----------------------------------------------------------------
 
 
-def _sublanes(dtype) -> int:
-    """Rows of a tile of the chip's memory in ``dtype``: 8 of 32 bits, 16 of 16."""
-    return 32 // jnp.dtype(dtype).itemsize
-
-
 def can_use_ring_kernel(window: int, kv_pairs: int, wide: int, dtype) -> bool:
     """Platform and static shape alone, as ``can_use_paged_kernel``: a TPU, a
     stored pair of whole lane tiles and a ring of whole sublane tiles."""
-    return jax.default_backend() == "tpu" and wide % 128 == 0 and (window * kv_pairs) % _sublanes(dtype) == 0
+    return jax.default_backend() == "tpu" and wide % 128 == 0 and (window * kv_pairs) % tile_rows(dtype) == 0
 
 
 def _ring_kernel(li_ref, rows_ref, live_ref, at_ref, q_ref, *refs, half, scale, kv_pairs, span):
@@ -179,7 +176,7 @@ def _ring_kernel(li_ref, rows_ref, live_ref, at_ref, q_ref, *refs, half, scale, 
     lam_ref = refs[0] if half else None
     own_ref, row_ref, nk_ref, nv_ref, k_ref, v_ref, o_ref, k_out, v_out, sem = refs[bool(half):]
     i = pl.program_id(0)
-    live, (cols, wide), sublanes = live_ref[i], k_ref.shape[2:], _sublanes(k_ref.dtype)
+    live, (cols, wide), sublanes = live_ref[i], k_ref.shape[2:], tile_rows(k_ref.dtype)
     first = at_ref[i] * kv_pairs  # the new row's pairs are rows first .. first + kv_pairs - 1
     start = pl.multiple_of(jnp.minimum(first // sublanes * sublanes, cols - span), sublanes)
     tiles = pl.ds(start, span)
@@ -239,7 +236,7 @@ def ring_window_attention(qp, new_k, new_v, ring_k, ring_v, layer, rows, live, a
     cols = ring_k.shape[2]
     paired = lam is not None
     half = -(-pairs // 8) * 8  # whole float32 sublane tiles: the output's two halves part on a tile
-    sublanes = _sublanes(ring_k.dtype)
+    sublanes = tile_rows(ring_k.dtype)
     span = min(cols, (-(-(kv_pairs - 1) // sublanes) + 1) * sublanes)  # the whole tiles a row's pairs can lie across
     pad = lambda x: jnp.pad(x, ((0, 0), (0, half - pairs), (0, 0)))  # noqa: E731
     q = jnp.concatenate([pad(x) for x in split_queries(qp)], axis=1) if paired else pad(qp)

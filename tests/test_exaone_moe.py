@@ -22,7 +22,7 @@ import pytest  # noqa: E402
 from benchmarks.families import exaone_moe as F  # noqa: E402
 from benchmarks.reference import exaone_moe as R  # noqa: E402
 from ray_tpu.models import exaone_moe as M, moe, paged  # noqa: E402
-from ray_tpu.ops import window_attention as WA  # noqa: E402
+from ray_tpu.ops import paged_attention as PA, window_attention as WA  # noqa: E402
 from ray_tpu.serve.llm.deployment import LLMServer, _resolve_model_cfg  # noqa: E402
 from ray_tpu.serve.llm.kv_cache import BlockAllocator, BlockTable  # noqa: E402
 
@@ -254,6 +254,33 @@ def test_the_same_decode_step_dispatched_twice_leaves_the_pool_bit_for_bit(serve
         for name in ("k", "v"):
             np.testing.assert_array_equal(kept[name][:, mine], np.asarray(pool[name])[:, mine], err_msg=name)
         assert np.abs(kept["ring_k"][:, row]).max(axis=-1).all() and np.abs(kept["k"][:, mine[: table.length * G]]).max(axis=-1).all()
+
+
+def test_a_decode_step_with_the_paged_kernel_in_it_is_the_step_that_scatters_and_gathers(served, monkeypatch):
+    """The decode step on the path it takes on a TPU (the paged kernel writes
+    the full layers' rows; here in interpret mode) against the path it takes
+    elsewhere (``write_spans`` and the gathered table): twelve steps from
+    position 21 through three block boundaries, an inactive slot either side."""
+    cfg, params, gathered, _, pool, table = served
+    _, fresh, _ = prefill_into(cfg, params, fresh_pool(cfg), BlockAllocator(BLOCKS, BLOCK, state_rows=ROWS), PROMPT)
+    monkeypatch.setattr(M, "can_use_paged_kernel", lambda *_: True)
+    traced = []
+    monkeypatch.setattr(M, "paged_decode_attention", lambda *a, **kw: traced.append(kw["new_k"].shape) or PA.paged_decode_attention(
+        *a, **kw, interpret=True))
+    kernel, _, kernel_pool, kernel_table = run_paged(cfg, params, PROMPT)
+    G, d = cfg.num_key_value_heads, cfg.head_dim
+    assert traced == [(3, G, d)] * 2  # a trace a section with a full layer, in the decode step alone
+    np.testing.assert_allclose(kernel, gathered, atol=2e-4, rtol=2e-4)
+    assert kernel_table.blocks == table.blocks
+    rows = (np.asarray(table.blocks)[:, None] * BLOCK + np.arange(BLOCK)).reshape(-1)[:table.length]
+    mine = (rows[:, None] * G + np.arange(G)).reshape(-1)
+    for name in ("k", "v"):
+        got, want = np.asarray(kernel_pool[name]), np.asarray(pool[name])
+        np.testing.assert_allclose(got[:, mine], want[:, mine], atol=2e-5, rtol=2e-5)
+        assert np.abs(got[:, mine]).max(axis=-1).all()  # every position's row written, a prompt's and a step's
+        # the null block: as the prefill left it (the inactive slots wrote nothing), where the scatter went on writing
+        np.testing.assert_array_equal(got[:, :BLOCK * G], np.asarray(fresh[name])[:, :BLOCK * G])
+        assert (want[:, :BLOCK * G] != got[:, :BLOCK * G]).any()
 
 
 def test_a_prompt_shorter_than_its_bucket_leaves_the_rows_of_an_exact_length_pass():

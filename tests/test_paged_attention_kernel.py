@@ -1,8 +1,13 @@
 """The decode step's paged-attention kernel (``ops/paged_attention.py``) in
 Pallas interpret mode on the CPU, against the path it replaces on a TPU: the
 table's rows gathered out of the pool and attended to as a masked dense block
-(``ops.attention.attention`` under the table's mask). The kernel's compile for the chip is in
-``test_tpu_compile.py``; its speed is the benchmark's."""
+(``ops.attention.attention`` under the table's mask); and the form that writes
+the decode step's own K and V row into flat pools, against a scatter of that
+row (``ops.window_attention.write_spans``) and then the form without rows. The
+kernel's compile for the chip is in ``test_tpu_compile.py``; its speed is the
+benchmark's."""
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -10,7 +15,8 @@ import numpy as np
 import pytest
 
 from ray_tpu.ops.attention import attention
-from ray_tpu.ops.paged_attention import can_use_paged_kernel, paged_decode_attention
+from ray_tpu.ops.paged_attention import can_use_paged_kernel, chunk_blocks_for, covering_span, paged_decode_attention
+from ray_tpu.ops.window_attention import write_spans
 
 # a chunk of the kernel is 16 of these blocks: a full table is two chunks and a half
 BLOCK, TABLE, LAYERS, POOL_BLOCKS, HEAD_DIM = 16, 40, 2, 176, 128
@@ -100,6 +106,79 @@ def test_a_sequence_reads_the_same_alone_and_among_neighbours(dtype):
             crowd_tables[row, : -(-n // BLOCK)] = rng.permutation(spare)[: -(-n // BLOCK)]
         among = _kernel(q[jnp.asarray([1, 2, 0, 3])], pk, pv, crowd_tables, crowd)
         assert np.array_equal(alone, np.asarray(among.astype(jnp.float32))[2]), length
+
+
+# The step's own row: position ``length - 1`` of four sequences, R a chunk's positions (16 x 32, 16, 24 or 12 blocks
+# by heads and type: the table is a chunk and a part, or three and a part), FULL the table's.
+ROWS = {
+    "first_in_a_block": (17, 33, 1, 49),
+    "last_in_a_block": (16, 32, 48, 16),
+    "last_of_a_chunks_last_block": ("R", "FULL", "R", 16),
+    "first_of_a_new_chunk": ("R+1", 1, "R+1", "FULL-15"),
+    "an_inactive_slot_among_live_ones": (0, 40, 0, 7),
+    "two_calls_at_one_position": (5, "R+1", 16, 33),
+}
+
+
+@functools.partial(jax.jit, static_argnums=0)  # one trace a form (with rows, without), a head count and a type
+def _flat_kernel(kv_heads, q, tables, lengths, pk, pv, **rows):
+    return paged_decode_attention(q, pk, pv, 1, tables, lengths, block_size=BLOCK, kv_heads=kv_heads, interpret=True, **rows)
+
+
+def _bits(x):
+    """An array's values as the integers they are stored as: -0.0 is not 0.0, and no NaN compares unequal to itself."""
+    x = np.asarray(x)
+    return x.view(f"u{x.dtype.itemsize}")
+
+
+@pytest.mark.parametrize("case", list(ROWS))
+@pytest.mark.parametrize("kv_heads", [8, 10], ids=["8_heads_half_a_bfloat16_tile", "10_pairs_across_two_tiles"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bfloat16_pool", "float32_pool"])
+def test_the_kernel_writes_the_steps_own_row_as_a_scatter_would_and_attends_over_it(dtype, kv_heads, case):
+    """The pools that come back are bit for bit ``write_spans``' over the live
+    sequences (every other row, the null block's among them, as it came), and
+    ``o`` is bit for bit the kernel's without rows over them: the new row is
+    scored where the stale one lay."""
+    chunk = BLOCK * chunk_blocks_for(TABLE, BLOCK * kv_heads * HEAD_DIM * jnp.dtype(dtype).itemsize,
+                                     whole=128 // np.gcd(BLOCK * kv_heads, 128))
+    assert chunk < FULL and covering_span(kv_heads, 32 // jnp.dtype(dtype).itemsize) == {
+        (8, 2): 16, (10, 2): 32, (8, 4): 8, (10, 4): 16}[kv_heads, jnp.dtype(dtype).itemsize]
+    lengths = np.asarray([{"R": chunk, "R+1": chunk + 1, "FULL": FULL, "FULL-15": FULL - 15}.get(n, n) for n in ROWS[case]],
+                         np.int32)
+    rng = np.random.default_rng(8)
+    pools = [jnp.asarray(rng.standard_normal((LAYERS, POOL_BLOCKS * BLOCK * kv_heads, HEAD_DIM)), dtype) for _ in range(2)]
+    tables = _tables(lengths, seed=9)
+    q = jnp.asarray(rng.standard_normal((4, 2 * kv_heads, HEAD_DIM)), dtype)
+    new = [jnp.asarray(rng.standard_normal((4, kv_heads, HEAD_DIM)), jnp.float32).at[0, 0, 0].set(-0.0) for _ in range(2)]
+    kernel = functools.partial(_flat_kernel, kv_heads, q, tables, lengths)
+
+    o, *got = kernel(*pools, new_k=new[0], new_v=new[1])
+    if case == "two_calls_at_one_position":  # the replay: the same row again, the same output
+        once, (o, *got) = o, kernel(*got, new_k=new[0], new_v=new[1])
+        np.testing.assert_array_equal(_bits(o), _bits(once))
+    held = np.flatnonzero(lengths)
+    at = lengths[held] - 1
+    slots = tables[held, at // BLOCK] * BLOCK + at % BLOCK
+    want = [write_spans(pool, (1,), jnp.asarray(slots * kv_heads), x[held]) for pool, x in zip(pools, new)]
+    for g, w, pool in zip(got, want, pools):
+        assert g.dtype == pool.dtype and g.shape == pool.shape
+        np.testing.assert_array_equal(_bits(g), _bits(w))
+        np.testing.assert_array_equal(_bits(g[:, :BLOCK * kv_heads]), _bits(pool[:, :BLOCK * kv_heads]))  # the null block
+        assert (_bits(g) != _bits(pool)).any(axis=-1).sum() == len(held) * kv_heads  # and nothing but the rows
+    np.testing.assert_array_equal(_bits(o), _bits(kernel(*want)))
+    assert not np.asarray(o.astype(jnp.float32))[lengths == 0].any()
+
+
+def test_rows_are_written_into_flat_pools_of_whole_tiles_alone():
+    q, new = jnp.zeros((2, 16, 128), jnp.bfloat16), jnp.zeros((2, 16, 128), jnp.bfloat16)
+    tables, lengths = jnp.zeros((2, 4), jnp.int32), jnp.zeros((2,), jnp.int32)
+    stored = jnp.zeros((1, 64, 16, 128), jnp.bfloat16)
+    with pytest.raises(ValueError, match="flat pools"):
+        paged_decode_attention(q, stored, stored, 0, tables, lengths, block_size=16, new_k=new, new_v=new, interpret=True)
+    flat = jnp.zeros((1, 64 * 10, 128), jnp.bfloat16)
+    with pytest.raises(ValueError, match="whole tiles"):  # blocks of 5 x 10 rows
+        paged_decode_attention(q[:, :10], flat, flat, 0, tables, lengths, block_size=5, kv_heads=10, new_k=new[:, :10],
+                               new_v=new[:, :10], interpret=True)
 
 
 GPTJ_Q, LLAMA7B_Q = (8, 1, 16, 256), (8, 1, 32, 128)

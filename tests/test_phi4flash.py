@@ -20,6 +20,7 @@ import pytest  # noqa: E402
 from benchmarks.families import phi4flash as F  # noqa: E402
 from benchmarks.reference import phi4flash as R  # noqa: E402
 from ray_tpu.models import paged, phi4flash as M  # noqa: E402
+from ray_tpu.ops import paged_attention as PA  # noqa: E402
 from ray_tpu.serve.llm.deployment import LLMServer, _resolve_model_cfg  # noqa: E402
 from ray_tpu.serve.llm.kv_cache import BlockAllocator, BlockTable  # noqa: E402
 
@@ -236,6 +237,37 @@ def test_the_same_decode_step_dispatched_twice_leaves_the_pool_bit_for_bit(serve
             np.testing.assert_array_equal(kept[name][:, row], np.asarray(pool[name])[:, row], err_msg=name)
         assert (kept["state_pos"][:, row] == table.length).all()
         assert (kept["state_pos"][:, 0] == 0).all() and not kept["state"][:, 0].any()  # the null row
+
+
+def test_a_decode_step_with_the_paged_kernel_in_it_is_the_step_that_scatters_and_gathers(monkeypatch):
+    """The decode step on the path it takes on a TPU (the paged kernel writes
+    the full layer's row into the shared cache and the cross layers read it
+    through the form without rows; here in interpret mode) against the path it
+    takes elsewhere (``_write_spans`` and the gathered table): eight steps from
+    position 21 through two block boundaries, an inactive slot either side."""
+    cfg = twin(num_attention_heads=8, num_key_value_heads=4)  # two K/V pairs: a block of 4 positions is a whole float32 tile
+    params = M.init_params(jax.random.PRNGKey(3), cfg)
+    G, steps = cfg.kv_pairs, 8
+    _, fresh, _ = prefill_into(cfg, params, fresh_pool(cfg), BlockAllocator(BLOCKS, BLOCK, state_rows=ROWS), PROMPT)
+    gathered, _, pool, table = run_paged(cfg, params, PROMPT, steps=steps)
+    monkeypatch.setattr(M, "can_use_paged_kernel", lambda *_: True)
+    traced = []
+    monkeypatch.setattr(M, "paged_decode_attention", lambda *a, **kw: traced.append("new_k" in kw) or PA.paged_decode_attention(
+        *a, **kw, interpret=True))
+    kernel, _, kernel_pool, kernel_table = run_paged(cfg, params, PROMPT, steps=steps)
+    # the prefill's cross layers on its last position; the decode step's full layer, with rows, and its cross layers
+    assert traced == [False, True, False]
+    np.testing.assert_allclose(kernel, gathered, atol=2e-4, rtol=2e-4)
+    assert kernel_table.blocks == table.blocks
+    rows = (np.asarray(table.blocks)[:, None] * BLOCK + np.arange(BLOCK)).reshape(-1)[:table.length]
+    mine = (rows[:, None] * G + np.arange(G)).reshape(-1)
+    for name in ("k", "v"):
+        got, want = np.asarray(kernel_pool[name]), np.asarray(pool[name])
+        np.testing.assert_allclose(got[:, mine], want[:, mine], atol=2e-5, rtol=2e-5)
+        assert np.abs(got[:, mine]).max(axis=-1).all()  # every position's row written, a prompt's and a step's
+        # the null block: as the prefill left it (the inactive slots wrote nothing), where the scatter went on writing
+        np.testing.assert_array_equal(got[:, :BLOCK * G], np.asarray(fresh[name])[:, :BLOCK * G])
+        assert (want[:, :BLOCK * G] != got[:, :BLOCK * G]).any()
 
 
 def test_a_state_row_handed_to_a_newcomer_carries_nothing_of_its_last_owner(served):

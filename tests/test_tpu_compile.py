@@ -480,6 +480,10 @@ def test_phi4flash_decode_and_prefill_at_published_widths(one_chip, monkeypatch)
         """The instructions, fused or alone, that write into a ring pool."""
         return re.findall(r"= bf16\[8,49,5120,128\]\S* (?:dynamic-update-slice|scatter)\(", text)
 
+    def cache_writes(text):
+        """The same for the shared cache's K and V."""
+        return re.findall(rf"= bf16\[1,{blocks * block * 10},128\]\S* (?:dynamic-update-slice|scatter)\(", text)
+
     compiled = decode_greedy.lower(
         params, arg((batch,), jnp.int32), arg((batch,), jnp.int32), arg((batch, per_seq), jnp.int32), pool,
         arg((batch,), jnp.bool_),
@@ -493,10 +497,13 @@ def test_phi4flash_decode_and_prefill_at_published_widths(one_chip, monkeypatch)
     assert mem.alias_size_in_bytes > 0.999 * nbytes(pool)  # the pool comes back in place
     # the ring's kernel writes a step's row itself: no scatter's loop over a ring is left in the step
     assert not ring_writes(text) and "ring_scatter" not in text  # in no instruction's ``op_name``
+    # and the paged kernel the full layer's, into the cache it scores (the pools its outputs in place): nor over the cache
+    assert not cache_writes(text) and "paged_scatter" not in text and " scatter(" not in text
     compiled = prefill.lower(
         params, arg((1, 2048), jnp.int32), arg((1, per_seq), jnp.int32), pool, arg((), jnp.int32)).compile()
     text, mem = compiled.as_text(), compiled.memory_analysis()
     assert len(ring_writes(text)) == 2 and "ring_scatter" in text  # a prompt's whole ring, K and V: one window a ring
+    assert len(cache_writes(text)) == 2 and "paged_scatter" in text  # a prompt's blocks, K and V: ``write_spans`` stays
     assert {"selective_scan_prefill", "paged_decode_attention"} <= set(_kernels(text))
     # (by kernel, not by text: a helper first traced inside ``ring_window_attention`` by another test of this process
     # keeps that frame's name in the module's table of stack frames)
@@ -564,6 +571,9 @@ def test_exaone_moe_decode_and_prefill_at_published_widths(one_chip, monkeypatch
     def ring_writes(text):
         return re.findall(rf"= bf16\[6,{ring}\]\S* (?:dynamic-update-slice|scatter)\(", text)
 
+    def pool_writes(text):
+        return re.findall(rf"= bf16\[2,{flat}\]\S* (?:dynamic-update-slice|scatter)\(", text)
+
     def gmm_rows(text):
         calls = [line for line in text.splitlines() if re.search(r"%gmm[.\d]* = ", line)]
         return sorted(int(re.search(r"= \w+\[(\d+),\d+\]", line).group(1)) for line in calls)
@@ -579,6 +589,9 @@ def test_exaone_moe_decode_and_prefill_at_published_widths(one_chip, monkeypatch
     assert gmm_rows(text) == [128] * 21 and "ragged-dot" not in text and " conditional(" not in text
     assert not pools_copied(text)
     assert not ring_writes(text) and "ring_scatter" not in text  # the ring's kernel writes a step's row itself
+    # and the paged kernel a full layer's, into the pools it scores (its outputs in place): no scatter over a pool is
+    # left in the step (the expert layers' sum of their rows is the one scatter it has)
+    assert not pool_writes(text) and "paged_scatter" not in text
     assert 13.0e9 < mem.argument_size_in_bytes < 13.2e9 and mem.temp_size_in_bytes < 0.1e9
     assert mem.alias_size_in_bytes > 0.999 * nbytes(pool)  # the pool comes back in place
     compiled = prefill.lower(
@@ -588,6 +601,7 @@ def test_exaone_moe_decode_and_prefill_at_published_widths(one_chip, monkeypatch
     assert (kernels.count("flash_attention"), kernels.count("gmm")) == (2, 21) and gmm_rows(text) == [512] * 21
     assert "ragged-dot" not in text and not {"ring_window_attention", "paged_decode_attention"} & set(kernels)
     assert len(ring_writes(text)) == 12 and "ring_scatter" in text  # a prompt's whole ring, K and V, a window layer
+    assert len(pool_writes(text)) == 4 and "paged_scatter" in text  # a prompt's blocks, K and V, a full layer: ``write_spans`` stays
     assert not pools_copied(text)
     assert mem.temp_size_in_bytes < 0.5e9
 
