@@ -82,6 +82,15 @@ LLM_MOE_FIELDS = (
     "windows",  # windows of held rows the grouped matmuls walked, a layer a step (1 a layer-step: none spilled)
     "layers",  # expert layers a decode step runs
 )
+# one read of how the dispatched sequences lay in their slots, once a flush interval: cumulative since the engine
+# started. The paged kernels start a sequence's first chunk during its predecessor's last where both slots are live
+# (``ops/paged_attention.py``): ``sum`` over ``count`` is the share of sequence-steps whose first copy was hidden so
+LLM_NEIGHBOUR_FIELDS = (
+    "t",
+    "step",  # decode steps dispatched so far: the counts cover exactly these
+    "count",  # live sequences dispatched, summed over steps
+    "sum",  # ... whose preceding slot was live too
+)
 # one engine's start, made when its loop's thread starts: where the time from
 # the constructor's first line to a replica that serves went
 LLM_START_FIELDS = (
@@ -113,7 +122,8 @@ COMPILE_STAGES = {
     "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load",
 }
 _KINDS = {"s": ("llm_step", LLM_STEP_RING_FIELDS), "r": ("llm_request", LLM_REQUEST_FIELDS),
-          "m": ("llm_moe", LLM_MOE_FIELDS), "b": ("llm_start", LLM_START_FIELDS),
+          "m": ("llm_moe", LLM_MOE_FIELDS), "n": ("llm_kv_neighbours", LLM_NEIGHBOUR_FIELDS),
+          "b": ("llm_start", LLM_START_FIELDS),
           "c": ("compile", COMPILE_FIELDS)}
 
 MAX_FILE_BYTES = 32 << 20  # a file past this moves to <name>.1 (one kept)

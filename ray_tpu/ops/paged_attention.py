@@ -24,6 +24,16 @@ A sequence's output depends on its own length, table and rows alone: it
 walks its own blocks in table order in chunks of a fixed size, whatever its
 neighbours hold.
 
+Both kernels run ahead *across* sequences: a sequence is only a few chunks
+(one to six at the served contexts), so before its last chunk is waited for
+and scored, the next sequence's first is started into the other buffer, and
+two words in SMEM carry from one grid step to the next which buffer that is
+and whether the copy is under way (``ahead``). A sequence behind an empty slot
+starts its own first chunk. Which buffer a chunk lands in reaches no output.
+``_kernel`` starts and waits for a chunk's copies in a loop over its live
+blocks; ``_latent_kernel`` keeps the walk unrolled over a chunk's places
+(``for_live_blocks``), which costs a replica's start seconds of tracing.
+
 Handed the decode step's own K and V row (``new_k``, ``new_v``; flat pools),
 ``paged_decode_attention`` writes it too: into the last chunk's buffer before
 that chunk is scored, and from there back to the pool, which is then the
@@ -139,18 +149,32 @@ def _kernel(
     block is whole tiles and came in whole) are read, patched row by row and
     stored, those tiles start on their way back to the pool, the chunk is
     scored as it now lies, and the copies are waited for before the grid step
-    ends: the next sequence's first chunk lands in a buffer. An inactive slot
-    patches and writes nothing: the null block is no sequence's."""
-    writes = len(refs) > 6
+    ends: the next sequence's second chunk lands in that buffer. An inactive
+    slot patches and writes nothing: the null block is no sequence's.
+
+    The copies run ahead *across* sequences, as ``_latent_kernel``'s do: before
+    a sequence's last chunk is waited for and scored, the next sequence's first
+    is started into the other buffer (never the one the row is patched in and
+    the tiles go back from; live sequences hold distinct last blocks, so it
+    reads no row this step writes). ``ahead`` (SMEM (2,), after ``sem``) carries
+    that from one grid step to the next: the buffer this sequence's first chunk
+    is in, and whether it is under way. An empty slot starts nothing for its
+    neighbour, which then starts its own. Which of the two buffers a chunk
+    lands in reaches no output: see the dead rows below."""
+    writes = len(refs) > 7
     if writes:
-        nk_ref, nv_ref, pk_ref, pv_ref, o_ref, pk_out, pv_out, kbuf, vbuf, sem, back_sem = refs
+        nk_ref, nv_ref, pk_ref, pv_ref, o_ref, pk_out, pv_out, kbuf, vbuf, sem, ahead, back_sem = refs
     else:
-        pk_ref, pv_ref, o_ref, kbuf, vbuf, sem = refs
-    b = pl.program_id(0)
+        pk_ref, pv_ref, o_ref, kbuf, vbuf, sem, ahead = refs
+    b, last = pl.program_id(0), pl.num_programs(0) - 1
     li = li_ref[0]
     length = len_ref[b]
-    n_blocks = (length + block_size - 1) // block_size
-    n_chunks = (n_blocks + chunk_blocks - 1) // chunk_blocks
+
+    def blocks_and_chunks(seq):
+        n_blocks = (len_ref[seq] + block_size - 1) // block_size
+        return n_blocks, (n_blocks + chunk_blocks - 1) // chunk_blocks
+
+    n_blocks, n_chunks = blocks_and_chunks(b)
     _, heads, head_dim = q_ref.shape
     flat = len(kbuf.shape) == 3  # the pools hold a slot's heads as rows: a block is copied as block_size x KV of them
     rows = chunk_blocks * block_size
@@ -164,21 +188,32 @@ def _kernel(
     @pl.when(b == 0)
     def _():
         vbuf[...] = jnp.zeros_like(vbuf)
+        ahead[0] = 0
+        ahead[1] = 0
 
-    def chunk_copies(chunk, slot, act):
-        def copies(block, j):
-            src = pl.ds(block * each, each)
-            dst = pl.ds(j * each, each)
-            return (
-                pltpu.make_async_copy(pk_ref.at[li, src], kbuf.at[slot, dst], sem.at[0, slot]),
-                pltpu.make_async_copy(pv_ref.at[li, src], vbuf.at[slot, dst], sem.at[1, slot]),
-            )
+    def chunk_copies(seq, seq_blocks, chunk, slot, act):
+        """``act`` on the two copies of every live block of chunk ``chunk`` of sequence ``seq``: a loop over the live
+        count, not ``for_live_blocks``' walk unrolled over the chunk's places, so the traced kernel holds one copy a
+        pool a site and not 8-32 (as fast on the chip; a sixth of the seconds to trace and a third of those to lower)."""
+        at_block = chunk * chunk_blocks
 
-        for_live_blocks(tbl_ref, b, n_blocks, chunk, chunk_blocks, copies, act)
+        def one(j, _):
+            src = pl.ds(tbl_ref[seq, at_block + j] * each, each)
+            dst = pl.ds(pl.multiple_of(j * each, each), each)
+            act(pltpu.make_async_copy(pk_ref.at[li, src], kbuf.at[slot, dst], sem.at[0, slot]))
+            act(pltpu.make_async_copy(pv_ref.at[li, src], vbuf.at[slot, dst], sem.at[1, slot]))
 
-    @pl.when(n_chunks > 0)
+        jax.lax.fori_loop(0, jnp.clip(seq_blocks - at_block, 0, chunk_blocks), one, None)
+
+    first_slot = ahead[0]
+
+    @pl.when((n_chunks > 0) & (ahead[1] == 0))
     def _():
-        chunk_copies(0, 0, lambda c: c.start())
+        chunk_copies(b, n_blocks, 0, first_slot, lambda c: c.start())
+
+    nxt = jnp.minimum(b + 1, last)
+    nxt_blocks, nxt_chunks = blocks_and_chunks(nxt)
+    run_ahead = (b < last) & (n_chunks > 0) & (nxt_chunks > 0)
 
     if writes:
         sublanes = tile_rows(kbuf.dtype)
@@ -188,7 +223,7 @@ def _kernel(
         first = in_chunk * kv_heads  # the new row's heads are rows first .. first + kv_heads - 1 of the last chunk's buffer
         block_start = in_chunk // block_size * each
         start = pl.multiple_of(jnp.minimum(first // sublanes * sublanes, block_start + each - span), sublanes)
-        tiles, last_slot = pl.ds(start, span), jax.lax.rem(jnp.maximum(n_chunks - 1, 0), 2)
+        tiles, last_slot = pl.ds(start, span), jax.lax.rem(first_slot + jnp.maximum(n_chunks - 1, 0), 2)
         home = pl.ds(pl.multiple_of(tbl_ref[b, at // block_size] * each + (start - block_start), sublanes), span)
         back = [pltpu.make_async_copy(buf.at[last_slot, tiles], out.at[li, home], back_sem.at[j])
                 for j, (buf, out) in enumerate(((kbuf, pk_out), (vbuf, pv_out)))]
@@ -214,13 +249,16 @@ def _kernel(
 
     def chunk_step(c, carry):
         m, l, acc = carry
-        slot = jax.lax.rem(c, 2)
+        slot = jax.lax.rem(first_slot + c, 2)
+        more = c + 1 < n_chunks
 
-        @pl.when(c + 1 < n_chunks)
+        # one site of copies for both: this sequence's next chunk or, at its last, the next sequence's first
+        @pl.when(more | run_ahead)
         def _():
-            chunk_copies(c + 1, 1 - slot, lambda d: d.start())
+            chunk_copies(jnp.where(more, b, nxt), jnp.where(more, n_blocks, nxt_blocks), jnp.where(more, c + 1, 0),
+                         1 - slot, lambda d: d.start())
 
-        chunk_copies(c, slot, lambda d: d.wait())
+        chunk_copies(b, n_blocks, c, slot, lambda d: d.wait())
         if writes:
             pl.when(c == n_chunks - 1)(write_row)
         s = jax.lax.dot_general(
@@ -242,6 +280,8 @@ def _kernel(
         def _():
             for copy in back:
                 copy.wait()
+    ahead[0] = jax.lax.rem(first_slot + n_chunks, 2)
+    ahead[1] = run_ahead.astype(jnp.int32)
 
 
 def paged_decode_attention(
@@ -315,11 +355,12 @@ def paged_decode_attention(
                 pltpu.VMEM(buffer, pool_k.dtype),
                 pltpu.VMEM(buffer, pool_v.dtype),
                 pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((2,), jnp.int32),
                 *([pltpu.SemaphoreType.DMA((2,))] if news else []),
             ],
         ),
-        # the V buffer is zeroed at the first sequence and the buffers pass
-        # from one sequence to the next: the grid runs in order on one core
+        # the V buffer is zeroed at the first sequence, and the buffers, copies under way and
+        # ``ahead`` pass from one sequence to the next: the grid runs in order on one core
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         name="paged_decode_attention",
         interpret=interpret,

@@ -76,6 +76,11 @@ step waits for it. Each read feeds ``ray_tpu_llm_moe_rows_total`` /
 ``ray_tpu_llm_moe_experts_touched_total`` / ``ray_tpu_llm_moe_peak_rows_total`` /
 ``ray_tpu_llm_moe_windows_total`` and, with telemetry on, one loop
 record of kind ``llm_moe`` (cumulative counts; ``looplog.LLM_MOE_FIELDS``).
+
+How the dispatched sequences lay in their slots (a live sequence behind a live
+one: the paged kernels hide its first copy) is two integers summed at each
+dispatch: ``loop_stats()["kv_neighbours"]``, and with telemetry on one
+``llm_kv_neighbours`` record a flush interval (``looplog.LLM_NEIGHBOUR_FIELDS``).
 """
 
 from __future__ import annotations
@@ -384,6 +389,7 @@ class InferenceEngine:
         self._thread: Optional[threading.Thread] = None
         self._init_thread = threading.get_ident()
         self.decode_steps = 0  # dispatched so far: a step's number
+        self._kv_neighbours = [0, 0]  # live sequences dispatched; those whose preceding slot was live too
         self._compiles: "collections.deque[tuple]" = collections.deque(maxlen=LOOP_RING)
         self._m_compile = {
             stage: _engine_metrics()["compile"].bind({"deployment": deployment, "stage": stage})
@@ -718,6 +724,10 @@ class InferenceEngine:
         with a step still in flight (sum over count is how often the loop ran
         ahead); ``overrun``: count of retired steps and the rows of them that
         were dropped because their sequence had already ended.
+        ``kv_neighbours``: count of live sequences dispatched since the engine
+        started and how many of them had a live sequence in the slot before
+        theirs: the paged kernels start such a sequence's first chunk during
+        its predecessor's last (sum over count is how often; telemetry or not).
         ``prefill`` now ends when the first token is read, which is after the
         steps that were in flight before the prefill, and ``prefill_stall`` is
         the host's time to enqueue the iteration's prefills.
@@ -773,6 +783,7 @@ class InferenceEngine:
             "kv_blocks": {"count": len(kv), "sum": sum(kv), "max": max(kv, default=0)},
             "ahead": {"count": len(ahead), "sum": sum(ahead)},
             "overrun": {"count": len(overrun), "sum": sum(overrun)},
+            "kv_neighbours": dict(zip(("count", "sum"), self._kv_neighbours)),
             # the newest read of the expert layers' routing counts (cumulative)
             "moe": dict(zip(LLM_MOE_FIELDS, routed[-1])) if routed else None,
             # the stacked tensors re-laid on the device at start, name -> major_to_minor
@@ -951,6 +962,8 @@ class InferenceEngine:
                 if drained or t_loop - self._gauges_at >= self._gauge_period_ns:
                     self._gauges_at = t_loop
                     self._refresh_kv_gauges()
+                    if self._tel is not None:  # to the loop's file alone: ``loop_stats`` reads the integers themselves
+                        self._tel.record_loop(self._stem, ("n", now(), self.decode_steps, *self._kv_neighbours))
                     if self._routing_counts is not None and self._moe_copy is None:
                         self._moe_copy = (self._routing_counts(self._pool), self.decode_steps)
                         if drained:
@@ -1165,6 +1178,8 @@ class InferenceEngine:
             return None
         self._newest = out if fused else None
         self.decode_steps += 1
+        self._kv_neighbours[0] += len(rows)
+        self._kv_neighbours[1] += int((active[1:] & active[:-1]).sum())
         step = _Step(rows, out, fused, t0, kv_blocks, ring_rows)
         self._flight.append(step)
         for _i, run in rows:

@@ -4,6 +4,7 @@ they leave under ``<session_dir>/loops/``, the bound metric handles they are
 folded through, and the named scopes of the device programs. Tiny sizes, CPU.
 """
 
+import dataclasses
 import glob
 import json
 import os
@@ -132,6 +133,48 @@ def test_live_sequences_over_records_are_the_decode_tokens_less_first_tokens(par
     assert [r[reason] for r in stats["requests"]] == ["length"] * 4
 
 
+# the step record as it stood before ``kv_neighbours``: the aggregate adds no field to it
+STEP_FIELDS = ("step", "t_loop", "t_admit_end", "t_result", "t_retire_end", "t_dispatch", "t_dispatch_end", "t_emit_end",
+               "live", "prefills", "fused", "kv_blocks", "ahead", "overrun")
+
+
+@pytest.mark.parametrize("dispatches,want", [
+    ([[1, 1, 1, 1]], {"count": 4, "sum": 3}),  # a full batch: every sequence but the first slot's behind a live one
+    ([[1, 0, 1, 1]], {"count": 3, "sum": 1}),  # a hole in the middle: the sequence behind it starts its own first chunk
+    ([[0, 0, 1, 0]], {"count": 1, "sum": 0}),  # one sequence
+    ([[1, 1, 1, 1], [1, 0, 1, 1], [0, 0, 1, 0], [0, 1, 1, 0]], {"count": 10, "sum": 5}),  # summed over dispatches
+], ids=["a_full_batch", "a_hole_in_the_middle", "one_sequence", "summed_over_dispatches"])
+def test_kv_neighbours_counts_the_live_sequences_behind_a_live_slot(params, dispatches, want):
+    """``loop_stats()["kv_neighbours"]`` over made-up dispatches: the slots
+    filled by hand, the loop's thread not started, the decode program stood in
+    for. Telemetry or not; and the step record keeps its fields."""
+    from ray_tpu.serve.llm.kv_cache import BlockTable
+
+    assert looplog.LLM_STEP_FIELDS == STEP_FIELDS and looplog.LLM_STEP_RING_FIELDS == STEP_FIELDS + ("ring_rows",)
+    eng = InferenceEngine(params, CFG, dataclasses.replace(ECFG, max_batch=4), deployment="loop-neighbours", start=False)
+    try:
+        seen = []
+        eng._decode_greedy = lambda _params, tokens, _positions, _tables, pool, active: (seen.append(list(active)) or tokens, pool)
+        assert eng.loop_stats()["kv_neighbours"] == {"count": 0, "sum": 0}
+        for slots in dispatches:
+            eng._slots = [
+                engine_mod._Running(engine_mod._Request(temperature=0.0, max_new_tokens=100, need_blocks=0),
+                                    BlockTable(eng._alloc, 3), i) if live else None
+                for i, live in enumerate(slots)]
+            step = eng._dispatch_step([])
+            assert [i for i, _run in step.rows] == [i for i, live in enumerate(slots) if live]
+            for _i, run in step.rows:
+                run.table.release()
+        eng._flight.clear()
+        eng._slots = [None] * 4
+        assert seen == [[bool(x) for x in slots] for slots in dispatches]
+        stats = eng.loop_stats()
+        assert stats["kv_neighbours"] == want and eng.decode_steps == len(dispatches)
+        assert stats["fields"] == STEP_FIELDS
+    finally:
+        eng.shutdown()
+
+
 def test_ahead_and_overrun_are_recorded_and_the_series_sums_to_the_runs_retire_to_retire_time(params, ray_start_regular):
     eng = InferenceEngine(params, CFG, ECFG, deployment="loop-ahead")
     try:
@@ -145,6 +188,7 @@ def test_ahead_and_overrun_are_recorded_and_the_series_sums_to_the_runs_retire_t
     finally:
         eng.shutdown()
     assert stats["fields"] == looplog.LLM_STEP_FIELDS and stats["fields"][-2:] == ("ahead", "overrun")
+    assert all(len(r) == len(STEP_FIELDS) for r in stats["records"])  # ``kv_neighbours`` rides no step record
     recs = [dict(zip(stats["fields"], r)) for r in stats["records"]]
     dispatching = [r for r in recs if r["live"]]
     retiring = [r for r in recs if r["t_result"]]
@@ -236,6 +280,10 @@ def test_every_ended_request_leaves_spans_under_the_callers_span(params, ray_sta
     assert 0 < reqs[ok.request_id]["t_submit"] <= reqs[ok.request_id]["t_admit"] <= reqs[ok.request_id]["t_first"]
     steps = [r for r in recs if r["kind"] == "llm_step"]
     assert steps and set(steps[0]) == {"kind", *looplog.LLM_STEP_FIELDS}
+    # the slots' neighbours, cumulative, in records of their own: one sequence a step here, so nobody behind a live slot
+    behind = [r for r in recs if r["kind"] == "llm_kv_neighbours"]
+    assert behind and set(behind[0]) == {"kind", *looplog.LLM_NEIGHBOUR_FIELDS}
+    assert behind[-1]["count"] == behind[-1]["step"] == steps[-1]["step"] and behind[-1]["sum"] == 0
     assert looplog.last_dir == os.path.join(session_dir, "loops")
 
 
@@ -489,7 +537,9 @@ def test_loop_summary_tool_reads_the_share_ahead_and_a_newcomers_wait(tmp_path):
     # cumulative counts at steps 3 and 9 of a model of 2 expert layers: 13 windows over 12 layer-steps
     moe = [("m", t * 20 * ms, t, *(w if k == "windows" else 2 if k == "layers" else 0
                                    for k in looplog.LLM_MOE_FIELDS[2:])) for t, w in ((3, 6), (9, 19))]
-    log.ingest({"llm-x-1": [*steps[:5], old, *steps[6:], req, *moe]})
+    # cumulative at steps 2 and 11: 18 live sequences dispatched between them, 9 of them behind a live slot
+    behind = [("n", t * 20 * ms, t, count, held) for t, count, held in ((2, 3, 1), (11, 21, 10))]
+    log.ingest({"llm-x-1": [*steps[:5], old, *steps[6:], req, *moe, *behind]})
     log.close()
     tool = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "tools", "loop_summary.py")
     out = subprocess.run([sys.executable, tool, str(tmp_path / "loops"), "--skip-s", "0"],
@@ -501,6 +551,7 @@ def test_loop_summary_tool_reads_the_share_ahead_and_a_newcomers_wait(tmp_path):
     assert got["first_token_ms"] == {"count": 1, "mean_ms": 35.0, "median_ms": 35.0, "p90_ms": 35.0, "max_ms": 35.0}
     assert got["queue_wait_ms"]["mean_ms"] == 30.0
     assert got["windows_per_layer_step"] == pytest.approx(13 / 12)
+    assert got["kv_neighbour_share"] == pytest.approx(9 / 18)
 
 
 # -- how a loop came to run: the start's stamps and the compile records -----------

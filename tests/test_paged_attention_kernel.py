@@ -29,6 +29,11 @@ LENGTHS = {
     "a_full_table": [FULL, FULL - 1, FULL - BLOCK + 1, FULL],
     "chunks": [16 * BLOCK, 16 * BLOCK + 1, 32 * BLOCK, 17 * BLOCK],  # whole, and one block into the next
     "inactive_slots": [0, 40, 0, 7],
+    # the first chunk carried from one sequence to the next (``ahead``): into either buffer, not at all, past nobody
+    "a_chunk_each": [16 * BLOCK, 255, 200, 16 * BLOCK],  # every first chunk but the first slot's is started by its predecessor
+    "one_two_and_three_chunks": [16 * BLOCK, FULL, 32 * BLOCK, 1],  # a first chunk in the second buffer, then the first
+    "an_empty_slot_between": [300, 0, FULL, 0],  # nothing started ahead: the third starts its own, where the first left off
+    "every_slot_empty": [0, 0, 0, 0],
 }
 
 
@@ -85,27 +90,49 @@ def test_kernel_agrees_with_attention_over_the_gathered_rows(dtype, n_rep, case)
     np.testing.assert_allclose(got[active], want[active], atol=tol, rtol=tol)
 
 
+# The sequence ("X", of one, one, two and three chunks in turn) among neighbours of these lengths: who starts its first
+# chunk, into which buffer, and whether it starts a neighbour's (C: a chunk's positions).
+C = 16 * BLOCK
+CROWDS = {
+    "behind_three_chunks_and_one": [FULL, 33, "X", 100],  # started ahead, into the first buffer
+    "behind_one_chunk": [C, "X", 2 * C, 7],  # an odd number before it: started ahead into the second buffer
+    "behind_two_chunks": [2 * C, "X", 1, 0],  # an even number: into the first
+    "behind_an_empty_slot": [100, 0, "X", 50],  # it starts its own, in the second buffer, and its successor's
+    "before_an_empty_slot": [C + 1, "X", 0, 77],  # started ahead, and starts nothing
+    "in_the_last_slot": [17, 2 * C, FULL, "X"],  # nothing to start
+    "in_the_first_slot": ["X", FULL, 0, C],
+    "the_others_empty": [0, 0, "X", 0],
+    "a_chunk_a_neighbour": [C, 17, "X", 1],
+}
+
+
+@pytest.mark.parametrize("crowd", list(CROWDS))
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bfloat16_pool", "float32_pool"])
-def test_a_sequence_reads_the_same_alone_and_among_neighbours(dtype):
+def test_a_sequence_reads_the_same_alone_and_among_neighbours(dtype, crowd):
     """Bit for bit: in another slot, beside neighbours of other lengths whose
-    rows passed through the same buffers, with the pool's other blocks changed."""
+    rows passed through the same buffers, with the pool's other blocks changed,
+    whichever buffer its first chunk lands in and whoever started it."""
     kv_heads = 32 // jnp.dtype(dtype).itemsize
+    assert C == BLOCK * chunk_blocks_for(TABLE, BLOCK * kv_heads * HEAD_DIM * jnp.dtype(dtype).itemsize)
     rng = np.random.default_rng(4)
     pk, pv = _pools(dtype, kv_heads, seed=5)
+    slot = CROWDS[crowd].index("X")
     for length in (1, 17, 300, FULL):
         lengths = np.asarray([length, 0, 0, 0], np.int32)
         tables = _tables(lengths, seed=6)
         q = jnp.asarray(rng.standard_normal((4, kv_heads, HEAD_DIM)), dtype)
         alone = np.asarray(_kernel(q, pk, pv, tables, lengths).astype(jnp.float32))[0]
 
-        crowd = np.asarray([FULL, 33, length, 100], np.int32)
-        crowd_tables = _tables(crowd, seed=7)
-        crowd_tables[2] = tables[0]
-        for row, n in zip((0, 1, 3), (FULL, 33, 100)):  # off the sequence's own blocks
-            spare = [b for b in range(1, POOL_BLOCKS) if b not in tables[0]]
-            crowd_tables[row, : -(-n // BLOCK)] = rng.permutation(spare)[: -(-n // BLOCK)]
-        among = _kernel(q[jnp.asarray([1, 2, 0, 3])], pk, pv, crowd_tables, crowd)
-        assert np.array_equal(alone, np.asarray(among.astype(jnp.float32))[2]), length
+        among = np.asarray([length if n == "X" else n for n in CROWDS[crowd]], np.int32)
+        among_tables = _tables(among, seed=7)
+        among_tables[slot] = tables[0]
+        spare = [b for b in range(1, POOL_BLOCKS) if b not in tables[0]]
+        for row, n in enumerate(among):  # off the sequence's own blocks
+            if row != slot:
+                among_tables[row, : -(-n // BLOCK)] = rng.permutation(spare)[: -(-n // BLOCK)]
+        order = [0 if row == slot else 1 + row % 3 for row in range(4)]  # the sequence's query in its slot
+        got = _kernel(q[jnp.asarray(order)], pk, pv, among_tables, among)
+        assert np.array_equal(alone, np.asarray(got.astype(jnp.float32))[slot]), length
 
 
 # The step's own row: position ``length - 1`` of four sequences, R a chunk's positions (16 x 32, 16, 24 or 12 blocks
@@ -117,6 +144,12 @@ ROWS = {
     "first_of_a_new_chunk": ("R+1", 1, "R+1", "FULL-15"),
     "an_inactive_slot_among_live_ones": (0, 40, 0, 7),
     "two_calls_at_one_position": (5, "R+1", 16, 33),
+    # a neighbour's first chunk started ahead while this sequence's tiles go back: from either buffer, past an empty
+    # slot (not started), and no slot live
+    "a_neighbour_started_ahead_from_either_buffer": ("R", "R+1", "FULL", "R+1"),
+    "nothing_started_past_an_empty_slot": ("R", 0, "R+1", 0),
+    "two_chunks_then_an_empty_slot": ("R+1", 0, "FULL", 33),
+    "every_slot_empty": (0, 0, 0, 0),
 }
 
 

@@ -13,7 +13,11 @@ was in flight) and ``t_admit - t_submit``; and, of a model with expert
 layers, the windows of held rows its grouped matmuls walked a layer a decode
 step between that time's first and last ``llm_moe`` record
 (``windows_per_layer_step``: 1 = no call spilled past its first window; None
-without two records that carry the count). Records older than a field read 0
+without two records that carry the count), and ``kv_neighbour_share``: of the
+live sequences dispatched between that time's first and last
+``llm_kv_neighbours`` record, the share whose preceding slot was live too, so
+whose first chunk the paged kernels started during the predecessor's last
+(None without two such records). Records older than a field read 0
 there. And ``start``, how the loop came to run (None of a program that leaves
 no such record): the phases of the engine's ``llm_start`` record in seconds
 (``init_to_backend`` .. ``pool_to_ready``), what it placed and its pool's
@@ -61,6 +65,14 @@ def windows_per_layer_step(moe_recs: list, t0: int, t1: int):
     first, last = recs[0], recs[-1]
     layer_steps = (last["step"] - first["step"]) * last["layers"]
     return (last["windows"] - first["windows"]) / layer_steps if layer_steps > 0 else None
+
+
+def kv_neighbour_share(recs: list, t0: int, t1: int):
+    recs = [r for r in recs if t0 <= r["t"] <= t1]
+    if len(recs) < 2:
+        return None
+    count = recs[-1]["count"] - recs[0]["count"]
+    return (recs[-1]["sum"] - recs[0]["sum"]) / count if count > 0 else None
 
 
 START_STAMPS = ("t_init", "t_backend", "t_params", "t_placed", "t_pool", "t_ready")
@@ -113,6 +125,7 @@ def summarise(recs: dict, skip_s: float) -> dict:
         "first_token_ms": spread_ms([r["t_first"] - r["t_admit"] for r in reqs]),
         "queue_wait_ms": spread_ms([r["t_admit"] - r["t_submit"] for r in reqs]),
         "windows_per_layer_step": windows_per_layer_step(recs["llm_moe"], t0, t1),
+        "kv_neighbour_share": kv_neighbour_share(recs["llm_kv_neighbours"], t0, t1),
     }
 
 
