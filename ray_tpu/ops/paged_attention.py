@@ -24,15 +24,22 @@ A sequence's output depends on its own length, table and rows alone: it
 walks its own blocks in table order in chunks of a fixed size, whatever its
 neighbours hold.
 
-Both kernels run ahead *across* sequences: a sequence is only a few chunks
-(one to six at the served contexts), so before its last chunk is waited for
-and scored, the next sequence's first is started into the other buffer, and
-two words in SMEM carry from one grid step to the next which buffer that is
-and whether the copy is under way (``ahead``). A sequence behind an empty slot
+Both kernels take that walk's carry from one place (``chunk_walk``), and the
+copies run ahead *across* sequences. A sequence is only a few chunks (one to
+six at the served contexts), so before its last chunk is waited for and
+scored, the next sequence's first is started into the other buffer, and two
+words in SMEM carry from one grid step to the next which buffer that is and
+whether the copy is under way (``ahead``). A sequence behind an empty slot
 starts its own first chunk. Which buffer a chunk lands in reaches no output.
-``_kernel`` starts and waits for a chunk's copies in a loop over its live
-blocks; ``_latent_kernel`` keeps the walk unrolled over a chunk's places
-(``for_live_blocks``), which costs a replica's start seconds of tracing.
+
+How a chunk's live blocks are gone through is a kernel's own, as its scoring
+is (``chunk_copies``), and the chip chose for each. ``_kernel`` goes in a
+loop: the traced kernel then holds one copy a pool at each of the three sites
+that start and the one that waits, whatever the table's width, and what a
+replica's start pays to trace and lower a kernel is the count of its binds.
+``_latent_kernel`` goes unrolled over the chunk's places: its copies are one
+block of 20 KB each, the scalar core's issue of them is on every chunk's path,
+and on the chip a call took 18-23% longer with the loop.
 
 Handed the decode step's own K and V row (``new_k``, ``new_v``; flat pools),
 ``paged_decode_attention`` writes it too: into the last chunk's buffer before
@@ -45,6 +52,7 @@ from __future__ import annotations
 
 import functools
 import math
+import operator
 
 import jax
 import jax.numpy as jnp
@@ -96,18 +104,80 @@ def chunk_blocks_for(max_blocks: int, block_bytes: int, chunk_bytes: int = _CHUN
     return max(whole, min(max_blocks, chunk_bytes // block_bytes) // whole * whole)
 
 
-def for_live_blocks(tbl_ref, seq, n_blocks, chunk, chunk_blocks: int, copies, act):
-    """``act`` on every copy of chunk ``chunk`` of sequence ``seq``'s table:
-    for the chunk's ``j``-th block, where it is live, ``copies(block, j)``
-    names the descriptors that take pool block ``block`` to the buffer's
-    ``j``-th place (one a pool). Started and waited for through the same walk."""
-    for j in range(chunk_blocks):
-        i = chunk * chunk_blocks + j
+def chunk_walk(b, last, len_ref, ahead, *, block_size: int, chunk_blocks: int, chunk_copies, zero_buffers):
+    """One grid step's (one sequence's) walk over its chunks and the carry
+    from one grid step to the next, for both kernels. ``b`` is the grid step
+    and ``last`` the grid's last one; ``len_ref`` (B,) the scalar-prefetched
+    lengths; ``ahead`` the two SMEM words: the buffer this sequence's first
+    chunk is in, and whether its copy is under way. A kernel hands in what is
+    its own: ``chunk_copies(seq, seq_blocks, chunk, slot, act)``, which calls
+    ``act`` on the copy of every live block (the first ``seq_blocks`` of its
+    table) of chunk ``chunk`` of sequence ``seq`` into buffer ``slot``, the
+    same walk whether ``act`` starts or waits; and ``zero_buffers()``, called
+    at the first grid step before anything is started (a chunk's dead rows keep
+    what the buffer held before: what that may be is the kernel's argument).
 
-        @pl.when(i < n_blocks)
-        def _():
-            for copy in copies(tbl_ref[seq, i], j):
-                act(copy)
+    Stated here and nowhere else: a sequence's live blocks and chunks from its
+    length; the start of this sequence's first chunk unless its predecessor
+    started it (an empty slot starts nothing for its neighbour, which then
+    starts its own); inside the chunk loop, the start of this sequence's next
+    chunk or, at its last, of the next sequence's first into the other buffer,
+    then the wait for this chunk; and what the next grid step finds in
+    ``ahead``.
+
+    -> ``first_slot`` and ``n_chunks`` (chunk ``c`` lies in buffer
+    ``(first_slot + c) % 2``; what is started ahead during the last chunk lands
+    in the other one), ``over_chunks(score, carry)``, which runs ``score(c,
+    slot, carry) -> carry`` on every chunk once it has come in, and
+    ``close()``, the grid step's last words."""
+    start, wait = operator.methodcaller("start"), operator.methodcaller("wait")
+
+    def blocks_and_chunks(seq):
+        n_blocks = (len_ref[seq] + block_size - 1) // block_size
+        return n_blocks, (n_blocks + chunk_blocks - 1) // chunk_blocks
+
+    n_blocks, n_chunks = blocks_and_chunks(b)
+
+    @pl.when(b == 0)
+    def _():
+        zero_buffers()
+        ahead[0] = 0
+        ahead[1] = 0
+
+    first_slot = ahead[0]
+
+    @pl.when((n_chunks > 0) & (ahead[1] == 0))
+    def _():
+        chunk_copies(b, n_blocks, 0, first_slot, start)
+
+    nxt = jnp.minimum(b + 1, last)
+    nxt_blocks, nxt_chunks = blocks_and_chunks(nxt)
+    run_ahead = (b < last) & (n_chunks > 0) & (nxt_chunks > 0)
+
+    def over_chunks(score, carry):
+        def chunk_step(c, carry):
+            slot = jax.lax.rem(first_slot + c, 2)
+
+            # a site each, so that whose chunk it is is the site's own: at one site under scalar selects the latent
+            # kernel's call took 7-9% longer on the chip (``_kernel``'s 0-2% shorter: under 0.05% of any cell's step)
+            @pl.when(c + 1 < n_chunks)
+            def _():
+                chunk_copies(b, n_blocks, c + 1, 1 - slot, start)
+
+            @pl.when((c + 1 == n_chunks) & run_ahead)
+            def _():
+                chunk_copies(nxt, nxt_blocks, 0, 1 - slot, start)
+
+            chunk_copies(b, n_blocks, c, slot, wait)
+            return score(c, slot, carry)
+
+        return jax.lax.fori_loop(0, n_chunks, chunk_step, carry)
+
+    def close():
+        ahead[0] = jax.lax.rem(first_slot + n_chunks, 2)
+        ahead[1] = run_ahead.astype(jnp.int32)
+
+    return first_slot, n_chunks, over_chunks, close
 
 
 def online_softmax_weights(m, l, s):
@@ -152,15 +222,14 @@ def _kernel(
     ends: the next sequence's second chunk lands in that buffer. An inactive
     slot patches and writes nothing: the null block is no sequence's.
 
-    The copies run ahead *across* sequences, as ``_latent_kernel``'s do: before
-    a sequence's last chunk is waited for and scored, the next sequence's first
-    is started into the other buffer (never the one the row is patched in and
-    the tiles go back from; live sequences hold distinct last blocks, so it
-    reads no row this step writes). ``ahead`` (SMEM (2,), after ``sem``) carries
-    that from one grid step to the next: the buffer this sequence's first chunk
-    is in, and whether it is under way. An empty slot starts nothing for its
-    neighbour, which then starts its own. Which of the two buffers a chunk
-    lands in reaches no output: see the dead rows below."""
+    The walk and the carry are ``chunk_walk``'s, so the copies run ahead
+    *across* sequences: before a sequence's last chunk is waited for and scored,
+    the next sequence's first is started into the other buffer (never the one
+    the row is patched in and the tiles go back from, ``last_slot``; live
+    sequences hold distinct last blocks, so it reads no row this step writes).
+    ``ahead`` (SMEM (2,), after ``sem``) carries that from one grid step to the
+    next. Which of the two buffers a chunk lands in reaches no output: see the
+    dead rows below."""
     writes = len(refs) > 7
     if writes:
         nk_ref, nv_ref, pk_ref, pv_ref, o_ref, pk_out, pv_out, kbuf, vbuf, sem, ahead, back_sem = refs
@@ -169,12 +238,6 @@ def _kernel(
     b, last = pl.program_id(0), pl.num_programs(0) - 1
     li = li_ref[0]
     length = len_ref[b]
-
-    def blocks_and_chunks(seq):
-        n_blocks = (len_ref[seq] + block_size - 1) // block_size
-        return n_blocks, (n_blocks + chunk_blocks - 1) // chunk_blocks
-
-    n_blocks, n_chunks = blocks_and_chunks(b)
     _, heads, head_dim = q_ref.shape
     flat = len(kbuf.shape) == 3  # the pools hold a slot's heads as rows: a block is copied as block_size x KV of them
     rows = chunk_blocks * block_size
@@ -182,19 +245,10 @@ def _kernel(
     cols = rows * kv_heads
     each = block_size * kv_heads if flat else block_size  # rows of the pool (and of a buffer) a block is
 
-    # A chunk's dead rows keep what the buffer held before, and a weight of
-    # exactly 0 times that must be 0: nothing but zeros and pool rows is ever
-    # in the V buffer. (K's dead columns are replaced after the product.)
-    @pl.when(b == 0)
-    def _():
-        vbuf[...] = jnp.zeros_like(vbuf)
-        ahead[0] = 0
-        ahead[1] = 0
-
     def chunk_copies(seq, seq_blocks, chunk, slot, act):
-        """``act`` on the two copies of every live block of chunk ``chunk`` of sequence ``seq``: a loop over the live
-        count, not ``for_live_blocks``' walk unrolled over the chunk's places, so the traced kernel holds one copy a
-        pool a site and not 8-32 (as fast on the chip; a sixth of the seconds to trace and a third of those to lower)."""
+        """In a loop over the chunk's live blocks: the traced kernel then holds one copy a pool at each of
+        ``chunk_walk``'s sites whatever the table's width, and what a replica's start pays to trace and lower a kernel
+        is the count of its binds."""
         at_block = chunk * chunk_blocks
 
         def one(j, _):
@@ -205,15 +259,15 @@ def _kernel(
 
         jax.lax.fori_loop(0, jnp.clip(seq_blocks - at_block, 0, chunk_blocks), one, None)
 
-    first_slot = ahead[0]
+    def zero_buffers():
+        # A chunk's dead rows keep what the buffer held before, and a weight of
+        # exactly 0 times that must be 0: nothing but zeros and pool rows is ever
+        # in the V buffer. (K's dead columns are replaced after the product.)
+        vbuf[...] = jnp.zeros_like(vbuf)
 
-    @pl.when((n_chunks > 0) & (ahead[1] == 0))
-    def _():
-        chunk_copies(b, n_blocks, 0, first_slot, lambda c: c.start())
-
-    nxt = jnp.minimum(b + 1, last)
-    nxt_blocks, nxt_chunks = blocks_and_chunks(nxt)
-    run_ahead = (b < last) & (n_chunks > 0) & (nxt_chunks > 0)
+    first_slot, n_chunks, over_chunks, close = chunk_walk(
+        b, last, len_ref, ahead, block_size=block_size, chunk_blocks=chunk_blocks, chunk_copies=chunk_copies,
+        zero_buffers=zero_buffers)
 
     if writes:
         sublanes = tile_rows(kbuf.dtype)
@@ -247,18 +301,8 @@ def _kernel(
     own_head = jax.lax.rem(col, kv_heads) == jax.lax.div(head, n_rep)
     row = jax.lax.div(col, kv_heads)
 
-    def chunk_step(c, carry):
+    def score(c, slot, carry):
         m, l, acc = carry
-        slot = jax.lax.rem(first_slot + c, 2)
-        more = c + 1 < n_chunks
-
-        # one site of copies for both: this sequence's next chunk or, at its last, the next sequence's first
-        @pl.when(more | run_ahead)
-        def _():
-            chunk_copies(jnp.where(more, b, nxt), jnp.where(more, n_blocks, nxt_blocks), jnp.where(more, c + 1, 0),
-                         1 - slot, lambda d: d.start())
-
-        chunk_copies(b, n_blocks, c, slot, lambda d: d.wait())
         if writes:
             pl.when(c == n_chunks - 1)(write_row)
         s = jax.lax.dot_general(
@@ -272,7 +316,7 @@ def _kernel(
         )
         return m, l, acc
 
-    m, l, acc = jax.lax.fori_loop(0, n_chunks, chunk_step, softmax_start(heads, head_dim))
+    m, l, acc = over_chunks(score, softmax_start(heads, head_dim))
     # an inactive slot (length 0) read nothing: its output is 0, not 0/0
     o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
     if writes:
@@ -280,8 +324,7 @@ def _kernel(
         def _():
             for copy in back:
                 copy.wait()
-    ahead[0] = jax.lax.rem(first_slot + n_chunks, 2)
-    ahead[1] = run_ahead.astype(jnp.int32)
+    close()
 
 
 def paged_decode_attention(
@@ -394,71 +437,47 @@ def _latent_kernel(
     ai_ref, len_ref, tbl_ref,  # scalar prefetch
     ql_ref, qr_ref, pool_ref,  # (1, H, r_kv) and (1, H, stored - r_kv) in VMEM; the pool in HBM
     o_ref,
-    buf, sem, ahead,  # (2, rows, stored); DMA semaphores (2,); SMEM (2,): see below
+    buf, sem, ahead,  # (2, rows, stored); DMA semaphores (2,); SMEM (2,): ``chunk_walk``
     *, block_size, chunk_blocks, scale,
 ):
-    """One sequence a grid step, as ``_kernel``, with two differences the
-    latent cache asks for. A row is key and value at once (scores over all of
-    it, the weighted sum over its first ``r_kv`` values) and all heads share
-    it: one buffer, no other head's columns. And a whole sequence is a chunk
-    or two, so the copies run ahead *across* sequences: before a sequence's
-    last chunk is computed, the next sequence's first is started into the
-    other buffer. ``ahead`` carries that from one grid step to the next: the
-    buffer this sequence's first chunk is in, and whether it is under way."""
+    """One sequence a grid step, as ``_kernel`` and over the same
+    ``chunk_walk``, with two differences the latent cache asks for: a row is
+    key and value at once (scores over all of it, the weighted sum over its
+    first ``r_kv`` values), and all heads share it: one buffer, no other
+    head's columns."""
     b, last = pl.program_id(0), pl.num_programs(0) - 1
     ai = ai_ref[0]
+    length = len_ref[b]
     _, heads, r_kv = ql_ref.shape
     _, rows, _ = buf.shape
 
-    def blocks_and_chunks(seq):
-        n_blocks = (len_ref[seq] + block_size - 1) // block_size
-        return n_blocks, (n_blocks + chunk_blocks - 1) // chunk_blocks
-
-    # dead rows of a chunk keep what the buffer held before, and a weight of
-    # exactly 0 times that must be 0: nothing but zeros and pool rows is ever
-    # in the buffer
-    @pl.when(b == 0)
-    def _():
-        buf[...] = jnp.zeros_like(buf)
-        ahead[0] = 0
-        ahead[1] = 0
-
     def chunk_copies(seq, seq_blocks, chunk, slot, act):
-        def copies(block, j):
-            dst = pl.ds(j * block_size, block_size)
-            return (pltpu.make_async_copy(pool_ref.at[ai, block], buf.at[slot, dst], sem.at[slot]),)
+        """Unrolled over the chunk's places, every index but the table's static: a copy is one block of 20 KB, the scalar
+        core's issue of them is on every chunk's path, and on the chip a call took 18-23% longer with ``_kernel``'s loop."""
+        at_block = chunk * chunk_blocks
+        for j in range(chunk_blocks):
+            i = at_block + j  # ahead of the condition, as the compare is: inside it, it is a cycle a block on the copies' path
 
-        for_live_blocks(tbl_ref, seq, seq_blocks, chunk, chunk_blocks, copies, act)
+            @pl.when(i < seq_blocks)
+            def _():
+                dst = pl.ds(j * block_size, block_size)
+                act(pltpu.make_async_copy(pool_ref.at[ai, tbl_ref[seq, i]], buf.at[slot, dst], sem.at[slot]))
 
-    length = len_ref[b]
-    n_blocks, n_chunks = blocks_and_chunks(b)
-    first = ahead[0]
+    def zero_buffers():
+        # dead rows of a chunk keep what the buffer held before, and a weight of
+        # exactly 0 times that must be 0: nothing but zeros and pool rows is ever
+        # in the buffer
+        buf[...] = jnp.zeros_like(buf)
 
-    @pl.when((n_chunks > 0) & (ahead[1] == 0))
-    def _():
-        chunk_copies(b, n_blocks, 0, first, lambda d: d.start())
-
-    # an empty slot starts nothing for its neighbour, which then starts its own
-    nxt = jnp.minimum(b + 1, last)
-    nxt_blocks, nxt_chunks = blocks_and_chunks(nxt)
-    run_ahead = (b < last) & (n_chunks > 0) & (nxt_chunks > 0)
+    _, _, over_chunks, close = chunk_walk(
+        b, last, len_ref, ahead, block_size=block_size, chunk_blocks=chunk_blocks, chunk_copies=chunk_copies,
+        zero_buffers=zero_buffers)
 
     q = jnp.concatenate([ql_ref[0], qr_ref[0]], axis=-1)
     col = jax.lax.broadcasted_iota(jnp.int32, (heads, rows), 1)
 
-    def chunk_step(c, carry):
+    def score(c, slot, carry):
         m, l, acc = carry
-        slot = jax.lax.rem(first + c, 2)
-
-        @pl.when(c + 1 < n_chunks)
-        def _():
-            chunk_copies(b, n_blocks, c + 1, 1 - slot, lambda d: d.start())
-
-        @pl.when((c + 1 == n_chunks) & run_ahead)
-        def _():
-            chunk_copies(nxt, nxt_blocks, 0, 1 - slot, lambda d: d.start())
-
-        chunk_copies(b, n_blocks, c, slot, lambda d: d.wait())
         kv = buf[slot]
         s = jax.lax.dot_general(q, kv, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32) * scale
         s = jnp.where(c * rows + col < length, s, _NEG_INF)
@@ -466,11 +485,10 @@ def _latent_kernel(
         acc = alpha * acc + jnp.dot(p.astype(kv.dtype), kv[:, :r_kv], preferred_element_type=jnp.float32)
         return m, l, acc
 
-    m, l, acc = jax.lax.fori_loop(0, n_chunks, chunk_step, softmax_start(heads, r_kv))
+    m, l, acc = over_chunks(score, softmax_start(heads, r_kv))
     # an empty slot (length 0) read nothing: its output is 0, not 0/0
     o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-    ahead[0] = jax.lax.rem(first + n_chunks, 2)
-    ahead[1] = run_ahead.astype(jnp.int32)
+    close()
 
 
 def paged_latent_attention(

@@ -12,7 +12,8 @@ import numpy as np
 import pytest
 
 from ray_tpu.ops.latent_attention import latent_decode_attention
-from ray_tpu.ops.paged_attention import can_use_latent_kernel, paged_latent_attention
+from ray_tpu.ops.paged_attention import _LATENT_CHUNK_BYTES, can_use_latent_kernel, chunk_blocks_for, paged_latent_attention
+from test_paged_attention_kernel import CROWDS, crowds
 
 HEADS, R_KV, D_R, STORED = 64, 512, 64, 640
 # a chunk of the kernel is 32 of these blocks in bfloat16 and 16 in float32: a full table is two chunks and a quarter, or four and a half
@@ -86,11 +87,17 @@ def test_kernel_agrees_with_latent_attention_over_the_gathered_rows(dtype, scale
     np.testing.assert_allclose(got[active], want[active], atol=tol, rtol=tol)
 
 
+@pytest.mark.parametrize("crowd", list(CROWDS))
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bfloat16_pool", "float32_pool"])
-def test_a_sequence_reads_the_same_alone_and_among_neighbours(dtype):
-    """Bit for bit: in another slot, behind neighbours of other lengths (whose
-    last chunk started this sequence's first, and whose rows passed through
-    the same buffers) and behind an empty slot (which started nothing)."""
+def test_a_sequence_reads_the_same_alone_and_among_neighbours(dtype, crowd):
+    """Bit for bit: in another slot, among ``test_paged_attention_kernel``'s
+    crowds at this kernel's chunk size (the walk and the carry are one
+    function's for both kernels): whoever started its first chunk, into
+    whichever buffer, its neighbours' rows having passed through both."""
+    chunk = BLOCK * chunk_blocks_for(TABLE, BLOCK * STORED * jnp.dtype(dtype).itemsize, _LATENT_CHUNK_BYTES)
+    assert chunk == {2: 32, 4: 16}[jnp.dtype(dtype).itemsize] * BLOCK
+    among_whom = crowds(chunk, FULL)[crowd]
+    slot = among_whom.index("X")
     rng = np.random.default_rng(4)
     pool = _pool(dtype, seed=5)
     for length in (1, 17, 600, FULL):
@@ -98,16 +105,17 @@ def test_a_sequence_reads_the_same_alone_and_among_neighbours(dtype):
         tables = _tables(lengths, seed=6)
         q_l, q_r = _queries(4, dtype, seed=7)
         alone = np.asarray(_kernel(q_l, q_r, pool, tables, lengths).astype(jnp.float32))[0]
-        for crowd in ([FULL, 33, length, 100], [600, 0, length, 0], [length, length, length, length]):
-            place = 2
-            crowd_tables = _tables(np.asarray(crowd, np.int32), seed=8)
-            spare = [b for b in range(1, POOL_BLOCKS) if b not in tables[0]]
-            for row, n in enumerate(crowd):  # the neighbours off the sequence's own blocks
-                crowd_tables[row, : -(-n // BLOCK)] = rng.permutation(spare)[: -(-n // BLOCK)]
-            crowd_tables[place] = tables[0]
-            order = jnp.asarray([1, 2, 0, 3])
-            among = _kernel(q_l[order], q_r[order], pool, crowd_tables, np.asarray(crowd, np.int32))
-            assert np.array_equal(alone, np.asarray(among.astype(jnp.float32))[place]), (length, crowd)
+
+        among = np.asarray([length if n in ("X", "x") else n for n in among_whom], np.int32)
+        among_tables = _tables(among, seed=8)
+        among_tables[slot] = tables[0]
+        spare = [b for b in range(1, POOL_BLOCKS) if b not in tables[0]]
+        for row, n in enumerate(among):  # the neighbours off the sequence's own blocks
+            if row != slot:
+                among_tables[row, : -(-n // BLOCK)] = rng.permutation(spare)[: -(-n // BLOCK)]
+        order = jnp.asarray([0 if row == slot else 1 + row % 3 for row in range(4)])  # the sequence's query in its slot
+        got = _kernel(q_l[order], q_r[order], pool, among_tables, among)
+        assert np.array_equal(alone, np.asarray(got.astype(jnp.float32))[slot]), length
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bfloat16_pool", "float32_pool"])

@@ -15,7 +15,9 @@ import numpy as np
 import pytest
 
 from ray_tpu.ops.attention import attention
-from ray_tpu.ops.paged_attention import can_use_paged_kernel, chunk_blocks_for, covering_span, paged_decode_attention
+from ray_tpu.ops.paged_attention import (
+    can_use_paged_kernel, chunk_blocks_for, covering_span, paged_decode_attention, paged_latent_attention,
+)
 from ray_tpu.ops.window_attention import write_spans
 
 # a chunk of the kernel is 16 of these blocks: a full table is two chunks and a half
@@ -90,20 +92,29 @@ def test_kernel_agrees_with_attention_over_the_gathered_rows(dtype, n_rep, case)
     np.testing.assert_allclose(got[active], want[active], atol=tol, rtol=tol)
 
 
-# The sequence ("X", of one, one, two and three chunks in turn) among neighbours of these lengths: who starts its first
-# chunk, into which buffer, and whether it starts a neighbour's (C: a chunk's positions).
+# The sequence ("X", of one, one, two and three chunks in turn) among neighbours of these lengths ("x": of its own): who
+# starts its first chunk, into which buffer, and whether it starts a neighbour's (C: a chunk's positions).
 C = 16 * BLOCK
-CROWDS = {
-    "behind_three_chunks_and_one": [FULL, 33, "X", 100],  # started ahead, into the first buffer
-    "behind_one_chunk": [C, "X", 2 * C, 7],  # an odd number before it: started ahead into the second buffer
-    "behind_two_chunks": [2 * C, "X", 1, 0],  # an even number: into the first
-    "behind_an_empty_slot": [100, 0, "X", 50],  # it starts its own, in the second buffer, and its successor's
-    "before_an_empty_slot": [C + 1, "X", 0, 77],  # started ahead, and starts nothing
-    "in_the_last_slot": [17, 2 * C, FULL, "X"],  # nothing to start
-    "in_the_first_slot": ["X", FULL, 0, C],
-    "the_others_empty": [0, 0, "X", 0],
-    "a_chunk_a_neighbour": [C, 17, "X", 1],
-}
+
+
+def crowds(c, full):
+    """The neighbours' lengths by a chunk's positions ``c`` and a table's ``full`` (an odd count of chunks): the latent
+    kernel's test takes the same crowds at its own chunk size."""
+    return {
+        "behind_a_full_table_and_one": [full, 33, "X", 100],  # an even number of chunks before it: started ahead, into the first buffer
+        "behind_one_chunk": [c, "X", 2 * c, 7],  # an odd number before it: started ahead into the second buffer
+        "behind_two_chunks": [2 * c, "X", 1, 0],  # an even number: into the first
+        "behind_an_empty_slot": [100, 0, "X", 50],  # it starts its own, in the second buffer, and its successor's
+        "before_an_empty_slot": [c + 1, "X", 0, 77],  # started ahead, and starts nothing
+        "in_the_last_slot": [17, 2 * c, full, "X"],  # nothing to start
+        "in_the_first_slot": ["X", full, 0, c],
+        "the_others_empty": [0, 0, "X", 0],
+        "a_chunk_a_neighbour": [c, 17, "X", 1],
+        "among_its_like": ["x", "x", "X", "x"],  # neighbours of its own length: every slot ends and starts its chunks in step
+    }
+
+
+CROWDS = crowds(C, FULL)
 
 
 @pytest.mark.parametrize("crowd", list(CROWDS))
@@ -123,7 +134,7 @@ def test_a_sequence_reads_the_same_alone_and_among_neighbours(dtype, crowd):
         q = jnp.asarray(rng.standard_normal((4, kv_heads, HEAD_DIM)), dtype)
         alone = np.asarray(_kernel(q, pk, pv, tables, lengths).astype(jnp.float32))[0]
 
-        among = np.asarray([length if n == "X" else n for n in CROWDS[crowd]], np.int32)
+        among = np.asarray([length if n in ("X", "x") else n for n in CROWDS[crowd]], np.int32)
         among_tables = _tables(among, seed=7)
         among_tables[slot] = tables[0]
         spare = [b for b in range(1, POOL_BLOCKS) if b not in tables[0]]
@@ -133,6 +144,41 @@ def test_a_sequence_reads_the_same_alone_and_among_neighbours(dtype, crowd):
         order = [0 if row == slot else 1 + row % 3 for row in range(4)]  # the sequence's query in its slot
         got = _kernel(q[jnp.asarray(order)], pk, pv, among_tables, among)
         assert np.array_equal(alone, np.asarray(got.astype(jnp.float32))[slot]), length
+
+
+def _count(jaxpr, primitive):
+    """Equations of ``primitive`` in ``jaxpr`` and in every jaxpr its equations hold (loops, conditionals)."""
+    return sum((eqn.primitive.name == primitive) + sum(_count(sub, primitive) for sub in jax.core.jaxprs_in_params(eqn.params))
+               for eqn in jaxpr.eqns)
+
+
+def _decode_call(tables, lengths):
+    q, pool = jnp.zeros((2, 16, HEAD_DIM), jnp.bfloat16), jnp.zeros((1, 64 * BLOCK, 16, HEAD_DIM), jnp.bfloat16)
+    return functools.partial(paged_decode_attention, layer=0, block_tables=tables, lengths=lengths, block_size=BLOCK), (q, pool, pool)
+
+
+def _latent_call(tables, lengths):
+    q_l, q_r = jnp.zeros((2, 8, 512), jnp.bfloat16), jnp.zeros((2, 8, 64), jnp.bfloat16)
+    rows = jnp.zeros((1, 64, BLOCK, 640), jnp.bfloat16)
+    return functools.partial(paged_latent_attention, att_index=0, block_tables=tables, lengths=lengths, scale=1.0), (q_l, q_r, rows)
+
+
+@pytest.mark.parametrize("call,pools,unrolled", [(_decode_call, 2, False), (_latent_call, 1, True)],
+                         ids=["keys_and_values_in_a_loop", "latent_rows_unrolled"])
+def test_a_traced_kernel_holds_the_copy_sites_of_its_walk(call, pools, unrolled):
+    """``chunk_walk``'s sites, counted in the traced kernel: the first chunk's
+    start, this sequence's next chunk's, the next sequence's first chunk's, and
+    the wait. What a replica's start pays to trace and lower a kernel is the
+    count of its binds. A kernel that walks a chunk's live blocks in a loop
+    holds one copy a pool a site whatever the table's width (a chunk of 4, 8 or
+    16 blocks); one that walks them unrolled (the latent kernel, where the chip
+    reads the loop a fifth slower) a copy a place of the chunk (4, 8, 32)."""
+    for width, chunk_blocks in ((4, 4), (8, 8), (40, 32 if unrolled else 16)):
+        fn, args = call(jnp.zeros((2, width), jnp.int32), jnp.zeros((2,), jnp.int32))
+        (kernel,) = [eqn for eqn in jax.make_jaxpr(fn)(*args).eqns if eqn.primitive.name == "pallas_call"]
+        a_site = pools * (chunk_blocks if unrolled else 1)
+        counted = _count(kernel.params["jaxpr"], "dma_start"), _count(kernel.params["jaxpr"], "dma_wait")
+        assert counted == (3 * a_site, a_site), width
 
 
 # The step's own row: position ``length - 1`` of four sequences, R a chunk's positions (16 x 32, 16, 24 or 12 blocks
