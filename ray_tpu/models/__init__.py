@@ -38,6 +38,7 @@ PAGED_KINDS = {
     "olmo_hybrid": ("ray_tpu.models.olmo_hybrid", "OlmoHybridConfig"),
     "phi4flash": ("ray_tpu.models.phi4flash", "Phi4FlashConfig"),
     "exaone_moe": ("ray_tpu.models.exaone_moe", "ExaoneMoeConfig"),
+    "falcon_h1": ("ray_tpu.models.falcon_h1", "FalconH1Config"),
 }
 
 

@@ -10,6 +10,16 @@ d_in, negative):
 
 (the skip ``D * c_t`` and the gate are the caller's: ``models/phi4flash.py``).
 
+**Mamba-2's state** (Dao, Gu, arXiv:2405.21060; ``models/falcon_h1.py``) is the
+same arithmetic over (N, channels) with two things coarser: ``B`` and ``C`` are
+shared by a **group** of channels and not by all (``bm``, ``cm`` (B, G, N):
+group ``g`` is channels ``g d_in / G ..``), and the decay is one number a head,
+``exp(dt_h A_h)``, constant down a column and across the head's channels. The
+step and the update take it **given** (``decay`` (B, d_in), in ``a``'s place):
+a sequence then costs as many exponentials as it has heads, computed by the
+caller, and not one an entry of the state. Between chunks of a prompt that
+form is a matrix product: ``ops/ssd.py``.
+
 * ``ssm_step``: that, for B rows, in ``jax.numpy``. A decode step off the TPU
   and the tests' statement of the rule; ``ssm_read`` is its ``y`` from a state
   as stored.
@@ -53,22 +63,34 @@ _TOKENS = 8  # positions the prefill's kernel reads a load: a float32 tile's sub
 _VMEM_LIMIT = 48 << 20
 
 
+def _over_channels(m, d_in):
+    """``B`` or ``C`` against a state (B, N, d_in): (B, N) -> (B, N, 1), every
+    channel's; (B, G, N) -> (B, N, d_in), group ``g``'s over its ``d_in / G``
+    channels. Float32."""
+    m = m.astype(jnp.float32)
+    if m.ndim == 2:
+        return m[:, :, None]
+    return jnp.repeat(jnp.swapaxes(m, 1, 2), d_in // m.shape[1], axis=2)
+
+
 def ssm_read(state, cm):
-    """``y = C h``: ``state`` (B, N, d_in) float32, ``cm`` (B, N) -> (B, d_in)
-    float32. Products and a sum, elementwise: no matmul whose precision a
-    backend may choose."""
-    return jnp.sum(state * cm.astype(jnp.float32)[:, :, None], axis=1)
+    """``y = C h``: ``state`` (B, N, d_in) float32, ``cm`` (B, N), or (B, G, N)
+    a group of channels -> (B, d_in) float32. Products and a sum, elementwise:
+    no matmul whose precision a backend may choose."""
+    return jnp.sum(state * _over_channels(cm, state.shape[-1]), axis=1)
 
 
-def ssm_step(state, c, dl, bm, cm, a, advance=None):
+def ssm_step(state, c, dl, bm, cm, a, advance=None, *, decay=None):
     """One token a row. ``state`` (B, N, d_in) float32; ``c``, ``dl`` (B,
-    d_in); ``bm``, ``cm`` (B, N); ``a`` (N, d_in), negative; ``advance`` (B,)
-    bool or None (every row). -> (y (B, d_in) float32, the new state). A
-    caller that must get the same ``y`` from an update and from its replay
-    reads it with ``ssm_read`` from the state *as stored*
+    d_in); ``bm``, ``cm`` (B, N), or (B, G, N) a group of channels; ``a`` (N,
+    d_in), negative, or None with ``decay`` (B, d_in) given in ``exp(dl a)``'s
+    place; ``advance`` (B,) bool or None (every row). -> (y (B, d_in) float32,
+    the new state). A caller that must get the same ``y`` from an update and
+    from its replay reads it with ``ssm_read`` from the state *as stored*
     (``gated_delta.gated_delta_step`` says why)."""
     c, dl = c.astype(jnp.float32), dl.astype(jnp.float32)
-    new = jnp.exp(dl[:, None, :] * a[None]) * state + (dl * c)[:, None, :] * bm.astype(jnp.float32)[:, :, None]
+    decay = jnp.exp(dl[:, None, :] * a[None]) if decay is None else decay.astype(jnp.float32)[:, None, :]
+    new = decay * state + (dl * c)[:, None, :] * _over_channels(bm, state.shape[-1])
     if advance is not None:
         new = jnp.where(advance[:, None, None], new, state)
     return ssm_read(new, cm), new
@@ -90,42 +112,59 @@ def _over_lanes(x, width):
 # -- a decode step's update, over the pool ------------------------------------------------
 
 
-def _update_kernel(li_ref, rows_ref, adv_ref, c_ref, dl_ref, b_ref, cm_ref, a_ref, s_ref, y_ref, s_out, *, width):
-    """One row's state (N, d_in) a grid step, a lane tile at a time."""
+_ROWS = 64  # state dimensions a pass of the update's kernel holds in registers: 8 vector registers a lane tile
+
+
+def _update_kernel(li_ref, rows_ref, adv_ref, c_ref, dl_ref, b_ref, cm_ref, a_ref, s_ref, y_ref, s_out, *, width, given):
+    """One row's state (N, d_in) a grid step, a lane tile and ``_ROWS`` state
+    dimensions at a time. ``b_ref``, ``cm_ref`` (1, G, N, width): a tile's
+    group is fixed where the kernel is traced. ``given``: ``a_ref`` is the
+    row's decays (1, 1, d_in), not the layer's ``A``."""
     del li_ref, rows_ref
     advance = adv_ref[pl.program_id(0)] != 0
-    d_in = a_ref.shape[-1]
-    b, cm = b_ref[0], cm_ref[0]  # (N, width)
+    groups, n = b_ref.shape[1:3]
+    d_in = s_ref.shape[-1]
     for first in range(0, d_in, width):
-        at = pl.ds(first, width)
-        state, dl = s_ref[0, 0, :, at], dl_ref[0, :, at]
-        new = jnp.exp(dl * a_ref[0, :, at]) * state + (dl * c_ref[0, :, at]) * b
-        new = jnp.where(advance, new, state)
-        s_out[0, 0, :, at] = new
-        y_ref[0, :, at] = jnp.sum(new * cm, axis=0, keepdims=True)
+        at, g = pl.ds(first, width), first // (d_in // groups)
+        dl = dl_ref[0, :, at]
+        x, y = dl * c_ref[0, :, at], None
+        for lo in range(0, n, _ROWS):
+            some = pl.ds(lo, min(_ROWS, n - lo))
+            state = s_ref[0, 0, some, at]
+            decay = a_ref[0, :, at] if given else jnp.exp(dl * a_ref[0, some, at])
+            new = jnp.where(advance, decay * state + x * b_ref[0, g, some, :], state)
+            s_out[0, 0, some, at] = new
+            part = jnp.sum(new * cm_ref[0, g, some, :], axis=0, keepdims=True)
+            y = part if y is None else y + part
+        y_ref[0, :, at] = y
 
 
-def selective_scan_update(pool, layer, rows, advance, c, dl, bm, cm, a, *, interpret=False):
+def selective_scan_update(pool, layer, rows, advance, c, dl, bm, cm, a=None, *, decay=None, interpret=False):
     """``ssm_step`` over the state pool where it lies. ``pool`` (layers, rows,
     N, d_in) float32; ``layer`` (traced) and ``rows`` (B,) name each sequence's
     state, ``advance`` (B,) bool as above; ``a`` (layers, N, d_in) float32,
-    negative, the stack's; the rest as ``ssm_step`` takes them. -> (y (B, d_in)
-    float32, the pool, the rows named updated in place; every other row
-    untouched). Rows that several sequences name (the null row of inactive
-    slots) must not advance."""
+    negative, the stack's, or None with ``decay`` (B, d_in) given; the rest as
+    ``ssm_step`` takes them. -> (y (B, d_in) float32, the pool, the rows named
+    updated in place; every other row untouched). Rows that several sequences
+    name (the null row of inactive slots) must not advance."""
     b, d_in = c.shape
-    n = bm.shape[-1]
+    if bm.ndim == 2:
+        bm, cm = bm[:, None], cm[:, None]
+    groups, n = bm.shape[1:]
     width = min(_LANES, d_in)  # the interpreter cuts lanes anywhere
+    if (d_in // groups) % width:
+        raise ValueError(f"{groups} groups of {d_in} channels are not whole lane tiles of {width}")
     row = pl.BlockSpec((1, 1, d_in), lambda i, *_: (i, 0, 0))
-    col = pl.BlockSpec((1, n, width), lambda i, *_: (i, 0, 0))
-    decay = pl.BlockSpec((1, n, d_in), lambda i, li, rows, adv: (li[0], 0, 0))
+    col = pl.BlockSpec((1, groups, n, width), lambda i, *_: (i, 0, 0, 0))
+    layers = pl.BlockSpec((1, n, d_in), lambda i, li, rows, adv: (li[0], 0, 0))
     state = pl.BlockSpec((1, 1, n, d_in), lambda i, li, rows, adv: (li[0], rows[i], 0, 0))
+    given = decay is not None
     y, pool = pl.pallas_call(
-        functools.partial(_update_kernel, width=width),
+        functools.partial(_update_kernel, width=width, given=given),
         out_shape=(jax.ShapeDtypeStruct((b, 1, d_in), jnp.float32), jax.ShapeDtypeStruct(pool.shape, pool.dtype)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(b,),
-            in_specs=[row, row, col, col, decay, state], out_specs=(row, state),
+            in_specs=[row, row, col, col, row if given else layers, state], out_specs=(row, state),
         ),
         input_output_aliases={8: 1},  # the pool, counted with the three prefetched scalars
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",), vmem_limit_bytes=_VMEM_LIMIT),
@@ -134,7 +173,7 @@ def selective_scan_update(pool, layer, rows, advance, c, dl, bm, cm, a, *, inter
     )(
         jnp.asarray(layer, jnp.int32).reshape(1), rows.astype(jnp.int32), advance.astype(jnp.int32),
         c.astype(jnp.float32)[:, None], dl.astype(jnp.float32)[:, None], _over_lanes(bm, width), _over_lanes(cm, width),
-        a, pool,
+        decay.astype(jnp.float32)[:, None] if given else a, pool,
     )
     return y[:, 0], pool
 

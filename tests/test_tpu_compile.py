@@ -606,6 +606,88 @@ def test_exaone_moe_decode_and_prefill_at_published_widths(one_chip, monkeypatch
     assert mem.temp_size_in_bytes < 0.5e9
 
 
+def test_falcon_h1_decode_and_prefill_at_published_widths(one_chip, monkeypatch):
+    """serve.llm's programs for Falcon-H1-34B-Instruct as the benchmark's
+    configuration cuts it (``benchmarks/configs/falcon-h1-34b-6l.json``): the
+    published widths, layers 0-5 of 72 as one section of a layer a call, the
+    whole vocabulary untied, the engine's 48 slots over 6,145 blocks of every
+    layer's four K/V heads and 49 state rows of six (256, 4096) float32 states.
+    The file's arithmetic against the compiler: 10.51 GB of weights, a 1.21 GB
+    K/V pool and 1.25 GB of state rows are the programs' arguments, 12.96 GB,
+    and the pool comes back in place. The decode step's one layer body holds the
+    paged kernel over the flat pool (it writes the step's row: no scatter over
+    the pool is left) and the state's update (``selective_scan_update`` within
+    its fast-memory budget: a 4 MB row in and out, twice buffered), and copies
+    no pool, state or layer's matrix. A prefill of 1,024 holds the flash kernel,
+    runs the recurrence as matrix products (eight chunks; no state-space kernel)
+    and scatters the prompt's blocks; its own memory stays under 0.2 GB."""
+    import json
+    import re
+
+    from benchmarks.families import falcon_h1 as family
+    from ray_tpu.models import falcon_h1 as M, paged
+    from ray_tpu.serve.llm.deployment import _resolve_model_cfg
+
+    _steered_to_tpu(monkeypatch)
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks", "configs", "falcon-h1-34b-6l.json")) as f:
+        config = json.load(f)
+    cfg = _resolve_model_cfg(family.model_kwargs(config))
+    e = config["engine"]
+    block, blocks, batch, per_seq = e["block_size"], e["num_blocks"], e["max_batch"], e["max_blocks_per_seq"]
+    prefill, _, decode_greedy = paged.make_paged_fns(M.paged_layer, cfg, block_size=block, state_rows=True)
+    params = _on(one_chip, jax.eval_shape(lambda: M.init_params(jax.random.PRNGKey(0), cfg)))
+    pool = _on(one_chip, jax.eval_shape(lambda: M.init_paged_pool(cfg, blocks, block, batch + 1)))
+
+    def nbytes(tree):
+        return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
+
+    assert 10.50e9 < nbytes(params) < 10.52e9
+    assert 1.20e9 < nbytes(pool["k"]) + nbytes(pool["v"]) == blocks * M.paged_block_bytes(cfg, block) < 1.21e9
+    rows = sum(nbytes(pool[name]) for name in ("state", "conv", "state_pos"))
+    assert 1.24e9 < rows == (batch + 1) * M.paged_state_bytes(cfg) < 1.25e9
+    flat, state = f"{blocks * block * 4},128", "49,256,4096"
+    assert pool["k"].shape == (6, blocks * block * 4, 128) and pool["state"].shape == (6, 49, 256, 4096)
+    assert not hasattr(M, "paged_layouts")  # every projection's contraction lies in the tiles as it is stacked
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def pools_copied(text):
+        """Instructions of their own that make a pool, or a layer of one, anew."""
+        pools = {f"{lead}{dims}" for dims in (flat, state, "49,20480") for lead in ("", "1,", "6,")}
+        return [(dims, op) for dims, _, op in _alone(text)
+                if dims in pools and op in ("copy", "transpose", "gather", "dynamic-slice")]
+
+    def pool_writes(text):
+        return re.findall(rf"= bf16\[6,{flat}\]\S* (?:dynamic-update-slice|scatter)\(", text)
+
+    def state_writes(text):
+        return re.findall(rf"= f32\[6,{state}\]\S* (?:dynamic-update-slice|scatter)\(", text)
+
+    compiled = decode_greedy.lower(
+        params, arg((batch,), jnp.int32), arg((batch,), jnp.int32), arg((batch, per_seq), jnp.int32), pool,
+        arg((batch,), jnp.bool_),
+    ).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert _kernels(text) == ["paged_decode_attention", "selective_scan_update"]  # one layer body: each once
+    layers = {lead + ",".join(map(str, x.shape[1:])) for x in params.values() if x.ndim > 2 and x.shape[1] >= 4096
+              for lead in ("", "1,")}  # the matrices
+    assert not pools_copied(text)
+    assert not [(dims, op) for dims, layout, op in _alone(text) if dims in layers and (op == "copy" or "S(1)" in layout)]
+    # both kernels write where they read: no scatter over the K/V pool and no update-slice of a state is left
+    assert not pool_writes(text) and "paged_scatter" not in text and not state_writes(text)
+    assert 12.9e9 < mem.argument_size_in_bytes < 13.0e9 and mem.temp_size_in_bytes < 0.05e9
+    assert mem.alias_size_in_bytes > 0.999 * nbytes(pool)  # the pool comes back in place
+    compiled = prefill.lower(
+        params, arg((1, 1024), jnp.int32), arg((1, per_seq), jnp.int32), pool, arg((), jnp.int32)).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert _kernels(text) == ["flash_attention"]
+    assert len(pool_writes(text)) == 2 and "paged_scatter" in text  # a prompt's blocks, K and V: ``write_spans`` stays
+    assert len(state_writes(text)) == 1  # the prompt's last state into its row
+    assert not pools_copied(text)
+    assert mem.temp_size_in_bytes < 0.2e9
+
+
 def _steered_to_tpu(monkeypatch):
     """``attention`` asks ``jax.default_backend()``, which is the CPU here:
     the test steers it to the branch it takes on the chip."""

@@ -64,9 +64,10 @@ def test_asha_stops_bad_trials(ray_start_regular, tmp_path):
 
     def objective(config):
         for i in range(1, 21):
-            # bad trials have high loss and would run long if not stopped
+            # bad trials have high loss and would run long if not stopped; the worse a trial, the later it reports, so
+            # that the fourth to reach a rung (the one the halving judges) is not the best one, whatever the start-up order
             train.report({"loss": config["q"] + i * 0.0})
-            time.sleep(0.02)
+            time.sleep(0.05 * config["q"])
 
     tuner = Tuner(
         objective,
