@@ -20,6 +20,11 @@ A head keeps a matrix ``S`` (d_k x d_v). A token with query ``q``, key ``k``
   WY form: inside a chunk the updates are solved as one unit-triangular system,
   between chunks the state is carried). It is the prefill's, held to the token
   recurrence by a test.
+* ``unit_lower_solve``: that system's solve, forward substitution by blocks of
+  ``SOLVE_BLOCK`` rows as batched float32 products over every chunk, row and
+  head at once (PR 48: block rows on the right-hand side were kept, the whole
+  inverse built by halves was as fast on the chip and read 3 to 5 times the
+  error on the CPU; no power of ``L`` is formed). One path on every platform.
 
 **How a state lies in memory.** (d_k, H x d_v), float32: the key dimension in
 the sublanes and every head's values side by side in the lanes. A head's own
@@ -45,6 +50,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 CHUNK = 64
+SOLVE_BLOCK = 16  # rows of a diagonal block of a chunk's unit-triangular system (``unit_lower_solve``)
 HIGHEST = jax.lax.Precision.HIGHEST
 _L2_EPS = 1e-6
 _LANES = 128
@@ -216,20 +222,84 @@ def short_conv_step(windows, u, weights, owner, advance, bias=None):
 # -- a prompt, in chunks ----------------------------------------------------------------
 
 
+def unit_lower_solve(lower, rhs):
+    """``X`` of ``(I + L) X = rhs`` for ``lower`` = ``L`` (..., c, c), strictly
+    lower triangular, and ``rhs`` (..., c, d): forward substitution by blocks
+    of ``SOLVE_BLOCK`` rows, every system at once, float32.
+
+    Two phases. The first inverts every diagonal block ``I + L_ii`` by the
+    substitution written out, row ``r`` of the inverse from the rows above it:
+    a loop of ``SOLVE_BLOCK`` steps with the blocks of all systems side by side
+    in the lanes (a (16, 16) block a system would fill an eighth of its
+    tiles). The second takes block row ``i`` of ``X`` as ``inv(I + L_ii)
+    (rhs_i - L_i,<i X_<i)``: two batched products at the highest precision a
+    block row, ``c / SOLVE_BLOCK`` deep, written out. A ``c`` that is not whole
+    blocks (the tests' 24 and 20 with blocks of 16; a power of two is nothing
+    special here) is padded with rows of the identity, which solve to the
+    zeros they are handed and are cut off again.
+
+    No power of ``L`` is formed and no inverse wider than a block: with
+    ``beta`` near 2 and a chunk's keys nearly parallel ``L`` is ~2 everywhere
+    under its diagonal, its powers grow by binomials (past 1e20) while the
+    solution stays ~10, and the product form ``(I - L)(I + L^2)(I + L^4)..``
+    cancels to nothing in float32 (NaN on the tests' adversarial case); the
+    whole inverse built by halves (``inv [[A, 0], [C, B]] = [[inv A, 0],
+    [-inv B C inv A, inv B]]``, 16 to 64) reads 3 to 5 times this form's error
+    there (``tests/test_gated_delta.py`` has every reading).
+
+    PR 48, on the chip (TPU v5 lite; 240 systems of 64 x 64 against 288
+    columns, the hybrid's 512 bucket; device time of ``gated_delta_chunked``
+    whole, us a call): ``jax.lax.linalg.triangular_solve``, which the TPU's
+    compiler lowers to ``InvertDiagBlocksLowerTriangular`` (a walk a row at a
+    time over each 64 x 64 block), 1,606.8; this form 491.4; the inverse by
+    halves 495.1 from diagonal blocks of 16 and 528.8 from 8; block rows of 8
+    614.5, of 32 649.9. Which phase is a loop was measured too: with the first
+    written out as well 494.4, and 373 more operations a prefill program,
+    3.5 s more of every replica's start; with the second a loop over ``X``
+    whole 825.4, its update of ``X`` a copy of all of it a step."""
+    c, w = lower.shape[-1], SOLVE_BLOCK
+    short = -c % w
+    if short:
+        batch = [(0, 0)] * (lower.ndim - 2)
+        lower = jnp.pad(lower, batch + [(0, short), (0, short)])
+        rhs = jnp.pad(rhs, batch + [(0, short), (0, 0)])
+    blocks = [slice(i, i + w) for i in range(0, c + short, w)]
+    diagonal = jnp.stack([lower[..., at, at] for at in blocks], axis=-3)  # (..., blocks, w, w)
+    across = jnp.moveaxis(diagonal.reshape(-1, w, w), 0, -1)  # (w, w, every block of every system): those in the lanes
+    eye = jnp.eye(w, dtype=lower.dtype)[..., None]
+
+    def row(r, inverse):  # the inverse's rows from r on are still zero, and so is L's row r there
+        return inverse.at[r].set(eye[r] - jnp.sum(across[r][:, None] * inverse, axis=0))
+
+    inverse = jax.lax.fori_loop(0, w, row, jnp.zeros_like(across))
+    inverse = jnp.moveaxis(inverse, -1, 0).reshape(diagonal.shape)
+    dot = functools.partial(jnp.einsum, "...ij,...jk->...ik", precision=HIGHEST)
+    solved = []
+    for at in blocks:
+        ahead = rhs[..., at, :]
+        if solved:
+            ahead = ahead - dot(lower[..., at, :at.start], jnp.concatenate(solved, axis=-2))
+        solved.append(dot(inverse[..., len(solved), :, :], ahead))
+    return jnp.concatenate(solved, axis=-2)[..., :c, :]
+
+
 def gated_delta_chunked(q, k, v, g, beta, live, chunk: int = CHUNK):
     """A prompt from an empty state. ``q``, ``k`` (B, S, H, d_k) as they leave
     the short convolution, ``v`` (B, S, H, d_v), ``g``, ``beta`` (B, S, H),
     ``live`` (B, S) bool: a padded position passes the state through (decay 1,
-    strength 0). S a multiple of ``chunk`` or less than it. -> (o (B, S, H,
-    d_v) float32, the state after the last position (B, d_k, H x d_v) float32).
+    strength 0, so its row of ``L`` is 0). S a multiple of ``chunk`` or less
+    than it. -> (o (B, S, H, d_v) float32, the state after the last position
+    (B, d_k, H x d_v) float32).
 
     Inside a chunk, with ``gamma`` the running sum of ``g`` and ``M_ij =
     exp(gamma_i - gamma_j)`` for i >= j: ``L = strict_tril((K beta) K^T . M)``,
-    ``U = (I + L)^-1 (V beta)``, ``W = (I + L)^-1 (K beta exp(gamma))``; with
-    the state ``S_0`` the chunk meets: ``V' = U - W S_0``, ``O = (Q exp(gamma))
-    S_0 + tril(Q K^T . M) V'``, ``S_1 = exp(gamma_C) S_0 + (K exp(gamma_C -
-    gamma))^T V'``. Float32, contractions at the highest precision: the state
-    outlives the prompt by a thousand steps."""
+    ``U = (I + L)^-1 (V beta)``, ``W = (I + L)^-1 (K beta exp(gamma))``, both
+    from one ``unit_lower_solve`` over every chunk, row and head at once, ahead
+    of the scan between chunks; with the state ``S_0`` the chunk meets: ``V' =
+    U - W S_0``, ``O = (Q exp(gamma)) S_0 + tril(Q K^T . M) V'``, ``S_1 =
+    exp(gamma_C) S_0 + (K exp(gamma_C - gamma))^T V'``. Float32, contractions
+    at the highest precision: the state outlives the prompt by a thousand
+    steps."""
     b, s, heads, d_k = q.shape
     d_v = v.shape[-1]
     c = min(chunk, s)
@@ -249,9 +319,9 @@ def gated_delta_chunked(q, k, v, g, beta, live, chunk: int = CHUNK):
     decay = jnp.exp(jnp.where(lower, gamma[..., :, None] - gamma[..., None, :], -jnp.inf))  # M
     kb = k * beta[..., None]
     dot = functools.partial(jnp.einsum, precision=HIGHEST)
-    system = jnp.eye(c) + jnp.where(jnp.tril(lower, -1), dot("nbhik,nbhjk->nbhij", kb, k) * decay, 0.0)
+    strict = jnp.where(jnp.tril(lower, -1), dot("nbhik,nbhjk->nbhij", kb, k) * decay, 0.0)  # L
     rhs = jnp.concatenate([v * beta[..., None], kb * jnp.exp(gamma)[..., None]], axis=-1)
-    solved = jax.lax.linalg.triangular_solve(system, rhs, left_side=True, lower=True, unit_diagonal=True)
+    solved = unit_lower_solve(strict, rhs)
     u, w = solved[..., :d_v], solved[..., d_v:]
     within = dot("nbhik,nbhjk->nbhij", q, k) * decay
     q_in = q * jnp.exp(gamma)[..., None]

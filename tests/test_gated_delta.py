@@ -64,7 +64,7 @@ def test_the_chunkwise_form_equals_the_token_recurrence(length, bucket, chunk):
     """Lengths that are whole chunks and lengths that are not: the padded
     positions (decay 1, strength 0) pass the state through, whole chunks of
     padding too. Tolerance 5e-6 absolute on outputs of size ~1: float32 sums in
-    another order, and a unit-triangular solve a chunk."""
+    another order, and a unit-triangular system solved by blocks a chunk."""
     args = inputs(length, seed=length)
     want_o, want_s = stepped(*args)
     padded = [jnp.pad(x, [(0, 0), (0, bucket - length)] + [(0, 0)] * (x.ndim - 2)) for x in args]
@@ -77,6 +77,65 @@ def test_the_chunkwise_form_equals_the_token_recurrence(length, bucket, chunk):
     np.testing.assert_allclose(got_s, want_s, atol=5e-6)
     with pytest.raises(ValueError, match="not whole chunks"):
         G.gated_delta_chunked(*[x[:, :bucket - 1] for x in padded], live[:, :bucket - 1], chunk=min(chunk, 8))
+
+
+def chunk_systems(r, c, close=False):
+    """Seeded systems of the chunkwise form's own kind, float64: ``L = strict_tril((K beta) K^T . M)`` over B x H
+    chunks of ``c`` normalised keys, and right-hand sides ``[V beta | K beta exp(gamma)]``. ``close``: the keys of a
+    chunk within 1e-3 of one direction, ``beta`` in 1.9-2.0, decays within 0.001 of 1."""
+    k = r.normal(size=(B, H, 1 if close else c, DK)) + (1e-3 * r.normal(size=(B, H, c, DK)) if close else 0.0)
+    beta = r.uniform(1.9, 2.0, size=(B, H, c)) if close else r.uniform(0.0, 2.0, size=(B, H, c))
+    gamma = np.cumsum(-r.uniform(0.0, 0.001, size=(B, H, c)) if close else -r.uniform(0.001, 0.3, size=(B, H, c)), axis=-1)
+    k = k / np.sqrt((k * k).sum(-1, keepdims=True) + 1e-6)
+    kb = k * beta[..., None]
+    lower = np.tril(np.einsum("bhik,bhjk->bhij", kb, k) * np.exp(gamma[..., :, None] - gamma[..., None, :]), -1)
+    return lower, np.concatenate([r.normal(size=(B, H, c, DV)) * beta[..., None], kb * np.exp(gamma)[..., None]], axis=-1)
+
+
+@pytest.mark.parametrize("c,close,atol", [(8, False, 2e-6), (16, False, 2e-6), (32, False, 2e-6), (64, False, 2e-6),
+                                          (24, False, 2e-6), (20, False, 2e-6), (64, True, 4e-4)])
+def test_the_solve_alone_is_the_substitution_in_float64(c, close, atol):
+    """``unit_lower_solve`` by itself against ``scipy.linalg.solve_triangular`` in float64, a system at a time: every
+    chunk the function takes (8, 16, 32 in the tests above, 64 on the chip) and two that are no power of two (24 and
+    20 rows are padded to 32 with rows of the identity). Solutions of size ~10: the parent's ``triangular_solve`` read
+    5.3e-7 to 1.6e-6 on these systems where this reads 4.1e-7 to 1.2e-6. The last case is the adversarial chunk by
+    itself (solutions up to 42): the parent's substitution a row at a time read 7.6e-5 and blocks of 16 read 2.8e-4;
+    in the mean of eight more seeds 1.1e-4 and 2.2e-4 (blocks of 8 1.5e-4, of 32 3.8e-4, the whole inverse built by
+    halves 5.8e-4 to 7.7e-4): an inverse of a block is formed, so the wider the block the further from a row-by-row
+    substitution, and inside ``gated_delta_chunked`` the chunk scan's own products cover the difference (below)."""
+    from scipy.linalg import solve_triangular
+
+    lower, rhs = chunk_systems(np.random.default_rng(c), c, close)
+    got = np.asarray(jax.jit(G.unit_lower_solve)(jnp.asarray(lower, jnp.float32), jnp.asarray(rhs, jnp.float32)), np.float64)
+    assert got.shape == rhs.shape
+    for i in range(B):
+        for h in range(H):
+            want = solve_triangular(np.eye(c) + lower[i, h], rhs[i, h], lower=True, unit_diagonal=True)
+            np.testing.assert_allclose(got[i, h], want, atol=atol)
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_the_chunkwise_form_holds_where_a_chunks_keys_are_nearly_parallel(seed):
+    """The adversarial case: 512 positions in chunks of 64, the keys of a chunk within 1e-3 of one direction,
+    ``beta`` in 1.9-2.0, ``g`` in -0.001..0, so that ``L`` is nearly 2 everywhere under its diagonal and its powers
+    grow by binomials while outputs stay under 10 and the state under 19. Against the rule in float64. Tolerance 2e-4
+    absolute, beside what the forms read on these inputs (outputs / state, seeds 0, 1, 2): the parent's
+    ``triangular_solve`` 9.5e-5 / 1.04e-4, 7.6e-5 / 8.2e-5, 5.3e-5 / 7.9e-5; this one (block rows of 16) 6.3e-5 /
+    1.40e-4, 5.1e-5 / 9.6e-5, 6.4e-5 / 9.0e-5; block rows of 8 5.8e-5 / 1.21e-4, 7.9e-5 / 1.00e-4, 5.9e-5 / 1.06e-4. Over
+    six seeds the state reads 8.7e-5 in the parent's mean (7.0e-5 to 1.04e-4), 1.01e-4 in this form's (8.5e-5 to
+    1.40e-4), 9.8e-5 with blocks of 8: the forms lie inside one seed's swing of each other, and the tolerance is the
+    parent's largest reading with that swing on top. Blocks of 32 read 2.0e-4 in the mean (1.4e-4 to 2.7e-4), the
+    whole inverse built by halves 3.4e-4 to 5.4e-4, and the product form ``(I - L)(I + L^2)(I + L^4)..`` NaN."""
+    r = np.random.default_rng(seed)
+    s, c = 512, G.CHUNK
+    k = (r.normal(size=(B, s // c, 1, H, DK)) + 1e-3 * r.normal(size=(B, s // c, c, H, DK))).reshape(B, s, H, DK)
+    q, v = r.normal(size=(B, s, H, DK)), r.normal(size=(B, s, H, DV))
+    g, beta = -r.uniform(0.0, 0.001, size=(B, s, H)), r.uniform(1.9, 2.0, size=(B, s, H))
+    args = [jnp.asarray(x, jnp.float32) for x in (q, k, v, g, beta)]
+    want_o, want_s = by_hand(*args)
+    got_o, got_s = jax.jit(G.gated_delta_chunked)(*args, jnp.ones((B, s), bool))
+    np.testing.assert_allclose(got_o, want_o, atol=2e-4)
+    np.testing.assert_allclose(got_s, want_s, atol=2e-4)
 
 
 def test_the_kernel_in_interpret_mode_is_the_step_over_the_rows_it_is_given():

@@ -368,7 +368,8 @@ def test_hybrid_decode_and_prefill_at_olmo_hybrid_widths(one_chip, monkeypatch):
     holds both kernels, ``gated_delta_update`` a linear layer and
     ``paged_decode_attention`` a full one, and makes no copy of the state
     pool, of the window pool or of a K/V layer. The prefill holds neither
-    kernel and gathers nothing out of the K/V pool."""
+    kernel, gathers nothing out of the K/V pool and solves its chunks without a
+    triangular-solve custom-call."""
     import json
     import re
 
@@ -420,6 +421,10 @@ def test_hybrid_decode_and_prefill_at_olmo_hybrid_widths(one_chip, monkeypatch):
         params, arg((1, 512), jnp.int32), arg((1, per_seq), jnp.int32), pool, arg((), jnp.int32)).compile()
     text, mem = compiled.as_text(), compiled.memory_analysis()
     assert "gated_delta_update" not in text and "paged_decode_attention" not in text
+    # the chunks' unit-triangular systems are solved by blocks, as products: until PR 48 each linear layer of the
+    # period held a custom-call to "InvertDiagBlocksLowerTriangular" (what the TPU's compiler makes of
+    # ``triangular_solve``: a walk a row at a time over every 64 x 64 block, a third of the prefill's device time)
+    assert "InvertDiagBlocksLowerTriangular" not in text and "triangular" not in text.lower()
     assert not pools_copied(text)
     assert mem.temp_size_in_bytes < 0.08e9  # beside 14.4 GB of arguments in a chip of 15.75 usable
 
