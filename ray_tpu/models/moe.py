@@ -23,11 +23,14 @@ over the experts held (``grouped_matmul``: ``jax.lax.ragged_dot``, on a TPU
 JAX's Pallas ``gmm`` at a stated tiling) a window at a time, so no token is
 ever dropped, whatever the routing. A window is ``window_rows`` compacted
 rows, a size that follows the rows the held experts expect and not the batch
-(a chip that holds 12 of 384 experts owns a thirty-second of a step's rows,
-and the row tile an expert's weights are streamed under is the rows handed in);
+(a chip that holds 12 of 384 experts owns a thirty-second of a step's rows);
 windows are walked on the device until the held rows run out: one for an even
 router, none where no held expert was chosen, and a layer that holds every
-expert has one window of every row.
+expert has one window of every row. Inside a window the kernel multiplies a
+touched expert by row tiles of at most ``ROW_TILE`` rows: a decode step's
+window is one tile, under which every touched expert's weights are streamed
+once; a prefill's window of 512 rows is two, so that an expert is multiplied
+by the tile its rows lie in and not by the whole window.
 """
 
 from __future__ import annotations
@@ -130,23 +133,41 @@ def window_rows(n_rows: int, held: int, n_outputs: int) -> int:
     the smallest power of two at or above ``WINDOW_MULTIPLE`` times the held
     rows an even router sends (``n_rows x held / n_outputs``), at least
     ``WINDOW_MIN`` and at most every row; and, where it is not every row, at
-    most the row tile the grouped kernel takes (``_KERNEL_ROWS``): a chip that
-    holds an eighth of the experts owns an eighth of a long prefill's rows, and
-    a window past the tile would send all of them through ``ragged_dot``
-    (PERF.md section 6, PR 43)."""
+    most the rows the grouped kernel takes in one call (``_KERNEL_ROWS``, which
+    it walks under row tiles of at most ``ROW_TILE``): a chip that holds an
+    eighth of the experts owns an eighth of a long prefill's rows, and a window
+    past that would send all of them through ``ragged_dot`` (PERF.md section
+    6, PR 43)."""
     window = WINDOW_MIN
     while window * n_outputs < WINDOW_MULTIPLE * n_rows * held:
         window *= 2
     return n_rows if window >= n_rows else min(window, _KERNEL_ROWS)
 
 
-# The grouped kernel's tiles: a window is one row tile, whatever its rows (so
-# every touched expert's weights are streamed once), and an expert's matrix
-# goes by in tiles of ``_WEIGHT_TILE`` elements (2 MB of bfloat16), the
-# contraction whole where it is no longer than ``_WHOLE_K``.
+# The grouped kernel's tiles. A window is walked once, under row tiles of at
+# most ``ROW_TILE`` rows: the kernel visits each (row tile, expert) pair that
+# holds a row, streams the expert's weights for it and multiplies them by the
+# whole tile, so a pair costs the larger of the expert's bytes and the tile's
+# products. 256 is the chip's ridge (197e12 FLOP/s over 819e9 B/s = 240 rows of
+# bfloat16): under it a pair is bound by the bytes, and one tile a window
+# streams every touched expert once (a decode step's windows, 32-256 rows);
+# over it by the products, and a prefill's 512 rows as one tile multiplied every
+# touched expert by rows other experts own, at the MXU's peak: 0.21 ms a pair
+# of an expert of 75 MB, where tiles of 256 take 0.12 and tiles of 128 save
+# nothing more, their pairs being more (PERF.md section 6, PR 49). A power of
+# two, so a window is whole tiles; rows that are no whole tiles (144) stay one.
+# A row's result does not depend on its tile: the contraction's order is the
+# weight tile's.
+# An expert's matrix goes by in tiles of ``_WEIGHT_TILE`` elements (2 MB of
+# bfloat16), the contraction whole where it is no longer than ``_WHOLE_K``.
+ROW_TILE = 256
 _WEIGHT_TILE = 1 << 20
 _WHOLE_K = 2048
-_KERNEL_ROWS = 512  # the largest window the kernel takes as one row tile
+_KERNEL_ROWS = 512  # the most rows the kernel takes in one call
+
+
+def _row_tile(rows: int) -> int:
+    return rows if rows % ROW_TILE else ROW_TILE
 
 
 def _weight_tile(k: int, n: int) -> Tuple[int, int]:
@@ -157,8 +178,8 @@ def _weight_tile(k: int, n: int) -> Tuple[int, int]:
 def can_use_grouped_kernel(rows, experts) -> bool:
     """Platform and static shape alone, as ``ops.paged_attention``'s kernels
     are chosen: a TPU, rows and experts of one 16-bit type, a window of whole
-    sublane tiles that fits one row tile, and matrices of whole weight tiles
-    of whole lanes."""
+    sublane tiles and no more than ``_KERNEL_ROWS`` rows, and matrices of whole
+    weight tiles of whole lanes."""
     if jax.default_backend() != "tpu":
         return False
     (r, k), n = rows.shape, experts.shape[-1]
@@ -180,7 +201,7 @@ def grouped_matmul(rows, experts, groups, out_type=None):
     shapes the kernel does not take, ``jax.lax.ragged_dot``."""
     out_type = rows.dtype if out_type is None else out_type
     if can_use_grouped_kernel(rows, experts):
-        tiling = (rows.shape[0], *_weight_tile(rows.shape[1], experts.shape[-1]))
+        tiling = (_row_tile(rows.shape[0]), *_weight_tile(rows.shape[1], experts.shape[-1]))
         return _megablox_gmm(rows, experts, groups, preferred_element_type=out_type, tiling=tiling)
     return jax.lax.ragged_dot(rows, experts, groups, preferred_element_type=out_type)
 

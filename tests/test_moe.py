@@ -131,7 +131,7 @@ def test_the_window_follows_the_held_rows_and_not_the_batch(n_rows, held, n_outp
 @pytest.mark.parametrize("rows,k,n,dtype,taken", [
     (32, 7168, 2048, jnp.bfloat16, True), (32, 2048, 7168, jnp.bfloat16, True),  # Kimi-K2's decode window: gate, down
     (512, 6144, 2048, jnp.bfloat16, True), (512, 2048, 6144, jnp.bfloat16, True),  # LongCat's 1,024 bucket
-    (1024, 7168, 2048, jnp.bfloat16, False),  # more rows than one row tile takes: every expert held, a large batch
+    (1024, 7168, 2048, jnp.bfloat16, False),  # more rows than the kernel takes in one call: every expert held, a large batch
     (24, 7168, 2048, jnp.bfloat16, False),  # no whole sublane tiles
     (32, 7168, 2048, jnp.float32, False),  # the tests' float32 twins
     (32, 7000, 2048, jnp.bfloat16, False), (32, 2048, 1000, jnp.bfloat16, False),  # no whole weight tiles
@@ -145,23 +145,69 @@ def test_the_grouped_kernel_is_chosen_by_platform_and_static_shape(monkeypatch, 
     assert tk * tn <= 1 << 20 and (not taken or (k % tk == 0 and n % tn == 0))
 
 
-def test_the_grouped_kernel_at_its_tiling_agrees_with_ragged_dot():
+def _stacked_operands(rows, sizes):
+    """``rows`` rows, three layers' experts stacked, and groups of which the
+    second layer's alone have rows (``sizes``)."""
+    k, n, held = 4096, 256, len(sizes)  # k past ``_WHOLE_K``: tiles of 512 x 256, eight steps of the contraction
+    x = jax.random.normal(jax.random.PRNGKey(0), (rows, k)).astype(jnp.bfloat16)
+    w = (jax.random.normal(jax.random.PRNGKey(1), (3 * held, k, n)) * k ** -0.5).astype(jnp.bfloat16)
+    return x, w, jnp.zeros((3 * held,), jnp.int32).at[held:2 * held].set(jnp.asarray(sizes))
+
+
+# rows handed in, the rows of one layer's four groups, the tiling ``grouped_matmul`` states
+TILINGS = {
+    # a decode step's window: one row tile, dead rows past the last group
+    "decode_window": (32, [5, 0, 9, 7], (32, 512, 256)),
+    # a prefill's window, two row tiles: the third group's rows straddle row 256, 62 dead rows at the end
+    "straddles_row_256": (512, [100, 0, 200, 150], (256, 512, 256)),
+    # held rows end inside the first tile: the second is wholly dead (LongCat's 1,024 bucket) and never visited
+    "second_tile_dead": (512, [60, 0, 90, 70], (256, 512, 256)),
+    # a group ends at the boundary, no row is dead
+    "boundary_and_full": (512, [256, 0, 128, 128], (256, 512, 256)),
+    # rows that are no whole tiles stay one tile
+    "one_odd_tile": (144, [40, 0, 60, 30], (144, 512, 256)),
+}
+
+
+@pytest.mark.parametrize("case", list(TILINGS))
+def test_the_grouped_kernel_at_its_tiling_agrees_with_ragged_dot(monkeypatch, case):
     """JAX's Pallas grouped matmul in interpret mode, at the tiling
     ``grouped_matmul`` states, against ``jax.lax.ragged_dot``: groups of a
     stacked tensor of which one layer's have rows, an empty group among them,
     and dead rows past the last group (left as whatever was there)."""
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
 
-    rows, k, n, held = 32, 4096, 256, 4  # k past ``_WHOLE_K``: tiles of 512 x 256, eight steps of the contraction
-    x = jax.random.normal(jax.random.PRNGKey(0), (rows, k)).astype(jnp.bfloat16)
-    w = (jax.random.normal(jax.random.PRNGKey(1), (3 * held, k, n)) * k ** -0.5).astype(jnp.bfloat16)
-    groups = jnp.zeros((3 * held,), jnp.int32).at[held:2 * held].set(jnp.asarray([5, 0, 9, 7]))
-    tiling = (rows, *moe._weight_tile(k, n))
-    assert tiling == (32, 512, 256)
-    got = gmm(x, w, groups, preferred_element_type=jnp.float32, tiling=tiling, interpret=True)
+    rows, sizes, tiling = TILINGS[case]
+    x, w, groups = _stacked_operands(rows, sizes)
     want = jax.lax.ragged_dot(x, w, groups, preferred_element_type=jnp.float32)
-    np.testing.assert_allclose(np.asarray(got)[:21], np.asarray(want)[:21], rtol=2e-2, atol=2e-2)
-    np.testing.assert_array_equal(np.asarray(want)[:21] != 0, True)
+    stated = []
+
+    def interpreted(*args, tiling, **kwargs):
+        stated.append(tiling)
+        return gmm(*args, tiling=tiling, interpret=True, **kwargs)
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the branch ``grouped_matmul`` takes on the chip
+    monkeypatch.setattr(moe, "_megablox_gmm", interpreted)
+    got = moe.grouped_matmul(x, w, groups, jnp.float32)
+    assert stated == [tiling]
+    live = sum(sizes)
+    np.testing.assert_allclose(np.asarray(got)[:live], np.asarray(want)[:live], rtol=2e-2, atol=2e-2)
+    np.testing.assert_array_equal(np.asarray(want)[:live] != 0, True)
+
+
+@pytest.mark.parametrize("case", [c for c, (rows, _, _) in TILINGS.items() if rows == 512])
+def test_a_rows_result_is_the_same_bits_under_either_row_tile(case):
+    """The contraction's order is the weight tile's: the held rows of a window
+    come out bit for bit the same through row tiles of 256 and through the one
+    tile of 512 that a prefill's window was."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+    rows, sizes, (_, tk, tn) = TILINGS[case]
+    x, w, groups = _stacked_operands(rows, sizes)
+    halves, whole = (np.asarray(gmm(x, w, groups, preferred_element_type=jnp.float32, tiling=(tm, tk, tn), interpret=True))
+                     for tm in (moe.ROW_TILE, rows))
+    live = sum(sizes)
+    np.testing.assert_array_equal(halves[:live].view(np.uint32), whole[:live].view(np.uint32))
 
 
 def test_a_window_never_exceeds_the_kernels_row_tile_and_the_older_kinds_windows_are_what_they_were():
@@ -169,9 +215,9 @@ def test_a_window_never_exceeds_the_kernels_row_tile_and_the_older_kinds_windows
     384, top-8) and LongCat (16 of 768 outputs, top-12) never met the cap: their
     largest windows are 512 exactly, and their programs lower as before.
     K-EXAONE holds an eighth of its experts: its 512 and 1,024 buckets would
-    have had windows of 1,024 and 2,048 rows, past the grouped kernel's row
-    tile, and walk windows of 512 instead. A layer that holds every expert
-    keeps one window of every row."""
+    have had windows of 1,024 and 2,048 rows, past the rows the grouped kernel
+    takes in one call, and walk windows of 512 instead. A layer that holds
+    every expert keeps one window of every row."""
     kimi = {48: 32, 128: 64, 256: 128, 512: 256, 1024: 512}
     for tokens, window in kimi.items():
         assert moe.window_rows(tokens * 8, 12, 384) == window
@@ -182,3 +228,31 @@ def test_a_window_never_exceeds_the_kernels_row_tile_and_the_older_kinds_windows
     for tokens, window in exaone.items():
         assert moe.window_rows(tokens * 8, 16, 128) == window <= moe._KERNEL_ROWS
     assert moe.window_rows(4096, 8, 8) == 4096 and moe.window_rows(48 * 3, 4, 8) == 144
+
+
+# kind: (choices a token, experts held, the router's outputs, {tokens: (window, row tile)})
+REACHED = {
+    "kimi": (8, 12, 384, {48: (32, 32), 128: (64, 64), 256: (128, 128), 512: (256, 256)}),
+    "longcat": (12, 16, 768, {32: (32, 32), 256: (128, 128), 512: (256, 256), 1024: (512, 256)}),
+    "exaone": (8, 16, 128, {48: (128, 128), 256: (512, 256), 512: (512, 256), 1024: (512, 256)}),
+    "every_expert_held": (3, 4, 8, {48: (144, 144)}),
+}
+
+
+@pytest.mark.parametrize("kind,tokens", [(kind, tokens) for kind, case in REACHED.items() for tokens in case[3]])
+def test_the_row_tile_at_every_window_the_benchmarks_kinds_reach(monkeypatch, kind, tokens):
+    """What ``grouped_matmul`` states for the window ``window_rows`` gives at
+    a decode step's and every prefill bucket's tokens: one tile of the window's
+    rows up to ``ROW_TILE`` (every decode program, all of Kimi-K2, LongCat's
+    smaller buckets: as they were), tiles of ``ROW_TILE`` in a window of 512
+    (LongCat's 1,024 bucket, K-EXAONE's three), and one tile where the rows
+    are no whole tiles."""
+    top_k, held, n_outputs, reached = REACHED[kind]
+    window, row_tile = reached[tokens]
+    assert moe.window_rows(tokens * top_k, held, n_outputs) == window
+    stated = []
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(moe, "_megablox_gmm", lambda x, w, g, *, tiling, preferred_element_type: stated.append(tiling) or x)
+    moe.grouped_matmul(jnp.zeros((window, 128), jnp.bfloat16), jnp.zeros((held, 128, 128), jnp.bfloat16), jnp.zeros((held,), jnp.int32))
+    assert stated == [(row_tile, 128, 128)] and window % row_tile == 0
+

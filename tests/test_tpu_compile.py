@@ -244,12 +244,31 @@ def _grouped_matmuls_take(text, rows, width, all_rows):
     assert str(all_rows) not in re.findall(rf"= \w+\[(\d+),{width}\]\S* gather\(", text)
 
 
+def _stated_tilings(monkeypatch):
+    """The tilings ``moe.grouped_matmul`` hands JAX's grouped kernel while a
+    program is traced, as a set that fills as the programs are lowered. A
+    decode step's windows and every window of Kimi-K2 are one row tile of the
+    operand's rows, the statement they always made, so those programs' lowered
+    texts are the parent's (PR 49 compared them, sha256 for sha256); a
+    window of 512 rows goes under two tiles of ``moe.ROW_TILE``."""
+    from ray_tpu.models import moe
+
+    stated, gmm = set(), moe._megablox_gmm
+
+    def recorded(*args, tiling, **kwargs):
+        stated.add(tiling)
+        return gmm(*args, tiling=tiling, **kwargs)
+
+    monkeypatch.setattr(moe, "_megablox_gmm", recorded)
+    return stated
+
+
 def test_latent_decode_and_prefill_at_longcat_widths(one_chip, monkeypatch):
     """serve.llm's programs for LongCat-Flash's language model at the published
     widths, one chip's share of the experts (16 of 512), 2 layers. The decode
     step holds the grouped matmuls over a window of 32 of its 384 (token,
-    choice) rows (``moe.window_rows``; a 1,024 bucket's over 512 of 12,288) and
-    the latent kernel,
+    choice) rows (``moe.window_rows``; a 1,024 bucket's over 512 of 12,288,
+    under row tiles of 256) and the latent kernel,
     which reads a table's live blocks where they lie (no gathered copy), and
     never re-lays the pool out: its rows are stored 640 wide (576
     values: the TPU gives such a pool another device layout than the one the
@@ -258,6 +277,7 @@ def test_latent_decode_and_prefill_at_longcat_widths(one_chip, monkeypatch):
     from ray_tpu.models import longcat as M, paged
 
     _steered_to_tpu(monkeypatch)
+    stated = _stated_tilings(monkeypatch)
     cfg = M.LongcatConfig(vocab_size=16384, num_layers=2, experts_held=16)
     block, blocks, batch, per_seq = 16, 4097, 32, 128
     prefill, _, decode_greedy = paged.make_paged_fns(M.paged_layer, cfg, block_size=block)
@@ -273,10 +293,13 @@ def test_latent_decode_and_prefill_at_longcat_widths(one_chip, monkeypatch):
         arg((batch,), jnp.bool_),
     ).compile().as_text()
     _grouped_matmuls_take(text, 32, 6144, batch * cfg.moe_topk)  # gate, up and down of the held experts
+    assert stated == {(32, 512, 2048), (32, 2048, 512)}  # one row tile, the window's rows: the text is the parent's
     _latent_kernel_reads_the_pool_in_place(text, f"[4,{blocks},{block},640]", batch, per_seq, block)
+    stated.clear()
     text = prefill.lower(
         params, arg((1, 1024), jnp.int32), arg((1, per_seq), jnp.int32), pool, arg((), jnp.int32)).compile().as_text()
     _grouped_matmuls_take(text, 512, 6144, 1024 * cfg.moe_topk)
+    assert stated == {(256, 512, 2048), (256, 2048, 512)}  # the operand's 512 rows under two row tiles
     assert "paged_latent_attention" not in text
     assert "gather(" not in "".join(line for line in text.splitlines() if f",{block},640]" in line)
 
@@ -308,6 +331,7 @@ def test_latent_decode_and_prefill_at_kimi_k2_widths(one_chip, monkeypatch):
     from ray_tpu.models import kimi as M, paged
 
     _steered_to_tpu(monkeypatch)
+    stated = _stated_tilings(monkeypatch)
     cfg = M.KimiConfig(vocab_size=20480, num_hidden_layers=7, experts_held=12)
     assert (cfg.first_k_dense_replace, cfg.n_expert_layers) == (1, 6) and not hasattr(M, "paged_layouts")
     block, blocks, batch, per_seq = 16, 7681, 48, 96
@@ -349,6 +373,8 @@ def test_latent_decode_and_prefill_at_kimi_k2_widths(one_chip, monkeypatch):
         params, arg((1, 512), jnp.int32), arg((1, per_seq), jnp.int32), pool, arg((), jnp.int32)).compile()
     text = compiled.as_text()
     _grouped_matmuls_take(text, 256, 7168, 512 * cfg.num_experts_per_tok)
+    # every window of the kind is one row tile of its own rows: all three programs' texts are the parent's
+    assert stated == {(rows, *tile) for rows in (32, 256) for tile in ((512, 2048), (2048, 512))}
     assert "paged_latent_attention" not in text
     assert "gather(" not in "".join(line for line in text.splitlines() if f",{block},640]" in line)
     assert staged(text) <= {"wqb", "wkvb"}
@@ -531,9 +557,10 @@ def test_exaone_moe_decode_and_prefill_at_published_widths(one_chip, monkeypatch
     layer over the flat pool of eight heads, and three grouped matmuls an expert
     layer over windows of 128 rows; no conditional (a ``lax.cond`` on the layer's
     kind copied both rings whole in its full branch), no pool or ring copied. A
-    prefill of 1,024 walks windows of 512 rows through the grouped kernel (no
-    ``ragged-dot``), holds the flash kernel a full layer and writes each ring
-    once; its own memory stays under 0.5 GB."""
+    prefill of 1,024 walks windows of 512 rows through the grouped kernel, each
+    under two row tiles of 256 (``moe.ROW_TILE``; no ``ragged-dot``), holds the
+    flash kernel a full layer and writes each ring once; its own memory stays
+    under 0.5 GB."""
     import json
     import re
 
@@ -542,6 +569,7 @@ def test_exaone_moe_decode_and_prefill_at_published_widths(one_chip, monkeypatch
     from ray_tpu.serve.llm.deployment import _resolve_model_cfg
 
     _steered_to_tpu(monkeypatch)
+    stated = _stated_tilings(monkeypatch)
     with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks", "configs", "k-exaone-236b-8l.json")) as f:
         config = json.load(f)
     cfg = _resolve_model_cfg(family.model_kwargs(config))
@@ -592,6 +620,8 @@ def test_exaone_moe_decode_and_prefill_at_published_widths(one_chip, monkeypatch
     # a section's body holds its layers' kernels once: (W), (W W F), (W W W F); three grouped matmuls an expert layer
     assert (kernels.count("ring_window_attention"), kernels.count("paged_decode_attention"), kernels.count("gmm")) == (6, 2, 21)
     assert gmm_rows(text) == [128] * 21 and "ragged-dot" not in text and " conditional(" not in text
+    assert stated == {(128, 512, 2048), (128, 2048, 512)}  # one row tile, the window's rows: the text is the parent's
+    stated.clear()
     assert not pools_copied(text)
     assert not ring_writes(text) and "ring_scatter" not in text  # the ring's kernel writes a step's row itself
     # and the paged kernel a full layer's, into the pools it scores (its outputs in place): no scatter over a pool is
@@ -604,6 +634,7 @@ def test_exaone_moe_decode_and_prefill_at_published_widths(one_chip, monkeypatch
     text, mem = compiled.as_text(), compiled.memory_analysis()
     kernels = _kernels(text)
     assert (kernels.count("flash_attention"), kernels.count("gmm")) == (2, 21) and gmm_rows(text) == [512] * 21
+    assert stated == {(256, 512, 2048), (256, 2048, 512)}  # the operand's 512 rows under two row tiles
     assert "ragged-dot" not in text and not {"ring_window_attention", "paged_decode_attention"} & set(kernels)
     assert len(ring_writes(text)) == 12 and "ring_scatter" in text  # a prompt's whole ring, K and V, a window layer
     assert len(pool_writes(text)) == 4 and "paged_scatter" in text  # a prompt's blocks, K and V, a full layer: ``write_spans`` stays
