@@ -39,6 +39,7 @@ PAGED_KINDS = {
     "phi4flash": ("ray_tpu.models.phi4flash", "Phi4FlashConfig"),
     "exaone_moe": ("ray_tpu.models.exaone_moe", "ExaoneMoeConfig"),
     "falcon_h1": ("ray_tpu.models.falcon_h1", "FalconH1Config"),
+    "lfm2_moe": ("ray_tpu.models.lfm2_moe", "Lfm2MoeConfig"),
 }
 
 

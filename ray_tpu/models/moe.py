@@ -115,17 +115,18 @@ def route(u: jax.Array, router: jax.Array, bias: jax.Array, *, top_k: int, scale
     return scale * jnp.take_along_axis(p, experts, axis=-1), experts
 
 
-def route_sigmoid(u: jax.Array, router: jax.Array, bias: jax.Array, *, top_k: int, scale: float):
+def route_sigmoid(u: jax.Array, router: jax.Array, bias: jax.Array, *, top_k: int, scale: float, eps: float = 1e-20):
     """The second rule, same signature. Sigmoid scores in float32 over every
     output; the ``top_k`` of ``s + bias`` are chosen (one group of experts:
     the published rule first keeps the ``topk_group`` best of ``n_group``
     groups, which at 1 of 1 keeps all; the kind refuses other values); a
-    chosen output's weight is ``scale * s / (sum of the chosen s + 1e-20)``."""
+    chosen output's weight is ``scale * s / (sum of the chosen s + eps)``
+    (``eps`` is the model's: DeepSeek-V3's 1e-20, LFM2's 1e-6)."""
     z = jnp.dot(u.astype(jnp.float32), router.astype(jnp.float32), precision=jax.lax.Precision.HIGHEST)
     s = jax.nn.sigmoid(z)
     _, experts = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
     chosen = jnp.take_along_axis(s, experts, axis=-1)
-    return scale * chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20), experts
+    return scale * chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + eps), experts
 
 
 def window_rows(n_rows: int, held: int, n_outputs: int) -> int:
@@ -133,11 +134,12 @@ def window_rows(n_rows: int, held: int, n_outputs: int) -> int:
     the smallest power of two at or above ``WINDOW_MULTIPLE`` times the held
     rows an even router sends (``n_rows x held / n_outputs``), at least
     ``WINDOW_MIN`` and at most every row; and, where it is not every row, at
-    most the rows the grouped kernel takes in one call (``_KERNEL_ROWS``, which
-    it walks under row tiles of at most ``ROW_TILE``): a chip that holds an
-    eighth of the experts owns an eighth of a long prefill's rows, and a window
-    past that would send all of them through ``ragged_dot`` (PERF.md section
-    6, PR 43)."""
+    most ``_KERNEL_ROWS``: a chip that holds an eighth of the experts owns an
+    eighth of a long prefill's rows and walks them 512 at a time (PERF.md
+    section 6, PR 43). A layer that holds every expert has one window of every
+    row, a prefill's 4,096 too: one call under ``ROW_TILE`` tiles visits the
+    same (tile, expert) pairs as eight windows would and gathers, sorts and
+    scatters once (PERF.md section 6, PR 50)."""
     window = WINDOW_MIN
     while window * n_outputs < WINDOW_MULTIPLE * n_rows * held:
         window *= 2
@@ -163,7 +165,7 @@ def window_rows(n_rows: int, held: int, n_outputs: int) -> int:
 ROW_TILE = 256
 _WEIGHT_TILE = 1 << 20
 _WHOLE_K = 2048
-_KERNEL_ROWS = 512  # the most rows the kernel takes in one call
+_KERNEL_ROWS = 512  # the most rows of one row tile, and of a window that is not every row
 
 
 def _row_tile(rows: int) -> int:
@@ -171,22 +173,29 @@ def _row_tile(rows: int) -> int:
 
 
 def _weight_tile(k: int, n: int) -> Tuple[int, int]:
+    """(rows, columns) of a matrix (k, n) a tile: ``_WEIGHT_TILE`` elements at
+    the most, and where that many columns do not divide ``n`` (a contraction of
+    1,536 leaves 682 of 2,048) the widest whole lanes under them that do (512)."""
     tk = k if k <= _WHOLE_K else 512
-    return tk, min(n, _WEIGHT_TILE // tk)
+    tn = min(n, _WEIGHT_TILE // tk)
+    if n % tn:
+        tn = next((w for w in range(tn // 128 * 128, 0, -128) if n % w == 0), tn)
+    return tk, tn
 
 
 def can_use_grouped_kernel(rows, experts) -> bool:
     """Platform and static shape alone, as ``ops.paged_attention``'s kernels
     are chosen: a TPU, rows and experts of one 16-bit type, a window of whole
-    sublane tiles and no more than ``_KERNEL_ROWS`` rows, and matrices of whole
-    weight tiles of whole lanes."""
+    sublane tiles whose row tile (``_row_tile``: ``ROW_TILE``, of which the
+    kernel walks any number, or every row) is no more than ``_KERNEL_ROWS``
+    rows, and matrices of whole weight tiles of whole lanes."""
     if jax.default_backend() != "tpu":
         return False
     (r, k), n = rows.shape, experts.shape[-1]
     tk, tn = _weight_tile(k, n)
     return (
         rows.dtype == experts.dtype and jnp.dtype(rows.dtype).itemsize == 2
-        and r % 16 == 0 and r <= _KERNEL_ROWS
+        and r % 16 == 0 and _row_tile(r) <= _KERNEL_ROWS
         and k % tk == 0 and n % tn == 0 and tk % 128 == 0 and tn % 128 == 0
     )
 

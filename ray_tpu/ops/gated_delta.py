@@ -190,7 +190,7 @@ def gated_delta_update(pool, layer, rows, advance, q, k, v, g, beta, *, interpre
 # -- the short convolution's window, a token a row ------------------------------------------
 
 
-def short_conv_step(windows, u, weights, owner, advance, bias=None):
+def short_conv_step(windows, u, weights, owner, advance, bias=None, activation=jax.nn.silu):
     """One token a sequence through the short convolution, over **all** of a
     layer's windows at once. ``windows`` (R, K x C) in the served type: a row's
     last K inputs, the oldest first, flat in the lanes (shifting one in is a
@@ -200,6 +200,8 @@ def short_conv_step(windows, u, weights, owner, advance, bias=None):
     it is false the window stays as stored, which already holds this token.
     ``bias`` (C,) or None: added to the taps' sum before the SiLU (a
     state-space layer's convolution has one, a gated delta-rule layer's none).
+    ``activation``: what the sum goes through (None: nothing, where the
+    convolution is the whole mixer and gated outside: ``models/lfm2_moe.py``).
     -> (silu(sum_j w_j u_{t-K+1+j}) (B, C) float32, the new windows).
 
     Dense on purpose: a layer's windows are a few megabytes, and rows move
@@ -216,7 +218,7 @@ def short_conv_step(windows, u, weights, owner, advance, bias=None):
     taps = sum(mine[:, j * c:(j + 1) * c] * weights[j].astype(jnp.float32) for j in range(k))
     if bias is not None:
         taps = taps + bias.astype(jnp.float32)
-    return jax.nn.silu(taps), windows
+    return (activation(taps) if activation else taps), windows
 
 
 # -- a prompt, in chunks ----------------------------------------------------------------

@@ -221,6 +221,16 @@ def _handle_config(spec: dict) -> dict:
     }
 
 
+def replica_threads(max_ongoing: int) -> int:
+    """A replica's thread pool: larger than the request gate so queued
+    requests are counted (autoscaling metric) and health probes aren't
+    starved by busy request threads. Four times the gate up to 64 threads;
+    past that the gate and a few more (a stream holds its thread while it
+    lasts: a replica whose 128 decode slots and 32 waiting requests met a pool
+    of 64 ran half empty and failed its probes)."""
+    return max(min(64, max_ongoing * 4 + 4), max_ongoing + 4)
+
+
 @ray_tpu.remote(max_concurrency=8)
 class ServeController:
     """Control plane: deployment table + replica reconciliation.
@@ -526,11 +536,8 @@ class ServeController:
         max_ongoing = spec["max_ongoing_requests"]
         replicas = []
         for _ in range(spec["num_replicas"]):
-            # thread pool larger than the request gate so queued requests
-            # are counted (autoscaling metric) and health probes aren't
-            # starved by busy request threads
             r = Replica.options(
-                max_concurrency=min(64, max_ongoing * 4 + 4),
+                max_concurrency=replica_threads(max_ongoing),
                 num_cpus=opts.get("num_cpus", 0.0),
                 num_tpus=opts.get("num_tpus", 0.0),
                 resources=opts.get("resources"),

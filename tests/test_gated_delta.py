@@ -194,8 +194,10 @@ def test_the_gates_and_where_the_kernel_is_chosen(monkeypatch):
     assert not G.can_use_gated_delta_kernel(30, 90, 192)  # keys that do not fill sublane tiles
 
 
-def test_the_short_convolutions_step_is_the_convolution_written_out():
-    """A window is the last K inputs, the current one among them: shifting a
+@pytest.mark.parametrize("activation", ["silu", None])
+def test_the_short_convolutions_step_is_the_convolution_written_out(activation):
+    """(``activation`` None: the taps' sum as it is, where the convolution is
+    the whole mixer and gated outside, ``models/lfm2_moe.py``.) A window is the last K inputs, the current one among them: shifting a
     token in and summing the K taps a channel gives ``silu(sum_j w_j u_{t-K+1+j})``
     over the whole sequence's inputs, zeros before its start. The step runs over
     all of a layer's windows: a row nobody holds, and the null row that every
@@ -207,14 +209,15 @@ def test_the_short_convolutions_step_is_the_convolution_written_out():
     w = jnp.asarray(r.normal(size=(taps, channels)), jnp.float32)
     padded = np.concatenate([np.zeros((3, taps - 1, channels)), np.asarray(u, np.float64)], axis=1)
     summed = sum(padded[:, j:j + steps] * np.asarray(w, np.float64)[j] for j in range(taps))
-    want = summed / (1 + np.exp(-summed))
+    want = summed / (1 + np.exp(-summed)) if activation else summed
+    kw = {} if activation else {"activation": None}  # left out: the SiLU every recurrent kind's convolution has
     held = np.asarray([3, 1, 0])  # the rows the three slots name; the inactive one names the null row
     owner = jnp.asarray((held[None, :] == np.arange(rows)[:, None]) & np.asarray([True, True, False])[None, :])
     windows = jnp.zeros((rows, taps * channels), jnp.float32).at[4].set(7.0)
     for t in range(steps):
-        got, windows = G.short_conv_step(windows, u[:, t], w, owner, jnp.any(owner, axis=1))
+        got, windows = G.short_conv_step(windows, u[:, t], w, owner, jnp.any(owner, axis=1), **kw)
         np.testing.assert_allclose(got[:2], want[:2, t], atol=1e-5)
-        again, still = G.short_conv_step(windows, u[:, t] * 0 + 9.0, w, owner, jnp.zeros((rows,), bool))  # replayed
+        again, still = G.short_conv_step(windows, u[:, t] * 0 + 9.0, w, owner, jnp.zeros((rows,), bool), **kw)  # replayed
         np.testing.assert_array_equal(again, got)
         np.testing.assert_array_equal(still, windows)
     assert not np.asarray(windows[0]).any() and not np.asarray(windows[2]).any() and (np.asarray(windows[4]) == 7.0).all()

@@ -123,6 +123,8 @@ def test_a_tokens_result_is_the_same_bit_for_bit_wherever_its_rows_fall(rule):
     (1024, 12, 384, 64), (2048, 12, 384, 128), (4096, 12, 384, 256),  # its prefill buckets
     (384, 16, 768, 32), (3072, 16, 768, 128), (6144, 16, 768, 256), (12288, 16, 768, 512),  # LongCat's
     (96, 8, 12, 96), (32, 2, 2, 32), (8, 4, 64, 8), (96, 6, 12, 96),  # most experts held, few rows: one window of every row
+    (192, 64, 64, 192), (512, 64, 64, 512),  # LFM2's decode step at 48 and at 128 slots, every expert held: one window, as it was
+    (1024, 64, 64, 1024), (2048, 64, 64, 2048), (4096, 64, 64, 4096), (8192, 64, 64, 8192),  # its prefill buckets: one call of every row
 ])
 def test_the_window_follows_the_held_rows_and_not_the_batch(n_rows, held, n_outputs, window):
     assert moe.window_rows(n_rows, held, n_outputs) == window
@@ -131,7 +133,9 @@ def test_the_window_follows_the_held_rows_and_not_the_batch(n_rows, held, n_outp
 @pytest.mark.parametrize("rows,k,n,dtype,taken", [
     (32, 7168, 2048, jnp.bfloat16, True), (32, 2048, 7168, jnp.bfloat16, True),  # Kimi-K2's decode window: gate, down
     (512, 6144, 2048, jnp.bfloat16, True), (512, 2048, 6144, jnp.bfloat16, True),  # LongCat's 1,024 bucket
-    (1024, 7168, 2048, jnp.bfloat16, False),  # more rows than the kernel takes in one call: every expert held, a large batch
+    (1024, 7168, 2048, jnp.bfloat16, True),  # past 512 rows in whole row tiles: every expert held, a prefill (PR 50)
+    (640, 7168, 2048, jnp.bfloat16, False),  # past 512 rows and no whole row tiles
+    (512, 2048, 1536, jnp.bfloat16, True), (512, 1536, 2048, jnp.bfloat16, True),  # LFM2's expert of 2048 x 1536: gate, down
     (24, 7168, 2048, jnp.bfloat16, False),  # no whole sublane tiles
     (32, 7168, 2048, jnp.float32, False),  # the tests' float32 twins
     (32, 7000, 2048, jnp.bfloat16, False), (32, 2048, 1000, jnp.bfloat16, False),  # no whole weight tiles
@@ -217,7 +221,9 @@ def test_a_window_never_exceeds_the_kernels_row_tile_and_the_older_kinds_windows
     K-EXAONE holds an eighth of its experts: its 512 and 1,024 buckets would
     have had windows of 1,024 and 2,048 rows, past the rows the grouped kernel
     takes in one call, and walk windows of 512 instead. A layer that holds
-    every expert keeps one window of every row."""
+    every expert (LFM2's 64) has one window of every row, a decode step's 512
+    and a prefill's 4,096 or 8,192, which the grouped kernel takes under row
+    tiles of 256 (past 512 rows they went through ``ragged_dot`` until PR 50)."""
     kimi = {48: 32, 128: 64, 256: 128, 512: 256, 1024: 512}
     for tokens, window in kimi.items():
         assert moe.window_rows(tokens * 8, 12, 384) == window
@@ -227,7 +233,33 @@ def test_a_window_never_exceeds_the_kernels_row_tile_and_the_older_kinds_windows
     exaone = {48: 128, 256: 512, 512: 512, 1024: 512}
     for tokens, window in exaone.items():
         assert moe.window_rows(tokens * 8, 16, 128) == window <= moe._KERNEL_ROWS
-    assert moe.window_rows(4096, 8, 8) == 4096 and moe.window_rows(48 * 3, 4, 8) == 144
+    assert moe.window_rows(48 * 3, 4, 8) == 144
+    for rows, window in {192: 192, 512: 512, 4096: 4096, 8192: 8192}.items():
+        assert moe.window_rows(rows, 64, 64) == window
+        x, w = jax.ShapeDtypeStruct((window, 2048), jnp.bfloat16), jax.ShapeDtypeStruct((64, 2048, 1536), jnp.bfloat16)
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(jax, "default_backend", lambda: "tpu")
+            assert moe.can_use_grouped_kernel(x, w)
+
+
+# (k, n) of every expert matrix the four expert configurations multiply by (gate and up; down) -> its weight tile
+WEIGHT_TILES = {
+    "longcat": {(6144, 2048): (512, 2048), (2048, 6144): (2048, 512)},
+    "kimi": {(7168, 2048): (512, 2048), (2048, 7168): (2048, 512)},
+    "exaone": {(6144, 2048): (512, 2048), (2048, 6144): (2048, 512)},
+    "lfm2": {(2048, 1536): (2048, 512), (1536, 2048): (1536, 512)},
+}
+
+
+@pytest.mark.parametrize("kind,k,n", [(kind, k, n) for kind, tiles in WEIGHT_TILES.items() for k, n in tiles])
+def test_every_expert_matrix_gets_a_tile_of_whole_lanes_that_divides_it_and_the_older_kinds_theirs_as_before(kind, k, n):
+    """``_weight_tile(1536, 2048)`` gave 682 columns, neither whole lanes nor a
+    divisor of 2,048, and LFM2's ``e_down`` fell to ``ragged_dot``: the width
+    is now the widest whole lanes under the tile's size that divide ``n``. Every
+    shape the three older kinds multiply by gets the tile it got (PR 38)."""
+    tk, tn = moe._weight_tile(k, n)
+    assert (tk, tn) == WEIGHT_TILES[kind][(k, n)]
+    assert tn % 128 == 0 and n % tn == 0 and k % tk == 0 and tk * tn <= 1 << 20
 
 
 # kind: (choices a token, experts held, the router's outputs, {tokens: (window, row tile)})
@@ -236,6 +268,7 @@ REACHED = {
     "longcat": (12, 16, 768, {32: (32, 32), 256: (128, 128), 512: (256, 256), 1024: (512, 256)}),
     "exaone": (8, 16, 128, {48: (128, 128), 256: (512, 256), 512: (512, 256), 1024: (512, 256)}),
     "every_expert_held": (3, 4, 8, {48: (144, 144)}),
+    "lfm2": (4, 64, 64, {48: (192, 192), 128: (512, 256), 256: (1024, 256), 512: (2048, 256), 1024: (4096, 256)}),
 }
 
 
@@ -245,8 +278,9 @@ def test_the_row_tile_at_every_window_the_benchmarks_kinds_reach(monkeypatch, ki
     a decode step's and every prefill bucket's tokens: one tile of the window's
     rows up to ``ROW_TILE`` (every decode program, all of Kimi-K2, LongCat's
     smaller buckets: as they were), tiles of ``ROW_TILE`` in a window of 512
-    (LongCat's 1,024 bucket, K-EXAONE's three), and one tile where the rows
-    are no whole tiles."""
+    (LongCat's 1,024 bucket, K-EXAONE's three) and in a whole layer's one
+    window of every row (LFM2's), and one tile where the rows are no whole
+    tiles."""
     top_k, held, n_outputs, reached = REACHED[kind]
     window, row_tile = reached[tokens]
     assert moe.window_rows(tokens * top_k, held, n_outputs) == window

@@ -176,6 +176,48 @@ def test_streaming_response(serve_cluster):
     assert out == [0, 3, 6, 9]
 
 
+@pytest.mark.parametrize("gate,threads", [(1, 8), (8, 36), (15, 64), (40, 64), (60, 64), (64, 68), (80, 84), (160, 164)])
+def test_a_replicas_thread_pool_is_past_its_request_gate_at_every_size(gate, threads):
+    """Four times the gate up to 64 threads (every size it had until a gate of
+    60), and past that the gate and a few more: a stream holds its thread
+    while it lasts, and a health probe needs one."""
+    from ray_tpu.serve.api import replica_threads
+
+    assert replica_threads(gate) == threads > gate
+
+
+def test_a_replica_holds_more_streams_than_64_and_still_answers_another_call(serve_cluster):
+    """65 streams held open under a gate of 66, past the 64 threads a replica's
+    pool stopped at: each has entered (its first item came) and the gate's
+    last request is still answered; then all of them end."""
+    @serve.deployment(max_ongoing_requests=66)
+    class Holder:
+        def __init__(self):
+            import threading
+
+            self.released, self.entered, self.lock = threading.Event(), 0, threading.Lock()
+
+        def hold(self):
+            with self.lock:
+                self.entered += 1
+            yield "in"
+            self.released.wait(120)
+            yield "out"
+
+        def release(self):
+            self.released.set()
+            return self.entered
+
+    handle = serve.run(Holder.bind(), name="holder")
+    streams = [iter(handle.options(stream=True).hold.remote()) for _ in range(65)]
+    try:
+        assert [next(s) for s in streams] == ["in"] * 65
+        assert handle.release.remote().result(timeout_s=60) == 65
+        assert [list(s) for s in streams] == [["out"]] * 65
+    finally:
+        serve.delete("holder")
+
+
 def test_multiplexed_models(serve_cluster):
     @serve.deployment(num_replicas=2)
     class MultiModel:

@@ -724,6 +724,94 @@ def test_falcon_h1_decode_and_prefill_at_published_widths(one_chip, monkeypatch)
     assert mem.temp_size_in_bytes < 0.2e9
 
 
+def test_lfm2_moe_decode_and_prefill_at_published_widths(one_chip, monkeypatch):
+    """serve.llm's programs for LFM2-24B-A2B as the benchmark's configuration
+    cuts it (``benchmarks/configs/lfm2-24b-a2b-8l.json``): the published widths,
+    layers 0-7 as three sections (dense C C; expert F C; expert C C F C), every
+    one of a layer's 64 experts, the whole vocabulary, the engine's 48 slots
+    over 7,681 blocks of the two full layers and 49 state rows of six conv
+    windows. The file's arithmetic against the compiler: 8.05 GB of weights and
+    a 0.50 GB K/V pool are the programs' arguments, and the pool comes back in
+    place. The decode step holds the paged kernel a full layer over the flat
+    pool of **two K/V heads of 64 to a row** (it writes the step's packed row,
+    so no scatter over a pool is left) and **all three** grouped matmuls of an
+    expert of 2048 x 1536 in the grouped kernel (``e_down``'s tile is 1536 x
+    512: whole lanes that divide 2,048), one window of the step's 192 rows,
+    one row tile; no ``ragged-dot``. A prefill of 1,024 holds every
+    expert's rows, 4,096, and hands them to the grouped kernel in one call
+    under sixteen row tiles; it holds the flash kernel a full layer at a head
+    of 64."""
+    import json
+    import re
+
+    from benchmarks.families import lfm2_moe as family
+    from ray_tpu.models import lfm2_moe as M, paged
+    from ray_tpu.serve.llm.deployment import _resolve_model_cfg
+
+    _steered_to_tpu(monkeypatch)
+    stated = _stated_tilings(monkeypatch)
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks", "configs", "lfm2-24b-a2b-8l.json")) as f:
+        config = json.load(f)
+    cfg = _resolve_model_cfg(family.model_kwargs(config))
+    e = config["engine"]
+    block, blocks, batch, per_seq = e["block_size"], e["num_blocks"], e["max_batch"], e["max_blocks_per_seq"]
+    prefill, _, decode_greedy = paged.make_paged_fns(M.paged_layer, cfg, block_size=block, state_rows=True)
+    params = _on(one_chip, jax.eval_shape(lambda: M.init_params(jax.random.PRNGKey(0), cfg)))
+    pool = _on(one_chip, jax.eval_shape(lambda: M.init_paged_pool(cfg, blocks, block, batch + 1)))
+
+    def nbytes(tree):
+        return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
+
+    assert 8.04e9 < nbytes(params) < 8.06e9 and "unembed" not in params
+    assert 0.50e9 < nbytes(pool["k"]) + nbytes(pool["v"]) == blocks * M.paged_block_bytes(cfg, block) < 0.51e9
+    assert nbytes(pool["conv"]) + nbytes(pool["state_pos"]) == (batch + 1) * M.paged_state_bytes(cfg) == 49 * 6 * (12288 + 4)
+    assert (cfg.head_dim, cfg.kv_pack) == (64, 2) and pool["k"].shape == (2, blocks * block * 4, 128)
+    assert pool["conv"].shape == (6, 49, 6144)
+    assert params["e_gate"].shape == (6, 64, 2048, 1536) and params["e_down"].shape == (6, 64, 1536, 2048)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    flat = f"{blocks * block * 4},128"
+
+    def pools_copied(text):
+        pools = {f"{lead}{flat}" for lead in ("", "1,", "2,")}
+        return [(dims, op) for dims, _, op in _alone(text)
+                if dims in pools and op in ("copy", "transpose", "gather", "dynamic-slice")]
+
+    def pool_writes(text):
+        return re.findall(rf"= bf16\[2,{flat}\]\S* (?:dynamic-update-slice|scatter)\(", text)
+
+    def gmm_rows(text):
+        calls = [line for line in text.splitlines() if re.search(r"%gmm[.\d]* = ", line)]
+        return sorted(int(re.search(r"= \w+\[(\d+),\d+\]", line).group(1)) for line in calls)
+
+    compiled = decode_greedy.lower(
+        params, arg((batch,), jnp.int32), arg((batch,), jnp.int32), arg((batch, per_seq), jnp.int32), pool,
+        arg((batch,), jnp.bool_),
+    ).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    kernels = _kernels(text)
+    # a section's body holds its layers' kernels once: (F C), (C C F C); three grouped matmuls an expert layer
+    assert (kernels.count("paged_decode_attention"), kernels.count("gmm")) == (2, 18)
+    assert gmm_rows(text) == [192] * 18 and "ragged-dot" not in text and " conditional(" not in text
+    assert stated == {(192, 2048, 512), (192, 1536, 512)}  # gate and up; down: all three in the kernel
+    stated.clear()
+    assert not pools_copied(text) and not pool_writes(text) and "paged_scatter" not in text and "paged_gather" not in text
+    assert 8.5e9 < mem.argument_size_in_bytes < 8.7e9 and mem.temp_size_in_bytes < 0.2e9
+    assert mem.alias_size_in_bytes > 0.999 * nbytes(pool)  # the pool comes back in place
+    compiled = prefill.lower(
+        params, arg((1, 1024), jnp.int32), arg((1, per_seq), jnp.int32), pool, arg((), jnp.int32)).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    kernels = _kernels(text)
+    assert (kernels.count("flash_attention"), kernels.count("gmm")) == (2, 18) and gmm_rows(text) == [4096] * 18
+    assert stated == {(256, 2048, 512), (256, 1536, 512)}  # one call of every row: no window is walked
+    assert "ragged-dot" not in text and "paged_decode_attention" not in kernels
+    assert len(pool_writes(text)) == 4 and "paged_scatter" in text  # a prompt's blocks, K and V, a full layer
+    assert not pools_copied(text)
+    assert mem.temp_size_in_bytes < 0.5e9
+
+
 def _steered_to_tpu(monkeypatch):
     """``attention`` asks ``jax.default_backend()``, which is the CPU here:
     the test steers it to the branch it takes on the chip."""
