@@ -20,9 +20,9 @@ one chip the layer runs without its exchange.
 No capacity and no ``(tokens, experts, capacity)`` tensor: the (token, choice)
 rows a held expert owns are sorted by expert and go through a grouped matmul
 over the experts held (``grouped_matmul``: ``jax.lax.ragged_dot``, on a TPU
-JAX's Pallas ``gmm`` at a stated tiling) a window at a time, so no token is
-ever dropped, whatever the routing. A window is ``window_rows`` compacted
-rows, a size that follows the rows the held experts expect and not the batch
+the Pallas kernel of ``ops/grouped_matmul.py`` at a stated tiling) a window at
+a time, so no token is ever dropped, whatever the routing. A window is
+``window_rows`` compacted rows, a size that follows the rows the held experts expect and not the batch
 (a chip that holds 12 of 384 experts owns a thirty-second of a step's rows);
 windows are walked on the device until the held rows run out: one for an even
 router, none where no held expert was chosen, and a layer that holds every
@@ -39,8 +39,7 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm as _megablox_gmm
-
+from ray_tpu.ops.grouped_matmul import VMEM_BUDGET, gmm as _gmm, vmem_bytes
 from ray_tpu.ops.layers import swiglu
 
 # what ``expert_layer`` counts of its routing, in this order: (token, choice)
@@ -160,10 +159,27 @@ def window_rows(n_rows: int, held: int, n_outputs: int) -> int:
 # two, so a window is whole tiles; rows that are no whole tiles (144) stay one.
 # A row's result does not depend on its tile: the contraction's order is the
 # weight tile's.
-# An expert's matrix goes by in tiles of ``_WEIGHT_TILE`` elements (2 MB of
-# bfloat16), the contraction whole where it is no longer than ``_WHOLE_K``.
+# An expert's matrix goes by in tiles of at most ``_WEIGHT_TILE`` elements (8 MB
+# of bfloat16) where the products bound the call or the matrix fits one whole,
+# and of a quarter of that where the copies bound it; the contraction whole
+# where it is no longer than ``_WHOLE_K``, each tile as wide as it can be
+# (PERF.md section 6, PR 51; one call alone on the chip, us a call, tiles of 2
+# MB -> 8). Under a row tile of 256 a grid step's fixed cost (the accumulator
+# zeroed, the masked store: ~0.26 us) hides under no copy, and a quarter of the
+# steps takes 5-11% off: 680 -> 645 and 725 -> 642 (LFM2), 690 -> 650 and 679
+# -> 638 (K-EXAONE), 567 -> 527 and 558 -> 522 (Kimi-K2), 649 -> 616 and 648
+# -> 603 (LongCat); 12.6 MB reads what 8 does. A decode step's one row tile is
+# bound by the copies, which run at 90-92% of 819 GB/s at any tile (the copies
+# alone, with no products under them, read the same, and so do two and four in
+# flight); there a larger tile only lengthens the first copy, under which
+# nothing runs: 538 -> 543 and 534 -> 540 at 128 rows alone, 10.73 -> 10.86 ms
+# for the 21 calls of K-EXAONE's step, so those keep 2 MB. What a decode call
+# does pay for is a tile of short runs at a stride of a power of two: LFM2's
+# ``e_down`` in tiles of 1,536 x 512 of 2,048 columns (runs of 1 KB, 4 KB
+# apart) took 610 us for 476 us of bytes, and whole, in one run, takes 566, as
+# ``e_gate`` does at any tile.
 ROW_TILE = 256
-_WEIGHT_TILE = 1 << 20
+_WEIGHT_TILE = 4 << 20
 _WHOLE_K = 2048
 _KERNEL_ROWS = 512  # the most rows of one row tile, and of a window that is not every row
 
@@ -172,46 +188,58 @@ def _row_tile(rows: int) -> int:
     return rows if rows % ROW_TILE else ROW_TILE
 
 
-def _weight_tile(k: int, n: int) -> Tuple[int, int]:
-    """(rows, columns) of a matrix (k, n) a tile: ``_WEIGHT_TILE`` elements at
-    the most, and where that many columns do not divide ``n`` (a contraction of
-    1,536 leaves 682 of 2,048) the widest whole lanes under them that do (512)."""
-    tk = k if k <= _WHOLE_K else 512
-    tn = min(n, _WEIGHT_TILE // tk)
-    if n % tn:
-        tn = next((w for w in range(tn // 128 * 128, 0, -128) if n % w == 0), tn)
-    return tk, tn
+def _widest(size: int, most: int) -> int:
+    """The widest whole lanes (128) at or under ``most`` that divide ``size``
+    (``size`` itself where it is whole lanes and no more), 0 where none do."""
+    return next((w for w in range(min(size, most) // 128 * 128, 0, -128) if size % w == 0), 0)
 
 
-def can_use_grouped_kernel(rows, experts) -> bool:
+def _weight_tile(k: int, n: int, row_tile: int) -> Tuple[int, int]:
+    """(rows, columns) of a matrix (k, n) a tile under a row tile of
+    ``row_tile`` rows. ``_WEIGHT_TILE`` elements at the most where the products
+    bound the call (a row tile at the ridge) or the matrix fits a tile whole
+    (1,536 x 2,048 and 2,048 x 1,536 go by in one run), a quarter of that where
+    the copies bound it; the contraction whole up to ``_WHOLE_K`` and else its
+    widest divisor of whole lanes that leaves a tile ``_WHOLE_K`` columns
+    (7,168: 1,792 of 8 MB, 512 of 2), then the widest whole lanes that divide
+    ``n``. 0 for a side that no whole lanes divide."""
+    most = _WEIGHT_TILE if row_tile >= ROW_TILE or k * n <= _WEIGHT_TILE else _WEIGHT_TILE // 4
+    tk = _widest(k, _WHOLE_K if k <= _WHOLE_K else most // _WHOLE_K)
+    return tk, _widest(n, most // tk) if tk else 0
+
+
+def can_use_grouped_kernel(rows, experts, out_type=None) -> bool:
     """Platform and static shape alone, as ``ops.paged_attention``'s kernels
     are chosen: a TPU, rows and experts of one 16-bit type, a window of whole
     sublane tiles whose row tile (``_row_tile``: ``ROW_TILE``, of which the
     kernel walks any number, or every row) is no more than ``_KERNEL_ROWS``
-    rows, and matrices of whole weight tiles of whole lanes."""
+    rows, matrices of whole weight tiles of whole lanes, and a call whose
+    buffers fit the fast memory a kernel may ask for."""
     if jax.default_backend() != "tpu":
         return False
     (r, k), n = rows.shape, experts.shape[-1]
-    tk, tn = _weight_tile(k, n)
+    tk, tn = _weight_tile(k, n, _row_tile(r))
+    out_itemsize = jnp.dtype(rows.dtype if out_type is None else out_type).itemsize
     return (
         rows.dtype == experts.dtype and jnp.dtype(rows.dtype).itemsize == 2
         and r % 16 == 0 and _row_tile(r) <= _KERNEL_ROWS
-        and k % tk == 0 and n % tn == 0 and tk % 128 == 0 and tn % 128 == 0
+        and tk > 0 and tn > 0
+        and vmem_bytes((_row_tile(r), tk, tn), 2, out_itemsize) <= VMEM_BUDGET
     )
 
 
 def grouped_matmul(rows, experts, groups, out_type=None):
     """``rows`` (R, K), sorted by group, times ``experts`` (G, K, N): the first
     ``groups[0]`` rows by expert 0 and so on; rows past the last group come
-    out as whatever was there. On a TPU JAX's own Pallas grouped matmul at a
-    stated tiling (``jax.lax.ragged_dot``'s lowering streams an expert under
-    512 x 512 tiles, a quarter of the size, and took 126% of the weights'
-    time where this takes 111%: PERF.md section 6, PR 38); elsewhere, and for
-    shapes the kernel does not take, ``jax.lax.ragged_dot``."""
+    out as whatever was there. On a TPU the Pallas grouped matmul
+    (``ops/grouped_matmul.py``) at a stated tiling; elsewhere, and for shapes
+    the kernel does not take, ``jax.lax.ragged_dot`` (whose own lowering on a
+    TPU streams an expert under 512 x 512 tiles: PERF.md section 6, PR 38)."""
     out_type = rows.dtype if out_type is None else out_type
-    if can_use_grouped_kernel(rows, experts):
-        tiling = (_row_tile(rows.shape[0]), *_weight_tile(rows.shape[1], experts.shape[-1]))
-        return _megablox_gmm(rows, experts, groups, preferred_element_type=out_type, tiling=tiling)
+    if can_use_grouped_kernel(rows, experts, out_type):
+        row_tile = _row_tile(rows.shape[0])
+        tiling = (row_tile, *_weight_tile(rows.shape[1], experts.shape[-1], row_tile))
+        return _gmm(rows, experts, groups, preferred_element_type=out_type, tiling=tiling)
     return jax.lax.ragged_dot(rows, experts, groups, preferred_element_type=out_type)
 
 

@@ -139,50 +139,61 @@ def test_the_window_follows_the_held_rows_and_not_the_batch(n_rows, held, n_outp
     (24, 7168, 2048, jnp.bfloat16, False),  # no whole sublane tiles
     (32, 7168, 2048, jnp.float32, False),  # the tests' float32 twins
     (32, 7000, 2048, jnp.bfloat16, False), (32, 2048, 1000, jnp.bfloat16, False),  # no whole weight tiles
+    (512, 128, 65536, jnp.bfloat16, False),  # whole tiles of whole lanes, and more fast memory than a kernel may ask for
 ])
 def test_the_grouped_kernel_is_chosen_by_platform_and_static_shape(monkeypatch, rows, k, n, dtype, taken):
     x, w = jax.ShapeDtypeStruct((rows, k), dtype), jax.ShapeDtypeStruct((6, k, n), dtype)
     assert not moe.can_use_grouped_kernel(x, w)  # the CPU keeps ragged_dot
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert moe.can_use_grouped_kernel(x, w) == taken
-    tk, tn = moe._weight_tile(k, n)
-    assert tk * tn <= 1 << 20 and (not taken or (k % tk == 0 and n % tn == 0))
+    tk, tn = moe._weight_tile(k, n, moe._row_tile(rows))
+    assert tk * tn <= moe._WEIGHT_TILE and (not taken or (k % tk == 0 and n % tn == 0))
 
 
-def _stacked_operands(rows, sizes):
+def _stacked_operands(rows, sizes, k=4096, n=256):
     """``rows`` rows, three layers' experts stacked, and groups of which the
-    second layer's alone have rows (``sizes``)."""
-    k, n, held = 4096, 256, len(sizes)  # k past ``_WHOLE_K``: tiles of 512 x 256, eight steps of the contraction
+    second layer's alone have rows (``sizes``). ``k`` past ``_WHOLE_K``: tiles
+    of 2,048 x 256, two steps of the contraction."""
+    held = len(sizes)
     x = jax.random.normal(jax.random.PRNGKey(0), (rows, k)).astype(jnp.bfloat16)
     w = (jax.random.normal(jax.random.PRNGKey(1), (3 * held, k, n)) * k ** -0.5).astype(jnp.bfloat16)
     return x, w, jnp.zeros((3 * held,), jnp.int32).at[held:2 * held].set(jnp.asarray(sizes))
 
 
-# rows handed in, the rows of one layer's four groups, the tiling ``grouped_matmul`` states
+# rows handed in, the rows of one layer's groups, the tiling ``grouped_matmul`` states, (k, n) where not 4,096 x 256
 TILINGS = {
     # a decode step's window: one row tile, dead rows past the last group
-    "decode_window": (32, [5, 0, 9, 7], (32, 512, 256)),
+    "decode_window": (32, [5, 0, 9, 7], (32, 2048, 256)),
     # a prefill's window, two row tiles: the third group's rows straddle row 256, 62 dead rows at the end
-    "straddles_row_256": (512, [100, 0, 200, 150], (256, 512, 256)),
+    "straddles_row_256": (512, [100, 0, 200, 150], (256, 2048, 256)),
     # held rows end inside the first tile: the second is wholly dead (LongCat's 1,024 bucket) and never visited
-    "second_tile_dead": (512, [60, 0, 90, 70], (256, 512, 256)),
+    "second_tile_dead": (512, [60, 0, 90, 70], (256, 2048, 256)),
     # a group ends at the boundary, no row is dead
-    "boundary_and_full": (512, [256, 0, 128, 128], (256, 512, 256)),
+    "boundary_and_full": (512, [256, 0, 128, 128], (256, 2048, 256)),
     # rows that are no whole tiles stay one tile
-    "one_odd_tile": (144, [40, 0, 60, 30], (144, 512, 256)),
+    "one_odd_tile": (144, [40, 0, 60, 30], (144, 2048, 256)),
+    # LFM2's expert, gate and down, whole: a decode step's 192 rows, empty groups, a ragged last group, dead rows
+    "lfm2_gate_whole": (192, [3, 0, 5, 1, 0, 7, 2, 13], (192, 2048, 1536), (2048, 1536)),
+    "lfm2_down_whole": (192, [3, 0, 5, 1, 0, 7, 2, 13], (192, 1536, 2048), (1536, 2048)),
+    # K-EXAONE's and LongCat's: a decode step's window in twelve tiles of 2 MB, along the contraction (gate) and the
+    # width (down); a prefill's, under row tiles of 256, in three of 8 MB
+    "exaone_gate": (128, [9, 0, 30], (128, 512, 2048), (6144, 2048)),
+    "exaone_down": (128, [9, 0, 30], (128, 2048, 512), (2048, 6144)),
+    "exaone_gate_prefill": (512, [200, 0, 230], (256, 2048, 2048), (6144, 2048)),
+    "exaone_down_prefill": (512, [200, 0, 230], (256, 2048, 2048), (2048, 6144)),
 }
 
 
 @pytest.mark.parametrize("case", list(TILINGS))
 def test_the_grouped_kernel_at_its_tiling_agrees_with_ragged_dot(monkeypatch, case):
-    """JAX's Pallas grouped matmul in interpret mode, at the tiling
-    ``grouped_matmul`` states, against ``jax.lax.ragged_dot``: groups of a
-    stacked tensor of which one layer's have rows, an empty group among them,
-    and dead rows past the last group (left as whatever was there)."""
-    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+    """The grouped kernel (``ops/grouped_matmul.py``) in interpret mode, at the
+    tiling ``grouped_matmul`` states, against ``jax.lax.ragged_dot``: groups
+    of a stacked tensor of which one layer's have rows, an empty group among
+    them, and dead rows past the last group (left as whatever was there)."""
+    from ray_tpu.ops.grouped_matmul import gmm
 
-    rows, sizes, tiling = TILINGS[case]
-    x, w, groups = _stacked_operands(rows, sizes)
+    rows, sizes, tiling, *shape = TILINGS[case]
+    x, w, groups = _stacked_operands(rows, sizes, *(shape[0] if shape else ()))
     want = jax.lax.ragged_dot(x, w, groups, preferred_element_type=jnp.float32)
     stated = []
 
@@ -191,7 +202,7 @@ def test_the_grouped_kernel_at_its_tiling_agrees_with_ragged_dot(monkeypatch, ca
         return gmm(*args, tiling=tiling, interpret=True, **kwargs)
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the branch ``grouped_matmul`` takes on the chip
-    monkeypatch.setattr(moe, "_megablox_gmm", interpreted)
+    monkeypatch.setattr(moe, "_gmm", interpreted)
     got = moe.grouped_matmul(x, w, groups, jnp.float32)
     assert stated == [tiling]
     live = sum(sizes)
@@ -199,12 +210,12 @@ def test_the_grouped_kernel_at_its_tiling_agrees_with_ragged_dot(monkeypatch, ca
     np.testing.assert_array_equal(np.asarray(want)[:live] != 0, True)
 
 
-@pytest.mark.parametrize("case", [c for c, (rows, _, _) in TILINGS.items() if rows == 512])
+@pytest.mark.parametrize("case", [c for c, (rows, *shape) in TILINGS.items() if rows == 512 and len(shape) == 2])
 def test_a_rows_result_is_the_same_bits_under_either_row_tile(case):
     """The contraction's order is the weight tile's: the held rows of a window
     come out bit for bit the same through row tiles of 256 and through the one
     tile of 512 that a prefill's window was."""
-    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+    from ray_tpu.ops.grouped_matmul import gmm
 
     rows, sizes, (_, tk, tn) = TILINGS[case]
     x, w, groups = _stacked_operands(rows, sizes)
@@ -243,23 +254,35 @@ def test_a_window_never_exceeds_the_kernels_row_tile_and_the_older_kinds_windows
 
 
 # (k, n) of every expert matrix the four expert configurations multiply by (gate and up; down) -> its weight tile
+# under a row tile below the ridge (a decode step's window, the small buckets') and under one at it (``ROW_TILE``)
 WEIGHT_TILES = {
-    "longcat": {(6144, 2048): (512, 2048), (2048, 6144): (2048, 512)},
-    "kimi": {(7168, 2048): (512, 2048), (2048, 7168): (2048, 512)},
-    "exaone": {(6144, 2048): (512, 2048), (2048, 6144): (2048, 512)},
-    "lfm2": {(2048, 1536): (2048, 512), (1536, 2048): (1536, 512)},
+    "longcat": {(6144, 2048): ((512, 2048), (2048, 2048)), (2048, 6144): ((2048, 512), (2048, 2048))},
+    "kimi": {(7168, 2048): ((512, 2048), (1792, 2048)), (2048, 7168): ((2048, 512), (2048, 1792))},
+    "exaone": {(6144, 2048): ((512, 2048), (2048, 2048)), (2048, 6144): ((2048, 512), (2048, 2048))},
+    "lfm2": {(2048, 1536): ((2048, 1536), (2048, 1536)), (1536, 2048): ((1536, 2048), (1536, 2048))},
 }
 
 
 @pytest.mark.parametrize("kind,k,n", [(kind, k, n) for kind, tiles in WEIGHT_TILES.items() for k, n in tiles])
 def test_every_expert_matrix_gets_a_tile_of_whole_lanes_that_divides_it_and_the_older_kinds_theirs_as_before(kind, k, n):
-    """``_weight_tile(1536, 2048)`` gave 682 columns, neither whole lanes nor a
-    divisor of 2,048, and LFM2's ``e_down`` fell to ``ragged_dot``: the width
-    is now the widest whole lanes under the tile's size that divide ``n``. Every
-    shape the three older kinds multiply by gets the tile it got (PR 38)."""
-    tk, tn = moe._weight_tile(k, n)
-    assert (tk, tn) == WEIGHT_TILES[kind][(k, n)]
-    assert tn % 128 == 0 and n % tn == 0 and k % tk == 0 and tk * tn <= 1 << 20
+    """Under a row tile at the ridge, where the products bound a call, the
+    fewest tiles of at most 8 MB, of whole lanes, that divide the matrix: the
+    three older kinds' 25 and 29 MB in three and four tiles, where they went by
+    in twelve and fourteen of 2 MB (PR 38) and under a smaller row tile still
+    do, the tile they got. LFM2's two shapes fit a tile and go by whole under
+    any row tile (its ``e_down`` once fell to ``ragged_dot`` on 682 columns,
+    then went by in four tiles of 512: runs of 1 KB at a stride of 4 KB). The
+    contraction is cut only where it is past ``_WHOLE_K``. At every row tile a
+    window reaches, the call's buffers fit the fast memory it states."""
+    from ray_tpu.ops.grouped_matmul import VMEM_BUDGET, vmem_bytes
+
+    for rows, want in zip((32, 128, 192, moe.ROW_TILE), 3 * WEIGHT_TILES[kind][(k, n)][:1] + WEIGHT_TILES[kind][(k, n)][1:]):
+        tk, tn = moe._weight_tile(k, n, rows)
+        assert (tk, tn) == want
+        assert tk % 128 == 0 and tn % 128 == 0 and n % tn == 0 and k % tk == 0
+        assert tk * tn <= (moe._WEIGHT_TILE if rows == moe.ROW_TILE or kind == "lfm2" else 1 << 20)
+        assert (k <= moe._WHOLE_K) == (tk == k) and ((tk, tn) == (k, n)) == (kind == "lfm2")
+        assert 2 * tk * tn * 2 < vmem_bytes((rows, tk, tn), 2, 4) <= 32 << 20 < VMEM_BUDGET
 
 
 # kind: (choices a token, experts held, the router's outputs, {tokens: (window, row tile)})
@@ -286,7 +309,7 @@ def test_the_row_tile_at_every_window_the_benchmarks_kinds_reach(monkeypatch, ki
     assert moe.window_rows(tokens * top_k, held, n_outputs) == window
     stated = []
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    monkeypatch.setattr(moe, "_megablox_gmm", lambda x, w, g, *, tiling, preferred_element_type: stated.append(tiling) or x)
+    monkeypatch.setattr(moe, "_gmm", lambda x, w, g, *, tiling, preferred_element_type: stated.append(tiling) or x)
     moe.grouped_matmul(jnp.zeros((window, 128), jnp.bfloat16), jnp.zeros((held, 128, 128), jnp.bfloat16), jnp.zeros((held,), jnp.int32))
     assert stated == [(row_tile, 128, 128)] and window % row_tile == 0
 

@@ -238,28 +238,50 @@ def _grouped_matmuls_take(text, rows, width, all_rows):
     calls = [line for line in text.splitlines() if re.search(r"%gmm[.\d]* = ", line)]
     made = [re.search(r"= (\w+)\[(\d+),(\d+)\]", line).groups() for line in calls]
     assert len(calls) == 3 and all('custom_call_target="tpu_custom_call"' in line for line in calls)
+    _nothing_is_copied_for_the_grouped_matmuls(text)
     assert "ragged-dot" not in text
     assert sorted(int(n) for _, n, _ in made) == [rows] * 3 and ("f32", str(rows), str(width)) in made
     assert sum(f"bf16[{rows},{width}]" in line for line in calls) == 2
     assert str(all_rows) not in re.findall(rf"= \w+\[(\d+),{width}\]\S* gather\(", text)
 
 
+def _nothing_is_copied_for_the_grouped_matmuls(text):
+    """No operand of a ``gmm`` call is made by a copy (``copy``, ``copy-done``:
+    an expert stack, a window's rows or the group metadata staged or laid out
+    anew ahead of the kernel), and no instruction of its own makes an expert
+    stack's dims: the kernel reads the stack where it lies, whatever its weight
+    tile and the fast memory it states."""
+    import re
+
+    made_by = {name: op for name, op in re.findall(r"%([\w.\-]+) = [^=\n]*? ([\w\-]+)\(", text)}
+    calls = [line for line in text.splitlines() if re.search(r"%gmm[.\d]* = ", line)]
+    assert calls
+    stacks = set()
+    for line in calls:
+        operands = re.findall(r"%([\w.\-]+)", line.split("custom-call(", 1)[1].split(")", 1)[0])
+        assert operands and not [o for o in operands if made_by.get(o, "").startswith("copy")], line[:300]
+        stacks.add(re.search(r"bf16\[(\d+,\d+,\d+)\]", line.split("operand_layout_constraints", 1)[1]).group(1))
+    assert not [(dims, op) for dims, _, op in _alone(text)
+                if dims in stacks and op not in ("parameter", "bitcast", "get-tuple-element")]
+
+
 def _stated_tilings(monkeypatch):
-    """The tilings ``moe.grouped_matmul`` hands JAX's grouped kernel while a
-    program is traced, as a set that fills as the programs are lowered. A
+    """The tilings ``moe.grouped_matmul`` hands the grouped kernel while a
+    program is traced, as a set that fills as the programs are lowered: a
     decode step's windows and every window of Kimi-K2 are one row tile of the
-    operand's rows, the statement they always made, so those programs' lowered
-    texts are the parent's (PR 49 compared them, sha256 for sha256); a
-    window of 512 rows goes under two tiles of ``moe.ROW_TILE``."""
+    operand's rows, a window of 512 rows goes under two tiles of
+    ``moe.ROW_TILE``; under a row tile at the ridge, and where it fits one
+    whole, an expert's matrix goes by in the fewest tiles of at most 8 MB
+    (PR 51: in 2 MB tiles before, as under a smaller row tile still)."""
     from ray_tpu.models import moe
 
-    stated, gmm = set(), moe._megablox_gmm
+    stated, gmm = set(), moe._gmm
 
     def recorded(*args, tiling, **kwargs):
         stated.add(tiling)
         return gmm(*args, tiling=tiling, **kwargs)
 
-    monkeypatch.setattr(moe, "_megablox_gmm", recorded)
+    monkeypatch.setattr(moe, "_gmm", recorded)
     return stated
 
 
@@ -293,13 +315,13 @@ def test_latent_decode_and_prefill_at_longcat_widths(one_chip, monkeypatch):
         arg((batch,), jnp.bool_),
     ).compile().as_text()
     _grouped_matmuls_take(text, 32, 6144, batch * cfg.moe_topk)  # gate, up and down of the held experts
-    assert stated == {(32, 512, 2048), (32, 2048, 512)}  # one row tile, the window's rows: the text is the parent's
+    assert stated == {(32, 512, 2048), (32, 2048, 512)}  # one row tile, the window's rows; 2 MB tiles: the parent's statement
     _latent_kernel_reads_the_pool_in_place(text, f"[4,{blocks},{block},640]", batch, per_seq, block)
     stated.clear()
     text = prefill.lower(
         params, arg((1, 1024), jnp.int32), arg((1, per_seq), jnp.int32), pool, arg((), jnp.int32)).compile().as_text()
     _grouped_matmuls_take(text, 512, 6144, 1024 * cfg.moe_topk)
-    assert stated == {(256, 512, 2048), (256, 2048, 512)}  # the operand's 512 rows under two row tiles
+    assert stated == {(256, 2048, 2048)}  # the operand's 512 rows under two row tiles; 25 MB a matrix in three tiles
     assert "paged_latent_attention" not in text
     assert "gather(" not in "".join(line for line in text.splitlines() if f",{block},640]" in line)
 
@@ -373,8 +395,9 @@ def test_latent_decode_and_prefill_at_kimi_k2_widths(one_chip, monkeypatch):
         params, arg((1, 512), jnp.int32), arg((1, per_seq), jnp.int32), pool, arg((), jnp.int32)).compile()
     text = compiled.as_text()
     _grouped_matmuls_take(text, 256, 7168, 512 * cfg.num_experts_per_tok)
-    # every window of the kind is one row tile of its own rows: all three programs' texts are the parent's
-    assert stated == {(rows, *tile) for rows in (32, 256) for tile in ((512, 2048), (2048, 512))}
+    # every window of the kind is one row tile of its own rows: the decode programs state what the parent's did; at
+    # the ridge 29 MB a matrix goes by in four tiles of 7.3 MB
+    assert stated == {(32, 512, 2048), (32, 2048, 512), (256, 1792, 2048), (256, 2048, 1792)}
     assert "paged_latent_attention" not in text
     assert "gather(" not in "".join(line for line in text.splitlines() if f",{block},640]" in line)
     assert staged(text) <= {"wqb", "wkvb"}
@@ -620,8 +643,9 @@ def test_exaone_moe_decode_and_prefill_at_published_widths(one_chip, monkeypatch
     # a section's body holds its layers' kernels once: (W), (W W F), (W W W F); three grouped matmuls an expert layer
     assert (kernels.count("ring_window_attention"), kernels.count("paged_decode_attention"), kernels.count("gmm")) == (6, 2, 21)
     assert gmm_rows(text) == [128] * 21 and "ragged-dot" not in text and " conditional(" not in text
-    assert stated == {(128, 512, 2048), (128, 2048, 512)}  # one row tile, the window's rows: the text is the parent's
+    assert stated == {(128, 512, 2048), (128, 2048, 512)}  # one row tile, the window's rows; 2 MB tiles: the parent's statement
     stated.clear()
+    _nothing_is_copied_for_the_grouped_matmuls(text)
     assert not pools_copied(text)
     assert not ring_writes(text) and "ring_scatter" not in text  # the ring's kernel writes a step's row itself
     # and the paged kernel a full layer's, into the pools it scores (its outputs in place): no scatter over a pool is
@@ -634,7 +658,8 @@ def test_exaone_moe_decode_and_prefill_at_published_widths(one_chip, monkeypatch
     text, mem = compiled.as_text(), compiled.memory_analysis()
     kernels = _kernels(text)
     assert (kernels.count("flash_attention"), kernels.count("gmm")) == (2, 21) and gmm_rows(text) == [512] * 21
-    assert stated == {(256, 512, 2048), (256, 2048, 512)}  # the operand's 512 rows under two row tiles
+    assert stated == {(256, 2048, 2048)}  # the operand's 512 rows under two row tiles; three tiles of 8 MB a matrix
+    _nothing_is_copied_for_the_grouped_matmuls(text)
     assert "ragged-dot" not in text and not {"ring_window_attention", "paged_decode_attention"} & set(kernels)
     assert len(ring_writes(text)) == 12 and "ring_scatter" in text  # a prompt's whole ring, K and V, a window layer
     assert len(pool_writes(text)) == 4 and "paged_scatter" in text  # a prompt's blocks, K and V, a full layer: ``write_spans`` stays
@@ -735,9 +760,9 @@ def test_lfm2_moe_decode_and_prefill_at_published_widths(one_chip, monkeypatch):
     place. The decode step holds the paged kernel a full layer over the flat
     pool of **two K/V heads of 64 to a row** (it writes the step's packed row,
     so no scatter over a pool is left) and **all three** grouped matmuls of an
-    expert of 2048 x 1536 in the grouped kernel (``e_down``'s tile is 1536 x
-    512: whole lanes that divide 2,048), one window of the step's 192 rows,
-    one row tile; no ``ragged-dot``. A prefill of 1,024 holds every
+    expert of 2048 x 1536 in the grouped kernel (each matrix one weight tile,
+    6.3 MB), one window of the step's 192 rows, one row tile; no
+    ``ragged-dot``. A prefill of 1,024 holds every
     expert's rows, 4,096, and hands them to the grouped kernel in one call
     under sixteen row tiles; it holds the flash kernel a full layer at a head
     of 64."""
@@ -795,8 +820,9 @@ def test_lfm2_moe_decode_and_prefill_at_published_widths(one_chip, monkeypatch):
     # a section's body holds its layers' kernels once: (F C), (C C F C); three grouped matmuls an expert layer
     assert (kernels.count("paged_decode_attention"), kernels.count("gmm")) == (2, 18)
     assert gmm_rows(text) == [192] * 18 and "ragged-dot" not in text and " conditional(" not in text
-    assert stated == {(192, 2048, 512), (192, 1536, 512)}  # gate and up; down: all three in the kernel
+    assert stated == {(192, 2048, 1536), (192, 1536, 2048)}  # gate and up; down: all three in the kernel, a matrix a tile
     stated.clear()
+    _nothing_is_copied_for_the_grouped_matmuls(text)
     assert not pools_copied(text) and not pool_writes(text) and "paged_scatter" not in text and "paged_gather" not in text
     assert 8.5e9 < mem.argument_size_in_bytes < 8.7e9 and mem.temp_size_in_bytes < 0.2e9
     assert mem.alias_size_in_bytes > 0.999 * nbytes(pool)  # the pool comes back in place
@@ -805,7 +831,8 @@ def test_lfm2_moe_decode_and_prefill_at_published_widths(one_chip, monkeypatch):
     text, mem = compiled.as_text(), compiled.memory_analysis()
     kernels = _kernels(text)
     assert (kernels.count("flash_attention"), kernels.count("gmm")) == (2, 18) and gmm_rows(text) == [4096] * 18
-    assert stated == {(256, 2048, 512), (256, 1536, 512)}  # one call of every row: no window is walked
+    assert stated == {(256, 2048, 1536), (256, 1536, 2048)}  # one call of every row: no window is walked
+    _nothing_is_copied_for_the_grouped_matmuls(text)
     assert "ragged-dot" not in text and "paged_decode_attention" not in kernels
     assert len(pool_writes(text)) == 4 and "paged_scatter" in text  # a prompt's blocks, K and V, a full layer
     assert not pools_copied(text)
