@@ -95,10 +95,11 @@ class _Channel:
 class _OwnedRef:
     """Local ownership record for a direct-call return object."""
 
-    __slots__ = ("count", "committed", "escalated", "escalate_on_commit", "dead")
+    __slots__ = ("count", "committed", "escalated", "escalate_on_commit", "dead", "t_sent")
 
     def __init__(self):
         self.count = 0
+        self.t_sent = 0  # a stream item's: time_ns() just before its sender sent it (0: it carried none)
         self.committed = False
         self.escalated = False
         self.escalate_on_commit = False
@@ -249,6 +250,13 @@ class DirectActorClient:
             # purely ours regardless of which store holds it
             self.store.evict(oid)
             self.stored_dirs.pop(oid, None)
+
+    def item_sent_ns(self, oid: ObjectID) -> int:
+        """When a stream item's sender sent it (its ``time_ns()``, stamped with
+        telemetry on), kept with the item's ownership record for as long as
+        that lives; 0 where the item carried none or is no longer ours."""
+        rec = self._owned.get(oid)
+        return rec.t_sent if rec is not None else 0
 
     def ensure_published(self, oids) -> None:
         """Escalate caller-owned oids to head ownership before they escape
@@ -558,12 +566,13 @@ class DirectActorClient:
 
     # -- commits -----------------------------------------------------------
 
-    def _commit_locked(self, oid: ObjectID, entry: Tuple, src_dir: str):
+    def _commit_locked(self, oid: ObjectID, entry: Tuple, src_dir: str, t_sent: int = 0):
         rec = self._owned.get(oid)
         if rec is None:
             rec = _OwnedRef()
             self._owned[oid] = rec
         rec.committed = True
+        rec.t_sent = t_sent
         if entry[0] == "stored" and src_dir:
             self.stored_dirs[oid] = src_dir
         escalated_now = False
@@ -690,10 +699,10 @@ class DirectActorClient:
             if self._on_commit is not None and committed:
                 self._on_commit(committed)
         elif kind == "gen_item":
-            _, tid_bin, index, entry, src_dir = msg
+            _, tid_bin, index, entry, src_dir, t_sent = msg
             oid = ObjectID.for_return(TaskID(tid_bin), index)
             with self._lock:
-                self._commit_locked(oid, entry, src_dir)
+                self._commit_locked(oid, entry, src_dir, t_sent)
                 self._gen_tracked.setdefault(tid_bin, []).append(oid)
             if self._on_commit is not None:
                 self._on_commit([oid])

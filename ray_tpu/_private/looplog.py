@@ -2,8 +2,10 @@
 where it outlives the cluster.
 
 The serving engine keeps one record per iteration of its loop and one per
-finished request; the trainer's step plane closes one record per step. Two
-kinds say how a loop came to run: ``llm_start``, one an engine, holds the
+finished request; the trainer's step plane closes one record per step. Three
+kinds follow a token out of the replica (``llm_stream``, ``serve_stream``) and
+the controller's health probe (``serve_probe``); they are set out beside their
+fields below. Two kinds say how a loop came to run: ``llm_start``, one an engine, holds the
 stamps of its start from the constructor's first line to the loop's thread;
 ``compile``, one a ``jax.monitoring`` duration event of a program's tracing,
 lowering, backend compilation or load from the compile cache, names the
@@ -14,6 +16,7 @@ and for both loops' ``compile`` records, the step records' own two channels
 for the trainer), and the head appends them as they land to
 
     <session_dir>/loops/llm-<deployment>-<pid>.jsonl
+    <session_dir>/loops/serve-<deployment>-<pid>.jsonl
     <session_dir>/loops/train-<run>-rank<r>.jsonl
 
 next to ``<session_dir>/logs/``: one JSON object a line, each naming its
@@ -115,6 +118,49 @@ COMPILE_FIELDS = (
     "step",  # decode steps dispatched, or the trainer's steps done, when it landed
     "where",  # init: the constructor's thread before the loop runs | loop: the loop's thread | other
 )
+# A token's way out of the replica, in four segments, each stamped by the thread that does the work:
+#   held     the step's result on the host (``llm_step.t_result``; a first token's ``llm_request.t_first``) -> the
+#            ``put`` into its stream's queue: the loop dispatching the next step before it delivers, the other rows
+#   wake     the ``put`` -> ``get`` returns on the stream's own thread: GIL turns, the thread still sending the last token
+#   send     ``get`` returns -> the iterator is entered again after its ``yield``: the generators above it, the
+#            runtime's ``serialize_to_bytes`` and ``conn.send`` (or the head's ``generator_item``)
+#   transit  just before the runtime's streaming loop sends the item -> ``ray_tpu.get(ref)`` has returned in the
+#            caller's ``DeploymentResponseGenerator``: the connection, the caller's wake-up, the fetch
+# ``send`` and ``transit`` overlap by the ``conn.send`` call itself; nothing is subtracted. Each segment is folded
+# into count, sum and maximum (ns) where it ends; no list a token.
+_SEGMENT = ("_n", "_sum", "_max")
+# one ``TokenStream`` whose iterator ended (finished, failed, timed out or closed by its consumer), written by the
+# stream's own thread into the engine's file
+LLM_STREAM_FIELDS = (
+    "request",  # the engine's id: joins ``llm_request``
+    "trace_id",
+    "tokens",  # taken from the queue by the consumer
+    "t_first_taken",  # ``get`` returned with the first token (0: none)
+    "t_last_back",  # the iterator was last entered again after a ``yield`` (0: never)
+    *(seg + part for seg in ("held", "wake", "send") for part in _SEGMENT),
+)
+# one ``DeploymentResponseGenerator`` that ended, written by the caller's process (a worker's or the driver's own)
+# into ``serve-<deployment>-<pid>.jsonl``
+SERVE_STREAM_FIELDS = (
+    "task",  # the stream's task id (of its last attempt), hex
+    "deployment",
+    "method",
+    "replica",  # the actor id of the replica that served the last attempt, hex
+    "items",  # in the caller's hands
+    "t_first_got",
+    "t_last_got",  # ``ray_tpu.get`` returned with the first and the last item (0: none)
+    *("transit" + part for part in _SEGMENT),  # over the items that arrived with the sender's stamp
+    "gap_max",  # the longest time between two items in the caller's hands, ns
+    "attempts",  # re-dispatches after a replica's death
+)
+# one health probe of one replica, written by the controller (its pid) into ``serve-<deployment>-<pid>.jsonl``
+SERVE_PROBE_FIELDS = (
+    "t_sent",  # before ``check_health.remote()``
+    "t_answered",  # its result is in the controller's hands (0: the budget ran out or the call failed)
+    "budget_s",  # what the pass allowed its probes together
+    "deployment",
+    "replica",
+)
 COMPILE_STAGES = {
     "/jax/core/compile/jaxpr_trace_duration": "trace",
     "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
@@ -124,7 +170,9 @@ COMPILE_STAGES = {
 _KINDS = {"s": ("llm_step", LLM_STEP_RING_FIELDS), "r": ("llm_request", LLM_REQUEST_FIELDS),
           "m": ("llm_moe", LLM_MOE_FIELDS), "n": ("llm_kv_neighbours", LLM_NEIGHBOUR_FIELDS),
           "b": ("llm_start", LLM_START_FIELDS),
-          "c": ("compile", COMPILE_FIELDS)}
+          "c": ("compile", COMPILE_FIELDS),
+          "t": ("llm_stream", LLM_STREAM_FIELDS), "g": ("serve_stream", SERVE_STREAM_FIELDS),
+          "p": ("serve_probe", SERVE_PROBE_FIELDS)}
 
 MAX_FILE_BYTES = 32 << 20  # a file past this moves to <name>.1 (one kept)
 _MAX_OPEN = 64

@@ -309,6 +309,10 @@ class DriverRuntime:
         if self._direct is not None:
             self._direct.release_stream(task_id)
 
+    def stream_item_sent_ns(self, oid) -> int:
+        """``time_ns()`` of a direct stream item's send in its sender's process (0: none came with it)."""
+        return self._direct.item_sent_ns(oid) if self._direct is not None else 0
+
     # -- pubsub (parity: GCS pubsub subscriber surface) --------------------
 
     def pubsub_publish(self, channel: str, blob: bytes) -> None:

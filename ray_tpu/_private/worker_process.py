@@ -714,6 +714,10 @@ class WorkerRuntime:
         if self._direct is not None:
             self._direct.release_stream(task_id)
 
+    def stream_item_sent_ns(self, oid) -> int:
+        """``time_ns()`` of a direct stream item's send in its sender's process (0: none came with it)."""
+        return self._direct.item_sent_ns(oid) if self._direct is not None else 0
+
     # -- pubsub (parity: GCS pubsub subscriber surface) --------------------
 
     def pubsub_publish(self, channel: str, blob: bytes) -> None:
@@ -991,6 +995,9 @@ class WorkerRuntime:
                 t_stream0 = time.perf_counter()
                 yield_ms = 0.0
                 count = 0
+                # telemetry on, each item leaves with the time_ns() of its send (``looplog``'s
+                # ``transit`` begins there: serve's handle folds it into its ``serve_stream`` record)
+                stamp = bool(getattr(self.config, "telemetry_enabled", True))
                 for item in result:
                     t_item = time.perf_counter()
                     if count == 0 and stages is not None:
@@ -1026,6 +1033,7 @@ class WorkerRuntime:
                                         count + 1,
                                         entry,
                                         getattr(self, "shm_dir", ""),
+                                        time.time_ns() if stamp else 0,
                                     )
                                 )
                         except (OSError, EOFError, BrokenPipeError):

@@ -28,6 +28,7 @@ Resilience plane (this module is the control-plane half; ``handle.py`` /
 from __future__ import annotations
 
 import logging
+import os
 import time
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional
@@ -842,6 +843,14 @@ class ServeController:
                 time.sleep(min(0.5 * (2 ** min(failures, 6)), 30.0))
 
     def _reconcile_once(self):
+        """One pass. With telemetry on every health probe leaves a
+        ``serve_probe`` record (``looplog.SERVE_PROBE_FIELDS``) in
+        ``loops/serve-<deployment>-<pid>.jsonl``: ``time.time_ns()`` before
+        ``check_health.remote()`` and when its result is in hand, around the
+        calls as they are; a probe that failed or outran the budget reads 0."""
+        from ray_tpu._private import telemetry
+
+        tel = telemetry.get_buffer() if telemetry.enabled() else None
         now = time.monotonic()
         with self._lock:
             snapshot = list(self.apps.items())
@@ -855,19 +864,20 @@ class ServeController:
                 if now >= d.get("_next_probe", 0.0):
                     d["_next_probe"] = now + period
                     replicas = list(d["replicas"])
-                    refs = []
+                    refs, sent = [], []
                     for r in replicas:
+                        sent.append(time.time_ns() if tel is not None else 0)
                         try:
                             refs.append(r.check_health.remote())
                         except Exception:
                             refs.append(None)
-                    due.append((app_name, name, d, replicas, refs))
+                    due.append((app_name, name, d, replicas, refs, sent))
         if not due:
             return
         probe_deadline = time.monotonic() + self.PROBE_BUDGET_S
-        for app_name, name, d, replicas, refs in due:
+        for app_name, name, d, replicas, refs, sent in due:
             alive = []
-            for r, ref in zip(replicas, refs):
+            for r, ref, t_sent in zip(replicas, refs, sent):
                 ok = False
                 if ref is not None:
                     try:
@@ -878,6 +888,10 @@ class ServeController:
                         ok = True
                     except Exception:
                         ok = False
+                if tel is not None:
+                    tel.record_loop(f"serve-{name}-{os.getpid()}", (
+                        "p", t_sent, time.time_ns() if ok else 0, self.PROBE_BUDGET_S, name, r._actor_id.hex(),
+                    ))
                 if ok:
                     alive.append(r)
                 else:

@@ -24,6 +24,7 @@ with a half-open probe per ``shed_retry_after_s`` window when the trigger is
 
 from __future__ import annotations
 
+import os
 import random
 import threading
 import time
@@ -203,6 +204,40 @@ class DeploymentResponse:
         return self._ref
 
 
+class _StreamStamps:
+    """What a streamed response's caller saw of it, for its ``serve_stream``
+    record: each item stamped (``time.time_ns()``) when ``ray_tpu.get`` has
+    returned it and folded where it lands; no list an item."""
+
+    __slots__ = ("task", "replica", "attempts", "items", "t_first_got", "t_last_got",
+                 "transit_n", "transit_sum", "transit_max", "gap_max", "_sent_ns")
+
+    def __init__(self):
+        from ray_tpu._private.worker import get_runtime
+
+        self.task = self.replica = None
+        self.attempts = self.items = self.t_first_got = self.t_last_got = 0
+        self.transit_n = self.transit_sum = self.transit_max = self.gap_max = 0
+        self._sent_ns = get_runtime().stream_item_sent_ns
+
+    def attempt(self, gen, replica_id: str, attempts: int) -> None:
+        self.task, self.replica, self.attempts = getattr(gen, "_task_id", None), replica_id, attempts
+
+    def got(self, ref) -> None:
+        t_got = time.time_ns()
+        self.items += 1
+        if self.t_last_got:
+            self.gap_max = max(self.gap_max, t_got - self.t_last_got)
+        else:
+            self.t_first_got = t_got
+        self.t_last_got = t_got
+        t_sent = self._sent_ns(ref.id())
+        if t_sent:  # an item that came without its sender's stamp (through the head) counts in ``items`` alone
+            self.transit_n += 1
+            self.transit_sum += t_got - t_sent
+            self.transit_max = max(self.transit_max, t_got - t_sent)
+
+
 class DeploymentResponseGenerator:
     """Streaming response: iterate per-item results (parity:
     ``DeploymentResponseGenerator``).
@@ -232,9 +267,36 @@ class DeploymentResponseGenerator:
         self._trace_ctx = trace_ctx
 
     def __iter__(self):
+        """With telemetry on, every item is stamped (``time.time_ns()``) when
+        ``ray_tpu.get`` has returned it, and when the stream ends, however it
+        ends, one ``serve_stream`` record (``looplog.SERVE_STREAM_FIELDS``)
+        goes to ``loops/serve-<deployment>-<pid>.jsonl`` from this process:
+        the items, the longest wait between two of them, and their ``transit``
+        from the sender's stamp, which rides the item's message."""
         if self._handle is None:
             yield from self._iter_legacy()
             return
+        from ray_tpu._private import telemetry
+
+        if not telemetry.enabled():
+            yield from self._iter_attempts(None)
+            return
+        seen = _StreamStamps()
+        try:
+            yield from self._iter_attempts(seen)
+        finally:
+            handle = self._handle
+            buf = telemetry.get_buffer()
+            buf.record_loop(f"serve-{handle.deployment_name}-{os.getpid()}", (
+                "g", seen.task.hex() if seen.task is not None else None, handle.deployment_name, self._method,
+                seen.replica, seen.items, seen.t_first_got, seen.t_last_got, seen.transit_n, seen.transit_sum,
+                seen.transit_max, seen.gap_max, seen.attempts,
+            ))
+            buf.ensure_flusher()  # the caller may be a process that records nothing else
+
+    def _iter_attempts(self, seen: "Optional[_StreamStamps]"):
+        """The stream's items over its dispatch attempts; ``seen`` (telemetry
+        on) is told of each attempt and of each item in the caller's hands."""
         from ray_tpu.util import tracing
 
         handle = self._handle
@@ -245,6 +307,8 @@ class DeploymentResponseGenerator:
                 gen, rid, done = handle._dispatch(
                     self._method, self._args, self._kwargs, streaming=True
                 )
+            if seen is not None:
+                seen.attempt(gen, rid, attempts)
             got_any = False
             try:
                 try:
@@ -277,6 +341,8 @@ class DeploymentResponseGenerator:
                                 self._method,
                                 item_timeout,
                             ) from te
+                        if seen is not None:
+                            seen.got(ref)
                         got_any = True
                         yield item
                 finally:

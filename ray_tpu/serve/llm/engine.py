@@ -99,6 +99,7 @@ from typing import Any, Dict, List, Optional, Sequence
 from ray_tpu._private import memplane, sampler, telemetry
 from ray_tpu._private.looplog import (
     COMPILE_FIELDS, COMPILE_STAGES, LLM_MOE_FIELDS, LLM_REQUEST_FIELDS, LLM_START_FIELDS, LLM_STEP_FIELDS, LLM_STEP_RING_FIELDS,
+    LLM_STREAM_FIELDS,
 )
 from ray_tpu._private.profiling import annotate
 from ray_tpu.serve.exceptions import DeploymentOverloadedError
@@ -301,25 +302,37 @@ class TokenStream:
     """Per-request consumer handle: iterate tokens as the engine emits
     them. Terminates cleanly at end-of-sequence; engine-side failures
     re-raise here (typed, never a silent hang — a stalled engine trips
-    ``stream_timeout_s``)."""
+    ``stream_timeout_s``).
 
-    def __init__(self, request_id: int, timeout_s: float, submitted_ns: Optional[int] = None):
+    ``record``: where the engine has telemetry on, its ``_record``. The
+    stream then follows each token out of the loop's hands (``looplog``'s
+    segments ``held``, ``wake`` and ``send``): the loop's thread stamps the
+    ``put`` into the queue's item beside the token's ``t_result``, the
+    consumer's thread stamps ``get``'s return and its own return from the
+    ``yield``, folds the three differences into count, sum and maximum, and
+    writes one ``llm_stream`` record when its iterator ends, however it
+    ends. Without one no stamp is made and the items carry zeros."""
+
+    def __init__(self, request_id: int, timeout_s: float, submitted_ns: Optional[int] = None,
+                 record=None, trace_id: Optional[str] = None):
         self.request_id = request_id
         self._timeout_s = timeout_s
         self._q: "queue.Queue" = queue.Queue()
         self._submitted_ns = time.time_ns() if submitted_ns is None else submitted_ns
+        self._record = record
+        self._trace_id = trace_id
         self.ttft_s: Optional[float] = None
         self.finish_reason: Optional[str] = None
 
     # engine side -------------------------------------------------------
-    def _emit(self, token: int, t_ns: Optional[int] = None) -> None:
+    def _emit(self, token: int, t_ns: int = 0) -> None:
         """``t_ns``: when the token reached the host, where the engine has
-        stamped it (the first token: the request's ``llm.prefill`` span ends
-        on the same stamp)."""
+        stamped it (a step's ``t_result``; the first token's ``t_first``, on
+        which the request's ``llm.prefill`` span ends too)."""
         if self.ttft_s is None:
             # wall-clock stamps (the spans'): a clock set back must not go negative
             self.ttft_s = max(0, (t_ns or time.time_ns()) - self._submitted_ns) / 1e9
-        self._q.put(("tok", token))
+        self._q.put(("tok", token, t_ns, time.time_ns() if self._record is not None else 0))
 
     def _finish(self, reason: str) -> None:
         self._q.put(("done", reason))
@@ -328,22 +341,74 @@ class TokenStream:
         self._q.put(("err", error))
 
     # consumer side -----------------------------------------------------
+    def _next(self) -> tuple:
+        try:
+            return self._q.get(timeout=self._timeout_s)
+        except queue.Empty:
+            raise TimeoutError(
+                f"token stream {self.request_id} stalled for "
+                f"{self._timeout_s:g}s"
+            ) from None
+
     def __iter__(self):
+        if self._record is not None:
+            return self._iter_stamped()
+        return self._iter_plain()
+
+    def _iter_plain(self):
         while True:
-            try:
-                kind, payload = self._q.get(timeout=self._timeout_s)
-            except queue.Empty:
-                raise TimeoutError(
-                    f"token stream {self.request_id} stalled for "
-                    f"{self._timeout_s:g}s"
-                ) from None
-            if kind == "tok":
-                yield payload
-            elif kind == "done":
-                self.finish_reason = payload
+            item = self._next()
+            if item[0] == "tok":
+                yield item[1]
+            elif item[0] == "done":
+                self.finish_reason = item[1]
                 return
             else:
-                raise payload
+                raise item[1]
+
+    def _iter_stamped(self):
+        """``_iter_plain`` with the consumer's two stamps a token and the
+        stream's record at its end. A consumer that closes the iterator at a
+        ``yield`` ends that token's ``send`` there."""
+        now = time.time_ns
+        tokens = t_first_taken = t_back = out_since = 0
+        held_n = held_sum = held_max = wake_sum = wake_max = send_n = send_sum = send_max = 0
+        try:
+            while True:
+                item = self._next()
+                if item[0] == "tok":
+                    t_taken = now()
+                    _kind, tok, t_result, t_put = item
+                    tokens += 1
+                    if tokens == 1:
+                        t_first_taken = t_taken
+                    if t_result:  # every token of an engine has one; a bare ``_emit(tok)`` does not
+                        held_n += 1
+                        held_sum += t_put - t_result
+                        held_max = max(held_max, t_put - t_result)
+                    wake_sum += t_taken - t_put
+                    wake_max = max(wake_max, t_taken - t_put)
+                    out_since = t_taken
+                    yield tok
+                    t_back, out_since = now(), 0
+                    send_n += 1
+                    send_sum += t_back - t_taken
+                    send_max = max(send_max, t_back - t_taken)
+                elif item[0] == "done":
+                    self.finish_reason = item[1]
+                    return
+                else:
+                    raise item[1]
+        finally:
+            if out_since:  # closed at the ``yield``: the token was the consumer's until now
+                t_back = now()
+                send_n += 1
+                send_sum += t_back - out_since
+                send_max = max(send_max, t_back - out_since)
+            self._record((
+                "t", self.request_id, self._trace_id, tokens, t_first_taken, t_back,
+                held_n, held_sum, held_max, tokens, wake_sum, wake_max, send_n, send_sum, send_max,
+            ))
 
     def tokens(self) -> List[int]:
         """Drain the stream to completion and return every token."""
@@ -634,7 +699,11 @@ class InferenceEngine:
                     t_first=0,
                     bucket=0,
                 )
-                stream = TokenStream(req.id, self.cfg.stream_timeout_s, req.t_submit)
+                stream = TokenStream(
+                    req.id, self.cfg.stream_timeout_s, req.t_submit,
+                    record=None if self._tel is None else self._record,
+                    trace_id=ctx.trace_id if ctx is not None else None,
+                )
                 req.out = stream
                 self._committed_blocks += need
                 self._waiting.append((req, stream))
@@ -728,6 +797,11 @@ class InferenceEngine:
         started and how many of them had a live sequence in the slot before
         theirs: the paged kernels start such a sequence's first chunk during
         its predecessor's last (sum over count is how often; telemetry or not).
+        ``stream``: a token's way out of the loop's hands, over the ``llm_stream``
+        records the ring holds (one a stream whose iterator ended): how many
+        streams, and count, sum and maximum in ns of ``held`` (result on the
+        host -> ``put``), ``wake`` (``put`` -> the stream's thread has it) and
+        ``send`` (-> the thread is back for the next), summed here when read.
         ``prefill`` now ends when the first token is read, which is after the
         steps that were in flight before the prefill, and ``prefill_stall`` is
         the host's time to enqueue the iteration's prefills.
@@ -746,6 +820,7 @@ class InferenceEngine:
         steps = [r[1:] for r in ring if r[0] == "s"]
         reqs = [r[1:] for r in ring if r[0] == "r"]
         routed = [r[1:] for r in ring if r[0] == "m"]
+        streams = [dict(zip(LLM_STREAM_FIELDS, r[1:])) for r in ring if r[0] == "t"]
         spans: Dict[str, List[int]] = {
             k: [] for k in ("queue_wait", "prefill", "prefill_stall", "device_wait", "dispatch_gap", "emit")
         }
@@ -784,6 +859,12 @@ class InferenceEngine:
             "ahead": {"count": len(ahead), "sum": sum(ahead)},
             "overrun": {"count": len(overrun), "sum": sum(overrun)},
             "kv_neighbours": dict(zip(("count", "sum"), self._kv_neighbours)),
+            "stream": {
+                "streams": len(streams),
+                **{seg: {"count": sum(d[seg + "_n"] for d in streams), "sum_ns": sum(d[seg + "_sum"] for d in streams),
+                         "max_ns": max((d[seg + "_max"] for d in streams), default=0)}
+                   for seg in ("held", "wake", "send")},
+            },
             # the newest read of the expert layers' routing counts (cumulative)
             "moe": dict(zip(LLM_MOE_FIELDS, routed[-1])) if routed else None,
             # the stacked tensors re-laid on the device at start, name -> major_to_minor
@@ -793,9 +874,10 @@ class InferenceEngine:
         }
 
     def _record(self, rec: tuple) -> None:
-        """One loop or request record (telemetry on): into the ring, and on
-        its way to ``<session_dir>/loops/``. An append and a locked append,
-        from the engine thread after its dispatch or a shedding caller."""
+        """One loop, request or stream record (telemetry on): into the ring,
+        and on its way to ``<session_dir>/loops/``. An append and a locked
+        append, from the engine thread after its dispatch, a shedding caller,
+        or a stream's own thread when its iterator ends."""
         self._ring.append(rec)
         self._tel.record_loop(self._stem, rec)
 
@@ -937,8 +1019,8 @@ class InferenceEngine:
                     live, fused, kv_blocks, ring_rows = len(step.rows), int(step.fused), step.kv_blocks, step.ring_rows
             # ---- the device is busy (or there is nothing for it to do) ----
             with annotate("llm.emit"):
-                for stream, tok in emissions:
-                    stream._emit(tok)
+                for stream, tok, t_tok in emissions:
+                    stream._emit(tok, t_tok)
                 for req, reason, _when, _tokens, error in ended:
                     if error is None:
                         req.out._finish(reason)  # after its final token
@@ -1235,7 +1317,7 @@ class InferenceEngine:
                     overrun += 1  # it ended at an earlier read: the row is dropped
                     continue
                 tok = int(np_out[i]) if head.fused else self._sample(np_out[i], run.req, step=run.generated)
-                emissions.append((run.req.out, tok))
+                emissions.append((run.req.out, tok, t_result))
                 self._take(run, tok, ended)
             # the step's series value, on the monotonic clock: what a stream
             # waited for this token, from the last retire's end (or the top of

@@ -18,7 +18,15 @@ live sequences dispatched between that time's first and last
 ``llm_kv_neighbours`` record, the share whose preceding slot was live too, so
 whose first chunk the paged kernels started during the predecessor's last
 (None without two such records). Records older than a field read 0
-there. And ``start``, how the loop came to run (None of a program that leaves
+there. ``stream`` (None of a program that writes no such record) follows a
+token out of the replica over the same time: of the engine's streams that ended
+in it (``llm_stream``) the mean, median, 90th percentile and maximum of a
+stream's own mean of ``held`` (result on the host to the queue), ``wake``
+(queue to the stream's thread) and ``send`` (the thread until it is back); of
+the callers' (``serve_stream``) the same of ``transit`` (the sender's send to
+the caller's hands) and the longest gap between two items of one stream; and
+the controller's health probes sent in it (``serve_probe``): their round trips,
+and how many were never answered. And ``start``, how the loop came to run (None of a program that leaves
 no such record): the phases of the engine's ``llm_start`` record in seconds
 (``init_to_backend`` .. ``pool_to_ready``), what it placed and its pool's
 bytes; from the ``compile`` records (the engine's or the trainer's) the
@@ -75,6 +83,25 @@ def kv_neighbour_share(recs: list, t0: int, t1: int):
     return (recs[-1]["sum"] - recs[0]["sum"]) / count if count > 0 else None
 
 
+def stream_summary(recs: dict, t0: int, t1: int):
+    engine = [r for r in recs["llm_stream"] if t0 <= r["t_last_back"] <= t1]
+    callers = [r for r in recs["serve_stream"] if t0 <= r["t_last_got"] <= t1]
+    probes = [r for r in recs["serve_probe"] if t0 <= r["t_sent"] <= t1]
+    if not (engine or callers or probes):
+        return None
+
+    def stream_means(streams: list, segment: str) -> dict:
+        return spread_ms([r[segment + "_sum"] / r[segment + "_n"] for r in streams if r[segment + "_n"]])
+
+    return {
+        "streams": len(engine), **{seg + "_ms": stream_means(engine, seg) for seg in ("held", "wake", "send")},
+        "caller_streams": len(callers), "transit_ms": stream_means(callers, "transit"),
+        "gap_max_ms": max(r["gap_max"] for r in callers) / 1e6 if callers else None,
+        "probe_ms": {**spread_ms([r["t_answered"] - r["t_sent"] for r in probes if r["t_answered"]]),
+                     "missed": sum(1 for r in probes if not r["t_answered"])},
+    }
+
+
 START_STAMPS = ("t_init", "t_backend", "t_params", "t_placed", "t_pool", "t_ready")
 
 
@@ -126,6 +153,7 @@ def summarise(recs: dict, skip_s: float) -> dict:
         "queue_wait_ms": spread_ms([r["t_admit"] - r["t_submit"] for r in reqs]),
         "windows_per_layer_step": windows_per_layer_step(recs["llm_moe"], t0, t1),
         "kv_neighbour_share": kv_neighbour_share(recs["llm_kv_neighbours"], t0, t1),
+        "stream": stream_summary(recs, t0, t1),
     }
 
 

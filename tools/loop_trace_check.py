@@ -8,7 +8,9 @@ them in ``jax.profiler.TraceAnnotation`` (``llm.*`` / ``train.*``). This tool
 shows that the two are on one clock: each annotation of the trace's
 ``/host:CPU`` plane that carries a step number is held against the loop record
 of that step, and the largest distance is printed (exit code 2 past 1 ms).
-Reads with JAX alone.
+The stream records' stamps (``llm_stream``, ``serve_stream``) lie on the same
+clock: ``streams_in_trace`` counts the engine's streams whose first token was
+taken inside the traced window. Reads with JAX alone.
 
 The ``jax.named_scope`` names of the device programs are not in such a trace
 (PERF.md, Open questions): its device events are named by their HLO line
@@ -59,6 +61,14 @@ def profile_start_ns(pd) -> int:
     raise SystemExit("the trace does not say when it began (no profile_start_time)")
 
 
+def trace_window_ns(pd) -> tuple:
+    """The traced window in ``time.time_ns()``: the session's start, and the end of its last event."""
+    t_base = profile_start_ns(pd)
+    last = max((int(e.start_ns + e.duration_ns) for plane in pd.planes for line in plane.lines for e in line.events),
+               default=0)
+    return t_base, t_base + last
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--trace", required=True)
@@ -73,7 +83,7 @@ def main() -> int:
     steps = {r["step"]: r for r in records.get("llm_step", ()) if r.get("t_dispatch")}
     train = {r["step"]: r for r in records.get("train_step", ())}
     pd = ProfileData.from_file(path)
-    t_base = profile_start_ns(pd)
+    t_base, t_end = trace_window_ns(pd)
 
     offsets = collections.defaultdict(list)  # annotation -> |annotation edge - record stamp|, ns
     counts = collections.Counter()
@@ -103,6 +113,7 @@ def main() -> int:
         "trace": path,
         "profile_start_ns": t_base,
         "annotations": dict(counts),
+        "streams_in_trace": sum(1 for r in records.get("llm_stream", ()) if t_base <= r["t_first_taken"] <= t_end),
         "matched": {k: len(v) for k, v in offsets.items()},
         "max_offset_ms": {k: max(v) / 1e6 for k, v in offsets.items()},
         "mean_offset_ms": {k: sum(v) / len(v) / 1e6 for k, v in offsets.items()},
