@@ -43,8 +43,10 @@ layers' own tensors over those layers, from their first layer on.
 **The pool holds two kinds of cache** behind one block table, and the routing
 counts:
 
-* ``k``, ``v`` (full layers, slots x G, d): the **full** layers' rows a
-  position, *flat* (a slot's G heads are G consecutive rows: eight heads are no
+* ``kv`` (full layers, 2, slots x G, d): the **full** layers' rows a
+  position, the keys in plane 0 and the values in plane 1 of one array
+  (``paged_decode_attention`` brings a block's keys and values in under one
+  copy), each plane *flat* (a slot's G heads are G consecutive rows: eight heads are no
   whole sublane tile of bfloat16, and ``ops/paged_attention.py`` takes a flat
   pool of any head count whose block is whole tiles). Full layer ``i`` is the
   pool's layer ``i // 4``. A block holds the full layers' rows alone
@@ -225,10 +227,10 @@ def init_paged_pool(cfg: ExaoneMoeConfig, num_blocks: int, block_size: int, stat
     ``state_rows`` counts the null row: the engine asks for ``max_batch + 1``."""
     cfg.served()
     G, d = cfg.num_key_value_heads, cfg.head_dim
-    flat = (cfg.n_full, num_blocks * block_size * G, d)
+    flat = (cfg.n_full, 2, num_blocks * block_size * G, d)  # keys in plane 0, values in plane 1
     ring = (cfg.n_window, state_rows, cfg.sliding_window * G, d)
     return {
-        "k": jnp.zeros(flat, cfg.dtype), "v": jnp.zeros(flat, cfg.dtype),
+        "kv": jnp.zeros(flat, cfg.dtype),
         "ring_k": jnp.zeros(ring, cfg.dtype), "ring_v": jnp.zeros(ring, cfg.dtype),
         "moe_counts": jnp.zeros((len(moe.COUNTS),), jnp.uint32),
     }
@@ -347,8 +349,8 @@ def paged_layer(cfg: ExaoneMoeConfig, params, step):
         fi = li // PERIOD
         with jax.named_scope("proj"):
             q, k, v = _qkv(cfg, at(li), u, None)
-        kv = {"k": pool["k"], "v": pool["v"]}
-        kernel = decode and can_use_paged_kernel(q, kv["k"], bs, G)
+        kv = pool["kv"]
+        kernel = decode and can_use_paged_kernel(q, kv, bs, G)
         if not kernel:
             with jax.named_scope("paged_scatter"):
                 if decode or s % bs:
@@ -356,23 +358,24 @@ def paged_layer(cfg: ExaoneMoeConfig, params, step):
                 else:  # a block a window: a prompt's rows past its length lie behind the mask where they land
                     starts = (step.block_tables[:, :s // bs] * (bs * G)).reshape(-1)
                     spans = (k.reshape(-1, bs * G, d), v.reshape(-1, bs * G, d))
-                kv = {name: write_spans(kv[name], (fi,), starts, t) for name, t in zip(("k", "v"), spans)}
+                for plane, t in enumerate(spans):
+                    kv = write_spans(kv, (fi, plane), starts, t)
         with jax.named_scope("paged_attn"):
             if not decode:
                 o = causal_attention(q, k, v, causal=True)
             elif kernel:  # the kernel puts the row in its block and scores the blocks with it there
-                o, kv["k"], kv["v"] = paged_decode_attention(
-                    q[:, 0], kv["k"], kv["v"], fi, step.block_tables, step.lengths, block_size=bs, kv_heads=G,
+                o, kv = paged_decode_attention(
+                    q[:, 0], kv, fi, step.block_tables, step.lengths, block_size=bs, kv_heads=G,
                     new_k=k[:, 0], new_v=v[:, 0])
                 o = o[:, None]
             else:
                 with jax.named_scope("paged_gather"):
                     slots = (step.block_tables[:, :, None] * bs + jnp.arange(bs)).reshape(b, -1)
                     mine = slots[:, :, None] * G + jnp.arange(G)  # (B, M, G): where each position's heads lie
-                    kk, vv = (jax.lax.dynamic_index_in_dim(kv[name], fi, keepdims=False)[mine] for name in ("k", "v"))
+                    kk, vv = jax.lax.dynamic_index_in_dim(kv, fi, keepdims=False)[:, mine]
                 o = window_attention_rows(q[:, 0], kk, vv, jnp.arange(slots.shape[1])[None, :] < step.lengths[:, None],
                                           scale=scale)[:, None]
-        return o.astype(dtype), {**pool, **kv}
+        return o.astype(dtype), {**pool, "kv": kv}
 
     def attention(x, pool, li, full: bool):
         """The half every layer has: (h, N(h) as (T, D), the pool)."""

@@ -39,7 +39,9 @@ This module gives ``models/paged.py`` a kind's four things, one section of one
 layer a call. **The pool holds both caches of every layer** behind one block
 table:
 
-* ``k``, ``v`` (layers, slots x G, d): every layer's rows a position, *flat* (a
+* ``kv`` (layers, 2, slots x G, d): every layer's rows a position, the keys in
+  plane 0 and the values in plane 1 of one array (``paged_decode_attention``
+  brings a block's keys and values in under one copy), each plane *flat* (a
   slot's G heads are G consecutive rows: four heads are no whole sublane tile,
   and ``ops/paged_attention.py`` takes a flat pool of any head count whose block
   is whole tiles), the keys rotated and multiplied before they are written. On a
@@ -212,9 +214,9 @@ def init_paged_pool(cfg: FalconH1Config, num_blocks: int, block_size: int, state
     """Both caches of every layer (module docstring). ``state_rows`` counts the
     null row: the engine asks for ``max_batch + 1``."""
     L = cfg.num_hidden_layers
-    flat = (L, num_blocks * block_size * cfg.num_key_value_heads, cfg.head_dim)
+    flat = (L, 2, num_blocks * block_size * cfg.num_key_value_heads, cfg.head_dim)  # keys in plane 0, values in plane 1
     return {
-        "k": jnp.zeros(flat, cfg.dtype), "v": jnp.zeros(flat, cfg.dtype),
+        "kv": jnp.zeros(flat, cfg.dtype),
         "state": jnp.zeros((L, state_rows, cfg.mamba_d_state, cfg.mamba_d_ssm), jnp.float32),
         "conv": jnp.zeros((L, state_rows, cfg.mamba_d_conv * cfg.conv_dim), cfg.dtype),
         "state_pos": jnp.zeros((L, state_rows), jnp.int32),
@@ -324,8 +326,8 @@ def paged_layer(cfg: FalconH1Config, params, step):
         with jax.named_scope("rope"):
             q, k = (apply_rope(t.reshape(b * s, -1, d), *rope).reshape(b, s, -1, d).astype(dtype) for t in (q, k))
             v = v.reshape(b, s, G, d).astype(dtype)
-        kv = {"k": pool["k"], "v": pool["v"]}
-        kernel = decode and can_use_paged_kernel(q, kv["k"], bs, G)
+        kv = pool["kv"]
+        kernel = decode and can_use_paged_kernel(q, kv, bs, G)
         if not kernel:
             with jax.named_scope("paged_scatter"):
                 if decode or s % bs:
@@ -333,24 +335,25 @@ def paged_layer(cfg: FalconH1Config, params, step):
                 else:  # a block a window: a prompt's rows past its length lie behind the mask where they land
                     starts = (step.block_tables[:, :s // bs] * (bs * G)).reshape(-1)
                     spans = (k.reshape(-1, bs * G, d), v.reshape(-1, bs * G, d))
-                kv = {name: write_spans(kv[name], (li,), starts, t) for name, t in zip(("k", "v"), spans)}
+                for plane, t in enumerate(spans):
+                    kv = write_spans(kv, (li, plane), starts, t)
         with jax.named_scope("paged_attn"):
             if not decode:
                 o = causal_attention(q, k, v, causal=True)
             elif kernel:  # the kernel puts the row in its block and scores the blocks with it there
-                o, kv["k"], kv["v"] = paged_decode_attention(
-                    q[:, 0], kv["k"], kv["v"], li, step.block_tables, step.lengths, block_size=bs, kv_heads=G,
+                o, kv = paged_decode_attention(
+                    q[:, 0], kv, li, step.block_tables, step.lengths, block_size=bs, kv_heads=G,
                     new_k=k[:, 0], new_v=v[:, 0])
                 o = o[:, None]
             else:
                 with jax.named_scope("paged_gather"):
                     slots = (step.block_tables[:, :, None] * bs + jnp.arange(bs)).reshape(b, -1)
                     mine = slots[:, :, None] * G + jnp.arange(G)  # (B, M, G): where each position's heads lie
-                    kk, vv = (jax.lax.dynamic_index_in_dim(kv[name], li, keepdims=False)[mine] for name in ("k", "v"))
+                    kk, vv = jax.lax.dynamic_index_in_dim(kv, li, keepdims=False)[:, mine]
                 o = window_attention_rows(q[:, 0], kk, vv, jnp.arange(slots.shape[1])[None, :] < step.lengths[:, None],
                                           scale=d ** -0.5)[:, None]
         with jax.named_scope("out"):
-            return o.astype(dtype).reshape(b, s, H * d) @ w("wo"), {**pool, **kv}
+            return o.astype(dtype).reshape(b, s, H * d) @ w("wo"), {**pool, "kv": kv}
 
     @jax.named_scope("block")
     def layer(x, pool, li):

@@ -90,9 +90,10 @@ def make_decode_fns(cfg: TransformerConfig, max_len: int):
 
 
 def init_paged_pool(cfg: TransformerConfig, num_blocks: int, block_size: int) -> Dict:
-    """Preallocated device pool for the paged KV cache (block 0 reserved)."""
-    shape = (cfg.n_layers, num_blocks * block_size, cfg.kv_heads, cfg.head_dim)
-    return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
+    """Preallocated device pool for the paged KV cache (block 0 reserved):
+    one array, a layer's keys in plane 0 and its values in plane 1, so that
+    the decode kernel brings a block's keys and values in under one copy."""
+    return {"kv": jnp.zeros((cfg.n_layers, 2, num_blocks * block_size, cfg.kv_heads, cfg.head_dim), cfg.dtype)}
 
 
 def paged_block_bytes(cfg: TransformerConfig, block_size: int) -> int:
@@ -123,21 +124,21 @@ def attend_pool(q, k, v, pool, *, li, step: paged.Step, rows):
     (every prefill, the CPU backend) gathers the table's ``rows`` (B,
     max_blocks x block_size; row index == absolute position) and attends to
     them as a masked dense block."""
-    pk, pv = pool["k"], pool["v"]
+    kv = pool["kv"]
     with jax.named_scope("paged_scatter"):
-        pk = pk.at[li, step.write_slots].set(k.reshape(-1, *k.shape[2:]).astype(pk.dtype))
-        pv = pv.at[li, step.write_slots].set(v.reshape(-1, *v.shape[2:]).astype(pv.dtype))
-    if can_use_paged_kernel(q, pk, step.block_size):
+        for plane, t in enumerate((k, v)):
+            kv = kv.at[li, plane, step.write_slots].set(t.reshape(-1, *t.shape[2:]).astype(kv.dtype))
+    if can_use_paged_kernel(q, kv, step.block_size):
         with jax.named_scope("paged_attn"):
-            att = paged_decode_attention(q[:, 0], pk, pv, li, step.block_tables, step.lengths,
+            att = paged_decode_attention(q[:, 0], kv, li, step.block_tables, step.lengths,
                                          block_size=step.block_size)[:, None]
     else:
         with jax.named_scope("paged_gather"):
-            gk, gv = pk[li][rows], pv[li][rows]
+            gk, gv = kv[li, 0][rows], kv[li, 1][rows]
         with jax.named_scope("paged_attn"):
             mask = jnp.arange(rows.shape[1]) <= step.positions[:, None, :, None]  # (B, 1, S, M)
             att = attention(q, gk, gv, causal=False, mask=mask)
-    return att, {"k": pk, "v": pv}
+    return att, {"kv": kv}
 
 
 def paged_layer(cfg: TransformerConfig, params, step: paged.Step):

@@ -37,8 +37,10 @@ the expert layers' over theirs, each read by the layer's index among its kind.
 **The pool holds two kinds of cache** behind one block table, and the routing
 counts:
 
-* ``k``, ``v`` (full layers, slots x G / P, P x d): the full layers' rows a
-  position, *flat* and **P K/V heads to a row** (``kv_pack``: two heads of 64
+* ``kv`` (full layers, 2, slots x G / P, P x d): the full layers' rows a
+  position, the keys in plane 0 and the values in plane 1 of one array
+  (``paged_decode_attention`` brings a block's keys and values in under one
+  copy), each plane *flat* and **P K/V heads to a row** (``kv_pack``: two heads of 64
   fill the 128 lanes ``ops/paged_attention.py`` scores; a position is G / P
   consecutive rows). Query head ``h`` is handed to the kernel as a row of P x d
   values that is zero outside the part of its own K/V head, so ``q . row`` is
@@ -202,9 +204,10 @@ def init_paged_pool(cfg: Lfm2MoeConfig, num_blocks: int, block_size: int, state_
     """The two kinds of cache and the routing counts (module docstring).
     ``state_rows`` counts the null row: the engine asks for ``max_batch + 1``."""
     P = cfg.kv_pack
-    flat = (cfg.n_full, num_blocks * block_size * cfg.num_key_value_heads // P, P * cfg.head_dim)
+    # keys in plane 0, values in plane 1
+    flat = (cfg.n_full, 2, num_blocks * block_size * cfg.num_key_value_heads // P, P * cfg.head_dim)
     return {
-        "k": jnp.zeros(flat, cfg.dtype), "v": jnp.zeros(flat, cfg.dtype),
+        "kv": jnp.zeros(flat, cfg.dtype),
         "conv": jnp.zeros((cfg.n_conv, state_rows, cfg.conv_L_cache * cfg.hidden_size), cfg.dtype),
         "state_pos": jnp.zeros((cfg.n_conv, state_rows), jnp.int32),
         "moe_counts": jnp.zeros((len(moe.COUNTS),), jnp.uint32),
@@ -342,9 +345,9 @@ def paged_layer(cfg: Lfm2MoeConfig, params, step):
         w = at(fi)
         with jax.named_scope("proj"):
             q, k, v = _qkv(cfg, w, u, rope)
-        kv = {"k": pool["k"], "v": pool["v"]}
+        kv = pool["kv"]
         packed = pack_queries(cfg, q[:, 0]) if decode else None
-        kernel = decode and can_use_paged_kernel(packed[:, None], kv["k"], bs, Gp)
+        kernel = decode and can_use_paged_kernel(packed[:, None], kv, bs, Gp)
         if not kernel:
             with jax.named_scope("paged_scatter"):
                 if decode or s % bs:
@@ -352,26 +355,26 @@ def paged_layer(cfg: Lfm2MoeConfig, params, step):
                 else:  # a block a window: a prompt's rows past its length lie behind the mask where they land
                     starts = (step.block_tables[:, :s // bs] * (bs * Gp)).reshape(-1)
                     spans = (k.reshape(-1, bs * Gp, wide), v.reshape(-1, bs * Gp, wide))
-                kv = {name: write_spans(kv[name], (fi,), starts, t) for name, t in zip(("k", "v"), spans)}
+                for plane, t in enumerate(spans):
+                    kv = write_spans(kv, (fi, plane), starts, t)
         with jax.named_scope("paged_attn"):
             if not decode:
                 o = causal_attention(q, k, v, causal=True)
             elif kernel:  # the kernel puts the packed row in its block and scores the blocks with it there
-                o, kv["k"], kv["v"] = paged_decode_attention(
-                    packed, kv["k"], kv["v"], fi, step.block_tables, step.lengths, block_size=bs, kv_heads=Gp,
+                o, kv = paged_decode_attention(
+                    packed, kv, fi, step.block_tables, step.lengths, block_size=bs, kv_heads=Gp,
                     scale=scale, new_k=k[:, 0].reshape(b, Gp, wide), new_v=v[:, 0].reshape(b, Gp, wide))
                 o = unpack_outputs(cfg, o)[:, None]
             else:
                 with jax.named_scope("paged_gather"):
                     slots = (step.block_tables[:, :, None] * bs + jnp.arange(bs)).reshape(b, -1)
                     mine = slots[:, :, None] * Gp + jnp.arange(Gp)  # (B, M, G / P): where each position's rows lie
-                    kk, vv = (jax.lax.dynamic_index_in_dim(kv[name], fi, keepdims=False)[mine].reshape(b, -1, G, d)
-                              for name in ("k", "v"))
+                    kk, vv = jax.lax.dynamic_index_in_dim(kv, fi, keepdims=False)[:, mine].reshape(2, b, -1, G, d)
                 o = window_attention_rows(q[:, 0], kk, vv, jnp.arange(slots.shape[1])[None, :] < step.lengths[:, None],
                                           scale=scale)[:, None]
         with jax.named_scope("out"):
             out = o.astype(dtype).reshape(b, s, H * d) @ w("wo")
-        return out, {**pool, **kv}
+        return out, {**pool, "kv": kv}
 
     def operator(x, pool, li, full: bool):
         """The half every layer has: (h, N(h) as (T, D), the pool)."""

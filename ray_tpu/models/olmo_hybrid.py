@@ -27,9 +27,11 @@ over the full ones, the norms and the MLP over all).
 
 **The pool holds two kinds of cache** behind one block table:
 
-* ``k``, ``v`` (full layers, slots, stored heads, head_dim): GPT-J's pool, and
-  its ``attend`` (``generation.attend_pool``: the paged kernel for a decode
-  step, the gathered rows elsewhere). 30 heads are stored 32 wide (whole
+* ``kv`` (full layers, 2, slots, stored heads, head_dim): GPT-J's pool, keys
+  in plane 0 and values in plane 1, and its ``attend``
+  (``generation.attend_pool``: the paged kernel for a decode step, which
+  brings a block's keys and values in under one copy; the gathered rows
+  elsewhere). 30 heads are stored 32 wide (whole
   sublane tiles, ``can_use_paged_kernel``); q is padded alike and the spare
   heads' outputs dropped.
 * ``state`` (linear layers, rows, d_k, H x d_v) float32, ``conv`` (linear
@@ -185,10 +187,10 @@ def init_params(key, cfg: OlmoHybridConfig) -> Dict[str, Any]:
 def init_paged_pool(cfg: OlmoHybridConfig, num_blocks: int, block_size: int, state_rows: int) -> Dict:
     """The two kinds of cache (module docstring). ``state_rows`` counts the
     null row: the engine asks for ``max_batch + 1``."""
-    kv = (cfg.n_full, num_blocks * block_size, cfg.kv_heads_stored, cfg.head_dim)
+    kv = (cfg.n_full, 2, num_blocks * block_size, cfg.kv_heads_stored, cfg.head_dim)  # keys in plane 0, values in plane 1
     wide = cfg.linear_num_value_heads * cfg.linear_value_head_dim
     return {
-        "k": jnp.zeros(kv, cfg.dtype), "v": jnp.zeros(kv, cfg.dtype),
+        "kv": jnp.zeros(kv, cfg.dtype),
         "state": jnp.zeros((cfg.n_linear, state_rows, cfg.linear_key_head_dim, wide), jnp.float32),
         "conv": jnp.zeros((cfg.n_linear, state_rows, cfg.linear_conv_kernel_dim * cfg.conv_channels), cfg.dtype),
         "state_pos": jnp.zeros((cfg.n_linear, state_rows), jnp.int32),
@@ -295,15 +297,16 @@ def paged_layer(cfg: OlmoHybridConfig, params, step):
             q = rms_norm(x @ w("wq"), w("q_norm"), eps).reshape(b, s, heads, hd)
             k = rms_norm(x @ w("wk"), w("k_norm"), eps).reshape(b, s, heads, hd)
             v = (x @ w("wv")).reshape(b, s, heads, hd)
-        kv = {"k": pool["k"], "v": pool["v"]}
         if decode:
-            att, kv = attend_pool(stored_heads(q), stored_heads(k), stored_heads(v), kv, li=fi, step=step,
+            att, kv = attend_pool(stored_heads(q), stored_heads(k), stored_heads(v), pool, li=fi, step=step,
                                   rows=table_rows)
             att = att[:, :, :heads]
         else:
+            kv = pool["kv"]
             with jax.named_scope("paged_scatter"):
-                kv = {name: kv[name].at[fi, step.write_slots].set(stored_heads(t).reshape(b * s, stored, hd))
-                      for name, t in (("k", k), ("v", v))}
+                for plane, t in enumerate((k, v)):
+                    kv = kv.at[fi, plane, step.write_slots].set(stored_heads(t).reshape(b * s, stored, hd))
+            kv = {"kv": kv}
             with jax.named_scope("paged_attn"):
                 att = attention(q, k, v, causal=True)
         with jax.named_scope("attn"):

@@ -28,10 +28,12 @@ entry of the last section).
 
 **The pool holds three kinds of cache** behind one block table:
 
-* ``k``, ``v`` (1, slots x K/V pairs, 128): **one** layer's rows a position,
-  written by the full layer and read by it and every cross layer (eight
+* ``kv`` (1, 2, slots x K/V pairs, 128): **one** layer's rows a position, the
+  keys in plane 0 and the values in plane 1 of one array
+  (``paged_decode_attention`` brings a block's keys and values in under one
+  copy), written by the full layer and read by it and every cross layer (eight
   attentions a decode step over one cache). A K/V pair ``[k1; k2]`` is stored as
-  one head of 128; the pool is *flat* (a slot's ten pairs are ten consecutive
+  one head of 128; a plane is *flat* (a slot's ten pairs are ten consecutive
   rows) because ten heads are no whole sublane tile and a (slots, 10, 128) array
   is padded to 16 on the device. ``paged_decode_attention`` reads it with the
   queries ``[q1; 0]`` and ``[0; q2]``; the subtraction is here. On a TPU the
@@ -176,10 +178,10 @@ def init_paged_pool(cfg: Phi4FlashConfig, num_blocks: int, block_size: int, stat
     """The three kinds of cache (module docstring). ``state_rows`` counts the
     null row: the engine asks for ``max_batch + 1``."""
     wide, pairs = cfg.pair_dim, cfg.kv_pairs
-    shared = (1, num_blocks * block_size * pairs, wide)
+    shared = (1, 2, num_blocks * block_size * pairs, wide)  # keys in plane 0, values in plane 1
     ring = (cfg.n_window, state_rows, cfg.sliding_window * pairs, wide)
     return {
-        "k": jnp.zeros(shared, cfg.dtype), "v": jnp.zeros(shared, cfg.dtype),
+        "kv": jnp.zeros(shared, cfg.dtype),
         "ring_k": jnp.zeros(ring, cfg.dtype), "ring_v": jnp.zeros(ring, cfg.dtype),
         "state": jnp.zeros((cfg.n_ssm, state_rows, cfg.ssm_state_size, cfg.d_inner), jnp.float32),
         "conv": jnp.zeros((cfg.n_ssm, state_rows, cfg.ssm_conv_kernel * cfg.d_inner), cfg.dtype),
@@ -317,20 +319,20 @@ def paged_layer(cfg: Phi4FlashConfig, params, step):
         pool). ``new_k``, ``new_v`` (B, G, 128): the position's own row, which
         the kernel writes into the cache before it scores it; where no kernel
         runs the caller has scattered it."""
-        k, v = pool["k"], pool["v"]
-        if can_use_paged_kernel(qp[:, None], k, bs, G):
+        kv = pool["kv"]
+        if can_use_paged_kernel(qp[:, None], kv, bs, G):
             packed = jnp.stack(split_queries(qp), axis=2).reshape(b, 2 * pairs, wide)
-            o = paged_decode_attention(packed, k, v, 0, step.block_tables, step.lengths, block_size=bs, kv_heads=G,
+            o = paged_decode_attention(packed, kv, 0, step.block_tables, step.lengths, block_size=bs, kv_heads=G,
                                        scale=scale, **new)
             if new:
-                o, k, v = o
-                pool = {**pool, "k": k, "v": v}
+                o, kv = o
+                pool = {**pool, "kv": kv}
             o = o.reshape(b, pairs, 2, wide).astype(jnp.float32)
             return o[:, :, 0] - lam * o[:, :, 1], pool
         with jax.named_scope("paged_gather"):
             slots = (step.block_tables[:, :, None] * bs + jnp.arange(bs)).reshape(b, -1)
             mine = (slots[:, :, None] * G + jnp.arange(G))  # (B, M, G): where each position's pairs lie
-            k, v = k[0][mine], v[0][mine]
+            k, v = kv[0][:, mine]
         live = jnp.arange(slots.shape[1])[None, :] < step.lengths[:, None]
         return diff_attention_rows(qp, k, v, live, lam, scale=scale), pool
 
@@ -371,14 +373,17 @@ def paged_layer(cfg: Phi4FlashConfig, params, step):
                             for name, t in (("ring_k", k), ("ring_v", v))}
             pool = {**pool, **ring}
         else:
-            if not (decode and can_use_paged_kernel(qp, pool["k"], bs, G)):  # else the kernel puts the row in the cache
+            if not (decode and can_use_paged_kernel(qp, pool["kv"], bs, G)):  # else the kernel puts the row in the cache
                 with jax.named_scope("paged_scatter"):
                     if decode or s % bs:
                         starts, spans = step.write_slots * G, (k.reshape(b * s, G, wide), v.reshape(b * s, G, wide))
                     else:  # a block a window: a prompt's rows past its length lie behind the mask where they land
                         starts = (step.block_tables[:, :s // bs] * (bs * G)).reshape(-1)
                         spans = (k.reshape(-1, bs * G, wide), v.reshape(-1, bs * G, wide))
-                    pool = {**pool, **{name: _write_spans(pool[name], (0,), starts, t) for name, t in zip(("k", "v"), spans)}}
+                    kv = pool["kv"]
+                    for plane, t in enumerate(spans):
+                        kv = _write_spans(kv, (0, plane), starts, t)
+                    pool = {**pool, "kv": kv}
             with jax.named_scope("paged_attn"):
                 if decode:
                     o, pool = over_shared_cache(qp[:, 0], pool, lam, new_k=k[:, 0], new_v=v[:, 0])
@@ -402,7 +407,7 @@ def paged_layer(cfg: Phi4FlashConfig, params, step):
                 o = over_shared_cache(qp[:, 0], pool, lam)[0][:, None]
             else:  # every position of a prompt (a prefill cuts to its last: only a test asks for this)
                 mine = step.write_slots[:, None] * G + jnp.arange(G)
-                k, v = (pool[name][0][mine].reshape(b, s, G, wide) for name in ("k", "v"))
+                k, v = pool["kv"][0][:, mine].reshape(2, b, s, G, wide)
                 o = diff_attention_prefill(qp, k, v, lam, scale=scale)
         with jax.named_scope("out"):
             return out_of(o, ai, lam0)

@@ -1,17 +1,26 @@
 """Decode-time attention over the paged pools: two Pallas TPU kernels that
 share one walk (a sequence's live blocks in table order, in chunks of a fixed
-size, under an online softmax): ``paged_decode_attention`` over a pool of keys
-and one of values (GPT-J, Llama), described here, and
+size, under an online softmax): ``paged_decode_attention`` over the pool of keys
+and values (GPT-J, Llama and the kinds with a flat pool), described here, and
 ``paged_latent_attention`` over the latent cache's one pool of rows (LongCat,
 Kimi-K2), at the end of the file.
 
-One query position a sequence. The pool (L, slots, kv_heads, head_dim)
-stays in HBM; for each sequence the kernel copies its *live* blocks, and
-only those, into VMEM where they lie (a block is ``block_size`` consecutive
-slots: one contiguous piece of a layer), several blocks a chunk and
-double-buffered, and keeps a running maximum, sum and output in float32
-(online softmax). It never reads ``pool[layer]``
-as a value, so no layer slab and no gathered copy is made.
+One query position a sequence. The pool (L, 2, slots, kv_heads, head_dim), a
+layer's keys in plane 0 and its values in plane 1, stays in HBM; for each
+sequence the kernel copies its *live* blocks, and only those, into VMEM where
+they lie (a block is ``block_size`` consecutive slots: one contiguous piece of
+each plane of a layer), several blocks a chunk and double-buffered, and keeps
+a running maximum, sum and output in float32 (online softmax). It never reads
+``pool[layer]`` as a value, so no layer slab and no gathered copy is made.
+
+**A block's keys and values come in under one copy**, a descriptor of two runs
+into the two planes of one buffer: the scalar core reads the table once a block
+and issues one descriptor, and what a descriptor costs to issue is paid half as
+often a byte. With blocks of 16 KB a plane (four heads of 128, bfloat16), where
+two copies a block took twice their bytes' time, the kernel alone went from 51%
+to 67% of it on the chip, and from 75% to 80% at 32 KB; from 40 KB a plane up
+nothing moved. A block's keys then its values contiguous (one run of 32 KB)
+read the same to a point: what is paid for is the descriptor, not its runs.
 
 Heads stay interleaved as the pool stores them: a chunk is read as
 (rows * kv_heads, head_dim) and every head is scored against every column
@@ -34,18 +43,18 @@ starts its own first chunk. Which buffer a chunk lands in reaches no output.
 
 How a chunk's live blocks are gone through is a kernel's own, as its scoring
 is (``chunk_copies``), and the chip chose for each. ``_kernel`` goes in a
-loop: the traced kernel then holds one copy a pool at each of the three sites
-that start and the one that waits, whatever the table's width, and what a
+loop: the traced kernel then holds one copy at each of the three sites that
+start and the one that waits, whatever the table's width, and what a
 replica's start pays to trace and lower a kernel is the count of its binds.
 ``_latent_kernel`` goes unrolled over the chunk's places: its copies are one
 block of 20 KB each, the scalar core's issue of them is on every chunk's path,
 and on the chip a call took 18-23% longer with the loop.
 
-Handed the decode step's own K and V row (``new_k``, ``new_v``; flat pools),
-``paged_decode_attention`` writes it too: into the last chunk's buffer before
-that chunk is scored, and from there back to the pool, which is then the
-call's output in place of its input (``_kernel``). The models' layers then
-scatter nothing in a decode step.
+Handed the decode step's own K and V row (``new_k``, ``new_v``; a flat pool),
+``paged_decode_attention`` writes it too: into the two planes of the last
+chunk's buffer before that chunk is scored, and from there back to the pool
+under one copy, which is then the call's output in place of its input
+(``_kernel``). The models' layers then scatter nothing in a decode step.
 """
 
 from __future__ import annotations
@@ -60,25 +69,25 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
-_CHUNK_BYTES = 1 << 20  # of K (and of V) in one VMEM buffer; two buffers each
+_CHUNK_BYTES = 1 << 20  # of K (and of V) in one VMEM buffer's plane; two buffers
 
 
-def can_use_paged_kernel(q, pool_k, block_size: int, kv_heads: int = 0) -> bool:
+def can_use_paged_kernel(q, pool, block_size: int, kv_heads: int = 0) -> bool:
     """Platform and static shape alone, as ``ops.attention._can_use_flash``:
     a TPU, one query position, a head_dim of whole lanes, and kv heads that
     fill whole sublane tiles of the pool's type (so that a chunk
     flattens to (rows * kv_heads, head_dim) without a relayout). A **flat**
-    pool (layers, slots x ``kv_heads``, head_dim) lies flattened already, so
+    pool (layers, 2, slots x ``kv_heads``, head_dim) lies flattened already, so
     any head count will do whose block is whole sublane tiles (10 heads of a
     block of 16: ``paged_decode_attention``)."""
     if jax.default_backend() != "tpu":
         return False
     _, s, heads, head_dim = q.shape
-    sublanes = tile_rows(pool_k.dtype)
-    if pool_k.ndim == 3:
+    sublanes = tile_rows(pool.dtype)
+    if pool.ndim == 4:
         tiles = (block_size * kv_heads) % sublanes == 0
     else:
-        kv_heads = pool_k.shape[2]
+        kv_heads = pool.shape[3]
         tiles = kv_heads % sublanes == 0 and (block_size * kv_heads) % 128 == 0
     return s == 1 and head_dim % 128 == 0 and heads % kv_heads == 0 and tiles
 
@@ -207,20 +216,23 @@ def _kernel(
     *refs,
     block_size, chunk_blocks, kv_heads, scale,
 ):
-    """``refs``: ``pk_ref, pv_ref`` (the pools in HBM), ``o_ref``, ``kbuf,
-    vbuf`` ((2, rows, KV, Hd) each, (2, rows x KV, Hd) over flat pools), ``sem``
-    (DMA semaphores (2, 2)). Where the call **writes the step's own row**
-    (flat pools): ``nk_ref, nv_ref`` ((B, KV, Hd): every sequence's new row)
-    ahead of the pools, ``pk_out, pv_out`` (the pools again, the same buffers
-    as the inputs) behind ``o_ref`` and ``back_sem`` (a DMA semaphore a pool)
-    last. The row of position ``length - 1`` lies in the sequence's last live
-    block, so in its last chunk's buffer: once that chunk has come in, the
-    whole sublane tiles that cover the row (``span`` rows, inside the block: a
-    block is whole tiles and came in whole) are read, patched row by row and
-    stored, those tiles start on their way back to the pool, the chunk is
-    scored as it now lies, and the copies are waited for before the grid step
-    ends: the next sequence's second chunk lands in that buffer. An inactive
-    slot patches and writes nothing: the null block is no sequence's.
+    """``refs``: ``pool_ref`` (the pool in HBM: keys in plane 0, values in
+    plane 1), ``o_ref``, ``buf`` ((2, 2, rows, KV, Hd): a buffer's two planes;
+    (2, 2, rows x KV, Hd) over a flat pool), ``sem`` (DMA semaphores (2,), one
+    a buffer). Where the call **writes the step's own row** (a flat pool):
+    ``nk_ref, nv_ref`` ((B, KV, Hd): every sequence's new row) ahead of the
+    pool, ``pool_out`` (the pool again, the same buffer as the input) behind
+    ``o_ref`` and ``back_sem`` (one DMA semaphore) last. A block's keys and
+    values come in under one copy, plane by plane into the buffer's planes, and
+    the row goes back under one. The row of position ``length - 1`` lies in the
+    sequence's last live block, so in its last chunk's buffer: once that chunk
+    has come in, the whole sublane tiles that cover the row (``span`` rows,
+    inside the block: a block is whole tiles and came in whole) are read,
+    patched row by row and stored in both planes, those tiles start on their
+    way back to the pool, the chunk is scored as it now lies, and the copy is
+    waited for before the grid step ends: the next sequence's second chunk
+    lands in that buffer. An inactive slot patches and writes nothing: the
+    null block is no sequence's.
 
     The walk and the carry are ``chunk_walk``'s, so the copies run ahead
     *across* sequences: before a sequence's last chunk is waited for and scored,
@@ -230,23 +242,23 @@ def _kernel(
     ``ahead`` (SMEM (2,), after ``sem``) carries that from one grid step to the
     next. Which of the two buffers a chunk lands in reaches no output: see the
     dead rows below."""
-    writes = len(refs) > 7
+    writes = len(refs) > 5
     if writes:
-        nk_ref, nv_ref, pk_ref, pv_ref, o_ref, pk_out, pv_out, kbuf, vbuf, sem, ahead, back_sem = refs
+        nk_ref, nv_ref, pool_ref, o_ref, pool_out, buf, sem, ahead, back_sem = refs
     else:
-        pk_ref, pv_ref, o_ref, kbuf, vbuf, sem, ahead = refs
+        pool_ref, o_ref, buf, sem, ahead = refs
     b, last = pl.program_id(0), pl.num_programs(0) - 1
     li = li_ref[0]
     length = len_ref[b]
     _, heads, head_dim = q_ref.shape
-    flat = len(kbuf.shape) == 3  # the pools hold a slot's heads as rows: a block is copied as block_size x KV of them
+    flat = len(buf.shape) == 4  # the pool holds a slot's heads as rows: a block is copied as block_size x KV of them
     rows = chunk_blocks * block_size
     n_rep = heads // kv_heads
     cols = rows * kv_heads
-    each = block_size * kv_heads if flat else block_size  # rows of the pool (and of a buffer) a block is
+    each = block_size * kv_heads if flat else block_size  # rows of a plane (and of a buffer's) a block is
 
     def chunk_copies(seq, seq_blocks, chunk, slot, act):
-        """In a loop over the chunk's live blocks: the traced kernel then holds one copy a pool at each of
+        """In a loop over the chunk's live blocks, one copy a block: the traced kernel then holds one copy at each of
         ``chunk_walk``'s sites whatever the table's width, and what a replica's start pays to trace and lower a kernel
         is the count of its binds."""
         at_block = chunk * chunk_blocks
@@ -254,23 +266,22 @@ def _kernel(
         def one(j, _):
             src = pl.ds(tbl_ref[seq, at_block + j] * each, each)
             dst = pl.ds(pl.multiple_of(j * each, each), each)
-            act(pltpu.make_async_copy(pk_ref.at[li, src], kbuf.at[slot, dst], sem.at[0, slot]))
-            act(pltpu.make_async_copy(pv_ref.at[li, src], vbuf.at[slot, dst], sem.at[1, slot]))
+            act(pltpu.make_async_copy(pool_ref.at[li, :, src], buf.at[slot, :, dst], sem.at[slot]))
 
         jax.lax.fori_loop(0, jnp.clip(seq_blocks - at_block, 0, chunk_blocks), one, None)
 
     def zero_buffers():
         # A chunk's dead rows keep what the buffer held before, and a weight of
         # exactly 0 times that must be 0: nothing but zeros and pool rows is ever
-        # in the V buffer. (K's dead columns are replaced after the product.)
-        vbuf[...] = jnp.zeros_like(vbuf)
+        # in the values' plane. (The keys' dead columns are replaced after the product.)
+        buf[:, 1] = jnp.zeros_like(buf[:, 1])
 
     first_slot, n_chunks, over_chunks, close = chunk_walk(
         b, last, len_ref, ahead, block_size=block_size, chunk_blocks=chunk_blocks, chunk_copies=chunk_copies,
         zero_buffers=zero_buffers)
 
     if writes:
-        sublanes = tile_rows(kbuf.dtype)
+        sublanes = tile_rows(buf.dtype)
         span = covering_span(kv_heads, sublanes)
         at = jnp.maximum(length - 1, 0)  # the step's own position (an inactive slot: nothing below is started)
         in_chunk = jax.lax.rem(at, rows)
@@ -279,22 +290,20 @@ def _kernel(
         start = pl.multiple_of(jnp.minimum(first // sublanes * sublanes, block_start + each - span), sublanes)
         tiles, last_slot = pl.ds(start, span), jax.lax.rem(first_slot + jnp.maximum(n_chunks - 1, 0), 2)
         home = pl.ds(pl.multiple_of(tbl_ref[b, at // block_size] * each + (start - block_start), sublanes), span)
-        back = [pltpu.make_async_copy(buf.at[last_slot, tiles], out.at[li, home], back_sem.at[j])
-                for j, (buf, out) in enumerate(((kbuf, pk_out), (vbuf, pv_out)))]
+        back = pltpu.make_async_copy(buf.at[last_slot, :, tiles], pool_out.at[li, :, home], back_sem.at[0])
 
         def write_row():
             place = jax.lax.broadcasted_iota(jnp.int32, (span, head_dim), 0) - (first - start)
-            for new_ref, buf in ((nk_ref, kbuf), (nv_ref, vbuf)):
+            for plane, new_ref in enumerate((nk_ref, nv_ref)):
                 # through float32, which holds every value of the pool's type: a select of packed rows is not every chip's
-                new, held = new_ref[b].astype(jnp.float32), buf[last_slot, tiles, :].astype(jnp.float32)
+                new, held = new_ref[b].astype(jnp.float32), buf[last_slot, plane, tiles, :].astype(jnp.float32)
                 for j in range(kv_heads):
                     held = jnp.where(place == j, new[j:j + 1], held)
-                buf[last_slot, tiles, :] = held.astype(buf.dtype)
-            for copy in back:
-                copy.start()
+                buf[last_slot, plane, tiles, :] = held.astype(buf.dtype)
+            back.start()
 
     q = q_ref[0]
-    # over flat pools a head count need not be a power of two: the column's head and row are worked out for
+    # over a flat pool a head count need not be a power of two: the column's head and row are worked out for
     # one row of columns, and broadcast in the comparisons
     col = jax.lax.broadcasted_iota(jnp.int32, (1 if flat else heads, cols), 1)
     head = jax.lax.broadcasted_iota(jnp.int32, (heads, 1 if flat else cols), 0)
@@ -306,13 +315,13 @@ def _kernel(
         if writes:
             pl.when(c == n_chunks - 1)(write_row)
         s = jax.lax.dot_general(
-            q, kbuf[slot].reshape(cols, head_dim), (((1,), (1,)), ((), ())),
+            q, buf[slot, 0].reshape(cols, head_dim), (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        ) * scale  # (a flat buffer is (cols, head_dim) as it lies)
+        ) * scale  # (a flat buffer's plane is (cols, head_dim) as it lies)
         s = jnp.where(own_head & (c * rows + row < length), s, _NEG_INF)
         m, alpha, p, l = online_softmax_weights(m, l, s)
         acc = alpha * acc + jnp.dot(
-            p.astype(vbuf.dtype), vbuf[slot].reshape(cols, head_dim), preferred_element_type=jnp.float32
+            p.astype(buf.dtype), buf[slot, 1].reshape(cols, head_dim), preferred_element_type=jnp.float32
         )
         return m, l, acc
 
@@ -320,67 +329,63 @@ def _kernel(
     # an inactive slot (length 0) read nothing: its output is 0, not 0/0
     o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
     if writes:
-        @pl.when(length > 0)
-        def _():
-            for copy in back:
-                copy.wait()
+        pl.when(length > 0)(back.wait)
     close()
 
 
 def paged_decode_attention(
-    q, pool_k, pool_v, layer, block_tables, lengths, *, block_size: int, kv_heads: int = 0, scale=None,
+    q, pool, layer, block_tables, lengths, *, block_size: int, kv_heads: int = 0, scale=None,
     new_k=None, new_v=None, interpret=False,
 ):
-    """q (B, H, Hd) against the pools (L, slots, KV, Hd), both in the model's
-    dtype, at layer ``layer``; or against **flat** pools (L, slots x
-    ``kv_heads``, Hd), a slot's heads as consecutive rows, which is how a head
-    count off the sublane tile lies without padding (10 heads: the device pads
-    a (slots, 10, Hd) array's heads to 16). ``scale`` where it is not
-    Hd^-1/2 (packed pairs of half-heads: ``ops/window_attention.py``).
-    ``block_tables`` (B, MB) maps a sequence's block index to a pool block;
-    ``lengths`` (B,) is how many positions of each sequence count (0: an
-    inactive slot, whose output is 0), at most MB x ``block_size``; both
-    are the caller's to keep in range (``BlockTable`` does). Returns
-    (B, H, Hd) in q's dtype: softmax(q k^T / sqrt(Hd)) v over positions
-    [0, length), scores and softmax in float32, the weights in q's dtype
-    into the weighted sum.
+    """q (B, H, Hd) against the pool (L, 2, slots, KV, Hd) in the model's
+    dtype, keys in plane 0 and values in plane 1, at layer ``layer``; or
+    against a **flat** pool (L, 2, slots x ``kv_heads``, Hd), a slot's heads as
+    consecutive rows, which is how a head count off the sublane tile lies
+    without padding (10 heads: the device pads a (slots, 10, Hd) array's heads
+    to 16). ``scale`` where it is not Hd^-1/2 (packed pairs of half-heads:
+    ``ops/window_attention.py``). ``block_tables`` (B, MB) maps a sequence's
+    block index to a pool block; ``lengths`` (B,) is how many positions of each
+    sequence count (0: an inactive slot, whose output is 0), at most MB x
+    ``block_size``; both are the caller's to keep in range (``BlockTable``
+    does). Returns (B, H, Hd) in q's dtype: softmax(q k^T / sqrt(Hd)) v over
+    positions [0, length), scores and softmax in float32, the weights in q's
+    dtype into the weighted sum.
 
-    **With ``new_k``, ``new_v``** (B, ``kv_heads``, Hd), flat pools only: the
-    call writes them first, cast to the pools' type, as position ``length -
-    1``'s row of each sequence (stale as the pools come in), and returns
-    ``(o, pool_k, pool_v)``: the pools in place (``input_output_aliases``), bit
-    for bit what a scatter of the rows of the live sequences leaves (only the
-    whole sublane tiles that cover a row are written, from the chunk the kernel
+    **With ``new_k``, ``new_v``** (B, ``kv_heads``, Hd), a flat pool only: the
+    call writes them first, cast to the pool's type, as position ``length -
+    1``'s row of each sequence (stale as the pool comes in), and returns
+    ``(o, pool)``: the pool in place (``input_output_aliases``), bit for bit
+    what a scatter of the rows of the live sequences leaves (only the whole
+    sublane tiles that cover a row are written, from the chunk the kernel
     scored; an inactive slot writes nothing), ``o`` bit for bit this call's
-    without rows over those pools. Live sequences hold distinct last blocks. A
+    without rows over that pool. Live sequences hold distinct last blocks. A
     call again at the same position writes the same row. A scatter ahead of the
     kernel did the same for ~1.1 us an index on the chip behind a bounds check
     and a select, 48 sequences x K and V a layer: as much as the attention took."""
     b, heads, head_dim = q.shape
-    flat = pool_k.ndim == 3
+    flat = pool.ndim == 4
     if not flat:
-        _, _, kv_heads, _ = pool_k.shape
-    block_bytes = block_size * kv_heads * head_dim * jnp.dtype(pool_k.dtype).itemsize
-    # over flat pools a chunk's columns (rows x heads) are whole lane tiles by the count of blocks
+        kv_heads = pool.shape[3]
+    block_bytes = block_size * kv_heads * head_dim * jnp.dtype(pool.dtype).itemsize  # of a plane
+    # over a flat pool a chunk's columns (rows x heads) are whole lane tiles by the count of blocks
     whole = 128 // math.gcd(block_size * kv_heads, 128) if flat else 1
     chunk_blocks = chunk_blocks_for(block_tables.shape[1], block_bytes, whole=whole)
     rows = chunk_blocks * block_size
-    buffer = (2, rows * kv_heads, head_dim) if flat else (2, rows, kv_heads, head_dim)
+    buffer = (2, 2, rows * kv_heads, head_dim) if flat else (2, 2, rows, kv_heads, head_dim)
     kernel = functools.partial(_kernel, block_size=block_size, chunk_blocks=chunk_blocks, kv_heads=kv_heads,
                                scale=scale or 1.0 / (head_dim**0.5))
     in_hbm = pl.BlockSpec(memory_space=pl.ANY)
     o_shape, o_spec = jax.ShapeDtypeStruct(q.shape, q.dtype), pl.BlockSpec((1, heads, head_dim), lambda i, *_: (i, 0, 0))
     news, writing = [], {}
     if new_k is not None:
-        sublanes = tile_rows(pool_k.dtype)
+        sublanes = tile_rows(pool.dtype)
         if not flat or (block_size * kv_heads) % sublanes or covering_span(kv_heads, sublanes) > block_size * kv_heads:
-            raise ValueError(f"a step's row is written into flat pools whose blocks are whole tiles: {pool_k.shape}, "
+            raise ValueError(f"a step's row is written into a flat pool whose blocks are whole tiles: {pool.shape}, "
                              f"blocks of {block_size} x {kv_heads} rows")
-        news = [(new_k.astype(pool_k.dtype), pl.BlockSpec(new_k.shape, lambda i, *_: (0, 0, 0))),  # whole, every step
-                (new_v.astype(pool_v.dtype), pl.BlockSpec(new_v.shape, lambda i, *_: (0, 0, 0)))]
-        o_shape = (o_shape, jax.ShapeDtypeStruct(pool_k.shape, pool_k.dtype), jax.ShapeDtypeStruct(pool_v.shape, pool_v.dtype))
-        o_spec = (o_spec, in_hbm, in_hbm)
-        writing = {"input_output_aliases": {6: 1, 7: 2}}  # the pools, the last two operands, counted with the three scalars
+        news = [(new.astype(pool.dtype), pl.BlockSpec(new.shape, lambda i, *_: (0, 0, 0)))  # whole, every step
+                for new in (new_k, new_v)]
+        o_shape, o_spec = (o_shape, jax.ShapeDtypeStruct(pool.shape, pool.dtype)), (o_spec, in_hbm)
+        writing = {"input_output_aliases": {6: 1}}  # the pool, the last operand, counted with the three scalars
     return pl.pallas_call(
         kernel,
         out_shape=o_shape,
@@ -391,18 +396,16 @@ def paged_decode_attention(
                 pl.BlockSpec((1, heads, head_dim), lambda i, *_: (i, 0, 0)),
                 *(spec for _, spec in news),
                 in_hbm,
-                in_hbm,
             ],
             out_specs=o_spec,
             scratch_shapes=[
-                pltpu.VMEM(buffer, pool_k.dtype),
-                pltpu.VMEM(buffer, pool_v.dtype),
-                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM(buffer, pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
                 pltpu.SMEM((2,), jnp.int32),
-                *([pltpu.SemaphoreType.DMA((2,))] if news else []),
+                *([pltpu.SemaphoreType.DMA((1,))] if news else []),
             ],
         ),
-        # the V buffer is zeroed at the first sequence, and the buffers, copies under way and
+        # the values' plane is zeroed at the first sequence, and the buffers, copies under way and
         # ``ahead`` pass from one sequence to the next: the grid runs in order on one core
         compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         name="paged_decode_attention",
@@ -412,7 +415,7 @@ def paged_decode_attention(
         jnp.asarray(layer, jnp.int32).reshape(1),
         lengths.astype(jnp.int32),
         block_tables.astype(jnp.int32),
-        q, *(x for x, _ in news), pool_k, pool_v,
+        q, *(x for x, _ in news), pool,
     )
 
 

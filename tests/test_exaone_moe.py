@@ -251,9 +251,8 @@ def test_the_same_decode_step_dispatched_twice_leaves_the_pool_bit_for_bit(serve
             np.testing.assert_array_equal(kept[name][:, row], np.asarray(pool[name])[:, row], err_msg=name)
         G = cfg.num_key_value_heads
         mine = ((blocks[:, None] * BLOCK + np.arange(BLOCK)).reshape(-1)[:, None] * G + np.arange(G)).reshape(-1)
-        for name in ("k", "v"):
-            np.testing.assert_array_equal(kept[name][:, mine], np.asarray(pool[name])[:, mine], err_msg=name)
-        assert np.abs(kept["ring_k"][:, row]).max(axis=-1).all() and np.abs(kept["k"][:, mine[: table.length * G]]).max(axis=-1).all()
+        np.testing.assert_array_equal(kept["kv"][:, :, mine], np.asarray(pool["kv"])[:, :, mine])
+        assert np.abs(kept["ring_k"][:, row]).max(axis=-1).all() and np.abs(kept["kv"][:, 0, mine[: table.length * G]]).max(axis=-1).all()
 
 
 def test_a_decode_step_with_the_paged_kernel_in_it_is_the_step_that_scatters_and_gathers(served, monkeypatch):
@@ -274,12 +273,12 @@ def test_a_decode_step_with_the_paged_kernel_in_it_is_the_step_that_scatters_and
     assert kernel_table.blocks == table.blocks
     rows = (np.asarray(table.blocks)[:, None] * BLOCK + np.arange(BLOCK)).reshape(-1)[:table.length]
     mine = (rows[:, None] * G + np.arange(G)).reshape(-1)
-    for name in ("k", "v"):
-        got, want = np.asarray(kernel_pool[name]), np.asarray(pool[name])
+    for plane in (0, 1):  # keys, values
+        got, want = np.asarray(kernel_pool["kv"][:, plane]), np.asarray(pool["kv"][:, plane])
         np.testing.assert_allclose(got[:, mine], want[:, mine], atol=2e-5, rtol=2e-5)
         assert np.abs(got[:, mine]).max(axis=-1).all()  # every position's row written, a prompt's and a step's
         # the null block: as the prefill left it (the inactive slots wrote nothing), where the scatter went on writing
-        np.testing.assert_array_equal(got[:, :BLOCK * G], np.asarray(fresh[name])[:, :BLOCK * G])
+        np.testing.assert_array_equal(got[:, :BLOCK * G], np.asarray(fresh["kv"][:, plane])[:, :BLOCK * G])
         assert (want[:, :BLOCK * G] != got[:, :BLOCK * G]).any()
 
 
@@ -342,7 +341,7 @@ def test_the_engine_serves_twice_its_slots_and_a_step_leaves_blocks_ring_rows_an
         assert stats["ring_bytes"] == stats["state_bytes"] == M.paged_state_bytes(eng.model_cfg) == ring
         assert stats["bytes_per_block"] == 2 * 2 * BLOCK * 32 * 4  # K and V of the two full layers, and of no other
         assert eng.max_context == (MAX_BLOCKS - 1) * BLOCK and eng._moe_layers == 7
-        assert eng._pool["ring_k"].shape[:2] == (6, 3) and eng._pool["k"].shape[0] == 2
+        assert eng._pool["ring_k"].shape[:2] == (6, 3) and eng._pool["kv"].shape[:2] == (2, 2)
         streams = [server.generate(p, max_new_tokens=10) for p in prompts]  # four requests on two slots
         together = [list(s) for s in streams]
         alone = [list(server.generate(p, max_new_tokens=10)) for p in prompts]

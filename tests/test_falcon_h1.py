@@ -136,7 +136,7 @@ def test_the_config_counts_the_published_layer_and_refuses_what_the_program_does
     assert M.paged_block_bytes(cfg, 16) == 72 * 16 * 2 * 512 * 2  # K and V of 4 heads of 128, bfloat16, every layer
     assert M.paged_state_bytes(cfg) == 72 * (256 * 4096 * 4 + 4 * 5120 * 2 + 4)  # 4,194,304 B of state a layer
     pool = jax.eval_shape(lambda: M.init_paged_pool(dataclasses.replace(cfg, num_hidden_layers=6), 6145, 16, 49))
-    assert pool["k"].shape == (6, 6145 * 16 * 4, 128) and pool["state"].shape == (6, 49, 256, 4096)
+    assert pool["kv"].shape == (6, 2, 6145 * 16 * 4, 128) and pool["state"].shape == (6, 49, 256, 4096)
     assert pool["conv"].shape == (6, 49, 4 * 5120) and pool["state_pos"].shape == (6, 49)
     for refused in (dict(mamba_rms_norm=False), dict(mamba_norm_before_gate=True), dict(attention_bias=True),
                     dict(mamba_proj_bias=True), dict(mamba_conv_bias=False), dict(rope_scaling={"type": "yarn"}),
@@ -240,8 +240,8 @@ def test_the_same_decode_step_dispatched_twice_leaves_the_pool_bit_for_bit(serve
         G = cfg.num_key_value_heads
         for name, leaf in kept.items():
             now = np.asarray(pool[name])
-            if name in ("k", "v"):  # the null block's rows take every inactive slot's writes
-                leaf, now = leaf[:, BLOCK * G:], now[:, BLOCK * G:]
+            if name == "kv":  # the null block's rows take every inactive slot's writes
+                leaf, now = leaf[:, :, BLOCK * G:], now[:, :, BLOCK * G:]
             np.testing.assert_array_equal(leaf, now, err_msg=name)
         assert (kept["state_pos"][:, table.state_row] == table.length).all()
         assert (kept["state_pos"][:, 0] == 0).all() and not kept["state"][:, 0].any()  # the null row
@@ -268,12 +268,12 @@ def test_a_decode_step_with_the_paged_kernel_in_it_is_the_step_that_scatters_and
     assert kernel_table.blocks == table.blocks
     rows = (np.asarray(table.blocks)[:, None] * BLOCK + np.arange(BLOCK)).reshape(-1)[:table.length]
     mine = (rows[:, None] * G + np.arange(G)).reshape(-1)
-    for name in ("k", "v"):
-        got, want = np.asarray(kernel_pool[name]), np.asarray(pool[name])
+    for plane in (0, 1):  # keys, values
+        got, want = np.asarray(kernel_pool["kv"][:, plane]), np.asarray(pool["kv"][:, plane])
         np.testing.assert_allclose(got[:, mine], want[:, mine], atol=2e-5, rtol=2e-5)
         assert np.abs(got[:, mine]).max(axis=-1).all()  # every position's row written, a prompt's and a step's
         # the null block: as the prefill left it (the inactive slots wrote nothing), where the scatter went on writing
-        np.testing.assert_array_equal(got[:, :BLOCK * G], np.asarray(fresh[name])[:, :BLOCK * G])
+        np.testing.assert_array_equal(got[:, :BLOCK * G], np.asarray(fresh["kv"][:, plane])[:, :BLOCK * G])
         assert (want[:, :BLOCK * G] != got[:, :BLOCK * G]).any()
     for name in ("state", "conv", "state_pos"):
         np.testing.assert_allclose(np.asarray(kernel_pool[name]), np.asarray(pool[name]), atol=2e-5, rtol=2e-5, err_msg=name)

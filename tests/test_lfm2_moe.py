@@ -243,7 +243,7 @@ def test_the_same_decode_step_dispatched_twice_leaves_the_window_and_the_rows_bi
         tokens, pool = greedy(params, *args[:3], pool, args[3])
         np.testing.assert_array_equal(np.asarray(once), np.asarray(twice))
         assert int(tokens[1]) == int(np.asarray(once)[1].argmax())
-        for name in ("conv", "state_pos", "k", "v"):
+        for name in ("conv", "state_pos", "kv"):
             np.testing.assert_array_equal(kept[name], np.asarray(pool[name]), err_msg=name)
         row = table.state_row
         assert kept["state_pos"][:, row].tolist() == [table.length] * 6 and np.abs(kept["conv"][:, row]).max(axis=-1).all()
@@ -298,10 +298,10 @@ def test_attention_over_rows_of_two_packed_heads_is_the_attention_a_head_at_a_ti
     tables = np.asarray([[3, 0, 0], [0, 0, 0], [7, 2, 5]], np.int32)
     k, v = (jax.random.normal(key, (BLOCKS * BLOCK, G, d), jnp.float32) for key in keys[:2])
     q = jax.random.normal(keys[2], (3, H, d), jnp.float32)
-    pool_k, pool_v = (t.reshape(1, BLOCKS * BLOCK * G // P, P * d) for t in (k, v))
+    pool = jnp.stack([k, v]).reshape(1, 2, BLOCKS * BLOCK * G // P, P * d)  # keys in plane 0, values in plane 1
     packed = M.pack_queries(cfg, q)
     assert packed.shape == (3, H, P * d) and float(jnp.abs(packed).sum()) == pytest.approx(float(jnp.abs(q).sum()), rel=1e-6)
-    o = PA.paged_decode_attention(packed, pool_k, pool_v, 0, jnp.asarray(tables), jnp.asarray(lengths), block_size=BLOCK,
+    o = PA.paged_decode_attention(packed, pool, 0, jnp.asarray(tables), jnp.asarray(lengths), block_size=BLOCK,
                                   kv_heads=G // P, scale=d ** -0.5, interpret=True)
     got = np.asarray(M.unpack_outputs(cfg, o))
     slots = (tables[:, :, None] * BLOCK + np.arange(BLOCK)).reshape(3, -1)
@@ -334,12 +334,12 @@ def test_a_decode_step_with_the_paged_kernel_in_it_is_the_step_that_scatters_and
     np.testing.assert_allclose(kernel, gathered, atol=2e-4, rtol=2e-4)
     assert kernel_table.blocks == table.blocks
     mine = (np.asarray(table.blocks)[:, None] * BLOCK + np.arange(BLOCK)).reshape(-1)[:table.length]  # a position a row here
-    for name in ("k", "v"):
-        got, want = np.asarray(kernel_pool[name]), np.asarray(pool[name])
+    for plane in (0, 1):  # keys, values
+        got, want = np.asarray(kernel_pool["kv"][:, plane]), np.asarray(pool["kv"][:, plane])
         np.testing.assert_allclose(got[:, mine], want[:, mine], atol=2e-5, rtol=2e-5)
         assert np.abs(got[:, mine]).max(axis=-1).all()  # every position's row written, a prompt's and a step's
         # the null block: as the prefill left it (the inactive slots wrote nothing), where the scatter went on writing
-        np.testing.assert_array_equal(got[:, :BLOCK], np.asarray(fresh[name])[:, :BLOCK])
+        np.testing.assert_array_equal(got[:, :BLOCK], np.asarray(fresh["kv"][:, plane])[:, :BLOCK])
         assert (want[:, :BLOCK] != got[:, :BLOCK]).any()
 
 
@@ -396,7 +396,7 @@ def test_the_engine_serves_twice_its_slots_with_each_request_as_if_alone():
         assert stats["state_bytes"] == M.paged_state_bytes(eng.model_cfg) == 6 * (3 * 64 * 4 + 4) and stats["ring_bytes"] == 0
         assert stats["bytes_per_block"] == 2 * 2 * BLOCK * 32 * 4  # K and V of the two full layers, and of no other
         assert eng.max_context == (MAX_BLOCKS - 1) * BLOCK and eng._moe_layers == 6
-        assert eng._pool["conv"].shape == (6, 3, 192) and eng._pool["k"].shape == (2, BLOCKS * BLOCK, 32)
+        assert eng._pool["conv"].shape == (6, 3, 192) and eng._pool["kv"].shape == (2, 2, BLOCKS * BLOCK, 32)
         streams = [server.generate(p, max_new_tokens=10) for p in prompts]  # four requests on two slots
         together = [list(s) for s in streams]
         alone = [list(server.generate(p, max_new_tokens=10)) for p in prompts]

@@ -1,11 +1,13 @@
 """The decode step's paged-attention kernel (``ops/paged_attention.py``) in
 Pallas interpret mode on the CPU, against the path it replaces on a TPU: the
 table's rows gathered out of the pool and attended to as a masked dense block
-(``ops.attention.attention`` under the table's mask); and the form that writes
-the decode step's own K and V row into flat pools, against a scatter of that
-row (``ops.window_attention.write_spans``) and then the form without rows. The
-kernel's compile for the chip is in ``test_tpu_compile.py``; its speed is the
-benchmark's."""
+(``ops.attention.attention`` under the table's mask); the form that writes
+the decode step's own K and V row into a flat pool, against a scatter of that
+row (``ops.window_attention.write_spans``) and then the form without rows; and
+both forms bit for bit against the walk written out plainly (a sequence's
+chunks gathered one at a time under an online softmax). The pool is one array,
+a layer's keys in plane 0 and its values in plane 1. The kernel's compile for
+the chip is in ``test_tpu_compile.py``; its speed is the benchmark's."""
 
 import functools
 
@@ -39,11 +41,11 @@ LENGTHS = {
 }
 
 
-def _pools(dtype, kv_heads, seed):
-    """Two pools of random rows in ``dtype``, as the engine holds them."""
+def _pool(dtype, kv_heads, seed):
+    """A pool of random rows in ``dtype``, as the engine holds it: (layers, 2, slots, heads, head_dim)."""
     rng = np.random.default_rng(seed)
     shape = (LAYERS, POOL_BLOCKS * BLOCK, kv_heads, HEAD_DIM)
-    return jnp.asarray(rng.standard_normal(shape), dtype), jnp.asarray(rng.standard_normal(shape), dtype)
+    return jnp.stack([jnp.asarray(rng.standard_normal(shape), dtype) for _ in range(2)], axis=1)
 
 
 def _tables(lengths, seed):
@@ -59,15 +61,15 @@ def _tables(lengths, seed):
 
 
 @jax.jit
-def _kernel(q, pk, pv, tables, lengths):
-    return paged_decode_attention(q, pk, pv, 1, tables, lengths, block_size=BLOCK, interpret=True)
+def _kernel(q, pool, tables, lengths):
+    return paged_decode_attention(q, pool, 1, tables, lengths, block_size=BLOCK, interpret=True)
 
 
 @jax.jit
-def _gathered(q, pk, pv, tables, lengths):
+def _gathered(q, pool, tables, lengths):
     idx = (tables[:, :, None] * BLOCK + jnp.arange(BLOCK)[None, None, :]).reshape(len(tables), -1)
     mask = jnp.arange(idx.shape[1]) < lengths[:, None, None, None]  # (B, 1, 1, M)
-    return attention(q[:, None], pk[1][idx], pv[1][idx], causal=False, mask=mask)[:, 0]
+    return attention(q[:, None], pool[1, 0][idx], pool[1, 1][idx], causal=False, mask=mask)[:, 0]
 
 
 @pytest.mark.parametrize("case", list(LENGTHS))
@@ -76,13 +78,13 @@ def _gathered(q, pk, pv, tables, lengths):
 def test_kernel_agrees_with_attention_over_the_gathered_rows(dtype, n_rep, case):
     kv_heads = 32 // jnp.dtype(dtype).itemsize  # one sublane tile of the pool's type
     lengths = np.asarray(LENGTHS[case], np.int32)
-    pk, pv = _pools(dtype, kv_heads, seed=1)
+    pool = _pool(dtype, kv_heads, seed=1)
     tables = _tables(lengths, seed=2)
     q = jnp.asarray(
         np.random.default_rng(3).standard_normal((len(lengths), kv_heads * n_rep, HEAD_DIM)), dtype
     )
-    got = np.asarray(_kernel(q, pk, pv, tables, lengths).astype(jnp.float32))
-    want = np.asarray(_gathered(q, pk, pv, tables, lengths).astype(jnp.float32))
+    got = np.asarray(_kernel(q, pool, tables, lengths).astype(jnp.float32))
+    want = np.asarray(_gathered(q, pool, tables, lengths).astype(jnp.float32))
     active = lengths > 0
     assert np.isfinite(got).all()
     assert not got[~active].any()  # an inactive slot reads nothing and gives 0
@@ -126,13 +128,13 @@ def test_a_sequence_reads_the_same_alone_and_among_neighbours(dtype, crowd):
     kv_heads = 32 // jnp.dtype(dtype).itemsize
     assert C == BLOCK * chunk_blocks_for(TABLE, BLOCK * kv_heads * HEAD_DIM * jnp.dtype(dtype).itemsize)
     rng = np.random.default_rng(4)
-    pk, pv = _pools(dtype, kv_heads, seed=5)
+    pool = _pool(dtype, kv_heads, seed=5)
     slot = CROWDS[crowd].index("X")
     for length in (1, 17, 300, FULL):
         lengths = np.asarray([length, 0, 0, 0], np.int32)
         tables = _tables(lengths, seed=6)
         q = jnp.asarray(rng.standard_normal((4, kv_heads, HEAD_DIM)), dtype)
-        alone = np.asarray(_kernel(q, pk, pv, tables, lengths).astype(jnp.float32))[0]
+        alone = np.asarray(_kernel(q, pool, tables, lengths).astype(jnp.float32))[0]
 
         among = np.asarray([length if n in ("X", "x") else n for n in CROWDS[crowd]], np.int32)
         among_tables = _tables(among, seed=7)
@@ -142,7 +144,7 @@ def test_a_sequence_reads_the_same_alone_and_among_neighbours(dtype, crowd):
             if row != slot:
                 among_tables[row, : -(-n // BLOCK)] = rng.permutation(spare)[: -(-n // BLOCK)]
         order = [0 if row == slot else 1 + row % 3 for row in range(4)]  # the sequence's query in its slot
-        got = _kernel(q[jnp.asarray(order)], pk, pv, among_tables, among)
+        got = _kernel(q[jnp.asarray(order)], pool, among_tables, among)
         assert np.array_equal(alone, np.asarray(got.astype(jnp.float32))[slot]), length
 
 
@@ -153,8 +155,15 @@ def _count(jaxpr, primitive):
 
 
 def _decode_call(tables, lengths):
-    q, pool = jnp.zeros((2, 16, HEAD_DIM), jnp.bfloat16), jnp.zeros((1, 64 * BLOCK, 16, HEAD_DIM), jnp.bfloat16)
-    return functools.partial(paged_decode_attention, layer=0, block_tables=tables, lengths=lengths, block_size=BLOCK), (q, pool, pool)
+    q, pool = jnp.zeros((2, 16, HEAD_DIM), jnp.bfloat16), jnp.zeros((1, 2, 64 * BLOCK, 16, HEAD_DIM), jnp.bfloat16)
+    return functools.partial(paged_decode_attention, layer=0, block_tables=tables, lengths=lengths, block_size=BLOCK), (q, pool)
+
+
+def _decode_call_with_the_steps_row(tables, lengths):
+    q, pool = jnp.zeros((2, 16, HEAD_DIM), jnp.bfloat16), jnp.zeros((1, 2, 64 * BLOCK * 8, HEAD_DIM), jnp.bfloat16)
+    new = jnp.zeros((2, 8, HEAD_DIM), jnp.bfloat16)
+    return functools.partial(paged_decode_attention, layer=0, block_tables=tables, lengths=lengths, block_size=BLOCK, kv_heads=8,
+                             new_k=new, new_v=new), (q, pool)
 
 
 def _latent_call(tables, lengths):
@@ -163,22 +172,25 @@ def _latent_call(tables, lengths):
     return functools.partial(paged_latent_attention, att_index=0, block_tables=tables, lengths=lengths, scale=1.0), (q_l, q_r, rows)
 
 
-@pytest.mark.parametrize("call,pools,unrolled", [(_decode_call, 2, False), (_latent_call, 1, True)],
-                         ids=["keys_and_values_in_a_loop", "latent_rows_unrolled"])
-def test_a_traced_kernel_holds_the_copy_sites_of_its_walk(call, pools, unrolled):
+@pytest.mark.parametrize(
+    "call,unrolled,back", [(_decode_call, False, 0), (_decode_call_with_the_steps_row, False, 1), (_latent_call, True, 0)],
+    ids=["keys_and_values_in_a_loop", "keys_and_values_and_the_steps_row", "latent_rows_unrolled"])
+def test_a_traced_kernel_holds_the_copy_sites_of_its_walk(call, unrolled, back):
     """``chunk_walk``'s sites, counted in the traced kernel: the first chunk's
     start, this sequence's next chunk's, the next sequence's first chunk's, and
     the wait. What a replica's start pays to trace and lower a kernel is the
     count of its binds. A kernel that walks a chunk's live blocks in a loop
-    holds one copy a pool a site whatever the table's width (a chunk of 4, 8 or
-    16 blocks); one that walks them unrolled (the latent kernel, where the chip
-    reads the loop a fifth slower) a copy a place of the chunk (4, 8, 32)."""
+    holds one copy a site whatever the table's width (a chunk of 4, 8 or 16
+    blocks: a block's keys and values come in under one copy); one that walks
+    them unrolled (the latent kernel, where the chip
+    reads the loop a fifth slower) a copy a place of the chunk (4, 8, 32). The
+    step's own row goes back under one copy more, keys and values together."""
     for width, chunk_blocks in ((4, 4), (8, 8), (40, 32 if unrolled else 16)):
         fn, args = call(jnp.zeros((2, width), jnp.int32), jnp.zeros((2,), jnp.int32))
         (kernel,) = [eqn for eqn in jax.make_jaxpr(fn)(*args).eqns if eqn.primitive.name == "pallas_call"]
-        a_site = pools * (chunk_blocks if unrolled else 1)
+        a_site = chunk_blocks if unrolled else 1
         counted = _count(kernel.params["jaxpr"], "dma_start"), _count(kernel.params["jaxpr"], "dma_wait")
-        assert counted == (3 * a_site, a_site), width
+        assert counted == (3 * a_site + back, a_site + back), width
 
 
 # The step's own row: position ``length - 1`` of four sequences, R a chunk's positions (16 x 32, 16, 24 or 12 blocks
@@ -200,8 +212,8 @@ ROWS = {
 
 
 @functools.partial(jax.jit, static_argnums=0)  # one trace a form (with rows, without), a head count and a type
-def _flat_kernel(kv_heads, q, tables, lengths, pk, pv, **rows):
-    return paged_decode_attention(q, pk, pv, 1, tables, lengths, block_size=BLOCK, kv_heads=kv_heads, interpret=True, **rows)
+def _flat_kernel(kv_heads, q, tables, lengths, pool, **rows):
+    return paged_decode_attention(q, pool, 1, tables, lengths, block_size=BLOCK, kv_heads=kv_heads, interpret=True, **rows)
 
 
 def _bits(x):
@@ -210,14 +222,25 @@ def _bits(x):
     return x.view(f"u{x.dtype.itemsize}")
 
 
+def _scattered(pool, tables, lengths, kv_heads, new):
+    """A flat ``pool`` with the live sequences' rows ``new`` (keys, values) scattered to position ``length - 1``, a plane
+    each: what the kernel's write has to leave, bit for bit."""
+    held = np.flatnonzero(lengths)
+    at = lengths[held] - 1
+    starts = jnp.asarray((tables[held, at // BLOCK] * BLOCK + at % BLOCK) * kv_heads)
+    for plane, x in enumerate(new):
+        pool = write_spans(pool, (1, plane), starts, x[held])
+    return pool
+
+
 @pytest.mark.parametrize("case", list(ROWS))
 @pytest.mark.parametrize("kv_heads", [8, 10], ids=["8_heads_half_a_bfloat16_tile", "10_pairs_across_two_tiles"])
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bfloat16_pool", "float32_pool"])
 def test_the_kernel_writes_the_steps_own_row_as_a_scatter_would_and_attends_over_it(dtype, kv_heads, case):
-    """The pools that come back are bit for bit ``write_spans``' over the live
-    sequences (every other row, the null block's among them, as it came), and
-    ``o`` is bit for bit the kernel's without rows over them: the new row is
-    scored where the stale one lay."""
+    """The pool that comes back is bit for bit ``write_spans``' over the live
+    sequences in each plane (every other row, the null block's among them, as
+    it came), and ``o`` is bit for bit the kernel's without rows over it: the
+    new row is scored where the stale one lay."""
     chunk = BLOCK * chunk_blocks_for(TABLE, BLOCK * kv_heads * HEAD_DIM * jnp.dtype(dtype).itemsize,
                                      whole=128 // np.gcd(BLOCK * kv_heads, 128))
     assert chunk < FULL and covering_span(kv_heads, 32 // jnp.dtype(dtype).itemsize) == {
@@ -225,38 +248,106 @@ def test_the_kernel_writes_the_steps_own_row_as_a_scatter_would_and_attends_over
     lengths = np.asarray([{"R": chunk, "R+1": chunk + 1, "FULL": FULL, "FULL-15": FULL - 15}.get(n, n) for n in ROWS[case]],
                          np.int32)
     rng = np.random.default_rng(8)
-    pools = [jnp.asarray(rng.standard_normal((LAYERS, POOL_BLOCKS * BLOCK * kv_heads, HEAD_DIM)), dtype) for _ in range(2)]
+    pool = jnp.stack([jnp.asarray(rng.standard_normal((LAYERS, POOL_BLOCKS * BLOCK * kv_heads, HEAD_DIM)), dtype)
+                      for _ in range(2)], axis=1)
     tables = _tables(lengths, seed=9)
     q = jnp.asarray(rng.standard_normal((4, 2 * kv_heads, HEAD_DIM)), dtype)
     new = [jnp.asarray(rng.standard_normal((4, kv_heads, HEAD_DIM)), jnp.float32).at[0, 0, 0].set(-0.0) for _ in range(2)]
     kernel = functools.partial(_flat_kernel, kv_heads, q, tables, lengths)
 
-    o, *got = kernel(*pools, new_k=new[0], new_v=new[1])
+    o, got = kernel(pool, new_k=new[0], new_v=new[1])
     if case == "two_calls_at_one_position":  # the replay: the same row again, the same output
-        once, (o, *got) = o, kernel(*got, new_k=new[0], new_v=new[1])
+        once, (o, got) = o, kernel(got, new_k=new[0], new_v=new[1])
         np.testing.assert_array_equal(_bits(o), _bits(once))
-    held = np.flatnonzero(lengths)
-    at = lengths[held] - 1
-    slots = tables[held, at // BLOCK] * BLOCK + at % BLOCK
-    want = [write_spans(pool, (1,), jnp.asarray(slots * kv_heads), x[held]) for pool, x in zip(pools, new)]
-    for g, w, pool in zip(got, want, pools):
-        assert g.dtype == pool.dtype and g.shape == pool.shape
-        np.testing.assert_array_equal(_bits(g), _bits(w))
-        np.testing.assert_array_equal(_bits(g[:, :BLOCK * kv_heads]), _bits(pool[:, :BLOCK * kv_heads]))  # the null block
-        assert (_bits(g) != _bits(pool)).any(axis=-1).sum() == len(held) * kv_heads  # and nothing but the rows
-    np.testing.assert_array_equal(_bits(o), _bits(kernel(*want)))
+    want = _scattered(pool, tables, lengths, kv_heads, new)
+    assert got.dtype == pool.dtype and got.shape == pool.shape
+    np.testing.assert_array_equal(_bits(got), _bits(want))
+    np.testing.assert_array_equal(_bits(got[:, :, :BLOCK * kv_heads]), _bits(pool[:, :, :BLOCK * kv_heads]))  # the null block
+    assert (_bits(got) != _bits(pool)).any(axis=-1).sum() == 2 * np.count_nonzero(lengths) * kv_heads  # and nothing but the rows
+    np.testing.assert_array_equal(_bits(o), _bits(kernel(want)))
     assert not np.asarray(o.astype(jnp.float32))[lengths == 0].any()
 
 
-def test_rows_are_written_into_flat_pools_of_whole_tiles_alone():
+# -- the walk written out plainly: what the kernel's arithmetic is, whatever brings the rows in --------------------------
+
+
+def _plain_walk(q, pool, tables, lengths, *, kv_heads, scale=None):
+    """``paged_decode_attention`` without a kernel, a buffer or a copy, over layer 1 of a flat ``pool``: a sequence's
+    live blocks gathered a chunk at a time (a dead block's rows zeros), every head scored against every row of the chunk,
+    the other heads' rows and the positions past the length masked, under the online softmax in float32 with the weights
+    cast to the pool's type. The chunk is the kernel's (``chunk_blocks_for``), so the sums are taken in its order."""
+    _, heads, head_dim = q.shape
+    each = BLOCK * kv_heads
+    blocks = chunk_blocks_for(tables.shape[1], each * head_dim * pool.dtype.itemsize, whole=128 // np.gcd(each, 128))
+    rows, cols = blocks * BLOCK, blocks * each
+    keys, values = (pool[1, plane].reshape(-1, each, head_dim) for plane in (0, 1))
+    own_head = (jnp.arange(cols) % kv_heads)[None, :] == (jnp.arange(heads) // (heads // kv_heads))[:, None]
+    out = []
+    for b, length in enumerate(lengths):
+        m, l = jnp.full((heads, 1), -1e30, jnp.float32), jnp.zeros((heads, 1), jnp.float32)
+        acc = jnp.zeros((heads, head_dim), jnp.float32)
+        n_blocks = -(-int(length) // BLOCK)
+        for c in range(-(-n_blocks // blocks)):
+            mine = np.zeros((blocks,), np.int32)
+            live = np.arange(c * blocks, min((c + 1) * blocks, n_blocks))
+            mine[: len(live)] = tables[b, live]
+            dead = (jnp.arange(blocks) >= len(live))[:, None, None]
+            k, v = (jnp.where(dead, 0, x[mine]).reshape(cols, head_dim) for x in (keys, values))
+            s = jax.lax.dot_general(q[b], k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+            s = s * (scale or 1.0 / head_dim**0.5)
+            s = jnp.where(own_head & (c * rows + jnp.arange(cols) // kv_heads < length)[None, :], s, -1e30)
+            m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+            alpha, p = jnp.exp(m - m_new), jnp.exp(s - m_new)
+            l = alpha * l + p.sum(axis=-1, keepdims=True)
+            acc = alpha * acc + jnp.dot(p.astype(pool.dtype), v, preferred_element_type=jnp.float32)
+            m = m_new
+        out.append((acc / jnp.maximum(l, 1e-30)).astype(q.dtype))
+    return jnp.stack(out)
+
+
+WALKS = ["one", "a_block_and_one", "chunks", "a_full_table", "inactive_slots", "an_empty_slot_between", "every_slot_empty"]
+
+
+@pytest.mark.parametrize("case", WALKS)
+@pytest.mark.parametrize(
+    "layout,kv_heads,rows",  # a step's row is written into a flat pool: GPT-J's and the hybrid's layers scatter theirs
+    [("flat", 4, False), ("flat", 4, True), ("flat", 10, False), ("flat", 10, True), ("stored", 16, False)],
+    ids=["4_heads_flat", "4_heads_flat_with_the_steps_row", "10_heads_flat_off_the_tile",
+         "10_heads_flat_off_the_tile_with_the_steps_row", "16_heads_stored"])
+def test_one_copy_a_block_leaves_the_output_and_the_pool_what_the_walk_written_out_gives(layout, kv_heads, rows, case):
+    """Bit for bit: the output against the plain walk over the pool as the
+    step leaves it, and the pool against a scatter of the step's rows (a call
+    without rows: the pool as it came). Keys and values of a block come in
+    under one copy and the row goes back under one; what they hold and the
+    order of every sum is what two pools and two copies gave. Four heads: a
+    block of one plane is 16 KB, where the copies were furthest from their
+    bytes' time."""
+    lengths = np.asarray(LENGTHS[case], np.int32)
+    rng = np.random.default_rng(54)
+    pool = jnp.stack([jnp.asarray(rng.standard_normal((LAYERS, POOL_BLOCKS * BLOCK * kv_heads, HEAD_DIM)), jnp.bfloat16)
+                      for _ in range(2)], axis=1)
+    tables = _tables(lengths, seed=55)
+    q = jnp.asarray(rng.standard_normal((4, 2 * kv_heads, HEAD_DIM)), jnp.bfloat16)
+    new = [jnp.asarray(rng.standard_normal((4, kv_heads, HEAD_DIM)), jnp.bfloat16) for _ in range(2)]
+    after = _scattered(pool, tables, lengths, kv_heads, new) if rows else pool
+    if layout == "flat":
+        got = _flat_kernel(kv_heads, q, tables, lengths, pool, **({"new_k": new[0], "new_v": new[1]} if rows else {}))
+    else:
+        got = _kernel(q, pool.reshape(LAYERS, 2, POOL_BLOCKS * BLOCK, kv_heads, HEAD_DIM), tables, lengths)
+    o, left = got if rows else (got, pool)
+    np.testing.assert_array_equal(_bits(left), _bits(after))
+    np.testing.assert_array_equal(_bits(o), _bits(_plain_walk(q, after, tables, lengths, kv_heads=kv_heads)))
+
+
+def test_rows_are_written_into_a_flat_pool_of_whole_tiles_alone():
     q, new = jnp.zeros((2, 16, 128), jnp.bfloat16), jnp.zeros((2, 16, 128), jnp.bfloat16)
     tables, lengths = jnp.zeros((2, 4), jnp.int32), jnp.zeros((2,), jnp.int32)
-    stored = jnp.zeros((1, 64, 16, 128), jnp.bfloat16)
-    with pytest.raises(ValueError, match="flat pools"):
-        paged_decode_attention(q, stored, stored, 0, tables, lengths, block_size=16, new_k=new, new_v=new, interpret=True)
-    flat = jnp.zeros((1, 64 * 10, 128), jnp.bfloat16)
+    stored = jnp.zeros((1, 2, 64, 16, 128), jnp.bfloat16)
+    with pytest.raises(ValueError, match="flat pool"):
+        paged_decode_attention(q, stored, 0, tables, lengths, block_size=16, new_k=new, new_v=new, interpret=True)
+    flat = jnp.zeros((1, 2, 64 * 10, 128), jnp.bfloat16)
     with pytest.raises(ValueError, match="whole tiles"):  # blocks of 5 x 10 rows
-        paged_decode_attention(q[:, :10], flat, flat, 0, tables, lengths, block_size=5, kv_heads=10, new_k=new[:, :10],
+        paged_decode_attention(q[:, :10], flat, 0, tables, lengths, block_size=5, kv_heads=10, new_k=new[:, :10],
                                new_v=new[:, :10], interpret=True)
 
 
@@ -278,5 +369,5 @@ GPTJ_Q, LLAMA7B_Q = (8, 1, 16, 256), (8, 1, 32, 128)
 def test_the_path_is_chosen_by_platform_and_shape(monkeypatch, backend, q_shape, kv_heads, pool_dtype, want):
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     q = jax.ShapeDtypeStruct(q_shape, jnp.bfloat16)
-    pool = jax.ShapeDtypeStruct((2, 64 * 16, kv_heads, q_shape[-1]), pool_dtype)
+    pool = jax.ShapeDtypeStruct((2, 2, 64 * 16, kv_heads, q_shape[-1]), pool_dtype)
     assert can_use_paged_kernel(q, pool, 16) is want

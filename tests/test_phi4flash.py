@@ -230,7 +230,7 @@ def test_the_same_decode_step_dispatched_twice_leaves_the_pool_bit_for_bit(serve
         np.testing.assert_array_equal(np.asarray(once), np.asarray(twice))
         assert int(tokens[1]) == int(np.asarray(once)[1].argmax())
         for name, leaf in kept.items():
-            if name not in ("k", "v", "ring_k", "ring_v"):  # the null block's and the null row's rows take every inactive slot's writes
+            if name not in ("kv", "ring_k", "ring_v"):  # the null block's and the null row's rows take every inactive slot's writes
                 np.testing.assert_array_equal(leaf, np.asarray(pool[name]), err_msg=name)
         row = table.state_row
         for name in ("ring_k", "ring_v"):
@@ -261,12 +261,12 @@ def test_a_decode_step_with_the_paged_kernel_in_it_is_the_step_that_scatters_and
     assert kernel_table.blocks == table.blocks
     rows = (np.asarray(table.blocks)[:, None] * BLOCK + np.arange(BLOCK)).reshape(-1)[:table.length]
     mine = (rows[:, None] * G + np.arange(G)).reshape(-1)
-    for name in ("k", "v"):
-        got, want = np.asarray(kernel_pool[name]), np.asarray(pool[name])
+    for plane in (0, 1):  # keys, values
+        got, want = np.asarray(kernel_pool["kv"][:, plane]), np.asarray(pool["kv"][:, plane])
         np.testing.assert_allclose(got[:, mine], want[:, mine], atol=2e-5, rtol=2e-5)
         assert np.abs(got[:, mine]).max(axis=-1).all()  # every position's row written, a prompt's and a step's
         # the null block: as the prefill left it (the inactive slots wrote nothing), where the scatter went on writing
-        np.testing.assert_array_equal(got[:, :BLOCK * G], np.asarray(fresh[name])[:, :BLOCK * G])
+        np.testing.assert_array_equal(got[:, :BLOCK * G], np.asarray(fresh["kv"][:, plane])[:, :BLOCK * G])
         assert (want[:, :BLOCK * G] != got[:, :BLOCK * G]).any()
 
 

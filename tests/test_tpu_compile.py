@@ -193,7 +193,7 @@ def test_paged_decode_and_prefill_at_published_widths(one_chip, monkeypatch, wid
     # results that are a table's rows (B, 1024, heads, head_dim) or one layer of the pool
     slots = blocks * block
     unwanted = {f"[{batch},{per_seq * block},{heads},{head_dim}]"} | {
-        f"[{lead}{slots},{heads},{head_dim}]" for lead in ("", "1,", "2,")}
+        f"[{lead}{slots},{heads},{head_dim}]" for lead in ("", "1,", "2,", "1,2,")}
     for step in (decode_greedy, decode):
         text = step_text(step, params)
         assert "tpu_custom_call" in text and "paged_decode_attention" in text
@@ -210,6 +210,63 @@ def test_paged_decode_and_prefill_at_published_widths(one_chip, monkeypatch, wid
         ).compile().as_text()
         assert "tpu_custom_call" not in text
         assert not _staged(text, plain)
+
+
+def _kernel_dmas(lowered_text):
+    """(starts, waits) of the one Pallas kernel a lowered program holds: each a list of (source shape, destination
+    shape), read from the kernel's Mosaic module as the custom call carries it (the ``body`` of its configuration)."""
+    import base64
+    import re
+
+    from jax._src.lib.mlir import ir
+
+    (body,) = re.findall(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', lowered_text)
+    ctx = ir.Context()
+    ctx.allow_unregistered_dialects = True  # the module is in Mosaic's serialized dialect, which nothing here registers
+    with ctx:
+        module = ir.Module.parse(base64.b64decode(body)).operation.get_asm(print_generic_op_form=True)
+    shapes = lambda line: re.findall(r"memref<([\dx]+)x\w+, #tpu.memory_space<(?:any|vmem)>>", line.split(" : ")[-1])
+    starts = [tuple(shapes(line)) for line in module.splitlines() if ".enqueue_dma\"" in line]
+    waits = [tuple(shapes(line)) for line in module.splitlines() if ".wait_dma2\"" in line]
+    return starts, waits
+
+
+@pytest.mark.parametrize("shape", ["falcon_h1_with_the_steps_row", "lfm2_with_the_steps_row", "gptj"])
+def test_the_paged_kernel_brings_a_block_in_under_one_copy(one_chip, shape):
+    """The lowered kernel at Falcon-H1's, LFM2's and GPT-J's shapes: one DMA
+    start at each of ``chunk_walk``'s three start sites and one wait, each of a
+    block's keys and values together (two planes of ``block_size`` x heads rows,
+    16 KB a plane in the first two), and, where the call writes the step's own
+    row, one start and one wait of the tiles that go back (both planes), where
+    there were two of each. The pool is the call's last operand and, with rows,
+    its second result in place."""
+    from ray_tpu.ops.paged_attention import paged_decode_attention
+
+    heads, kv, hd, blocks, per_seq, batch, flat = {
+        "falcon_h1_with_the_steps_row": (20, 4, 128, 6145, 128, 48, True),
+        "lfm2_with_the_steps_row": (32, 4, 128, 7681, 128, 48, True),
+        "gptj": (16, 16, 256, 384, 64, 8, False),
+    }[shape]
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def call(q, pool, tables, lengths, new_k, new_v):
+        rows = dict(kv_heads=kv, new_k=new_k, new_v=new_v) if flat else {}
+        return paged_decode_attention(q, pool, 1, tables, lengths, block_size=16, **rows)
+
+    pool = arg((2, 2, blocks * 16 * kv, hd) if flat else (2, 2, blocks * 16, kv, hd))
+    lowered = jax.jit(call, donate_argnums=(1,) if flat else ()).lower(
+        arg((batch, heads, hd)), pool, arg((batch, per_seq), jnp.int32), arg((batch,), jnp.int32), arg((batch, kv, hd)),
+        arg((batch, kv, hd)))
+    starts, waits = _kernel_dmas(lowered.as_text())
+    block = f"2x{16 * kv}x{hd}" if flat else f"2x16x{kv}x{hd}"
+    back = [(f"2x16x{hd}",) * 2] if flat else []  # four heads of bfloat16: one sublane tile covers the row
+    assert sorted(starts) == sorted([(block, block)] * 3 + back) and sorted(waits) == sorted([(block, block)] + back)
+    compiled = lowered.compile()
+    assert _kernels(compiled.as_text()) == ["paged_decode_attention"]
+    if flat:
+        assert compiled.memory_analysis().alias_size_in_bytes >= pool.size * 2  # the pool comes back in place
 
 
 def _latent_kernel_reads_the_pool_in_place(text, pool_dims, batch, per_seq, block):
@@ -441,7 +498,7 @@ def test_hybrid_decode_and_prefill_at_olmo_hybrid_widths(one_chip, monkeypatch):
         return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
 
     assert 8.19e9 < nbytes(params) < 8.21e9
-    assert 4.83e9 < nbytes(pool["k"]) + nbytes(pool["v"]) == blocks * M.paged_block_bytes(cfg, block) < 4.84e9
+    assert 4.83e9 < nbytes(pool["kv"]) == blocks * M.paged_block_bytes(cfg, block) < 4.84e9
     rows = {name: nbytes(pool[name]) for name in ("state", "conv", "state_pos")}
     assert 1.35e9 < sum(rows.values()) == (batch + 1) * M.paged_state_bytes(cfg) < 1.36e9
     assert pool["state"].shape == (12, 49, 96, 5760) and pool["conv"].shape == (12, 49, 4 * 11520)
@@ -452,7 +509,7 @@ def test_hybrid_decode_and_prefill_at_olmo_hybrid_widths(one_chip, monkeypatch):
     def pools_copied(text):
         """Instructions of their own that make a pool, or a layer of one, anew."""
         pools = {f"{lead}{dims}" for dims in ("49,96,5760", "49,46080", f"{blocks * block},32,128")
-                 for lead in ("", "1,", "12,", "4,")}
+                 for lead in ("", "1,", "12,", "4,", "2,", "1,2,", "4,2,")}
         return [(dims, op) for dims, _, op in _alone(text)
                 if dims in pools and op in ("copy", "transpose", "gather", "dynamic-slice")]
 
@@ -513,12 +570,12 @@ def test_phi4flash_decode_and_prefill_at_published_widths(one_chip, monkeypatch)
         return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
 
     assert 7.69e9 < nbytes(params) < 7.72e9
-    assert 0.94e9 < nbytes(pool["k"]) + nbytes(pool["v"]) == blocks * M.paged_block_bytes(cfg, block) < 0.95e9
+    assert 0.94e9 < nbytes(pool["kv"]) == blocks * M.paged_block_bytes(cfg, block) < 0.95e9
     assert 1.02e9 < nbytes(pool["ring_k"]) + nbytes(pool["ring_v"]) == (batch + 1) * M.paged_ring(cfg)["bytes"] < 1.03e9
     rows = sum(nbytes(pool[name]) for name in ("state", "conv", "state_pos", "ring_k", "ring_v"))
     assert rows == (batch + 1) * M.paged_state_bytes(cfg) and 1.18e9 < rows < 1.21e9
     assert pool["state"].shape == (9, 49, 16, 5120) and pool["ring_k"].shape == (8, 49, 5120, 128)
-    assert pool["k"].shape == (1, blocks * block * 10, 128)
+    assert pool["kv"].shape == (1, 2, blocks * block * 10, 128)
 
     def arg(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -526,7 +583,7 @@ def test_phi4flash_decode_and_prefill_at_published_widths(one_chip, monkeypatch)
     def pools_copied(text):
         """Instructions of their own that make a pool, or a layer of one, anew."""
         pools = {f"{lead}{dims}" for dims in ("49,16,5120", "49,20480", "49,5120,128", f"{blocks * block * 10},128")
-                 for lead in ("", "1,", "9,", "8,")}
+                 for lead in ("", "1,", "9,", "8,", "2,", "1,2,")}
         return [(dims, op) for dims, _, op in _alone(text)
                 if dims in pools and op in ("copy", "transpose", "gather", "dynamic-slice")]
 
@@ -536,7 +593,7 @@ def test_phi4flash_decode_and_prefill_at_published_widths(one_chip, monkeypatch)
 
     def cache_writes(text):
         """The same for the shared cache's K and V."""
-        return re.findall(rf"= bf16\[1,{blocks * block * 10},128\]\S* (?:dynamic-update-slice|scatter)\(", text)
+        return re.findall(rf"= bf16\[1,2,{blocks * block * 10},128\]\S* (?:dynamic-update-slice|scatter)\(", text)
 
     compiled = decode_greedy.lower(
         params, arg((batch,), jnp.int32), arg((batch,), jnp.int32), arg((batch, per_seq), jnp.int32), pool,
@@ -606,10 +663,10 @@ def test_exaone_moe_decode_and_prefill_at_published_widths(one_chip, monkeypatch
         return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
 
     assert 11.95e9 < nbytes(params) < 11.97e9
-    assert 1.00e9 < nbytes(pool["k"]) + nbytes(pool["v"]) == blocks * M.paged_block_bytes(cfg, block) < 1.01e9
+    assert 1.00e9 < nbytes(pool["kv"]) == blocks * M.paged_block_bytes(cfg, block) < 1.01e9
     rings = nbytes(pool["ring_k"]) + nbytes(pool["ring_v"])
     assert 0.15e9 < rings == (batch + 1) * M.paged_state_bytes(cfg) == (batch + 1) * M.paged_ring(cfg)["bytes"] < 0.16e9
-    assert pool["k"].shape == (2, blocks * block * 8, 128) and pool["ring_k"].shape == (6, 49, 1024, 128)
+    assert pool["kv"].shape == (2, 2, blocks * block * 8, 128) and pool["ring_k"].shape == (6, 49, 1024, 128)
     assert params["e_gate"].shape == (7, 16, 6144, 2048) and params["e_down"].shape == (7, 16, 2048, 6144)
     assert not hasattr(M, "paged_layouts")  # every projection's contraction lies in the tiles as it is stacked
 
@@ -620,7 +677,7 @@ def test_exaone_moe_decode_and_prefill_at_published_widths(one_chip, monkeypatch
 
     def pools_copied(text):
         """Instructions of their own that make a pool, or a layer of one, anew."""
-        pools = {f"{lead}{dims}" for dims in (flat, ring) for lead in ("", "1,", "2,", "6,")}
+        pools = {f"{lead}{dims}" for dims in (flat, ring) for lead in ("", "1,", "2,", "6,", "1,2,", "2,2,")}
         return [(dims, op) for dims, _, op in _alone(text)
                 if dims in pools and op in ("copy", "transpose", "gather", "dynamic-slice")]
 
@@ -628,7 +685,7 @@ def test_exaone_moe_decode_and_prefill_at_published_widths(one_chip, monkeypatch
         return re.findall(rf"= bf16\[6,{ring}\]\S* (?:dynamic-update-slice|scatter)\(", text)
 
     def pool_writes(text):
-        return re.findall(rf"= bf16\[2,{flat}\]\S* (?:dynamic-update-slice|scatter)\(", text)
+        return re.findall(rf"= bf16\[2,2,{flat}\]\S* (?:dynamic-update-slice|scatter)\(", text)
 
     def gmm_rows(text):
         calls = [line for line in text.splitlines() if re.search(r"%gmm[.\d]* = ", line)]
@@ -703,11 +760,11 @@ def test_falcon_h1_decode_and_prefill_at_published_widths(one_chip, monkeypatch)
         return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
 
     assert 10.50e9 < nbytes(params) < 10.52e9
-    assert 1.20e9 < nbytes(pool["k"]) + nbytes(pool["v"]) == blocks * M.paged_block_bytes(cfg, block) < 1.21e9
+    assert 1.20e9 < nbytes(pool["kv"]) == blocks * M.paged_block_bytes(cfg, block) < 1.21e9
     rows = sum(nbytes(pool[name]) for name in ("state", "conv", "state_pos"))
     assert 1.24e9 < rows == (batch + 1) * M.paged_state_bytes(cfg) < 1.25e9
     flat, state = f"{blocks * block * 4},128", "49,256,4096"
-    assert pool["k"].shape == (6, blocks * block * 4, 128) and pool["state"].shape == (6, 49, 256, 4096)
+    assert pool["kv"].shape == (6, 2, blocks * block * 4, 128) and pool["state"].shape == (6, 49, 256, 4096)
     assert not hasattr(M, "paged_layouts")  # every projection's contraction lies in the tiles as it is stacked
 
     def arg(shape, dtype):
@@ -715,12 +772,12 @@ def test_falcon_h1_decode_and_prefill_at_published_widths(one_chip, monkeypatch)
 
     def pools_copied(text):
         """Instructions of their own that make a pool, or a layer of one, anew."""
-        pools = {f"{lead}{dims}" for dims in (flat, state, "49,20480") for lead in ("", "1,", "6,")}
+        pools = {f"{lead}{dims}" for dims in (flat, state, "49,20480") for lead in ("", "1,", "6,", "2,", "1,2,", "6,2,")}
         return [(dims, op) for dims, _, op in _alone(text)
                 if dims in pools and op in ("copy", "transpose", "gather", "dynamic-slice")]
 
     def pool_writes(text):
-        return re.findall(rf"= bf16\[6,{flat}\]\S* (?:dynamic-update-slice|scatter)\(", text)
+        return re.findall(rf"= bf16\[6,2,{flat}\]\S* (?:dynamic-update-slice|scatter)\(", text)
 
     def state_writes(text):
         return re.findall(rf"= f32\[6,{state}\]\S* (?:dynamic-update-slice|scatter)\(", text)
@@ -788,9 +845,9 @@ def test_lfm2_moe_decode_and_prefill_at_published_widths(one_chip, monkeypatch):
         return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
 
     assert 8.04e9 < nbytes(params) < 8.06e9 and "unembed" not in params
-    assert 0.50e9 < nbytes(pool["k"]) + nbytes(pool["v"]) == blocks * M.paged_block_bytes(cfg, block) < 0.51e9
+    assert 0.50e9 < nbytes(pool["kv"]) == blocks * M.paged_block_bytes(cfg, block) < 0.51e9
     assert nbytes(pool["conv"]) + nbytes(pool["state_pos"]) == (batch + 1) * M.paged_state_bytes(cfg) == 49 * 6 * (12288 + 4)
-    assert (cfg.head_dim, cfg.kv_pack) == (64, 2) and pool["k"].shape == (2, blocks * block * 4, 128)
+    assert (cfg.head_dim, cfg.kv_pack) == (64, 2) and pool["kv"].shape == (2, 2, blocks * block * 4, 128)
     assert pool["conv"].shape == (6, 49, 6144)
     assert params["e_gate"].shape == (6, 64, 2048, 1536) and params["e_down"].shape == (6, 64, 1536, 2048)
 
@@ -800,12 +857,12 @@ def test_lfm2_moe_decode_and_prefill_at_published_widths(one_chip, monkeypatch):
     flat = f"{blocks * block * 4},128"
 
     def pools_copied(text):
-        pools = {f"{lead}{flat}" for lead in ("", "1,", "2,")}
+        pools = {f"{lead}{flat}" for lead in ("", "1,", "2,", "1,2,", "2,2,")}
         return [(dims, op) for dims, _, op in _alone(text)
                 if dims in pools and op in ("copy", "transpose", "gather", "dynamic-slice")]
 
     def pool_writes(text):
-        return re.findall(rf"= bf16\[2,{flat}\]\S* (?:dynamic-update-slice|scatter)\(", text)
+        return re.findall(rf"= bf16\[2,2,{flat}\]\S* (?:dynamic-update-slice|scatter)\(", text)
 
     def gmm_rows(text):
         calls = [line for line in text.splitlines() if re.search(r"%gmm[.\d]* = ", line)]

@@ -198,16 +198,16 @@ def test_the_paged_kernel_on_packed_pairs_over_a_flat_pool_is_differential_atten
     """Ten K/V heads of 128 are no whole sublane tile: the pool is flat, a slot's pairs consecutive rows."""
     bs, nb, mb = 4, 16, 5
     qp = normal(10, 3, pairs, 2 * HALF)
-    pool_k, pool_v = normal(11, 1, nb * bs * kv_pairs, 2 * HALF), normal(12, 1, nb * bs * kv_pairs, 2 * HALF)
+    pool = jnp.stack([normal(11, 1, nb * bs * kv_pairs, 2 * HALF), normal(12, 1, nb * bs * kv_pairs, 2 * HALF)], axis=1)
     tables = jnp.asarray([[3, 7, 2, 0, 0], [5, 1, 9, 11, 4], [0] * 5], jnp.int32)
     lengths = jnp.asarray([9, 18, 0], jnp.int32)
     packed = jnp.stack(W.split_queries(qp), axis=2).reshape(3, 2 * pairs, 2 * HALF)
-    o = paged_decode_attention(packed, pool_k, pool_v, 0, tables, lengths, block_size=bs, kv_heads=kv_pairs, scale=SCALE,
+    o = paged_decode_attention(packed, pool, 0, tables, lengths, block_size=bs, kv_heads=kv_pairs, scale=SCALE,
                                interpret=True).reshape(3, pairs, 2, 2 * HALF)
     got = o[:, :, 0] - LAM * o[:, :, 1]
     slots = np.asarray((tables[:, :, None] * bs + jnp.arange(bs)).reshape(3, -1))
     for b in range(2):
-        k, v = (np.asarray(x[0]).reshape(nb * bs, kv_pairs, 2 * HALF)[slots[b]] for x in (pool_k, pool_v))
+        k, v = (np.asarray(x).reshape(nb * bs, kv_pairs, 2 * HALF)[slots[b]] for x in pool[0])
         want = masked(qp[b][None], k, v, (np.arange(mb * bs) < int(lengths[b]))[None])[0]
         np.testing.assert_allclose(got[b], want, atol=2e-5, rtol=2e-5)
     assert not np.asarray(got[2]).any()
@@ -215,7 +215,7 @@ def test_the_paged_kernel_on_packed_pairs_over_a_flat_pool_is_differential_atten
 
 def test_the_kernels_are_chosen_from_platform_and_shape_alone(monkeypatch):
     q = jnp.zeros((48, 1, 40, 128), jnp.bfloat16)
-    flat, stored = jnp.zeros((1, 64 * 16 * 10, 128), jnp.bfloat16), jnp.zeros((1, 64 * 16, 10, 128), jnp.bfloat16)
+    flat, stored = jnp.zeros((1, 2, 64 * 16 * 10, 128), jnp.bfloat16), jnp.zeros((1, 2, 64 * 16, 10, 128), jnp.bfloat16)
     assert not can_use_paged_kernel(q, flat, 16, 10) and not W.can_use_ring_kernel(512, 10, 128, jnp.bfloat16)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert can_use_paged_kernel(q, flat, 16, 10) and not can_use_paged_kernel(q, stored, 16)  # ten heads: flat or not at all
