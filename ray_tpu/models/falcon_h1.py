@@ -55,7 +55,9 @@ table:
   ``models/olmo_hybrid.py`` keeps them and under its rule for a decode step
   dispatched twice at one position. A decode step updates the state in place
   (``ops/selective_scan.py:selective_scan_update``, its decays given a head); a
-  prefill computes it in chunks of matrix products (``ops/ssd.py``).
+  prefill computes it in chunks of matrix products (``ops/ssd.py``). The mixer
+  itself is ``models/mamba2.py``'s, which ``models/granite_hybrid.py`` calls at
+  other numbers; here it takes ``ssm_scales`` over its input projection.
 
 A prefill starts from an empty state: no chunked prefill, no prefix reuse.
 """
@@ -69,12 +71,10 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops import selective_scan
+from ray_tpu.models import mamba2
 from ray_tpu.ops.attention import attention as causal_attention
-from ray_tpu.ops.gated_delta import short_conv_step
 from ray_tpu.ops.layers import apply_rope, rms_norm, swiglu
 from ray_tpu.ops.paged_attention import can_use_paged_kernel, paged_decode_attention
-from ray_tpu.ops.ssd import ssd_chunked
 from ray_tpu.ops.window_attention import window_attention_rows, write_spans
 
 
@@ -147,9 +147,12 @@ class FalconH1Config:
     n_layers = property(lambda self: self.num_hidden_layers)
     max_seq_len = property(lambda self: self.max_position_embeddings)
     kv_row = property(lambda self: self.num_key_value_heads * self.head_dim)  # values of one position's K (or V)
-    bc_dim = property(lambda self: self.mamba_n_groups * self.mamba_d_state)  # values of a token's B (or C)
-    conv_dim = property(lambda self: self.mamba_d_ssm + 2 * self.bc_dim)  # channels the short convolution runs over
-    in_dim = property(lambda self: self.mamba_d_ssm + self.conv_dim + self.mamba_n_heads)  # [z | x | B | C | dt]
+    bc_dim = property(lambda self: self.mamba.bc_dim)  # values of a token's B (or C)
+    conv_dim = property(lambda self: self.mamba.conv_dim)  # channels the short convolution runs over
+    in_dim = property(lambda self: self.mamba.in_dim)  # [z | x | B | C | dt]
+    mamba = property(lambda self: mamba2.Mamba2(
+        self.mamba_d_ssm, self.mamba_d_state, self.mamba_d_head, self.mamba_n_heads, self.mamba_n_groups, self.mamba_d_conv,
+        self.mamba_chunk_size, self.rms_norm_eps, self.dtype))  # what ``models/mamba2.py`` takes
 
     def final_norm(self, params, x):
         """The model's last norm with ``lm_head_multiplier`` on its output, in
@@ -215,12 +218,7 @@ def init_paged_pool(cfg: FalconH1Config, num_blocks: int, block_size: int, state
     null row: the engine asks for ``max_batch + 1``."""
     L = cfg.num_hidden_layers
     flat = (L, 2, num_blocks * block_size * cfg.num_key_value_heads, cfg.head_dim)  # keys in plane 0, values in plane 1
-    return {
-        "kv": jnp.zeros(flat, cfg.dtype),
-        "state": jnp.zeros((L, state_rows, cfg.mamba_d_state, cfg.mamba_d_ssm), jnp.float32),
-        "conv": jnp.zeros((L, state_rows, cfg.mamba_d_conv * cfg.conv_dim), cfg.dtype),
-        "state_pos": jnp.zeros((L, state_rows), jnp.int32),
-    }
+    return {"kv": jnp.zeros(flat, cfg.dtype), **mamba2.init_pool(cfg.mamba, L, state_rows)}
 
 
 def paged_block_bytes(cfg: FalconH1Config, block_size: int) -> int:
@@ -232,25 +230,19 @@ def paged_state_bytes(cfg: FalconH1Config) -> int:
     """Bytes one state row holds, over every layer: the float32 state, the
     convolution's window and the position count. A kind that gives this wants
     a row a sequence."""
-    state = cfg.mamba_d_state * cfg.mamba_d_ssm * 4
-    window = cfg.mamba_d_conv * cfg.conv_dim * jnp.dtype(cfg.dtype).itemsize
-    return cfg.num_hidden_layers * (state + window + 4)
+    return cfg.num_hidden_layers * mamba2.state_bytes(cfg.mamba)
 
 
 def paged_layer(cfg: FalconH1Config, params, step):
     """The model's layer for one call of a paged program (module docstring)."""
     eps, dtype = cfg.rms_norm_eps, cfg.dtype
     H, G, d = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
-    d_ssm, N, P, Hs, Gs, K = (cfg.mamba_d_ssm, cfg.mamba_d_state, cfg.mamba_d_head, cfg.mamba_n_heads,
-                              cfg.mamba_n_groups, cfg.mamba_d_conv)
-    conv_dim, bc = cfg.conv_dim, cfg.bc_dim
+    mixer = cfg.mamba
     gate_mult, down_mult = cfg.mlp_multipliers
     b, s = step.positions.shape
-    rows, live, bs = step.state_rows, step.live.reshape(b, s), step.block_size
+    bs = step.block_size
     decode = s == 1
     dot32 = functools.partial(jnp.einsum, preferred_element_type=jnp.float32)
-    over = functools.partial(jnp.repeat, repeats=P, axis=-1)  # a head's number over its channels
-    scan_kernel = decode and selective_scan.can_use_selective_scan_kernel(d_ssm, N)
     # what is the same for every layer: once a call
     ssm_scales, qkv_scales = cfg.ssm_scales(), cfg.qkv_scales()
     inv_freq = 1.0 / (cfg.rope_theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
@@ -259,64 +251,6 @@ def paged_layer(cfg: FalconH1Config, params, step):
 
     def at(index):  # a layer's tensors, each read out of its stack in place
         return lambda name: jax.lax.dynamic_index_in_dim(params[name], index, keepdims=False)
-
-    def ssm(u, pool, li):
-        """Layer ``li``'s Mamba-2 mixer over ``u`` = N_in(x): (out, pool)."""
-        w = at(li)
-        with jax.named_scope("proj"):
-            z, xbc, dt = jnp.split(dot32("bsd,dc->bsc", u, w("ssm_in")) * ssm_scales, [d_ssm, d_ssm + conv_dim], axis=-1)
-            xbc = xbc.astype(dtype)
-        taps, bias = w("ssm_conv"), w("ssm_conv_b")
-        if decode:
-            # who holds which row, the position each row's sequence is at, which rows take this step
-            # (``models/olmo_hybrid.py``: a step dispatched twice at one position)
-            seen = pool["state_pos"][li]
-            owner = (rows[None, :] == jnp.arange(len(seen))[:, None]) & live[None, :, 0]
-            at_row = jnp.sum(jnp.where(owner, step.positions[None, :, 0], 0), axis=1)
-            advance_rows = jnp.any(owner, axis=1) & (seen == at_row)
-            seen = jnp.where(advance_rows, at_row + 1, seen)
-            advance = jnp.any(owner & advance_rows[:, None], axis=0)
-            with jax.named_scope("conv"):
-                c, windows = short_conv_step(pool["conv"][li], xbc[:, 0], taps, owner, advance_rows, bias)
-                c, windows = c[:, None], pool["conv"].at[li].set(windows)
-        else:
-            length = jnp.sum(live, axis=1)
-            with jax.named_scope("conv"):
-                padded = jnp.pad(xbc, ((0, 0), (K, 0), (0, 0)))
-                # position t at index t + K: its K inputs are indices t + 1 .. t + K
-                c = jax.nn.silu(bias + sum(padded[:, 1 + j:1 + j + s].astype(jnp.float32) * taps[j].astype(jnp.float32)
-                                           for j in range(K)))
-                # the last K inputs of the real tokens: zeros before the sequence's start
-                last = jax.vmap(lambda p, n: jax.lax.dynamic_slice_in_dim(p, n, K, axis=0))(padded, length)
-                windows = pool["conv"].at[li, rows].set(last.reshape(b, K * conv_dim))
-        with jax.named_scope("gates"):
-            xs, bm, cm = jnp.split(c, [d_ssm, d_ssm + bc], axis=-1)
-            bm, cm = bm.reshape(b, s, Gs, N), cm.reshape(b, s, Gs, N)
-            dt = jax.nn.softplus(dt + w("ssm_dt_b"))
-            dt = jnp.where(live[..., None], dt, 0.0)  # a padded position passes the state through
-            a = -jnp.exp(w("ssm_a_log").astype(jnp.float32))  # (Hs,)
-        if decode:
-            with jax.named_scope("update"):
-                decay, dl = over(jnp.exp(dt[:, 0] * a)), over(dt[:, 0])
-                if scan_kernel:
-                    y, states = selective_scan.selective_scan_update(
-                        pool["state"], li, rows, advance, xs[:, 0], dl, bm[:, 0], cm[:, 0], decay=decay)
-                else:
-                    _, new = selective_scan.ssm_step(pool["state"][li, rows], xs[:, 0], dl, bm[:, 0], cm[:, 0], None,
-                                                     advance, decay=decay)
-                    states = pool["state"].at[li, rows].set(new)
-                    y = selective_scan.ssm_read(states[li, rows], cm[:, 0])  # from the state as stored, as a replay reads it
-                y, positions_seen = y[:, None], pool["state_pos"].at[li].set(seen)
-        else:
-            with jax.named_scope("scan"):
-                y, new = ssd_chunked(xs.reshape(b, s, Hs, P), dt, a, bm, cm, cfg.mamba_chunk_size)
-                y, states = y.reshape(b, s, d_ssm), pool["state"].at[li, rows].set(new)
-            positions_seen = pool["state_pos"].at[li, rows].set(length.astype(jnp.int32))
-        pool = {**pool, "state": states, "conv": windows, "state_pos": positions_seen}
-        with jax.named_scope("gate"):
-            y = (y + over(w("ssm_d")) * xs) * jax.nn.silu(z)
-            y = rms_norm(y.reshape(b, s, Gs, d_ssm // Gs), w("ssm_norm").reshape(Gs, -1), eps).reshape(b, s, d_ssm)
-            return y.astype(dtype) @ w("ssm_out"), pool
 
     def attention(u, pool, li):
         """Layer ``li``'s attention over ``u`` = N_in(x): (out, pool)."""
@@ -362,7 +296,7 @@ def paged_layer(cfg: FalconH1Config, params, step):
         x = (x.astype(jnp.float32) * jnp.where(li == 0, cfg.embedding_multiplier, 1.0)).astype(x.dtype)
         u = rms_norm(x, w("in_norm"), eps)
         with jax.named_scope("ssm"):
-            mixed, pool = ssm(u, pool, li)
+            mixed, pool = mamba2.mixer(mixer, at(li), u, pool, li, step, ssm_scales)
         with jax.named_scope("attn"):
             attended, pool = attention(u, pool, li)
         h = x + (mixed.astype(jnp.float32) * cfg.ssm_out_multiplier

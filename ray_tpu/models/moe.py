@@ -3,12 +3,13 @@
 A router over ``n_routed + n_zero`` outputs chooses ``top_k`` of them for
 every token; the first ``n_routed`` are SwiGLU experts, the rest are
 zero-compute (identity) experts that add ``w * u`` with no matmul (a model
-may have none: ``n_zero`` is what the router has beyond ``n_routed``). Two
+may have none: ``n_zero`` is what the router has beyond ``n_routed``). Three
 routing rules stand side by side and the layer's caller names one: ``route``
-(LongCat-Flash: a softmax over every output, weights not renormalised) and
+(LongCat-Flash: a softmax over every output, weights not renormalised),
 ``route_sigmoid`` (DeepSeek-V3's and Kimi-K2's ``noaux_tc``: sigmoid scores,
-weights renormalised over the chosen); in both a bias moves the choice and
-never the weights. A shared expert is no part of this layer: it is a dense
+weights renormalised over the chosen) and ``route_topk_softmax`` (Granite 4.0:
+the largest logits chosen, a softmax over the chosen logits alone); in all a
+bias moves the choice and never the weights. A shared expert is no part of this layer: it is a dense
 SwiGLU that the kind's own layer adds for every token. A chip
 holds experts ``[expert_offset, expert_offset + held)`` of a layer that is
 shared over several chips: it routes over all the outputs, computes its own
@@ -128,6 +129,17 @@ def route_sigmoid(u: jax.Array, router: jax.Array, bias: jax.Array, *, top_k: in
     return scale * chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + eps), experts
 
 
+def route_topk_softmax(u: jax.Array, router: jax.Array, bias: Optional[jax.Array], *, top_k: int, scale: float):
+    """The third rule, same signature. The ``top_k`` largest router logits are
+    chosen (under ``bias`` where the model has one; Granite 4.0 has none and
+    hands None) and a chosen output's weight is ``scale`` times the softmax
+    over the chosen logits alone, in float32: the softmax over every output
+    renormalised over the chosen."""
+    z = jnp.dot(u.astype(jnp.float32), router.astype(jnp.float32), precision=jax.lax.Precision.HIGHEST)
+    _, experts = jax.lax.top_k(z if bias is None else z + bias.astype(jnp.float32), top_k)
+    return scale * jax.nn.softmax(jnp.take_along_axis(z, experts, axis=-1), axis=-1), experts
+
+
 def window_rows(n_rows: int, held: int, n_outputs: int) -> int:
     """Rows of one window of the grouped matmuls, from static shapes alone:
     the smallest power of two at or above ``WINDOW_MULTIPLE`` times the held
@@ -138,11 +150,19 @@ def window_rows(n_rows: int, held: int, n_outputs: int) -> int:
     section 6, PR 43). A layer that holds every expert has one window of every
     row, a prefill's 4,096 too: one call under ``ROW_TILE`` tiles visits the
     same (tile, expert) pairs as eight windows would and gathers, sorts and
-    scatters once (PERF.md section 6, PR 50)."""
+    scatters once (PERF.md section 6, PR 50). **Every row, past the ridge and no
+    whole tiles** (Granite's decode step: 48 tokens x 10 choices = 480, half of
+    them absent experts' and sorted to the back), is rounded up to whole tiles
+    of ``ROW_TILE``: as one tile of 480 every touched expert was multiplied by
+    480 rows, twice its bytes' time at the MXU's peak; as two tiles of 256 the
+    ~240 held rows fill the first and the second is visited only by the experts
+    whose rows reach it (PERF.md section 6, PR 55)."""
     window = WINDOW_MIN
     while window * n_outputs < WINDOW_MULTIPLE * n_rows * held:
         window *= 2
-    return n_rows if window >= n_rows else min(window, _KERNEL_ROWS)
+    if window < n_rows:
+        return min(window, _KERNEL_ROWS)
+    return n_rows if n_rows < ROW_TILE else -(-n_rows // ROW_TILE) * ROW_TILE
 
 
 # The grouped kernel's tiles. A window is walked once, under row tiles of at
@@ -310,8 +330,8 @@ def expert_layer(params: Dict[str, jax.Array], u: jax.Array, *, n_routed: int, t
             return out.at[place].set(res, mode="drop", unique_indices=True)
 
         out = jnp.zeros((n_rows, d), jnp.float32)
-        # a window that takes every row is the whole walk, and is traced without a loop
-        out = walk(0, out) if window == n_rows else jax.lax.fori_loop(0, n_windows, walk, out)
+        # a window that takes every row (or more: whole row tiles) is the whole walk, and is traced without a loop
+        out = walk(0, out) if window >= n_rows else jax.lax.fori_loop(0, n_windows, walk, out)
         # a token's parts add in the order of its choices, whoever else is in
         # the batch (a row no held expert owns was never written and adds zero)
         y = y + jnp.sum(out.reshape(t, top_k, d) * weights[..., None], axis=1)

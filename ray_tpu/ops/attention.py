@@ -42,12 +42,16 @@ def attention(
     q_positions: Optional[jax.Array] = None,
     kv_positions: Optional[jax.Array] = None,
     use_flash: bool = True,
+    scale: Optional[float] = None,
 ) -> jax.Array:
     """Multi-head attention. On TPU with supported shapes, dispatches to the
     Pallas flash kernel; otherwise a fused-by-XLA einsum softmax. The choice
     is made from platform and shape alone (``_can_use_flash``): a kernel
     that was chosen and fails raises, it never gives way to the other path
-    — a run must be able to tell which one it timed."""
+    — a run must be able to tell which one it timed. ``scale``: what the
+    scores are multiplied by ahead of the softmax (None: ``head_dim ** -0.5``;
+    a model states its own where it is not that: Granite 4.0's
+    ``attention_multiplier``)."""
     n_rep = q.shape[2] // k.shape[2]
     k = _repeat_kv(k, n_rep)
     v = _repeat_kv(v, n_rep)
@@ -60,9 +64,9 @@ def attention(
         and kv_positions is None
         and _can_use_flash(q, k)
     ):
-        return _flash(q, k, v, causal=causal)
+        return _flash(q, k, v, causal=causal, scale=scale)
     return _einsum_attention(
-        q, k, v, causal=causal, mask=mask, q_positions=q_positions, kv_positions=kv_positions
+        q, k, v, causal=causal, mask=mask, q_positions=q_positions, kv_positions=kv_positions, scale=scale
     )
 
 
@@ -117,9 +121,14 @@ def _tuned_block_sizes(head_dim: int, q_seq: int, kv_seq: int):
 FLASH_RESIDUALS = "flash_residuals"
 
 
-def _flash(q, k, v, *, causal):
+def _flash(q, k, v, *, causal, scale=None):
     with jax.named_scope("flash"):  # the kernels keep the names they give themselves
-        return _flash_bshd(q, k, v, causal)
+        return _flash_bshd(q, k, v, causal, scale)
+
+
+def _sm_scale(q, scale):
+    """The softmax's scale: the caller's, or ``head_dim ** -0.5``."""
+    return q.shape[-1] ** -0.5 if scale is None else scale
 
 
 def _heads_major(*xs):
@@ -128,15 +137,15 @@ def _heads_major(*xs):
     return tuple(jnp.swapaxes(x, 1, 2) for x in xs)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _flash_bshd(q, k, v, causal):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _flash_bshd(q, k, v, causal, scale=None):
     """Flash attention over (batch, seq, heads, head_dim). Without a gradient
     it is the library's forward kernel and keeps nothing."""
     from jax.experimental.pallas.ops.tpu.flash_attention import flash_attention
 
     q, k, v = _heads_major(q, k, v)
     (out,) = _heads_major(flash_attention(
-        q, k, v, causal=causal, sm_scale=q.shape[-1] ** -0.5,
+        q, k, v, causal=causal, sm_scale=_sm_scale(q, scale),
         block_sizes=_tuned_block_sizes(q.shape[3], q.shape[2], k.shape[2]),
     ))
     return out
@@ -158,7 +167,7 @@ def _training_blocks(q, k):
     return tuple(next(b for b in (512, 256, 128) if x.shape[2] % b == 0) for x in (q, k))
 
 
-def _flash_fwd(q, k, v, causal):
+def _flash_fwd(q, k, v, causal, scale):
     """Under a gradient: the forward kernel that keeps the softmax's
     log-sum-exp, (B, H, S) float32, beside its output. Both are named for a
     ``jax.checkpoint`` policy to keep."""
@@ -169,13 +178,13 @@ def _flash_fwd(q, k, v, causal):
     qt, kt, vt = _heads_major(q, k, v)
     block_q, block_k = _training_blocks(qt, kt)
     o, lse = flash_kernels.flash_attention_fwd(
-        qt, kt, vt, causal=causal, sm_scale=q.shape[-1] ** -0.5, block_q=block_q, block_k=block_k
+        qt, kt, vt, causal=causal, sm_scale=_sm_scale(q, scale), block_q=block_q, block_k=block_k
     )
     out, lse = checkpoint_name((*_heads_major(o), lse), FLASH_RESIDUALS)
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, residuals, dout):
+def _flash_bwd(causal, scale, residuals, dout):
     from ray_tpu.ops import flash_kernels
 
     q, k, v, out, lse = residuals
@@ -183,7 +192,7 @@ def _flash_bwd(causal, residuals, dout):
     q, k, v, do = _heads_major(q, k, v, dout)
     block_q, block_k = _training_blocks(q, k)
     return _heads_major(*flash_kernels.flash_attention_bwd(
-        q, k, v, do, lse, di, causal=causal, sm_scale=q.shape[-1] ** -0.5, block_q=block_q, block_k=block_k
+        q, k, v, do, lse, di, causal=causal, sm_scale=_sm_scale(q, scale), block_q=block_q, block_k=block_k
     ))
 
 
@@ -191,9 +200,10 @@ _flash_bshd.defvjp(_flash_fwd, _flash_bwd)
 
 
 def _einsum_attention(
-    q, k, v, *, causal, mask=None, q_positions=None, kv_positions=None
+    q, k, v, *, causal, mask=None, q_positions=None, kv_positions=None, scale=None
 ):
-    scale = 1.0 / (q.shape[-1] ** 0.5)
+    if scale is None:
+        scale = 1.0 / (q.shape[-1] ** 0.5)
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k, preferred_element_type=jnp.float32)
     scores = scores * scale
     if causal:

@@ -1,7 +1,7 @@
 """The expert layer's window (``models/moe.py``): the grouped matmuls see the
 held (token, choice) rows ``window_rows`` at a time and walk windows until
 they run out. Against a plain loop over tokens and choices written here, for
-both routing rules, whatever the routing leaves the walk to do."""
+the three routing rules, whatever the routing leaves the walk to do."""
 
 import jax
 import jax.numpy as jnp
@@ -11,7 +11,7 @@ import pytest
 from ray_tpu.models import moe
 
 D, F = 16, 32
-RULES = {"softmax": moe.route, "sigmoid": moe.route_sigmoid}
+RULES = {"softmax": moe.route, "sigmoid": moe.route_sigmoid, "topk_softmax": moe.route_topk_softmax}
 
 
 def plain(params, u, *, n_routed, top_k, scale, rule, expert_offset=0, live=None, layer=None):
@@ -45,7 +45,7 @@ def layer_params(held, n_outputs, *, favoured=(), shunned=(), seed=0):
     ``favoured`` outputs and none to the ``shunned``."""
     params = moe.init_expert_params(jax.random.PRNGKey(seed), D, F, held=held, n_outputs=n_outputs)
     bias = np.zeros(n_outputs, np.float32)
-    bias[list(favoured)], bias[list(shunned)] = 10.0, -10.0
+    bias[list(favoured)], bias[list(shunned)] = 100.0, -100.0  # past any score of the three rules (a logit reaches 20)
     return {**params, "router_bias": params["router_bias"] + bias}
 
 
@@ -118,6 +118,28 @@ def test_a_tokens_result_is_the_same_bit_for_bit_wherever_its_rows_fall(rule):
         np.testing.assert_array_equal(np.asarray(got), np.asarray(alone[0]))
 
 
+def test_the_third_rule_by_hand_a_softmax_over_the_chosen_logits():
+    """Three tokens of one value through a "router" whose rows are their
+    logits: the ``top_k`` largest are chosen and the weights are their softmax,
+    which is the softmax over every output renormalised over the chosen; a
+    bias moves the choice and never the weights; ``scale`` multiplies them."""
+    logits = jnp.asarray([[2.0, 0.0, 1.0, -1.0, 3.0], [0.0, 0.0, 5.0, 4.0, -2.0], [1.0, 2.0, 3.0, 4.0, 5.0]])
+    router = jnp.stack([logits[i] for i in range(3)])  # token i is e_i: its logits are row i
+    w, e = moe.route_topk_softmax(jnp.eye(3), router, None, top_k=2, scale=1.0)
+    assert np.asarray(e).tolist() == [[4, 0], [2, 3], [4, 3]]
+    by_hand = np.asarray([[np.e, 1.0], [np.e, 1.0], [np.e, 1.0]]) / (np.e + 1.0)  # each pair a logit apart
+    np.testing.assert_allclose(np.asarray(w), by_hand, rtol=1e-6)
+    full = np.asarray(jax.nn.softmax(logits, axis=-1))
+    picked = np.take_along_axis(full, np.asarray(e), axis=-1)
+    np.testing.assert_allclose(np.asarray(w), picked / picked.sum(-1, keepdims=True), rtol=1e-6)
+    assert not np.allclose(np.asarray(w), picked, rtol=1e-2)  # and not the softmax over every output
+    bias = jnp.asarray([0.0, 10.0, 0.0, 0.0, 0.0])  # output 1 into every token's choice
+    wb, eb = moe.route_topk_softmax(jnp.eye(3), router, bias, top_k=2, scale=2.0)
+    assert (np.asarray(eb)[:, 0] == 1).all() and np.asarray(eb)[:, 1].tolist() == [4, 2, 4]
+    pairs = np.take_along_axis(np.asarray(logits), np.asarray(eb), axis=-1)  # the logits, unbiased
+    np.testing.assert_allclose(np.asarray(wb), 2.0 * np.exp(pairs) / np.exp(pairs).sum(-1, keepdims=True), rtol=1e-6)
+
+
 @pytest.mark.parametrize("n_rows,held,n_outputs,window", [
     (384, 12, 384, 32), (512, 12, 384, 32),  # Kimi-K2's decode step at 48 and at 64 slots
     (1024, 12, 384, 64), (2048, 12, 384, 128), (4096, 12, 384, 256),  # its prefill buckets
@@ -125,6 +147,10 @@ def test_a_tokens_result_is_the_same_bit_for_bit_wherever_its_rows_fall(rule):
     (96, 8, 12, 96), (32, 2, 2, 32), (8, 4, 64, 8), (96, 6, 12, 96),  # most experts held, few rows: one window of every row
     (192, 64, 64, 192), (512, 64, 64, 512),  # LFM2's decode step at 48 and at 128 slots, every expert held: one window, as it was
     (1024, 64, 64, 1024), (2048, 64, 64, 2048), (4096, 64, 64, 4096), (8192, 64, 64, 8192),  # its prefill buckets: one call of every row
+    # Granite's decode step at 48 slots: every row (twice the 240 an even router sends), past the ridge and no whole
+    # tiles, so two tiles of 256; at 32 slots 320 rows likewise; its prefill buckets are whole tiles as they are
+    (480, 36, 72, 512), (320, 36, 72, 512), (2560, 36, 72, 2560), (5120, 36, 72, 5120), (10240, 36, 72, 10240),
+    (240, 36, 72, 240), (80, 36, 72, 80),  # under the ridge: every row, one tile
 ])
 def test_the_window_follows_the_held_rows_and_not_the_batch(n_rows, held, n_outputs, window):
     assert moe.window_rows(n_rows, held, n_outputs) == window
@@ -260,6 +286,7 @@ WEIGHT_TILES = {
     "kimi": {(7168, 2048): ((512, 2048), (1792, 2048)), (2048, 7168): ((2048, 512), (2048, 1792))},
     "exaone": {(6144, 2048): ((512, 2048), (2048, 2048)), (2048, 6144): ((2048, 512), (2048, 2048))},
     "lfm2": {(2048, 1536): ((2048, 1536), (2048, 1536)), (1536, 2048): ((1536, 2048), (1536, 2048))},
+    "granite": {(4096, 768): ((2048, 768), (2048, 768)), (768, 4096): ((768, 4096), (768, 4096))},
 }
 
 
@@ -271,8 +298,10 @@ def test_every_expert_matrix_gets_a_tile_of_whole_lanes_that_divides_it_and_the_
     in twelve and fourteen of 2 MB (PR 38) and under a smaller row tile still
     do, the tile they got. LFM2's two shapes fit a tile and go by whole under
     any row tile (its ``e_down`` once fell to ``ragged_dot`` on 682 columns,
-    then went by in four tiles of 512: runs of 1 KB at a stride of 4 KB). The
-    contraction is cut only where it is past ``_WHOLE_K``. At every row tile a
+    then went by in four tiles of 512: runs of 1 KB at a stride of 4 KB), as do
+    Granite's 6.3 MB: ``e_down`` whole, ``e_gate`` in two halves of its
+    contraction of 4,096. The contraction is cut only where it is past
+    ``_WHOLE_K``. At every row tile a
     window reaches, the call's buffers fit the fast memory it states."""
     from ray_tpu.ops.grouped_matmul import VMEM_BUDGET, vmem_bytes
 
@@ -280,8 +309,10 @@ def test_every_expert_matrix_gets_a_tile_of_whole_lanes_that_divides_it_and_the_
         tk, tn = moe._weight_tile(k, n, rows)
         assert (tk, tn) == want
         assert tk % 128 == 0 and tn % 128 == 0 and n % tn == 0 and k % tk == 0
-        assert tk * tn <= (moe._WEIGHT_TILE if rows == moe.ROW_TILE or kind == "lfm2" else 1 << 20)
-        assert (k <= moe._WHOLE_K) == (tk == k) and ((tk, tn) == (k, n)) == (kind == "lfm2")
+        fits = k * n <= moe._WEIGHT_TILE  # LFM2's and Granite's: a matrix that fits a tile gets the larger tile at any row tile
+        assert fits == (kind in ("lfm2", "granite"))
+        assert tk * tn <= (moe._WEIGHT_TILE if rows == moe.ROW_TILE or fits else 1 << 20)
+        assert (k <= moe._WHOLE_K) == (tk == k) and ((tk, tn) == (k, n)) == (fits and k <= moe._WHOLE_K)
         assert 2 * tk * tn * 2 < vmem_bytes((rows, tk, tn), 2, 4) <= 32 << 20 < VMEM_BUDGET
 
 
@@ -292,6 +323,7 @@ REACHED = {
     "exaone": (8, 16, 128, {48: (128, 128), 256: (512, 256), 512: (512, 256), 1024: (512, 256)}),
     "every_expert_held": (3, 4, 8, {48: (144, 144)}),
     "lfm2": (4, 64, 64, {48: (192, 192), 128: (512, 256), 256: (1024, 256), 512: (2048, 256), 1024: (4096, 256)}),
+    "granite": (10, 36, 72, {48: (512, 256), 256: (2560, 256), 512: (5120, 256), 1024: (10240, 256)}),
 }
 
 
@@ -302,8 +334,8 @@ def test_the_row_tile_at_every_window_the_benchmarks_kinds_reach(monkeypatch, ki
     rows up to ``ROW_TILE`` (every decode program, all of Kimi-K2, LongCat's
     smaller buckets: as they were), tiles of ``ROW_TILE`` in a window of 512
     (LongCat's 1,024 bucket, K-EXAONE's three) and in a whole layer's one
-    window of every row (LFM2's), and one tile where the rows are no whole
-    tiles."""
+    window of every row (LFM2's; Granite's decode step's 480 rows rounded up to
+    two), and one tile where the rows are no whole tiles and under the ridge."""
     top_k, held, n_outputs, reached = REACHED[kind]
     window, row_tile = reached[tokens]
     assert moe.window_rows(tokens * top_k, held, n_outputs) == window

@@ -302,12 +302,15 @@ def _grouped_matmuls_take(text, rows, width, all_rows):
     assert str(all_rows) not in re.findall(rf"= \w+\[(\d+),{width}\]\S* gather\(", text)
 
 
-def _nothing_is_copied_for_the_grouped_matmuls(text):
+def _nothing_is_copied_for_the_grouped_matmuls(text, but_the_metadata=False):
     """No operand of a ``gmm`` call is made by a copy (``copy``, ``copy-done``:
     an expert stack, a window's rows or the group metadata staged or laid out
     anew ahead of the kernel), and no instruction of its own makes an expert
     stack's dims: the kernel reads the stack where it lies, whatever its weight
-    tile and the fast memory it states."""
+    tile and the fast memory it states. ``but_the_metadata``: the call's first
+    four operands (the group metadata: vectors of a kilobyte or two) may be
+    staged, as the compiler does for Granite's prefill of 40 row tiles by 360
+    groups."""
     import re
 
     made_by = {name: op for name, op in re.findall(r"%([\w.\-]+) = [^=\n]*? ([\w\-]+)\(", text)}
@@ -316,7 +319,8 @@ def _nothing_is_copied_for_the_grouped_matmuls(text):
     stacks = set()
     for line in calls:
         operands = re.findall(r"%([\w.\-]+)", line.split("custom-call(", 1)[1].split(")", 1)[0])
-        assert operands and not [o for o in operands if made_by.get(o, "").startswith("copy")], line[:300]
+        assert len(operands) == 6
+        assert not [o for o in operands[4 * but_the_metadata:] if made_by.get(o, "").startswith("copy")], line[:300]
         stacks.add(re.search(r"bf16\[(\d+,\d+,\d+)\]", line.split("operand_layout_constraints", 1)[1]).group(1))
     assert not [(dims, op) for dims, _, op in _alone(text)
                 if dims in stacks and op not in ("parameter", "bitcast", "get-tuple-element")]
@@ -894,6 +898,106 @@ def test_lfm2_moe_decode_and_prefill_at_published_widths(one_chip, monkeypatch):
     assert len(pool_writes(text)) == 4 and "paged_scatter" in text  # a prompt's blocks, K and V, a full layer
     assert not pools_copied(text)
     assert mem.temp_size_in_bytes < 0.5e9
+
+
+def test_granite_hybrid_decode_and_prefill_at_published_widths(one_chip, monkeypatch):
+    """serve.llm's programs for Granite-4.0-H-Small as the benchmark's
+    configuration cuts it (``benchmarks/configs/granite-4.0-h-small-10l.json``):
+    the published widths, layers 0-9 as one section of a whole period a call
+    (five Mamba-2 layers, attention, four Mamba-2 layers), experts 0-35 of every
+    layer's 72, rows 0-50,175 of the tied vocabulary, the engine's 48 slots over
+    6,145 blocks of the one attention layer and 49 state rows of nine (128,
+    8192) float32 states. The file's arithmetic against the compiler: 9.51 GB
+    of weights, 1.88 GB of state rows and a 0.40 GB K/V pool are the programs'
+    arguments, 11.80 GB, and the pool comes back in place. The decode step's
+    body holds the state's update nine times (``selective_scan_update``: a 4 MB
+    row in and out, twice buffered, 128 heads of 64 under decays a channel), the
+    paged kernel once (it writes the step's row) and **all three** grouped
+    matmuls of every layer in the grouped kernel: the step's 480 rows as a
+    window of 512 under two row tiles of 256, ``e_gate`` in two tiles of its
+    contraction and ``e_down`` whole; no ``ragged-dot``, no conditional, no
+    pool, state or expert stack copied. A prefill of 1,024 holds the flash
+    kernel once (its softmax's scale the model's own), runs the recurrence as
+    matrix products (four chunks of 256) and hands every layer's 10,240 rows to
+    the grouped kernel in one call."""
+    import json
+    import re
+
+    from benchmarks.families import granite_hybrid as family
+    from ray_tpu.models import granite_hybrid as M, paged
+    from ray_tpu.serve.llm.deployment import _resolve_model_cfg
+
+    _steered_to_tpu(monkeypatch)
+    stated = _stated_tilings(monkeypatch)
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks", "configs", "granite-4.0-h-small-10l.json")) as f:
+        config = json.load(f)
+    cfg = _resolve_model_cfg(family.model_kwargs(config))
+    e = config["engine"]
+    block, blocks, batch, per_seq = e["block_size"], e["num_blocks"], e["max_batch"], e["max_blocks_per_seq"]
+    prefill, _, decode_greedy = paged.make_paged_fns(M.paged_layer, cfg, block_size=block, state_rows=True)
+    params = _on(one_chip, jax.eval_shape(lambda: M.init_params(jax.random.PRNGKey(0), cfg)))
+    pool = _on(one_chip, jax.eval_shape(lambda: M.init_paged_pool(cfg, blocks, block, batch + 1)))
+
+    def nbytes(tree):
+        return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
+
+    assert 9.51e9 < nbytes(params) < 9.52e9 and "unembed" not in params
+    assert 0.40e9 < nbytes(pool["kv"]) == blocks * M.paged_block_bytes(cfg, block) < 0.41e9
+    rows = sum(nbytes(pool[name]) for name in ("state", "conv", "state_pos"))
+    assert 1.87e9 < rows == (batch + 1) * M.paged_state_bytes(cfg) < 1.89e9
+    flat, state = f"{blocks * block * 8},128", "49,128,8192"
+    assert pool["kv"].shape == (1, 2, blocks * block * 8, 128) and pool["state"].shape == (9, 49, 128, 8192)
+    assert params["e_gate"].shape == (10, 36, 4096, 768) and params["e_down"].shape == (10, 36, 768, 4096)
+    assert params["ssm_in"].shape == (9, 4096, 16768) and params["wqkv"].shape == (1, 4096, 6144)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def pools_copied(text):
+        """Instructions of their own that make a pool, or a layer of one, anew."""
+        pools = {f"{lead}{dims}" for dims in (flat, state, "49,33792") for lead in ("", "1,", "9,", "2,", "1,2,")}
+        return [(dims, op) for dims, _, op in _alone(text)
+                if dims in pools and op in ("copy", "transpose", "gather", "dynamic-slice")]
+
+    def pool_writes(text):
+        return re.findall(rf"= bf16\[1,2,{flat}\]\S* (?:dynamic-update-slice|scatter)\(", text)
+
+    def state_writes(text):
+        return re.findall(rf"= f32\[9,{state}\]\S* (?:dynamic-update-slice|scatter)\(", text)
+
+    def gmm_rows(text):
+        calls = [line for line in text.splitlines() if re.search(r"%gmm[.\d]* = ", line)]
+        return sorted(int(re.search(r"= \w+\[(\d+),\d+\]", line).group(1)) for line in calls)
+
+    compiled = decode_greedy.lower(
+        params, arg((batch,), jnp.int32), arg((batch,), jnp.int32), arg((batch, per_seq), jnp.int32), pool,
+        arg((batch,), jnp.bool_),
+    ).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    kernels = _kernels(text)
+    # the period's body holds its ten layers' kernels once: nine updates, one paged attention, three grouped matmuls a layer
+    assert (kernels.count("selective_scan_update"), kernels.count("paged_decode_attention"), kernels.count("gmm")) == (9, 1, 30)
+    assert gmm_rows(text) == [512] * 30 and "ragged-dot" not in text and " conditional(" not in text
+    assert stated == {(256, 2048, 768), (256, 768, 4096)}  # gate and up; down: two row tiles of the 480 rows' window of 512
+    stated.clear()
+    _nothing_is_copied_for_the_grouped_matmuls(text)
+    assert not pools_copied(text) and not pool_writes(text) and not state_writes(text)
+    assert "paged_scatter" not in text and "paged_gather" not in text
+    assert 11.7e9 < mem.argument_size_in_bytes < 11.9e9 and mem.temp_size_in_bytes < 0.2e9
+    assert mem.alias_size_in_bytes > 0.999 * nbytes(pool)  # the pool comes back in place
+    compiled = prefill.lower(
+        params, arg((1, 1024), jnp.int32), arg((1, per_seq), jnp.int32), pool, arg((), jnp.int32)).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    kernels = _kernels(text)
+    assert (kernels.count("flash_attention"), kernels.count("gmm")) == (1, 30) and gmm_rows(text) == [10240] * 30
+    assert "selective_scan" not in " ".join(kernels) and "paged_decode_attention" not in kernels
+    assert stated == {(256, 2048, 768), (256, 768, 4096)}  # one call of every row: no window is walked
+    _nothing_is_copied_for_the_grouped_matmuls(text, but_the_metadata=True)
+    assert "ragged-dot" not in text
+    assert len(pool_writes(text)) == 2 and "paged_scatter" in text  # a prompt's blocks, K and V, the one attention layer
+    assert len(state_writes(text)) == 9  # the prompt's last state into its row, a Mamba layer
+    assert not pools_copied(text)
+    assert mem.temp_size_in_bytes < 1.0e9
 
 
 def _steered_to_tpu(monkeypatch):
