@@ -152,7 +152,7 @@ class FalconH1Config:
     in_dim = property(lambda self: self.mamba.in_dim)  # [z | x | B | C | dt]
     mamba = property(lambda self: mamba2.Mamba2(
         self.mamba_d_ssm, self.mamba_d_state, self.mamba_d_head, self.mamba_n_heads, self.mamba_n_groups, self.mamba_d_conv,
-        self.mamba_chunk_size, self.rms_norm_eps, self.dtype))  # what ``models/mamba2.py`` takes
+        self.rms_norm_eps, self.dtype))  # what ``models/mamba2.py`` takes (``mamba_chunk_size`` is the upstream kernel's tile)
 
     def final_norm(self, params, x):
         """The model's last norm with ``lm_head_multiplier`` on its output, in

@@ -163,7 +163,7 @@ class GraniteHybridConfig:
     kv_row = property(lambda self: self.num_key_value_heads * self.head_dim)  # values of one position's K (or V)
     mamba = property(lambda self: mamba2.Mamba2(
         self.mamba_expand * self.hidden_size, self.mamba_d_state, self.mamba_d_head, self.mamba_n_heads,
-        self.mamba_n_groups, self.mamba_d_conv, self.mamba_chunk_size, self.rms_norm_eps, self.dtype))
+        self.mamba_n_groups, self.mamba_d_conv, self.rms_norm_eps, self.dtype))
 
     def final_norm(self, params, x):
         """The model's last norm with ``1 / logits_scaling`` on its output, in

@@ -23,7 +23,10 @@ dispatched twice at one position. A decode step updates the state in place
 (``ops/selective_scan.py:selective_scan_update``, its decays given a channel: a
 head's number repeated over its P channels, so a head of half a lane tile is the
 same call); a prefill computes it in chunks of matrix products
-(``ops/ssd.py``) from an empty state.
+(``ops/ssd.py``) from an empty state, ``x`` and ``y`` (positions, d_ssm) and the
+state (N, d_ssm) as the pool keeps it, in chunks of that module's own tile: the
+published ``mamba_chunk_size`` is the upstream kernel's tile, defines no
+mathematics and is not read here.
 """
 
 from __future__ import annotations
@@ -49,7 +52,6 @@ class Mamba2(NamedTuple):
     n_heads: int
     n_groups: int
     d_conv: int
-    chunk_size: int
     eps: float
     dtype: Any
 
@@ -136,7 +138,7 @@ def mixer(m: Mamba2, w, u, pool, index, step, scales=None):
             y, positions_seen = y[:, None], pool["state_pos"].at[index].set(seen)
     else:
         with jax.named_scope("scan"):
-            y, new = ssd_chunked(xs.reshape(b, s, Hs, P), dt, a, bm, cm, m.chunk_size)
+            y, new = ssd_chunked(xs.reshape(b, s, Hs, P), dt, a, bm, cm)
             y, states = y.reshape(b, s, d_ssm), pool["state"]
             for i in range(b):  # a prompt a call: each row's state written where it lies
                 states = jax.lax.dynamic_update_slice(states, new[i][None, None], (index, rows[i], 0, 0))

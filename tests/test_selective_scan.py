@@ -11,6 +11,7 @@ import os
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
+import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402
@@ -192,15 +193,60 @@ def test_the_update_with_two_groups_and_a_decay_a_head_is_the_mamba2_step():
                                 decay=decay[..., :384], interpret=True)
 
 
-@pytest.mark.parametrize("t, chunk", [(24, 8), (8, 8), (5, 8), (256, 128), (64, 128)])
-def test_a_prompt_in_chunks_of_matrix_products_is_the_token_walk(t, chunk):
-    """Chunk edges: a prompt of whole chunks, of one, of less than one; the
-    published chunk of 128 over two chunks and over half of one."""
-    x, dt, a, bm, cm = mamba2_inputs(8, t, p=16, n=32)
-    y, state = ssd_chunked(x, dt, a, bm, cm, chunk)
+GRANITE = dict(heads=4, p=64, groups=1, n=128)  # the published head shapes in small: a head of half a lane tile, one group
+FALCON = dict(heads=4, p=128, groups=2, n=256)  # a head a lane tile, two groups of two heads
+
+
+@pytest.mark.parametrize("t, chunk, shape, form", [
+    *((t, chunk, dict(p=16, n=32), "products") for t, chunk in [(24, 8), (8, 8), (5, 8), (256, 128), (64, 128)]),
+    # the tile from the shapes: a bucket of two tiles and one of half a tile, B 1 and 2, in both forms
+    *((t, None, dict(shape, b=b), form) for shape in (GRANITE, FALCON) for t, b in [(256, 1), (256, 2), (64, 2), (64, 1)]
+      for form in ("products", "kernel")),
+    (384, None, dict(heads=8, p=64, groups=2, n=128, b=1), "kernel"),  # three tiles, two blocks of lanes a group
+    (512, 256, dict(GRANITE, b=1), "kernel"),  # a tile that is given
+])
+def test_a_prompt_in_chunks_of_matrix_products_is_the_token_walk(t, chunk, shape, form):
+    """Chunk edges: a prompt of whole chunks, of one, of less than one; the tile
+    of 128 over two chunks and over half of one; the kernel under the
+    interpreter at heads that share a lane tile and heads that fill one."""
+    x, dt, a, bm, cm = mamba2_inputs(8, t, **shape)
+    y, state = ssd_chunked(x, dt, a, bm, cm, chunk, kernel=form == "kernel", interpret=True)
     want_y, want_state = mamba2_recurrence(x, dt, a, bm, cm)
     np.testing.assert_allclose(y, want_y, atol=2e-5, rtol=2e-5)
     np.testing.assert_allclose(state, want_state, atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("form", ["products", "kernel"])
+def test_a_prompts_chunked_form_at_granites_shape_keeps_no_heads_decays_and_turns_no_state(form):
+    """(1, 1024, 128, 64), N 128, traced and not run: the parent's ``L`` was
+    (chunks, heads, 256, 256) float32, 134 MB a layer, and its state (heads, N,
+    P) until a transposition at the end. Nothing over 40 MB is made in either
+    form (the kernel's blocks are its own), and no array with the heads, the
+    state dimension and a head's channels apart is transposed."""
+    import collections
+
+    heads, p, n, s = 128, 64, 128, 1024
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)  # noqa: E731
+    jaxpr = jax.make_jaxpr(lambda *a: ssd_chunked(*a, kernel=form == "kernel"))(
+        f32(1, s, heads, p), f32(1, s, heads), f32(heads), f32(1, s, 1, n), f32(1, s, 1, n))
+    apart = collections.Counter([heads, n, p])
+    largest, kernels = 0, 0
+
+    def walk(jaxpr):
+        nonlocal largest, kernels
+        for eqn in jaxpr.eqns:
+            kernels += eqn.primitive.name == "pallas_call"
+            for v in eqn.outvars:
+                largest = max(largest, int(np.prod(v.aval.shape)) * v.aval.dtype.itemsize)
+            if eqn.primitive.name == "transpose":
+                assert apart - collections.Counter(eqn.invars[0].aval.shape), eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jaxpr.jaxpr)
+    assert kernels == (form == "kernel")
+    assert 33e6 < largest < 40e6  # x and y, 33.5 MB (and the other form's ``L`` at its tile of 64); the parent's 134 MB
+    assert [v.aval.shape for v in jaxpr.jaxpr.outvars] == [(1, s, heads, p), (1, n, heads * p)]
 
 
 def test_a_padded_tail_and_a_chunk_of_padding_alone_pass_the_chunked_state_through():

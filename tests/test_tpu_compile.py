@@ -741,8 +741,9 @@ def test_falcon_h1_decode_and_prefill_at_published_widths(one_chip, monkeypatch)
     the pool is left) and the state's update (``selective_scan_update`` within
     its fast-memory budget: a 4 MB row in and out, twice buffered), and copies
     no pool, state or layer's matrix. A prefill of 1,024 holds the flash kernel,
-    runs the recurrence as matrix products (eight chunks; no state-space kernel)
-    and scatters the prompt's blocks; its own memory stays under 0.2 GB."""
+    runs the recurrence as matrix products in ``ssd_prefill`` (eight chunks of
+    128, a head's decays made in fast memory) and scatters the prompt's blocks;
+    its own memory stays under 0.2 GB."""
     import json
     import re
 
@@ -803,7 +804,7 @@ def test_falcon_h1_decode_and_prefill_at_published_widths(one_chip, monkeypatch)
     compiled = prefill.lower(
         params, arg((1, 1024), jnp.int32), arg((1, per_seq), jnp.int32), pool, arg((), jnp.int32)).compile()
     text, mem = compiled.as_text(), compiled.memory_analysis()
-    assert _kernels(text) == ["flash_attention"]
+    assert _kernels(text) == ["flash_attention", "ssd_prefill"]
     assert len(pool_writes(text)) == 2 and "paged_scatter" in text  # a prompt's blocks, K and V: ``write_spans`` stays
     assert len(state_writes(text)) == 1  # the prompt's last state into its row
     assert not pools_copied(text)
@@ -918,8 +919,10 @@ def test_granite_hybrid_decode_and_prefill_at_published_widths(one_chip, monkeyp
     contraction and ``e_down`` whole; no ``ragged-dot``, no conditional, no
     pool, state or expert stack copied. A prefill of 1,024 holds the flash
     kernel once (its softmax's scale the model's own), runs the recurrence as
-    matrix products (four chunks of 256) and hands every layer's 10,240 rows to
-    the grouped kernel in one call."""
+    matrix products in ``ssd_prefill`` nine times (eight chunks of 128 whatever
+    the published 256: no array of all heads' decays, 134 MB a layer at the
+    parent, nor any (heads, N, P) state is left in the program) and hands every
+    layer's 10,240 rows to the grouped kernel in one call."""
     import json
     import re
 
@@ -990,7 +993,10 @@ def test_granite_hybrid_decode_and_prefill_at_published_widths(one_chip, monkeyp
     text, mem = compiled.as_text(), compiled.memory_analysis()
     kernels = _kernels(text)
     assert (kernels.count("flash_attention"), kernels.count("gmm")) == (1, 30) and gmm_rows(text) == [10240] * 30
-    assert "selective_scan" not in " ".join(kernels) and "paged_decode_attention" not in kernels
+    assert kernels.count("ssd_prefill") == 9 and "selective_scan" not in " ".join(kernels) and "paged_decode_attention" not in kernels
+    # the chunks' decays stay in fast memory and the state lies (N, heads x P) from the first chunk: no (.., 128 heads, 256, 256)
+    # or (.., 128, 128, 128) array of decays and no state with its heads apart, in any order of their dimensions
+    assert not re.search(r"f32\[(?:\d+,)*128,(?:256,256|128,128)\]", text) and not re.search(r"f32\[(?:\d+,)*128,128,64\]", text)
     assert stated == {(256, 2048, 768), (256, 768, 4096)}  # one call of every row: no window is walked
     _nothing_is_copied_for_the_grouped_matmuls(text, but_the_metadata=True)
     assert "ragged-dot" not in text
@@ -998,6 +1004,27 @@ def test_granite_hybrid_decode_and_prefill_at_published_widths(one_chip, monkeyp
     assert len(state_writes(text)) == 9  # the prompt's last state into its row, a Mamba layer
     assert not pools_copied(text)
     assert mem.temp_size_in_bytes < 1.0e9
+
+
+@pytest.mark.parametrize("s", [256, 512, 1024])
+@pytest.mark.parametrize("heads, p, groups, n", [(128, 64, 1, 128), (32, 128, 2, 256)], ids=["granite", "falcon-h1"])
+def test_the_prompts_ssd_kernel_at_the_published_head_shapes(one_chip, monkeypatch, heads, p, groups, n, s):
+    """``ops/ssd.py`` alone at the two mixers the benchmark serves, a bucket a
+    case: on a TPU the shapes choose the kernel, which compiles within the
+    fast memory it states (``_VMEM_LIMIT``: Mosaic refuses a kernel over it),
+    and beside ``x``, ``y`` and the state the program keeps under 8 MB (the
+    chunks' ``C B^T``, ``dt`` and its running sums by block of heads): the
+    heads' decays, 134 MB a layer at Granite's 1,024 before, are nowhere."""
+    from ray_tpu.ops import ssd
+
+    _steered_to_tpu(monkeypatch)
+    assert ssd.can_use_ssd_kernel(s, heads, p, groups, n) and ssd._VMEM_LIMIT <= 32 << 20
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)  # noqa: E731
+    compiled = jax.jit(ssd.ssd_chunked).lower(
+        f32(1, s, heads, p), f32(1, s, heads), f32(heads), f32(1, s, groups, n), f32(1, s, groups, n)
+    ).compile()
+    assert _kernels(compiled.as_text()) == ["ssd_prefill"]
+    assert compiled.memory_analysis().temp_size_in_bytes < 8e6
 
 
 def _steered_to_tpu(monkeypatch):
