@@ -31,12 +31,13 @@ __all__.append("vit")
 # The model kinds ``serve.llm`` runs: the ``kind`` a config dict names (None:
 # it names none) -> (its module, the name of its config class there). The module
 # gives ``models/paged.py`` a kind's four things; it is imported when asked for.
-# Nine: GPT-J/Llama, LongCat and Kimi-K2 (latent attention, routed experts),
+# Ten: GPT-J/Llama, LongCat and Kimi-K2 (latent attention, routed experts),
 # Olmo-Hybrid (gated delta rule), Phi-4-mini-flash (Mamba-1, window rings),
 # K-EXAONE (window rings, sigmoid experts), Falcon-H1 (Mamba-2 beside attention),
 # LFM2 (short convolutions, experts all held), Granite 4.0-H (Mamba-2 nine layers
-# in ten, softmax-over-the-chosen experts in every layer; ``models/mamba2.py`` is
-# the mixer it shares with Falcon-H1).
+# in ten, softmax-over-the-chosen experts in every layer), Nemotron-H (layers of
+# one part: a Mamba-2 mixer, attention or experts of two matrices in a latent;
+# ``models/mamba2.py`` is the mixer it shares with Falcon-H1 and Granite).
 PAGED_KINDS = {
     None: ("ray_tpu.models.generation", "TransformerConfig"),
     "longcat": ("ray_tpu.models.longcat", "LongcatConfig"),
@@ -47,6 +48,7 @@ PAGED_KINDS = {
     "falcon_h1": ("ray_tpu.models.falcon_h1", "FalconH1Config"),
     "lfm2_moe": ("ray_tpu.models.lfm2_moe", "Lfm2MoeConfig"),
     "granite_hybrid": ("ray_tpu.models.granite_hybrid", "GraniteHybridConfig"),
+    "nemotron_h": ("ray_tpu.models.nemotron_h", "NemotronHConfig"),
 }
 
 

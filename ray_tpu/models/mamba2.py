@@ -2,7 +2,11 @@
 function the kinds with such a layer call (``models/falcon_h1.py``: 32 heads of
 128 in two groups beside attention in every layer, under µP multipliers;
 ``models/granite_hybrid.py``: 128 heads of 64 in one group, nine layers in ten,
-no multiplier inside the mixer). ``u`` is the layer's normed input:
+no multiplier inside the mixer; ``models/nemotron_h.py``: 128 heads of 64 in
+eight groups, a layer's only part, five layers in eleven: B and C 1,024 values
+each, the convolution over 10,240 channels, the gated norm over eight groups of
+1,024). A change here is judged at one, two and eight groups at once. ``u`` is
+the layer's normed input:
 
     [z | x | B | C | dt] = (W_in u) * scales, widths d_ssm, d_ssm, G N, G N, H
     [x | B | C] <- silu(b_conv + causal depthwise convolution of width K)

@@ -1,16 +1,26 @@
-"""The expert layer, told which experts it holds.
+"""The expert layer, told which experts it holds. Six kinds call it: LongCat
+(12 of 768 outputs chosen, 16 of 512 experts held), Kimi-K2 (8 of 384, 12
+held), K-EXAONE (8 of 128, 16 held), LFM2 (4 of 64, all held), Granite 4.0-H
+(10 of 72, 36 held), all with gated experts of three matrices over the residual
+width, and Nemotron-H (22 of 512, 128 held) with ungated experts of two
+matrices that read and write a latent a quarter of that width.
 
 A router over ``n_routed + n_zero`` outputs chooses ``top_k`` of them for
-every token; the first ``n_routed`` are SwiGLU experts, the rest are
-zero-compute (identity) experts that add ``w * u`` with no matmul (a model
-may have none: ``n_zero`` is what the router has beyond ``n_routed``). Three
+every token; the first ``n_routed`` are experts (SwiGLU, ``e_down (silu(e_gate
+v) * e_up v)``, or where the caller hands in no ``e_gate`` the ungated
+``e_down relu(e_up v)^2``), the rest are
+zero-compute (identity) experts that add ``w * v`` with no matmul (a model
+may have none: ``n_zero`` is what the router has beyond ``n_routed``). ``v`` is
+what the router reads, ``u``, unless the caller hands the experts' rows apart
+(``expert_layer(rows=)``: experts in a latent; the projections into it and out
+of it are the caller's dense matmuls, and the result is latent-wide). Three
 routing rules stand side by side and the layer's caller names one: ``route``
 (LongCat-Flash: a softmax over every output, weights not renormalised),
 ``route_sigmoid`` (DeepSeek-V3's and Kimi-K2's ``noaux_tc``: sigmoid scores,
 weights renormalised over the chosen) and ``route_topk_softmax`` (Granite 4.0:
 the largest logits chosen, a softmax over the chosen logits alone); in all a
 bias moves the choice and never the weights. A shared expert is no part of this layer: it is a dense
-SwiGLU that the kind's own layer adds for every token. A chip
+MLP that the kind's own layer adds for every token. A chip
 holds experts ``[expert_offset, expert_offset + held)`` of a layer that is
 shared over several chips: it routes over all the outputs, computes its own
 experts' part of the result for the tokens that chose them and every identity
@@ -41,7 +51,7 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from ray_tpu.ops.grouped_matmul import VMEM_BUDGET, gmm as _gmm, vmem_bytes
-from ray_tpu.ops.layers import swiglu
+from ray_tpu.ops.layers import relu2, swiglu
 
 # what ``expert_layer`` counts of its routing, in this order: (token, choice)
 # rows sent to held, identity and absent experts; held experts with at least one
@@ -264,7 +274,8 @@ def grouped_matmul(rows, experts, groups, out_type=None):
 
 
 def expert_layer(params: Dict[str, jax.Array], u: jax.Array, *, n_routed: int, top_k: int, scale: float,
-                 expert_offset: int = 0, live: Optional[jax.Array] = None, layer=None, rule=route):
+                 expert_offset: int = 0, live: Optional[jax.Array] = None, layer=None, rule=route,
+                 rows: Optional[jax.Array] = None):
     """``u`` (T, D) -> (y (T, D), counts uint32 in the order ``COUNTS``).
 
     ``params``: ``router`` (D, n_routed + n_zero), ``router_bias``, and the
@@ -273,10 +284,18 @@ def expert_layer(params: Dict[str, jax.Array], u: jax.Array, *, n_routed: int, t
     marks the rows that are tokens (padding and empty decode slots route
     nowhere and are not counted). ``counts``: rows sent to held, identity and
     absent experts, held experts with at least one row, the most rows any
-    held expert got, and the windows walked. ``rule``: ``route`` or
-    ``route_sigmoid``.
+    held expert got, and the windows walked. ``rule``: one of the three.
 
-    The held rows go through the three grouped matmuls ``window_rows`` at a
+    **What the caller hands in chooses the expert.** With ``e_gate`` it is the
+    gated one, ``e_down (silu(e_gate v) * e_up v)``; without, the ungated one of
+    two matrices, ``e_down relu(e_up v)^2`` (two grouped calls a window, the
+    square taken in float32 ahead of the rounding ``e_down`` reads). ``rows``
+    (T, C) is ``v``, what the experts read, where it is not what the router
+    reads (experts in a latent: ``v = W_in u``, the caller's matmul): the
+    experts are then (held, C, F) and (held, F, C), an identity expert adds
+    ``w * v``, and ``y`` is (T, C), the weighted sum the caller projects back.
+
+    The held rows go through the grouped matmuls ``window_rows`` at a
     time, in sorted order, and each float32 result row is written to its own
     (token, choice) place: a token's result depends neither on who shares its
     batch nor on the window or the place in it that its rows fell to.
@@ -286,9 +305,10 @@ def expert_layer(params: Dict[str, jax.Array], u: jax.Array, *, n_routed: int, t
     runs over ``layers x held`` groups of which only this layer's have rows,
     and reads those experts where they lie: cutting a layer out of the stack
     would copy every held expert, touched or not, once a call."""
-    t, d = u.shape
-    e_gate, e_up, e_down = params["e_gate"], params["e_up"], params["e_down"]
-    held_n = e_gate.shape[0] if layer is None else e_gate.shape[1]
+    t, v = u.shape[0], u if rows is None else rows
+    d = v.shape[-1]
+    stacks = tuple(params[k] for k in ("e_gate", "e_up", "e_down") if params.get(k) is not None)  # two where no gate
+    held_n = stacks[0].shape[0] if layer is None else stacks[0].shape[1]
     with jax.named_scope("router"):
         weights, experts = rule(u, params["router"], params["router_bias"], top_k=top_k, scale=scale)
         alive = jnp.ones((t, 1), bool) if live is None else live[:, None]
@@ -297,7 +317,7 @@ def expert_layer(params: Dict[str, jax.Array], u: jax.Array, *, n_routed: int, t
         is_held = (local >= 0) & (local < held_n) & (experts < n_routed) & alive
     with jax.named_scope("zero"):
         # identity experts: the sum of their weights times the token, no matmul
-        y = (jnp.sum(jnp.where(is_zero, weights, 0.0), axis=-1, keepdims=True) * u.astype(jnp.float32))
+        y = (jnp.sum(jnp.where(is_zero, weights, 0.0), axis=-1, keepdims=True) * v.astype(jnp.float32))
     with jax.named_scope("experts"):
         # every (token, choice) pair is a row; the held ones sort to the front,
         # by expert, and the grouped matmuls see them a window at a time
@@ -312,20 +332,24 @@ def expert_layer(params: Dict[str, jax.Array], u: jax.Array, *, n_routed: int, t
         order = jnp.pad(jnp.argsort(group, stable=True).astype(jnp.int32), (0, -n_rows % window),
                         constant_values=n_rows)
         if layer is not None:
-            e_gate, e_up, e_down = (w.reshape(-1, *w.shape[2:]) for w in (e_gate, e_up, e_down))
+            stacks = tuple(w.reshape(-1, *w.shape[2:]) for w in stacks)
+        *e_in, e_down = stacks
 
         def walk(w, out):
-            """Window ``w``'s rows through the three grouped matmuls, each
-            result row written to its own (token, choice) place."""
+            """Window ``w``'s rows through the grouped matmuls, each result
+            row written to its own (token, choice) place."""
             at = w * window + jnp.arange(window, dtype=jnp.int32)
             # rows past the last held one go nowhere: each a place of its own past the end
             place = jnp.where(at < n_held, jax.lax.dynamic_slice(order, (w * window,), (window,)), n_rows + at)
-            rows = u[jnp.minimum(place, n_rows - 1) // top_k]
+            mine = v[jnp.minimum(place, n_rows - 1) // top_k]
             groups = jnp.clip(ends - w * window, 0, window) - jnp.clip(starts - w * window, 0, window)
             if layer is not None:
                 groups = jax.lax.dynamic_update_slice(
-                    jnp.zeros((e_gate.shape[0],), jnp.int32), groups, (layer * held_n,))
-            hidden = swiglu(grouped_matmul(rows, e_gate, groups), grouped_matmul(rows, e_up, groups))
+                    jnp.zeros((e_down.shape[0],), jnp.int32), groups, (layer * held_n,))
+            if len(e_in) == 2:
+                hidden = swiglu(*(grouped_matmul(mine, e, groups) for e in e_in))
+            else:
+                hidden = relu2(grouped_matmul(mine, e_in[0], groups, jnp.float32)).astype(mine.dtype)
             res = grouped_matmul(hidden, e_down, groups, jnp.float32)
             return out.at[place].set(res, mode="drop", unique_indices=True)
 

@@ -65,5 +65,11 @@ def swiglu(x_gate: jax.Array, x_up: jax.Array) -> jax.Array:
     return jax.nn.silu(x_gate) * x_up
 
 
+def relu2(x: jax.Array) -> jax.Array:
+    """relu(x)^2, the ungated activation of a two-matrix MLP: hand it the
+    product in float32 so that the square is taken ahead of the rounding."""
+    return jnp.square(jax.nn.relu(x))
+
+
 def gelu(x: jax.Array) -> jax.Array:
     return jax.nn.gelu(x, approximate=True)

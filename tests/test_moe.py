@@ -14,14 +14,17 @@ D, F = 16, 32
 RULES = {"softmax": moe.route, "sigmoid": moe.route_sigmoid, "topk_softmax": moe.route_topk_softmax}
 
 
-def plain(params, u, *, n_routed, top_k, scale, rule, expert_offset=0, live=None, layer=None):
+def plain(params, u, *, n_routed, top_k, scale, rule, expert_offset=0, live=None, layer=None, rows=None):
     """(y, counts without ``windows``): a token at a time, a choice at a time,
-    the parts added in the order of the choices."""
+    the parts added in the order of the choices. Without ``e_gate`` an expert is
+    ``e_down relu(e_up v)^2``; ``rows`` is what the experts read where it is not
+    what the router reads."""
     take = (lambda w: w) if layer is None else (lambda w: w[layer])
-    gate, up, down = (np.asarray(take(params[k]), np.float32) for k in ("e_gate", "e_up", "e_down"))
+    up, down = (np.asarray(take(params[k]), np.float32) for k in ("e_up", "e_down"))
+    gate = np.asarray(take(params["e_gate"]), np.float32) if params.get("e_gate") is not None else up
     routed = rule(u, params["router"], params["router_bias"], top_k=top_k, scale=scale)
     weights, chosen = (np.asarray(a) for a in routed)
-    rows = np.asarray(u, np.float32)
+    rows = np.asarray(u if rows is None else rows, np.float32)
     y, sizes, zero, absent = np.zeros_like(rows), np.zeros(len(gate), np.int64), 0, 0
     for t in range(len(rows)):
         if live is not None and not live[t]:
@@ -32,7 +35,10 @@ def plain(params, u, *, n_routed, top_k, scale, rule, expert_offset=0, live=None
             elif 0 <= e - expert_offset < len(gate):
                 e -= expert_offset
                 sizes[e] += 1
-                part = (np.asarray(jax.nn.silu(rows[t] @ gate[e])) * (rows[t] @ up[e])) @ down[e]
+                if gate is up:
+                    part = np.square(np.maximum(rows[t] @ up[e], 0.0)) @ down[e]
+                else:
+                    part = (np.asarray(jax.nn.silu(rows[t] @ gate[e])) * (rows[t] @ up[e])) @ down[e]
             else:
                 absent += 1
                 continue
@@ -118,6 +124,49 @@ def test_a_tokens_result_is_the_same_bit_for_bit_wherever_its_rows_fall(rule):
         np.testing.assert_array_equal(np.asarray(got), np.asarray(alone[0]))
 
 
+C = 8  # a latent half the width
+
+
+@pytest.mark.parametrize("latent,gated", [(False, False), (True, False), (True, True)],
+                         ids=["two_matrices", "two_matrices_latent_rows", "gated_latent_rows"])
+@pytest.mark.parametrize("case", ["two_windows", "every_expert_held", "offset_share", "masked_rows_spill", "stacked_layer", "no_held_row"])
+def test_two_matrix_experts_and_latent_rows_agree_with_a_plain_loop(case, latent, gated):
+    """What the caller hands in chooses the expert: without ``e_gate`` two
+    grouped calls a window, ``e_down relu(e_up v)^2``; with ``rows`` the experts
+    read and write the latent (held, C, F), (held, F, C) while the router reads
+    ``u``, the identity experts add ``w * v`` and the result is latent-wide.
+    Against the loop over tokens and choices, under the sigmoid rule, through
+    one window, several, none, a share from an offset, masked rows and a stack
+    of layers (the gated expert over the residual width is
+    ``test_the_walk_agrees_with_a_plain_loop``'s)."""
+    t, held, n_routed, n_outputs, top_k, favoured, shunned, extra, windows = CASES[case]
+    width = C if latent else D
+
+    def experts(seed):
+        k = jax.random.split(jax.random.PRNGKey(100 + seed), 3)
+        made = {"e_gate": jax.random.normal(k[0], (held, width, F)) * width ** -0.5,
+                "e_up": jax.random.normal(k[1], (held, width, F)) * width ** -0.5,
+                "e_down": jax.random.normal(k[2], (held, F, width)) * F ** -0.5}
+        return made if gated else {name: w for name, w in made.items() if name != "e_gate"}
+
+    params = {**{k: v for k, v in layer_params(held, n_outputs, favoured=favoured, shunned=shunned).items() if not k.startswith("e_")},
+              **experts(0)}
+    if "layer" in extra:
+        others = [experts(1), experts(2)]
+        for k in experts(0):
+            params[k] = jnp.stack([others[0][k], params[k], others[1][k]])
+    u = jax.random.normal(jax.random.PRNGKey(7), (t, D))
+    v = u @ (jax.random.normal(jax.random.PRNGKey(8), (D, C)) * D ** -0.5) if latent else None
+    kw = dict(n_routed=n_routed, top_k=top_k, scale=2.5, rule=moe.route_sigmoid, **extra)
+    y, counts = jax.jit(lambda a, b: moe.expert_layer(params, a, rows=b, **kw))(u, v)
+    want, want_counts = plain(params, u, rows=v, **kw)
+    assert y.shape == (t, width)
+    np.testing.assert_allclose(np.asarray(y), want, atol=2e-5)
+    assert dict(zip(moe.COUNTS, np.asarray(counts).tolist())) == dict(zip(moe.COUNTS, want_counts + [windows]))
+    if case == "no_held_row":  # the identity experts' part alone: the weights times what the experts would have read
+        assert want_counts[1] > 0 and np.abs(want).max() > 1e-3
+
+
 def test_the_third_rule_by_hand_a_softmax_over_the_chosen_logits():
     """Three tokens of one value through a "router" whose rows are their
     logits: the ``top_k`` largest are chosen and the weights are their softmax,
@@ -151,6 +200,9 @@ def test_the_third_rule_by_hand_a_softmax_over_the_chosen_logits():
     # tiles, so two tiles of 256; at 32 slots 320 rows likewise; its prefill buckets are whole tiles as they are
     (480, 36, 72, 512), (320, 36, 72, 512), (2560, 36, 72, 2560), (5120, 36, 72, 5120), (10240, 36, 72, 10240),
     (240, 36, 72, 240), (80, 36, 72, 80),  # under the ridge: every row, one tile
+    # Nemotron 3 Super's: 22 choices over 512 outputs, 128 held. A decode step's 1,056 rows (264 held of an even router)
+    # are a window of 512, two row tiles, of which the held rows pass the first; its prefill buckets walk windows of 512
+    (1056, 128, 512, 512), (704, 128, 512, 512), (5632, 128, 512, 512), (11264, 128, 512, 512), (22528, 128, 512, 512),
 ])
 def test_the_window_follows_the_held_rows_and_not_the_batch(n_rows, held, n_outputs, window):
     assert moe.window_rows(n_rows, held, n_outputs) == window
@@ -162,6 +214,7 @@ def test_the_window_follows_the_held_rows_and_not_the_batch(n_rows, held, n_outp
     (1024, 7168, 2048, jnp.bfloat16, True),  # past 512 rows in whole row tiles: every expert held, a prefill (PR 50)
     (640, 7168, 2048, jnp.bfloat16, False),  # past 512 rows and no whole row tiles
     (512, 2048, 1536, jnp.bfloat16, True), (512, 1536, 2048, jnp.bfloat16, True),  # LFM2's expert of 2048 x 1536: gate, down
+    (512, 1024, 2688, jnp.bfloat16, True), (512, 2688, 1024, jnp.bfloat16, True),  # Nemotron 3 Super's, in its latent: up, down
     (24, 7168, 2048, jnp.bfloat16, False),  # no whole sublane tiles
     (32, 7168, 2048, jnp.float32, False),  # the tests' float32 twins
     (32, 7000, 2048, jnp.bfloat16, False), (32, 2048, 1000, jnp.bfloat16, False),  # no whole weight tiles
@@ -207,6 +260,10 @@ TILINGS = {
     "exaone_down": (128, [9, 0, 30], (128, 2048, 512), (2048, 6144)),
     "exaone_gate_prefill": (512, [200, 0, 230], (256, 2048, 2048), (6144, 2048)),
     "exaone_down_prefill": (512, [200, 0, 230], (256, 2048, 2048), (2048, 6144)),
+    # Nemotron 3 Super's two matrices in its latent, a window of 512 under row tiles of 256 whose held rows pass the first
+    # by a few: up whole (5.5 MB), down in three tiles of its contraction of 2,688
+    "nemotron_up": (512, [120, 0, 100, 44], (256, 1024, 2688), (1024, 2688)),
+    "nemotron_down": (512, [120, 0, 100, 44], (256, 896, 1024), (2688, 1024)),
 }
 
 
@@ -287,6 +344,7 @@ WEIGHT_TILES = {
     "exaone": {(6144, 2048): ((512, 2048), (2048, 2048)), (2048, 6144): ((2048, 512), (2048, 2048))},
     "lfm2": {(2048, 1536): ((2048, 1536), (2048, 1536)), (1536, 2048): ((1536, 2048), (1536, 2048))},
     "granite": {(4096, 768): ((2048, 768), (2048, 768)), (768, 4096): ((768, 4096), (768, 4096))},
+    "nemotron": {(1024, 2688): ((1024, 2688), (1024, 2688)), (2688, 1024): ((896, 1024), (896, 1024))},
 }
 
 
@@ -300,7 +358,8 @@ def test_every_expert_matrix_gets_a_tile_of_whole_lanes_that_divides_it_and_the_
     any row tile (its ``e_down`` once fell to ``ragged_dot`` on 682 columns,
     then went by in four tiles of 512: runs of 1 KB at a stride of 4 KB), as do
     Granite's 6.3 MB: ``e_down`` whole, ``e_gate`` in two halves of its
-    contraction of 4,096. The contraction is cut only where it is past
+    contraction of 4,096, and Nemotron 3 Super's 5.5 MB: ``e_up`` whole,
+    ``e_down`` in three tiles of its contraction of 2,688. The contraction is cut only where it is past
     ``_WHOLE_K``. At every row tile a
     window reaches, the call's buffers fit the fast memory it states."""
     from ray_tpu.ops.grouped_matmul import VMEM_BUDGET, vmem_bytes
@@ -309,8 +368,8 @@ def test_every_expert_matrix_gets_a_tile_of_whole_lanes_that_divides_it_and_the_
         tk, tn = moe._weight_tile(k, n, rows)
         assert (tk, tn) == want
         assert tk % 128 == 0 and tn % 128 == 0 and n % tn == 0 and k % tk == 0
-        fits = k * n <= moe._WEIGHT_TILE  # LFM2's and Granite's: a matrix that fits a tile gets the larger tile at any row tile
-        assert fits == (kind in ("lfm2", "granite"))
+        fits = k * n <= moe._WEIGHT_TILE  # LFM2's, Granite's and Nemotron's: a matrix that fits a tile gets the larger tile at any row tile
+        assert fits == (kind in ("lfm2", "granite", "nemotron"))
         assert tk * tn <= (moe._WEIGHT_TILE if rows == moe.ROW_TILE or fits else 1 << 20)
         assert (k <= moe._WHOLE_K) == (tk == k) and ((tk, tn) == (k, n)) == (fits and k <= moe._WHOLE_K)
         assert 2 * tk * tn * 2 < vmem_bytes((rows, tk, tn), 2, 4) <= 32 << 20 < VMEM_BUDGET
@@ -324,6 +383,7 @@ REACHED = {
     "every_expert_held": (3, 4, 8, {48: (144, 144)}),
     "lfm2": (4, 64, 64, {48: (192, 192), 128: (512, 256), 256: (1024, 256), 512: (2048, 256), 1024: (4096, 256)}),
     "granite": (10, 36, 72, {48: (512, 256), 256: (2560, 256), 512: (5120, 256), 1024: (10240, 256)}),
+    "nemotron": (22, 128, 512, {48: (512, 256), 256: (512, 256), 512: (512, 256), 1024: (512, 256)}),
 }
 
 

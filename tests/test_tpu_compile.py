@@ -1006,6 +1006,108 @@ def test_granite_hybrid_decode_and_prefill_at_published_widths(one_chip, monkeyp
     assert mem.temp_size_in_bytes < 1.0e9
 
 
+def test_nemotron_h_decode_and_prefill_at_published_widths(one_chip, monkeypatch):
+    """serve.llm's programs for Nemotron 3 Super as the benchmark's
+    configuration cuts it (``benchmarks/configs/nemotron-3-super-120b-11l.json``):
+    the published widths, layers 0-10 (``MEMEMEM*EME``) as one section of eleven
+    layers a call, each a norm and one part, experts 0-127 of every expert
+    layer's 512 in a 1,024-wide latent, rows 0-32,767 of the embedding and of
+    the head, the engine's 48 slots over 1,585 blocks of 64 positions of the
+    one attention layer and 49 state rows of five (128, 8192) float32 states.
+    The file's arithmetic against the compiler: 9.30 GB of weights, 1.05 GB of
+    state rows and a 0.10 GB K/V pool are the programs' arguments, 10.45 GB,
+    and the pool comes back in place. The decode step's body holds the state's
+    update five times (eight B/C groups of eight lane tiles each), the paged
+    kernel once (two K/V heads: a block is 128 rows of 128) and **two** grouped
+    matmuls an expert layer in the grouped kernel: the step's 1,056 rows as a
+    window of 512 under two row tiles of 256 over a stack of 640 groups, ``e_up``
+    (1,024 x 2,688) whole and ``e_down`` in three tiles of its contraction, the
+    hidden rows out of the first in float32 for the square; no ``ragged-dot``,
+    no conditional, no pool, state or expert stack copied. A prefill of 1,024
+    holds the flash kernel once, ``ssd_prefill`` five times and the same two
+    grouped calls a layer inside the walk over eleven windows of 512."""
+    import json
+    import re
+
+    from benchmarks.families import nemotron_h as family
+    from ray_tpu.models import nemotron_h as M, paged
+    from ray_tpu.serve.llm.deployment import _resolve_model_cfg
+
+    _steered_to_tpu(monkeypatch)
+    stated = _stated_tilings(monkeypatch)
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks", "configs", "nemotron-3-super-120b-11l.json")) as f:
+        config = json.load(f)
+    cfg = _resolve_model_cfg(family.model_kwargs(config))
+    e = config["engine"]
+    block, blocks, batch, per_seq = e["block_size"], e["num_blocks"], e["max_batch"], e["max_blocks_per_seq"]
+    prefill, _, decode_greedy = paged.make_paged_fns(M.paged_layer, cfg, block_size=block, state_rows=True)
+    params = _on(one_chip, jax.eval_shape(lambda: M.init_params(jax.random.PRNGKey(0), cfg)))
+    pool = _on(one_chip, jax.eval_shape(lambda: M.init_paged_pool(cfg, blocks, block, batch + 1)))
+
+    def nbytes(tree):
+        return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
+
+    assert 9.29e9 < nbytes(params) < 9.31e9 and params["unembed"].shape == (4096, 32768) and "e_gate" not in params
+    assert 0.10e9 < nbytes(pool["kv"]) == blocks * M.paged_block_bytes(cfg, block) < 0.11e9
+    rows = sum(nbytes(pool[name]) for name in ("state", "conv", "state_pos"))
+    assert 1.04e9 < rows == (batch + 1) * M.paged_state_bytes(cfg) < 1.05e9
+    flat, state = f"{blocks * block * 2},128", "49,128,8192"
+    assert pool["kv"].shape == (1, 2, blocks * block * 2, 128) and pool["state"].shape == (5, 49, 128, 8192)
+    assert params["e_up"].shape == (5, 128, 1024, 2688) and params["e_down"].shape == (5, 128, 2688, 1024)
+    assert params["ssm_in"].shape == (5, 4096, 18560) and params["wqkv"].shape == (1, 4096, 4608) and params["norm"].shape == (11, 4096)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def pools_copied(text):
+        """Instructions of their own that make a pool, or a layer of one, anew."""
+        pools = {f"{lead}{dims}" for dims in (flat, state, "49,40960") for lead in ("", "1,", "5,", "2,", "1,2,")}
+        return [(dims, op) for dims, _, op in _alone(text)
+                if dims in pools and op in ("copy", "transpose", "gather", "dynamic-slice")]
+
+    def pool_writes(text):
+        return re.findall(rf"= bf16\[1,2,{flat}\]\S* (?:dynamic-update-slice|scatter)\(", text)
+
+    def state_writes(text):
+        return re.findall(rf"= f32\[5,{state}\]\S* (?:dynamic-update-slice|scatter)\(", text)
+
+    def gmm_shapes(text):
+        calls = [line for line in text.splitlines() if re.search(r"%gmm[.\d]* = ", line)]
+        return sorted(re.search(r"= (\w+\[\d+,\d+\])", line).group(1) for line in calls)
+
+    compiled = decode_greedy.lower(
+        params, arg((batch,), jnp.int32), arg((batch,), jnp.int32), arg((batch, per_seq), jnp.int32), pool,
+        arg((batch,), jnp.bool_),
+    ).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    kernels = _kernels(text)
+    # the body holds its eleven layers' kernels once: five updates, one paged attention, two grouped matmuls an expert layer
+    assert (kernels.count("selective_scan_update"), kernels.count("paged_decode_attention"), kernels.count("gmm")) == (5, 1, 10)
+    assert gmm_shapes(text) == ["f32[512,1024]"] * 5 + ["f32[512,2688]"] * 5  # up's hidden rows in float32, for the square
+    assert "ragged-dot" not in text and " conditional(" not in text
+    assert stated == {(256, 1024, 2688), (256, 896, 1024)}  # up whole; down: two row tiles of the 1,056 rows' window of 512
+    stated.clear()
+    _nothing_is_copied_for_the_grouped_matmuls(text)
+    assert not pools_copied(text) and not pool_writes(text) and not state_writes(text)
+    assert "paged_scatter" not in text and "paged_gather" not in text
+    assert 10.44e9 < mem.argument_size_in_bytes < 10.46e9 and mem.temp_size_in_bytes < 0.1e9
+    assert mem.alias_size_in_bytes > 0.999 * nbytes(pool)  # the pool comes back in place
+    compiled = prefill.lower(
+        params, arg((1, 1024), jnp.int32), arg((1, per_seq), jnp.int32), pool, arg((), jnp.int32)).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    kernels = _kernels(text)
+    assert (kernels.count("flash_attention"), kernels.count("gmm"), kernels.count("ssd_prefill")) == (1, 10, 5)
+    assert gmm_shapes(text) == ["f32[512,1024]"] * 5 + ["f32[512,2688]"] * 5  # a window of the eleven a layer walks
+    assert "selective_scan" not in " ".join(kernels) and "paged_decode_attention" not in kernels
+    assert stated == {(256, 1024, 2688), (256, 896, 1024)}
+    _nothing_is_copied_for_the_grouped_matmuls(text, but_the_metadata=True)
+    assert "ragged-dot" not in text
+    assert len(pool_writes(text)) == 2 and "paged_scatter" in text  # a prompt's blocks, K and V, the one attention layer
+    assert len(state_writes(text)) == 5  # the prompt's last state into its row, a mixer layer
+    assert not pools_copied(text)
+    assert mem.temp_size_in_bytes < 0.5e9
+
+
 @pytest.mark.parametrize("s", [256, 512, 1024])
 @pytest.mark.parametrize("heads, p, groups, n", [(128, 64, 1, 128), (32, 128, 2, 256)], ids=["granite", "falcon-h1"])
 def test_the_prompts_ssd_kernel_at_the_published_head_shapes(one_chip, monkeypatch, heads, p, groups, n, s):
