@@ -41,7 +41,11 @@ expert has one window of every row. Inside a window the kernel multiplies a
 touched expert by row tiles of at most ``ROW_TILE`` rows: a decode step's
 window is one tile, under which every touched expert's weights are streamed
 once; a prefill's window of 512 rows is two, so that an expert is multiplied
-by the tile its rows lie in and not by the whole window.
+by the tile its rows lie in and not by the whole window. The results stay in
+the sorted order the grouped matmul wrote them in, and a token sums the rows
+at its choices' ranks in the sort (``combine``): no buffer of every (token,
+choice) place is zeroed, scattered into, laid anew and read back (PERF.md
+section 6, PR 58).
 """
 
 from __future__ import annotations
@@ -50,7 +54,7 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from ray_tpu.ops.grouped_matmul import VMEM_BUDGET, gmm as _gmm, vmem_bytes
+from ray_tpu.ops.grouped_matmul import VMEM_BUDGET, gmm as _gmm, unwritten, vmem_bytes
 from ray_tpu.ops.layers import relu2, swiglu
 
 # what ``expert_layer`` counts of its routing, in this order: (token, choice)
@@ -159,8 +163,8 @@ def window_rows(n_rows: int, held: int, n_outputs: int) -> int:
     eighth of a long prefill's rows and walks them 512 at a time (PERF.md
     section 6, PR 43). A layer that holds every expert has one window of every
     row, a prefill's 4,096 too: one call under ``ROW_TILE`` tiles visits the
-    same (tile, expert) pairs as eight windows would and gathers, sorts and
-    scatters once (PERF.md section 6, PR 50). **Every row, past the ridge and no
+    same (tile, expert) pairs as eight windows would and sorts and gathers
+    once (PERF.md section 6, PR 50). **Every row, past the ridge and no
     whole tiles** (Granite's decode step: 48 tokens x 10 choices = 480, half of
     them absent experts' and sorted to the back), is rounded up to whole tiles
     of ``ROW_TILE``: as one tile of 480 every touched expert was multiplied by
@@ -273,6 +277,49 @@ def grouped_matmul(rows, experts, groups, out_type=None):
     return jax.lax.ragged_dot(rows, experts, groups, preferred_element_type=out_type)
 
 
+# A prompt's combine, past this many bytes of gathered rows (every (token,
+# choice) row, float32), walks the tokens' held choices in a loop; under it, a
+# decode step's and the small buckets', one gather brings them all, and they
+# stay in fast memory (K x T x d x 4: Granite's 256 bucket 42 MB, Kimi-K2's
+# 58, LFM2's 1,024 bucket 34; PERF.md section 6, PR 58)
+_GATHERED_BYTES = 64 << 20
+
+
+def combine(results, rank, is_held, weights):
+    """A token's weighted sum of its held choices' result rows, float32:
+    ``results`` (R, d) in the sorted order the grouped matmuls wrote them,
+    ``rank`` (T, K) the row of each (token, choice), ``is_held`` and
+    ``weights`` (T, K) -> (T, d). The parts add in the order of the choices,
+    whoever else is in the batch; a choice no held expert owns adds exactly
+    zero, whatever lies at its rank (a row nobody wrote).
+
+    Two walks of the same sum, chosen by static shape. Few rows (a decode
+    step, a small bucket): one gather of every (choice, token) row,
+    choice-major, and the sum down its leading axis: two operations, whose rows
+    stay in fast memory. Many: the j-th held choice of every token in trip j of
+    a loop that ends with the token that has the most, so that a chip that
+    holds a thirty-second of the experts (a token's held choices: a quarter of
+    one in the mean, three at the most) gathers three rows a token and not
+    eight. A gather moves a row a copy (~30-80 ns a row of 16 KB on a v5e)
+    whether the row is held or not, which is what the loop is for."""
+    (t, top_k), d = rank.shape, results.shape[1]
+    if top_k * t * d * 4 <= _GATHERED_BYTES:
+        rows = results[rank.T.reshape(-1)].reshape(top_k, t, d)
+        parts = jnp.where(is_held.T[..., None], weights.T[..., None] * rows, 0.0)
+        total = parts[0]
+        for k in range(1, top_k):
+            total = total + parts[k]
+        return total
+    nth = jnp.cumsum(is_held, axis=1) - 1  # a held choice's number among its token's held choices
+
+    def trip(j, total):
+        mine = is_held & (nth == j)  # one choice a token at the most
+        row, w = (jnp.sum(jnp.where(mine, x, 0), axis=1) for x in (rank, weights))
+        return total + jnp.where(jnp.any(mine, axis=1)[:, None], w[:, None] * results[row], 0.0)
+
+    return jax.lax.fori_loop(0, jnp.max(jnp.sum(is_held, axis=1)), trip, jnp.zeros((t, d), jnp.float32))
+
+
 def expert_layer(params: Dict[str, jax.Array], u: jax.Array, *, n_routed: int, top_k: int, scale: float,
                  expert_offset: int = 0, live: Optional[jax.Array] = None, layer=None, rule=route,
                  rows: Optional[jax.Array] = None):
@@ -296,9 +343,12 @@ def expert_layer(params: Dict[str, jax.Array], u: jax.Array, *, n_routed: int, t
     ``w * v``, and ``y`` is (T, C), the weighted sum the caller projects back.
 
     The held rows go through the grouped matmuls ``window_rows`` at a
-    time, in sorted order, and each float32 result row is written to its own
-    (token, choice) place: a token's result depends neither on who shares its
-    batch nor on the window or the place in it that its rows fell to.
+    time, in sorted order, and their float32 result rows stay in that order:
+    one window of every row leaves them where the grouped matmul wrote them,
+    windows that are walked write theirs one after another. A token then adds
+    the rows at its choices' ranks in the sort (``combine``), in the order of
+    its choices: its result depends neither on who shares its batch nor on
+    the window or the row of it that its rows fell to.
 
     ``layer``: the expert tensors are all layers' stacked, (layers, held, ..),
     and this is the layer to use (it may be traced). The grouped matmul then
@@ -328,20 +378,23 @@ def expert_layer(params: Dict[str, jax.Array], u: jax.Array, *, n_routed: int, t
         ends = jnp.cumsum(sizes)
         starts, n_held = ends - sizes, ends[-1]
         n_windows = (n_held + window - 1) // window
-        # padded to whole windows with places past the result's end
-        order = jnp.pad(jnp.argsort(group, stable=True).astype(jnp.int32), (0, -n_rows % window),
-                        constant_values=n_rows)
+        order = jnp.argsort(group, stable=True).astype(jnp.int32)
+        # where each (token, choice) row fell in the sort: the inverse of ``order`` (a second sort: a scatter of
+        # 22,528 integers writes them one at a time, four times a sort's time on a v5e)
+        rank = jnp.argsort(order).astype(jnp.int32).reshape(t, top_k)
+        order = jnp.pad(order, (0, -n_rows % window))  # to whole windows
         if layer is not None:
             stacks = tuple(w.reshape(-1, *w.shape[2:]) for w in stacks)
         *e_in, e_down = stacks
 
-        def walk(w, out):
-            """Window ``w``'s rows through the grouped matmuls, each result
-            row written to its own (token, choice) place."""
+        def walk(w, results):
+            """Window ``w``'s rows through the grouped matmuls; their float32
+            results, in the sorted order, to the window's rows of ``results``
+            (None: the window is the whole sort, and they are the results)."""
             at = w * window + jnp.arange(window, dtype=jnp.int32)
-            # rows past the last held one go nowhere: each a place of its own past the end
-            place = jnp.where(at < n_held, jax.lax.dynamic_slice(order, (w * window,), (window,)), n_rows + at)
-            mine = v[jnp.minimum(place, n_rows - 1) // top_k]
+            # rows past the last held one are no token's: each reads the last token, and its result is never read
+            row = jnp.where(at < n_held, jax.lax.dynamic_slice(order, (w * window,), (window,)), n_rows - 1)
+            mine = v[row // top_k]
             groups = jnp.clip(ends - w * window, 0, window) - jnp.clip(starts - w * window, 0, window)
             if layer is not None:
                 groups = jax.lax.dynamic_update_slice(
@@ -351,14 +404,16 @@ def expert_layer(params: Dict[str, jax.Array], u: jax.Array, *, n_routed: int, t
             else:
                 hidden = relu2(grouped_matmul(mine, e_in[0], groups, jnp.float32)).astype(mine.dtype)
             res = grouped_matmul(hidden, e_down, groups, jnp.float32)
-            return out.at[place].set(res, mode="drop", unique_indices=True)
+            return res if results is None else jax.lax.dynamic_update_slice(results, res, (w * window, 0))
 
-        out = jnp.zeros((n_rows, d), jnp.float32)
-        # a window that takes every row (or more: whole row tiles) is the whole walk, and is traced without a loop
-        out = walk(0, out) if window >= n_rows else jax.lax.fori_loop(0, n_windows, walk, out)
-        # a token's parts add in the order of its choices, whoever else is in
-        # the batch (a row no held expert owns was never written and adds zero)
-        y = y + jnp.sum(out.reshape(t, top_k, d) * weights[..., None], axis=1)
+        # a window that takes every row (or more: whole row tiles) is the whole walk, is traced without a loop and
+        # leaves its results where the grouped matmul wrote them; windows that are walked write theirs one after
+        # another, each one contiguous copy, into rows nobody has written (no window: none is ever read back)
+        if window >= n_rows:
+            results = walk(0, None)
+        else:
+            results = jax.lax.fori_loop(0, n_windows, walk, unwritten((order.shape[0], d), jnp.float32))
+        y = y + combine(results, rank, is_held, weights)
     counts = jnp.stack([
         jnp.sum(is_held), jnp.sum(is_zero), jnp.sum(alive & ~is_held & ~is_zero), jnp.sum(sizes > 0),
         jnp.max(sizes), n_windows,

@@ -41,6 +41,20 @@ def vmem_bytes(tiling, itemsize: int, out_itemsize: int) -> int:
     return 2 * (tk * tn + tm * tk) * itemsize + 2 * tm * tn * out_itemsize + 2 * tm * tn * 4 + _COMPILER_SCRATCH
 
 
+def unwritten(shape, dtype):
+    """An array nobody has written, for a caller that writes what it will read
+    (the windows' results, ``models/moe.py``): on a TPU a kernel call with no
+    body, whose result is the memory as the allocator hands it out (whatever
+    was there: mask what was not written, never multiply it by zero), where
+    ``jnp.zeros`` writes every byte first (a layer's 117 MB at Kimi-K2's 512
+    bucket, 0.17 ms at the chip's bandwidth; PERF.md section 6, PR 58).
+    Elsewhere zeros."""
+    if jax.default_backend() != "tpu":
+        return jnp.zeros(shape, dtype)
+    return pl.pallas_call(lambda out: None, out_shape=jax.ShapeDtypeStruct(shape, dtype),
+                          out_specs=pl.BlockSpec(memory_space=pl.ANY), name="unwritten")()
+
+
 @functools.partial(jax.jit, static_argnames=("preferred_element_type", "tiling", "interpret"))
 def gmm(lhs, rhs, group_sizes, *, preferred_element_type, tiling, interpret: bool = False):
     """``lhs`` (m, k) times ``rhs`` (groups, k, n) by ``group_sizes`` (groups,)

@@ -55,7 +55,21 @@ def layer_params(held, n_outputs, *, favoured=(), shunned=(), seed=0):
     return {**params, "router_bias": params["router_bias"] + bias}
 
 
-# name: (tokens, held, routed, outputs, top_k, favoured, shunned, extra arguments, windows walked)
+C = 8  # a latent half the width
+
+
+def latent_experts(held, width, *, gated, seed=0):
+    """Seeded float32 experts (held, width, F), (held, F, width): three
+    matrices where ``gated``, else ``e_up`` and ``e_down`` alone."""
+    k = jax.random.split(jax.random.PRNGKey(100 + seed), 3)
+    made = {"e_gate": jax.random.normal(k[0], (held, width, F)) * width ** -0.5,
+            "e_up": jax.random.normal(k[1], (held, width, F)) * width ** -0.5,
+            "e_down": jax.random.normal(k[2], (held, F, width)) * F ** -0.5}
+    return made if gated else {name: w for name, w in made.items() if name != "e_gate"}
+
+
+# name: (tokens, held, routed, outputs, top_k, favoured, shunned, extra arguments (``latent``: two-matrix experts that
+# read ``rows=`` in a latent of ``C``), windows walked)
 CASES = {
     # 128 rows, 8 expected of an even router: a window of 32 and room to spare
     "one_window": (64, 4, 56, 64, 2, (), (), {}, 1),
@@ -74,19 +88,36 @@ CASES = {
     "masked_rows_spill": (90, 4, 56, 64, 2, (0,), (), {"live": np.arange(90) % 2 == 0}, 2),
     # the experts of three layers stacked, the second one's used
     "stacked_layer": (40, 4, 56, 64, 2, (2,), (), {"layer": 1}, 2),
+    # Granite's: half the outputs held, ten choices a token, no identity expert; 480 rows, past the ridge and no whole
+    # row tiles, are one window of 512 whose results are summed from where the grouped matmul left them
+    "granite_like": (48, 36, 72, 72, 10, (), (), {}, 1),
+    # Nemotron's: 22 choices a token, a quarter of the outputs held, experts of two matrices in a latent (``rows=``);
+    # fifteen of the sixteen held experts in every token's choice: 1,584 rows walked in three windows of 512
+    "nemotron_like": (72, 16, 64, 64, 22, tuple(range(15)), (), {"latent": True}, 3),
 }
 
 
-@pytest.mark.parametrize("rule", list(RULES))
-@pytest.mark.parametrize("case", list(CASES))
-def test_the_walk_agrees_with_a_plain_loop(case, rule):
-    t, held, n_routed, n_outputs, top_k, favoured, shunned, extra, windows = CASES[case]
-    params = layer_params(held, n_outputs, favoured=favoured, shunned=shunned)
+def case_inputs(case):
+    """(params, tokens, the layer's extra arguments) of a case: its choice
+    bias, its stack of three layers, its latent rows and two-matrix experts."""
+    t, held, _, n_outputs, _, favoured, shunned, extra, _ = CASES[case]
+    params, extra = layer_params(held, n_outputs, favoured=favoured, shunned=shunned), dict(extra)
     if "layer" in extra:
         others = [layer_params(held, n_outputs, seed=s) for s in (1, 2)]
         for k in ("e_gate", "e_up", "e_down"):
             params[k] = jnp.stack([others[0][k], params[k], others[1][k]])
     u = jax.random.normal(jax.random.PRNGKey(7), (t, D))
+    if extra.pop("latent", False):
+        params = {**{k: w for k, w in params.items() if not k.startswith("e_")}, **latent_experts(held, C, gated=False)}
+        extra["rows"] = u @ (jax.random.normal(jax.random.PRNGKey(8), (D, C)) * D ** -0.5)
+    return params, u, extra
+
+
+@pytest.mark.parametrize("rule", list(RULES))
+@pytest.mark.parametrize("case", list(CASES))
+def test_the_walk_agrees_with_a_plain_loop(case, rule):
+    t, held, n_routed, n_outputs, top_k, favoured, shunned, _, windows = CASES[case]
+    params, u, extra = case_inputs(case)
     kw = dict(n_routed=n_routed, top_k=top_k, scale=2.5, rule=RULES[rule], **extra)
     y, counts = jax.jit(lambda rows: moe.expert_layer(params, rows, **kw))(u)
     want, want_counts = plain(params, u, **kw)
@@ -124,7 +155,97 @@ def test_a_tokens_result_is_the_same_bit_for_bit_wherever_its_rows_fall(rule):
         np.testing.assert_array_equal(np.asarray(got), np.asarray(alone[0]))
 
 
-C = 8  # a latent half the width
+@pytest.mark.parametrize("rule", list(RULES))
+def test_at_ten_choices_a_token_the_result_is_the_same_bits_alone_and_across_two_windows(rule):
+    """The same at a ``top_k`` that is no multiple of 8 (Granite's ten). Alone
+    its ten rows are the window; among 19 others the 60 held rows and a few
+    more walk three windows of 32; first or last of 60 tokens that all choose held experts 0, 1 and 2, 180
+    held rows and a few more in two windows of 128, its row for expert 2 is the
+    first window's 121st or the second's 52nd. The tokens and the router are
+    in eighths, so that a logit is exact in whatever order its sixteen products
+    add: the CPU multiplies one row by another routine than sixty, and of ten
+    chosen weights one then differs in its last bit before the layer has begun."""
+    eighths = lambda x: jnp.round(x * 8) / 8
+    params = layer_params(4, 64, favoured=(0, 1, 2))
+    params["router"] = eighths(params["router"])
+    kw = dict(n_routed=56, top_k=10, scale=2.5, rule=RULES[rule])
+    layer = jax.jit(lambda rows: moe.expert_layer(params, rows, **kw))
+    token = eighths(jax.random.normal(jax.random.PRNGKey(11), (1, D)))
+    others = eighths(jax.random.normal(jax.random.PRNGKey(12), (59, D)))
+    alone, counts = layer(token)
+    assert np.asarray(counts).tolist()[0] >= 3 and np.asarray(counts).tolist()[-1] == 1 and np.any(np.asarray(alone) != 0)
+    among, counts = layer(jnp.concatenate([others[:7], token, others[7:19]]))
+    assert np.asarray(counts).tolist()[-1] == 3
+    first, counts_first = layer(jnp.concatenate([token, others]))
+    last, counts_last = layer(jnp.concatenate([others, token]))
+    assert np.asarray(counts_first).tolist() == np.asarray(counts_last).tolist() and np.asarray(counts_last)[-1] == 2
+    assert moe.window_rows(600, 4, 64) == 128 and 180 <= np.asarray(counts_last)[0] <= 256
+    for got in (among[7], first[0], last[-1]):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(alone[0]))
+
+
+def _values(jaxpr):
+    """Every (equation, value it makes or reads) of a jaxpr and of the jaxprs its equations hold."""
+    for eqn in jaxpr.eqns:
+        for var in (*eqn.invars, *eqn.outvars):
+            yield eqn, var
+        for param in eqn.params.values():
+            for inner in (param if isinstance(param, (tuple, list)) else (param,)):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    yield from _values(inner)
+
+
+@pytest.mark.parametrize("case", ["granite_like", "nemotron_like"])
+def test_no_buffer_of_every_place_is_made_scattered_into_or_laid_anew(case):
+    """The traced layer at one window of every row and at several windows
+    walked: no value of it is (tokens, choices, width) and no scatter writes a
+    float32 (tokens x choices, width) operand. A token's result rows are read
+    from the sorted order the grouped matmuls wrote them in (PR 58): the buffer
+    of every (token, choice) place, zeroed, scattered into and re-laid before
+    the sum, cannot come back unnoticed."""
+    t, held, n_routed, _, top_k, *_ = CASES[case]
+    params, u, extra = case_inputs(case)
+    width = extra["rows"].shape[1] if "rows" in extra else D
+    traced = jax.make_jaxpr(lambda a, b: moe.expert_layer(params, a, rows=b, n_routed=n_routed, top_k=top_k, scale=1.0))(u, extra.get("rows"))
+    seen = [(eqn.primitive.name, tuple(var.aval.shape), str(var.aval.dtype)) for eqn, var in _values(traced.jaxpr)
+            if hasattr(var, "aval") and hasattr(var.aval, "shape")]
+    assert len(seen) > 100 and any(name.startswith("ragged_dot") for name, _, _ in seen)
+    # however the width is laid
+    assert not [s for s in seen if s[1][:2] == (t, top_k) and np.prod(s[1]) == t * top_k * width]
+    scatters = [eqn for eqn, _ in _values(traced.jaxpr) if eqn.primitive.name.startswith("scatter")]
+    operands = {tuple(eqn.invars[0].aval.shape) for eqn in scatters}
+    assert not [shape for shape in operands if np.prod(shape) >= t * top_k * width]
+    assert operands == {(held + 1,)}  # the bincount's alone
+
+
+@pytest.mark.parametrize("rule", list(RULES))
+@pytest.mark.parametrize("case", ["granite_like", "nemotron_like", "two_windows", "no_held_row", "masked_rows_spill"])
+def test_a_prompts_walk_of_the_held_choices_gives_the_decode_steps_sum(monkeypatch, case, rule):
+    """``moe.combine`` past ``_GATHERED_BYTES`` (here: always) walks every
+    token's j-th held choice in trip j, as many trips as the token with the
+    most held choices has; under it, one gather of every row. The same parts in the
+    same order: against the plain loop, the counts, and the other walk's bits
+    (the rule's weights rounded to powers of two: a weight times a row is then
+    exact, and the bits do not depend on whether the CPU's compiler fuses a
+    product into the add that follows it, as it does in one walk and not the
+    other)."""
+    def powers_of_two(*args, **kwargs):
+        weights, experts = RULES[rule](*args, **kwargs)
+        return 2.0 ** jnp.round(jnp.log2(weights)), experts
+
+    _, _, n_routed, _, top_k, *_, windows = CASES[case]
+    params, u, extra = case_inputs(case)
+    kw = dict(n_routed=n_routed, top_k=top_k, scale=2.5, rule=powers_of_two, **extra)
+    one_gather, _ = jax.jit(lambda rows: moe.expert_layer(params, rows, **kw))(u)
+    monkeypatch.setattr(moe, "_GATHERED_BYTES", 0)
+    traced = str(jax.make_jaxpr(lambda rows: moe.expert_layer(params, rows, **kw))(u))
+    assert traced.count("gather[") == 3 and "while[" in traced  # the loop's one, the window's rows', the router's
+    y, counts = jax.jit(lambda rows: moe.expert_layer(params, rows, **kw))(u)
+    want, want_counts = plain(params, u, **kw)
+    np.testing.assert_allclose(np.asarray(y), want, atol=2e-5)
+    assert np.asarray(counts).tolist() == want_counts + [windows]
+    np.testing.assert_array_equal(np.asarray(y), np.asarray(one_gather))
 
 
 @pytest.mark.parametrize("latent,gated", [(False, False), (True, False), (True, True)],
@@ -143,11 +264,7 @@ def test_two_matrix_experts_and_latent_rows_agree_with_a_plain_loop(case, latent
     width = C if latent else D
 
     def experts(seed):
-        k = jax.random.split(jax.random.PRNGKey(100 + seed), 3)
-        made = {"e_gate": jax.random.normal(k[0], (held, width, F)) * width ** -0.5,
-                "e_up": jax.random.normal(k[1], (held, width, F)) * width ** -0.5,
-                "e_down": jax.random.normal(k[2], (held, F, width)) * F ** -0.5}
-        return made if gated else {name: w for name, w in made.items() if name != "e_gate"}
+        return latent_experts(held, width, gated=gated, seed=seed)
 
     params = {**{k: v for k, v in layer_params(held, n_outputs, favoured=favoured, shunned=shunned).items() if not k.startswith("e_")},
               **experts(0)}
