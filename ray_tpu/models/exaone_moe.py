@@ -44,16 +44,11 @@ layers' own tensors over those layers, from their first layer on.
 counts:
 
 * ``kv`` (full layers, 2, slots x G, d): the **full** layers' rows a
-  position, the keys in plane 0 and the values in plane 1 of one array
-  (``paged_decode_attention`` brings a block's keys and values in under one
-  copy), each plane *flat* (a slot's G heads are G consecutive rows: eight heads are no
-  whole sublane tile of bfloat16, and ``ops/paged_attention.py`` takes a flat
-  pool of any head count whose block is whole tiles). Full layer ``i`` is the
+  position in the flat pool (``models/flat_kv.py``: its format, how a call's
+  rows are written and read back, which kernel scores them), a K/V head a row
+  (eight heads are no whole sublane tile of bfloat16). Full layer ``i`` is the
   pool's layer ``i // 4``. A block holds the full layers' rows alone
-  (``paged_block_bytes``): a quarter of what every layer's would cost. On a
-  TPU a decode step's own row is written by ``paged_decode_attention``, into
-  the blocks it scores; elsewhere, and in every prefill, rows are scattered
-  (``write_spans``) and a decode step gathers its table's.
+  (``paged_block_bytes``): a quarter of what every layer's would cost.
 * ``ring_k``, ``ring_v`` (window layers, state rows, W x G, d): a **ring** of
   the last W positions' rows a sequence a window layer, in the sequence's state
   row (``models/phi4flash.py`` says how a state row is handed out). Window
@@ -72,16 +67,15 @@ A prefill starts from empty rings: no chunked prefill, no prefix reuse.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, Optional, Tuple
+import functools
+from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models import moe
+from ray_tpu.models import flat_kv, moe, paged
 from ray_tpu.models.moe import routing_counts  # noqa: F401 - the engine asks the kind's module for it
-from ray_tpu.ops.attention import attention as causal_attention
-from ray_tpu.ops.layers import apply_rope, rms_norm, swiglu
-from ray_tpu.ops.paged_attention import can_use_paged_kernel, paged_decode_attention
+from ray_tpu.ops.layers import apply_rope, rms_norm, rope_tables, swiglu
 from ray_tpu.ops.window_attention import (can_use_ring_kernel, ring_window_attention, window_attention_prefill,
                                           window_attention_rows, write_spans)
 
@@ -227,10 +221,9 @@ def init_paged_pool(cfg: ExaoneMoeConfig, num_blocks: int, block_size: int, stat
     ``state_rows`` counts the null row: the engine asks for ``max_batch + 1``."""
     cfg.served()
     G, d = cfg.num_key_value_heads, cfg.head_dim
-    flat = (cfg.n_full, 2, num_blocks * block_size * G, d)  # keys in plane 0, values in plane 1
     ring = (cfg.n_window, state_rows, cfg.sliding_window * G, d)
     return {
-        "kv": jnp.zeros(flat, cfg.dtype),
+        "kv": flat_kv.init_pool(cfg.n_full, num_blocks, block_size, G, d, cfg.dtype),
         "ring_k": jnp.zeros(ring, cfg.dtype), "ring_v": jnp.zeros(ring, cfg.dtype),
         "moe_counts": jnp.zeros((len(moe.COUNTS),), jnp.uint32),
     }
@@ -239,7 +232,7 @@ def init_paged_pool(cfg: ExaoneMoeConfig, num_blocks: int, block_size: int, stat
 def paged_block_bytes(cfg: ExaoneMoeConfig, block_size: int) -> int:
     """Bytes one block of the pool holds: K and V rows of the full layers
     alone (the window layers' live in the state row)."""
-    return 2 * cfg.n_full * block_size * cfg.kv_row * jnp.dtype(cfg.dtype).itemsize
+    return flat_kv.block_bytes(cfg.n_full, block_size, cfg.num_key_value_heads, cfg.head_dim, cfg.dtype)
 
 
 def paged_ring(cfg: ExaoneMoeConfig) -> Dict[str, int]:
@@ -252,16 +245,6 @@ def paged_ring(cfg: ExaoneMoeConfig) -> Dict[str, int]:
 def paged_state_bytes(cfg: ExaoneMoeConfig) -> int:
     """Bytes one state row holds: the window layers' rings and nothing else."""
     return paged_ring(cfg)["bytes"]
-
-
-def _rotary(cfg: ExaoneMoeConfig, positions) -> Tuple[jax.Array, jax.Array]:
-    """(cos, sin), each (positions.size, d / 2) float32, of the angles
-    ``position x theta^(-2j/d)``: ``apply_rope``'s tables with the call's own
-    positions as their rows."""
-    d = cfg.head_dim
-    inv_freq = 1.0 / (cfg.rope_theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    ang = positions.reshape(-1, 1).astype(jnp.float32) * inv_freq
-    return jnp.cos(ang), jnp.sin(ang)
 
 
 def _qkv(cfg: ExaoneMoeConfig, w, u, rope):
@@ -302,14 +285,13 @@ def paged_layer(cfg: ExaoneMoeConfig, params, step):
     eps, dense_layers, dtype = cfg.rms_norm_eps, cfg.first_k_dense_replace, cfg.dtype
     H, G, d, W = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim, cfg.sliding_window
     b, s = step.positions.shape
-    rows, live, bs = step.state_rows, step.live.reshape(b, s), step.block_size
+    rows, live = step.state_rows, step.live.reshape(b, s)
     decode = s == 1
     scale = d ** -0.5
     ring_kernel = decode and can_use_ring_kernel(W, G, d, dtype)
-    rope = _rotary(cfg, step.positions)  # the same for every window layer: once a call
+    rope = rope_tables(step.positions, d, cfg.rope_theta)  # the same for every window layer: once a call
 
-    def at(index):  # a layer's tensors, each read out of its stack in place
-        return lambda name: jax.lax.dynamic_index_in_dim(params[name], index, keepdims=False)
+    at = functools.partial(paged.at, params)  # a layer's tensors, each read out of its stack in place
 
     def window_attention(u, pool, li):
         """Window layer ``li``: (o (B, S, H, d), the pool with its rings written)."""
@@ -349,32 +331,7 @@ def paged_layer(cfg: ExaoneMoeConfig, params, step):
         fi = li // PERIOD
         with jax.named_scope("proj"):
             q, k, v = _qkv(cfg, at(li), u, None)
-        kv = pool["kv"]
-        kernel = decode and can_use_paged_kernel(q, kv, bs, G)
-        if not kernel:
-            with jax.named_scope("paged_scatter"):
-                if decode or s % bs:
-                    starts, spans = step.write_slots * G, (k.reshape(b * s, G, d), v.reshape(b * s, G, d))
-                else:  # a block a window: a prompt's rows past its length lie behind the mask where they land
-                    starts = (step.block_tables[:, :s // bs] * (bs * G)).reshape(-1)
-                    spans = (k.reshape(-1, bs * G, d), v.reshape(-1, bs * G, d))
-                for plane, t in enumerate(spans):
-                    kv = write_spans(kv, (fi, plane), starts, t)
-        with jax.named_scope("paged_attn"):
-            if not decode:
-                o = causal_attention(q, k, v, causal=True)
-            elif kernel:  # the kernel puts the row in its block and scores the blocks with it there
-                o, kv = paged_decode_attention(
-                    q[:, 0], kv, fi, step.block_tables, step.lengths, block_size=bs, kv_heads=G,
-                    new_k=k[:, 0], new_v=v[:, 0])
-                o = o[:, None]
-            else:
-                with jax.named_scope("paged_gather"):
-                    slots = (step.block_tables[:, :, None] * bs + jnp.arange(bs)).reshape(b, -1)
-                    mine = slots[:, :, None] * G + jnp.arange(G)  # (B, M, G): where each position's heads lie
-                    kk, vv = jax.lax.dynamic_index_in_dim(kv, fi, keepdims=False)[:, mine]
-                o = window_attention_rows(q[:, 0], kk, vv, jnp.arange(slots.shape[1])[None, :] < step.lengths[:, None],
-                                          scale=scale)[:, None]
+        o, kv = flat_kv.attend(pool["kv"], fi, step, q, k, v, kv_heads=G)
         return o.astype(dtype), {**pool, "kv": kv}
 
     def attention(x, pool, li, full: bool):

@@ -39,15 +39,10 @@ This module gives ``models/paged.py`` a kind's four things, one section of one
 layer a call. **The pool holds both caches of every layer** behind one block
 table:
 
-* ``kv`` (layers, 2, slots x G, d): every layer's rows a position, the keys in
-  plane 0 and the values in plane 1 of one array (``paged_decode_attention``
-  brings a block's keys and values in under one copy), each plane *flat* (a
-  slot's G heads are G consecutive rows: four heads are no whole sublane tile,
-  and ``ops/paged_attention.py`` takes a flat pool of any head count whose block
-  is whole tiles), the keys rotated and multiplied before they are written. On a
-  TPU a decode step's own row is written by ``paged_decode_attention``, into the
-  blocks it scores; elsewhere, and in every prefill, rows are scattered
-  (``write_spans``) and a decode step gathers its table's.
+* ``kv`` (layers, 2, slots x G, d): every layer's rows a position in the flat
+  pool (``models/flat_kv.py``: its format, how a call's rows are written and
+  read back, which kernel scores them), a K/V head a row (four heads are no
+  whole sublane tile), the keys rotated and multiplied before they are written.
 * ``state`` (layers, state rows, N, d_ssm) float32, ``conv`` (.., K x (d_ssm + 2
   G_s N)), ``state_pos``: the recurrent state (the state dimension in the
   sublanes, every head's channels side by side in the lanes: a head is one lane
@@ -71,11 +66,8 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models import mamba2
-from ray_tpu.ops.attention import attention as causal_attention
-from ray_tpu.ops.layers import apply_rope, rms_norm, swiglu
-from ray_tpu.ops.paged_attention import can_use_paged_kernel, paged_decode_attention
-from ray_tpu.ops.window_attention import window_attention_rows, write_spans
+from ray_tpu.models import flat_kv, mamba2, paged
+from ray_tpu.ops.layers import apply_rope, rms_norm, rope_tables, swiglu
 
 
 @dataclasses.dataclass(frozen=True)
@@ -217,13 +209,13 @@ def init_paged_pool(cfg: FalconH1Config, num_blocks: int, block_size: int, state
     """Both caches of every layer (module docstring). ``state_rows`` counts the
     null row: the engine asks for ``max_batch + 1``."""
     L = cfg.num_hidden_layers
-    flat = (L, 2, num_blocks * block_size * cfg.num_key_value_heads, cfg.head_dim)  # keys in plane 0, values in plane 1
-    return {"kv": jnp.zeros(flat, cfg.dtype), **mamba2.init_pool(cfg.mamba, L, state_rows)}
+    return {"kv": flat_kv.init_pool(L, num_blocks, block_size, cfg.num_key_value_heads, cfg.head_dim, cfg.dtype),
+            **mamba2.init_pool(cfg.mamba, L, state_rows)}
 
 
 def paged_block_bytes(cfg: FalconH1Config, block_size: int) -> int:
     """Bytes one block of the pool holds: K and V rows of every layer."""
-    return 2 * cfg.num_hidden_layers * block_size * cfg.kv_row * jnp.dtype(cfg.dtype).itemsize
+    return flat_kv.block_bytes(cfg.num_hidden_layers, block_size, cfg.num_key_value_heads, cfg.head_dim, cfg.dtype)
 
 
 def paged_state_bytes(cfg: FalconH1Config) -> int:
@@ -240,17 +232,12 @@ def paged_layer(cfg: FalconH1Config, params, step):
     mixer = cfg.mamba
     gate_mult, down_mult = cfg.mlp_multipliers
     b, s = step.positions.shape
-    bs = step.block_size
-    decode = s == 1
     dot32 = functools.partial(jnp.einsum, preferred_element_type=jnp.float32)
     # what is the same for every layer: once a call
     ssm_scales, qkv_scales = cfg.ssm_scales(), cfg.qkv_scales()
-    inv_freq = 1.0 / (cfg.rope_theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    ang = step.positions.reshape(-1, 1).astype(jnp.float32) * inv_freq
-    rope = jnp.cos(ang), jnp.sin(ang)
+    rope = rope_tables(step.positions, d, cfg.rope_theta)
 
-    def at(index):  # a layer's tensors, each read out of its stack in place
-        return lambda name: jax.lax.dynamic_index_in_dim(params[name], index, keepdims=False)
+    at = functools.partial(paged.at, params)  # a layer's tensors, each read out of its stack in place
 
     def attention(u, pool, li):
         """Layer ``li``'s attention over ``u`` = N_in(x): (out, pool)."""
@@ -260,32 +247,7 @@ def paged_layer(cfg: FalconH1Config, params, step):
         with jax.named_scope("rope"):
             q, k = (apply_rope(t.reshape(b * s, -1, d), *rope).reshape(b, s, -1, d).astype(dtype) for t in (q, k))
             v = v.reshape(b, s, G, d).astype(dtype)
-        kv = pool["kv"]
-        kernel = decode and can_use_paged_kernel(q, kv, bs, G)
-        if not kernel:
-            with jax.named_scope("paged_scatter"):
-                if decode or s % bs:
-                    starts, spans = step.write_slots * G, (k.reshape(b * s, G, d), v.reshape(b * s, G, d))
-                else:  # a block a window: a prompt's rows past its length lie behind the mask where they land
-                    starts = (step.block_tables[:, :s // bs] * (bs * G)).reshape(-1)
-                    spans = (k.reshape(-1, bs * G, d), v.reshape(-1, bs * G, d))
-                for plane, t in enumerate(spans):
-                    kv = write_spans(kv, (li, plane), starts, t)
-        with jax.named_scope("paged_attn"):
-            if not decode:
-                o = causal_attention(q, k, v, causal=True)
-            elif kernel:  # the kernel puts the row in its block and scores the blocks with it there
-                o, kv = paged_decode_attention(
-                    q[:, 0], kv, li, step.block_tables, step.lengths, block_size=bs, kv_heads=G,
-                    new_k=k[:, 0], new_v=v[:, 0])
-                o = o[:, None]
-            else:
-                with jax.named_scope("paged_gather"):
-                    slots = (step.block_tables[:, :, None] * bs + jnp.arange(bs)).reshape(b, -1)
-                    mine = slots[:, :, None] * G + jnp.arange(G)  # (B, M, G): where each position's heads lie
-                    kk, vv = jax.lax.dynamic_index_in_dim(kv, li, keepdims=False)[:, mine]
-                o = window_attention_rows(q[:, 0], kk, vv, jnp.arange(slots.shape[1])[None, :] < step.lengths[:, None],
-                                          scale=d ** -0.5)[:, None]
+        o, kv = flat_kv.attend(pool["kv"], li, step, q, k, v, kv_heads=G)
         with jax.named_scope("out"):
             return o.astype(dtype).reshape(b, s, H * d) @ w("wo"), {**pool, "kv": kv}
 

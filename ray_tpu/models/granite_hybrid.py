@@ -50,7 +50,7 @@ counts:
 
 * ``kv`` (attention layers, 2, slots x G, d): the attention layers' rows a
   position, keys in plane 0 and values in plane 1, each plane flat
-  (``models/exaone_moe.py``'s layout). Attention layer ``i`` is the pool's
+  (``models/flat_kv.py``). Attention layer ``i`` is the pool's
   layer ``i // 10``; a block holds those layers' rows alone.
 * ``state``, ``conv``, ``state_pos`` (Mamba layers, state rows, ..): a
   sequence's recurrent state, its convolution's window and the positions it has
@@ -64,17 +64,15 @@ A prefill starts from an empty state: no chunked prefill, no prefix reuse.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models import mamba2, moe
+from ray_tpu.models import flat_kv, mamba2, moe, paged
 from ray_tpu.models.moe import routing_counts  # noqa: F401 - the engine asks the kind's module for it
-from ray_tpu.ops.attention import attention as causal_attention
 from ray_tpu.ops.layers import rms_norm, swiglu
-from ray_tpu.ops.paged_attention import can_use_paged_kernel, paged_decode_attention
-from ray_tpu.ops.window_attention import window_attention_rows, write_spans
 
 PERIOD = ("mamba",) * 5 + ("attention",) + ("mamba",) * 4
 ROUTER_SCALE = 1.5
@@ -221,9 +219,8 @@ def init_params(key, cfg: GraniteHybridConfig) -> Dict[str, Any]:
 def init_paged_pool(cfg: GraniteHybridConfig, num_blocks: int, block_size: int, state_rows: int) -> Dict:
     """The two kinds of cache and the routing counts (module docstring).
     ``state_rows`` counts the null row: the engine asks for ``max_batch + 1``."""
-    flat = (cfg.n_attention, 2, num_blocks * block_size * cfg.num_key_value_heads, cfg.head_dim)  # keys, values
     return {
-        "kv": jnp.zeros(flat, cfg.dtype),
+        "kv": flat_kv.init_pool(cfg.n_attention, num_blocks, block_size, cfg.num_key_value_heads, cfg.head_dim, cfg.dtype),
         **mamba2.init_pool(cfg.mamba, cfg.n_mamba, state_rows),
         "moe_counts": jnp.zeros((len(moe.COUNTS),), jnp.uint32),
     }
@@ -232,7 +229,7 @@ def init_paged_pool(cfg: GraniteHybridConfig, num_blocks: int, block_size: int, 
 def paged_block_bytes(cfg: GraniteHybridConfig, block_size: int) -> int:
     """Bytes one block of the pool holds: K and V rows of the attention layers
     alone (a Mamba layer keeps nothing a position)."""
-    return 2 * cfg.n_attention * block_size * cfg.kv_row * jnp.dtype(cfg.dtype).itemsize
+    return flat_kv.block_bytes(cfg.n_attention, block_size, cfg.num_key_value_heads, cfg.head_dim, cfg.dtype)
 
 
 def paged_state_bytes(cfg: GraniteHybridConfig) -> int:
@@ -263,11 +260,9 @@ def paged_layer(cfg: GraniteHybridConfig, params, step):
     eps, dtype, m_r, scale = cfg.rms_norm_eps, cfg.dtype, cfg.residual_multiplier, cfg.attention_multiplier
     H, G, d, mixer, n = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim, cfg.mamba, len(PERIOD)
     b, s = step.positions.shape
-    bs = step.block_size
     decode = s == 1
 
-    def at(index):  # a layer's tensors, each read out of its stack in place
-        return lambda name: jax.lax.dynamic_index_in_dim(params[name], index, keepdims=False)
+    at = functools.partial(paged.at, params)  # a layer's tensors, each read out of its stack in place
 
     def attention(u, pool, li):
         """Attention layer ``li``: (out (B, S, D), the pool with its rows written)."""
@@ -275,32 +270,7 @@ def paged_layer(cfg: GraniteHybridConfig, params, step):
         w = at(ai)
         with jax.named_scope("proj"):
             q, k, v = (t.reshape(b, s, -1, d) for t in jnp.split(u @ w("wqkv"), [H * d, (H + G) * d], axis=-1))
-        kv = pool["kv"]
-        kernel = decode and can_use_paged_kernel(q, kv, bs, G)
-        if not kernel:
-            with jax.named_scope("paged_scatter"):
-                if decode or s % bs:
-                    starts, spans = step.write_slots * G, (k.reshape(b * s, G, d), v.reshape(b * s, G, d))
-                else:  # a block a window: a prompt's rows past its length lie behind the mask where they land
-                    starts = (step.block_tables[:, :s // bs] * (bs * G)).reshape(-1)
-                    spans = (k.reshape(-1, bs * G, d), v.reshape(-1, bs * G, d))
-                for plane, t in enumerate(spans):
-                    kv = write_spans(kv, (ai, plane), starts, t)
-        with jax.named_scope("paged_attn"):
-            if not decode:
-                o = causal_attention(q, k, v, causal=True, scale=scale)
-            elif kernel:  # the kernel puts the row in its block and scores the blocks with it there
-                o, kv = paged_decode_attention(
-                    q[:, 0], kv, ai, step.block_tables, step.lengths, block_size=bs, kv_heads=G, scale=scale,
-                    new_k=k[:, 0], new_v=v[:, 0])
-                o = o[:, None]
-            else:
-                with jax.named_scope("paged_gather"):
-                    slots = (step.block_tables[:, :, None] * bs + jnp.arange(bs)).reshape(b, -1)
-                    mine = slots[:, :, None] * G + jnp.arange(G)  # (B, M, G): where each position's heads lie
-                    kk, vv = jax.lax.dynamic_index_in_dim(kv, ai, keepdims=False)[:, mine]
-                o = window_attention_rows(q[:, 0], kk, vv, jnp.arange(slots.shape[1])[None, :] < step.lengths[:, None],
-                                          scale=scale)[:, None]
+        o, kv = flat_kv.attend(pool["kv"], ai, step, q, k, v, kv_heads=G, scale=scale)
         with jax.named_scope("out"):
             return o.astype(dtype).reshape(b, s, H * d) @ w("wo"), {**pool, "kv": kv}
 

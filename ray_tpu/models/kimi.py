@@ -38,7 +38,7 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models import moe
+from ray_tpu.models import moe, paged
 from ray_tpu.models.moe import routing_counts  # noqa: F401 - the engine asks the kind's module for it
 from ray_tpu.ops.latent_attention import mla, rotate_pairs, yarn_inv_freq, yarn_mscale
 from ray_tpu.ops.layers import rms_norm, swiglu
@@ -205,8 +205,7 @@ def paged_layer(cfg: KimiConfig, params, step):
     eps, dense_layers = cfg.rms_norm_eps, cfg.first_k_dense_replace
     attend = functools.partial(mla, cfg, rotate=cfg.rotary(), att_scale=cfg.att_scale)
 
-    def at(index):  # a layer's tensors, each read out of its stack in place
-        return lambda name: jax.lax.dynamic_index_in_dim(params[name], index, keepdims=False)
+    at = functools.partial(paged.at, params)  # a layer's tensors, each read out of its stack in place
 
     def attention(x, pool, li):
         """The half every layer has: (h, N(h), the pool's rows)."""

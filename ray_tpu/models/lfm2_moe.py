@@ -38,19 +38,19 @@ the expert layers' over theirs, each read by the layer's index among its kind.
 counts:
 
 * ``kv`` (full layers, 2, slots x G / P, P x d): the full layers' rows a
-  position, the keys in plane 0 and the values in plane 1 of one array
-  (``paged_decode_attention`` brings a block's keys and values in under one
-  copy), each plane *flat* and **P K/V heads to a row** (``kv_pack``: two heads of 64
-  fill the 128 lanes ``ops/paged_attention.py`` scores; a position is G / P
-  consecutive rows). Query head ``h`` is handed to the kernel as a row of P x d
-  values that is zero outside the part of its own K/V head, so ``q . row`` is
-  ``q . k`` to the bit; the kernel groups query heads over rows as it groups
-  them over K/V heads (``h // (H / G x P)``), and of the output row the part
-  of the head's own K/V head is kept. On a TPU a decode step's own row is
-  written by ``paged_decode_attention`` in that packed form; elsewhere, and in
-  every prefill, rows are scattered (``write_spans``) and a decode step gathers
-  its table's. Full layer ``i`` is the pool's layer ``i // 4``, and a block
-  holds the full layers' rows alone (``paged_block_bytes``).
+  position in the flat pool (``models/flat_kv.py``: its format, how a call's
+  rows are written and read back), **P K/V heads to a row** (``kv_pack``: two
+  heads of 64 fill the 128 lanes ``ops/paged_attention.py`` scores; a position
+  is G / P consecutive rows). Query head ``h`` is handed to the kernel as a row
+  of P x d values that is zero outside the part of its own K/V head, so ``q .
+  row`` is ``q . k`` to the bit; the kernel groups query heads over rows as it
+  groups them over K/V heads (``h // (H / G x P)``), and of the output row the
+  part of the head's own K/V head is kept. That call, which on a TPU writes a
+  decode step's own row in the packed form, is this module's; elsewhere, and
+  in every prefill, ``flat_kv.attend`` scatters the rows and a decode step
+  gathers its table's back into heads. Full layer ``i`` is the pool's layer
+  ``i // 4``, and a block holds the full layers' rows alone
+  (``paged_block_bytes``).
 * ``conv`` (conv layers, state rows, K x D) and ``state_pos`` (conv layers,
   state rows): the convolution's window of a sequence, its last K products
   ``B * z`` with the current one among them, flat in the lanes, in the
@@ -72,13 +72,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu.models import moe
+from ray_tpu.models import flat_kv, moe, paged
 from ray_tpu.models.moe import routing_counts  # noqa: F401 - the engine asks the kind's module for it
-from ray_tpu.ops.attention import attention as causal_attention
 from ray_tpu.ops.gated_delta import short_conv_step
-from ray_tpu.ops.layers import apply_rope, rms_norm, swiglu
+from ray_tpu.ops.layers import apply_rope, rms_norm, rope_tables, swiglu
 from ray_tpu.ops.paged_attention import can_use_paged_kernel, paged_decode_attention
-from ray_tpu.ops.window_attention import window_attention_rows, write_spans
 
 PERIOD = ("conv", "conv", "full_attention", "conv")
 ROUTER_SCALE = 1.5
@@ -204,10 +202,8 @@ def init_paged_pool(cfg: Lfm2MoeConfig, num_blocks: int, block_size: int, state_
     """The two kinds of cache and the routing counts (module docstring).
     ``state_rows`` counts the null row: the engine asks for ``max_batch + 1``."""
     P = cfg.kv_pack
-    # keys in plane 0, values in plane 1
-    flat = (cfg.n_full, 2, num_blocks * block_size * cfg.num_key_value_heads // P, P * cfg.head_dim)
     return {
-        "kv": jnp.zeros(flat, cfg.dtype),
+        "kv": flat_kv.init_pool(cfg.n_full, num_blocks, block_size, cfg.num_key_value_heads // P, P * cfg.head_dim, cfg.dtype),
         "conv": jnp.zeros((cfg.n_conv, state_rows, cfg.conv_L_cache * cfg.hidden_size), cfg.dtype),
         "state_pos": jnp.zeros((cfg.n_conv, state_rows), jnp.int32),
         "moe_counts": jnp.zeros((len(moe.COUNTS),), jnp.uint32),
@@ -217,23 +213,13 @@ def init_paged_pool(cfg: Lfm2MoeConfig, num_blocks: int, block_size: int, state_
 def paged_block_bytes(cfg: Lfm2MoeConfig, block_size: int) -> int:
     """Bytes one block of the pool holds: K and V rows of the full layers
     alone (a conv layer keeps nothing a position)."""
-    return 2 * cfg.n_full * block_size * cfg.kv_row * jnp.dtype(cfg.dtype).itemsize
+    return flat_kv.block_bytes(cfg.n_full, block_size, cfg.num_key_value_heads, cfg.head_dim, cfg.dtype)
 
 
 def paged_state_bytes(cfg: Lfm2MoeConfig) -> int:
     """Bytes one state row holds: the conv layers' windows and position
     counts, and nothing of the full layers."""
     return cfg.n_conv * (cfg.conv_L_cache * cfg.hidden_size * jnp.dtype(cfg.dtype).itemsize + 4)
-
-
-def _rotary(cfg: Lfm2MoeConfig, positions) -> Tuple[jax.Array, jax.Array]:
-    """(cos, sin), each (positions.size, d / 2) float32, of the angles
-    ``position x theta^(-2j/d)``: ``apply_rope``'s tables with the call's own
-    positions as their rows."""
-    d = cfg.head_dim
-    inv_freq = 1.0 / (cfg.rope_theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    ang = positions.reshape(-1, 1).astype(jnp.float32) * inv_freq
-    return jnp.cos(ang), jnp.sin(ang)
 
 
 def own_part(cfg: Lfm2MoeConfig) -> np.ndarray:
@@ -298,10 +284,9 @@ def paged_layer(cfg: Lfm2MoeConfig, params, step):
     rows, live, bs = step.state_rows, step.live.reshape(b, s), step.block_size
     decode = s == 1
     scale = d ** -0.5
-    rope = _rotary(cfg, step.positions)  # the same for every full layer: once a call
+    rope = rope_tables(step.positions, d, cfg.rope_theta)  # the same for every full layer: once a call
 
-    def at(index):  # a layer's tensors, each read out of its stack in place
-        return lambda name: jax.lax.dynamic_index_in_dim(params[name], index, keepdims=False)
+    at = functools.partial(paged.at, params)  # a layer's tensors, each read out of its stack in place
 
     def short_conv(u, pool, li):
         """Conv layer ``li``: (out (B, S, D), the pool with its windows written)."""
@@ -347,31 +332,15 @@ def paged_layer(cfg: Lfm2MoeConfig, params, step):
             q, k, v = _qkv(cfg, w, u, rope)
         kv = pool["kv"]
         packed = pack_queries(cfg, q[:, 0]) if decode else None
-        kernel = decode and can_use_paged_kernel(packed[:, None], kv, bs, Gp)
-        if not kernel:
-            with jax.named_scope("paged_scatter"):
-                if decode or s % bs:
-                    starts, spans = step.write_slots * Gp, (k.reshape(b * s, Gp, wide), v.reshape(b * s, Gp, wide))
-                else:  # a block a window: a prompt's rows past its length lie behind the mask where they land
-                    starts = (step.block_tables[:, :s // bs] * (bs * Gp)).reshape(-1)
-                    spans = (k.reshape(-1, bs * Gp, wide), v.reshape(-1, bs * Gp, wide))
-                for plane, t in enumerate(spans):
-                    kv = write_spans(kv, (fi, plane), starts, t)
-        with jax.named_scope("paged_attn"):
-            if not decode:
-                o = causal_attention(q, k, v, causal=True)
-            elif kernel:  # the kernel puts the packed row in its block and scores the blocks with it there
+        if decode and can_use_paged_kernel(packed[:, None], kv, bs, Gp):
+            # the kernel puts the packed row in its block and scores the blocks with it there
+            with jax.named_scope("paged_attn"):
                 o, kv = paged_decode_attention(
                     packed, kv, fi, step.block_tables, step.lengths, block_size=bs, kv_heads=Gp,
                     scale=scale, new_k=k[:, 0].reshape(b, Gp, wide), new_v=v[:, 0].reshape(b, Gp, wide))
                 o = unpack_outputs(cfg, o)[:, None]
-            else:
-                with jax.named_scope("paged_gather"):
-                    slots = (step.block_tables[:, :, None] * bs + jnp.arange(bs)).reshape(b, -1)
-                    mine = slots[:, :, None] * Gp + jnp.arange(Gp)  # (B, M, G / P): where each position's rows lie
-                    kk, vv = jax.lax.dynamic_index_in_dim(kv, fi, keepdims=False)[:, mine].reshape(2, b, -1, G, d)
-                o = window_attention_rows(q[:, 0], kk, vv, jnp.arange(slots.shape[1])[None, :] < step.lengths[:, None],
-                                          scale=scale)[:, None]
+        else:  # its own rows, or the table's gathered back into heads
+            o, kv = flat_kv.attend(kv, fi, step, q, k, v, kv_heads=Gp, scale=scale)
         with jax.named_scope("out"):
             out = o.astype(dtype).reshape(b, s, H * d) @ w("wo")
         return out, {**pool, "kv": kv}

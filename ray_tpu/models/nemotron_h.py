@@ -50,7 +50,7 @@ layer's place among its kind in the period).
 
 * ``kv`` (``*`` layers, 2, slots x G, head_dim): the attention layers' rows a
   position, keys in plane 0 and values in plane 1, each plane flat
-  (``models/exaone_moe.py``'s layout); a block holds those layers' rows alone.
+  (``models/flat_kv.py``); a block holds those layers' rows alone.
 * ``state``, ``conv``, ``state_pos`` (``M`` layers, state rows, ..): a
   sequence's recurrent state, its convolution's window and the positions it has
   consumed, in the sequence's state row (``models/mamba2.py``).
@@ -65,17 +65,15 @@ refuses a config that asks for more.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models import mamba2, moe
+from ray_tpu.models import flat_kv, mamba2, moe, paged
 from ray_tpu.models.moe import routing_counts  # noqa: F401 - the engine asks the kind's module for it
-from ray_tpu.ops.attention import attention as causal_attention
 from ray_tpu.ops.layers import relu2, rms_norm
-from ray_tpu.ops.paged_attention import can_use_paged_kernel, paged_decode_attention
-from ray_tpu.ops.window_attention import window_attention_rows, write_spans
 
 PATTERN = "MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEM*EMEMEMEME"
 ROUTER_SCALE = 1.5
@@ -243,9 +241,8 @@ def init_paged_pool(cfg: NemotronHConfig, num_blocks: int, block_size: int, stat
     """Each kind of layer's own cache and the routing counts (module
     docstring). ``state_rows`` counts the null row: the engine asks for
     ``max_batch + 1``."""
-    flat = (cfg.n_attention, 2, num_blocks * block_size * cfg.num_key_value_heads, cfg.head_dim)  # keys, values
     return {
-        "kv": jnp.zeros(flat, cfg.dtype),
+        "kv": flat_kv.init_pool(cfg.n_attention, num_blocks, block_size, cfg.num_key_value_heads, cfg.head_dim, cfg.dtype),
         **mamba2.init_pool(cfg.mamba, cfg.n_mamba, state_rows),
         "moe_counts": jnp.zeros((len(moe.COUNTS),), jnp.uint32),
     }
@@ -254,7 +251,7 @@ def init_paged_pool(cfg: NemotronHConfig, num_blocks: int, block_size: int, stat
 def paged_block_bytes(cfg: NemotronHConfig, block_size: int) -> int:
     """Bytes one block of the pool holds: K and V rows of the attention layers
     alone (a mixer keeps nothing a position, an expert layer nothing at all)."""
-    return 2 * cfg.n_attention * block_size * cfg.kv_row * jnp.dtype(cfg.dtype).itemsize
+    return flat_kv.block_bytes(cfg.n_attention, block_size, cfg.num_key_value_heads, cfg.head_dim, cfg.dtype)
 
 
 def paged_state_bytes(cfg: NemotronHConfig) -> int:
@@ -290,11 +287,9 @@ def paged_layer(cfg: NemotronHConfig, params, step):
     eps, dtype, scale = cfg.layer_norm_epsilon, cfg.dtype, cfg.head_dim ** -0.5
     H, G, d, mixer = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim, cfg.mamba
     b, s = step.positions.shape
-    bs = step.block_size
     decode = s == 1
 
-    def at(index):  # a layer's tensors, each read out of its stack in place
-        return lambda name: jax.lax.dynamic_index_in_dim(params[name], index, keepdims=False)
+    at = functools.partial(paged.at, params)  # a layer's tensors, each read out of its stack in place
 
     def attention(u, pool, ai):
         """Attention layer ``ai`` among the attention layers: (out (B, S, D),
@@ -302,32 +297,7 @@ def paged_layer(cfg: NemotronHConfig, params, step):
         w = at(ai)
         with jax.named_scope("proj"):
             q, k, v = (t.reshape(b, s, -1, d) for t in jnp.split(u @ w("wqkv"), [H * d, (H + G) * d], axis=-1))
-        kv = pool["kv"]
-        kernel = decode and can_use_paged_kernel(q, kv, bs, G)
-        if not kernel:
-            with jax.named_scope("paged_scatter"):
-                if decode or s % bs:
-                    starts, spans = step.write_slots * G, (k.reshape(b * s, G, d), v.reshape(b * s, G, d))
-                else:  # a block a window: a prompt's rows past its length lie behind the mask where they land
-                    starts = (step.block_tables[:, :s // bs] * (bs * G)).reshape(-1)
-                    spans = (k.reshape(-1, bs * G, d), v.reshape(-1, bs * G, d))
-                for plane, t in enumerate(spans):
-                    kv = write_spans(kv, (ai, plane), starts, t)
-        with jax.named_scope("paged_attn"):
-            if not decode:
-                o = causal_attention(q, k, v, causal=True, scale=scale)
-            elif kernel:  # the kernel puts the row in its block and scores the blocks with it there
-                o, kv = paged_decode_attention(
-                    q[:, 0], kv, ai, step.block_tables, step.lengths, block_size=bs, kv_heads=G, scale=scale,
-                    new_k=k[:, 0], new_v=v[:, 0])
-                o = o[:, None]
-            else:
-                with jax.named_scope("paged_gather"):
-                    slots = (step.block_tables[:, :, None] * bs + jnp.arange(bs)).reshape(b, -1)
-                    mine = slots[:, :, None] * G + jnp.arange(G)  # (B, M, G): where each position's heads lie
-                    kk, vv = jax.lax.dynamic_index_in_dim(kv, ai, keepdims=False)[:, mine]
-                o = window_attention_rows(q[:, 0], kk, vv, jnp.arange(slots.shape[1])[None, :] < step.lengths[:, None],
-                                          scale=scale)[:, None]
+        o, kv = flat_kv.attend(pool["kv"], ai, step, q, k, v, kv_heads=G, scale=scale)
         with jax.named_scope("out"):
             return o.astype(dtype).reshape(b, s, H * d) @ w("wo"), {**pool, "kv": kv}
 

@@ -59,11 +59,13 @@ A prefill starts from an empty state: no chunked prefill, no prefix reuse.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models import paged
 from ray_tpu.models.generation import attend_pool
 from ray_tpu.ops import gated_delta
 from ray_tpu.ops.attention import attention
@@ -226,8 +228,7 @@ def paged_layer(cfg: OlmoHybridConfig, params, step):
     table_rows = (step.block_tables[:, :, None] * bs + jnp.arange(bs)).reshape(b, -1)  # a decode step's gather path's
     use_kernel = decode and gated_delta.can_use_gated_delta_kernel(H, dk, dv)
 
-    def at(index):  # a layer's tensors, each read out of its stack in place
-        return lambda name: jax.lax.dynamic_index_in_dim(params[name], index, keepdims=False)
+    at = functools.partial(paged.at, params)  # a layer's tensors, each read out of its stack in place
 
     def heads_of(c):  # (..., C) -> q, k (..., H, d_k), v (..., H, d_v)
         q, k, v = jnp.split(c, [H * dk, 2 * H * dk], axis=-1)

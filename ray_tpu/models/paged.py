@@ -28,7 +28,16 @@ four things, and may give a fifth:
 
 over a config with ``n_layers`` and ``max_seq_len`` (and ``rms_norm_eps``,
 where the final norm's is not ``rms_norm``'s own; or ``final_norm(params, x)``,
-where the final norm is not an RMSNorm at all). **A model of unlike
+where the final norm is not an RMSNorm at all). **Two parts a kind may build
+from**, each the one owner of a format in the pool: ``models/mamba2.py`` (the
+Mamba-2 mixer, its state rows and their bytes) and ``models/flat_kv.py`` (the
+flat K/V pool ``"kv"``: its shape, a block's bytes, how a call's rows are
+written and read back, which kernel scores them). A kind with plain softmax
+attention over K/V rows keeps its projection, its position signal and ``wo``,
+calls ``flat_kv.attend`` between them and writes no pool code of its own; its
+``init_paged_pool`` and ``paged_block_bytes`` call ``flat_kv.init_pool`` and
+``flat_kv.block_bytes`` for that part. ``at(params, index)`` is how any kind
+reads a layer's tensors (below: how a layer gets its weights). **A model of unlike
 layers** (Kimi-K2: one dense layer, then expert layers) hands back a section
 a kind of layer, and ``forward_paged`` runs one scan a section with ``(x,
 pool)`` carried from each into the next and the layer index running on
@@ -111,6 +120,13 @@ class Step(NamedTuple):
     live: jax.Array  # (B * S,) bool: the rows that are tokens
     lengths: jax.Array  # (B,): a decode step's sequences count positions [0, position]; an inactive slot none
     state_rows: Optional[jax.Array] = None  # (B,): each sequence's state row, for a kind that keeps one; 0 the null row
+
+
+def at(params, index):
+    """``w(name)``: layer ``index``'s tensor of the stack ``params[name]``, read
+    out of it in place by one dynamic index (module docstring: how a layer gets
+    its weights). ``index`` counts the stack's own layers."""
+    return lambda name: jax.lax.dynamic_index_in_dim(params[name], index, keepdims=False)
 
 
 class Carried(NamedTuple):

@@ -38,8 +38,9 @@ entry of the last section).
   is padded to 16 on the device. ``paged_decode_attention`` reads it with the
   queries ``[q1; 0]`` and ``[0; q2]``; the subtraction is here. On a TPU the
   full layer's call writes the decode step's own row too, into the cache it
-  scores; elsewhere, and in every prefill, rows are scattered
-  (``_write_spans``).
+  scores; elsewhere, and in every prefill, rows are scattered and a decode
+  step's gathered back (``models/flat_kv.py``, the flat pool's one owner:
+  ``write_rows``, ``gather_rows``).
 * ``ring_k``, ``ring_v`` (window layers, state rows, W x K/V pairs, 128): a
   **ring** of the last W positions' rows a sequence a window layer, in the
   sequence's state row. Position p lies at ``p % W``; a decode step attends
@@ -68,7 +69,7 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models import paged
+from ray_tpu.models import flat_kv, paged
 from ray_tpu.ops import selective_scan
 from ray_tpu.ops.gated_delta import short_conv_step
 from ray_tpu.ops.layers import layer_norm, rms_norm
@@ -178,10 +179,9 @@ def init_paged_pool(cfg: Phi4FlashConfig, num_blocks: int, block_size: int, stat
     """The three kinds of cache (module docstring). ``state_rows`` counts the
     null row: the engine asks for ``max_batch + 1``."""
     wide, pairs = cfg.pair_dim, cfg.kv_pairs
-    shared = (1, 2, num_blocks * block_size * pairs, wide)  # keys in plane 0, values in plane 1
     ring = (cfg.n_window, state_rows, cfg.sliding_window * pairs, wide)
     return {
-        "kv": jnp.zeros(shared, cfg.dtype),
+        "kv": flat_kv.init_pool(1, num_blocks, block_size, pairs, wide, cfg.dtype),
         "ring_k": jnp.zeros(ring, cfg.dtype), "ring_v": jnp.zeros(ring, cfg.dtype),
         "state": jnp.zeros((cfg.n_ssm, state_rows, cfg.ssm_state_size, cfg.d_inner), jnp.float32),
         "conv": jnp.zeros((cfg.n_ssm, state_rows, cfg.ssm_conv_kernel * cfg.d_inner), cfg.dtype),
@@ -192,7 +192,7 @@ def init_paged_pool(cfg: Phi4FlashConfig, num_blocks: int, block_size: int, stat
 def paged_block_bytes(cfg: Phi4FlashConfig, block_size: int) -> int:
     """Bytes one block of the pool holds: K and V rows of **one** layer, the
     full layer's, which every cross layer reads."""
-    return 2 * block_size * cfg.kv_pairs * cfg.pair_dim * jnp.dtype(cfg.dtype).itemsize
+    return flat_kv.block_bytes(1, block_size, cfg.kv_pairs, cfg.pair_dim, cfg.dtype)
 
 
 def paged_ring(cfg: Phi4FlashConfig) -> Dict[str, int]:
@@ -227,8 +227,7 @@ def paged_layer(cfg: Phi4FlashConfig, params, step):
     ring_kernel = decode and can_use_ring_kernel(W, G, wide, dtype)
     decays = -jnp.exp(params["ssm_a_log"].astype(jnp.float32))  # A, every state-space layer's: once a call
 
-    def at(index):  # a layer's tensors, each read out of its stack in place
-        return lambda name: jax.lax.dynamic_index_in_dim(params[name], index, keepdims=False)
+    at = functools.partial(paged.at, params)  # a layer's tensors, each read out of its stack in place
 
     def normed(x, li, which):
         w = at(li)
@@ -329,12 +328,7 @@ def paged_layer(cfg: Phi4FlashConfig, params, step):
                 pool = {**pool, "kv": kv}
             o = o.reshape(b, pairs, 2, wide).astype(jnp.float32)
             return o[:, :, 0] - lam * o[:, :, 1], pool
-        with jax.named_scope("paged_gather"):
-            slots = (step.block_tables[:, :, None] * bs + jnp.arange(bs)).reshape(b, -1)
-            mine = (slots[:, :, None] * G + jnp.arange(G))  # (B, M, G): where each position's pairs lie
-            k, v = kv[0][:, mine]
-        live = jnp.arange(slots.shape[1])[None, :] < step.lengths[:, None]
-        return diff_attention_rows(qp, k, v, live, lam, scale=scale), pool
+        return diff_attention_rows(qp, *flat_kv.gather_rows(kv, 0, step, G), lam, scale=scale), pool
 
     def own_attention(u, pool, ai, li, window: bool):
         """Attention ``ai`` (a window layer, or the full layer) of layer ``li``,
@@ -374,16 +368,7 @@ def paged_layer(cfg: Phi4FlashConfig, params, step):
             pool = {**pool, **ring}
         else:
             if not (decode and can_use_paged_kernel(qp, pool["kv"], bs, G)):  # else the kernel puts the row in the cache
-                with jax.named_scope("paged_scatter"):
-                    if decode or s % bs:
-                        starts, spans = step.write_slots * G, (k.reshape(b * s, G, wide), v.reshape(b * s, G, wide))
-                    else:  # a block a window: a prompt's rows past its length lie behind the mask where they land
-                        starts = (step.block_tables[:, :s // bs] * (bs * G)).reshape(-1)
-                        spans = (k.reshape(-1, bs * G, wide), v.reshape(-1, bs * G, wide))
-                    kv = pool["kv"]
-                    for plane, t in enumerate(spans):
-                        kv = _write_spans(kv, (0, plane), starts, t)
-                    pool = {**pool, "kv": kv}
+                pool = {**pool, "kv": flat_kv.write_rows(pool["kv"], 0, step, k, v, G)}
             with jax.named_scope("paged_attn"):
                 if decode:
                     o, pool = over_shared_cache(qp[:, 0], pool, lam, new_k=k[:, 0], new_v=v[:, 0])

@@ -38,6 +38,15 @@ def rope_frequencies(
     return jnp.cos(freqs).astype(dtype), jnp.sin(freqs).astype(dtype)
 
 
+def rope_tables(positions: jax.Array, head_dim: int, theta: float) -> Tuple[jax.Array, jax.Array]:
+    """(cos, sin), each (positions.size, head_dim / 2) float32, of the angles
+    ``position x theta^(-2j/d)``: ``apply_rope``'s tables with a call's own
+    positions as their rows."""
+    inv_freq = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+    ang = positions.reshape(-1, 1).astype(jnp.float32) * inv_freq
+    return jnp.cos(ang), jnp.sin(ang)
+
+
 def apply_rope(
     x: jax.Array,
     cos: jax.Array,

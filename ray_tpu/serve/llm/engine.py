@@ -209,8 +209,6 @@ class EngineConfig:
     max_blocks_per_seq: int = 32
     max_waiting: int = 32
     retry_after_s: float = 1.0
-    prefill_bucket_min: int = 8
-    idle_poll_s: float = 0.05
     stream_timeout_s: float = 120.0
 
 
@@ -234,6 +232,8 @@ def _take_first_token_program():
 # loop and request records kept in the process for loop_stats(); at 20 steps
 # a second this is the last three minutes
 LOOP_RING = 4096
+PREFILL_BUCKET_MIN = 8  # the smallest prompt bucket; each one on is twice the last
+IDLE_POLL_S = 0.05  # how long an idle loop waits before it looks again
 
 
 class _Request:
@@ -972,7 +972,7 @@ class InferenceEngine:
             admits: List[tuple] = []
             with self._cv:
                 while not (self._stop or self._waiting or flight or self._any_slot()):
-                    self._cv.wait(self.cfg.idle_poll_s)
+                    self._cv.wait(IDLE_POLL_S)
                 stopping = self._stop  # one last iteration reads what is in flight
                 if stopping and not flight:
                     return
@@ -1080,7 +1080,7 @@ class InferenceEngine:
     # -- phases ---------------------------------------------------------
 
     def _bucket(self, n: int) -> int:
-        b = max(int(self.cfg.prefill_bucket_min), 1)
+        b = PREFILL_BUCKET_MIN
         while b < n:
             b *= 2
         return b

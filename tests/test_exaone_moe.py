@@ -21,7 +21,7 @@ import pytest  # noqa: E402
 
 from benchmarks.families import exaone_moe as F  # noqa: E402
 from benchmarks.reference import exaone_moe as R  # noqa: E402
-from ray_tpu.models import exaone_moe as M, moe, paged  # noqa: E402
+from ray_tpu.models import exaone_moe as M, flat_kv, moe, paged  # noqa: E402
 from ray_tpu.ops import paged_attention as PA, window_attention as WA  # noqa: E402
 from ray_tpu.serve.llm.deployment import LLMServer, _resolve_model_cfg  # noqa: E402
 from ray_tpu.serve.llm.kv_cache import BlockAllocator, BlockTable  # noqa: E402
@@ -211,7 +211,7 @@ def test_a_rings_live_rows_permuted_give_the_same_output():
     ``s`` lies in the ring, and the mask is a count. The same rows in another
     order, K and V permuted alike, are the same attention."""
     cfg = twin()
-    rope = lambda x, pos: M.apply_rope(x, *M._rotary(cfg, pos))  # noqa: E731
+    rope = lambda x, pos: M.apply_rope(x, *M.rope_tables(pos, cfg.head_dim, cfg.rope_theta))  # noqa: E731
     key = jax.random.split(jax.random.PRNGKey(1), 3)
     t, (G, H, d, W) = 13, (cfg.num_key_value_heads, cfg.num_attention_heads, cfg.head_dim, cfg.sliding_window)
     positions = jnp.arange(t - W + 1, t + 1)
@@ -262,9 +262,9 @@ def test_a_decode_step_with_the_paged_kernel_in_it_is_the_step_that_scatters_and
     position 21 through three block boundaries, an inactive slot either side."""
     cfg, params, gathered, _, pool, table = served
     _, fresh, _ = prefill_into(cfg, params, fresh_pool(cfg), BlockAllocator(BLOCKS, BLOCK, state_rows=ROWS), PROMPT)
-    monkeypatch.setattr(M, "can_use_paged_kernel", lambda *_: True)
+    monkeypatch.setattr(flat_kv, "can_use_paged_kernel", lambda *_: True)
     traced = []
-    monkeypatch.setattr(M, "paged_decode_attention", lambda *a, **kw: traced.append(kw["new_k"].shape) or PA.paged_decode_attention(
+    monkeypatch.setattr(flat_kv, "paged_decode_attention", lambda *a, **kw: traced.append(kw["new_k"].shape) or PA.paged_decode_attention(
         *a, **kw, interpret=True))
     kernel, _, kernel_pool, kernel_table = run_paged(cfg, params, PROMPT)
     G, d = cfg.num_key_value_heads, cfg.head_dim

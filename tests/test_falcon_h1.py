@@ -21,7 +21,7 @@ import pytest  # noqa: E402
 
 from benchmarks.families import falcon_h1 as F  # noqa: E402
 from benchmarks.reference import falcon_h1 as R  # noqa: E402
-from ray_tpu.models import falcon_h1 as M, paged  # noqa: E402
+from ray_tpu.models import falcon_h1 as M, flat_kv, paged  # noqa: E402
 from ray_tpu.ops import paged_attention as PA  # noqa: E402
 from ray_tpu.serve.llm.deployment import LLMServer, _resolve_model_cfg  # noqa: E402
 from ray_tpu.serve.llm.kv_cache import BlockAllocator, BlockTable  # noqa: E402
@@ -258,9 +258,9 @@ def test_a_decode_step_with_the_paged_kernel_in_it_is_the_step_that_scatters_and
     G, steps = cfg.num_key_value_heads, 8
     _, fresh, _ = prefill_into(cfg, params, fresh_pool(cfg), BlockAllocator(BLOCKS, BLOCK, state_rows=ROWS), PROMPT)
     gathered, _, pool, table = run_paged(cfg, params, PROMPT, steps=steps)
-    monkeypatch.setattr(M, "can_use_paged_kernel", lambda *_: True)
+    monkeypatch.setattr(flat_kv, "can_use_paged_kernel", lambda *_: True)
     traced = []
-    monkeypatch.setattr(M, "paged_decode_attention", lambda *a, **kw: traced.append("new_k" in kw) or PA.paged_decode_attention(
+    monkeypatch.setattr(flat_kv, "paged_decode_attention", lambda *a, **kw: traced.append("new_k" in kw) or PA.paged_decode_attention(
         *a, **kw, interpret=True))
     kernel, _, kernel_pool, kernel_table = run_paged(cfg, params, PROMPT, steps=steps)
     assert traced == [True]  # the decode step's one layer body, with rows; a prefill scatters

@@ -1,9 +1,10 @@
 """The seams of ``ray_tpu/models``: one block under its three ``attend``s
 (training's, the dense cache's, the paged pool's) gives the same logits, a
 model kind costs ``models/paged.py`` four things and one table entry (a
-made-up kind, defined here, served by the engine), and the fifth thing a kind
-may give, how its stacked weights lie on the device, changes no token. CPU,
-float32."""
+made-up kind, defined here, served by the engine), the fifth thing a kind
+may give, how its stacked weights lie on the device, changes no token, and the
+flat K/V pool's one owner (``models/flat_kv.py``) writes and reads a call's
+rows as dense attention would, apart from any kind. CPU, float32."""
 
 import dataclasses
 import logging
@@ -18,7 +19,8 @@ import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
 from ray_tpu import models  # noqa: E402
-from ray_tpu.models import generation as G, paged, transformer as T  # noqa: E402
+from ray_tpu.models import flat_kv, generation as G, paged, transformer as T  # noqa: E402
+from ray_tpu.ops.attention import attention  # noqa: E402
 from ray_tpu.ops.layers import gelu, rms_norm  # noqa: E402
 from ray_tpu.serve.llm.deployment import LLMServer  # noqa: E402
 from ray_tpu.serve.llm.engine import EngineConfig, InferenceEngine  # noqa: E402
@@ -660,3 +662,66 @@ def test_the_placing_program_never_comes_from_the_persistent_compile_cache(tmp_p
         for name, value in before.items():
             jax.config.update(name, value)
         compilation_cache.reset_cache()
+
+
+# -- (f) the flat K/V pool's one owner, apart from any kind --------------------------------
+
+
+def flat_step(positions, live, tables, block):
+    """``paged.Step`` as ``forward_paged`` makes it, over ``positions`` and
+    ``live`` (B, S) and ``tables`` (B, MB)."""
+    b, n = positions.shape
+    held = np.take_along_axis(tables, np.clip(positions // block, 0, tables.shape[1] - 1), axis=1)
+    slots, flat = (held * block + positions % block).reshape(-1), live.reshape(-1)
+    return paged.Step(
+        jnp.asarray(positions), jnp.asarray(tables), block, jnp.asarray(np.where(flat, slots, np.arange(b * n) % block)),
+        jnp.asarray(flat), jnp.asarray(np.where(live[:, 0], positions[:, 0] + 1, 0)))
+
+
+@pytest.mark.parametrize("rows", [(2, 64), (1, 128)], ids=["a_head_a_row", "two_heads_a_row"])
+@pytest.mark.parametrize("bucket", [8, 6], ids=["whole_blocks", "a_part_block"])
+def test_the_flat_pool_writes_a_prompt_and_scores_the_next_step_as_dense_attention_does(bucket, rows):
+    """``write_rows`` over two prompts (a call each, as a prefill makes them;
+    the second shorter than its bucket), then ``attend`` for the decode step at
+    each one's next position, in slots 0 and 2 of 3 with blocks out of order:
+    every live output is dense causal attention's last row over the same keys
+    and values, the empty slot's is zero, and the step's own row lies in the
+    pool. A prompt of whole blocks goes a block a span and one written a
+    position a call goes by row: behind the mask the two pools are the same.
+    Both forms of a row: a K/V head of 64 a row, and LFM2's two heads to a row
+    of 128. The CPU path (``paged_gather``)."""
+    kv_heads, wide = rows
+    H, G, d, layer, layers = 4, 2, 64, 1, 2
+    assert kv_heads * wide == G * d
+    rng = np.random.default_rng(bucket + wide)
+    lengths, tables = [bucket, bucket - 1], np.array([[5, 2, 7], [0, 0, 0], [3, 6, 1]], np.int32)
+    k, v = (rng.standard_normal((2, bucket + 1, G, d)).astype(np.float32) for _ in range(2))
+    q = rng.standard_normal((3, 1, H, d)).astype(np.float32)
+    spans = by_row = flat_kv.init_pool(layers, BLOCKS, BLOCK, kv_heads, wide, jnp.float32)
+    assert spans.shape == (layers, 2, BLOCKS * BLOCK * kv_heads, wide)
+    assert flat_kv.block_bytes(layers, BLOCK, kv_heads, wide, jnp.float32) * BLOCKS == spans.nbytes
+    for i, slot in enumerate((0, 2)):
+        positions, table = np.arange(bucket)[None, :], tables[slot:slot + 1]
+        prompt = flat_step(positions, positions < lengths[i], table, BLOCK)
+        spans = flat_kv.write_rows(spans, layer, prompt, k[i:i + 1, :bucket], v[i:i + 1, :bucket], kv_heads)
+        for t in range(lengths[i]):
+            one = flat_step(np.array([[t]]), np.array([[True]]), table, BLOCK)
+            by_row = flat_kv.write_rows(by_row, layer, one, k[i:i + 1, t:t + 1], v[i:i + 1, t:t + 1], kv_heads)
+    step = flat_step(np.array([[lengths[0]], [0], [lengths[1]]]), np.array([[True], [False], [True]]), tables, BLOCK)
+    *held, mask = flat_kv.gather_rows(spans, layer, step._replace(lengths=step.lengths - 1), kv_heads)
+    assert mask.sum(axis=1).tolist() == [lengths[0], 0, lengths[1]]
+    for mine, theirs in zip(held, flat_kv.gather_rows(by_row, layer, step, kv_heads)[:2]):
+        np.testing.assert_array_equal(np.where(mask[..., None, None], mine, 0), np.where(mask[..., None, None], theirs, 0))
+    assert not np.any(spans[0]) and not np.any(by_row[0])  # the other layer's rows are left alone
+    new_k, new_v = (np.stack([t[0, lengths[0]], np.zeros((G, d), np.float32), t[1, lengths[1]]])[:, None] for t in (k, v))
+    o, after = flat_kv.attend(spans, layer, step, jnp.asarray(q), jnp.asarray(new_k), jnp.asarray(new_v), kv_heads=kv_heads)
+    assert o.shape == (3, 1, H, d) and not np.any(o[1])
+    keys, values, mask = flat_kv.gather_rows(after, layer, step, kv_heads)
+    for i, slot in enumerate((0, 2)):
+        n = lengths[i] + 1
+        mine = jnp.asarray(np.repeat(q[slot], n, axis=0)[None])  # the step's query at every position: the last row is the step's
+        want = attention(mine, jnp.asarray(k[i:i + 1, :n]), jnp.asarray(v[i:i + 1, :n]), causal=True)
+        np.testing.assert_allclose(o[slot, 0], want[0, -1], rtol=1e-5, atol=1e-5)
+        np.testing.assert_array_equal(keys[slot, :n].reshape(n, G, d), k[i, :n])
+        np.testing.assert_array_equal(values[slot, :n].reshape(n, G, d), v[i, :n])
+        assert mask[slot].sum() == n
