@@ -83,6 +83,7 @@ LLM_MOE_FIELDS = (
     "touched",  # held experts with at least one row, a layer a step
     "peak",  # rows of the held expert that got the most, a layer a step
     "windows",  # windows of held rows the grouped matmuls walked, a layer a step (1 a layer-step: none spilled)
+    "pairs",  # (row tile, expert) pairs a layer's grouped calls visited (over ``touched``: 1 = every touched expert streamed once a call)
     "layers",  # expert layers a decode step runs
 )
 # one read of how the dispatched sequences lay in their slots, once a flush interval: cumulative since the engine

@@ -38,10 +38,14 @@ a time, so no token is ever dropped, whatever the routing. A window is
 windows are walked on the device until the held rows run out: one for an even
 router, none where no held expert was chosen, and a layer that holds every
 expert has one window of every row. Inside a window the kernel multiplies a
-touched expert by row tiles of at most ``ROW_TILE`` rows: a decode step's
-window is one tile, under which every touched expert's weights are streamed
-once; a prefill's window of 512 rows is two, so that an expert is multiplied
-by the tile its rows lie in and not by the whole window. The results stay in
+touched expert by row tiles of at most ``ROW_TILE`` rows (``_row_tile``): a
+decode step's window of up to 256 rows is one tile, under which every touched
+expert's weights are streamed once; a prefill's window of 512 rows is two, so
+that an expert is multiplied by the tile its rows lie in and not by the whole
+window; and where a call sends an expert fewer than ``_SPARSE_ROWS`` rows
+(Granite's and Nemotron's decode steps, whose windows are 512 rows; Nemotron's
+and LFM2's small buckets) the tiles are half that, so that a tile's products
+hide under the expert's bytes. The results stay in
 the sorted order the grouped matmul wrote them in, and a token sums the rows
 at its choices' ranks in the sort (``combine``): no buffer of every (token,
 choice) place is zeroed, scattered into, laid anew and read back (PERF.md
@@ -61,8 +65,10 @@ from ray_tpu.ops.layers import relu2, swiglu
 # rows sent to held, identity and absent experts; held experts with at least one
 # row; the rows of the held expert that got the most (over ``held / touched``
 # it says how uneven the choice leaves the load: 1 = even); windows of held
-# rows walked (over the calls: 1 = no call spilled past its first window)
-COUNTS = ("held", "zero", "absent", "touched", "peak", "windows")
+# rows walked (over the calls: 1 = no call spilled past its first window); (row
+# tile, expert) pairs a grouped call visits, each an expert's weights streamed
+# once (over ``touched``: 1 = no touched expert's rows straddled two row tiles)
+COUNTS = ("held", "zero", "absent", "touched", "peak", "windows", "pairs")
 
 # A window holds this many times the (token, choice) rows a call's held experts
 # expect of an even router, rounded up to a power of two, and no fewer than
@@ -168,9 +174,9 @@ def window_rows(n_rows: int, held: int, n_outputs: int) -> int:
     whole tiles** (Granite's decode step: 48 tokens x 10 choices = 480, half of
     them absent experts' and sorted to the back), is rounded up to whole tiles
     of ``ROW_TILE``: as one tile of 480 every touched expert was multiplied by
-    480 rows, twice its bytes' time at the MXU's peak; as two tiles of 256 the
-    ~240 held rows fill the first and the second is visited only by the experts
-    whose rows reach it (PERF.md section 6, PR 55)."""
+    480 rows, twice its bytes' time at the MXU's peak; in whole tiles the ~240
+    held rows fill the first ones and the last is visited by no expert
+    (PERF.md section 6, PR 55; which tiles: ``_row_tile``)."""
     window = WINDOW_MIN
     while window * n_outputs < WINDOW_MULTIPLE * n_rows * held:
         window *= 2
@@ -183,16 +189,40 @@ def window_rows(n_rows: int, held: int, n_outputs: int) -> int:
 # most ``ROW_TILE`` rows: the kernel visits each (row tile, expert) pair that
 # holds a row, streams the expert's weights for it and multiplies them by the
 # whole tile, so a pair costs the larger of the expert's bytes and the tile's
-# products. 256 is the chip's ridge (197e12 FLOP/s over 819e9 B/s = 240 rows of
-# bfloat16): under it a pair is bound by the bytes, and one tile a window
-# streams every touched expert once (a decode step's windows, 32-256 rows);
-# over it by the products, and a prefill's 512 rows as one tile multiplied every
-# touched expert by rows other experts own, at the MXU's peak: 0.21 ms a pair
-# of an expert of 75 MB, where tiles of 256 take 0.12 and tiles of 128 save
-# nothing more, their pairs being more (PERF.md section 6, PR 49). A power of
-# two, so a window is whole tiles; rows that are no whole tiles (144) stay one.
-# A row's result does not depend on its tile: the contraction's order is the
-# weight tile's.
+# products, and a call visits about ``touched + held rows / tile`` pairs. 256 is
+# the chip's ridge (197e12 FLOP/s over 819e9 B/s = 240 rows of bfloat16): under
+# it a pair is bound by the bytes, and one tile a window streams every touched
+# expert once (a decode step's windows, 32-256 rows); over it by the products,
+# and a prefill's 512 rows as one tile multiplied every touched expert by rows
+# other experts own, at the MXU's peak: 0.21 ms a pair of an expert of 75 MB,
+# where tiles of 256 take 0.12 and tiles of 128 save nothing more, their pairs
+# being more (PERF.md section 6, PR 49). A power of two, so a window is whole
+# tiles; rows that are no whole tiles (144) stay one. A row's result does not
+# depend on its tile: the contraction's order is the weight tile's.
+# **At the ridge the products do not hide**: the copies run at 90-92% of the
+# chip's bandwidth, so a tile of 256 rows' products (8.2 us for 6.3 MB) stand
+# beside bytes that take 8.4, and a pair costs 1.10-1.11 times a pair under 128
+# rows inside a decode program (1.04-1.05 for a matrix in one weight tile
+# alone; where the contraction is cut, the tile of rows is copied again with
+# every weight tile, 0.46 MB at 256 rows of Nemotron's ``e_down``). That is
+# worth paying where an expert owns much of the tile, and a loss where a tile
+# holds many experts of a few rows each: ``_row_tile`` halves the tile where a
+# call sends an expert fewer than ``_SPARSE_ROWS`` rows in the mean (the call's
+# (token, choice) rows over the router's outputs, which ``expert_layer`` knows
+# and the operands of one grouped call do not say: Nemotron's decode step and
+# its 256 bucket hand the kernel the same (512, 1,024) rows). Set by the
+# programs alone on the chip, ms a run at 256 -> 128 (PERF.md section 6, PR 60):
+# decode steps, 2.06 rows an expert 15.63 -> 14.89 (Nemotron) and 6.7 rows 21.04
+# -> 20.41 (Granite; tiles of 64 20.76: more pairs, more grid steps); prefill
+# buckets, 11 rows 16.52 -> 15.73 and 22 rows 23.17 -> 22.60 (Nemotron), 16 rows
+# 12.86 -> 12.60 (LFM2); and a tie within a percent or a loss at 32 rows 14.94
+# -> 14.79 (LFM2), 36 rows 19.34 -> 19.43 (Granite), 44 rows 34.79 -> 34.70
+# (Nemotron), which keep 256 as every larger bucket does. A straddling expert's
+# second pair costs no second copy where its matrix is one weight tile (the
+# block index does not change), which is why more pairs cost less than the
+# arithmetic has it. Only for a matrix that fits a weight tile: the three older
+# kinds' go by in 2 MB tiles under a smaller row tile (below), 5-11% dearer in
+# their prefills, and keep ``ROW_TILE``.
 # An expert's matrix goes by in tiles of at most ``_WEIGHT_TILE`` elements (8 MB
 # of bfloat16) where the products bound the call or the matrix fits one whole,
 # and of a quarter of that where the copies bound it; the contraction whole
@@ -216,10 +246,20 @@ ROW_TILE = 256
 _WEIGHT_TILE = 4 << 20
 _WHOLE_K = 2048
 _KERNEL_ROWS = 512  # the most rows of one row tile, and of a window that is not every row
+_SPARSE_ROWS = 24  # rows a call sends an expert in the mean under which a row tile is half of ``ROW_TILE``
 
 
-def _row_tile(rows: int) -> int:
-    return rows if rows % ROW_TILE else ROW_TILE
+def _row_tile(rows: int, an_expert: float = ROW_TILE, weights: int = 0) -> int:
+    """Rows of a row tile of a window of ``rows`` rows, from static shapes
+    alone: ``an_expert``, the rows the call sends an expert in the mean, and
+    ``weights``, the elements of an expert's matrix (a caller that gives
+    neither gets ``ROW_TILE``). Rows that are no whole tiles of ``ROW_TILE``
+    are one tile; whole tiles are ``ROW_TILE`` rows where an expert owns much
+    of one, half that where a tile holds many experts of a few rows each and
+    the matrix fits a weight tile (the comment above has the measurements)."""
+    if rows % ROW_TILE:
+        return rows
+    return ROW_TILE // 2 if an_expert < _SPARSE_ROWS and weights <= _WEIGHT_TILE else ROW_TILE
 
 
 def _widest(size: int, most: int) -> int:
@@ -242,36 +282,41 @@ def _weight_tile(k: int, n: int, row_tile: int) -> Tuple[int, int]:
     return tk, _widest(n, most // tk) if tk else 0
 
 
-def can_use_grouped_kernel(rows, experts, out_type=None) -> bool:
+def can_use_grouped_kernel(rows, experts, out_type=None, row_tile: Optional[int] = None) -> bool:
     """Platform and static shape alone, as ``ops.paged_attention``'s kernels
     are chosen: a TPU, rows and experts of one 16-bit type, a window of whole
-    sublane tiles whose row tile (``_row_tile``: ``ROW_TILE``, of which the
-    kernel walks any number, or every row) is no more than ``_KERNEL_ROWS``
-    rows, matrices of whole weight tiles of whole lanes, and a call whose
-    buffers fit the fast memory a kernel may ask for."""
+    sublane tiles and of whole row tiles (``row_tile``; not given:
+    ``_row_tile``'s ``ROW_TILE``, of which the kernel walks any number, or
+    every row) of no more than ``_KERNEL_ROWS`` rows, matrices of whole weight
+    tiles of whole lanes, and a call whose buffers fit the fast memory a kernel
+    may ask for."""
     if jax.default_backend() != "tpu":
         return False
     (r, k), n = rows.shape, experts.shape[-1]
-    tk, tn = _weight_tile(k, n, _row_tile(r))
+    row_tile = _row_tile(r) if row_tile is None else row_tile
+    tk, tn = _weight_tile(k, n, row_tile)
     out_itemsize = jnp.dtype(rows.dtype if out_type is None else out_type).itemsize
     return (
         rows.dtype == experts.dtype and jnp.dtype(rows.dtype).itemsize == 2
-        and r % 16 == 0 and _row_tile(r) <= _KERNEL_ROWS
+        and r % 16 == 0 and r % row_tile == 0 and row_tile <= _KERNEL_ROWS
         and tk > 0 and tn > 0
-        and vmem_bytes((_row_tile(r), tk, tn), 2, out_itemsize) <= VMEM_BUDGET
+        and vmem_bytes((row_tile, tk, tn), 2, out_itemsize) <= VMEM_BUDGET
     )
 
 
-def grouped_matmul(rows, experts, groups, out_type=None):
+def grouped_matmul(rows, experts, groups, out_type=None, row_tile: Optional[int] = None):
     """``rows`` (R, K), sorted by group, times ``experts`` (G, K, N): the first
     ``groups[0]`` rows by expert 0 and so on; rows past the last group come
     out as whatever was there. On a TPU the Pallas grouped matmul
-    (``ops/grouped_matmul.py``) at a stated tiling; elsewhere, and for shapes
-    the kernel does not take, ``jax.lax.ragged_dot`` (whose own lowering on a
-    TPU streams an expert under 512 x 512 tiles: PERF.md section 6, PR 38)."""
+    (``ops/grouped_matmul.py``) at a stated tiling, under row tiles of
+    ``row_tile`` rows (``expert_layer`` knows the rows an expert gets; not
+    given, ``_row_tile`` of the operand's rows alone); elsewhere, and for
+    shapes the kernel does not take, ``jax.lax.ragged_dot`` (whose own lowering
+    on a TPU streams an expert under 512 x 512 tiles: PERF.md section 6, PR
+    38)."""
     out_type = rows.dtype if out_type is None else out_type
-    if can_use_grouped_kernel(rows, experts, out_type):
-        row_tile = _row_tile(rows.shape[0])
+    row_tile = _row_tile(rows.shape[0]) if row_tile is None else row_tile
+    if can_use_grouped_kernel(rows, experts, out_type, row_tile):
         tiling = (row_tile, *_weight_tile(rows.shape[1], experts.shape[-1], row_tile))
         return _gmm(rows, experts, groups, preferred_element_type=out_type, tiling=tiling)
     return jax.lax.ragged_dot(rows, experts, groups, preferred_element_type=out_type)
@@ -331,7 +376,8 @@ def expert_layer(params: Dict[str, jax.Array], u: jax.Array, *, n_routed: int, t
     marks the rows that are tokens (padding and empty decode slots route
     nowhere and are not counted). ``counts``: rows sent to held, identity and
     absent experts, held experts with at least one row, the most rows any
-    held expert got, and the windows walked. ``rule``: one of the three.
+    held expert got, the windows walked and the (row tile, expert) pairs a
+    grouped call visits. ``rule``: one of the three.
 
     **What the caller hands in chooses the expert.** With ``e_gate`` it is the
     gated one, ``e_down (silu(e_gate v) * e_up v)``; without, the ungated one of
@@ -378,6 +424,10 @@ def expert_layer(params: Dict[str, jax.Array], u: jax.Array, *, n_routed: int, t
         ends = jnp.cumsum(sizes)
         starts, n_held = ends - sizes, ends[-1]
         n_windows = (n_held + window - 1) // window
+        # the grouped calls' row tile, from the rows this call sends an expert; a window is whole tiles, so the (tile,
+        # expert) pairs the calls visit follow from where an expert's rows start and end in the sort
+        tile = _row_tile(window, n_rows / params["router"].shape[-1], stacks[0].shape[-2] * stacks[0].shape[-1])
+        n_pairs = jnp.sum(jnp.where(sizes > 0, (ends - 1) // tile - starts // tile + 1, 0))
         order = jnp.argsort(group, stable=True).astype(jnp.int32)
         # where each (token, choice) row fell in the sort: the inverse of ``order`` (a second sort: a scatter of
         # 22,528 integers writes them one at a time, four times a sort's time on a v5e)
@@ -400,10 +450,10 @@ def expert_layer(params: Dict[str, jax.Array], u: jax.Array, *, n_routed: int, t
                 groups = jax.lax.dynamic_update_slice(
                     jnp.zeros((e_down.shape[0],), jnp.int32), groups, (layer * held_n,))
             if len(e_in) == 2:
-                hidden = swiglu(*(grouped_matmul(mine, e, groups) for e in e_in))
+                hidden = swiglu(*(grouped_matmul(mine, e, groups, row_tile=tile) for e in e_in))
             else:
-                hidden = relu2(grouped_matmul(mine, e_in[0], groups, jnp.float32)).astype(mine.dtype)
-            res = grouped_matmul(hidden, e_down, groups, jnp.float32)
+                hidden = relu2(grouped_matmul(mine, e_in[0], groups, jnp.float32, tile)).astype(mine.dtype)
+            res = grouped_matmul(hidden, e_down, groups, jnp.float32, tile)
             return res if results is None else jax.lax.dynamic_update_slice(results, res, (w * window, 0))
 
         # a window that takes every row (or more: whole row tiles) is the whole walk, is traced without a loop and
@@ -416,6 +466,6 @@ def expert_layer(params: Dict[str, jax.Array], u: jax.Array, *, n_routed: int, t
         y = y + combine(results, rank, is_held, weights)
     counts = jnp.stack([
         jnp.sum(is_held), jnp.sum(is_zero), jnp.sum(alive & ~is_held & ~is_zero), jnp.sum(sizes > 0),
-        jnp.max(sizes), n_windows,
+        jnp.max(sizes), n_windows, n_pairs,
     ]).astype(jnp.uint32)
     return y.astype(u.dtype), counts

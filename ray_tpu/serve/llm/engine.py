@@ -74,7 +74,7 @@ loop, after its dispatch, enqueues a copy of that leaf behind the step in
 flight and reads it an iteration later, when that step has been retired: no
 step waits for it. Each read feeds ``ray_tpu_llm_moe_rows_total`` /
 ``ray_tpu_llm_moe_experts_touched_total`` / ``ray_tpu_llm_moe_peak_rows_total`` /
-``ray_tpu_llm_moe_windows_total`` and, with telemetry on, one loop
+``ray_tpu_llm_moe_windows_total`` / ``ray_tpu_llm_moe_pairs_total`` and, with telemetry on, one loop
 record of kind ``llm_moe`` (cumulative counts; ``looplog.LLM_MOE_FIELDS``).
 
 How the dispatched sequences lay in their slots (a live sequence behind a live
@@ -178,6 +178,13 @@ def _engine_metrics() -> dict:
             "windows of held rows the expert layers' grouped matmuls walked, "
             "summed over layers and decode steps: over decode steps x expert "
             "layers, 1 = every call's held rows fit its first window",
+            tag_keys=("deployment",),
+        )
+        _metrics["moe_pairs"] = Counter(
+            "ray_tpu_llm_moe_pairs_total",
+            "(row tile, expert) pairs the expert layers' grouped matmuls "
+            "visited, summed over layers and decode steps: over experts "
+            "touched, 1 = every touched expert's weights streamed once a call",
             tag_keys=("deployment",),
         )
         _metrics["compile"] = Counter(
@@ -543,7 +550,7 @@ class InferenceEngine:
         self._m_decode_tokens = m["tokens"].bind({**tags, "phase": "decode"})
         if self._routing_counts is not None:  # a model without an expert layer has no such series
             self._m_moe = [m["moe_rows"].bind({**tags, "dest": d}) for d in ("held", "zero", "absent")]
-            self._m_moe += [m[name].bind(tags) for name in ("moe_touched", "moe_peak", "moe_windows")]
+            self._m_moe += [m[name].bind(tags) for name in ("moe_touched", "moe_peak", "moe_windows", "moe_pairs")]
         # periodic device sweeps refresh the ray_tpu_kv_* gauges of an idle engine
         memplane.register_kv_provider(deployment, self._occupancy)
         if start:

@@ -23,7 +23,7 @@ import pytest  # noqa: E402
 
 from benchmarks.families import granite_hybrid as F  # noqa: E402
 from benchmarks.reference import granite_hybrid as R  # noqa: E402
-from ray_tpu.models import granite_hybrid as M, paged  # noqa: E402
+from ray_tpu.models import granite_hybrid as M, moe, paged  # noqa: E402
 from ray_tpu.serve.llm.deployment import LLMServer, _resolve_model_cfg  # noqa: E402
 from ray_tpu.serve.llm.kv_cache import BlockAllocator, BlockTable  # noqa: E402
 
@@ -146,7 +146,7 @@ def test_the_config_counts_the_published_layers_and_refuses_what_the_program_doe
     assert M.paged_state_bytes(cut) == 9 * (128 * 8192 * 4 + 4 * 8448 * 2 + 4) == 38_357_028  # 38.36 MB a sequence
     pool = jax.eval_shape(lambda: M.init_paged_pool(cut, 6145, 16, 49))
     assert pool["kv"].shape == (1, 2, 6145 * 16 * 8, 128) and pool["state"].shape == (9, 49, 128, 8192)
-    assert pool["conv"].shape == (9, 49, 4 * 8448) and pool["state_pos"].shape == (9, 49) and pool["moe_counts"].shape == (6,)
+    assert pool["conv"].shape == (9, 49, 4 * 8448) and pool["state_pos"].shape == (9, 49) and pool["moe_counts"].shape == (len(moe.COUNTS),)
     for refused in (dict(position_embedding_type="rope"), dict(rope_scaling={"type": "yarn"}), dict(attention_bias=True),
                     dict(mamba_proj_bias=True), dict(mamba_conv_bias=False), dict(tie_word_embeddings=False),
                     dict(mamba_n_groups=3), dict(mamba_expand=4), dict(normalization_function="layernorm"),

@@ -263,7 +263,7 @@ def test_no_row_is_dropped_when_every_token_chooses_one_expert():
     u = jax.random.normal(jax.random.PRNGKey(1), (t, d))
     y, counts = jax.jit(lambda rows: moe.expert_layer(params, rows, n_routed=2, top_k=1, scale=6.0))(u)
     w, chosen = moe.route(u, params["router"], params["router_bias"], top_k=1, scale=6.0)
-    assert np.all(np.asarray(chosen) == 0) and np.asarray(counts).tolist() == [t, 0, 0, 1, t, 1]
+    assert np.all(np.asarray(chosen) == 0) and np.asarray(counts).tolist() == [t, 0, 0, 1, t, 1, 1]
     dense = (jax.nn.silu(u @ params["e_gate"][0]) * (u @ params["e_up"][0])) @ params["e_down"][0]
     np.testing.assert_allclose(np.asarray(y), np.asarray(w * dense), atol=1e-5)
     assert np.all(np.abs(np.asarray(y)).sum(-1) > 0)  # every one of the 40 rows came through
@@ -316,10 +316,11 @@ def test_the_engine_serves_the_replayed_tokens_and_reports_the_latent_pool():
         assert stats["blocks_total"] == BLOCKS - 1 and stats["blocks_free"] == BLOCKS - 1
         eng._moe_copy = (M.routing_counts(eng._pool), eng.decode_steps)
         eng._fold_routing_counts()
-        held, zero, absent, touched, peak, windows = eng._moe_total
+        held, zero, absent, touched, peak, windows, pairs = eng._moe_total
         assert held + zero == sum(5 + i for i in range(6)) * cfg.num_layers * cfg.moe_topk and absent == 0
-        # every expert is held, so a call's window is all its rows: one a layer a step that routed a row to an expert
-        assert 0 < windows <= eng.decode_steps * cfg.num_layers
+        # every expert is held, so a call's window is all its rows: one a layer a step that routed a row to an expert,
+        # and it is one row tile: every touched expert is visited once
+        assert 0 < windows <= eng.decode_steps * cfg.num_layers and pairs == touched
     finally:
         server._engine.shutdown()
 

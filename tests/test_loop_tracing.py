@@ -520,7 +520,7 @@ def test_loop_summary_tool_reads_the_share_ahead_and_a_newcomers_wait(tmp_path):
     """``tools/loop_summary.py`` over records as the head writes them: the
     time the batch was full, how often the loop ran ahead, what a newcomer
     waited for its first token, the windows the expert layers walked a layer
-    a step. A record older than ``ahead`` reads 0."""
+    a step, the pairs their grouped calls visited a touched expert. A record older than ``ahead`` reads 0."""
     import subprocess
 
     ms = 1_000_000
@@ -535,9 +535,11 @@ def test_loop_summary_tool_reads_the_share_ahead_and_a_newcomers_wait(tmp_path):
     old = steps[5][: 1 + fields.index("ahead")]  # as a program before the two fields wrote it
     req = ("r", 7, 30 * ms, 60 * ms, 95 * ms, 200 * ms, 5, 8, 4, 3, "length", None)
     log = looplog.LoopLog(str(tmp_path))
-    # cumulative counts at steps 3 and 9 of a model of 2 expert layers: 13 windows over 12 layer-steps
-    moe = [("m", t * 20 * ms, t, *(w if k == "windows" else 2 if k == "layers" else 0
-                                   for k in looplog.LLM_MOE_FIELDS[2:])) for t, w in ((3, 6), (9, 19))]
+    # cumulative counts at steps 3 and 9 of a model of 2 expert layers: 13 windows over 12 layer-steps, 42 pairs
+    # visited for 40 experts touched
+    moe = [("m", t * 20 * ms, t, *({"windows": w, "touched": touched, "pairs": pairs, "layers": 2}.get(k, 0)
+                                   for k in looplog.LLM_MOE_FIELDS[2:]))
+           for t, w, touched, pairs in ((3, 6, 10, 10), (9, 19, 50, 52))]
     # cumulative at steps 2 and 11: 18 live sequences dispatched between them, 9 of them behind a live slot
     behind = [("n", t * 20 * ms, t, count, held) for t, count, held in ((2, 3, 1), (11, 21, 10))]
     log.ingest({"llm-x-1": [*steps[:5], old, *steps[6:], req, *moe, *behind]})
@@ -552,6 +554,7 @@ def test_loop_summary_tool_reads_the_share_ahead_and_a_newcomers_wait(tmp_path):
     assert got["first_token_ms"] == {"count": 1, "mean_ms": 35.0, "median_ms": 35.0, "p90_ms": 35.0, "max_ms": 35.0}
     assert got["queue_wait_ms"]["mean_ms"] == 30.0
     assert got["windows_per_layer_step"] == pytest.approx(13 / 12)
+    assert got["pairs_per_touched"] == pytest.approx(42 / 40)
     assert got["kv_neighbour_share"] == pytest.approx(9 / 18)
 
 

@@ -11,14 +11,24 @@ import pytest
 from ray_tpu.models import moe
 
 D, F = 16, 32
+WINDOWS = moe.COUNTS.index("windows")
 RULES = {"softmax": moe.route, "sigmoid": moe.route_sigmoid, "topk_softmax": moe.route_topk_softmax}
 
 
+def pairs_visited(sizes, tile):
+    """The (row tile, expert) pairs a grouped call visits, counted from the
+    sorted groups: an expert with a row, every tile from its first row's to its
+    last's."""
+    ends = np.cumsum(sizes)
+    return int(sum((e - 1) // tile - (e - n) // tile + 1 for e, n in zip(ends, sizes) if n))
+
+
 def plain(params, u, *, n_routed, top_k, scale, rule, expert_offset=0, live=None, layer=None, rows=None):
-    """(y, counts without ``windows``): a token at a time, a choice at a time,
-    the parts added in the order of the choices. Without ``e_gate`` an expert is
-    ``e_down relu(e_up v)^2``; ``rows`` is what the experts read where it is not
-    what the router reads."""
+    """(y, counts without ``windows``, ``pairs``): a token at a time, a choice
+    at a time, the parts added in the order of the choices; ``pairs`` of the
+    held experts' rows in expert order under the row tile the layer's static
+    shapes give. Without ``e_gate`` an expert is ``e_down relu(e_up v)^2``;
+    ``rows`` is what the experts read where it is not what the router reads."""
     take = (lambda w: w) if layer is None else (lambda w: w[layer])
     up, down = (np.asarray(take(params[k]), np.float32) for k in ("e_up", "e_down"))
     gate = np.asarray(take(params["e_gate"]), np.float32) if params.get("e_gate") is not None else up
@@ -43,7 +53,9 @@ def plain(params, u, *, n_routed, top_k, scale, rule, expert_offset=0, live=None
                 absent += 1
                 continue
             y[t] += w * part
-    return y, [int(sizes.sum()), zero, absent, int((sizes > 0).sum()), int(sizes.max())]
+    n_rows, n_outputs = len(rows) * top_k, params["router"].shape[-1]
+    tile = moe._row_tile(moe.window_rows(n_rows, len(gate), n_outputs), n_rows / n_outputs, up[0].size)
+    return y, [int(sizes.sum()), zero, absent, int((sizes > 0).sum()), int(sizes.max())], pairs_visited(sizes, tile)
 
 
 def layer_params(held, n_outputs, *, favoured=(), shunned=(), seed=0):
@@ -120,9 +132,9 @@ def test_the_walk_agrees_with_a_plain_loop(case, rule):
     params, u, extra = case_inputs(case)
     kw = dict(n_routed=n_routed, top_k=top_k, scale=2.5, rule=RULES[rule], **extra)
     y, counts = jax.jit(lambda rows: moe.expert_layer(params, rows, **kw))(u)
-    want, want_counts = plain(params, u, **kw)
+    want, want_counts, pairs = plain(params, u, **kw)
     np.testing.assert_allclose(np.asarray(y), want, atol=2e-5)
-    assert dict(zip(moe.COUNTS, np.asarray(counts).tolist())) == dict(zip(moe.COUNTS, want_counts + [windows]))
+    assert dict(zip(moe.COUNTS, np.asarray(counts).tolist())) == dict(zip(moe.COUNTS, want_counts + [windows, pairs]))
     n_held, window = want_counts[0], moe.window_rows(t * top_k, held, n_outputs)
     assert windows == -(-n_held // window)  # the case is what its name says
     if case == "no_held_row":
@@ -147,10 +159,10 @@ def test_a_tokens_result_is_the_same_bit_for_bit_wherever_its_rows_fall(rule):
     alone, counts = layer(token)
     assert np.asarray(counts).tolist()[0] >= 1 and np.any(np.asarray(alone) != 0)
     among, counts = layer(jnp.concatenate([others[:7], token, others[7:19]]))
-    assert np.asarray(counts).tolist()[-1] == 1
+    assert np.asarray(counts).tolist()[WINDOWS] == 1
     first, counts_first = layer(jnp.concatenate([token, others]))
     last, counts_last = layer(jnp.concatenate([others, token]))
-    assert np.asarray(counts_first).tolist() == np.asarray(counts_last).tolist() and np.asarray(counts_last)[-1] == 2
+    assert np.asarray(counts_first).tolist() == np.asarray(counts_last).tolist() and np.asarray(counts_last)[WINDOWS] == 2
     for got in (among[7], first[0], last[-1]):
         np.testing.assert_array_equal(np.asarray(got), np.asarray(alone[0]))
 
@@ -173,12 +185,12 @@ def test_at_ten_choices_a_token_the_result_is_the_same_bits_alone_and_across_two
     token = eighths(jax.random.normal(jax.random.PRNGKey(11), (1, D)))
     others = eighths(jax.random.normal(jax.random.PRNGKey(12), (59, D)))
     alone, counts = layer(token)
-    assert np.asarray(counts).tolist()[0] >= 3 and np.asarray(counts).tolist()[-1] == 1 and np.any(np.asarray(alone) != 0)
+    assert np.asarray(counts).tolist()[0] >= 3 and np.asarray(counts).tolist()[WINDOWS] == 1 and np.any(np.asarray(alone) != 0)
     among, counts = layer(jnp.concatenate([others[:7], token, others[7:19]]))
-    assert np.asarray(counts).tolist()[-1] == 3
+    assert np.asarray(counts).tolist()[WINDOWS] == 3
     first, counts_first = layer(jnp.concatenate([token, others]))
     last, counts_last = layer(jnp.concatenate([others, token]))
-    assert np.asarray(counts_first).tolist() == np.asarray(counts_last).tolist() and np.asarray(counts_last)[-1] == 2
+    assert np.asarray(counts_first).tolist() == np.asarray(counts_last).tolist() and np.asarray(counts_last)[WINDOWS] == 2
     assert moe.window_rows(600, 4, 64) == 128 and 180 <= np.asarray(counts_last)[0] <= 256
     for got in (among[7], first[0], last[-1]):
         np.testing.assert_array_equal(np.asarray(got), np.asarray(alone[0]))
@@ -242,9 +254,9 @@ def test_a_prompts_walk_of_the_held_choices_gives_the_decode_steps_sum(monkeypat
     traced = str(jax.make_jaxpr(lambda rows: moe.expert_layer(params, rows, **kw))(u))
     assert traced.count("gather[") == 3 and "while[" in traced  # the loop's one, the window's rows', the router's
     y, counts = jax.jit(lambda rows: moe.expert_layer(params, rows, **kw))(u)
-    want, want_counts = plain(params, u, **kw)
+    want, want_counts, pairs = plain(params, u, **kw)
     np.testing.assert_allclose(np.asarray(y), want, atol=2e-5)
-    assert np.asarray(counts).tolist() == want_counts + [windows]
+    assert np.asarray(counts).tolist() == want_counts + [windows, pairs]
     np.testing.assert_array_equal(np.asarray(y), np.asarray(one_gather))
 
 
@@ -276,10 +288,10 @@ def test_two_matrix_experts_and_latent_rows_agree_with_a_plain_loop(case, latent
     v = u @ (jax.random.normal(jax.random.PRNGKey(8), (D, C)) * D ** -0.5) if latent else None
     kw = dict(n_routed=n_routed, top_k=top_k, scale=2.5, rule=moe.route_sigmoid, **extra)
     y, counts = jax.jit(lambda a, b: moe.expert_layer(params, a, rows=b, **kw))(u, v)
-    want, want_counts = plain(params, u, rows=v, **kw)
+    want, want_counts, pairs = plain(params, u, rows=v, **kw)
     assert y.shape == (t, width)
     np.testing.assert_allclose(np.asarray(y), want, atol=2e-5)
-    assert dict(zip(moe.COUNTS, np.asarray(counts).tolist())) == dict(zip(moe.COUNTS, want_counts + [windows]))
+    assert dict(zip(moe.COUNTS, np.asarray(counts).tolist())) == dict(zip(moe.COUNTS, want_counts + [windows, pairs]))
     if case == "no_held_row":  # the identity experts' part alone: the weights times what the experts would have read
         assert want_counts[1] > 0 and np.abs(want).max() > 1e-3
 
@@ -314,11 +326,12 @@ def test_the_third_rule_by_hand_a_softmax_over_the_chosen_logits():
     (192, 64, 64, 192), (512, 64, 64, 512),  # LFM2's decode step at 48 and at 128 slots, every expert held: one window, as it was
     (1024, 64, 64, 1024), (2048, 64, 64, 2048), (4096, 64, 64, 4096), (8192, 64, 64, 8192),  # its prefill buckets: one call of every row
     # Granite's decode step at 48 slots: every row (twice the 240 an even router sends), past the ridge and no whole
-    # tiles, so two tiles of 256; at 32 slots 320 rows likewise; its prefill buckets are whole tiles as they are
+    # tiles, so rounded up to 512 (four tiles of 128 since PR 60); at 32 slots 320 rows likewise; its prefill buckets
+    # are whole tiles as they are
     (480, 36, 72, 512), (320, 36, 72, 512), (2560, 36, 72, 2560), (5120, 36, 72, 5120), (10240, 36, 72, 10240),
     (240, 36, 72, 240), (80, 36, 72, 80),  # under the ridge: every row, one tile
     # Nemotron 3 Super's: 22 choices over 512 outputs, 128 held. A decode step's 1,056 rows (264 held of an even router)
-    # are a window of 512, two row tiles, of which the held rows pass the first; its prefill buckets walk windows of 512
+    # are a window of 512 (row tiles of 128 since PR 60: the held rows fill three); its prefill buckets walk windows of 512
     (1056, 128, 512, 512), (704, 128, 512, 512), (5632, 128, 512, 512), (11264, 128, 512, 512), (22528, 128, 512, 512),
 ])
 def test_the_window_follows_the_held_rows_and_not_the_batch(n_rows, held, n_outputs, window):
@@ -381,6 +394,10 @@ TILINGS = {
     # by a few: up whole (5.5 MB), down in three tiles of its contraction of 2,688
     "nemotron_up": (512, [120, 0, 100, 44], (256, 1024, 2688), (1024, 2688)),
     "nemotron_down": (512, [120, 0, 100, 44], (256, 896, 1024), (2688, 1024)),
+    # a decode step's window of 512 under the row tiles of 128 ``expert_layer`` hands down where an expert gets a few rows
+    # (PR 60): Granite's gate in two tiles of its contraction, Nemotron's down in three; groups across rows 128 and 256
+    "granite_gate_step": (512, [70, 0, 90, 60, 20], (128, 2048, 768), (4096, 768)),
+    "nemotron_down_step": (512, [120, 0, 100, 44], (128, 896, 1024), (2688, 1024)),
 }
 
 
@@ -403,26 +420,42 @@ def test_the_grouped_kernel_at_its_tiling_agrees_with_ragged_dot(monkeypatch, ca
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the branch ``grouped_matmul`` takes on the chip
     monkeypatch.setattr(moe, "_gmm", interpreted)
-    got = moe.grouped_matmul(x, w, groups, jnp.float32)
+    # a row tile that is not what the operand's rows alone give is the caller's to state, as ``expert_layer`` does
+    got = moe.grouped_matmul(x, w, groups, jnp.float32, None if tiling[0] == moe._row_tile(rows) else tiling[0])
     assert stated == [tiling]
     live = sum(sizes)
     np.testing.assert_allclose(np.asarray(got)[:live], np.asarray(want)[:live], rtol=2e-2, atol=2e-2)
     np.testing.assert_array_equal(np.asarray(want)[:live] != 0, True)
 
 
-@pytest.mark.parametrize("case", [c for c, (rows, *shape) in TILINGS.items() if rows == 512 and len(shape) == 2])
+# rows of one layer's groups in a window of 512, the contraction and the width, the row tiles compared
+BITS = {
+    **{c: (sizes, 4096, 256, (moe.ROW_TILE, 512)) for c, (rows, sizes, *shape) in TILINGS.items() if rows == 512 and len(shape) == 1},
+    # Granite's decode step: 36 groups of ~7 rows, 252 held; the nineteenth's rows straddle row 128
+    "granite_step_128": ([7] * 36, 512, 256, (128, moe.ROW_TILE)),
+    # Nemotron's: 128 groups of 0-3 rows, 268 held in three tiles of 128, an expert's rows across each boundary
+    "nemotron_step_128": ([(3, 2, 0, 3, 2, 3, 2, 3)[i % 8] for i in range(120)] + [2] * 8, 512, 256, (128, moe.ROW_TILE)),
+}
+
+
+@pytest.mark.parametrize("case", list(BITS))
 def test_a_rows_result_is_the_same_bits_under_either_row_tile(case):
     """The contraction's order is the weight tile's: the held rows of a window
     come out bit for bit the same through row tiles of 256 and through the one
-    tile of 512 that a prefill's window was."""
+    tile of 512 that a prefill's window was; and through tiles of 128, the
+    decode steps' of Granite and Nemotron (PR 60), where an expert's rows lie
+    across a boundary that tiles of 256 do not have."""
     from ray_tpu.ops.grouped_matmul import gmm
 
-    rows, sizes, (_, tk, tn) = TILINGS[case]
-    x, w, groups = _stacked_operands(rows, sizes)
-    halves, whole = (np.asarray(gmm(x, w, groups, preferred_element_type=jnp.float32, tiling=(tm, tk, tn), interpret=True))
-                     for tm in (moe.ROW_TILE, rows))
+    sizes, k, n, tiles = BITS[case]
+    x, w, groups = _stacked_operands(512, sizes, k, n)
+    if tiles[0] == 128:
+        assert pairs_visited(sizes, 128) > pairs_visited(sizes, 256) >= sum(g > 0 for g in sizes)  # one straddles, at the least
+    first, second = (np.asarray(gmm(x, w, groups, preferred_element_type=jnp.float32, tiling=(tm, k // 2, n), interpret=True))
+                     for tm in tiles)
     live = sum(sizes)
-    np.testing.assert_array_equal(halves[:live].view(np.uint32), whole[:live].view(np.uint32))
+    assert live <= 512 and np.all(first[:live] != 0)
+    np.testing.assert_array_equal(first[:live].view(np.uint32), second[:live].view(np.uint32))
 
 
 def test_a_window_never_exceeds_the_kernels_row_tile_and_the_older_kinds_windows_are_what_they_were():
@@ -498,27 +531,91 @@ REACHED = {
     "longcat": (12, 16, 768, {32: (32, 32), 256: (128, 128), 512: (256, 256), 1024: (512, 256)}),
     "exaone": (8, 16, 128, {48: (128, 128), 256: (512, 256), 512: (512, 256), 1024: (512, 256)}),
     "every_expert_held": (3, 4, 8, {48: (144, 144)}),
-    "lfm2": (4, 64, 64, {48: (192, 192), 128: (512, 256), 256: (1024, 256), 512: (2048, 256), 1024: (4096, 256)}),
-    "granite": (10, 36, 72, {48: (512, 256), 256: (2560, 256), 512: (5120, 256), 1024: (10240, 256)}),
-    "nemotron": (22, 128, 512, {48: (512, 256), 256: (512, 256), 512: (512, 256), 1024: (512, 256)}),
+    "lfm2": (4, 64, 64, {48: (192, 192), 128: (512, 128), 256: (1024, 128), 512: (2048, 256), 1024: (4096, 256)}),
+    "granite": (10, 36, 72, {48: (512, 128), 256: (2560, 256), 512: (5120, 256), 1024: (10240, 256)}),
+    "nemotron": (22, 128, 512, {48: (512, 128), 256: (512, 128), 512: (512, 128), 1024: (512, 256)}),
 }
+EVERY_REACHED = [(kind, tokens) for kind, case in REACHED.items() for tokens in case[3]]
 
 
-@pytest.mark.parametrize("kind,tokens", [(kind, tokens) for kind, case in REACHED.items() for tokens in case[3]])
+def _an_experts_matrix(kind):
+    """(k, n) of the kind's gate and up (the test's own kind: a matrix that fits a tile)."""
+    return next(iter(WEIGHT_TILES.get(kind, {(128, 128): None})))
+
+
+@pytest.mark.parametrize("kind,tokens", EVERY_REACHED)
 def test_the_row_tile_at_every_window_the_benchmarks_kinds_reach(monkeypatch, kind, tokens):
     """What ``grouped_matmul`` states for the window ``window_rows`` gives at
-    a decode step's and every prefill bucket's tokens: one tile of the window's
-    rows up to ``ROW_TILE`` (every decode program, all of Kimi-K2, LongCat's
-    smaller buckets: as they were), tiles of ``ROW_TILE`` in a window of 512
-    (LongCat's 1,024 bucket, K-EXAONE's three) and in a whole layer's one
-    window of every row (LFM2's; Granite's decode step's 480 rows rounded up to
-    two), and one tile where the rows are no whole tiles and under the ridge."""
+    a decode step's and every prefill bucket's tokens, under the row tile
+    ``expert_layer`` hands it: one tile of the window's rows up to ``ROW_TILE``
+    (every decode program but two, all of Kimi-K2, LongCat's smaller buckets:
+    as they were), tiles of ``ROW_TILE`` in a window of 512 (LongCat's 1,024
+    bucket, K-EXAONE's three) and in a whole layer's one window of every row
+    (LFM2's, Granite's prefills), tiles of half that where an expert gets a
+    few rows (Granite's and Nemotron's decode steps, Nemotron's 256 and 512
+    buckets, LFM2's 256 bucket and its decode step at 128 slots: PR 60), and
+    one tile where the rows are no whole tiles and under the ridge."""
     top_k, held, n_outputs, reached = REACHED[kind]
     window, row_tile = reached[tokens]
     assert moe.window_rows(tokens * top_k, held, n_outputs) == window
+    k, n = _an_experts_matrix(kind)
     stated = []
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     monkeypatch.setattr(moe, "_gmm", lambda x, w, g, *, tiling, preferred_element_type: stated.append(tiling) or x)
-    moe.grouped_matmul(jnp.zeros((window, 128), jnp.bfloat16), jnp.zeros((held, 128, 128), jnp.bfloat16), jnp.zeros((held,), jnp.int32))
+    moe.grouped_matmul(jnp.zeros((window, 128), jnp.bfloat16), jnp.zeros((held, 128, 128), jnp.bfloat16), jnp.zeros((held,), jnp.int32),
+                       row_tile=moe._row_tile(window, tokens * top_k / n_outputs, k * n))
     assert stated == [(row_tile, 128, 128)] and window % row_tile == 0
 
+
+@pytest.mark.parametrize("kind,tokens", EVERY_REACHED)
+def test_the_rule_picks_the_stated_row_tile_from_static_shapes_alone(kind, tokens):
+    """``moe._row_tile`` from the window's rows, the rows a call sends an
+    expert in the mean and the elements of an expert's matrix: ``ROW_TILE``
+    wherever an expert owns ``_SPARSE_ROWS`` rows or more of a call (LFM2's 512
+    bucket 32, Granite's 256 bucket 36, Nemotron's 1,024 bucket 44, every
+    larger one) or its matrix goes by in several weight tiles (the three older
+    kinds', whose tiles are smaller under a smaller row tile: 10.7-64 rows an
+    expert in their prefills), half of it under that (Nemotron's decode step
+    2.06 rows an expert, Granite's 6.7, LFM2's at 128 slots 8; Nemotron's 256
+    and 512 buckets 11 and 22, LFM2's 256 bucket 16), and whatever the rows
+    are where they are no whole tiles. No model's name, no configuration key:
+    two calls of one shape get one tile."""
+    top_k, held, n_outputs, reached = REACHED[kind]
+    window, row_tile = reached[tokens]
+    an_expert, (k, n) = tokens * top_k / n_outputs, _an_experts_matrix(kind)
+    assert moe._row_tile(window, an_expert, k * n) == row_tile
+    if window % moe.ROW_TILE == 0:
+        fits = k * n <= moe._WEIGHT_TILE
+        assert (row_tile == moe.ROW_TILE // 2) == (an_expert < moe._SPARSE_ROWS and fits)
+        assert moe._row_tile(window) == moe.ROW_TILE  # a caller that says nothing of its experts' rows gets the ridge
+        assert moe._row_tile(window, moe._SPARSE_ROWS, k * n) == moe.ROW_TILE
+        assert moe._row_tile(window, moe._SPARSE_ROWS - 0.01, k * n) == (moe.ROW_TILE // 2 if fits else moe.ROW_TILE)
+    else:
+        assert row_tile == window < moe.ROW_TILE
+
+
+def test_an_expert_whose_rows_straddle_two_row_tiles_is_visited_twice():
+    """48 experts, all held, three a token by a router that sends token type
+    ``j`` (a unit vector) to experts ``3j .. 3j + 2``; 96 rows of which 80 are
+    tokens, five of each type: every expert gets five rows, 240 held rows in a
+    window of 512 under row tiles of 128 (six rows an expert by the static
+    shapes). Sorted by expert, expert 25's rows are 125-129: it alone lies
+    across row 128 and is visited in both tiles, ``pairs == touched + 1``;
+    under one tile of 256 every touched expert was streamed once."""
+    types, top_k, held = 16, 3, 48
+    router = np.zeros((D, held), np.float32)
+    for j in range(types):
+        router[j, 3 * j:3 * j + 3] = (12.0, 11.0, 10.0)
+    params = {**moe.init_expert_params(jax.random.PRNGKey(0), D, F, held=held, n_outputs=held),
+              "router": jnp.asarray(router), "router_bias": jnp.zeros((held,))}
+    u = jnp.eye(D)[jnp.arange(96) % types] * 2.0
+    live = np.arange(96) < 80
+    kw = dict(n_routed=held, top_k=top_k, scale=1.0, rule=moe.route, live=jnp.asarray(live))
+    assert moe.window_rows(96 * top_k, held, held) == 512 and moe._row_tile(512, 96 * top_k / held, D * F) == 128
+    y, counts = jax.jit(lambda rows: moe.expert_layer(params, rows, **kw))(u)
+    want, want_counts, pairs = plain(params, u, **kw)
+    np.testing.assert_allclose(np.asarray(y), want, atol=2e-5)
+    got = dict(zip(moe.COUNTS, np.asarray(counts).tolist()))
+    assert (got["held"], got["touched"], got["peak"], got["windows"]) == (240, 48, 5, 1)
+    assert got["pairs"] == pairs == got["touched"] + 1
+    assert pairs_visited([5] * 48, 256) == 48

@@ -154,7 +154,7 @@ def test_the_config_counts_the_published_layers_and_refuses_what_the_program_doe
     assert M.paged_state_bytes(cut) == 5 * (128 * 8192 * 4 + 4 * 10240 * 2 + 4) == 21_381_140  # 21.38 MB a sequence
     pool = jax.eval_shape(lambda: M.init_paged_pool(cut, 1585, 64, 49))
     assert pool["kv"].shape == (1, 2, 1585 * 64 * 2, 128) and pool["state"].shape == (5, 49, 128, 8192)
-    assert pool["conv"].shape == (5, 49, 4 * 10240) and pool["state_pos"].shape == (5, 49) and pool["moe_counts"].shape == (6,)
+    assert pool["conv"].shape == (5, 49, 4 * 10240) and pool["state_pos"].shape == (5, 49) and pool["moe_counts"].shape == (len(moe.COUNTS),)
     assert twin(num_hidden_layers=22, hybrid_override_pattern=PATTERN * 2).period == 11
     for refused in (dict(hybrid_override_pattern="MEMEMEM*EM-"), dict(hybrid_override_pattern="MEME"), dict(attention_bias=True),
                     dict(mamba_proj_bias=True), dict(use_conv_bias=False), dict(tie_word_embeddings=True), dict(n_groups=3),

@@ -333,7 +333,8 @@ def _stated_tilings(monkeypatch):
     program is traced, as a set that fills as the programs are lowered: a
     decode step's windows and every window of Kimi-K2 are one row tile of the
     operand's rows, a window of 512 rows goes under two tiles of
-    ``moe.ROW_TILE``; under a row tile at the ridge, and where it fits one
+    ``moe.ROW_TILE`` (under four of half that in Granite's and Nemotron's
+    decode steps, where an expert gets a few rows); under a row tile at the ridge, and where it fits one
     whole, an expert's matrix goes by in the fewest tiles of at most 8 MB
     (PR 51: in 2 MB tiles before, as under a smaller row tile still)."""
     from ray_tpu.models import moe
@@ -917,14 +918,16 @@ def test_granite_hybrid_decode_and_prefill_at_published_widths(one_chip, monkeyp
     row in and out, twice buffered, 128 heads of 64 under decays a channel), the
     paged kernel once (it writes the step's row) and **all three** grouped
     matmuls of every layer in the grouped kernel: the step's 480 rows as a
-    window of 512 under two row tiles of 256, ``e_gate`` in two tiles of its
+    window of 512 under row tiles of 128 (an expert gets 6.7 rows: ``moe._row_tile``;
+    two tiles hold the ~240 held rows), ``e_gate`` in two tiles of its
     contraction and ``e_down`` whole; no ``ragged-dot``, no conditional, no
     pool, state or expert stack copied. A prefill of 1,024 holds the flash
     kernel once (its softmax's scale the model's own), runs the recurrence as
     matrix products in ``ssd_prefill`` nine times (eight chunks of 128 whatever
     the published 256: no array of all heads' decays, 134 MB a layer at the
     parent, nor any (heads, N, P) state is left in the program) and hands every
-    layer's 10,240 rows to the grouped kernel in one call."""
+    layer's 10,240 rows to the grouped kernel in one call, under row tiles of
+    256 (142 rows an expert)."""
     import json
     import re
 
@@ -983,7 +986,7 @@ def test_granite_hybrid_decode_and_prefill_at_published_widths(one_chip, monkeyp
     # the period's body holds its ten layers' kernels once: nine updates, one paged attention, three grouped matmuls a layer
     assert (kernels.count("selective_scan_update"), kernels.count("paged_decode_attention"), kernels.count("gmm")) == (9, 1, 30)
     assert gmm_rows(text) == [512] * 30 and "ragged-dot" not in text and " conditional(" not in text
-    assert stated == {(256, 2048, 768), (256, 768, 4096)}  # gate and up; down: two row tiles of the 480 rows' window of 512
+    assert stated == {(128, 2048, 768), (128, 768, 4096)}  # gate and up; down: row tiles of 128 in the 480 rows' window of 512
     stated.clear()
     _nothing_is_copied_for_the_grouped_matmuls(text)
     assert not pools_copied(text) and not pool_writes(text) and not state_writes(text)
@@ -1022,12 +1025,14 @@ def test_nemotron_h_decode_and_prefill_at_published_widths(one_chip, monkeypatch
     update five times (eight B/C groups of eight lane tiles each), the paged
     kernel once (two K/V heads: a block is 128 rows of 128) and **two** grouped
     matmuls an expert layer in the grouped kernel: the step's 1,056 rows as a
-    window of 512 under two row tiles of 256 over a stack of 640 groups, ``e_up``
+    window of 512 under row tiles of 128 (an expert gets 2.06 rows: ``moe._row_tile``;
+    three tiles hold the ~264 held rows) over a stack of 640 groups, ``e_up``
     (1,024 x 2,688) whole and ``e_down`` in three tiles of its contraction, the
     hidden rows out of the first in float32 for the square; no ``ragged-dot``,
     no conditional, no pool, state or expert stack copied. A prefill of 1,024
     holds the flash kernel once, ``ssd_prefill`` five times and the same two
-    grouped calls a layer inside the walk over eleven windows of 512."""
+    grouped calls a layer inside the walk over eleven windows of 512, under two
+    row tiles of 256 (44 rows an expert)."""
     import json
     import re
 
@@ -1087,7 +1092,7 @@ def test_nemotron_h_decode_and_prefill_at_published_widths(one_chip, monkeypatch
     assert (kernels.count("selective_scan_update"), kernels.count("paged_decode_attention"), kernels.count("gmm")) == (5, 1, 10)
     assert gmm_shapes(text) == ["f32[512,1024]"] * 5 + ["f32[512,2688]"] * 5  # up's hidden rows in float32, for the square
     assert "ragged-dot" not in text and " conditional(" not in text
-    assert stated == {(256, 1024, 2688), (256, 896, 1024)}  # up whole; down: two row tiles of the 1,056 rows' window of 512
+    assert stated == {(128, 1024, 2688), (128, 896, 1024)}  # up whole; down: row tiles of 128 in the 1,056 rows' window of 512
     stated.clear()
     _nothing_is_copied_for_the_grouped_matmuls(text)
     assert not pools_copied(text) and not pool_writes(text) and not state_writes(text)
@@ -1101,7 +1106,7 @@ def test_nemotron_h_decode_and_prefill_at_published_widths(one_chip, monkeypatch
     assert (kernels.count("flash_attention"), kernels.count("gmm"), kernels.count("ssd_prefill")) == (1, 10, 5)
     assert gmm_shapes(text) == ["f32[512,1024]"] * 5 + ["f32[512,2688]"] * 5  # a window of the eleven a layer walks
     assert "selective_scan" not in " ".join(kernels) and "paged_decode_attention" not in kernels
-    assert stated == {(256, 1024, 2688), (256, 896, 1024)}
+    assert stated == {(256, 1024, 2688), (256, 896, 1024)}  # a prompt's windows keep the tile at the ridge
     _nothing_is_copied_for_the_grouped_matmuls(text, but_the_metadata=True)
     assert "ragged-dot" not in text
     assert len(pool_writes(text)) == 2 and "paged_scatter" in text  # a prompt's blocks, K and V, the one attention layer

@@ -13,7 +13,9 @@ was in flight) and ``t_admit - t_submit``; and, of a model with expert
 layers, the windows of held rows its grouped matmuls walked a layer a decode
 step between that time's first and last ``llm_moe`` record
 (``windows_per_layer_step``: 1 = no call spilled past its first window; None
-without two records that carry the count), and ``kv_neighbour_share``: of the
+without two records that carry the count), the (row tile, expert) pairs those
+calls visited over the held experts that got a row (``pairs_per_touched``: 1 =
+every touched expert's weights streamed once a call), and ``kv_neighbour_share``: of the
 live sequences dispatched between that time's first and last
 ``llm_kv_neighbours`` record, the share whose preceding slot was live too, so
 whose first chunk the paged kernels started during the predecessor's last
@@ -66,13 +68,16 @@ def spread_ms(values_ns: list) -> dict:
             "p90_ms": ms[min(len(ms) - 1, int(0.9 * len(ms)))], "max_ms": ms[-1]}
 
 
-def windows_per_layer_step(moe_recs: list, t0: int, t1: int):
-    recs = [r for r in moe_recs if t0 <= r["t"] <= t1 and "windows" in r]
+def moe_ratio(moe_recs: list, t0: int, t1: int, count: str, over: str = ""):
+    """What the ``llm_moe`` records between ``t0`` and ``t1`` added to ``count``
+    over what they added to ``over`` (none named: the expert layers that ran,
+    decode steps x ``layers``); None without two records that carry both."""
+    recs = [r for r in moe_recs if t0 <= r["t"] <= t1 and count in r and (not over or over in r)]
     if len(recs) < 2:
         return None
     first, last = recs[0], recs[-1]
-    layer_steps = (last["step"] - first["step"]) * last["layers"]
-    return (last["windows"] - first["windows"]) / layer_steps if layer_steps > 0 else None
+    under = last[over] - first[over] if over else (last["step"] - first["step"]) * last["layers"]
+    return (last[count] - first[count]) / under if under > 0 else None
 
 
 def kv_neighbour_share(recs: list, t0: int, t1: int):
@@ -151,7 +156,8 @@ def summarise(recs: dict, skip_s: float) -> dict:
         "result_to_result_ms": (results[-1] - results[0]) / 1e6 / (len(results) - 1) if len(results) > 1 else None,
         "first_token_ms": spread_ms([r["t_first"] - r["t_admit"] for r in reqs]),
         "queue_wait_ms": spread_ms([r["t_admit"] - r["t_submit"] for r in reqs]),
-        "windows_per_layer_step": windows_per_layer_step(recs["llm_moe"], t0, t1),
+        "windows_per_layer_step": moe_ratio(recs["llm_moe"], t0, t1, "windows"),
+        "pairs_per_touched": moe_ratio(recs["llm_moe"], t0, t1, "pairs", "touched"),
         "kv_neighbour_share": kv_neighbour_share(recs["llm_kv_neighbours"], t0, t1),
         "stream": stream_summary(recs, t0, t1),
     }
