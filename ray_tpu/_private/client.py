@@ -19,6 +19,7 @@ from multiprocessing.connection import Client
 from typing import Optional
 
 from ray_tpu._private.ids import JobID, ObjectID, TaskID, WorkerID
+from ray_tpu._private.worker import apply_dropped
 from ray_tpu._private.worker_process import WorkerRuntime
 
 
@@ -146,6 +147,7 @@ class RemoteDriverRuntime(WorkerRuntime):
     def put(self, value):
         if not self._cross_machine:
             return super().put(value)
+        apply_dropped()
         oid = ObjectID.for_put(
             self.current_task_id or TaskID.nil(), self._put_counter.next()
         )
@@ -198,6 +200,7 @@ class RemoteDriverRuntime(WorkerRuntime):
 
     def shutdown(self):
         """Disconnect from the cluster (the cluster keeps running)."""
+        apply_dropped()  # nothing is left counted at exit
         self.closed = True
         if self._direct is not None:
             self._direct.shutdown()

@@ -43,6 +43,7 @@ from typing import Any, Dict, List, Optional, Tuple
 from ray_tpu import exceptions as exc
 from ray_tpu._private.ids import ActorID, ObjectID, TaskID
 from ray_tpu._private.task_spec import TaskSpec
+from ray_tpu._private.worker import apply_dropped
 
 logger = logging.getLogger(__name__)
 
@@ -131,6 +132,11 @@ class DirectActorClient:
         # evicted by this client's bookkeeping
         self._shared_store = shared_store
         self._on_commit = on_commit
+        # re-entered by this module's own frames (``_fail_call_locked`` and
+        # ``_relay_one_locked`` -> ``_unpin`` -> ``remove_refs``;
+        # ``_relay_one_locked`` -> ``rt.legacy_submit`` -> ``ensure_published``),
+        # never by a finalizer (worker.py's rule); taken before the store's
+        # lock, never after it
         self._lock = threading.RLock()
         self._actors: Dict[bytes, _Channel] = {}
         # addr -> dict(conn=, send_lock=, aids=set, alive=bool)
@@ -148,29 +154,16 @@ class DirectActorClient:
         self._need_resolve: set = set()  # aid_bin
         # pump wakeup pipe
         self._wake_r, self._wake_w = os.pipe()
-        self._threads_started = False
-        self._threads_lock = threading.Lock()
-
-    # -- lifecycle ---------------------------------------------------------
-
-    def _ensure_threads(self):
-        # lock-free fast path: the flag is monotonic, so a stale read only
-        # falls through to the locked check
-        if self._threads_started:
-            return
-        # guarded by its own lock, never self._lock: Thread.start() waits
-        # for the child's bootstrap, whose GC finalizers may need
-        # self._lock (see submit)
-        with self._threads_lock:
-            if self._threads_started:
-                return
-            self._threads_started = True
+        # from the start, not from the first call: the pump's turn is also
+        # what applies a process's dropped references (worker.apply_dropped)
         threading.Thread(
             target=self._pump_loop, name="direct-actor-pump", daemon=True
         ).start()
         threading.Thread(
             target=self._resolve_loop, name="direct-actor-resolve", daemon=True
         ).start()
+
+    # -- lifecycle ---------------------------------------------------------
 
     def shutdown(self):
         self._closed = True
@@ -188,11 +181,6 @@ class DirectActorClient:
                     pass
 
     # -- ownership ---------------------------------------------------------
-
-    def owns(self, oid: ObjectID) -> bool:
-        with self._lock:
-            rec = self._owned.get(oid)
-            return rec is not None and not rec.escalated
 
     def add_refs(self, oids) -> List[ObjectID]:
         """Count locally-owned oids; returns the remainder for the caller's
@@ -294,9 +282,6 @@ class DirectActorClient:
             # head now; keeping a private copy would leak per escaped oid
             self.store.evict(oid)
 
-    def entry_hint(self, oid: ObjectID):
-        return self.store.get_entry(oid)
-
     def routes_local(self, oid: ObjectID) -> bool:
         """True when this oid will (eventually) commit on the local plane —
         the caller should not register a head pull for it. Covers owned
@@ -352,13 +337,6 @@ class DirectActorClient:
             return False
         t_submit = time.time()  # submission anchor for the trace event below
         aid_bin = spec.actor_id.binary()
-        # thread startup must happen OUTSIDE self._lock: Thread.start()
-        # blocks until the new thread signals started, and if a GC cycle
-        # fires inside that thread's bootstrap, an ObjectRef.__del__ ->
-        # remove_refs there needs self._lock — holding it here while
-        # waiting on the thread is a deadlock (observed under pytest's
-        # full-suite GC pressure)
-        self._ensure_threads()
         with self._lock:
             ch = self._actors.get(aid_bin)
             if ch is None:
@@ -553,16 +531,10 @@ class DirectActorClient:
             self._commit_locked(oid, ("error", blob), "")
             oids.append(oid)
         if rec.arg_refs:
-            self._unpin_later(rec.arg_refs)
+            self._unpin(rec.arg_refs)
         self._task_actor.pop(rec.spec.task_id.binary(), None)
         if self._on_commit is not None and oids:
             self._on_commit(oids)
-
-    def _unpin_later(self, arg_refs):
-        # deferred outside the lock via a tiny thread-free trick: unpin
-        # touches rt channels that are safe under our RLock in practice,
-        # but keep it simple and call through directly.
-        self._unpin(arg_refs)
 
     # -- commits -----------------------------------------------------------
 
@@ -645,6 +617,7 @@ class DirectActorClient:
 
     def _pump_loop(self):
         while not self._closed:
+            apply_dropped()  # this frame holds no lock
             with self._lock:
                 conns = {st["conn"]: addr for addr, st in self._conns.items() if st["alive"]}
                 pending_out = any(
@@ -674,26 +647,14 @@ class DirectActorClient:
 
     def _handle_reply(self, msg):
         kind = msg[0]
-        if kind == "results":
+        if kind in ("results", "result"):  # a batch of a call's four, or the four
             committed: list = []
             unpin: list = []
             with self._lock:
-                for _, tid_bin, results, src_dir in msg[1]:
+                for _, tid_bin, results, src_dir in msg[1] if kind == "results" else [msg]:
                     self._apply_result_locked(
                         tid_bin, results, src_dir, committed, unpin
                     )
-            for refs in unpin:
-                self._unpin(refs)
-            if self._on_commit is not None and committed:
-                self._on_commit(committed)
-        elif kind == "result":
-            _, tid_bin, results, src_dir = msg
-            committed = []
-            unpin = []
-            with self._lock:
-                self._apply_result_locked(
-                    tid_bin, results, src_dir, committed, unpin
-                )
             for refs in unpin:
                 self._unpin(refs)
             if self._on_commit is not None and committed:

@@ -11,6 +11,7 @@ and in-flight task args; objects are freed when the count drops to zero).
 
 from __future__ import annotations
 
+import collections
 import os
 import pickle
 import threading
@@ -47,6 +48,59 @@ def get_runtime():
 
 def is_initialized() -> bool:
     return _worker_runtime is not None or _driver is not None
+
+
+# THE RULE FOR EVERY FINALIZER of this package (``ObjectRef``,
+# ``ObjectRefGenerator``, ``ActorHandle``, serve's ``DeploymentResponse``, the
+# compiled DAGs): a ``__del__`` takes no lock and calls nothing that may. The
+# collector runs it on whatever thread allocates next, under whatever lock that
+# thread holds (``MemoryStore.wait_for`` hashing an id under the store's lock
+# was one), so a finalizer that locks, sends on a connection or calls a
+# runtime, a store or a handle can stop its process for good. It calls
+# ``note_dropped``, which appends to this deque (an append is atomic and needs
+# no lock), and returns. ``apply_dropped`` runs the decrement (``remove_refs``,
+# ``release_stream``, ``actor_handle_count(-1)``, a response's ``done()``, a
+# DAG's ``teardown()``) from frames that hold no lock: once a turn of the
+# direct plane's pump, so a thread that drops and never asks again frees
+# within that turn, and at the top of the runtime's entry points
+# (``get_objects``, ``wait``, ``put``, ``submit``, ``stream_item_sent_ns``,
+# ``shutdown``), so whoever drops and then asks sees it gone; one caller
+# applies at a time and the next waits for it, for the same reason. A
+# collector that fires in there appends, and the loop takes it up. Only
+# decrements wait here. ``add_refs`` and ``transit_pin`` stay synchronous: a
+# count briefly too high frees late, a count briefly too low frees a live
+# object, and an add posted before a task (``DriverRuntime.submit``) still
+# comes before the dropped handle's decrement, which is only later than it was.
+_dropped: "collections.deque" = collections.deque()
+_apply_lock = threading.Lock()  # taken in apply_dropped alone, never under another lock
+
+
+def note_dropped(kind: str, what) -> None:
+    """All a finalizer does. The entry names the runtime that counted it: a later session never applies it."""
+    rt = _worker_runtime if _worker_runtime is not None else _driver
+    if rt is not None:
+        _dropped.append((rt, kind, what))
+
+
+def apply_dropped() -> None:
+    """Run what the finalizers queued (the rule above says from where)."""
+    with _apply_lock:
+        refs, counted_by = [], None  # a process has one open runtime: one batch
+        while _dropped:
+            rt, kind, what = _dropped.popleft()
+            if getattr(rt, "closed", False):
+                continue  # what a closed runtime counted went with it
+            if kind == "ref":
+                refs.append(what)
+                counted_by = rt
+            elif kind == "stream":
+                rt.release_stream(what)
+            elif kind == "handle":
+                rt.actor_handle_count(what, -1)
+            else:
+                what()
+        if refs:
+            counted_by.remove_refs(refs)
 
 
 class ObjectRef:
@@ -106,14 +160,8 @@ class ObjectRef:
         return (_deserialize_ref_tok, (self._id, token))
 
     def __del__(self):
-        if not self._owned:
-            return
-        rt = _worker_runtime if _worker_runtime is not None else _driver
-        if rt is not None and not getattr(rt, "closed", False):
-            try:
-                rt.remove_refs([self._id])
-            except Exception:
-                pass
+        if self._owned:
+            note_dropped("ref", self._id)
 
     def future(self):
         """Return a concurrent.futures.Future resolving to the value."""
@@ -192,16 +240,14 @@ class ObjectRefGenerator:
         # push-based: block on the runtime's wait plane (pull registration in
         # workers, memory-store condition vars in the driver) instead of
         # spinning on object_ready (round-1 polled at 1 ms here)
-        import time as _time
-
         rt = get_runtime()
-        deadline = None if timeout_s is None else _time.monotonic() + timeout_s
+        deadline = None if timeout_s is None else time.monotonic() + timeout_s
         next_oid = ObjectID.for_return(self._task_id, self._index + 1)
         count_oid = self._count_ref.id()
         while True:
             slice_s = 30.0
             if deadline is not None:
-                slice_s = min(slice_s, max(0.0, deadline - _time.monotonic()))
+                slice_s = min(slice_s, max(0.0, deadline - time.monotonic()))
             if self._total is None:
                 ready, _ = rt.wait([next_oid, count_oid], 1, timeout=slice_s)
                 if count_oid in ready and not rt.object_ready(next_oid):
@@ -218,24 +264,16 @@ class ObjectRefGenerator:
                 return ObjectRef(next_oid, _owned=True)
             if self._total is not None and self._index >= self._total:
                 raise StopIteration
-            if deadline is not None and _time.monotonic() >= deadline:
-                from ray_tpu import exceptions as exc
-
+            if deadline is not None and time.monotonic() >= deadline:
                 raise exc.GetTimeoutError(
                     f"stream item {self._index + 1} not produced within "
                     f"{timeout_s:g}s"
                 )
 
     def __del__(self):
-        # abandoned mid-stream (or fully drained): let the runtime drop
+        # abandoned mid-stream (or fully drained): the runtime drops
         # locally-owned items that were committed but never consumed
-        try:
-            rt = get_runtime()
-            release = getattr(rt, "release_stream", None)
-            if release is not None:
-                release(self._task_id)
-        except Exception:
-            pass
+        note_dropped("stream", self._task_id)
 
 
 class DriverRuntime:
@@ -283,13 +321,12 @@ class DriverRuntime:
             _sampler.ensure_running(self.config)
 
     # -- refs --------------------------------------------------------------
-    # Ref ops post individually (no driver-side batching): a buffer would
-    # need a lock that ObjectRef.__del__ can re-enter via GC (deadlock) and
-    # delays adds past the transit-pin TTL. The cheap part of posting —
-    # skipping the wakeup syscall when the loop is already signaled — lives
-    # in Scheduler.post instead. Refs to direct-call results are counted in
-    # process (this driver OWNS them) and never touch the loop until the
-    # ref escapes to another process (ensure_published escalation).
+    # Adds post at once; removes come from ``apply_dropped`` (the finalizers'
+    # rule, top of this module). The cheap part of posting, skipping the
+    # wakeup syscall when the loop is already signaled, lives in
+    # Scheduler.post. Refs to direct-call results are counted in process
+    # (this driver OWNS them) and never touch the loop until the ref escapes
+    # to another process (ensure_published escalation).
 
     def add_refs(self, oids):
         if self._direct is not None:
@@ -311,6 +348,7 @@ class DriverRuntime:
 
     def stream_item_sent_ns(self, oid) -> int:
         """``time_ns()`` of a direct stream item's send in its sender's process (0: none came with it)."""
+        apply_dropped()
         return self._direct.item_sent_ns(oid) if self._direct is not None else 0
 
     # -- pubsub (parity: GCS pubsub subscriber surface) --------------------
@@ -385,6 +423,7 @@ class DriverRuntime:
     def put(self, value) -> ObjectID:
         if isinstance(value, ObjectRef):
             raise TypeError("Calling put() on an ObjectRef is not allowed")
+        apply_dropped()
         oid = ObjectID.for_put(self.task_id, self._put_counter.next())
         size = self.store.put_serialized(oid, self.serde, value)
         self.scheduler.memory_store.put(oid, ("stored",))
@@ -417,6 +456,7 @@ class DriverRuntime:
         return None
 
     def get_objects(self, oids: List[ObjectID], timeout: Optional[float] = None) -> List[Any]:
+        apply_dropped()
         ms = self.scheduler.memory_store
         deadline = None if timeout is None else time.monotonic() + timeout
         missing = list(dict.fromkeys(o for o in oids if not ms.contains(o)))
@@ -563,6 +603,7 @@ class DriverRuntime:
         return exc.RayTpuError(f"bad entry {kind}"), True
 
     def wait(self, oids: List[ObjectID], num_returns: int, timeout: Optional[float]):
+        apply_dropped()
         ms = self.scheduler.memory_store
         if self._direct is not None:
             self._direct.flush()
@@ -580,9 +621,10 @@ class DriverRuntime:
         # worker when possible; everything else goes through the scheduler.
         # For the legacy path, pin ref args for the duration of the task
         # (submitted-task references, parity: reference_count.h). add_ref is
-        # posted to the same command queue *before* submit, so a subsequent
-        # ObjectRef.__del__ remove_ref can never drop the count to zero
+        # posted to the same command queue *before* submit, so the remove_ref
+        # of a handle dropped afterwards can never drop the count to zero
         # while the task is in flight.
+        apply_dropped()
         if (
             self._direct is not None
             and spec.task_type == TaskType.ACTOR_TASK
@@ -684,6 +726,7 @@ class DriverRuntime:
         return _scope()
 
     def shutdown(self):
+        apply_dropped()  # nothing is left counted at exit
         if getattr(self.config, "telemetry_enabled", True):
             # the last pull: what the workers and this process still hold
             # (a loop's last records, a session's last step) reaches the

@@ -33,6 +33,7 @@ from ray_tpu._private import memplane, netplane, serialization
 from ray_tpu._private.ids import ObjectID, TaskID, WorkerID, _Counter
 from ray_tpu._private.object_store import StoreFullError
 from ray_tpu._private.task_spec import Arg, TaskSpec, TaskType
+from ray_tpu._private.worker import apply_dropped
 
 
 class _ReplyBuf:
@@ -342,6 +343,7 @@ class WorkerRuntime:
     # -- object plane ------------------------------------------------------
 
     def put(self, value) -> ObjectID:
+        apply_dropped()
         tid = self.current_task_id or TaskID.nil()
         oid = ObjectID.for_put(tid, self._put_counter.next())
         size = self.store.put_serialized(oid, self.serde, value)
@@ -350,6 +352,7 @@ class WorkerRuntime:
         return oid
 
     def get_objects(self, oids: List[ObjectID], timeout: Optional[float] = None) -> List[Any]:
+        apply_dropped()
         out: Dict[ObjectID, Any] = {}
         errs: Dict[ObjectID, bool] = {}
         missing = []
@@ -592,6 +595,7 @@ class WorkerRuntime:
     def wait(self, oids, num_returns, timeout):
         """One pull registration for the whole wait; readiness arrives via the
         initial reply plus per-object follow-ups (no per-poll churn)."""
+        apply_dropped()
         ready: List[ObjectID] = []
         pending = list(dict.fromkeys(oids))
         if self._direct is not None:
@@ -644,6 +648,7 @@ class WorkerRuntime:
         return sel, [o for o in oids if o not in sel_set]
 
     def submit(self, spec: TaskSpec):
+        apply_dropped()
         if (
             self._direct is not None
             and spec.task_type == TaskType.ACTOR_TASK
@@ -716,6 +721,7 @@ class WorkerRuntime:
 
     def stream_item_sent_ns(self, oid) -> int:
         """``time_ns()`` of a direct stream item's send in its sender's process (0: none came with it)."""
+        apply_dropped()
         return self._direct.item_sent_ns(oid) if self._direct is not None else 0
 
     # -- pubsub (parity: GCS pubsub subscriber surface) --------------------

@@ -18,7 +18,7 @@ from ray_tpu._private.ids import ActorID, ObjectID, TaskID
 from ray_tpu._private.runtime_env import upload_runtime_env as _upload_runtime_env
 from ray_tpu.util.tracing import for_submission as _trace_for_submission
 from ray_tpu._private.task_spec import SchedulingStrategy, TaskSpec, TaskType
-from ray_tpu._private.worker import ObjectRef, ObjectRefGenerator, get_runtime, pack_args
+from ray_tpu._private.worker import ObjectRef, ObjectRefGenerator, get_runtime, note_dropped, pack_args
 from ray_tpu.remote_function import resolve_resources, resolve_strategy
 
 _DEFAULT_ACTOR_OPTIONS = dict(
@@ -125,10 +125,7 @@ class ActorHandle:
 
     def __del__(self):
         if getattr(self, "_owned", False):
-            try:
-                get_runtime().actor_handle_count(self._actor_id, -1)
-            except Exception:
-                pass
+            note_dropped("handle", self._actor_id)
 
 
 class ActorClass:

@@ -18,6 +18,7 @@ from __future__ import annotations
 from typing import Any, Dict, List, Optional
 
 import ray_tpu
+from ray_tpu._private.worker import note_dropped
 
 
 class DAGNode:
@@ -274,6 +275,13 @@ class _SeqBufferedResults:
         self._next_read = 0
         self._buffered: Dict[int, Any] = {}
 
+    def __del__(self):
+        # a dropped DAG must not leak resident stage actors (their loops
+        # never finish on their own, so out-of-scope reaping can't fire);
+        # killing them takes the runtime's locks, so not from here
+        if not getattr(self, "_closed", True):  # built to the end, and open
+            note_dropped("call", self.teardown)
+
     def _result_for(self, seq: int, timeout: float):
         if seq in self._buffered:
             return self._buffered.pop(seq)
@@ -386,14 +394,6 @@ class ChannelCompiledDAG(_SeqBufferedResults):
                 os.unlink(p)
             except OSError:
                 pass
-
-    def __del__(self):
-        # a dropped DAG must not leak resident stage actors (their loops
-        # never finish on their own, so out-of-scope reaping can't fire)
-        try:
-            self.teardown()
-        except Exception:
-            pass
 
 
 def _general_actor_graph(output: DAGNode):
@@ -805,14 +805,6 @@ class GeneralCompiledDAG(_SeqBufferedResults):
                 _os.unlink(p)
             except OSError:
                 pass
-
-    def __del__(self):
-        # a dropped DAG must not leak resident stage actors (their loops
-        # never finish on their own, so out-of-scope reaping can't fire)
-        try:
-            self.teardown()
-        except Exception:
-            pass
 
 
 def _children(node) -> List[DAGNode]:

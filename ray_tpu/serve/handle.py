@@ -32,6 +32,7 @@ import warnings
 from typing import Any, Dict, List, Optional
 
 import ray_tpu
+from ray_tpu._private.worker import note_dropped
 from ray_tpu.exceptions import ActorDiedError, GetTimeoutError
 from ray_tpu.serve.exceptions import (
     DeploymentOverloadedError,
@@ -193,12 +194,11 @@ class DeploymentResponse:
                 self._on_done()
 
     def __del__(self):
-        # fire-and-forget callers never call result(); settle on GC so the
-        # replica's outstanding counter doesn't inflate forever
-        try:
-            self._settle()
-        except Exception:
-            pass
+        # fire-and-forget callers never call result(): the slot is given back
+        # on GC, through the runtime's queue (``done`` takes the handle's lock)
+        # so the replica's outstanding counter doesn't inflate forever
+        if not self._settled and self._on_done:
+            note_dropped("call", self._on_done)
 
     def _to_object_ref(self) -> ray_tpu.ObjectRef:
         return self._ref

@@ -93,9 +93,17 @@ def pytest_sessionfinish(session, exitstatus):
 # So a test that is still going after TEST_LIMIT_S fails where it stands, with
 # every thread's stack on the real stderr, and the run goes on to its end.
 # Where the main thread cannot be interrupted (blocked inside native code) the
-# stacks are all there is: the process is left alone, because under
-# ``--dist loadfile`` xdist hands a crashed worker's test to the next worker,
-# and the next, each for the whole of the limit.
+# stacks are all there is: the process is left alone. Where it can be, but sits
+# in a finalizer under the collector, Python prints and swallows whatever the
+# alarm raises there, and the next piece of garbage blocks again: at the second
+# cut of such a test its xdist worker ends itself. Under ``--dist loadfile``
+# xdist alone would hand the file back with the test the worker died in still
+# to run, to the next worker and the next, each for the whole of the limit,
+# until it has replaced four workers a process and gives the run up (seen: five
+# failures of the one test, and no test after it run);
+# ``pytest_handlecrashitem`` below takes that test out of what is handed on, so
+# it fails once, the next worker runs the rest of its file, and the hang costs
+# TEST_LIMIT_S + TEST_CUT_AGAIN_S and the new worker's collection.
 TEST_LIMIT_S = 480
 # what is left of a test that was cut (its ``finally``, its fixtures' teardown)
 # may be stuck on the same thing: it is cut again, this often
@@ -112,6 +120,25 @@ def pytest_configure(config):
     _real_stderr_fd = os.dup(2)
 
 
+@pytest.hookimpl(optionalhook=True)
+def pytest_handlecrashitem(crashitem, report, sched):
+    """A test whose worker died is reported failed (xdist does that) and is
+    not run again: the load-scope scheduler puts the worker's file back in its
+    queue with the test it died in among those still to run."""
+    queue = getattr(sched, "workqueue", {})
+    for scope, tests in list(queue.items()):
+        if crashitem in tests:
+            tests[crashitem] = True
+            if all(tests.values()):
+                del queue[scope]
+
+
+def _in_a_finalizer(frame):
+    while frame is not None and frame.f_code.co_name != "__del__":
+        frame = frame.f_back
+    return frame is not None
+
+
 @pytest.hookimpl(hookwrapper=True)
 def pytest_runtest_protocol(item, nextitem):
     """Setup, call and teardown of one test, under one limit."""
@@ -121,8 +148,11 @@ def pytest_runtest_protocol(item, nextitem):
     import time
 
     started = time.monotonic()
+    cuts = 0
 
     def _out_of_time(signum, frame):
+        nonlocal cuts
+        cuts += 1
         faulthandler.cancel_dump_traceback_later()
         signal.alarm(TEST_CUT_AGAIN_S)
         os.write(
@@ -131,6 +161,14 @@ def pytest_runtest_protocol(item, nextitem):
             f"{time.monotonic() - started:.0f}s; every thread's stack:\n".encode(),
         )
         faulthandler.dump_traceback(file=_real_stderr_fd, all_threads=True)
+        if cuts > 1 and _in_a_finalizer(frame) and hasattr(item.config, "workerinput"):
+            os.write(
+                _real_stderr_fd,
+                f"[conftest] {item.nodeid} sits in a finalizer, where nothing "
+                "raised can travel: its worker ends here, the test counts as "
+                "failed, and the next worker runs the rest of its file\n".encode(),
+            )
+            os._exit(1)
         pytest.fail(
             f"{item.nodeid} ran into the {TEST_LIMIT_S}s limit that "
             "tests/conftest.py gives every test",

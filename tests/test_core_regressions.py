@@ -470,3 +470,148 @@ def test_generator_refs_borrowed_cross_actor(ray_start_regular):
 
     gc.collect()
     assert ray_tpu.get(totals, timeout=120) == [0.0, 5_000.0, 10_000.0]
+
+
+class _Cycle:
+    """Holds what only the cyclic collector will free."""
+
+    def __init__(self, held):
+        self.held, self.me = held, self
+
+
+@pytest.mark.parametrize("dropped", ["ref", "stream", "handle", "response"])
+def test_a_finalizer_under_the_collector_takes_no_lock(ray_start_regular, dropped):
+    """The rule at the top of ``_private/worker.py``: the collector may run a
+    finalizer on a thread that holds the very lock the finalizer's decrement
+    needs, so the finalizer only queues it, and the runtime's next entry point
+    applies it. Before the rule the first case never came back: ``ObjectRef.__del__``
+    went through ``remove_refs`` to ``MemoryStore.evict`` under the store's lock."""
+    import gc
+    import threading
+
+    from ray_tpu import serve
+    from ray_tpu._private.ids import ObjectID
+    from ray_tpu._private.worker import get_driver
+
+    @ray_tpu.remote
+    class Source:
+        def value(self):
+            return 7
+
+        def slow(self):
+            time.sleep(3)
+            return 7
+
+        def items(self, n):
+            yield from range(n)
+
+    direct = get_driver()._direct
+    store = get_driver().scheduler.memory_store
+    if dropped == "ref":  # a committed direct-call result
+        held = Source.remote().value.remote()
+        assert ray_tpu.get(held, timeout=60) == 7
+        oid, lock = held.id(), store._lock
+
+        def applied():
+            return not store.contains(oid) and oid not in direct._owned
+
+    elif dropped == "stream":  # an abandoned stream: four items nobody took
+        held = Source.remote().items.options(num_returns="streaming").remote(4)
+        ray_tpu.get(held._count_ref, timeout=60)
+        items = [ObjectID.for_return(held._task_id, i) for i in range(1, 5)]
+        assert all(store.contains(o) for o in items)
+        lock = store._lock
+
+        def applied():
+            return not any(store.contains(o) for o in items)
+
+    elif dropped == "handle":  # an actor handle with a call in flight
+        held = Source.remote()
+        in_flight = held.slow.remote()
+        channel, lock = direct._actors[held._actor_id.binary()], direct._lock
+
+        def applied():  # the decrement waits for the call, as it did
+            return channel.pending_release == 1
+
+    else:  # a response nobody asked for its result
+        held = serve.run(serve.deployment(lambda: 7).bind(), name="dropped")
+        held = held.remote()
+        handle = held._call[0]
+        lock = handle._lock
+
+        def applied():
+            return sum(handle._outstanding.values()) == 0
+
+    try:
+        assert not applied()
+        box = [_Cycle(held)]
+        del held
+
+        def drop_under_the_lock():
+            with lock:
+                box.clear()
+                gc.collect()
+
+        thread = threading.Thread(target=drop_under_the_lock, daemon=True)
+        thread.start()
+        thread.join(10)
+        assert not thread.is_alive(), "a finalizer waits for a lock its own thread holds"
+        ray_tpu.put(0)  # the runtime's next entry point
+        assert applied()
+        if dropped == "handle":
+            assert ray_tpu.get(in_flight, timeout=60) == 7
+    finally:
+        if dropped == "response":
+            serve.shutdown()
+
+
+def test_replica_death_and_failover_with_the_collector_at_every_allocation(ray_start_regular):
+    """``tests/test_serve.py::test_replica_death_reconciled`` with the collector
+    made to run where load used to make it: that test once sat out a whole
+    tier-1 run in ``ObjectRef.__del__`` under ``MemoryStore.wait_for``."""
+    import gc
+    import threading
+
+    from ray_tpu import serve
+
+    @serve.deployment(num_replicas=1)
+    class Fragile:
+        def __call__(self):
+            return "alive"
+
+        def die(self):
+            import os
+
+            os._exit(1)
+
+    def death_and_failover():
+        handle = serve.run(Fragile.bind(), name="fragile")
+        assert handle.remote().result(timeout_s=30) == "alive"
+        try:
+            handle.die.remote().result(timeout_s=30)
+        except Exception:
+            pass
+        deadline = time.monotonic() + 30
+        while time.monotonic() < deadline:
+            try:
+                assert serve.get_app_handle("fragile").remote().result(timeout_s=30) == "alive"
+                done.append(True)
+                return
+            except Exception:
+                time.sleep(0.5)
+
+    done = []
+    before = gc.get_threshold()
+    gc.set_threshold(1)
+    try:
+        thread = threading.Thread(target=death_and_failover, daemon=True)
+        thread.start()
+        thread.join(60)
+        alive = thread.is_alive()
+    finally:
+        gc.set_threshold(*before)
+    try:
+        assert not alive, "still going after 60 s"
+        assert done, "the replica was not restarted"
+    finally:
+        serve.shutdown()
