@@ -3,7 +3,8 @@
 Design choices for the MXU/XLA (SURVEY.md §7, BASELINE.md north-star GPT-J):
 
 * params are a flat dict of stacked per-layer arrays scanned with
-  ``jax.lax.scan`` — one compiled block body regardless of depth;
+  ``jax.lax.scan`` — one compiled block body regardless of depth (one
+  device's stack of at most ``UNROLLED_LAYERS`` is laid out whole: see there);
 * every parameter has a logical-axes tuple (``param_logical_axes``) consumed
   by ``ray_tpu.parallel.sharding`` so DP/FSDP/TP/CP are pure annotation
   changes;
@@ -275,6 +276,24 @@ _gradients_together.defvjp(
 )
 
 
+# The deepest stack whose loop over the layers is laid out whole in the
+# program. Rolled, the forward loop stacks what each layer keeps for the
+# backward pass and the backward loop takes layer ``i``'s slice at a traced
+# index, which the compiler cannot hand to a Pallas call, nor to some fusions,
+# in place: it copies the slice out first, and a product that writes its kept
+# result into a stack at a traced index runs slower than on a buffer of its
+# own (at GPT-J's widths and 8 x 2048 on a v5e: 7.8 ms of copies and 5.5 ms of
+# slower products a layer; PERF.md, PR 62). Laid out whole, every layer's kept
+# tensors are buffers of their own, read where they were written. The price is
+# the compiler's time and the program's size, which grow with the depth (~4 s
+# a layer at those widths) where a rolled loop's one body does not: a deep
+# model keeps the rolled loop. So does a step that spans a mesh: laid out
+# whole, the fsdp 2 x tensor 2 step of four and of eight such layers read
+# 0.33 and 0.59 s on four chips where the rolled one reads 0.22 and 0.39
+# (every layer's collectives scheduled as one program; CHANGES.md, PR 62).
+UNROLLED_LAYERS = 8
+
+
 def forward(
     params: Dict[str, jax.Array],
     tokens: jax.Array,
@@ -304,7 +323,8 @@ def forward(
 
     if cfg.remat:
         body = _recomputed(body, cfg.remat_policy)
-    x, _ = jax.lax.scan(body, x, stacked)
+    one_device = mesh is None or mesh.size == 1
+    x, _ = jax.lax.scan(body, x, stacked, unroll=one_device and cfg.n_layers <= UNROLLED_LAYERS)
     with jax.named_scope("head"):
         x = rms_norm(x, params["final_norm"])
         unembed = params.get("unembed")

@@ -131,6 +131,29 @@ def test_flash_custom_vjp(interpreted, what, head_dim, seq):
         assert sorted(forward) == [False, False, True, True], calls
 
 
+@pytest.mark.parametrize("n_layers", [2, transformer.UNROLLED_LAYERS + 1], ids=["unrolled", "rolled"])
+def test_the_models_loop_keeps_the_forward_kernels_results_under_dots(interpreted, monkeypatch, n_layers):
+    """``transformer.loss_fn``'s gradient with ``remat_policy="dots"``, the
+    layers' loop laid out whole and rolled: the loop that goes forward holds
+    the forward kernel and the one that goes back holds the backward kernel
+    beside no forward kernel, in its recomputation or out of it (what
+    ``FLASH_RESIDUALS`` names was kept)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the branch ``attention`` takes on the chip
+    cfg = transformer.TransformerConfig(
+        vocab_size=128, d_model=256, n_layers=n_layers, n_heads=2, d_ff=256, max_seq_len=128,
+        parallel_block=True, use_swiglu=False, remat_policy="dots",
+    )
+    params = jax.eval_shape(lambda: transformer.init_params(jax.random.PRNGKey(0), cfg))
+    tokens = jax.ShapeDtypeStruct((1, 128), jnp.int32)
+    jaxpr = jax.make_jaxpr(jax.grad(functools.partial(transformer.loss_fn, cfg=cfg)))(params, tokens, tokens).jaxpr
+    loops = [eqn for eqn in jaxpr.eqns if eqn.primitive.name == "scan"]
+    assert [eqn.params["unroll"] for eqn in loops] == [n_layers if n_layers <= transformer.UNROLLED_LAYERS else 1] * 2
+    forward, backward = (_kernel_calls(eqn.params["jaxpr"].jaxpr) for eqn in loops)
+    assert forward == [(FORWARD_KERNEL, False)]
+    assert [name for name, _ in backward] == [BACKWARD_KERNEL]  # beside the block's recomputation, which runs no kernel
+    assert _kernel_calls(jaxpr) == forward + backward
+
+
 @pytest.mark.parametrize("block_q,block_k,causal", [(128, 256, True), (256, 128, True), (128, 128, False)])
 def test_backward_kernel_blocks(block_q, block_k, causal):
     """The one-pass backward kernel alone, at block shapes that differ and

@@ -3,6 +3,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ray_tpu.parallel.mesh import MeshConfig, create_mesh
@@ -135,6 +136,41 @@ def test_kv_cache_generation_matches_full_forward(cpu_mesh_devices):
         nxt = np.argmax(np.asarray(logits[:, -1, :], dtype=np.float32), axis=-1)
         assert (toks[:, step] == nxt).all(), f"divergence at step {step}"
         cur = np.concatenate([cur, nxt[:, None].astype(np.int32)], axis=1)
+
+
+@pytest.mark.parametrize("remat_policy", ["dots", "full"])
+@pytest.mark.parametrize("n_layers", [2, 4])
+def test_the_layers_loop_gives_the_same_loss_and_gradients_unrolled_and_rolled(monkeypatch, n_layers, remat_policy):
+    """``forward`` lays a shallow stack's loop out whole (``UNROLLED_LAYERS``)
+    and keeps a deep one's rolled: the same products in the same types either
+    way, so the loss and every gradient agree to float32's rounding of a
+    re-ordered sum (the compiler fuses the two programs differently)."""
+    from ray_tpu.models import transformer
+
+    cfg = transformer.TransformerConfig(
+        vocab_size=128, d_model=64, n_layers=n_layers, n_heads=4, d_ff=128, max_seq_len=32,
+        parallel_block=True, use_swiglu=False, remat_policy=remat_policy, dtype=jnp.float32,
+    )
+    params = transformer.init_params(jax.random.PRNGKey(2), cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(3), (2, 32), 0, cfg.vocab_size)
+    targets = jnp.roll(tokens, -1, axis=1)
+
+    def step():
+        f = jax.jit(jax.value_and_grad(lambda p: transformer.loss_fn(p, tokens, targets, cfg)))
+        return f.lower(params).as_text().count("stablehlo.while"), f(params)
+
+    assert n_layers <= transformer.UNROLLED_LAYERS
+    whiles, (loss, grads) = step()
+    assert whiles == 0  # every layer stands in the program
+    monkeypatch.setattr(transformer, "UNROLLED_LAYERS", n_layers - 1)
+    whiles, (rolled_loss, rolled_grads) = step()
+    assert whiles == 2  # the forward loop and the backward loop
+    np.testing.assert_allclose(loss, rolled_loss, rtol=1e-6)
+    assert sorted(grads) == sorted(params)
+    for name in grads:
+        # the parallel block has no norm of the MLP's own: its gradient is zero
+        assert name == "mlp_norm" or np.abs(np.asarray(grads[name])).max() > 0, name
+        np.testing.assert_allclose(grads[name], rolled_grads[name], rtol=1e-4, atol=1e-7, err_msg=name)
 
 
 def test_vit_forward_and_grads():

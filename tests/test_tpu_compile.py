@@ -118,22 +118,29 @@ WIDTHS = {
 }
 
 
+def _computations(text):
+    """({computation: [(name, dims, layout, op, operands and attributes)]} of a
+    compiled program's instructions with an array result, the names of the
+    fused computations)."""
+    import re
+
+    bodies, body = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\{$", line)
+        if head:
+            body = bodies.setdefault(head.group(1), [])
+        made = re.match(r"\s+(?:ROOT )?%([\w.\-]+) = \w+\[([\d,]*)\](\S*) ([\w\-]+)\((.*)", line)
+        if made and body is not None:
+            body.append(made.groups())
+    return bodies, set(re.findall(r" fusion\(.*?calls=%([\w.\-]+)", text))
+
+
 def _alone(text):
     """(dims, layout, op) of every instruction that runs by itself. What stands
     inside a fused computation is the fusion's own arithmetic: a
     ``dynamic-slice`` there is the fusion reading its slice in place."""
-    import re
-
-    fused = set(re.findall(r" fusion\(.*?calls=%([\w.\-]+)", text))
-    out, inside = [], False
-    for line in text.splitlines():
-        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\{$", line)
-        if head:
-            inside = head.group(1) in fused
-        made = re.match(r"\s+(?:ROOT )?%[\w.\-]+ = \w+\[([\d,]*)\](\S*) ([\w\-]+)\(", line)
-        if made and not inside:
-            out.append(made.groups())
-    return out
+    bodies, fused = _computations(text)
+    return [(dims, layout, op) for comp, body in bodies.items() if comp not in fused for _, dims, layout, op, _ in body]
 
 
 def _staged(text, params):
@@ -1150,28 +1157,39 @@ def _bench_cfg(n_layers):
     )
 
 
-def test_train_step_at_bench_geometry(topo, monkeypatch):
-    """``build_lm_train_step`` at ``bench.py``'s geometry (GPT-J widths,
-    4 layers, batch 8 x 2048, dots remat) on one described chip."""
-    from ray_tpu.ops.attention import _can_use_flash
+def _bench_step(topo, n_layers):
+    """``build_lm_train_step``'s ``jit_step`` lowered at ``bench.py``'s
+    geometry (GPT-J widths, batch 8 x 2048, dots remat) on one described chip."""
     from ray_tpu.parallel.spmd import build_lm_train_step
 
-    _steered_to_tpu(monkeypatch)
     mesh = Mesh([topo.devices[0]], ("data",))
-    bundle = build_lm_train_step(_bench_cfg(4), mesh, learning_rate=1e-4)
+    bundle = build_lm_train_step(_bench_cfg(n_layers), mesh, learning_rate=1e-4)
     state = jax.eval_shape(bundle.init_fn, jax.random.PRNGKey(0))
     rep = NamedSharding(mesh, PartitionSpec())
     tok = jax.ShapeDtypeStruct((8, 2048), jnp.int32, sharding=bundle.batch_shard)
-    compiled = bundle.step_fn.lower(_on(rep, state), tok, tok).compile()
-    qk = jax.ShapeDtypeStruct((8, 2048, 16, 256), jnp.bfloat16)
-    assert _can_use_flash(qk, qk)
-    # one forward kernel, in the forward scan's body (the backward scan's body
-    # takes its output and sums from what "dots" kept), and one backward
-    # kernel. benchmarks/layer_metrics/flash_attn_roofline.py finds them by
+    return bundle.step_fn.lower(_on(rep, state), tok, tok)
+
+
+@pytest.fixture(scope="module")
+def bench_step(topo):
+    """The benchmark's training step (4 layers) compiled once for the tests
+    that read it."""
+    with pytest.MonkeyPatch.context() as monkeypatch:
+        _steered_to_tpu(monkeypatch)
+        return _bench_step(topo, 4).compile()
+
+
+def test_train_step_at_bench_geometry(bench_step):
+    """``build_lm_train_step`` at ``bench.py``'s geometry (GPT-J widths,
+    4 layers, batch 8 x 2048, dots remat) on one described chip."""
+    compiled = bench_step
+    # a forward kernel a layer, in the forward pass alone (the backward pass
+    # takes its output and sums from what "dots" kept), and a backward
+    # kernel a layer. benchmarks/layer_metrics/flash_attn_roofline.py finds them by
     # name: KERNELS = ("flash_attention", "flash_mha")
     text = compiled.as_text()
     kernels = _kernels(text)
-    assert kernels == ["flash_attention_fwd", "flash_mha_bwd"]
+    assert kernels == ["flash_attention_fwd"] * 4 + ["flash_mha_bwd"] * 4
     assert all("flash_attention" in k or "flash_mha" in k for k in kernels)
     # the step fits without the compiler's own rematerialization pass: short of
     # room it recomputes what it judges cheapest and names it `<op>.remat`
@@ -1184,8 +1202,55 @@ def test_train_step_at_bench_geometry(topo, monkeypatch):
     # results) and the step's own with 1 GB and more to spare
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes > 0.99 * mem.argument_size_in_bytes
-    # temp_size counts the aliased arguments (7.31 GB) with the step's own (6.08 GB)
+    # temp_size counts the aliased arguments (7.31 GB) with the step's own
     assert mem.argument_size_in_bytes < mem.temp_size_in_bytes < 15.0e9
+
+
+def _moved_alone(text):
+    """(dims, name) of every instruction that runs by itself and computes
+    nothing: a ``copy``, a ``slice``, a ``dynamic-slice``, or a fusion of
+    nothing but those (``dynamic-slice_bitcast_fusion``: a slice copied out
+    of what it is cut from before its reader runs)."""
+    import re
+
+    moves = {"parameter", "constant", "bitcast", "copy", "slice", "dynamic-slice", "reshape", "transpose"}
+    bodies, fused = _computations(text)
+
+    def computes_nothing(op, rest):
+        if op != "fusion":
+            return op in ("copy", "slice", "dynamic-slice")
+        return {o for *_, o, _ in bodies[re.search(r"calls=%([\w.\-]+)", rest).group(1)]} <= moves
+
+    return [
+        (dims, name) for comp, body in bodies.items() if comp not in fused
+        for name, dims, _, op, rest in body if computes_nothing(op, rest)
+    ]
+
+
+def test_train_step_reads_a_layers_saved_outputs_where_they_were_written(bench_step):
+    """The same step: no instruction that runs by itself copies a layer's
+    saved tensor, or a slice of a saved stack that size, before a kernel or a
+    fusion reads it. With the layers' loop rolled its backward body held eight
+    (PERF.md, PR 62): six slices of the saved stacks at a traced index, and
+    the kernel's output and dO re-laid."""
+    cfg = _bench_cfg(4)
+    saved = [(8, 2048, cfg.d_model), (8, 2048, cfg.n_heads, cfg.head_dim), (8, 2048, cfg.d_ff)]
+    saved = {lead + ",".join(map(str, dims)) for dims in saved for lead in ("", "1,")}
+    text = bench_step.as_text()
+    assert " while(" not in text
+    assert not [(dims, name) for dims, name in _moved_alone(text) if dims in saved]
+
+
+@pytest.mark.parametrize("n_layers,loops", [(8, 0), (9, 2), (28, 2)])
+def test_train_steps_loop_is_rolled_past_a_depth(topo, monkeypatch, n_layers, loops):
+    """``transformer.UNROLLED_LAYERS``: up to that depth every layer stands
+    in the lowered step; a deeper model (GPT-J-6B's 28) keeps one rolled loop
+    forward and one back, and their one compiled body each."""
+    from ray_tpu.models.transformer import UNROLLED_LAYERS
+
+    _steered_to_tpu(monkeypatch)
+    assert (n_layers <= UNROLLED_LAYERS) == (loops == 0)
+    assert _bench_step(topo, n_layers).as_text().count("stablehlo.while") == loops
 
 
 def test_train_step_on_a_2x2_mesh(topo, monkeypatch):
@@ -1209,8 +1274,10 @@ def test_train_step_on_a_2x2_mesh(topo, monkeypatch):
     tok = jax.ShapeDtypeStruct((8, 2048), jnp.int32, sharding=bundle.batch_shard)
     step = bundle.step_fn.lower(state, tok, tok).compile()
     text = step.as_text()
-    # the named residuals are found inside the shard_map: one forward kernel
+    # the named residuals are found inside the shard_map: one forward kernel,
+    # in the forward loop's body (a mesh's step keeps its layers' loop rolled)
     assert _kernels(text) == ["flash_attention_fwd", "flash_mha_bwd"]
+    assert text.count(" while(") == 2
     assert "all-gather" in text and "all-reduce" in text
     mem = step.memory_analysis()
     assert mem.alias_size_in_bytes > 0.99 * mem.argument_size_in_bytes  # all but the batch
