@@ -48,7 +48,9 @@ start and the one that waits, whatever the table's width, and what a
 replica's start pays to trace and lower a kernel is the count of its binds.
 ``_latent_kernel`` goes unrolled over the chunk's places: its copies are one
 block of 20 KB each, the scalar core's issue of them is on every chunk's path,
-and on the chip a call took 18-23% longer with the loop.
+and on the chip a call took 18-23% longer with the loop; it waits for a chunk
+by its bytes and not copy by copy, and scores a chunk's live rows alone (PR 63:
+at ``_LATENT_CHUNK_BYTES``).
 
 Handed the decode step's own K and V row (``new_k``, ``new_v``; a flat pool),
 ``paged_decode_attention`` writes it too: into the two planes of the last
@@ -70,6 +72,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
 _CHUNK_BYTES = 1 << 20  # of K (and of V) in one VMEM buffer's plane; two buffers
+# what ``chunk_walk`` hands a kernel's ``chunk_copies`` to do with a copy: the same two objects at every site, so a
+# kernel that waits for a chunk otherwise than copy by copy can tell which it was given (``act is WAIT``)
+START, WAIT = operator.methodcaller("start"), operator.methodcaller("wait")
 
 
 def can_use_paged_kernel(q, pool, block_size: int, kv_heads: int = 0) -> bool:
@@ -120,9 +125,10 @@ def chunk_walk(b, last, len_ref, ahead, *, block_size: int, chunk_blocks: int, c
     lengths; ``ahead`` the two SMEM words: the buffer this sequence's first
     chunk is in, and whether its copy is under way. A kernel hands in what is
     its own: ``chunk_copies(seq, seq_blocks, chunk, slot, act)``, which calls
-    ``act`` on the copy of every live block (the first ``seq_blocks`` of its
-    table) of chunk ``chunk`` of sequence ``seq`` into buffer ``slot``, the
-    same walk whether ``act`` starts or waits; and ``zero_buffers()``, called
+    ``act`` (``START`` or ``WAIT``) on the copy of every live block (the first
+    ``seq_blocks`` of its table) of chunk ``chunk`` of sequence ``seq`` into
+    buffer ``slot``, or waits for as many bytes as those copies bring
+    (``_latent_kernel``); and ``zero_buffers()``, called
     at the first grid step before anything is started (a chunk's dead rows keep
     what the buffer held before: what that may be is the kernel's argument).
 
@@ -139,8 +145,6 @@ def chunk_walk(b, last, len_ref, ahead, *, block_size: int, chunk_blocks: int, c
     in the other one), ``over_chunks(score, carry)``, which runs ``score(c,
     slot, carry) -> carry`` on every chunk once it has come in, and
     ``close()``, the grid step's last words."""
-    start, wait = operator.methodcaller("start"), operator.methodcaller("wait")
-
     def blocks_and_chunks(seq):
         n_blocks = (len_ref[seq] + block_size - 1) // block_size
         return n_blocks, (n_blocks + chunk_blocks - 1) // chunk_blocks
@@ -157,7 +161,7 @@ def chunk_walk(b, last, len_ref, ahead, *, block_size: int, chunk_blocks: int, c
 
     @pl.when((n_chunks > 0) & (ahead[1] == 0))
     def _():
-        chunk_copies(b, n_blocks, 0, first_slot, start)
+        chunk_copies(b, n_blocks, 0, first_slot, START)
 
     nxt = jnp.minimum(b + 1, last)
     nxt_blocks, nxt_chunks = blocks_and_chunks(nxt)
@@ -171,13 +175,13 @@ def chunk_walk(b, last, len_ref, ahead, *, block_size: int, chunk_blocks: int, c
             # kernel's call took 7-9% longer on the chip (``_kernel``'s 0-2% shorter: under 0.05% of any cell's step)
             @pl.when(c + 1 < n_chunks)
             def _():
-                chunk_copies(b, n_blocks, c + 1, 1 - slot, start)
+                chunk_copies(b, n_blocks, c + 1, 1 - slot, START)
 
             @pl.when((c + 1 == n_chunks) & run_ahead)
             def _():
-                chunk_copies(nxt, nxt_blocks, 0, 1 - slot, start)
+                chunk_copies(nxt, nxt_blocks, 0, 1 - slot, START)
 
-            chunk_copies(b, n_blocks, c, slot, wait)
+            chunk_copies(b, n_blocks, c, slot, WAIT)
             return score(c, slot, carry)
 
         return jax.lax.fori_loop(0, n_chunks, chunk_step, carry)
@@ -421,7 +425,12 @@ def paged_decode_attention(
 
 # -- the latent cache (ops/latent_attention.py:mla) ----------------------------
 
-_LATENT_CHUNK_BYTES = 640 << 10  # of cache rows in one VMEM buffer (512 rows in bfloat16); two buffers
+# Timed alone on the chip (``tools/latent_time.py``, PR 63) a call's time is the scalar core's and the softmax's, not the
+# bytes': ~17 ns a copy issued, ~15 ns a guard that skips one, ~0.4 us a chunk's chain of scores, softmax and sum
+# whatever its rows, ~0.1 us a further 128 rows scored; copies and scores do not overlap (one instruction stream).
+_LATENT_CHUNK_BYTES = 1280 << 10  # of cache rows in one VMEM buffer (1,024 rows in bfloat16: a chain a sequence at most served lengths); two buffers
+_LATENT_GROUP = 8  # blocks one guard skips together: a short sequence pays a guard a dead group, not one a dead block
+_LATENT_PREFIX = 256  # rows: a chunk is scored over its live rows rounded up to this, never over the dead rows past them
 
 
 def can_use_latent_kernel(s: int, r_kv: int, rows_pool) -> bool:
@@ -447,7 +456,11 @@ def _latent_kernel(
     ``chunk_walk``, with two differences the latent cache asks for: a row is
     key and value at once (scores over all of it, the weighted sum over its
     first ``r_kv`` values), and all heads share it: one buffer, no other
-    head's columns."""
+    head's columns. And three the chip asked for (PR 63: the numbers above):
+    a chunk's copies are waited for by their bytes, in as many waits as the
+    live count has binary digits; a guard skips ``_LATENT_GROUP`` dead blocks
+    at once; and a chunk is scored over a static prefix of its buffer, the
+    live rows rounded up to ``_LATENT_PREFIX``, under one softmax."""
     b, last = pl.program_id(0), pl.num_programs(0) - 1
     ai = ai_ref[0]
     length = len_ref[b]
@@ -455,21 +468,47 @@ def _latent_kernel(
     _, rows, _ = buf.shape
 
     def chunk_copies(seq, seq_blocks, chunk, slot, act):
-        """Unrolled over the chunk's places, every index but the table's static: a copy is one block of 20 KB, the scalar
-        core's issue of them is on every chunk's path, and on the chip a call took 18-23% longer with ``_kernel``'s loop."""
+        """Laid out over the chunk's places, every index but the table's static: a copy is one block of 20 KB, the scalar
+        core's issue of them is on every chunk's path, and on the chip a call took 18-23% longer with ``_kernel``'s loop.
+        The wait needs no descriptor a block: every copy signals the buffer's semaphore by its bytes, so the chunk is
+        waited for in the binary digits of its live count, each a wait of that many blocks' bytes (7 guards for 64)."""
         at_block = chunk * chunk_blocks
-        for j in range(chunk_blocks):
-            i = at_block + j  # ahead of the condition, as the compare is: inside it, it is a cycle a block on the copies' path
+        if act is WAIT:
+            live = jnp.minimum(seq_blocks - at_block, chunk_blocks)
+            n = 1 << (chunk_blocks.bit_length() - 1)
+            while n:
+                @pl.when((live & n) != 0)
+                def _():
+                    landed = buf.at[slot, pl.ds(0, n * block_size)]  # a descriptor for its size alone
+                    pltpu.make_async_copy(landed, landed, sem.at[slot]).wait()
 
-            @pl.when(i < seq_blocks)
+                n >>= 1
+            return
+        group = math.gcd(chunk_blocks, _LATENT_GROUP)
+
+        def copy(j):
+            dst = pl.ds(pl.multiple_of(j * block_size, block_size), block_size)
+            pltpu.make_async_copy(pool_ref.at[ai, tbl_ref[seq, at_block + j]], buf.at[slot, dst], sem.at[slot]).start()
+
+        def a_group(g, _):
+            @pl.when(at_block + g * group < seq_blocks)  # the group's guard is its first block's
             def _():
-                dst = pl.ds(j * block_size, block_size)
-                act(pltpu.make_async_copy(pool_ref.at[ai, tbl_ref[seq, i]], buf.at[slot, dst], sem.at[slot]))
+                copy(g * group)
+
+                def another(k, _):
+                    i = at_block + g * group + k  # ahead of the condition, as the compare is: inside it, it is a cycle a block on the copies' path
+                    pl.when(i < seq_blocks)(lambda: copy(g * group + k))
+
+                jax.lax.fori_loop(1, group, another, None, unroll=True)
+
+        # laid out whole where the kernel is lowered (every place's index a constant there, as if written out here), and
+        # traced once a site: a replica's start pays a kernel's binds, and a guarded copy a place was 18 ms of it each
+        jax.lax.fori_loop(0, chunk_blocks // group, a_group, None, unroll=True)
 
     def zero_buffers():
-        # dead rows of a chunk keep what the buffer held before, and a weight of
-        # exactly 0 times that must be 0: nothing but zeros and pool rows is ever
-        # in the buffer
+        # dead rows of a chunk's scored prefix keep what the buffer held before, and a
+        # weight of exactly 0 times that must be 0: nothing but zeros and pool rows is
+        # ever in the buffer (the rows past the prefix are not read at all)
         buf[...] = jnp.zeros_like(buf)
 
     _, _, over_chunks, close = chunk_walk(
@@ -477,16 +516,31 @@ def _latent_kernel(
         zero_buffers=zero_buffers)
 
     q = jnp.concatenate([ql_ref[0], qr_ref[0]], axis=-1)
-    col = jax.lax.broadcasted_iota(jnp.int32, (heads, rows), 1)
+    prefixes = [*range(_LATENT_PREFIX, rows, _LATENT_PREFIX), rows]
 
     def score(c, slot, carry):
-        m, l, acc = carry
-        kv = buf[slot]
-        s = jax.lax.dot_general(q, kv, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32) * scale
-        s = jnp.where(c * rows + col < length, s, _NEG_INF)
-        m, alpha, p, l = online_softmax_weights(m, l, s)
-        acc = alpha * acc + jnp.dot(p.astype(kv.dtype), kv[:, :r_kv], preferred_element_type=jnp.float32)
-        return m, l, acc
+        live = length - c * rows  # of this chunk's rows, at least one; more than ``rows``: all
+
+        def over(end, carry):
+            m, l, acc = carry
+            kv = buf[slot, :end]
+            s = jax.lax.dot_general(q, kv, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32) * scale
+            s = jnp.where(jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) < live, s, _NEG_INF)
+            m, alpha, p, l = online_softmax_weights(m, l, s)
+            acc = alpha * acc + jnp.dot(p.astype(kv.dtype), kv[:, :r_kv], preferred_element_type=jnp.float32)
+            return m, l, acc
+
+        def among(ends, carry):
+            """The shortest of ``ends`` that holds the live rows, found by halves: a branch taken costs the scalar core
+            as much as a copy issued, and a chain a 128 rows (the online softmax carried across sub-chunks) took 0.4 us
+            each on the chip where a longer prefix under one chain takes 0.1 a 128 rows."""
+            if len(ends) == 1:
+                return over(ends[0], carry)
+            half = len(ends) // 2
+            return jax.lax.cond(live <= ends[half - 1], functools.partial(among, ends[:half]),
+                                functools.partial(among, ends[half:]), carry)
+
+        return among(prefixes, carry)
 
     m, l, acc = over_chunks(score, softmax_start(heads, r_kv))
     # an empty slot (length 0) read nothing: its output is 0, not 0/0

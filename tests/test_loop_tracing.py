@@ -516,6 +516,14 @@ def test_sessions_land_under_the_process_temporary_directory(monkeypatch, tmp_pa
     assert json.loads(line) == {"kind": "llm_step", **dict(zip(looplog.LLM_STEP_FIELDS, range(len(looplog.LLM_STEP_FIELDS))))}
 
 
+def _step_20ms(i, live, **kw):
+    """An ``llm_step`` record as the head ingests it: iteration ``i`` at 20 ms an iteration, its result 15 ms in."""
+    ms = 1_000_000
+    rec = dict.fromkeys(looplog.LLM_STEP_FIELDS, 0)
+    rec.update(step=i, t_loop=i * 20 * ms, t_result=i * 20 * ms + 15 * ms, live=live, **kw)
+    return ("s", *(rec[k] for k in looplog.LLM_STEP_FIELDS))
+
+
 def test_loop_summary_tool_reads_the_share_ahead_and_a_newcomers_wait(tmp_path):
     """``tools/loop_summary.py`` over records as the head writes them: the
     time the batch was full, how often the loop ran ahead, what a newcomer
@@ -525,13 +533,7 @@ def test_loop_summary_tool_reads_the_share_ahead_and_a_newcomers_wait(tmp_path):
 
     ms = 1_000_000
     fields = looplog.LLM_STEP_FIELDS
-
-    def step(i, live, ahead, **kw):
-        rec = dict.fromkeys(fields, 0)
-        rec.update(step=i, t_loop=i * 20 * ms, t_result=i * 20 * ms + 15 * ms, live=live, ahead=ahead, **kw)
-        return ("s", *(rec[k] for k in fields))
-
-    steps = [step(1, 1, 0), *(step(i, 2, 1) for i in range(2, 12)), step(12, 1, 1)]
+    steps = [_step_20ms(1, 1), *(_step_20ms(i, 2, ahead=1) for i in range(2, 12)), _step_20ms(12, 1, ahead=1)]
     old = steps[5][: 1 + fields.index("ahead")]  # as a program before the two fields wrote it
     req = ("r", 7, 30 * ms, 60 * ms, 95 * ms, 200 * ms, 5, 8, 4, 3, "length", None)
     log = looplog.LoopLog(str(tmp_path))
@@ -555,7 +557,42 @@ def test_loop_summary_tool_reads_the_share_ahead_and_a_newcomers_wait(tmp_path):
     assert got["queue_wait_ms"]["mean_ms"] == 30.0
     assert got["windows_per_layer_step"] == pytest.approx(13 / 12)
     assert got["pairs_per_touched"] == pytest.approx(42 / 40)
-    assert got["kv_neighbour_share"] == pytest.approx(9 / 18)
+    assert got["kv_neighbour_share"] == pytest.approx(9 / 18) and "latent" not in got
+
+
+@pytest.mark.parametrize("prompt, steps, share, chunks", [
+    (5, 3, 3 * 256 / (6 + 7 + 8), 1.0),  # one prefix of 256 rows scored at every step
+    (250, 8, (6 * 256 + 2 * 512) / sum(range(251, 259)), 1.0),  # the answer crosses into the second prefix
+    (1020, 6, (4 * 1024 + 2 * 1280) / sum(range(1021, 1027)), (4 * 1 + 2 * 2) / 6),  # and into the second chunk
+])
+def test_loop_summary_tool_says_what_the_latent_kernel_scored(tmp_path, prompt, steps, share, chunks):
+    """``--latent``: rows scored over rows live and chunks a sequence a call,
+    by arithmetic over the finished requests' prompt and decode steps, at the
+    kernel's own prefix and chunk."""
+    import subprocess
+
+    from ray_tpu.ops import paged_attention
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, os.path.join(root, "tools"))
+    try:
+        import loop_summary
+    finally:
+        sys.path.pop(0)
+    assert loop_summary.LATENT_PREFIX_ROWS == paged_attention._LATENT_PREFIX
+    assert loop_summary.LATENT_CHUNK_ROWS * 640 * 2 == paged_attention._LATENT_CHUNK_BYTES  # a bfloat16 row stored 640 wide
+
+    ms = 1_000_000
+    req = ("r", 7, 30 * ms, 60 * ms, 95 * ms, 200 * ms, prompt, 8, steps + 1, steps, "length", None)
+    late = ("r", 8, 30 * ms, 60 * ms, 95 * ms, 900 * ms, 9, 16, 2, 1, "length", None)  # finished after the batch was full
+    log = looplog.LoopLog(str(tmp_path))
+    log.ingest({"llm-x-1": [_step_20ms(1, 1), *(_step_20ms(i, 2) for i in range(2, 12)), _step_20ms(12, 1), req, late]})
+    log.close()
+    out = subprocess.run([sys.executable, os.path.join(root, "tools", "loop_summary.py"), str(tmp_path / "loops"),
+                          "--skip-s", "0", "--latent"], capture_output=True, text=True, check=True).stdout
+    got = json.loads(out)["latent"]
+    assert got["requests"] == 1
+    assert got["rows_scored_share"] == pytest.approx(share) and got["chunks_a_sequence"] == pytest.approx(chunks)
 
 
 # -- how a loop came to run: the start's stamps and the compile records -----------

@@ -181,16 +181,21 @@ def test_a_traced_kernel_holds_the_copy_sites_of_its_walk(call, unrolled, back):
     the wait. What a replica's start pays to trace and lower a kernel is the
     count of its binds. A kernel that walks a chunk's live blocks in a loop
     holds one copy a site whatever the table's width (a chunk of 4, 8 or 16
-    blocks: a block's keys and values come in under one copy); one that walks
-    them unrolled (the latent kernel, where the chip
-    reads the loop a fifth slower) a copy a place of the chunk (4, 8, 32). The
-    step's own row goes back under one copy more, keys and values together."""
-    for width, chunk_blocks in ((4, 4), (8, 8), (40, 32 if unrolled else 16)):
+    blocks: a block's keys and values come in under one copy); the latent
+    kernel, where the chip reads a loop over the blocks a fifth slower, lays
+    its copies out a place of the chunk in the lowered kernel (``test_tpu_compile``
+    counts them there) from two binds a site, a group's first place and the
+    body of the loop over its others, both loops unrolled where the kernel is
+    lowered (PR 63: 18 ms of a replica's start a traced copy), and waits one
+    wait a binary digit of a chunk's live count, each for that many blocks'
+    bytes (3, 4, 6). The step's own row goes back under one copy more, keys and
+    values together."""
+    for width, chunk_blocks in ((4, 4), (8, 8), (40, 40 if unrolled else 16)):
         fn, args = call(jnp.zeros((2, width), jnp.int32), jnp.zeros((2,), jnp.int32))
         (kernel,) = [eqn for eqn in jax.make_jaxpr(fn)(*args).eqns if eqn.primitive.name == "pallas_call"]
-        a_site = chunk_blocks if unrolled else 1
+        starts, waits = (2, chunk_blocks.bit_length()) if unrolled else (1, 1)
         counted = _count(kernel.params["jaxpr"], "dma_start"), _count(kernel.params["jaxpr"], "dma_wait")
-        assert counted == (3 * a_site + back, a_site + back), width
+        assert counted == (3 * starts + back, waits + back), width
 
 
 # The step's own row: position ``length - 1`` of four sequences, R a chunk's positions (16 x 32, 16, 24 or 12 blocks

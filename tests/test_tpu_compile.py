@@ -289,6 +289,28 @@ def _latent_kernel_reads_the_pool_in_place(text, pool_dims, batch, per_seq, bloc
     assert pool_dims not in made
 
 
+def _latent_kernel_waits_for_a_chunk_by_its_bytes(one_chip, attentions, blocks, block, batch, per_seq):
+    """The latent kernel alone at a cell's shapes (PR 63): a copy a block at
+    each place of a chunk at ``chunk_walk``'s three start sites, and at the one
+    site that waits no wait a block (32 before, when a chunk was 32 blocks) but
+    one a binary digit of a chunk's live count, each for that many blocks'
+    bytes, the whole chunk's first: seven for a chunk of 64 blocks."""
+    from ray_tpu.ops.paged_attention import _LATENT_CHUNK_BYTES, chunk_blocks_for, paged_latent_attention
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    lowered = jax.jit(lambda q_l, q_r, pool, tables, lengths: paged_latent_attention(
+        q_l, q_r, pool, jnp.int32(1), tables, lengths, scale=192 ** -0.5)).lower(
+        arg((batch, 64, 512)), arg((batch, 64, 64)), arg((attentions, blocks, block, 640)), arg((batch, per_seq), jnp.int32),
+        arg((batch,), jnp.int32))
+    starts, waits = _kernel_dmas(lowered.as_text())
+    chunk = chunk_blocks_for(per_seq, block * 640 * 2, _LATENT_CHUNK_BYTES)
+    assert chunk == 64 and starts == [(f"{block}x640",) * 2] * (3 * chunk)
+    assert waits == [(f"{n * block}x640",) * 2 for n in (64, 32, 16, 8, 4, 2, 1)]
+    assert _kernels(lowered.compile().as_text()) == ["paged_latent_attention"]
+
+
 def _grouped_matmuls_take(text, rows, width, all_rows):
     """The program's three grouped matmuls (``moe.grouped_matmul``: on a TPU
     at these widths JAX's Pallas ``gmm``, a Mosaic call each) multiply ``rows``
@@ -388,6 +410,7 @@ def test_latent_decode_and_prefill_at_longcat_widths(one_chip, monkeypatch):
     _grouped_matmuls_take(text, 32, 6144, batch * cfg.moe_topk)  # gate, up and down of the held experts
     assert stated == {(32, 512, 2048), (32, 2048, 512)}  # one row tile, the window's rows; 2 MB tiles: the parent's statement
     _latent_kernel_reads_the_pool_in_place(text, f"[4,{blocks},{block},640]", batch, per_seq, block)
+    _latent_kernel_waits_for_a_chunk_by_its_bytes(one_chip, 4, blocks, block, batch, per_seq)
     stated.clear()
     text = prefill.lower(
         params, arg((1, 1024), jnp.int32), arg((1, per_seq), jnp.int32), pool, arg((), jnp.int32)).compile().as_text()
@@ -454,6 +477,7 @@ def test_latent_decode_and_prefill_at_kimi_k2_widths(one_chip, monkeypatch):
     text = compiled.as_text()
     _grouped_matmuls_take(text, 32, 7168, batch * cfg.num_experts_per_tok)  # gate, up and down of the held experts
     _latent_kernel_reads_the_pool_in_place(text, f"[7,{blocks},{block},640]", batch, per_seq, block)
+    _latent_kernel_waits_for_a_chunk_by_its_bytes(one_chip, 7, blocks, block, batch, per_seq)
     assert staged(text) == {"wqb", "wkvb"}
     assert compiled.memory_analysis().temp_size_in_bytes < 0.02e9  # beside 10.8 GB of arguments
     text = decode_greedy.lower(

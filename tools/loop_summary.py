@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """What the engine's loop records of one run say of the time the batch was full:
 
-    python tools/loop_summary.py <session_dir>/loops [--skip-s 4]
+    python tools/loop_summary.py <session_dir>/loops [--skip-s 4] [--latent]
 
 One JSON object: over the iterations from the first to the last with every
 decode slot dispatched (less ``--skip-s`` seconds of ramp at the start), the
@@ -36,6 +36,16 @@ seconds by stage, ``lowering_s`` (tracing and lowering) and ``compile_s`` (the
 backend's, loads from the compile cache inside it) by program, heaviest first;
 and ``compiles_after_full``, the programs compiled after the first iteration
 with every slot dispatched: in a replica whose shapes were warmed, none.
+``--latent``, of a kind whose decode step reads a latent cache
+(``ops/paged_attention.py:paged_latent_attention``; LongCat, Kimi-K2): what the
+kernel scored over what was live, from the requests that finished in that time
+alone (a request of prompt ``p`` and ``n`` decode steps met the kernel at every
+length from ``p + 1`` to ``p + n``, and a length is scored in whole prefixes of
+``LATENT_PREFIX_ROWS`` under one chain a chunk of ``LATENT_CHUNK_ROWS``):
+``rows_scored_share``, the mean over requests of rows scored over rows live
+(1.0 = no dead row scored; a chunk of 512 rows scored whole, as before PR 63,
+reads 1.3-1.4 at answers of hundreds of tokens) and ``chunks_a_sequence``, the
+mean chunks a sequence a call.
 Reads with the standard library alone; newest session under the temporary
 directory where no directory is given.
 """
@@ -88,6 +98,23 @@ def kv_neighbour_share(recs: list, t0: int, t1: int):
     return (recs[-1]["sum"] - recs[0]["sum"]) / count if count > 0 else None
 
 
+# the latent kernel's constants in rows (``ops/paged_attention.py``: ``_LATENT_PREFIX``, and ``_LATENT_CHUNK_BYTES`` over a
+# stored row's 1,280 B); ``tests/test_loop_tracing.py`` holds them to the kernel's
+LATENT_PREFIX_ROWS, LATENT_CHUNK_ROWS = 256, 1024
+
+
+def latent_summary(requests: list, prefix: int = LATENT_PREFIX_ROWS, chunk: int = LATENT_CHUNK_ROWS):
+    shares, chunks = [], []
+    for r in requests:
+        lengths = range(r["prompt_len"] + 1, r["prompt_len"] + r["steps"] + 1)
+        if lengths:
+            shares.append(sum(-(-n // prefix) * prefix for n in lengths) / sum(lengths))
+            chunks.append(sum(-(-n // chunk) for n in lengths) / len(lengths))
+    if not shares:
+        return None
+    return {"requests": len(shares), "rows_scored_share": statistics.fmean(shares), "chunks_a_sequence": statistics.fmean(chunks)}
+
+
 def stream_summary(recs: dict, t0: int, t1: int):
     engine = [r for r in recs["llm_stream"] if t0 <= r["t_last_back"] <= t1]
     callers = [r for r in recs["serve_stream"] if t0 <= r["t_last_got"] <= t1]
@@ -138,7 +165,7 @@ def start_summary(recs: dict):
     return out
 
 
-def summarise(recs: dict, skip_s: float) -> dict:
+def summarise(recs: dict, skip_s: float, latent: bool = False) -> dict:
     steps = [r for r in recs["llm_step"] if r["live"]]
     if not steps:
         return {"steps": 0}
@@ -160,6 +187,7 @@ def summarise(recs: dict, skip_s: float) -> dict:
         "pairs_per_touched": moe_ratio(recs["llm_moe"], t0, t1, "pairs", "touched"),
         "kv_neighbour_share": kv_neighbour_share(recs["llm_kv_neighbours"], t0, t1),
         "stream": stream_summary(recs, t0, t1),
+        **({"latent": latent_summary([r for r in recs["llm_request"] if t0 <= r["t_finish"] <= t1])} if latent else {}),
     }
 
 
@@ -167,10 +195,11 @@ def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("loops", nargs="?", help="<session_dir>/loops (default: the newest session's)")
     ap.add_argument("--skip-s", type=float, default=4.0)
+    ap.add_argument("--latent", action="store_true", help="the kind's decode step runs paged_latent_attention")
     args = ap.parse_args()
     where = args.loops or newest_loops_dir()
     recs = load_records(where)
-    print(json.dumps({"loops": where, **summarise(recs, args.skip_s), "start": start_summary(recs)}))
+    print(json.dumps({"loops": where, **summarise(recs, args.skip_s, args.latent), "start": start_summary(recs)}))
     return 0
 
 
